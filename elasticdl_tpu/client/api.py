@@ -41,13 +41,9 @@ def _train_local(args, job_type: str = "train") -> int:
     """Master + worker(s) in one process: the zero-cluster path (and the
     dev loop for model-zoo modules)."""
     from elasticdl_tpu.common.model_handler import get_model_spec
-    from elasticdl_tpu.common.virtual_mesh import (
-        apply_compilation_cache_config,
-    )
+    from elasticdl_tpu.common.virtual_mesh import enable_compile_cache
 
-    apply_compilation_cache_config(
-        getattr(args, "compilation_cache_dir", "")
-    )
+    enable_compile_cache(getattr(args, "compilation_cache_dir", ""))
     from elasticdl_tpu.data.reader import create_data_reader
     from elasticdl_tpu.master.main import Master
     from elasticdl_tpu.proto.service import InProcessMasterClient
@@ -348,6 +344,7 @@ def build_serving_server(args):
     import numpy as np
 
     from elasticdl_tpu.common.model_handler import get_model_spec
+    from elasticdl_tpu.common.virtual_mesh import enable_compile_cache
     from elasticdl_tpu.serving.batcher import DynamicBatcher
     from elasticdl_tpu.serving.engine import ServingEngine
     from elasticdl_tpu.serving.reloader import CheckpointReloader
@@ -358,6 +355,7 @@ def build_serving_server(args):
             "elasticdl serve needs exactly one of --export_dir or "
             "--checkpoint_dir"
         )
+    enable_compile_cache()
     spec = get_model_spec(
         args.model_zoo, args.model_def, model_params=args.model_params,
         arena_dtype=getattr(args, "arena_dtype", ""),

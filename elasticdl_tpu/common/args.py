@@ -216,12 +216,13 @@ def add_common_params(parser: argparse.ArgumentParser):
         "--compilation_cache_dir", default="",
         help="Persistent XLA-executable cache directory.  A relaunched "
         "worker then LOADS the train-step executable instead of "
-        "recompiling it, cutting elastic recovery by the ~20-40s compile "
-        "— the AOT mitigation SURVEY.md hard part 1 calls for.  Empty "
-        "disables.  Re-serialized into worker pod commands like every "
-        "flag; on a real cluster pair it with --volume so the directory "
-        "is a mount shared across pod relaunches (e.g. --volume "
-        "'claim_name=cache,mount_path=/cache' "
+        "recompiling it — the AOT mitigation SURVEY.md hard part 1 "
+        "calls for.  JAX_COMPILATION_CACHE_DIR, when set, wins over "
+        "this flag; with neither, the cache is <checkout>/.jax_cache "
+        "(common/virtual_mesh.compile_cache_dir).  Re-serialized into "
+        "worker pod commands like every flag; on a real cluster pair it "
+        "with --volume so the directory is a mount shared across pod "
+        "relaunches (e.g. --volume 'claim_name=cache,mount_path=/cache' "
         "--compilation_cache_dir /cache).",
     )
     # ---- serving fleet (master/serving_fleet.py, docs/SERVING.md) ----
@@ -431,8 +432,7 @@ def add_train_params(parser: argparse.ArgumentParser):
         "--steps_per_execution", type=pos_int, default=1,
         help="Dispatch this many train steps as ONE compiled program "
         "(lax.scan over a batch stack).  Amortizes per-dispatch "
-        "overhead — significant on remote/tunneled TPU runtimes; "
-        "losses/metrics are still recorded per step.",
+        "overhead; losses/metrics are still recorded per step.",
     )
     parser.add_argument("--num_epochs", type=pos_int, default=1)
     parser.add_argument(

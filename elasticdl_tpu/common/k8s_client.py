@@ -221,6 +221,25 @@ class FakeK8sClient(AbstractK8sClient):
             self._callback(name, phase, address, exit_code)
 
 
+# libtpu's default port for the runtime-to-runtime channel between the
+# processes of one host; chip c's process listens on base + c.
+_TPU_PROCESS_BASE_PORT = 8476
+
+
+def host_tpu_chips() -> List[int]:
+    """The TPU chips this host exposes, as libtpu numbers them: the
+    numeric entries of /dev/vfio (read off a v5e host).  Listing /dev
+    keeps the caller off any backend — the master must never hold a
+    chip its workers need."""
+    import os
+
+    try:
+        names = os.listdir("/dev/vfio")
+    except OSError:
+        return []
+    return sorted(int(n) for n in names if n.isdigit())
+
+
 class ProcessK8sClient(AbstractK8sClient):
     """Local 'cluster' whose pods are OS subprocesses.
 
@@ -239,6 +258,7 @@ class ProcessK8sClient(AbstractK8sClient):
         self.phases: Dict[str, str] = {}
         self.create_calls: List[PodSpec] = []
         self._output: Dict[str, List[bytes]] = {}
+        self._chip_of: Dict[str, int] = {}   # pod name -> pinned TPU chip
         self._extra_env = dict(extra_env or {})
         self._callback: Optional[EventCallback] = None
         self._stop = threading.Event()
@@ -247,12 +267,59 @@ class ProcessK8sClient(AbstractK8sClient):
     def master_host(self, job_name: str) -> str:
         return "127.0.0.1"
 
+    def _pin_chip(self, spec: PodSpec, env: Dict[str, str]) -> None:
+        """Give a worker or serving child exactly ONE of the host's TPU
+        chips.  A chip belongs to one process at a time: a child left
+        with the parent's environment would try to own every chip and
+        the second one to start would fail or hang.  No-op on a host
+        without chips (the CPU harness) and for the master pod."""
+        if spec.pod_type not in (PodType.WORKER, PodType.SERVING):
+            return
+        chips = host_tpu_chips()
+        if not chips:
+            return
+        with self._lock:
+            # a pinned pod whose process is not registered yet is being
+            # created by another thread: its chip is taken
+            held = {
+                chip for name, chip in self._chip_of.items()
+                if name != spec.name and (
+                    name not in self.procs
+                    or self.procs[name].poll() is None
+                )
+            }
+            free = [c for c in chips if c not in held]
+            if not free:
+                raise RuntimeError(
+                    f"no free TPU chip for pod {spec.name}: chips {chips} "
+                    f"are held by {sorted(self._chip_of)}"
+                )
+            chip = self._chip_of[spec.name] = free[0]
+        env["TPU_VISIBLE_CHIPS"] = str(chip)
+        env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
+        grid = env.get("TPU_CHIPS_PER_HOST_BOUNDS")
+        if spec.pod_type == PodType.WORKER and grid:
+            # One process per chip: the process grid IS the host's chip
+            # grid, and the workers' runtimes find each other on
+            # loopback to form the one mesh jax.distributed then spans.
+            # The job must run as many workers as the host has chips.
+            env["TPU_PROCESS_BOUNDS"] = grid
+            env["TPU_PROCESS_ADDRESSES"] = ",".join(
+                f"localhost:{_TPU_PROCESS_BASE_PORT + c}" for c in chips
+            )
+            env["TPU_PROCESS_PORT"] = str(_TPU_PROCESS_BASE_PORT + chip)
+            env["CLOUD_TPU_TASK_ID"] = str(chips.index(chip))
+        else:
+            env["TPU_PROCESS_BOUNDS"] = "1,1,1"  # a replica stands alone
+        logger.info("Pod %s pinned to TPU chip %d", spec.name, chip)
+
     def create_pod(self, spec: PodSpec) -> None:
         import os
         import subprocess
 
         env = dict(os.environ)
         env.update(self._extra_env)
+        self._pin_chip(spec, env)
         with self._lock:
             self.pods[spec.name] = spec
             self.create_calls.append(spec)

@@ -9,9 +9,8 @@ per named program and per distinct aval signature:
 - compile wall seconds (``worker_program_compile_seconds{program}``
   histogram, injectable clock so tests replay deterministically);
 - compile / retrace counts and the distinct-signature count;
-- XLA's own cost model (``cost_analysis()`` flops + bytes accessed,
-  version-tolerant: dict on new jax, list-of-dict on old) — the same
-  numbers bench.py used to compute privately per run.
+- XLA's own cost model (``cost_analysis()`` flops + bytes accessed) —
+  the same numbers bench.py used to compute privately per run.
 
 Joining per-program cost against the step-rate telemetry the worker
 already publishes (``bind_step_rate``) turns the static ledger into
@@ -63,35 +62,38 @@ _COMPILE_SAMPLES_KEPT = 256
 _DIGEST_CHARS = 12
 
 
-def device_peaks() -> Optional[dict]:
-    """Datasheet peak numbers for MFU / bandwidth rooflines; None
-    off-TPU (the ratio gauges then read 0.0).  Shared with bench.py so
-    bench reports and live telemetry divide by the same roofline."""
-    try:
-        import jax
+# Datasheet peaks keyed by the exact `device_kind` string the chip
+# reports.  One row per kind that has actually been read off a device:
+#   "TPU v5 lite" — Google Cloud documentation, "TPU v5e": 197 TFLOP/s
+#   bf16, 819 GB/s HBM per chip.
+_DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+}
 
-        kind = getattr(jax.devices()[0], "device_kind", "cpu").lower()
-    except Exception:
+
+def device_peaks() -> Optional[dict]:
+    """Datasheet peak numbers for MFU / bandwidth rooflines, shared by
+    bench.py and live telemetry.  The CPU platform has no peaks (None:
+    the ratio gauges read 0.0); an accelerator whose `device_kind` is
+    not in the table is an error, never a guessed row."""
+    import jax
+
+    device = jax.devices()[0]
+    if device.platform == "cpu":
         return None
-    if "v5 lite" in kind or "v5e" in kind:
-        return {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
-    if "v5p" in kind or "v5" in kind:
-        return {"bf16_flops": 459e12, "hbm_bytes_per_s": 2765e9}
-    if "v4" in kind:
-        return {"bf16_flops": 275e12, "hbm_bytes_per_s": 1228e9}
-    return None
+    peaks = _DEVICE_PEAKS.get(device.device_kind)
+    if peaks is None:
+        raise ValueError(
+            f"no peaks row for device_kind {device.device_kind!r} "
+            f"(platform {device.platform!r}); add it to "
+            "programs._DEVICE_PEAKS with its source"
+        )
+    return peaks
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """flops / bytes-accessed from XLA's own cost model (version-
-    tolerant: dict on new jax, list-of-dict on old)."""
-    try:
-        analysis = compiled.cost_analysis()
-    except Exception:
-        return {}
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else {}
-    return dict(analysis or {})
+    """flops / bytes-accessed from XLA's own cost model."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def _flops_bytes(cost: dict) -> Tuple[float, float]:
@@ -355,7 +357,10 @@ class ProgramRegistry:
                 rate = 0.0
             flops_rate += cost["flops"] * rate / spe
             bytes_rate += cost["bytes"] * rate / spe
-        peaks = device_peaks()
+        # no rate-bound program means this process runs none (the
+        # master): asking for peaks would initialise a backend there and
+        # take the chips its workers need
+        peaks = device_peaks() if bound else None
         return {
             "flops_per_sec": flops_rate,
             "bytes_per_sec": bytes_rate,
@@ -509,7 +514,7 @@ class RegisteredProgram:
         return self._aot_for(args)
 
     def cost_for(self, *args) -> dict:
-        """Version-tolerant cost_analysis() dict for this signature,
+        """cost_analysis() dict for this signature,
         AOT-compiling (once, recorded) if no executable is cached —
         the bench path, and the source of the ledger's flops/bytes."""
         compiled = self._aot_for(args)

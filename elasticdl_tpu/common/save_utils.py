@@ -490,9 +490,12 @@ class CheckpointSaver:
         if step in self._tiered_meta:
             manifest["tiered"] = self._tiered_meta[step]
         path = self._manifest_path(step)
-        tmp = path + ".tmp"
         # temp file + os.replace: readers only ever see a complete
-        # manifest, even across a crash mid-write
+        # manifest, even across a crash mid-write.  The temp name is
+        # per-process: every SPMD rank writes the (identical) manifest
+        # of a step into this shared directory, and with one shared
+        # temp name the second rank's replace found it already moved.
+        tmp = f"{path}.{os.getpid()}.tmp"
         with open(tmp, "w") as f:
             json.dump(manifest, f)
             f.flush()

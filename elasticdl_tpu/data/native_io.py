@@ -3,7 +3,9 @@
 The shared library is built by `make -C native` (or scripts/build_native.sh)
 — attempted automatically once per process if g++ is available.  All
 callers degrade to the pure-Python implementation when the library is
-missing, so the native path is a pure accelerator, never a requirement.
+missing — an order of magnitude slower, so the fallback is logged once
+per process at ERROR and `available()` lets a caller that must not run
+degraded (chip_smoke.py) refuse.
 """
 
 from __future__ import annotations
@@ -15,6 +17,10 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
+from elasticdl_tpu.common.log_utils import get_logger
+
+logger = get_logger(__name__)
+
 _ROOT = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
@@ -22,6 +28,18 @@ _SO_PATH = os.path.join(_ROOT, "native", "build", "librecordio.so")
 
 _lib = None
 _build_attempted = False
+_fallback_logged = False
+
+
+def _log_fallback(reason: str) -> None:
+    global _fallback_logged
+    if not _fallback_logged:
+        _fallback_logged = True
+        logger.error(
+            "native record scanner unavailable (%s): falling back to the "
+            "pure-Python TFRecord path, which is far slower — run "
+            "scripts/build_native.sh to see the build error", reason,
+        )
 
 
 def _try_build() -> None:
@@ -56,8 +74,12 @@ def _try_build() -> None:
             finally:
                 if fcntl is not None:
                     fcntl.flock(lock, fcntl.LOCK_UN)
-    except (subprocess.SubprocessError, OSError):
-        pass
+    except (subprocess.SubprocessError, OSError) as exc:
+        stderr = getattr(exc, "stderr", b"") or b""
+        _log_fallback(
+            f"build failed: {exc!r} "
+            f"{stderr.decode(errors='replace')[-500:]}"
+        )
 
 
 def _stale() -> bool:
@@ -75,12 +97,14 @@ def _load():
     if not os.path.exists(_SO_PATH) or _stale():
         _try_build()
     if not os.path.exists(_SO_PATH):
+        _log_fallback(f"{_SO_PATH} was not built")
         return None
     try:
         lib = ctypes.CDLL(_SO_PATH)
-    except OSError:
+    except OSError as exc:
         # corrupt artifact (e.g. from an interrupted historical build):
         # degrade to the pure-Python path rather than crash the worker
+        _log_fallback(f"dlopen failed: {exc}")
         return None
     lib.recordio_build_index.restype = ctypes.c_int64
     lib.recordio_build_index.argtypes = [

@@ -25,23 +25,6 @@ from urllib.parse import parse_qsl, urlparse
 from elasticdl_tpu.data.reader.base import AbstractDataReader
 
 
-def grain_api():
-    """The module exposing Grain's user API (MapDataset etc.).
-
-    Newer grain wheels ship `grain` as a namespace package whose symbols
-    live in `grain.python`; older ones exposed them at top level.  Zoo
-    factories and tests import through this shim so either layout works
-    (the same compat pattern as common/jax_compat.py).
-    """
-    import grain
-
-    if hasattr(grain, "MapDataset"):
-        return grain
-    from grain import python as grain_python
-
-    return grain_python
-
-
 def _resolve(origin: str):
     if not origin.startswith("grain://"):
         origin = "grain://" + origin

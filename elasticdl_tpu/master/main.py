@@ -667,11 +667,6 @@ def main(argv=None, k8s_client=None, linger_s: float = 5.0) -> int:
     --use_fake_k8s an in-memory — Kubernetes client); tests may inject
     `k8s_client` directly."""
     args = args_lib.parse_master_args(argv)
-    from elasticdl_tpu.common.virtual_mesh import (
-        apply_compilation_cache_config,
-    )
-
-    apply_compilation_cache_config(args.compilation_cache_dir)
     if k8s_client is None and args.distribution_strategy != "Local":
         if args.use_process_k8s:
             from elasticdl_tpu.common.k8s_client import ProcessK8sClient

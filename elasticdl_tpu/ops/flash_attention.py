@@ -135,17 +135,11 @@ def _pallas_forward(q3, k3, v3, causal: bool, scale: float, block_q: int,
 
     # Outputs inherit the inputs' varying-axes type (vma): inside a
     # shard_map with the varying-axis audit on, an untyped out_shape is a
-    # ValueError — which round 4's blanket except silently converted into
-    # the O(L^2) fallback on every single-chip run (round-5 profile
-    # finding).  Older jax without vma typing skips the annotation.
+    # ValueError.
+    vma = frozenset().union(*(jax.typeof(x).vma for x in (q3, k3, v3)))
+
     def out_struct(shape, dtype):
-        try:
-            vma = frozenset().union(
-                *(jax.typeof(x).vma for x in (q3, k3, v3))
-            )
-            return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-        except (AttributeError, TypeError):
-            return jax.ShapeDtypeStruct(shape, dtype)
+        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
     return pl.pallas_call(
         kernel,
@@ -167,7 +161,10 @@ def _pallas_forward(q3, k3, v3, causal: bool, scale: float, block_q: int,
     )(q3, k3, v3)
 
 
-def _use_interpret() -> bool:
+def use_interpret() -> bool:
+    """Whether the kernel runs in the Pallas interpreter (any backend
+    but TPU) instead of being compiled by Mosaic.  The one predicate
+    every caller that treats the two differently dispatches on."""
     return jax.default_backend() != "tpu"
 
 
@@ -199,7 +196,7 @@ def _flash_fwd(q, k, v, causal, scale):
         q.reshape(batch, q_len, hd),
         k.reshape(batch, k_len, hd),
         v.reshape(batch, k_len, hd),
-        causal, scale, block_q, block_k, heads, dim, _use_interpret(),
+        causal, scale, block_q, block_k, heads, dim, use_interpret(),
     )
     out = out3.reshape(batch, q_len, heads, dim)
     return out, (q, k, v, out, lse)

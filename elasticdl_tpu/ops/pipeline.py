@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from elasticdl_tpu.common.jax_compat import pcast_to_varying, shard_map
 from elasticdl_tpu.parallel.mesh import DATA_AXIS, PIPE_AXIS
 
 
@@ -74,7 +73,7 @@ def _pipeline_local(
         apply_stage = jax.checkpoint(apply_stage)
 
     def varying(v):
-        return pcast_to_varying(v, (data_axis, pipe_axis))
+        return lax.pcast(v, (data_axis, pipe_axis), to="varying")
 
     mb_shape = micro.shape[1:]
     state0 = varying(jnp.zeros(mb_shape, x.dtype))
@@ -162,7 +161,7 @@ def gpipe_spmd(
         remat=remat,
     )
     param_spec = jax.tree.map(lambda _: P(pipe_axis), stacked_params)
-    return shard_map(
+    return jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(param_spec, P(data_axis)),

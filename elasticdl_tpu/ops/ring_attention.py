@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from elasticdl_tpu.common.jax_compat import pcast_to_varying, shard_map
 from elasticdl_tpu.parallel.mesh import DATA_AXIS, SEQ_AXIS
 
 _NEG_INF = -1e30
@@ -44,7 +43,7 @@ def _ring_attention_local(
     # pcast-to-varying marks them as shard-varying so the scan carry
     # types match the per-shard loop outputs.
     def _varying(x):
-        return pcast_to_varying(x, varying_axes)
+        return jax.lax.pcast(x, varying_axes, to="varying")
 
     m0 = _varying(jnp.full((batch, heads, q_len), _NEG_INF, jnp.float32))
     l0 = _varying(jnp.zeros((batch, heads, q_len), jnp.float32))
@@ -116,25 +115,27 @@ def ring_self_attention(
         # `except ValueError` here once swallowed a shard_map vma error
         # and silently downgraded every single-chip run (bench included)
         # to the O(L^2) path (round-5 on-chip profile finding).
-        # check_vma=False: the kernel types its outputs' vma from its
-        # inputs for real TPU lowering, but interpret mode (CPU tests)
-        # re-evaluates the kernel body where the block-slicing internals
-        # mix varying and invariant operands and fail the audit; the
-        # wrapper's in/out specs still pin the sharding contract.
+        # The vma audit is on when Mosaic compiles the kernel (it types
+        # its outputs' vma from its inputs) and off in interpret mode
+        # (CPU tests), which re-evaluates the kernel body where the
+        # block-slicing internals mix varying and invariant operands and
+        # fail the audit; the wrapper's in/out specs pin the sharding
+        # contract either way.
         from elasticdl_tpu.ops.flash_attention import (
+            use_interpret,
             flash_attention,
             flash_shapes_ok,
         )
 
         if flash_shapes_ok(q.shape, k.shape):
-            return shard_map(
+            return jax.shard_map(
                 functools.partial(
                     flash_attention, causal=causal, scale=scale
                 ),
                 mesh=mesh,
                 in_specs=(spec, spec, spec),
                 out_specs=spec,
-                check_vma=False,
+                check_vma=not use_interpret(),
             )(q, k, v)
     fn = functools.partial(
         _ring_attention_local,
@@ -144,7 +145,7 @@ def ring_self_attention(
         scale=scale,
         varying_axes=(data_axis, seq_axis),
     )
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec
     )(q, k, v)
 

@@ -1,46 +1,6 @@
-import os
-import subprocess
+"""Wire messages.  `elasticdl_pb2.py` and `serving_pb2.py` are checked in
+and are the artifacts every process imports; nothing is generated at
+import.  After editing a .proto, regenerate explicitly:
+`scripts/gen_elasticdl_pb2.sh` / `python scripts/gen_serving_pb2.py`."""
 
-_HERE = os.path.dirname(__file__)
-
-
-def _ensure_generated():
-    """Regenerate elasticdl_pb2.py from the .proto if missing or stale."""
-    proto = os.path.join(_HERE, "elasticdl.proto")
-    gen = os.path.join(_HERE, "elasticdl_pb2.py")
-    if not os.path.exists(gen) or os.path.getmtime(gen) < os.path.getmtime(proto):
-        subprocess.run(
-            ["protoc", f"--python_out={_HERE}", f"--proto_path={_HERE}", proto],
-            check=True,
-        )
-
-
-def _ensure_serving_generated():
-    """Regenerate serving_pb2.py if serving.proto changed.
-
-    serving_pb2.py is built by scripts/gen_serving_pb2.py (pure python —
-    no protoc needed) and checked in; best-effort here because the script
-    lives outside the installed package, and the checked-in module is
-    valid whenever the .proto hasn't been edited."""
-    proto = os.path.join(_HERE, "serving.proto")
-    gen = os.path.join(_HERE, "serving_pb2.py")
-    script = os.path.normpath(
-        os.path.join(_HERE, "..", "..", "scripts", "gen_serving_pb2.py")
-    )
-    if not os.path.exists(proto) or not os.path.exists(script):
-        return
-    if os.path.exists(gen) and (
-        os.path.getmtime(gen) >= os.path.getmtime(proto)
-    ):
-        return
-    import sys
-
-    # strict only when the generated module is missing outright; a stale
-    # regen failure still leaves a working (if outdated) checked-in module
-    subprocess.run([sys.executable, script], check=not os.path.exists(gen))
-
-
-_ensure_generated()
-_ensure_serving_generated()
-
-from elasticdl_tpu.proto import elasticdl_pb2  # noqa: E402,F401
+from elasticdl_tpu.proto import elasticdl_pb2  # noqa: F401

@@ -40,7 +40,6 @@ from elasticdl_tpu.common import metrics as metrics_lib
 from elasticdl_tpu.common import profiler as profiler_lib
 from elasticdl_tpu.common import programs as programs_lib
 from elasticdl_tpu.common import resilience
-from elasticdl_tpu.common.jax_compat import distributed_is_initialized
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.model_handler import ModelSpec, resolve_wire_format
 from elasticdl_tpu.parallel import mesh as mesh_lib
@@ -265,12 +264,21 @@ class SPMDWorker:
             # be saved by the watchdog restarting the process.
             self._watchdog_started = True
             threading.Thread(target=self._watchdog, daemon=True).start()
-        if self.num_processes > 1 and not distributed_is_initialized():
+        if self.num_processes > 1 and not jax.distributed.is_initialized():
             jax.distributed.initialize(
                 coordinator_address=self._coordinator,
                 num_processes=self.num_processes,
                 process_id=self.process_id,
                 initialization_timeout=self.INIT_TIMEOUT_S,
+            )
+        if jax.process_count() != self.num_processes:
+            # e.g. TPU children each pinned to a stand-alone chip: every
+            # rank would then train its own diverging replica
+            raise RuntimeError(
+                f"rank {self.process_id}: the {jax.default_backend()} "
+                f"backend spans {jax.process_count()} process(es) but the "
+                f"rendezvous world has {self.num_processes}; the ranks "
+                "would not share one mesh"
             )
         if self._saver is None and self._saver_factory is not None:
             self._saver = self._saver_factory()
@@ -905,7 +913,7 @@ class SPMDWorker:
         # that confirmed the new epoch and THEN exited would release the
         # barrier for fresh joiners, who would initialize a world whose
         # members are already gone and wedge until their watchdogs fire.
-        if distributed_is_initialized() or self.num_processes > 1:
+        if jax.distributed.is_initialized() or self.num_processes > 1:
             self._restart_for_topology_change()
         self._recovery_t0 = time.time()
         # Peek (no confirmation) at the new spec: a single-process worker

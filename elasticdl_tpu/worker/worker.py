@@ -195,7 +195,7 @@ class Worker:
         self.compact_wire = self.wire_format == "compact"
         # >1 dispatches that many train steps as ONE jitted lax.scan
         # program (Trainer.train_on_batch_stack) — amortizes per-dispatch
-        # overhead, which dominates on remote/tunneled TPU runtimes.
+        # overhead.
         self.steps_per_execution = max(1, int(steps_per_execution))
         self._client = master_client
         self._data_service = TaskDataService(

@@ -70,6 +70,7 @@ class LocalSelfAttention(nn.Module):
     @nn.compact
     def __call__(self, x):
         from elasticdl_tpu.ops.flash_attention import (
+            use_interpret,
             flash_attention,
             flash_shapes_ok,
         )
@@ -83,16 +84,12 @@ class LocalSelfAttention(nn.Module):
         q, k, v = q.reshape(shape), k.reshape(shape), v.reshape(shape)
         # Explicit tile-shape dispatch — a try/except here once swallowed
         # an unrelated shard_map typing error and silently took the
-        # O(L^2) path (round-5 profile finding).  TPU-backend only: this
-        # runs INSIDE the pipeline's vma-audited shard_map, where the
-        # CPU interpreter's block-slicing internals fail the audit; the
+        # O(L^2) path (round-5 profile finding).  Compiled kernel only:
+        # this runs INSIDE the pipeline's vma-audited shard_map, where
+        # the interpreter's block-slicing internals fail the audit; the
         # reference path is the same math, and the kernel itself is
         # covered by tests/test_flash_attention.py in interpret mode.
-        import jax
-
-        if jax.default_backend() == "tpu" and flash_shapes_ok(
-            q.shape, k.shape
-        ):
+        if not use_interpret() and flash_shapes_ok(q.shape, k.shape):
             out = flash_attention(q, k, v, causal=False)
         else:
             out = full_attention_reference(q, k, v, causal=False)

@@ -31,9 +31,8 @@ def grain_dataset(n: int = 2048, seed: int = 0):
     random-access Grain MapDataset serving the same 785-byte records the
     TFRecord pipeline does — submit with
     --training_data 'grain://mnist.data:grain_dataset?n=2048'."""
-    from elasticdl_tpu.data.reader.grain_reader import grain_api
+    import grain
 
-    grain = grain_api()
     images, labels = synthetic_mnist(n, seed)
     return grain.MapDataset.source(
         [

@@ -32,10 +32,10 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _ROOT)
 
 from elasticdl_tpu.common.virtual_mesh import (  # noqa: E402
-    enable_persistent_compile_cache,
+    enable_compile_cache,
 )
 
-enable_persistent_compile_cache()
+enable_compile_cache()
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402

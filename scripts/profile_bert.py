@@ -38,11 +38,9 @@ def capture(batch_size: int, seq_len: int, steps: int, out_dir: str,
     import jax
     import numpy as np
 
-    from elasticdl_tpu.common.virtual_mesh import (
-        enable_persistent_compile_cache,
-    )
+    from elasticdl_tpu.common.virtual_mesh import enable_compile_cache
 
-    enable_persistent_compile_cache()
+    enable_compile_cache()
     sys.path.insert(0, os.path.join(_ROOT, "model_zoo"))
     from bench import _trainer_for
     from elasticdl_tpu.parallel import mesh as mesh_lib

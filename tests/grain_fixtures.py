@@ -2,9 +2,8 @@
 
 
 def dict_dataset(n: int = 8):
-    from elasticdl_tpu.data.reader.grain_reader import grain_api
+    import grain
 
-    grain = grain_api()
     return grain.MapDataset.range(n).map(
         lambda i: {"image": [i] * 4, "label": i % 2}
     )

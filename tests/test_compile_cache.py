@@ -1,16 +1,60 @@
-"""--compilation_cache_dir: persistent XLA-executable cache plumbing.
+"""The one compile-cache rule (common/virtual_mesh.compile_cache_dir) and
+the --compilation_cache_dir plumbing.
 
-A relaunched worker that finds the train-step executable on the shared
-cache volume skips the ~20-40s recompile — the dominant chunk of elastic
-recovery time (SURVEY.md hard part 1's AOT mitigation)."""
+A relaunched worker that finds the train-step executable in the cache
+skips the recompile — the dominant chunk of elastic recovery time
+(SURVEY.md hard part 1's AOT mitigation)."""
 
 import os
 
 import jax
+import pytest
 
 from elasticdl_tpu.common import args as args_lib
-from elasticdl_tpu.common.virtual_mesh import apply_compilation_cache_config
+from elasticdl_tpu.common.virtual_mesh import (
+    compile_cache_dir,
+    enable_compile_cache,
+)
 from elasticdl_tpu.master.main import Master
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def restore_cache_config():
+    prev = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_env_var_wins_over_flag_and_default(monkeypatch, restore_cache_config):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/x")
+    assert compile_cache_dir() == "/x"
+    assert compile_cache_dir("/y") == "/x"
+    assert enable_compile_cache("/y") == "/x"
+    assert jax.config.jax_compilation_cache_dir == "/x"
+    # no code path rewrites what the machine's owner set
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == "/x"
+
+
+def test_flag_applies_when_env_unset(monkeypatch, restore_cache_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert enable_compile_cache("/y") == "/y"
+    assert jax.config.jax_compilation_cache_dir == "/y"
+    assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
+
+
+def test_default_is_fixed_path_inside_checkout(
+    monkeypatch, restore_cache_config
+):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    resolved = enable_compile_cache()
+    # the directory is part of what makes a later process hit: what the
+    # rule adds to the checkout is a constant — no temp dir, user, pid or
+    # time
+    assert resolved == os.path.join(REPO, ".jax_cache")
+    assert jax.config.jax_compilation_cache_dir == resolved
+    assert resolved == compile_cache_dir()
 
 
 def test_flag_reaches_worker_pod_command(tmp_path):
@@ -35,23 +79,6 @@ def test_flag_reaches_worker_pod_command(tmp_path):
     assert "--compilation_cache_dir" in joined and cache in joined
 
 
-def test_flag_overrides_env_and_applies_to_jax_config(tmp_path):
-    prev_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    prev_cfg = jax.config.jax_compilation_cache_dir
-    explicit = str(tmp_path / "explicit")
-    try:
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "ambient")
-        apply_compilation_cache_config(explicit)
-        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == explicit
-        assert jax.config.jax_compilation_cache_dir == explicit
-    finally:
-        if prev_env is None:
-            os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
-        else:
-            os.environ["JAX_COMPILATION_CACHE_DIR"] = prev_env
-        jax.config.update("jax_compilation_cache_dir", prev_cfg)
-
-
 def test_relaunched_process_reuses_cached_executable(tmp_path):
     """Two fresh OS processes compile the same jitted step against the
     same cache dir; the second must hit the cache (observable via jax's
@@ -64,8 +91,8 @@ def test_relaunched_process_reuses_cached_executable(tmp_path):
 import sys
 sys.path.insert(0, {root!r})
 import jax; jax.config.update("jax_platforms", "cpu")
-from elasticdl_tpu.common.virtual_mesh import apply_compilation_cache_config
-apply_compilation_cache_config({cache!r})
+from elasticdl_tpu.common.virtual_mesh import enable_compile_cache
+assert enable_compile_cache({cache!r}) == {cache!r}
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 import jax.numpy as jnp
 from jax._src import monitoring

@@ -177,6 +177,39 @@ def test_forensics_is_clock_free():
     assert rec["compiles"] == 1
 
 
+def test_device_peaks_is_an_exact_table(monkeypatch):
+    """CPU: explicitly no peaks.  A known device_kind: its row.  An
+    accelerator the table has never seen: an error, not a neighbour's
+    row and not a silent None that drops MFU."""
+    from types import SimpleNamespace
+
+    assert programs.device_peaks() is None  # the suite runs on CPU
+
+    def fake(kind):
+        device = SimpleNamespace(platform="tpu", device_kind=kind)
+        monkeypatch.setattr(jax, "devices", lambda: [device])
+
+    fake("TPU v5 lite")
+    assert programs.device_peaks()["bf16_flops"] == 197e12
+    fake("TPU v5")
+    with pytest.raises(ValueError, match="no peaks row.*'TPU v5'"):
+        programs.device_peaks()
+
+
+def test_live_gauges_do_not_touch_a_backend_without_bound_programs(
+    monkeypatch,
+):
+    """The master scrapes the same gauges but runs no program: reading
+    them must not call jax.devices() (on a TPU host that would take the
+    chips its worker children need)."""
+    def boom():
+        raise AssertionError("live() initialised a backend")
+
+    monkeypatch.setattr(jax, "devices", boom)
+    reg = programs.ProgramRegistry(metrics=metrics_lib.MetricsRegistry())
+    assert reg.live()["mfu"] == 0.0
+
+
 def test_default_registry_is_a_process_singleton():
     assert (
         programs.default_program_registry()
