@@ -1,56 +1,37 @@
 """Profiling/tracing utilities.
 
 The reference had only coarse log-line timing (SURVEY.md §5); here the
-baseline is step timing with device synchronisation plus one-call access to
-the JAX profiler (Perfetto/XPlane traces TensorBoard can read).
+worker's timed regions are phases with totals AND spans on one clock
+(`PhaseTimer`, one a process), the step rate comes from the loop's
+per-task synchronised stamp (`SyncedStepRate`), and the JAX profiler is
+one call away (Perfetto/XPlane traces TensorBoard can read, with the
+same regions on the host plane as `edl:<phase>`).
 """
 
 from __future__ import annotations
 
 import contextlib
+import threading
 import time
 from collections import deque
-from typing import Optional
+from typing import NamedTuple, Optional
 
 from elasticdl_tpu.common.log_utils import get_logger
 
 logger = get_logger(__name__)
 
 
-class StepTimer:
-    """Rolling step-rate meter.  `tick()` after each train step; reads are
-    O(1).  Use `synchronize=True` at measurement boundaries only (it calls
-    block_until_ready, which would serialize the pipeline every step)."""
-
-    def __init__(self, window: int = 100):
-        self._times = deque(maxlen=window)
-        self._last: Optional[float] = None
-
-    def tick(self, result=None, synchronize: bool = False):
-        if synchronize and result is not None:
-            import jax
-
-            jax.block_until_ready(result)
-        now = time.perf_counter()
-        if self._last is not None:
-            self._times.append(now - self._last)
-        self._last = now
-
-    @property
-    def steps_per_sec(self) -> float:
-        if not self._times:
-            return 0.0
-        return len(self._times) / sum(self._times)
-
-    def log(self, prefix: str = ""):
-        logger.info("%ssteps/sec=%.2f", prefix, self.steps_per_sec)
-
-
 #: The step-phase vocabulary (docs/OBSERVABILITY.md "Phase catalogue").
 #: Every phase a worker attributes step time to; the labeled
 #: `worker_step_phase_seconds{phase=...}` histogram uses exactly these.
-STEP_PHASES = (
-    "data_wait", "pack", "h2d_stage", "compute", "report",
+#: LOOP_PHASES tile the worker loop's own thread from one task end to the
+#: next; PRODUCER_PHASES run on the prefetch producer thread and overlap
+#: them.
+LOOP_PHASES = (
+    "get_task", "data_wait", "h2d_stage", "compute", "task_sync", "report",
+)
+PRODUCER_PHASES = ("read", "pack", "queue_full")
+STEP_PHASES = LOOP_PHASES + PRODUCER_PHASES + (
     # tiered embedding store (elasticdl_tpu/store): host-tier gathers for
     # cold rows — on the prefetcher thread when overlapped, on the
     # consumer when a deferred row forces a synchronous gather.  Its
@@ -59,56 +40,183 @@ STEP_PHASES = (
     "cold_gather",
 )
 
+#: Records the span ring keeps before the oldest fall out.  A train step
+#: makes about five (measured: 4.6 in the benchmark's DeepFM cell), so
+#: this is some 7,000 steps back.
+SPAN_RING_RECORDS = 32768
+
+
+class Span(NamedTuple):
+    """One timed region as the ring keeps it.  `start` / `end` are
+    `time.perf_counter()` seconds, `thread` the native thread id (the
+    profiler's host lines carry the same), `step` the index of the batch
+    in its task, `parent` the name of the enclosing span on that thread,
+    `attrs` what the call site added (e.g. data_wait's queue `depth`)."""
+
+    name: str
+    start: float
+    end: float
+    thread: int
+    task_id: Optional[int]
+    step: Optional[int]
+    parent: Optional[str]
+    attrs: Optional[dict]
+
+
+_KEEP = object()          # mark(): "leave this field as it is"
+
+
+class _ThreadMarks:
+    """What a PhaseTimer keeps for each thread that records into it."""
+
+    __slots__ = ("native_id", "open", "task_id", "step")
+
+    def __init__(self):
+        self.native_id = threading.get_native_id()
+        self.open = []        # names of the spans open on this thread
+        self.task_id = self.step = None
+
+
+class _OpenSpan:
+    """The context manager `PhaseTimer.phase()` returns.  `task_id` and
+    `step` start as the thread's marks and may be set until the region
+    closes; `end` is readable afterwards (a synchronised stamp when the
+    region ended in a device fetch)."""
+
+    __slots__ = ("_timer", "_marks", "_annotation", "name", "start",
+                 "end", "task_id", "step", "parent", "attrs")
+
+    def __init__(self, timer, name, attrs):
+        self._timer = timer
+        self.name = name
+        self.attrs = attrs or None
+        self.end = None
+
+    def __enter__(self):
+        marks = self._marks = self._timer._thread_marks()
+        self.task_id, self.step = marks.task_id, marks.step
+        self.parent = marks.open[-1] if marks.open else None
+        marks.open.append(self.name)
+        # with no profiler session on this is a flag check; with one on,
+        # the region lies on the trace's host plane, on the device's clock
+        self._annotation = self._timer._annotate("edl:" + self.name)
+        self._annotation.__enter__()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.end = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        self._marks.open.pop()
+        self._timer._record(Span(
+            self.name, self.start, self.end, self._marks.native_id,
+            self.task_id, self.step, self.parent, self.attrs,
+        ))
+        return False
+
 
 class PhaseTimer:
-    """Attributes each train step's wall time to named phases.
+    """Attributes each train step's wall time to named phases, and keeps
+    each timed region as a span.
 
     The worker loop wraps each region in `with timer.phase("compute"):`
-    (or calls `add(name, seconds)` for regions timed elsewhere, e.g. on
-    the prefetch producer thread) and calls `step_done()` once per
-    executed step.  Per-phase seconds feed a labeled registry histogram
-    when one is supplied, cumulative totals back the telemetry payload,
-    and every `flush_every` steps the accumulated breakdown is emitted as
-    ONE `step_phases` span event so the attribution survives into the
-    cross-process event log (common/events.py) without a per-step write.
+    (or calls `add(name, seconds, start)` for regions timed elsewhere,
+    e.g. the tiered store's gather thread) and calls `step_done()` once
+    per executed step.  Per-phase seconds feed a labeled registry
+    histogram when one is supplied, cumulative totals back the telemetry
+    payload, and every `flush_every` steps the accumulated breakdown is
+    emitted as ONE `step_phases` span event so the attribution survives
+    into the cross-process event log (common/events.py) without a
+    per-step write.
 
-    Thread-safe: `add()` may be called from the prefetch producer thread
-    while the consumer loop runs `phase()`/`step_done()`.
+    Every region is also one `Span` in a bounded in-memory ring
+    (`spans()`), and `phase()` opens `jax.profiler.TraceAnnotation(
+    "edl:<name>")` around it, so under a profiler session the same
+    regions lie on the `.xplane.pb` host plane.  `mark()` sets the task
+    and step the calling thread's next spans belong to.  Nothing is
+    written anywhere on the hot path.
+
+    Thread-safe: the prefetch producer thread times `read` / `pack` /
+    `queue_full` while the consumer loop runs `phase()`/`step_done()`.
     """
 
     def __init__(self, phases=STEP_PHASES, histogram=None,
-                 flush_every: int = 50):
-        import threading
+                 flush_every: int = 50, ring: int = SPAN_RING_RECORDS):
+        # here, not at import: the master reads this module's vocabulary
+        # and never touches jax
+        from jax.profiler import TraceAnnotation
 
+        self._annotate = TraceAnnotation
         self.phases = tuple(phases)
         self._histogram = histogram   # labeled _HistogramFamily or None
         self._flush_every = max(1, int(flush_every))
         self._lock = threading.Lock()
+        self._local = threading.local()   # .marks: this thread's own
+        self._ring = deque(maxlen=int(ring))
         self._totals = {p: 0.0 for p in self.phases}      # job lifetime
         self._pending = {p: 0.0 for p in self.phases}     # since last flush
         self._steps = 0
         self._pending_steps = 0
 
-    @contextlib.contextmanager
-    def phase(self, name: str):
-        start = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - start)
+    def mark(self, task_id=_KEEP, step=_KEEP) -> None:
+        """The task and the step (index of the batch in its task) that
+        this thread's following spans belong to."""
+        marks = self._thread_marks()
+        if task_id is not _KEEP:
+            marks.task_id = task_id
+        if step is not _KEEP:
+            marks.step = step
 
-    def add(self, name: str, seconds: float) -> None:
+    def marks(self) -> tuple:
+        """(task_id, step) as marked on this thread: what a thread hands
+        to one it starts."""
+        marks = self._thread_marks()
+        return marks.task_id, marks.step
+
+    def _thread_marks(self) -> _ThreadMarks:
+        try:
+            return self._local.marks
+        except AttributeError:
+            marks = self._local.marks = _ThreadMarks()
+            return marks
+
+    def phase(self, name: str, **attrs) -> _OpenSpan:
+        return _OpenSpan(self, name, attrs)
+
+    def add(self, name: str, seconds: float,
+            start: Optional[float] = None) -> None:
+        """A region timed by the caller: `seconds` long, begun at `start`
+        on `time.perf_counter()` (default: ended now)."""
+        seconds = max(0.0, float(seconds))
+        if start is None:
+            start = time.perf_counter() - seconds
+        marks = self._thread_marks()
+        self._record(Span(
+            name, start, start + seconds, marks.native_id,
+            marks.task_id, marks.step,
+            marks.open[-1] if marks.open else None, None,
+        ))
+
+    def _record(self, span: Span) -> None:
+        name = span.name
         if name not in self._totals:
             return  # unknown phase: attribution must never raise
-        seconds = max(0.0, float(seconds))
+        seconds = span.end - span.start
         with self._lock:
             self._totals[name] += seconds
             self._pending[name] += seconds
+            self._ring.append(span)
         if self._histogram is not None:
             try:
                 self._histogram.labels(phase=name).record(seconds)
             except Exception:
                 pass
+
+    def spans(self) -> list:
+        """The ring's records, oldest first (in the order regions ENDED;
+        at most `ring` of them)."""
+        with self._lock:
+            return list(self._ring)
 
     def step_done(self) -> None:
         """Count one executed step; flush a `step_phases` span event at
@@ -118,16 +226,7 @@ class PhaseTimer:
             self._pending_steps += 1
             if self._pending_steps < self._flush_every:
                 return
-            payload = {
-                p: round(v, 6) for p, v in self._pending.items()
-            }
-            steps = self._pending_steps
-            for p in self._pending:
-                self._pending[p] = 0.0
-            self._pending_steps = 0
-        from elasticdl_tpu.common import events
-
-        events.emit(events.STEP_PHASES, phases=payload, steps=steps)
+        self.flush()
 
     def flush(self) -> None:
         """Force out whatever accumulated since the last flush (end of a
@@ -175,6 +274,55 @@ class PhaseTimer:
             return {
                 p: int(round(v * 1000.0)) for p, v in self._totals.items()
             }
+
+
+_process_timer: Optional[PhaseTimer] = None
+_process_timer_lock = threading.Lock()
+
+
+def process_phase_timer() -> PhaseTimer:
+    """The process's one PhaseTimer, feeding the labeled
+    `worker_step_phase_seconds` histogram of the default registry.  Both
+    worker loops (threaded and SPMD) and the tiered store record into it:
+    cluster mode runs one worker or rank a process, so per-process totals
+    are per-worker totals."""
+    global _process_timer
+    with _process_timer_lock:
+        if _process_timer is None:
+            # here, not at import: common/metrics imports this module
+            from elasticdl_tpu.common import metrics as metrics_lib
+
+            histogram = metrics_lib.default_registry().histogram(
+                "worker_step_phase_seconds",
+                "per-step wall time attributed to a phase "
+                "(profiler.STEP_PHASES)",
+                labelnames=("phase",),
+            )
+            # Zero-initialize every catalogued phase so /metrics always
+            # exposes the full vocabulary — phases a given run never
+            # exercises (cold_gather is tiered-store-only) render with
+            # count 0 instead of disappearing.
+            for phase in STEP_PHASES:
+                histogram.labels(phase=phase)
+            _process_timer = PhaseTimer(histogram=histogram)
+        return _process_timer
+
+
+class SyncedStepRate:
+    """Steps a second between synchronised stamps: the steps of a task
+    over the time from the previous `task_sync` span's end to this one's.
+    The loop fetches the task's last loss there, so at both stamps the
+    device has finished what the host has counted, and nothing is
+    synchronised that was not already."""
+
+    def __init__(self):
+        self._last_end: Optional[float] = None
+        self.steps_per_sec = 0.0
+
+    def task_synced(self, end: float, steps: int) -> None:
+        if self._last_end is not None and end > self._last_end and steps:
+            self.steps_per_sec = steps / (end - self._last_end)
+        self._last_end = end
 
 
 class LatencyHistogram:

@@ -24,7 +24,8 @@ Per tick, in priority order, at most ONE action:
    `stream_lag_ticks` consecutive ticks (reason `stream_lag`): the
    trainer fleet is falling behind live ingest.
 3. **Scale down** (whole groups, straggler-preferring victims) when the
-   fleet-wide `data_wait` phase share — the fraction of worker step time
+   fleet-wide `data_wait` phase share — the fraction of the worker
+   loops' time (`profiler.LOOP_PHASES`, which tile the loop thread)
    spent blocked on the input pipeline, computed as a windowed delta of
    the cumulative phase clocks between ticks — has exceeded
    `data_wait_share` for `data_wait_ticks` consecutive ticks and the
@@ -57,6 +58,7 @@ from typing import Callable, Dict, List, Optional
 from elasticdl_tpu.common import events, faults
 from elasticdl_tpu.common import metrics as metrics_lib
 from elasticdl_tpu.common.log_utils import get_logger
+from elasticdl_tpu.common.profiler import LOOP_PHASES
 
 logger = get_logger(__name__)
 
@@ -112,6 +114,9 @@ class PolicyConfig:
             stream_lag_s=getattr(args, "stream_lag_s", 0.0),
             stream_lag_ticks=getattr(args, "stream_lag_ticks", 3),
         )
+
+
+_LOOP_PHASE_KEYS = frozenset(f"phase_{p}_ms" for p in LOOP_PHASES)
 
 
 class PolicyEngine:
@@ -310,10 +315,13 @@ class PolicyEngine:
         else:
             self._backlog_streak = 0
 
+        # data_wait's share of the worker LOOP's time: the producer
+        # thread's phases (read, pack, queue_full) overlap the loop and
+        # would count that time twice
         wait_ms = total_ms = 0.0
         for entry in self._telemetry_fn().values():
             for key, value in entry.items():
-                if not key.startswith("phase_") or not key.endswith("_ms"):
+                if key not in _LOOP_PHASE_KEYS:
                     continue
                 try:
                     value = float(value)

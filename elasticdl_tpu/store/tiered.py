@@ -225,7 +225,7 @@ class TieredStore:
                 dt = time.perf_counter() - t0
                 self._gather_hist.record(dt)
                 if self.phase_timer is not None:
-                    self.phase_timer.add("cold_gather", dt)
+                    self.phase_timer.add("cold_gather", dt, start=t0)
                 self.gather_async_s += dt
                 self.prefetch_ticks += 1
             except Exception:
@@ -407,7 +407,7 @@ class TieredStore:
                 dt = time.perf_counter() - t0
                 self._gather_hist.record(dt)
                 if self.phase_timer is not None:
-                    self.phase_timer.add("cold_gather", dt)
+                    self.phase_timer.add("cold_gather", dt, start=t0)
                 self.gather_sync_s += dt
                 full = {}
                 for name, dim in self.planes.items():

@@ -36,7 +36,6 @@ from typing import Optional
 import jax
 import numpy as np
 
-from elasticdl_tpu.common import metrics as metrics_lib
 from elasticdl_tpu.common import profiler as profiler_lib
 from elasticdl_tpu.common import programs as programs_lib
 from elasticdl_tpu.common import resilience
@@ -49,18 +48,11 @@ from elasticdl_tpu.worker.trainer import Trainer
 
 logger = get_logger(__name__)
 
-# Step-phase attribution: shares the labeled histogram FAMILY with the
-# threaded worker (default_registry get-or-create), but keeps its own
-# timer — SPMD cluster mode runs one rank per process, so per-process
-# totals are per-rank totals.  Module-level for __new__ scaffolding.
-_phase_timer = profiler_lib.PhaseTimer(
-    histogram=metrics_lib.default_registry().histogram(
-        "worker_step_phase_seconds",
-        "per-step wall time attributed to a phase "
-        "(profiler.STEP_PHASES)",
-        labelnames=("phase",),
-    )
-)
+# Step-phase attribution and spans: the process's one PhaseTimer, the
+# threaded worker's too — SPMD cluster mode runs one rank per process, so
+# per-process totals are per-rank totals.  Module-level for __new__
+# scaffolding.
+_phase_timer = profiler_lib.process_phase_timer()
 
 
 def wait_for_confirmed_epoch(
@@ -226,16 +218,15 @@ class SPMDWorker:
         self._in_rendezvous_wait = False
         # Leader-only observability: ONE rank writes scalars (every rank
         # holds identical state/loss by construction).
-        from elasticdl_tpu.common.profiler import StepTimer
         from elasticdl_tpu.common.summary import SummaryWriter
 
-        self.step_timer = StepTimer()
+        self.step_rate = profiler_lib.SyncedStepRate()
         # cost x rate join for the live MFU/bandwidth gauges — each rank
         # binds its own process's registry (per-process /metrics)
         programs_lib.default_program_registry().bind_step_rate(
             "worker_train_step_many"
             if self.steps_per_execution > 1 else "worker_train_step",
-            lambda: self.step_timer.steps_per_sec,
+            lambda: self.step_rate.steps_per_sec,
             steps_per_execution=self.steps_per_execution,
         )
         self._summary = SummaryWriter(
@@ -452,23 +443,27 @@ class SPMDWorker:
             # infinite loop; exhaustion raises RetryBudgetExhausted,
             # which worker/main.py maps to exit code 45 so the pod
             # manager relaunches us (charged against the budget).
-            resp = self._rpc_policy.call(
-                lambda: self._client.get_spmd_task(
-                    pb.GetSpmdTaskRequest(
-                        worker_id=self.worker_id,
-                        rendezvous_id=self._epoch,
-                        seq=seq,
-                    )
-                ),
-                description="get_spmd_task",
-            )
+            with _phase_timer.phase("get_task"):
+                resp = self._rpc_policy.call(
+                    lambda: self._client.get_spmd_task(
+                        pb.GetSpmdTaskRequest(
+                            worker_id=self.worker_id,
+                            rendezvous_id=self._epoch,
+                            seq=seq,
+                        )
+                    ),
+                    description="get_spmd_task",
+                )
             if resp.job_finished:
                 logger.info(
                     "Job finished; SPMD rank %d exiting", self.process_id
                 )
                 self._flush_predictions()
-                if self.is_leader and self.step_timer.steps_per_sec:
-                    self.step_timer.log(f"rank {self.process_id}: ")
+                if self.is_leader and self.step_rate.steps_per_sec:
+                    logger.info(
+                        "rank %d: steps/sec=%.2f",
+                        self.process_id, self.step_rate.steps_per_sec,
+                    )
                 self._summary.close()
                 from elasticdl_tpu.worker.worker import invoke_callbacks
 
@@ -485,7 +480,8 @@ class SPMDWorker:
                 continue
             task = resp.task
             if task.task_id < 0 or task.type == pb.WAIT:
-                time.sleep(self._wait_sleep_s)
+                with _phase_timer.phase("get_task"):   # the lease wait
+                    time.sleep(self._wait_sleep_s)
                 continue
             self._process_task(task)
             seq += 1
@@ -496,6 +492,7 @@ class SPMDWorker:
         # epoch-bump path, not a task retry.
         from elasticdl_tpu.worker.worker import invoke_callbacks
 
+        _phase_timer.mark(task_id=task.task_id, step=None)
         invoke_callbacks(self.spec.callbacks, "on_task_start", task)
         records = 0
         if task.type == pb.TRAINING:
@@ -573,7 +570,7 @@ class SPMDWorker:
         `elasticdl top` render both worker kinds identically."""
         payload = {
             "steps_per_sec_milli": int(
-                self.step_timer.steps_per_sec * 1000
+                self.step_rate.steps_per_sec * 1000
             ),
             "model_step": (
                 int(self.state.step) if self.state is not None else 0
@@ -598,6 +595,11 @@ class SPMDWorker:
 
     def _train_task_inner(self, task: pb.Task) -> int:
         records = 0
+        steps_before = _phase_timer.steps
+
+        def steps_done() -> int:   # of this task: one rank a process
+            return _phase_timer.steps - steps_before
+
         # Slice-local reads (SURVEY §3.3 per-worker disjoint reads): each
         # rank reads only its addressable rows of every full global batch
         # — aggregate host IO is O(shard), not O(world_size * shard).
@@ -647,6 +649,7 @@ class SPMDWorker:
                 return mesh_lib.make_global_batch(one_batch, self.mesh)
 
         def single_step(one_batch, one_is_local, gb=None):
+            _phase_timer.mark(step=steps_done())
             if gb is None:
                 gb = make_gb(one_batch, one_is_local)
             self.state, loss = self.trainer.train_on_global_batch(
@@ -654,7 +657,6 @@ class SPMDWorker:
             )
             self.last_loss = loss
             mark_recovered()
-            self.step_timer.tick()
             _phase_timer.step_done()
             self._maybe_checkpoint()
 
@@ -702,6 +704,7 @@ class SPMDWorker:
             ):
                 pending.append(batch)
                 if len(pending) == self.steps_per_execution:
+                    _phase_timer.mark(step=steps_done())
                     with _phase_timer.phase("h2d_stage"):
                         stack = (
                             mesh_lib.make_global_batch_stack_from_local(
@@ -718,7 +721,6 @@ class SPMDWorker:
                     self.last_loss = losses[-1]
                     mark_recovered()
                     for _ in range(self.steps_per_execution):
-                        self.step_timer.tick()
                         _phase_timer.step_done()
                     self._maybe_checkpoint(
                         stride=self.steps_per_execution
@@ -732,12 +734,17 @@ class SPMDWorker:
             single_step(batch, is_local, gb=gb)
         for batch in pending:  # task tail: single-step program
             single_step(batch, True)
+        _phase_timer.mark(step=None)
         _phase_timer.flush()
         if self.last_loss is not None:
+            # the loop's one synchronised stamp a task (see Worker)
+            with _phase_timer.phase("task_sync") as sync:
+                loss_value = float(np.asarray(self.last_loss))
+            self.step_rate.task_synced(sync.end, steps_done())
             self._summary.scalars(
                 {
-                    "train/loss": float(np.asarray(self.last_loss)),
-                    "train/steps_per_sec": self.step_timer.steps_per_sec,
+                    "train/loss": loss_value,
+                    "train/steps_per_sec": self.step_rate.steps_per_sec,
                 },
                 step=int(self.state.step),
             )

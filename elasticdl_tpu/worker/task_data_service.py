@@ -11,6 +11,8 @@ with the true record count carried alongside for metrics.
 
 from __future__ import annotations
 
+import contextlib
+import itertools
 import time
 from typing import Callable, Iterator, Optional, Tuple
 
@@ -39,6 +41,12 @@ def _retryable(exc: BaseException) -> bool:
     return _is_rpc_error(exc) or isinstance(exc, InjectedFault)
 
 
+def _phase(timer, name: str):
+    """`timer.phase(name)`; where no timer is set, nothing to enter (and
+    `as` gives None)."""
+    return contextlib.nullcontext() if timer is None else timer.phase(name)
+
+
 def prefetch_batches(iterator, depth: int = 2, device_stage=None,
                      device_depth: int = 1, phase_timer=None):
     """Run a host-side batch iterator (reader IO + feed parsing) in a
@@ -63,10 +71,15 @@ def prefetch_batches(iterator, depth: int = 2, device_stage=None,
     order, never ahead of earlier un-yielded batches).  Abandoning the
     generator (break / task failure) unblocks and stops the producer.
 
-    `phase_timer` (common/profiler.PhaseTimer), when given, attributes
-    the consumer's BLOCKED time on the queue to the `data_wait` phase —
-    the signal that says "the input pipeline, not the device, is the
-    bottleneck"."""
+    `phase_timer` (common/profiler.PhaseTimer), when given, times both
+    ends of the queue, each region with the step (index of the batch in
+    the task) it belongs to.  The consumer's BLOCKED time on the queue is
+    `data_wait` — the signal that says "the input pipeline, not the
+    device, is the bottleneck" — with the queue's depth as the `get`
+    found it; the producer's blocked `put` is `queue_full`, the opposite
+    signal: the pipeline is ahead and the device paces the job.  The
+    iterator's own regions (`read`, `pack`) run on the producer thread
+    under the task the caller's thread is marked with."""
     import queue
     import threading
 
@@ -74,45 +87,65 @@ def prefetch_batches(iterator, depth: int = 2, device_stage=None,
     sentinel = object()
     stop = threading.Event()
     error = []
+    task_id = None if phase_timer is None else phase_timer.marks()[0]
+
+    def put(item) -> bool:
+        """False when the consumer went away before there was room."""
+        try:
+            q.put_nowait(item)
+            return True
+        except queue.Full:
+            pass
+        with _phase(phase_timer, "queue_full"):
+            while not stop.is_set():
+                try:
+                    q.put(item, timeout=0.5)
+                    return True
+                except queue.Full:
+                    continue
+        return False
 
     def produce():
         try:
-            for item in iterator:
-                while not stop.is_set():
-                    try:
-                        q.put(item, timeout=0.5)
-                        break
-                    except queue.Full:
-                        continue
-                if stop.is_set():
+            source = iter(iterator)
+            step = 0
+            while True:
+                if phase_timer is not None:
+                    phase_timer.mark(task_id=task_id, step=step)
+                try:
+                    item = next(source)
+                except StopIteration:
+                    break
+                if not put(item):
                     return
+                step += 1
         except BaseException as exc:  # re-raised at the consumer
             error.append(exc)
         finally:
-            while not stop.is_set():
-                try:
-                    q.put(sentinel, timeout=0.5)
-                    break
-                except queue.Full:
-                    continue
+            put(sentinel)
 
     thread = threading.Thread(target=produce, daemon=True)
     thread.start()
 
     def consume():
+        step = 0
         while True:
             if phase_timer is None:
                 item = q.get()
             else:
-                wait_start = time.perf_counter()
-                item = q.get()
-                phase_timer.add(
-                    "data_wait", time.perf_counter() - wait_start
-                )
+                # h2d_stage of this batch follows on this thread
+                phase_timer.mark(step=step)
+                with phase_timer.phase(
+                    "data_wait", depth=q.qsize()
+                ) as wait:
+                    item = q.get()
+                    if item is sentinel:
+                        wait.step = None   # the task's end, not a step
             if item is sentinel:
                 if error:
                     raise error[0]
                 return
+            step += 1
             yield item
 
     try:
@@ -194,7 +227,16 @@ class TaskDataService:
         it turns true, returns (None, False) so the caller regains control
         — without it a worker parked on WAIT (e.g. the last shard of an
         epoch leased to another worker) never notices a drain request
-        until a task happens to arrive."""
+        until a task happens to arrive.
+
+        The whole call, lease wait included, is the `get_task` phase."""
+        with _phase(self.phase_timer, "get_task") as span:
+            task, finished = self._poll_task(task_type, should_stop)
+            if span is not None and task is not None:
+                span.task_id = task.task_id
+            return task, finished
+
+    def _poll_task(self, task_type, should_stop):
         while True:
             req = pb.GetTaskRequest(worker_id=self._worker_id)
             if task_type is not None:
@@ -268,11 +310,8 @@ class TaskDataService:
             return fn
 
         def timed(*args, **kwargs):
-            start = time.perf_counter()
-            try:
+            with timer.phase("pack"):
                 return fn(*args, **kwargs)
-            finally:
-                timer.add("pack", time.perf_counter() - start)
 
         return timed
 
@@ -348,7 +387,8 @@ class TaskDataService:
                             end=min(shard.start + off + chunk, shard.end),
                         ),
                     )
-                    bulk = reader_bulk(sub)
+                    with _phase(self.phase_timer, "read"):
+                        bulk = reader_bulk(sub)
                     if bulk is None:
                         if used_bulk:
                             # a reader that served earlier chunks must
@@ -366,14 +406,17 @@ class TaskDataService:
                     )
                 if used_bulk or total == 0:
                     return
-        buf = []
-        for record in self._reader.read_records(task):
-            buf.append(record)
+        records = iter(self._reader.read_records(task))
+        while True:
+            # streaming path: one batch's record loop is one `read`
+            with _phase(self.phase_timer, "read"):
+                buf = list(itertools.islice(records, batch_size))
             if len(buf) == batch_size:
                 yield feed(buf), batch_size
-                buf = []
-        if buf:
-            yield pad_to_multiple(feed(buf), batch_size)
+                continue
+            if buf:
+                yield pad_to_multiple(feed(buf), batch_size)
+            return
 
     def local_batches_for_task(
         self,

@@ -104,6 +104,9 @@ class Trainer:
     # here: h2d_stage covers stage_batch, compute covers the train
     # dispatch (including CPU-backend lock wait — attributing contention
     # to compute is deliberate: it IS time the step spent not overlapped).
+    # On an asynchronous backend `compute` is ENQUEUE time: the device's
+    # work shows in the loop's `task_sync`, and a long `compute` means
+    # the device's queue was full.
     phase_timer = None
 
     # Tiered embedding store (elasticdl_tpu/store).  When set, batches
@@ -116,11 +119,8 @@ class Trainer:
         timer = self.phase_timer
         if timer is None:
             return fn(*args)
-        start = time.perf_counter()
-        try:
+        with timer.phase(phase_name):
             return fn(*args)
-        finally:
-            timer.add(phase_name, time.perf_counter() - start)
 
     def __init__(
         self,

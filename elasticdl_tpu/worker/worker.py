@@ -41,28 +41,19 @@ _steps_counter = metrics_lib.default_registry().counter(
     "worker_train_steps_total", "optimizer steps completed"
 )
 _steps_gauge = metrics_lib.default_registry().gauge(
-    "worker_steps_per_sec", "rolling step rate (StepTimer window)"
+    "worker_steps_per_sec",
+    "steps of the last task over the time between the synchronised "
+    "ends of consecutive tasks (profiler.SyncedStepRate)",
 )
 _tasks_counter = metrics_lib.default_registry().counter(
     "worker_tasks_total",
     "tasks processed, by outcome",
     labelnames=("result",),
 )
-# Step-phase attribution (ISSUE 5): one process-wide PhaseTimer feeding
-# the labeled histogram, shared by the threaded and SPMD loops.  Module-
-# level for the same __new__ reason as the counters above.
-_phase_hist = metrics_lib.default_registry().histogram(
-    "worker_step_phase_seconds",
-    "per-step wall time attributed to a phase "
-    "(profiler.STEP_PHASES)",
-    labelnames=("phase",),
-)
-_phase_timer = profiler_lib.PhaseTimer(histogram=_phase_hist)
-# Zero-initialize every catalogued phase so /metrics always exposes the
-# full vocabulary — phases a given run never exercises (cold_gather is
-# tiered-store-only) render with count 0 instead of disappearing.
-for _p in profiler_lib.STEP_PHASES:
-    _phase_hist.labels(phase=_p)
+# Step-phase attribution (ISSUE 5) and spans (ISSUE 24): the process's
+# one PhaseTimer, shared by the threaded and SPMD loops.  Module-level
+# for the same __new__ reason as the counters above.
+_phase_timer = profiler_lib.process_phase_timer()
 
 
 def _same_batch_shapes(a, b) -> bool:
@@ -229,7 +220,8 @@ class Worker:
         self._owner = model_owner
         # Phase attribution: hand the process-wide timer to the layers
         # that own each phase (trainer: h2d_stage/compute; data service:
-        # pack; prefetch_batches gets it per-iteration for data_wait).
+        # get_task/read/pack; prefetch_batches gets it per-iteration for
+        # data_wait/queue_full).
         self._owner.trainer.phase_timer = _phase_timer
         self._data_service.phase_timer = _phase_timer
         self._reader = data_reader
@@ -239,13 +231,12 @@ class Worker:
 
         self.losses = deque(maxlen=1024)
         self._elastic = elastic_manager
-        # Observability (SURVEY.md §5): rolling step rate + TensorBoard
-        # scalars.  Both are cheap no-ops when no tensorboard_dir is set
-        # (the timer costs one perf_counter per batch).
-        from elasticdl_tpu.common.profiler import StepTimer
+        # Observability (SURVEY.md §5): step rate from the per-task
+        # synchronised stamp + TensorBoard scalars (a no-op when no
+        # tensorboard_dir is set).
         from elasticdl_tpu.common.summary import SummaryWriter
 
-        self.step_timer = StepTimer()
+        self.step_rate = profiler_lib.SyncedStepRate()
         # Join the live step rate against the per-program cost model
         # (docs/OBSERVABILITY.md "Program observatory"): the dominant
         # train program — fused when steps_per_execution > 1 — feeds the
@@ -253,7 +244,7 @@ class Worker:
         programs_lib.default_program_registry().bind_step_rate(
             "worker_train_step_many"
             if self.steps_per_execution > 1 else "worker_train_step",
-            lambda: self.step_timer.steps_per_sec,
+            lambda: self.step_rate.steps_per_sec,
             steps_per_execution=self.steps_per_execution,
         )
         self._summary = SummaryWriter(tensorboard_dir or None)
@@ -307,8 +298,11 @@ class Worker:
             )
             if finished:
                 logger.info("Job finished; worker %d exiting", self.worker_id)
-                if self.step_timer.steps_per_sec:
-                    self.step_timer.log(f"worker {self.worker_id}: ")
+                if self.step_rate.steps_per_sec:
+                    logger.info(
+                        "worker %d: steps/sec=%.2f",
+                        self.worker_id, self.step_rate.steps_per_sec,
+                    )
                 self._summary.close()
                 invoke_callbacks(self.spec.callbacks, "on_job_end")
                 return True
@@ -316,6 +310,7 @@ class Worker:
                 # woken out of the WAIT loop by should_stop: loop back so
                 # the drain check at the top runs
                 continue
+            _phase_timer.mark(task_id=task.task_id, step=None)
             self._maybe_remesh()
             events.emit(
                 events.TASK_CLAIMED,
@@ -382,7 +377,7 @@ class Worker:
         payload = {
             "steps_total": int(_steps_counter.value()),
             "steps_per_sec_milli": int(
-                self.step_timer.steps_per_sec * 1000
+                self.step_rate.steps_per_sec * 1000
             ),
             "model_step": int(self._owner.step),
         }
@@ -468,17 +463,17 @@ class Worker:
                     # mixed-shape group can't np.stack — drain the held
                     # batches through the single-step program first
                     for held in pending:
+                        _phase_timer.mark(step=steps)
                         loss = self._owner.train_batch(held)
-                        self.step_timer.tick()
                         _phase_timer.step_done()
                         steps += 1
                         self.losses.append(loss)
                     pending.clear()
                 pending.append(batch)
                 if len(pending) == self.steps_per_execution:
+                    _phase_timer.mark(step=steps)
                     losses = self._owner.train_batch_stack(pending)
                     for _ in pending:
-                        self.step_timer.tick()
                         _phase_timer.step_done()
                         steps += 1
                     pending.clear()
@@ -487,34 +482,40 @@ class Worker:
                     # all K losses (one device array; indexing is lazy)
                     self.losses.extend(losses)
                 continue
+            _phase_timer.mark(step=steps)
             loss = self._owner.train_batch(batch)
-            self.step_timer.tick()
             _phase_timer.step_done()
             steps += 1
             self.losses.append(loss)
         for batch in pending:
+            _phase_timer.mark(step=steps)
             loss = self._owner.train_batch(batch)
-            self.step_timer.tick()
             _phase_timer.step_done()
             steps += 1
             self.losses.append(loss)
+        _phase_timer.mark(step=None)
         if steps:
             _steps_counter.inc(steps)
-            _steps_gauge.set(self.step_timer.steps_per_sec)
             # partial flush window: the task boundary must not strand
             # accumulated phase time (the trace exporter reads these)
             _phase_timer.flush()
         if loss is not None:
-            # One scalar write per TASK, not per step: forcing the loss to
-            # host every batch would serialize the device pipeline.
+            # One fetch per TASK, not per step: forcing the loss to host
+            # every batch would serialize the device pipeline.  The wait
+            # is the device work that stood behind the host (`task_sync`)
+            # and its end is the loop's one synchronised stamp.
+            with _phase_timer.phase("task_sync") as sync:
+                # serialized: a device->host fetch racing another
+                # thread's step execution corrupts the CPU backend
+                loss_value = run_device_serialized(
+                    lambda: float(np.asarray(loss))
+                )
+            self.step_rate.task_synced(sync.end, steps)
+            _steps_gauge.set(self.step_rate.steps_per_sec)
             self._summary.scalars(
                 {
-                    # serialized: a device->host fetch racing another
-                    # thread's step execution corrupts the CPU backend
-                    "train/loss": run_device_serialized(
-                        lambda: float(np.asarray(loss))
-                    ),
-                    "train/steps_per_sec": self.step_timer.steps_per_sec,
+                    "train/loss": loss_value,
+                    "train/steps_per_sec": self.step_rate.steps_per_sec,
                 },
                 step=self._owner.step,
             )

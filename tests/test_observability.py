@@ -1,7 +1,7 @@
 """Observability integration (SURVEY.md §5 — round-2 verdict gap #3):
 `--tensorboard_log_dir` must yield real event files from BOTH sides —
 worker scalars (train/loss, train/steps_per_sec, eval/*) and the master's
-aggregated eval curve — and the StepTimer must have measured a step rate.
+aggregated eval curve — and the worker must have written a step rate.
 """
 
 import glob

@@ -223,6 +223,34 @@ def test_scale_down_on_data_wait_prefers_stragglers():
     assert pods.alive_workers() == [1, 2]
 
 
+@pytest.mark.parametrize("producer_ms, device_ms, want", [
+    # the loop waits 800 of its 1000 ms whatever the producer thread did
+    # meanwhile: its phases overlap the loop's and are not loop time
+    ({"read": 5000.0, "pack": 700.0, "queue_full": 0.0}, 0.0, 0.8),
+    # a device-bound worker: the producer sits blocked (queue_full) and
+    # the loop waits behind the device (task_sync), not on the data
+    ({"read": 100.0, "pack": 100.0, "queue_full": 9000.0}, 3000.0, 0.2),
+])
+def test_data_wait_share_is_of_the_loops_time(producer_ms, device_ms, want):
+    tm = StubTaskManager()
+    pods, _ = make_pods(2, tm=tm)
+    telemetry = {0: {"phase_data_wait_ms": 0.0}}
+    engine = PolicyEngine(
+        tm, pods,
+        PolicyConfig(min_workers=1, max_workers=2, backlog_per_worker=1e9),
+        telemetry_fn=lambda: telemetry,
+        clock=FakeClock(),
+    )
+    engine.tick()
+    telemetry[0] = {
+        "phase_data_wait_ms": 800.0, "phase_compute_ms": 150.0,
+        "phase_h2d_stage_ms": 50.0, "phase_task_sync_ms": device_ms,
+        **{f"phase_{k}_ms": v for k, v in producer_ms.items()},
+    }
+    engine.tick()
+    assert engine._last_data_wait_ratio == pytest.approx(want)
+
+
 def test_no_data_wait_signal_without_step_progress():
     clk = FakeClock()
     tm = StubTaskManager()

@@ -7,8 +7,8 @@ a synthetic log.  The e2e test runs an in-process master + worker (the
 Local-mode pattern from test_telemetry.py) with an event log configured
 and asserts `elasticdl trace --chrome` emits valid Chrome trace JSON in
 which every completed task is a duration slice on its worker's track —
-and that /metrics exposes `worker_step_phase_seconds` for all five
-phases after a real run.
+and that /metrics exposes `worker_step_phase_seconds` for every
+phase after a real run.
 """
 
 import json
@@ -367,7 +367,7 @@ def test_trace_e2e_cluster_run(mnist_data, spec, tmp_path):
         events.configure(None)
 
     # acceptance: /metrics exposes worker_step_phase_seconds for every
-    # phase after a real run (the worker records all five)
+    # phase after a real run
     text = metrics_lib.render_text([metrics_lib.default_registry()])
     for phase in STEP_PHASES:
         assert (

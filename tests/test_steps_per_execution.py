@@ -103,11 +103,11 @@ def test_worker_tail_uses_single_step(monkeypatch):
     worker._profiled = True
     from collections import deque
 
-    from elasticdl_tpu.common.profiler import StepTimer
+    from elasticdl_tpu.common.profiler import SyncedStepRate
     from elasticdl_tpu.common.summary import SummaryWriter
 
     worker.losses = deque(maxlen=8)
-    worker.step_timer = StepTimer()
+    worker.step_rate = SyncedStepRate()
     worker._summary = SummaryWriter(None)
     task = pb.Task(task_id=0, type=pb.TRAINING)
     records = worker._train_task_inner(task)
