@@ -58,6 +58,38 @@ def rel_l2(got, want) -> float:
     )
 
 
+def share(error: float, allowed: float) -> float:
+    """An error's size over what is allowed: at most 1 passes; with no
+    room at all, 0 for no error and inf otherwise."""
+    error = abs(float(error))
+    return error / allowed if allowed else (0.0 if not error else float("inf"))
+
+
+def along_across(error, want) -> tuple:
+    """An error of `want` as (its signed length along `want`, the L2 norm
+    of what is left across it).  Along `want` an error is a wrong scale
+    of the leaf, one number; across it, a wrong shape.  Where `want` is
+    all zero, all of the error is across."""
+    error = np.asarray(error, np.float64).ravel()
+    want = np.asarray(want, np.float64).ravel()
+    norm = float(np.linalg.norm(want))
+    along = float(np.dot(error, want)) / norm if norm else 0.0
+    across = float(np.sqrt(max(float(np.dot(error, error)) - along ** 2, 0.0)))
+    return along, across
+
+
+def standard_error(parts, mean) -> float:
+    """L2 standard error of `mean`, the mean of the K rows of `parts`
+    taken as independent draws: sqrt(sum |row - mean|**2 / (K (K - 1)))."""
+    parts = np.asarray(parts, np.float64)
+    spread = parts.reshape(len(parts), -1) - np.asarray(
+        mean, np.float64
+    ).ravel()
+    return float(np.sqrt(np.sum(np.square(spread)) / (
+        len(parts) * (len(parts) - 1)
+    )))
+
+
 def excess(got, want, rel: float, rounded) -> float:
     """|got - want| over what is allowed: `rel` of |want| plus the f32
     rounding of the quantity both were rounded into (`rounded`: a sum
