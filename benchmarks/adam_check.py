@@ -115,4 +115,15 @@ def cosine(got: dict, want: dict) -> float:
         np.sqrt(sum(float(np.sum(np.square(np.asarray(t[k], np.float64))))
                     for k in want)) for t in (got, want)
     ]
-    return dot / (norms[0] * norms[1]) if norms[0] and norms[1] else 0.0
+    return float(dot / (norms[0] * norms[1])) if norms[0] and norms[1] else 0.0
+
+
+def cosine_floor(twin_cosine: float, ratio: float, eps: float) -> float:
+    """The least cosine against the reference that passes where the
+    stated type's own twin makes `twin_cosine` on the same parameters and
+    batch: an angle of at most `ratio` times the twin's, or that many
+    roundings (`eps` / 2, the type's) where the twin's has cancelled.
+    1 - cosine is half the angle squared, so the ratio enters squared."""
+    rounding = (eps / 2) ** 2 / 2
+    return 1.0 - ratio ** 2 * max(1.0 - twin_cosine, rounding)
+

@@ -44,21 +44,41 @@ LOSS_ATOL = 1e-2
 # the wrong sign reads 3 to 100 times what it allows
 # (benchmarks/tests/test_leaf_rule.py).
 LEAF_REL_L2 = (("", 1e-1),)
-# Where the configuration states bfloat16 (`use_bf16`): the part of a
-# leaf's error across the reference's gradient, and the part along it,
-# may each be this many times that part of the error of this reference's
-# own twin with its tower in bfloat16 (`loss_and_grads(...,
-# tower="bfloat16")`), on the same parameters and batch.  Read on the
-# chip at B=65536 from the trained state (PERF.md section 6, PR 26): the
-# job's step 0.66 .. 1.47 times its twin on its worst leaf (32 samples on
-# 11 seeds; the bound at 3), the control (the twin in float8_e4m3fn in the
-# step's place) 7.0 .. 88 times on its worst leaf (21 samples): 3 stands
-# at twice the step's largest and under half the control's smallest.  As
-# a share of the leaf's norm alone the same samples do not separate: the
-# step 0.3e-2 .. 5.9e-2, the control 3.5e-2 .. 2.0, because the norm
-# swings 25-60x from step to step and neither error follows it.
+# Where the configuration states bfloat16 (`use_bf16`), what the step is
+# held to is this reference's own twin with its tower in bfloat16
+# (`loss_and_grads(..., tower="bfloat16")`) on the same parameters and
+# batch, and this is how many times the twin's error the step's may be.
+# It governs (1) each leaf (drivers/train.py: `leaf_shares`): the part of
+# its error across the reference's gradient, and the part along it, each
+# against that part of the twin's; (2) since PR 28, all leaves as one
+# vector (`cosine_floor`): the angle to the reference's gradient against
+# the twin's angle, so 1 - cosine against 9 times the twin's.  Read on
+# the chip at B=65536 from the trained state.  Leaves (PERF.md section
+# 6, PR 26): the job's step 0.66 .. 1.47 times its twin on its worst leaf
+# (32 samples on 11 seeds; the bound at 3), the control (the twin in
+# float8_e4m3fn in the step's place) 7.0 .. 88 times on its worst leaf
+# (21 samples): 3 stands at twice the step's largest and under half the
+# control's smallest.  As a share of the leaf's norm alone the same
+# samples do not separate: the step 0.3e-2 .. 5.9e-2, the control 3.5e-2
+# .. 2.0, because the norm swings 25-60x from step to step and neither
+# error follows it.  The angle (PERF.md section 6, PR 28; 96 samples on
+# 24 seeds at steps 113 .. 180): the step's 1 - cosine 0.63 .. 1.64 times
+# its twin's (the bound at 9 = 3 squared), the control's 25.7 .. 11,000
+# times: 9 stands at 5.5 times the step's largest and at 0.35 of the
+# control's smallest.
 STATED_RATIO = 3.0
-# All leaves as one vector: its cosine against the reference's.
+# All leaves as one vector: the least cosine against the reference's
+# that passes, where the configuration states float32.  Where it states
+# bfloat16 this constant governs nothing in this file's model since PR
+# 28: the cosine is held to the twin's (`STATED_RATIO`).  The whole
+# gradient's norm crosses zero from step to step (0.045 .. 3.85 in 96
+# samples on the chip) while bfloat16's error across it stays, and grows
+# with the step (2.7e-3 in the median at step 113, 7.1e-3 at 177), so the
+# sound step's worst 1 - cosine climbed from 1.1% of this constant's room
+# at step 113 to 74% at step 177, and the check lands later the faster
+# the program is; the fp8 control passed the constant in 76 samples of
+# 92 (PERF.md section 6, PR 28).  A reference with no twin
+# (reference/bert.py) keeps its own constant of this name.
 GRAD_COSINE_MIN = 0.99
 
 _MIX = np.uint32(2654435761)           # Knuth, 2**32 / phi

@@ -11,7 +11,10 @@ tower in the type below), that the twin computes what the job's own
 bfloat16 model computes, and where the sampling noise comes from.  The
 rule holds no batch size; the sampling noise, the one term that follows
 it, only shrinks with the batch, so what fails at 256 rows fails at the
-cell's 65536."""
+cell's 65536.  Beside the leaves, all of them as one vector
+(`cosine_floor`, `check_gradient`): its angle to the reference's gradient
+is held to that many times the twin's angle where there is a twin, to
+`GRAD_COSINE_MIN` where there is none."""
 
 import jax
 import jax.numpy as jnp
@@ -33,10 +36,11 @@ PARENT_LIMIT = deepfm.LEAF_REL_L2[-1][1]
 class HandMade:
     """A reference whose gradient, whose bfloat16 twin and whose part
     gradients are given: eight parts that spread `noise` (L2 standard
-    error of their mean) around `want`."""
+    error of their mean; one number, or {leaf: number}) around `want`."""
 
     LEAF_REL_L2 = (("^tight", 3e-2), ("", 1e-1))
     STATED_RATIO = RATIO
+    GRAD_COSINE_MIN = deepfm.GRAD_COSINE_MIN
 
     def __init__(self, want, twin, noise=0.0):
         self.want, self.twin, self.noise, self.calls = want, twin, noise, []
@@ -53,16 +57,22 @@ class HandMade:
         for name, value in self.want.items():
             value = np.asarray(value, np.float64)
             spread = np.zeros((parts,) + value.shape)
+            noise = self.noise
+            if isinstance(noise, dict):
+                noise = noise[name]
             spread.reshape(parts, -1)[:, 0] = (
-                sign * self.noise * np.sqrt(parts - 1)
+                sign * noise * np.sqrt(parts - 1)
             )
             out[name] = value + spread
         return out
 
 
+def f32(tree):
+    return {k: np.asarray(v, np.float32) for k, v in tree.items()}
+
+
 def shares_of_hand_made(want, twin, got, noise=0.0, config=BF16,
                         reference=None):
-    f32 = lambda tree: {k: np.asarray(v, np.float32) for k, v in tree.items()}
     want, twin, got = f32(want), f32(twin), f32(got)
     reference = reference or HandMade(want, twin, noise)
     return train.leaf_shares(
@@ -129,6 +139,86 @@ def test_a_float32_leaf_is_held_to_its_share_of_the_norm():
     finally:
         HandMade.STATED_RATIO = RATIO
     assert not hasattr(bert, "STATED_RATIO")
+
+
+def gradient_of_hand_made(want, twin, got, config=BF16, reference=None):
+    want, twin, got = f32(want), f32(twin), f32(got)
+    reference = reference or HandMade(want, twin)
+    return train.check_gradient(
+        reference, "params", "features", np.zeros(64), config, want, got
+    )
+
+
+def at_angle(angle):
+    """(1, 0, 0, 0) turned by `angle` towards the second axis."""
+    return [np.cos(angle), np.sin(angle), 0.0, 0.0]
+
+
+def test_the_cosine_is_held_to_the_twins_angle():
+    """The twin stands at an angle of 0.02 to the reference's gradient:
+    the step may stand at three times that, whichever way it turned."""
+    want, twin = {"a": at_angle(0.0)}, {"a": at_angle(0.02)}
+    reference = HandMade(want, twin)
+    read = gradient_of_hand_made(want, twin, {"a": at_angle(0.05)},
+                                 reference=reference)
+    assert read["twin_cosine"] == pytest.approx(np.cos(0.02), abs=1e-7)
+    assert read["cosine_floor"] == pytest.approx(
+        1 - RATIO ** 2 * (1 - np.cos(0.02)), abs=1e-7
+    )
+    assert read["cosine"] == pytest.approx(np.cos(0.05), abs=1e-7)
+    assert read["ok"]
+    # the twin is computed once for the leaves and the cosine together
+    assert [call[3] for call in reference.calls] == [
+        "bfloat16", train.NOISE_PARTS
+    ]
+    other_way = {"a": [np.cos(0.05), 0.0, np.sin(0.05), 0.0]}
+    assert gradient_of_hand_made(want, twin, other_way)["cosine"] >= (
+        read["cosine_floor"]
+    )
+    past = gradient_of_hand_made(want, twin, {"a": at_angle(0.07)})
+    assert past["cosine"] < past["cosine_floor"] and not past["ok"]
+    # tighter than the constant away from a crossing, looser at one
+    assert read["cosine_floor"] > deepfm.GRAD_COSINE_MIN
+    wide = gradient_of_hand_made(
+        want, {"a": at_angle(0.1)}, {"a": at_angle(0.2)}
+    )
+    assert wide["cosine"] < deepfm.GRAD_COSINE_MIN < 1.0
+    assert wide["cosine"] >= wide["cosine_floor"] and wide["ok"]
+
+
+def test_a_twin_that_happens_to_agree_leaves_roundings_of_room():
+    """Three roundings of bfloat16 as an angle: 1 - cosine at most
+    9 x (2**-8)**2 / 2."""
+    floor = adam_check.cosine_floor(1.0, RATIO, 2.0 ** -7)
+    assert floor == pytest.approx(1 - RATIO ** 2 * ROUNDOFF ** 2 / 2)
+    want = {"a": at_angle(0.0)}
+    read = gradient_of_hand_made(want, want, {"a": at_angle(2 * ROUNDOFF)})
+    assert read["cosine_floor"] == pytest.approx(floor) and read["ok"]
+    assert not gradient_of_hand_made(
+        want, want, {"a": at_angle(4 * ROUNDOFF)}
+    )["ok"]
+
+
+@pytest.mark.parametrize("why", ["float32 is stated", "no twin"])
+def test_without_a_twin_the_cosine_keeps_its_constant(why, monkeypatch):
+    """Where float32 is stated, or the reference has no twin
+    (`reference/bert.py`): `GRAD_COSINE_MIN` of the reference's file, and
+    no twin is computed."""
+    config = F32 if why == "float32 is stated" else BF16
+    if why == "no twin":
+        monkeypatch.delattr(HandMade, "STATED_RATIO")
+    want = {"a": at_angle(0.0)}
+    reference = HandMade(want, None)
+    # cos(0.14) = 0.9902, cos(0.15) = 0.9888
+    for angle, passes in ((0.14, True), (0.15, False)):
+        read = gradient_of_hand_made(
+            want, want, {"a": at_angle(angle)}, config, reference
+        )
+        assert read["cosine_floor"] == HandMade.GRAD_COSINE_MIN
+        assert read["twin_cosine"] is None
+        assert (read["cosine"] >= read["cosine_floor"]) is passes
+    assert reference.calls == []
+    assert not hasattr(bert, "STATED_RATIO") and bert.GRAD_COSINE_MIN == 0.9
 
 
 # bfloat16's error in the bias leaf of the cell is 1e-4 .. 6e-4 whatever
@@ -217,12 +307,16 @@ def deepfm_case(seed, rows, config=DEEPFM, zoo=DEEPFM_ZOO):
     return flat, features, labels, want, cut(grads)
 
 
-def shares_of(case, config, got):
+def gradient_of(case, config, got):
+    """`check_gradient` under the bound a bfloat16 configuration gets."""
     flat, features, labels, want, _ = case
-    return train.leaf_shares(
-        deepfm, flat, features, labels, dict(config, **BF16), want,
-        {k: np.asarray(v, np.float32) for k, v in got.items()},
+    return train.check_gradient(
+        deepfm, flat, features, labels, dict(config, **BF16), want, f32(got)
     )
+
+
+def shares_of(case, config, got):
+    return gradient_of(case, config, got)["shares"]
 
 
 PAPER_TOWER = {"vocab_capacity": 65536, "embed_dim": 16,
@@ -324,11 +418,18 @@ def test_the_jobs_bfloat16_model_passes(seed, rows, config, zoo):
     tower's kernels and the tables, where the CPU sums as the twin does,
     at the third of it that "as the twin" is."""
     case = deepfm_case(seed, rows, config, zoo)
-    shares = shares_of(case, config, case[-1])
+    read = gradient_of(case, config, case[-1])
+    shares = read["shares"]
     assert max(shares.values()) <= 1.0, shares
     for name, value in shares.items():
         if name.endswith("/kernel") or name in deepfm.TABLES:
             assert value <= 0.4, shares
+    # all leaves as one vector: inside the twin's angle, which is far
+    # inside the constant's here, no crossing being near
+    assert read["ok"], read
+    assert read["cosine"] >= read["cosine_floor"] > deepfm.GRAD_COSINE_MIN
+    angle = np.sqrt((1 - read["cosine"]) / (1 - read["twin_cosine"]))
+    assert angle <= 1.5, read
 
 
 def fm2(emb_rows, inverse):
@@ -395,7 +496,8 @@ def test_a_dropped_term_or_field_still_fails(logits_of, leaf, rows):
         want = {k: np.asarray(v, np.float32) for k, v in want.items()}
         case = (flat, features, labels, want, None)
     got = deepfm_grads(flat, features, labels, DEEPFM, logits_of)
-    assert shares_of(case, DEEPFM, got)[leaf] > 3.0
+    read = gradient_of(case, DEEPFM, got)
+    assert read["shares"][leaf] > 3.0 and not read["ok"]
 
 
 @pytest.mark.parametrize("factor", [0.0, 0.5, 2.0, -1.0])
@@ -407,7 +509,9 @@ def test_a_leaf_missing_halved_doubled_or_of_the_wrong_sign_fails(factor):
     want = case[3]
     for leaf in want:
         got = dict(want, **{leaf: factor * want[leaf]})
-        shares = shares_of(case, PAPER_TOWER, got)
+        read = gradient_of(case, PAPER_TOWER, got)
+        shares = read["shares"]
+        assert not read["ok"]
         assert shares[leaf] > 3.0, (leaf, shares[leaf])
         assert max(v for k, v in shares.items() if k != leaf) == 0.0
 
@@ -427,12 +531,76 @@ def test_the_control_a_tower_in_fp8_fails(seed, rows, config, zoo):
     _, control = deepfm.loss_and_grads(
         flat, features, labels, config, tower="float8_e4m3fn"
     )
-    shares = shares_of(case, config, control)
+    read = gradient_of(case, config, control)
+    shares = read["shares"]
+    assert not read["ok"] and read["cosine"] < read["cosine_floor"], read
     over = [name for name, value in shares.items() if value > 1.0]
     assert "fm_embedding" in over, shares
     assert sum(n.startswith("mlp_") and n.endswith("/kernel")
                for n in over) >= 3, shares
     assert max(shares.values()) > 3.0, shares
+
+
+@pytest.fixture(scope="module")
+def paper_case():
+    """The job's bfloat16 model at the paper's tower on a fresh state:
+    the case, the twin's and the control's gradients, the leaves' noise."""
+    case = deepfm_case(2 ** 31 + 4, 4096, PAPER_TOWER, paper_tower_zoo(True))
+    flat, features, labels, want, _ = case
+    config = dict(PAPER_TOWER, **BF16)
+    twin = train.stated_twin(deepfm, flat, features, labels, config)
+    _, control = deepfm.loss_and_grads(
+        flat, features, labels, config, tower="float8_e4m3fn"
+    )
+    control = f32(control)
+    noise = train.sampling_noise(deepfm, flat, features, labels, config, want)
+    return case, twin, control, noise
+
+
+@pytest.mark.parametrize("shrunk", [1, 30])
+@pytest.mark.parametrize("who", ["the step", "the control"])
+def test_a_crossing_of_the_whole_gradient(paper_case, who, shrunk):
+    """The common mode crosses zero (PERF.md section 6): every leaf of
+    the reference's gradient `shrunk` times smaller while the errors of
+    the step, the twin and the control, and the leaves' sampling noise,
+    stay what they were.  The step passes at the crossing, where the
+    constant `GRAD_COSINE_MIN` would have failed it; the control fails
+    at it and away from it, on its leaves and on the cosine."""
+    (_, _, _, want, step), twin, control, noise = paper_case
+    got = step if who == "the step" else control
+    small = {k: v / shrunk for k, v in want.items()}
+    moved = lambda tree: {k: small[k] + (tree[k] - want[k]) for k in want}
+    read = train.check_gradient(
+        HandMade(small, moved(twin), noise), "params", "features",
+        np.zeros(64), BF16, small, moved(got),
+    )
+    if who == "the step":
+        assert read["ok"], read
+        assert (read["cosine"] < deepfm.GRAD_COSINE_MIN) is (shrunk == 30)
+    else:
+        assert not read["ok"]
+        assert read["cosine"] < read["cosine_floor"], read
+        assert max(read["shares"].values()) > 3.0, read
+
+
+@pytest.mark.parametrize("off", [0.05, 0.08])
+def test_leaves_scaled_against_each_other_fail_on_the_cosine(paper_case, off):
+    """What the cosine sees and no leaf does: the two tables' gradients
+    `off` too small and every other leaf's as much too large.  Each leaf
+    is inside the tenth of its norm it may be off along itself, and the
+    constant lets the whole through; the twin's angle does not."""
+    case = paper_case[0]
+    want = case[3]
+    got = {
+        k: (1 - off if k in deepfm.TABLES else 1 + off) * v
+        for k, v in want.items()
+    }
+    read = gradient_of(case, PAPER_TOWER, got)
+    assert max(read["shares"].values()) == pytest.approx(
+        off / PARENT_LIMIT, rel=1e-3
+    )
+    assert read["cosine"] >= deepfm.GRAD_COSINE_MIN
+    assert read["cosine"] < read["cosine_floor"] and not read["ok"]
 
 
 def test_berts_encoder_at_3_percent_of_its_gradient_still_fails():

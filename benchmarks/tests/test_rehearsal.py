@@ -128,3 +128,6 @@ def test_train_cells(tiny_root, workload, devices):
     )
     assert check and float(check.group(1)) < 1e-3, out[-2000:]
     assert float(check.group(2)) <= 1.0
+    # every number compared stands beside its limit, the cosine too
+    angle = re.search(r"1 - cosine ([0-9.e+-]+) \(at most ([0-9.e+-]+)", out)
+    assert angle and float(angle.group(1)) <= float(angle.group(2))
