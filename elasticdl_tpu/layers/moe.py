@@ -22,16 +22,68 @@ pjit way (SURVEY.md §7: annotate shardings, let XLA insert collectives):
 Capacity assignment uses the standard position-in-expert cumsum, which
 is deterministic and position-biased (earlier tokens win slots), exactly
 like the reference implementations.
+
+`RoutedExperts` is the second scheme, for decoders of the DeepSeek-V3 /
+GLM family: sigmoid scores, a selection bias that picks and does not
+weigh, top-k of ALL experts, and a layer that is told which experts it
+holds (`held_experts`) and computes their part of the result.  One-hot
+dispatch cannot stand there (16,384 tokens x 64 experts x capacity), so
+the slots routed to held experts are SORTED by expert into one buffer of
+static worst-case size, the group sizes travel as data, and a grouped
+matrix product (`grouped_matmul`) does work in proportion to the rows
+routed: no token is dropped at any imbalance and nothing recompiles when
+the loads change.  Both layers count router load with `expert_loads`.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+
+# Collections the Trainer knows (worker/trainer.py): every leaf sown into
+# AUX_LOSS is added to the training objective; STEP_METRICS holds the
+# last step's scalars and rides in `model_state` to the task's one fetch;
+# ROUTER_STATE holds buffers a layer updates itself (no gradient).
+AUX_LOSS = "aux_loss"
+STEP_METRICS = "step_metrics"
+ROUTER_STATE = "router_state"
+
+
+def sow_step_metric(module: nn.Module, name: str, value) -> None:
+    """Keep `value` (the LAST step's, not a history) in STEP_METRICS."""
+    value = jax.lax.stop_gradient(jnp.asarray(value, jnp.float32))
+    module.sow(
+        STEP_METRICS, name, value,
+        reduce_fn=lambda previous, new: new,
+        init_fn=lambda: jnp.zeros(value.shape, jnp.float32),
+    )
+
+
+def expert_loads(expert_idx, num_experts: int):
+    """(num_experts,) f32: how many routing slots chose each expert."""
+    return jnp.zeros((num_experts,), jnp.float32).at[
+        expert_idx.reshape(-1)
+    ].add(1.0)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """(rows, k) x (groups, k, n) -> (rows, n): rows [0, g0) times rhs[0],
+    the next g1 rows times rhs[1], ...; rows past sum(group_sizes) come
+    back zero.  The device does work for the rows in groups only, and
+    on the TPU leaves the other rows of its result UNWRITTEN, forward
+    and in both transposes: masked here on the way in and on the way
+    out, so neither the product nor its gradients ever read them."""
+    live = (jnp.arange(lhs.shape[0]) < group_sizes.sum())[:, None]
+    return jnp.where(
+        live,
+        jax.lax.ragged_dot(jnp.where(live, lhs, 0), rhs, group_sizes),
+        0,
+    )
 
 
 class MoEMLP(nn.Module):
@@ -41,7 +93,7 @@ class MoEMLP(nn.Module):
     ffn_dim:         per-expert intermediate width
     capacity_factor: slots per expert = ceil(tokens/experts * factor)
     aux_loss_coef:   weight of the sown Switch load-balancing loss; the
-                     Trainer adds every sown `moe_aux_loss` to the
+                     Trainer adds everything sown into AUX_LOSS to the
                      training objective, so routing cannot collapse onto
                      one expert
     """
@@ -121,17 +173,127 @@ class MoEMLP(nn.Module):
             "nec,ech->nh", combine, expert_out.astype(jnp.float32)
         )
         # auxiliary load-balancing loss (Switch eq.4), pre-scaled by its
-        # coefficient; the Trainer sums every sown `moe_aux_loss` into the
-        # training objective (worker/trainer.py)
-        density = onehot.astype(jnp.float32).mean(axis=0)
+        # coefficient; the Trainer sums everything sown into AUX_LOSS
+        # into the training objective (worker/trainer.py)
+        density = expert_loads(expert_idx, self.num_experts) / n_tokens
         density_proxy = probs.mean(axis=0)
         self.sow(
-            "intermediates", "moe_aux_loss",
+            AUX_LOSS, "moe_aux_loss",
             self.aux_loss_coef
             * self.num_experts
             * jnp.sum(density * density_proxy),
         )
         return out.astype(x.dtype).reshape(*batch_dims, hidden)
+
+
+class RoutedExperts(nn.Module):
+    """Sigmoid top-k routed SwiGLU experts, this holder's part:
+    (..., hidden) -> (..., hidden) in float32.
+
+        s = sigmoid(x Wr)                     float32, all `num_experts`
+        S = top_k(s + b)                      b selects, never weighs
+        w_i = routed_scaling * s_i / sum_{j in S} s_j      (i in S)
+        out = sum_{i in S, i held here} w_i SwiGLU_i(x)
+
+    num_experts:      the router's width (every expert of the layer)
+    held_experts:     (first, count) of the experts whose weights live
+                      here; None holds all.  What absent experts would
+                      add is left out (expert parallelism's partial sum;
+                      the exchange is the caller's)
+    bias_update_rate: gamma of loss-free balancing (arXiv:2408.15664):
+                      b_i += gamma * sign(mean load - load_i) after each
+                      train step, in ROUTER_STATE; 0 keeps b as it is
+
+    Expert stacks are `expert_w_gate_up` (gate and up fused) and
+    `expert_w_down`, no biases; `moe_param_sharding` shards them.
+    """
+
+    num_experts: int
+    top_k: int
+    ffn_dim: int
+    held_experts: Optional[Tuple[int, int]] = None
+    routed_scaling: float = 1.0
+    bias_update_rate: float = 0.0
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        *lead, hidden = x.shape
+        tokens = x.reshape(-1, hidden)
+        n, k = tokens.shape[0], self.top_k
+        first, count = self.held_experts or (0, self.num_experts)
+
+        with jax.named_scope("router"):
+            w_router = self.param(
+                "router_kernel", nn.initializers.lecun_normal(),
+                (hidden, self.num_experts), jnp.float32,
+            )
+            bias = self.variable(
+                ROUTER_STATE, "e_score_correction_bias",
+                lambda: jnp.zeros((self.num_experts,), jnp.float32),
+            )
+            scores = jax.nn.sigmoid(jnp.dot(
+                tokens.astype(jnp.float32), w_router,
+                precision=jax.lax.Precision.HIGHEST,
+            ))
+            _, idx = jax.lax.top_k(
+                jax.lax.stop_gradient(scores) + bias.value, k
+            )                                               # (n, k)
+            picked = jnp.take_along_axis(scores, idx, axis=1)
+            weights = self.routed_scaling * picked / picked.sum(
+                axis=1, keepdims=True
+            )
+            loads = expert_loads(idx, self.num_experts)
+            if (self.bias_update_rate
+                    and not self.is_initializing()
+                    and self.is_mutable_collection(ROUTER_STATE)):
+                bias.value = bias.value + self.bias_update_rate * jnp.sign(
+                    loads.mean() - loads
+                )
+
+        with jax.named_scope("dispatch"):
+            local = idx - first
+            held = (local >= 0) & (local < count)
+            # slots of absent experts sort past every held group
+            key = jnp.where(held, local, count).reshape(-1)
+            order = jnp.argsort(key, stable=True)
+            token_of = order // k
+            group_sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(
+                1
+            )[:count]
+            rows = group_sizes.sum()
+            slot_weight = weights.reshape(-1)[order][:, None]
+            sorted_tokens = tokens.astype(self.dtype)[token_of]
+
+        with jax.named_scope("experts"):
+            w_gate_up = self.param(
+                "expert_w_gate_up", nn.initializers.lecun_normal(),
+                (count, hidden, 2 * self.ffn_dim), jnp.float32,
+            )
+            w_down = self.param(
+                "expert_w_down", nn.initializers.lecun_normal(),
+                (count, self.ffn_dim, hidden), jnp.float32,
+            )
+            gate_up = grouped_matmul(
+                sorted_tokens, w_gate_up.astype(self.dtype), group_sizes
+            )
+            gate, up = jnp.split(gate_up, 2, axis=-1)
+            expert_out = grouped_matmul(
+                nn.silu(gate) * up, w_down.astype(self.dtype), group_sizes
+            )
+
+        with jax.named_scope("combine"):
+            # rows past the last group are zero (`grouped_matmul`)
+            out = jnp.zeros((n, hidden), jnp.float32).at[token_of].add(
+                expert_out.astype(jnp.float32) * slot_weight
+            )
+
+        sow_step_metric(
+            self, "expert_load_imbalance_ratio", loads.max() / loads.mean()
+        )
+        sow_step_metric(self, "routed_here_ratio", rows / (n * k))
+        sow_step_metric(self, "dropped_tokens", held.sum() - rows)
+        return out.reshape(*lead, hidden)
 
 
 def moe_param_sharding(path, value) -> Optional[P]:

@@ -41,6 +41,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
@@ -261,15 +262,24 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 _MAX_KV_BLOCK_ELEMENTS = 5 * 256 * 1024  # 1.25M
 
 
-def flash_shapes_ok(q_shape, k_shape) -> bool:
-    """Whether (B, L, H, D) q/k shapes satisfy the kernel's constraints:
-    tile shapes (L multiple of 128 or a sub-128 multiple of 8, D <= 128)
-    AND per-program K/V VMEM residency (k_len * H * D within
-    _MAX_KV_BLOCK_ELEMENTS).  Callers dispatch on THIS instead of
-    catching ValueError from `flash_attention` — a blanket except around
-    a traced call swallowed an unrelated shard_map vma error for a full
-    round and silently downgraded the bench to the O(L^2) reference path
-    (round-5 profile finding)."""
+def flash_shapes_ok(q_shape, k_shape, causal: bool = False) -> bool:
+    """Whether (B, L, H, D) q/k shapes satisfy a kernel's constraints.
+    The resident kernel: tile shapes (L multiple of 128 or a sub-128
+    multiple of 8, D <= 128) AND per-program K/V VMEM residency (k_len *
+    H * D within _MAX_KV_BLOCK_ELEMENTS).  Past those, CAUSAL
+    self-attention at a head width of whole lane tiles goes to the
+    streaming kernel (`stream_shapes_ok`), which holds one K/V tile at a
+    time.  Callers dispatch on THIS instead of catching ValueError from
+    `flash_attention` — a blanket except around a traced call swallowed
+    an unrelated shard_map vma error for a full round and silently
+    downgraded the bench to the O(L^2) reference path (round-5 profile
+    finding)."""
+    return _resident_shapes_ok(q_shape, k_shape) or (
+        causal and stream_shapes_ok(q_shape, k_shape, k_shape)
+    )
+
+
+def _resident_shapes_ok(q_shape, k_shape) -> bool:
     def bad(length):
         return (length >= 128 and length % 128 != 0) or (
             length < 128 and length % 8 != 0
@@ -291,7 +301,8 @@ def flash_attention(
 
     Differentiable (custom VJP with flash recompute).  Sequence lengths
     must be multiples of the 128 tile (or shorter than it) — pad upstream
-    if not; head dim <= 128.
+    if not; head dim <= 128, or causal self-attention at a head width of
+    whole lane tiles (the streaming kernel).
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -310,7 +321,7 @@ def flash_attention(
     # silently DROP tail keys — the kernel streams whole tiles); a
     # separate inline copy here could drift from flash_shapes_ok and
     # reintroduce the uncaught-ValueError-in-shard_map failure mode.
-    if not flash_shapes_ok(q.shape, k.shape) or k.shape != v.shape:
+    if not flash_shapes_ok(q.shape, k.shape, causal) or k.shape != v.shape:
         raise ValueError(
             f"flash_attention needs L a multiple of 128 (or a sub-128 "
             f"multiple of 8) for BOTH q and k/v, k.shape == v.shape, "
@@ -319,4 +330,380 @@ def flash_attention(
             f"Lq={q.shape[1]}, Lk={k.shape[1]}, H={q.shape[2]}, "
             f"D={q.shape[3]}"
         )
+    if not _resident_shapes_ok(q.shape, k.shape):
+        return _stream(q, k, v, float(scale))
     return _flash(q, k, v, causal, scale)
+
+
+# ---- causal self-attention past the resident kernel's shapes -------------
+#
+# The kernel above keeps a program's whole K and V in VMEM and its
+# backward builds (B, H, L, L) operands: a decoder at 20 heads of width
+# 256 over 4,096 positions passes both limits (5,120 columns a row; 671
+# MB of probabilities a sequence).  `blocked_causal_attention` walks the
+# causal triangle one row of query tiles at a time, forward and backward,
+# in plain lax: nothing (B, H, L, L)-shaped exists for more than one tile
+# row, the tiles above the diagonal are never computed, and the backward
+# rebuilds each row's probabilities from the saved log-sum-exp.
+
+_BLOCKED_TILE = 512
+
+
+def _tile_rows(length: int, tile: int):
+    """[(start, end)] of the query tiles; the keys of a row are [0, end)."""
+    tile = min(tile, length)
+    return [(s, min(s + tile, length)) for s in range(0, length, tile)]
+
+
+def _row_logits(q_row, k_pre, start, scale):
+    """(B, H, rows, keys) f32 logits of one tile row, masked causally."""
+    logits = jnp.einsum(
+        "bqhd,bkhd->bhqk", q_row, k_pre, preferred_element_type=jnp.float32
+    ) * scale
+    q_pos = start + jnp.arange(q_row.shape[1])[:, None]
+    k_pos = jnp.arange(k_pre.shape[1])[None, :]
+    return jnp.where(q_pos >= k_pos, logits, _NEG_INF)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _blocked(q, k, v, scale, tile):
+    return _blocked_fwd(q, k, v, scale, tile)[0]
+
+
+def _blocked_fwd(q, k, v, scale, tile):
+    outs, lses = [], []
+    for start, end in _tile_rows(q.shape[1], tile):
+        logits = _row_logits(q[:, start:end], k[:, :end], start, scale)
+        m = logits.max(axis=-1, keepdims=True)
+        p = jnp.exp(logits - m)
+        l = p.sum(axis=-1, keepdims=True)
+        out = jnp.einsum(
+            "bhqk,bkhd->bqhd", p.astype(v.dtype), v[:, :end],
+            preferred_element_type=jnp.float32,
+        ) / l[..., 0].transpose(0, 2, 1)[..., None]
+        outs.append(out.astype(q.dtype))
+        lses.append((m + jnp.log(l))[..., 0])           # (B, H, rows)
+    out = jnp.concatenate(outs, axis=1)
+    lse = jnp.concatenate(lses, axis=2)
+    return out, (q, k, v, out, lse)
+
+
+def _blocked_bwd(scale, tile, residuals, g):
+    q, k, v, out, lse = residuals
+    g = g.astype(q.dtype)
+    delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
+    delta = delta.transpose(0, 2, 1)                    # (B, H, L)
+    dqs = []
+    dk = jnp.zeros(k.shape, jnp.float32)
+    dv = jnp.zeros(v.shape, jnp.float32)
+    for start, end in _tile_rows(q.shape[1], tile):
+        q_row, g_row = q[:, start:end], g[:, start:end]
+        logits = _row_logits(q_row, k[:, :end], start, scale)
+        p = jnp.exp(logits - lse[:, :, start:end, None])
+        dp = jnp.einsum(
+            "bqhd,bkhd->bhqk", g_row, v[:, :end],
+            preferred_element_type=jnp.float32,
+        )
+        ds = (p * (dp - delta[:, :, start:end, None]) * scale).astype(q.dtype)
+        dqs.append(jnp.einsum(
+            "bhqk,bkhd->bqhd", ds, k[:, :end],
+            preferred_element_type=jnp.float32,
+        ).astype(q.dtype))
+        dk = dk.at[:, :end].add(jnp.einsum(
+            "bhqk,bqhd->bkhd", ds, q_row, preferred_element_type=jnp.float32,
+        ))
+        dv = dv.at[:, :end].add(jnp.einsum(
+            "bhqk,bqhd->bkhd", p.astype(q.dtype), g_row,
+            preferred_element_type=jnp.float32,
+        ))
+    return (
+        jnp.concatenate(dqs, axis=1), dk.astype(k.dtype), dv.astype(v.dtype)
+    )
+
+
+_blocked.defvjp(_blocked_fwd, _blocked_bwd)
+
+
+def blocked_causal_attention(q, k, v, scale: Optional[float] = None,
+                             tile: int = _BLOCKED_TILE):
+    """Causal self-attention, q/k/v (B, L, H, D) -> (B, L, H, D), any head
+    width, one row of `tile` queries at a time (module comment above)."""
+    if q.shape != k.shape or k.shape[:3] != v.shape[:3]:
+        raise ValueError(
+            f"blocked_causal_attention is self-attention: q {q.shape}, "
+            f"k {k.shape}, v {v.shape} must agree (v's width apart)"
+        )
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    return _blocked(q, k, v, float(scale), int(tile))
+
+
+# ---- the streaming kernel: causal, head width a multiple of 128 ----------
+#
+# Inputs stay (B, L, H*D) (the free view of the model's layout) and the
+# grid walks (batch, head, query tile, key tile): a program holds ONE
+# (tile, D) block of Q, K and V (D = 256 is two lane tiles, so a head is
+# a column block and no head loop is needed), K/V tiles stream through
+# VMEM with the running max, normaliser and accumulator in scratch, and
+# tiles above the diagonal neither compute nor move (their block index is
+# clamped to the last tile needed, which Pallas does not fetch again).
+# The backward is two kernels from the saved log-sum-exp, as the blocked
+# form above but tile by tile: dK/dV with the key tile resident and the
+# query tiles streaming, dQ with the query tile resident.
+
+_STREAM_TILE = 512
+_LANES = 128
+
+
+def _stream_tiles(length: int):
+    for cand in (_STREAM_TILE, 256, 128):
+        if length % cand == 0:
+            return cand
+    return None
+
+
+def stream_shapes_ok(q_shape, k_shape, v_shape) -> bool:
+    """Whether the streaming kernel takes causal self-attention at these
+    (B, L, H, D) shapes: q, k and v alike, L whole 128-tiles, D whole
+    lane tiles."""
+    return (
+        tuple(q_shape) == tuple(k_shape) == tuple(v_shape)
+        and q_shape[3] % _LANES == 0
+        and _stream_tiles(q_shape[1]) is not None
+    )
+
+
+def _causal_tile(s, i, j, tile):
+    """Mask logits of query tile i against key tile j."""
+    q_pos = i * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_pos = j * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(
+        a, b, (contract, ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def _stream_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
+                       acc_sc, *, scale: float, tile: int, num_k: int):
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, _NEG_INF, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    @pl.when(j <= i)
+    def _():
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        s = _causal_tile(
+            _dot(q, k, ((1,), (1,))) * scale, i, j, tile
+        )                                               # (tile, tile)
+        m_prev = m_sc[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        correction = jnp.exp(m_prev - m_new)
+        l_new = l_sc[:, :1] * correction + p.sum(axis=-1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * correction + _dot(
+            p.astype(v.dtype), v, ((1,), (0,))
+        )
+        m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
+        l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
+
+    @pl.when(j == num_k - 1)
+    def _():
+        l = l_sc[:, :1]
+        o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_sc[:, :1] + jnp.log(l)
+
+
+def _stream_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                       dk_ref, dv_ref, dk_sc, dv_sc, *, scale: float,
+                       tile: int, num_q: int):
+    j, i = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(i == 0)
+    def _():
+        dk_sc[...] = jnp.zeros(dk_sc.shape, jnp.float32)
+        dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
+
+    @pl.when(i >= j)
+    def _():
+        q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
+        s = _causal_tile(
+            _dot(q, k, ((1,), (1,))) * scale, i, j, tile
+        )
+        p = jnp.exp(s - lse_ref[0, 0])                  # (tile q, tile k)
+        dv_sc[...] += _dot(p.astype(g.dtype), g, ((0,), (0,)))
+        dp = _dot(g, v, ((1,), (1,)))
+        ds = (p * (dp - delta_ref[0, 0]) * scale).astype(q.dtype)
+        dk_sc[...] += _dot(ds, q, ((0,), (0,)))
+
+    @pl.when(i == num_q - 1)
+    def _():
+        dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
+
+
+def _stream_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                      dq_ref, dq_sc, *, scale: float, tile: int,
+                      num_k: int):
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        dq_sc[...] = jnp.zeros(dq_sc.shape, jnp.float32)
+
+    @pl.when(j <= i)
+    def _():
+        q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
+        s = _causal_tile(
+            _dot(q, k, ((1,), (1,))) * scale, i, j, tile
+        )
+        p = jnp.exp(s - lse_ref[0, 0])
+        dp = _dot(g, v, ((1,), (1,)))
+        ds = (p * (dp - delta_ref[0, 0]) * scale).astype(k.dtype)
+        dq_sc[...] += _dot(ds, k, ((1,), (0,)))
+
+    @pl.when(j == num_k - 1)
+    def _():
+        dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
+
+
+def _stream_call(kernel, grid, in_specs, out_specs, out_shape, scratch,
+                 operands, name):
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=0, grid=grid, in_specs=in_specs,
+            out_specs=out_specs, scratch_shapes=scratch,
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+            for shape, dtype in out_shape
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=(
+                "parallel", "parallel", "parallel", "arbitrary"
+            ),
+        ),
+        interpret=use_interpret(),
+        name=name,
+    )(*operands)
+
+
+def _stream_specs(tile: int, dim: int):
+    """Block specs by role, for a grid (batch, head, outer tile, inner
+    tile): `row` follows the query tile and `col` the key tile, each
+    clamped to the causal triangle so a skipped step moves nothing."""
+    def tiles(which):
+        return pl.BlockSpec(
+            (1, tile, dim), lambda b, h, x, y: (b, which(x, y), h)
+        )
+
+    def per_row(which):
+        return pl.BlockSpec(
+            (1, 1, tile, 1), lambda b, h, x, y: (b, h, which(x, y), 0)
+        )
+
+    return tiles, per_row
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _stream(q, k, v, scale):
+    return _stream_fwd(q, k, v, scale)[0]
+
+
+def _stream_fwd(q, k, v, scale):
+    batch, length, heads, dim = q.shape
+    tile = _stream_tiles(length)
+    num = length // tile
+    tiles, per_row = _stream_specs(tile, dim)
+    flat = (batch, length, heads * dim)
+    # grid (b, h, i over queries, j over keys): keys past the diagonal
+    # stay on the diagonal's tile
+    out, lse = _stream_call(
+        functools.partial(
+            _stream_fwd_kernel, scale=scale, tile=tile, num_k=num
+        ),
+        (batch, heads, num, num),
+        [tiles(lambda i, j: i), tiles(lambda i, j: jnp.minimum(i, j)),
+         tiles(lambda i, j: jnp.minimum(i, j))],
+        [tiles(lambda i, j: i), per_row(lambda i, j: i)],
+        [(flat, q.dtype), ((batch, heads, length, 1), jnp.float32)],
+        [pltpu.VMEM((tile, _LANES), jnp.float32),
+         pltpu.VMEM((tile, _LANES), jnp.float32),
+         pltpu.VMEM((tile, dim), jnp.float32)],
+        [t.reshape(flat) for t in (q, k, v)],
+        "causal_attention_fwd",
+    )
+    out = out.reshape(q.shape)
+    return out, (q, k, v, out, lse)
+
+
+def _stream_bwd(scale, residuals, g):
+    q, k, v, out, lse = residuals
+    batch, length, heads, dim = q.shape
+    tile = _stream_tiles(length)
+    num = length // tile
+    tiles, per_row = _stream_specs(tile, dim)
+    flat = (batch, length, heads * dim)
+    g = g.astype(q.dtype)
+    delta = (
+        (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
+        .transpose(0, 2, 1)[..., None]
+    )                                                   # (B, H, L, 1)
+    operands = [t.reshape(flat) for t in (q, k, v, g)] + [lse, delta]
+    # grid (b, h, j over keys, i over queries): queries before the
+    # diagonal stay on the diagonal's tile
+    dk, dv = _stream_call(
+        functools.partial(
+            _stream_dkv_kernel, scale=scale, tile=tile, num_q=num
+        ),
+        (batch, heads, num, num),
+        [tiles(lambda j, i: jnp.maximum(i, j)), tiles(lambda j, i: j),
+         tiles(lambda j, i: j), tiles(lambda j, i: jnp.maximum(i, j)),
+         per_row(lambda j, i: jnp.maximum(i, j)),
+         per_row(lambda j, i: jnp.maximum(i, j))],
+        [tiles(lambda j, i: j), tiles(lambda j, i: j)],
+        [(flat, k.dtype), (flat, v.dtype)],
+        [pltpu.VMEM((tile, dim), jnp.float32),
+         pltpu.VMEM((tile, dim), jnp.float32)],
+        operands, "causal_attention_dkv",
+    )
+    (dq,) = _stream_call(
+        functools.partial(
+            _stream_dq_kernel, scale=scale, tile=tile, num_k=num
+        ),
+        (batch, heads, num, num),
+        [tiles(lambda i, j: i), tiles(lambda i, j: jnp.minimum(i, j)),
+         tiles(lambda i, j: jnp.minimum(i, j)), tiles(lambda i, j: i),
+         per_row(lambda i, j: i), per_row(lambda i, j: i)],
+        [tiles(lambda i, j: i)],
+        [(flat, q.dtype)],
+        [pltpu.VMEM((tile, dim), jnp.float32)],
+        operands, "causal_attention_dq",
+    )
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+_stream.defvjp(_stream_fwd, _stream_bwd)
+
+
+def causal_attention(q, k, v, scale: Optional[float] = None):
+    """Causal self-attention for a decoder's train step, (B, L, H, D) ->
+    (B, L, H, Dv), at any length and head width: the one entry a model
+    calls.  The streaming Pallas kernel where the shapes tile
+    (`stream_shapes_ok`), the blocked lax form elsewhere (the same
+    mathematics, one row of query tiles at a time)."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    from elasticdl_tpu.parallel.mesh import in_export_mode
+
+    if stream_shapes_ok(q.shape, k.shape, v.shape) and not in_export_mode():
+        return _stream(q, k, v, float(scale))
+    return blocked_causal_attention(q, k, v, scale)

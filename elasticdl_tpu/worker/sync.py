@@ -116,6 +116,37 @@ class ModelOwner:
             self._maybe_checkpoint()
             return loss
 
+    def fetch_loss(self, loss):
+        """(the loss as a float, {path: float} of the last step's
+        STEP_METRICS): the task's ONE device fetch.  The scalars a model
+        sows there ride in `model_state`, so they cost a task no second
+        sync and a step none at all; a model that sows none takes the
+        plain loss fetch, outside the lock as it always was."""
+        import jax
+        import numpy as np
+
+        from elasticdl_tpu.layers.moe import STEP_METRICS
+        from elasticdl_tpu.worker.trainer import run_device_serialized
+
+        sown = None
+        with self.lock:
+            if self.state is not None:
+                sown = self.state.model_state.get(STEP_METRICS)
+            if sown is not None:
+                # fetched under the lock: the next step donates the
+                # state's buffers
+                loss, sown = run_device_serialized(
+                    lambda: jax.device_get((loss, sown))
+                )
+        if sown is None:
+            return run_device_serialized(
+                lambda: float(np.asarray(loss))
+            ), {}
+        return float(loss), {
+            "/".join(str(getattr(k, "key", k)) for k in path): float(leaf)
+            for path, leaf in jax.tree_util.tree_leaves_with_path(sown)
+        }
+
     def train_batch_stack(self, batches):
         """steps_per_execution path: len(batches) steps in one dispatch
         (Trainer.train_on_batch_stack); returns the per-step losses."""

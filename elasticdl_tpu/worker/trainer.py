@@ -31,6 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from elasticdl_tpu.common import programs
 from elasticdl_tpu.common.log_utils import get_logger
+from elasticdl_tpu.layers import moe as moe_layers
 from elasticdl_tpu.layers.arena import fold_quantized_updates
 from elasticdl_tpu.parallel import mesh as mesh_lib
 
@@ -69,16 +70,34 @@ def model_has_train_kwarg(model) -> bool:
         return False
 
 
-def _sown_aux_loss(intermediates) -> jnp.ndarray:
-    """Sum every `moe_aux_loss` value sown anywhere in the module tree
-    (already scaled by its coefficient at sow time).  Zero when nothing
-    was sown — models without auxiliary objectives are unaffected."""
-    total = jnp.zeros((), jnp.float32)
-    for path, leaf in jax.tree_util.tree_leaves_with_path(intermediates):
-        names = [getattr(k, "key", str(k)) for k in path]
-        if "moe_aux_loss" in names:
-            total = total + jnp.asarray(leaf, jnp.float32)
-    return total
+# Collections a layer sows into for the TRAIN step only: mutable there,
+# read once, never part of the persistent `model_state`.  Everything in
+# AUX_LOSS is an auxiliary objective, already scaled where it was sown
+# (MoE load balancing, a multi-token-prediction loss); "intermediates"
+# is flax's own scratch collection.
+_EPHEMERAL = (moe_layers.AUX_LOSS, "intermediates")
+
+
+def _sown_aux_loss(sown) -> jnp.ndarray:
+    """Sum of every value sown into AUX_LOSS anywhere in the module
+    tree.  Zero when nothing was sown — models without auxiliary
+    objectives are unaffected."""
+    return sum(
+        (jnp.asarray(leaf, jnp.float32) for leaf in jax.tree.leaves(sown)),
+        jnp.zeros((), jnp.float32),
+    )
+
+
+def split_variables(variables):
+    """`model.init`'s variables as ({"params": ...}, model_state): the
+    optimizer sees only the former; what `init` sowed into the ephemeral
+    collections is dropped, so a step never adds init's values to its
+    own."""
+    variables = dict(variables)
+    params = {"params": variables.pop("params")}
+    for collection in _EPHEMERAL:
+        variables.pop(collection, None)
+    return params, variables
 
 
 class TrainState(struct.PyTreeNode):
@@ -180,13 +199,17 @@ class Trainer:
     def _init_state_impl(self, rng, sample_features) -> TrainState:
         mesh_lib.set_current_mesh(self.mesh)
         kwargs = {"train": False} if self._has_train_kwarg else {}
-        variables = dict(
-            self.model.init(rng, self._cast(sample_features), **kwargs)
-        )
         # Split trainable ("params") from mutable model state (e.g.
-        # batch_stats); the optimizer sees only the former.
-        params = {"params": variables.pop("params")}
-        model_state = variables
+        # batch_stats); the optimizer sees only the former.  The init is
+        # ONE program, not an op-by-op walk of the model's forward: a
+        # decoder's eager init at 16,384 tokens dispatched (and compiled)
+        # every operation of its blocks on the way to its parameters.
+        params, model_state = split_variables(programs.registered_jit(
+            "worker_init_state",
+            lambda rng, features: self.model.init(
+                rng, self._cast(features), **kwargs
+            ),
+        )(rng, jax.tree.map(np.asarray, sample_features)))
         state = TrainState(
             step=jnp.zeros((), jnp.int32),
             params=params,
@@ -207,10 +230,9 @@ class Trainer:
         features = jax.tree.map(np.asarray, sample_features)
 
         def make():
-            variables = dict(
+            params, variables = split_variables(
                 self.model.init(rng, self._cast(features), **kwargs)
             )
-            params = {"params": variables.pop("params")}
             return TrainState(
                 step=jnp.zeros((), jnp.int32),
                 params=params,
@@ -295,21 +317,25 @@ class Trainer:
         def loss_of(params, model_state, features, labels):
             variables = {**params, **model_state}
             kwargs = {"train": True} if self._has_train_kwarg else {}
-            # "intermediates" is always mutable in the TRAIN step so
-            # layer-sown auxiliary objectives (MoE load balancing) reach
-            # the loss; sown values are ephemeral and never enter the
-            # persistent model_state.
-            mutable = list(model_state.keys()) + ["intermediates"]
+            # The ephemeral collections are always mutable in the TRAIN
+            # step so layer-sown auxiliary objectives (MoE load balancing,
+            # a second prediction loss) reach the loss; they never enter
+            # the persistent model_state.  What a model keeps THERE it
+            # updates in place: a layer's own buffers, and the last
+            # step's STEP_METRICS scalars, which so ride beside the loss
+            # to the worker's one fetch a task (`step_metrics`).
+            mutable = list(model_state.keys()) + list(_EPHEMERAL)
             preds, updates = self.model.apply(
                 variables, self._cast(features), mutable=mutable, **kwargs
             )
             updates = dict(updates)
-            intermediates = updates.pop("intermediates", {})
+            sown = updates.pop(moe_layers.AUX_LOSS, {})
+            updates.pop("intermediates", None)
             new_model_state = updates if updates else model_state
             loss = jnp.asarray(
                 self.loss_fn(labels, preds.astype(jnp.float32)), jnp.float32
             )
-            loss = loss + _sown_aux_loss(intermediates)
+            loss = loss + _sown_aux_loss(sown)
             return loss, new_model_state
 
         def train_step(state: TrainState, batch):
@@ -640,10 +666,9 @@ class Trainer:
         kwargs = {"train": False} if self._has_train_kwarg else {}
 
         def make():
-            variables = dict(
+            params, variables = split_variables(
                 self.model.init(rng, warm._cast(features), **kwargs)
             )
-            params = {"params": variables.pop("params")}
             return TrainState(
                 step=jnp.zeros((), jnp.int32),
                 params=params,

@@ -50,6 +50,27 @@ _tasks_counter = metrics_lib.default_registry().counter(
     "tasks processed, by outcome",
     labelnames=("result",),
 )
+# What a routed expert layer sows into STEP_METRICS (layers/moe.py), read
+# once a task with the loss: leaf name -> gauge by layer.
+_moe_gauges = {
+    "expert_load_imbalance_ratio": metrics_lib.default_registry().gauge(
+        "worker_moe_expert_load_imbalance_ratio",
+        "largest router load over the mean load, over all the router's "
+        "outputs, last step of the task",
+        labelnames=("layer",),
+    ),
+    "routed_here_ratio": metrics_lib.default_registry().gauge(
+        "worker_moe_routed_here_ratio",
+        "routing slots that chose an expert held here / tokens x top_k, "
+        "last step of the task",
+        labelnames=("layer",),
+    ),
+}
+_moe_dropped = metrics_lib.default_registry().counter(
+    "worker_moe_dropped_tokens_total",
+    "slots routed to a held expert that got no row (sorted dispatch has "
+    "a worst-case buffer: stays 0)",
+)
 # Step-phase attribution (ISSUE 5) and spans (ISSUE 24): the process's
 # one PhaseTimer, shared by the threaded and SPMD loops.  Module-level
 # for the same __new__ reason as the counters above.
@@ -507,18 +528,22 @@ class Worker:
             with _phase_timer.phase("task_sync") as sync:
                 # serialized: a device->host fetch racing another
                 # thread's step execution corrupts the CPU backend
-                loss_value = run_device_serialized(
-                    lambda: float(np.asarray(loss))
-                )
+                loss_value, sown = self._owner.fetch_loss(loss)
             self.step_rate.task_synced(sync.end, steps)
             _steps_gauge.set(self.step_rate.steps_per_sec)
-            self._summary.scalars(
-                {
-                    "train/loss": loss_value,
-                    "train/steps_per_sec": self.step_rate.steps_per_sec,
-                },
-                step=self._owner.step,
-            )
+            scalars = {
+                "train/loss": loss_value,
+                "train/steps_per_sec": self.step_rate.steps_per_sec,
+            }
+            for path, value in sown.items():
+                layer, _, name = path.rpartition("/")
+                if name in _moe_gauges:
+                    _moe_gauges[name].labels(layer=layer).set(value)
+                elif name == "dropped_tokens":
+                    _moe_dropped.inc(value)
+                else:
+                    scalars["train/" + path] = value
+            self._summary.scalars(scalars, step=self._owner.step)
         return records
 
     def _evaluate_task(self, task: pb.Task) -> int:

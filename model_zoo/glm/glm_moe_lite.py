@@ -1,0 +1,398 @@
+"""GLM-4.7-Flash (`model_type: glm4_moe_lite`) as a causal language model
+on the train path: multi-head latent attention, one leading dense SwiGLU
+layer, then layers of 64 sigmoid-routed experts (top-4, `noaux_tc`
+selection bias, renormalised weights times 1.8) beside one shared expert,
+and one multi-token-prediction module (structure from DeepSeek-V3,
+arXiv:2412.19437 section 2.2, which this family follows).
+
+    h = x + MLA(RMSNorm(x));  y = h + FFN(RMSNorm(h))
+    L = CE(head(RMSNorm(h_L)), x_{t+1}) + lambda * CE(head(norm(MTP)), x_{t+2})
+
+The layer equations are written out in `benchmarks/reference/
+glm_moe_lite.py`, the plain float32 reference this model is held to leaf
+by leaf (tests/test_glm_moe_lite.py).
+
+What makes it a citizen of THIS system rather than a port:
+
+- the expert layer is told which experts it holds (`held_experts`),
+  routes over all of them and computes its own experts' part
+  (`layers/moe.py: RoutedExperts`): one chip's share of an
+  expert-parallel deployment, and the layer expert parallelism over the
+  mesh `expert` axis needs anyway;
+- targets are the input ids shifted, taken INSIDE the model, and the
+  model hands back per-position negative log-likelihoods: the logits
+  (16,384 tokens x 19,360 rows in float32 are 1.27 GB, twice with MTP)
+  never leave a cross-entropy taken in blocks of tokens;
+- every block rematerialises in the backward (`remat`), the MTP loss is a
+  sown auxiliary objective, and router load and the two losses ride in
+  STEP_METRICS to the worker's one fetch a task.
+
+Record format: seq_len int32 token ids | 1 label byte (ignored), the
+fixed-width record `model_zoo/bert` reads.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+
+from elasticdl_tpu.layers.embedding import (
+    DistributedEmbedding,
+    embedding_param_sharding,
+)
+from elasticdl_tpu.layers.moe import (
+    AUX_LOSS,
+    RoutedExperts,
+    moe_param_sharding,
+    sow_step_metric,
+)
+from elasticdl_tpu.ops.flash_attention import causal_attention
+from model_zoo.bert.bert_finetune import feed, feed_bulk  # noqa: F401
+
+# Tokens whose logits exist at once in the cross-entropy.
+CE_BLOCK = 2048
+
+
+def rms_norm(x, scale, eps: float):
+    """Statistics in float32 whatever `x` is; float32 out."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(
+        jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps
+    ) * scale
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-5
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        return rms_norm(x, scale, self.eps).astype(self.dtype)
+
+
+def rotary(x, theta: float):
+    """Rotary embedding over the last axis of (B, L, H, R), position =
+    index along L, the HALVES pairing: column i turns with column
+    i + R/2 (the row of the catalog does not say which pairing the
+    checkpoint uses; with seeded weights the two differ by a permutation
+    of columns).  float32 inside."""
+    length, width = x.shape[1], x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
+    angle = jnp.arange(length, dtype=jnp.float32)[:, None] * inv_freq[None]
+    cos, sin = jnp.cos(angle)[None, :, None], jnp.sin(angle)[None, :, None]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    ).astype(x.dtype)
+
+
+def dense(features: int, name: str, dtype):
+    return nn.Dense(features, use_bias=False, name=name, dtype=dtype)
+
+
+class MLA(nn.Module):
+    """Multi-head latent attention for TRAINING: keys and values are
+    materialised per head from the latent (no cache, no absorbed form);
+    the rotary part of the key is ONE head shared by all."""
+
+    hidden: int
+    heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+    eps: float
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        batch, length, _ = x.shape
+        heads, nope, rope = (
+            self.heads, self.qk_nope_head_dim, self.qk_rope_head_dim
+        )
+        with jax.named_scope("glm/mla/proj"):
+            cq = RMSNorm(self.eps, self.dtype, name="q_a_norm")(
+                dense(self.q_lora_rank, "q_a", self.dtype)(x)
+            )
+            q = dense(heads * (nope + rope), "q_b", self.dtype)(cq).reshape(
+                batch, length, heads, nope + rope
+            )
+            ckv, k_rope = jnp.split(
+                dense(self.kv_lora_rank + rope, "kv_a", self.dtype)(x),
+                [self.kv_lora_rank], axis=-1,
+            )
+            ckv = RMSNorm(self.eps, self.dtype, name="kv_a_norm")(ckv)
+            k_nope, v = jnp.split(
+                dense(heads * (nope + self.v_head_dim), "kv_b", self.dtype)(
+                    ckv
+                ).reshape(batch, length, heads, nope + self.v_head_dim),
+                [nope], axis=-1,
+            )
+            q_nope, q_rope = jnp.split(q, [nope], axis=-1)
+            q = jnp.concatenate(
+                [q_nope, rotary(q_rope, self.rope_theta)], axis=-1
+            )
+            k_rope = rotary(k_rope[:, :, None, :], self.rope_theta)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(
+                    k_rope, (batch, length, heads, rope)
+                )], axis=-1,
+            )
+        with jax.named_scope("glm/mla/core"):
+            out = causal_attention(q, k, v, scale=(nope + rope) ** -0.5)
+        with jax.named_scope("glm/mla/out"):
+            return dense(self.hidden, "o", self.dtype)(
+                out.reshape(batch, length, heads * self.v_head_dim)
+            )
+
+
+class SwiGLU(nn.Module):
+    """(silu(x Wg) * (x Wu)) Wd, gate and up in one kernel."""
+
+    hidden: int
+    width: int
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        gate, up = jnp.split(
+            dense(2 * self.width, "gate_up", self.dtype)(x), 2, axis=-1
+        )
+        return dense(self.hidden, "down", self.dtype)(nn.silu(gate) * up)
+
+
+class MoEFFN(nn.Module):
+    """The shared expert, computed by every holder alike, plus this
+    holder's part of the routed experts."""
+
+    hidden: int
+    num_experts: int
+    top_k: int
+    expert_width: int
+    shared_experts: int
+    held_experts: Optional[Tuple[int, int]]
+    routed_scaling: float
+    bias_update_rate: float
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        with jax.named_scope("glm/moe"):
+            routed = RoutedExperts(
+                num_experts=self.num_experts, top_k=self.top_k,
+                ffn_dim=self.expert_width, held_experts=self.held_experts,
+                routed_scaling=self.routed_scaling,
+                bias_update_rate=self.bias_update_rate, dtype=self.dtype,
+                name="routed",
+            )(x)
+            with jax.named_scope("shared"):
+                shared = SwiGLU(
+                    self.hidden, self.shared_experts * self.expert_width,
+                    self.dtype, name="shared",
+                )(x)
+            return (routed + shared.astype(jnp.float32)).astype(self.dtype)
+
+
+class Block(nn.Module):
+    """One pre-norm decoder block; `moe` picks its feed-forward."""
+
+    config: "GLMConfig"
+    moe: bool
+
+    @nn.compact
+    def __call__(self, x):
+        c = self.config
+        x = x + MLA(
+            c.hidden, c.heads, c.q_lora_rank, c.kv_lora_rank,
+            c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim,
+            c.rope_theta, c.eps, c.dtype, name="mla",
+        )(RMSNorm(c.eps, c.dtype, name="attn_norm")(x))
+        y = RMSNorm(c.eps, c.dtype, name="ffn_norm")(x)
+        if self.moe:
+            y = MoEFFN(
+                c.hidden, c.num_experts, c.top_k, c.expert_width,
+                c.shared_experts, c.held_experts, c.routed_scaling,
+                c.bias_update_rate, c.dtype, name="moe",
+            )(y)
+        else:
+            with jax.named_scope("glm/dense_ffn"):
+                y = SwiGLU(c.hidden, c.dense_width, c.dtype, name="mlp")(y)
+        return x + y
+
+
+@dataclasses.dataclass(frozen=True)
+class GLMConfig:
+    """Every size of the model (`custom_model` documents them)."""
+
+    hidden: int
+    num_layers: int
+    dense_layers: int
+    heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    dense_width: int
+    expert_width: int
+    num_experts: int
+    top_k: int
+    shared_experts: int
+    held_experts: Optional[Tuple[int, int]]
+    routed_scaling: float
+    bias_update_rate: float
+    vocab_size: int
+    mtp_layers: int
+    mtp_loss_weight: float
+    rope_theta: float
+    eps: float
+    dtype: Any
+    remat: bool
+
+
+def blocked_nll(h, head_kernel, targets, dtype, block: int = CE_BLOCK):
+    """(tokens,) float32 negative log-likelihood of `targets` under
+    softmax(h @ head_kernel), `block` tokens' logits at a time, each block
+    rebuilt in the backward: nothing (tokens, vocab)-shaped is ever held.
+    The kernel is cast inside the block so that its gradient sums over
+    the blocks in its own float32."""
+    tokens, hidden = h.shape
+    if tokens % block:
+        block = tokens
+
+    @jax.checkpoint
+    def one(args):
+        h_block, t_block = args
+        logits = jnp.dot(
+            h_block.astype(dtype), head_kernel.astype(dtype),
+            preferred_element_type=jnp.float32,
+        )
+        picked = jnp.take_along_axis(logits, t_block[:, None], axis=1)[:, 0]
+        return jax.nn.logsumexp(logits, axis=-1) - picked
+
+    return jax.lax.map(one, (
+        h.reshape(tokens // block, block, hidden),
+        targets.reshape(tokens // block, block),
+    )).reshape(tokens)
+
+
+class GLMMoELite(nn.Module):
+    config: GLMConfig
+
+    @nn.compact
+    def __call__(self, features):
+        c = self.config
+        ids = features["input_ids"].astype(jnp.int32)        # (B, L)
+        batch, length = ids.shape
+        embed = DistributedEmbedding(
+            c.vocab_size, c.hidden, hash_input=False, name="token_embedding"
+        )
+        block_cls = nn.remat(Block) if c.remat else Block
+        x = embed(ids).astype(c.dtype)
+        for i in range(c.num_layers):
+            x = block_cls(c, moe=i >= c.dense_layers, name=f"layer_{i}")(x)
+        head = self.param(
+            "lm_head_kernel", nn.initializers.lecun_normal(),
+            (c.hidden, c.vocab_size),
+        )
+
+        def nll(h, shift):
+            """Per-position loss against the ids `shift` places on; the
+            positions with no such id read 0."""
+            targets = jnp.roll(ids, -shift, axis=1)
+            with jax.named_scope("glm/head_ce"):
+                out = blocked_nll(
+                    h.reshape(batch * length, c.hidden), head,
+                    targets.reshape(-1), c.dtype,
+                ).reshape(batch, length)
+            return out[:, :length - shift]
+
+        main = nll(RMSNorm(c.eps, c.dtype, name="final_norm")(x), 1)
+        sow_step_metric(self, "main_loss", main.mean())
+        if c.mtp_layers:
+            with jax.named_scope("glm/mtp"):
+                # position t joins h_t with the embedding of x_{t+1} and
+                # predicts x_{t+2}; the last position's partner wraps
+                # round, is causal-masked from every other and has no
+                # target
+                joined = jnp.concatenate([
+                    RMSNorm(c.eps, c.dtype, name="mtp_h_norm")(x),
+                    RMSNorm(c.eps, c.dtype, name="mtp_e_norm")(
+                        embed(jnp.roll(ids, -1, axis=1))
+                    ),
+                ], axis=-1)
+                y = dense(c.hidden, "mtp_eh_proj", c.dtype)(joined)
+                y = block_cls(c, moe=True, name="mtp_block")(y)
+                y = RMSNorm(c.eps, c.dtype, name="mtp_final_norm")(y)
+            mtp_loss = nll(y, 2).mean()
+            sow_step_metric(self, "mtp_loss", mtp_loss)
+            self.sow(AUX_LOSS, "mtp_loss", c.mtp_loss_weight * mtp_loss)
+        return main
+
+
+def custom_model(
+    hidden: int = 2048, num_layers: int = 5, dense_layers: int = 1,
+    heads: int = 20, q_lora_rank: int = 768, kv_lora_rank: int = 512,
+    qk_nope_head_dim: int = 192, qk_rope_head_dim: int = 64,
+    v_head_dim: int = 256, dense_width: int = 10240,
+    expert_width: int = 1536, num_experts: int = 64, top_k: int = 4,
+    shared_experts: int = 1, held_experts=None,
+    routed_scaling: float = 1.8, bias_update_rate: float = 0.0,
+    vocab_size: int = 19360, mtp_layers: int = 1,
+    mtp_loss_weight: float = 0.3, rope_theta: float = 1e6,
+    eps: float = 1e-5, bf16: bool = False, remat: bool = False,
+):
+    """`held_experts` is (first, count) of the routed experts whose
+    weights live in this process; None holds all `num_experts`."""
+    if mtp_layers not in (0, 1):
+        raise ValueError("this family has one MTP module or none")
+    return GLMMoELite(GLMConfig(
+        hidden=hidden, num_layers=num_layers, dense_layers=dense_layers,
+        heads=heads, q_lora_rank=q_lora_rank, kv_lora_rank=kv_lora_rank,
+        qk_nope_head_dim=qk_nope_head_dim,
+        qk_rope_head_dim=qk_rope_head_dim, v_head_dim=v_head_dim,
+        dense_width=dense_width, expert_width=expert_width,
+        num_experts=num_experts, top_k=top_k, shared_experts=shared_experts,
+        held_experts=None if held_experts is None else tuple(held_experts),
+        routed_scaling=routed_scaling, bias_update_rate=bias_update_rate,
+        vocab_size=vocab_size, mtp_layers=mtp_layers,
+        mtp_loss_weight=mtp_loss_weight, rope_theta=rope_theta, eps=eps,
+        dtype=jnp.bfloat16 if bf16 else jnp.float32, remat=remat,
+    ))
+
+
+def loss(labels, predictions):
+    """`predictions` are the model's per-position negative
+    log-likelihoods of the next token; the record's label byte is not
+    used."""
+    return predictions.mean()
+
+
+def optimizer(lr: float = 1e-4):
+    return optax.adam(lr)
+
+
+def eval_metrics_fn():
+    return {
+        "perplexity": lambda labels, predictions: float(
+            np.exp(np.mean(predictions))
+        ),
+    }
+
+
+def param_sharding(path, value):
+    """Expert stacks over `expert`, the token embedding over `model`;
+    everything else replicated."""
+    spec = moe_param_sharding(path, value)
+    if spec is not None:
+        return spec
+    return embedding_param_sharding(path, value)

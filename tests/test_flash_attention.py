@@ -20,9 +20,19 @@ def _qkv(batch=2, length=256, heads=4, dim=32, seed=0):
     )
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_matches_reference_forward(causal):
-    q, k, v = _qkv()
+# (causal, q/k/v shape overrides): the resident kernel's shapes, and the
+# streaming kernel's (causal, head width 256, three tiles of 128)
+CASES = [
+    pytest.param(False, {}, id="full"),
+    pytest.param(True, {}, id="causal"),
+    pytest.param(True, dict(batch=2, length=384, heads=2, dim=256),
+                 id="causal-width256"),
+]
+
+
+@pytest.mark.parametrize("causal, shape", CASES)
+def test_matches_reference_forward(causal, shape):
+    q, k, v = _qkv(**shape)
     out = flash_attention(q, k, v, causal=causal)
     ref = full_attention_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
@@ -35,9 +45,9 @@ def test_short_sequence_single_tile():
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_gradients_match_reference(causal):
-    q, k, v = _qkv(batch=1, length=128, heads=2, dim=16)
+@pytest.mark.parametrize("causal, shape", CASES)
+def test_gradients_match_reference(causal, shape):
+    q, k, v = _qkv(**(shape or dict(batch=1, length=128, heads=2, dim=16)))
 
     def loss_flash(q, k, v):
         return (flash_attention(q, k, v, causal=causal) ** 2).sum()
@@ -112,4 +122,42 @@ def test_flash_shapes_ok_bounds():
     assert not ok((16, 2048, 12, 64), (16, 2048, 12, 64))  # 1.57M
     assert not ok((8, 520, 4, 64), (8, 520, 4, 64))        # L % 128
     assert not ok((8, 512, 4, 256), (8, 512, 4, 256))      # D > 128
+    # ...which CAUSAL self-attention takes on the streaming kernel, at
+    # any length of whole tiles (no K/V residency ceiling)
+    wide = (4, 4096, 20, 256)
+    assert ok(wide, wide, causal=True) and not ok(wide, wide)
+    assert not ok((4, 4000, 20, 256), (4, 4000, 20, 256), causal=True)
+    assert not ok((4, 4096, 20, 192), (4, 4096, 20, 192), causal=True)
     assert ok((8, 64, 4, 64), (8, 64, 4, 64))              # sub-128 L
+
+
+@pytest.mark.parametrize("dims", [(16, 16), (6 + 4, 10)])
+def test_blocked_causal_attention_any_width(dims):
+    """The lax form `causal_attention` falls back to where the shapes do
+    not tile: one row of query tiles at a time, v of another width."""
+    from elasticdl_tpu.ops.flash_attention import (
+        blocked_causal_attention,
+        causal_attention,
+    )
+
+    rng = np.random.RandomState(3)
+    q, k = (jnp.asarray(rng.randn(2, 96, 3, dims[0]).astype(np.float32))
+            for _ in range(2))
+    v = jnp.asarray(rng.randn(2, 96, 3, dims[1]).astype(np.float32))
+
+    def grads(fn):
+        return jax.grad(
+            lambda q, k, v: (fn(q, k, v) ** 2).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    want = grads(lambda q, k, v: full_attention_reference(
+        q, k, v, causal=True
+    ))
+    for fn in (causal_attention,
+               lambda q, k, v: blocked_causal_attention(q, k, v, tile=40)):
+        np.testing.assert_allclose(
+            fn(q, k, v), full_attention_reference(q, k, v, causal=True),
+            rtol=2e-4, atol=2e-5,
+        )
+        for got, ref in zip(grads(fn), want):
+            np.testing.assert_allclose(got, ref, rtol=2e-3, atol=2e-4)

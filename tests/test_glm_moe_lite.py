@@ -1,0 +1,389 @@
+"""The GLM-4.7-Flash decoder (model_zoo/glm/glm_moe_lite.py) and its
+routed expert layer (layers/moe.py: RoutedExperts) at tiny widths on the
+CPU, seeded weights: against the plain float32 reference leaf by leaf,
+the selection bias and its update, the shares of an expert-parallel
+deployment adding up to the uncut layer, no dropped token at any load,
+one compile across loads, and a two-task job through the CLI."""
+
+import os
+import threading
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import datagen, trees
+from benchmarks.reference import glm_moe_lite as reference
+from elasticdl_tpu.layers.moe import (
+    AUX_LOSS,
+    ROUTER_STATE,
+    STEP_METRICS,
+    RoutedExperts,
+)
+from model_zoo.glm import glm_moe_lite as zoo
+
+CONFIG = dict(
+    hidden_size=32, num_hidden_layers=3, first_k_dense_replace=1,
+    num_attention_heads=2, q_lora_rank=12, kv_lora_rank=8,
+    qk_nope_head_dim=6, qk_rope_head_dim=4, v_head_dim=10,
+    intermediate_size=48, moe_intermediate_size=16,
+    n_routed_experts_published=8, num_experts_per_tok=2,
+    n_shared_experts=1, held_experts=[2, 3], routed_scaling_factor=1.8,
+    vocab_size=50, num_nextn_predict_layers=1, mtp_loss_weight=0.3,
+    rope_theta=1e6, rms_norm_eps=1e-5, use_bf16=True,
+)
+MUTABLE = [AUX_LOSS, STEP_METRICS, ROUTER_STATE]
+
+
+def model_of(config, **overrides):
+    sizes = dict(
+        hidden=config["hidden_size"], num_layers=config["num_hidden_layers"],
+        dense_layers=config["first_k_dense_replace"],
+        heads=config["num_attention_heads"],
+        q_lora_rank=config["q_lora_rank"],
+        kv_lora_rank=config["kv_lora_rank"],
+        qk_nope_head_dim=config["qk_nope_head_dim"],
+        qk_rope_head_dim=config["qk_rope_head_dim"],
+        v_head_dim=config["v_head_dim"],
+        dense_width=config["intermediate_size"],
+        expert_width=config["moe_intermediate_size"],
+        num_experts=config["n_routed_experts_published"],
+        top_k=config["num_experts_per_tok"],
+        held_experts=config["held_experts"],
+        vocab_size=config["vocab_size"],
+        mtp_layers=config["num_nextn_predict_layers"], remat=True,
+    )
+    sizes.update(overrides)
+    return zoo.custom_model(**sizes)
+
+
+def ids_of(rows, length=16, seed=0):
+    return np.random.RandomState(seed).randint(
+        0, CONFIG["vocab_size"], (rows, length)
+    ).astype(np.int32)
+
+
+def loss_and_grads(model, variables, ids):
+    """The objective the Trainer builds: the mean of the model's
+    per-position losses plus everything sown into AUX_LOSS."""
+    state = {k: v for k, v in variables.items() if k not in
+             ("params", AUX_LOSS)}
+
+    def loss_of(params):
+        out, sown = model.apply(
+            {"params": params, **state}, {"input_ids": ids}, mutable=MUTABLE
+        )
+        return zoo.loss(None, out.astype(jnp.float32)) + sum(
+            jax.tree.leaves(sown[AUX_LOSS])
+        )
+
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.value_and_grad(loss_of)(variables["params"])
+    return float(loss), {
+        k: np.asarray(v, np.float32) for k, v in trees.flat(grads).items()
+    }
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    ids = ids_of(8)
+    model = model_of(CONFIG)
+    variables = model.init(jax.random.PRNGKey(0), {"input_ids": ids})
+    flat = {
+        k: np.asarray(v) for k, v in trees.flat(variables["params"]).items()
+    }
+    want_loss, want = reference.loss_and_grads(
+        flat, {"input_ids": ids}, None, CONFIG
+    )
+    return types.SimpleNamespace(
+        ids=ids, variables=variables, flat=flat, want_loss=want_loss,
+        want={k: np.asarray(v) for k, v in want.items()},
+    )
+
+
+def test_float32_matches_reference_leaf_by_leaf(seeded):
+    loss, got = loss_and_grads(model_of(CONFIG), seeded.variables, seeded.ids)
+    assert abs(loss - seeded.want_loss) < 1e-4 * abs(seeded.want_loss)
+    assert set(got) == set(seeded.want) and len(got) == 60
+    for name, want in seeded.want.items():
+        error = np.linalg.norm(got[name] - want) / np.linalg.norm(want)
+        assert error < 1e-4, (name, error)
+
+
+def test_bfloat16_inside_the_twins_rule(seeded):
+    """The model computing in bfloat16 is held as the benchmark holds a
+    cell that states it: to the reference's own bfloat16 twin, leaf by
+    leaf and on the angle (`check_gradient`), where the float8 control
+    in the step's place fails."""
+    from benchmarks.drivers import train
+
+    held = types.SimpleNamespace(
+        **{k: getattr(reference, k) for k in dir(reference)
+           if not k.startswith("__")},
+        STATED_RATIO=reference.TWIN_RATIO,
+    )
+    features = {"input_ids": seeded.ids}
+    labels = np.zeros(len(seeded.ids), np.int32)
+    _, got = loss_and_grads(
+        model_of(CONFIG, bf16=True), seeded.variables, seeded.ids
+    )
+    check = train.check_gradient(
+        held, seeded.flat, features, labels, CONFIG, seeded.want, got
+    )
+    assert check["ok"], sorted(
+        check["shares"].items(), key=lambda kv: -kv[1]
+    )[:4]
+    _, control = reference.loss_and_grads(
+        seeded.flat, features, labels, CONFIG, tower="float8_e4m3fn"
+    )
+    control = {k: np.asarray(v, np.float32) for k, v in control.items()}
+    assert not train.check_gradient(
+        held, seeded.flat, features, labels, CONFIG, seeded.want, control
+    )["ok"]
+
+
+# ---- the routed layer -----------------------------------------------------
+
+
+def routed_layer(held=None, experts=8, top_k=2, rate=0.0):
+    return RoutedExperts(
+        num_experts=experts, top_k=top_k, ffn_dim=16, held_experts=held,
+        routed_scaling=1.8, bias_update_rate=rate,
+    )
+
+
+def tokens_of(rows=64, hidden=32, seed=1):
+    return jnp.asarray(
+        np.random.RandomState(seed).randn(rows, hidden).astype(np.float32)
+    )
+
+
+def routing_of(variables, x, top_k=2):
+    """(chosen experts, their weights) as the layer's equations give
+    them, in numpy."""
+    p = variables["params"]
+    scores = 1.0 / (1.0 + np.exp(-np.asarray(x) @ np.asarray(
+        p["router_kernel"]
+    )))
+    bias = np.asarray(
+        variables[ROUTER_STATE]["e_score_correction_bias"]
+    )
+    chosen = np.argsort(-(scores + bias), axis=1)[:, :top_k]
+    picked = np.take_along_axis(scores, chosen, axis=1)
+    return chosen, 1.8 * picked / picked.sum(axis=1, keepdims=True)
+
+
+def dense_routed(variables, x, chosen, weights, first=0):
+    """Every held expert over the tokens that chose it, no dispatch."""
+    p = variables["params"]
+    x = np.asarray(x)
+    out = np.zeros_like(x)
+    for e in range(p["expert_w_down"].shape[0]):
+        gate, up = np.split(x @ np.asarray(p["expert_w_gate_up"][e]), 2, -1)
+        y = (gate / (1 + np.exp(-gate)) * up) @ np.asarray(
+            p["expert_w_down"][e]
+        )
+        weight = np.where(chosen == first + e, weights, 0.0).sum(axis=1)
+        out += weight[:, None] * y
+    return out
+
+
+def test_selection_bias_picks_and_does_not_weigh():
+    layer, x = routed_layer(), tokens_of()
+    variables = layer.init(jax.random.PRNGKey(0), x)
+    bias = np.zeros(8, np.float32)
+    bias[5] = 10.0                      # expert 5 wins a slot everywhere
+    biased = {
+        **variables,
+        ROUTER_STATE: {"e_score_correction_bias": jnp.asarray(bias)},
+    }
+    plain_chosen, _ = routing_of(variables, x)
+    chosen, weights = routing_of(biased, x)
+    assert (chosen == 5).any(axis=1).all()
+    assert not (plain_chosen == 5).any(axis=1).all()
+    # the weights are the UNBIASED scores of the chosen, renormalised:
+    # the layer's output equals the dense form built from them
+    with jax.default_matmul_precision("highest"):
+        out = layer.apply(biased, x)
+    np.testing.assert_allclose(
+        out, dense_routed(biased, x, chosen, weights), rtol=2e-4, atol=2e-5
+    )
+
+
+def test_bias_update_moves_toward_the_mean_load():
+    layer, x = routed_layer(rate=0.01), tokens_of()
+    variables = layer.init(jax.random.PRNGKey(0), x)
+    chosen, _ = routing_of(variables, x)
+    loads = np.bincount(chosen.reshape(-1), minlength=8)
+    _, updated = layer.apply(variables, x, mutable=[ROUTER_STATE,
+                                                    STEP_METRICS])
+    bias = np.asarray(updated[ROUTER_STATE]["e_score_correction_bias"])
+    np.testing.assert_allclose(
+        bias, 0.01 * np.sign(loads.mean() - loads), atol=1e-7
+    )
+    assert (bias[loads > loads.mean()] < 0).all()
+    assert (bias[loads < loads.mean()] > 0).all()
+    # rate 0 (the benchmark's configuration) leaves the buffer alone
+    _, kept = routed_layer().apply(
+        variables, x, mutable=[ROUTER_STATE, STEP_METRICS]
+    )
+    assert not np.asarray(
+        kept[ROUTER_STATE]["e_score_correction_bias"]
+    ).any()
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """8 experts over 4 shares of 2: the routed parts of all shares plus
+    the shared expert counted ONCE equal the uncut reference's layer."""
+    config = dict(CONFIG, held_experts=[0, 8])
+    sizes = reference.sizes_of(config, None)
+    x = tokens_of(rows=48)
+    whole = zoo.MoEFFN(
+        32, 8, 2, 16, 1, None, 1.8, 0.0, name=None
+    )
+    variables = whole.init(jax.random.PRNGKey(3), x)
+    p = variables["params"]
+    with jax.default_matmul_precision("highest"):
+        want = reference.routed(x, p["routed"], sizes, lambda t: t) + (
+            reference.swiglu(x, p["shared"], lambda t: t)
+        )
+        total = np.zeros_like(np.asarray(want))
+        for share in range(4):
+            first = 2 * share
+            held = {
+                "router_kernel": p["routed"]["router_kernel"],
+                "expert_w_gate_up":
+                    p["routed"]["expert_w_gate_up"][first:first + 2],
+                "expert_w_down":
+                    p["routed"]["expert_w_down"][first:first + 2],
+            }
+            part = routed_layer(held=(first, 2)).apply(
+                {"params": held, ROUTER_STATE: variables[ROUTER_STATE][
+                    "routed"]}, x
+            )
+            total += np.asarray(part)
+        total += np.asarray(zoo.SwiGLU(32, 16).apply(
+            {"params": p["shared"]}, x
+        ))
+    np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-5)
+
+
+@pytest.mark.parametrize("held, here", [((0, 8), 1.0), ((8, 8), 0.0)])
+def test_no_token_dropped_at_either_extreme(held, here):
+    """A router of 16 outputs whose top 2 always fall in experts 0-7 (a
+    large selection bias): a holder of 0-7 takes EVERY slot (the
+    worst-case buffer is full), a holder of 8-15 takes none."""
+    layer = routed_layer(held=held, experts=16)
+    x = tokens_of()
+    variables = layer.init(jax.random.PRNGKey(0), x)
+    bias = np.where(np.arange(16) < 8, 10.0, 0.0).astype(np.float32)
+    variables = {
+        **variables,
+        ROUTER_STATE: {"e_score_correction_bias": jnp.asarray(bias)},
+    }
+    with jax.default_matmul_precision("highest"):
+        out, sown = layer.apply(variables, x, mutable=[STEP_METRICS])
+    metrics = sown[STEP_METRICS]
+    assert float(metrics["dropped_tokens"]) == 0.0
+    assert float(metrics["routed_here_ratio"]) == here
+    chosen, weights = routing_of(variables, x)
+    np.testing.assert_allclose(
+        out, dense_routed(variables, x, chosen, weights, first=held[0]),
+        rtol=2e-4, atol=2e-5,
+    )
+    assert bool(np.abs(np.asarray(out)).sum() > 0) == bool(here)
+
+
+def test_one_compile_across_loads():
+    layer = routed_layer(held=(0, 4))
+    x = tokens_of()
+    variables = layer.init(jax.random.PRNGKey(0), x)
+    traces = []
+
+    @jax.jit
+    def run(variables, x):
+        traces.append(1)
+        return layer.apply(variables, x, mutable=[STEP_METRICS])
+
+    shares = set()
+    for seed in range(4):
+        _, sown = run(variables, tokens_of(seed=seed) * (1 + seed))
+        shares.add(float(sown[STEP_METRICS]["routed_here_ratio"]))
+    assert len(traces) == 1 and len(shares) > 1
+
+
+# ---- through the system ---------------------------------------------------
+
+
+def test_trainer_adds_the_mtp_loss_and_carries_step_metrics(seeded):
+    from elasticdl_tpu.worker.sync import ModelOwner
+    from elasticdl_tpu.worker.trainer import Trainer
+
+    trainer = Trainer(
+        model=model_of(CONFIG), optimizer=zoo.optimizer(1e-3),
+        loss_fn=zoo.loss,
+    )
+    batch = {"features": {"input_ids": seeded.ids},
+             "labels": np.zeros(len(seeded.ids), np.int32)}
+    state = trainer.init_state(jax.random.PRNGKey(0), batch["features"])
+    assert AUX_LOSS not in state.model_state
+    state, loss = trainer.train_on_batch(state, batch)
+    sown = state.model_state[STEP_METRICS]
+    assert float(loss) == pytest.approx(
+        float(sown["main_loss"]) + 0.3 * float(sown["mtp_loss"]), rel=1e-5
+    )
+    assert float(loss) == pytest.approx(seeded.want_loss, rel=1e-3)
+    owner = ModelOwner.__new__(ModelOwner)
+    owner.state, owner.lock = state, threading.Lock()
+    value, metrics = owner.fetch_loss(loss)
+    assert value == pytest.approx(float(loss))
+    assert metrics["layer_1/moe/routed/dropped_tokens"] == 0.0
+    assert 0.0 < metrics["layer_1/moe/routed/routed_here_ratio"] < 1.0
+    assert metrics["mtp_block/moe/routed/expert_load_imbalance_ratio"] >= 1.0
+
+
+def test_cli_job_of_two_tasks_with_a_falling_loss(tmp_path):
+    from elasticdl_tpu.client.main import main as cli_main
+    from elasticdl_tpu.common import metrics as metrics_lib
+    from elasticdl_tpu.worker.worker import Worker
+
+    path = str(tmp_path / "train.tfrecord")
+    datagen.write_task_file(
+        path, 7, {"format": "tokens", "seq_len": 16, "vocab_size": 50},
+        64, 2,
+    )
+    workers = []
+    init = Worker.__init__
+
+    def recording_init(self, *args, **kwargs):
+        init(self, *args, **kwargs)
+        workers.append(self)
+
+    Worker.__init__ = recording_init
+    try:
+        rc = cli_main([
+            "train", "--model_zoo",
+            os.path.join(os.path.dirname(__file__), "..", "model_zoo"),
+            "--model_def", "glm.glm_moe_lite.custom_model",
+            "--model_params",
+            "hidden=32;num_layers=3;heads=2;q_lora_rank=12;kv_lora_rank=8;"
+            "qk_nope_head_dim=6;qk_rope_head_dim=4;v_head_dim=10;"
+            "dense_width=48;expert_width=16;num_experts=8;top_k=2;"
+            "held_experts=(0,4);vocab_size=50;remat=True;lr=0.01",
+            "--distribution_strategy", "Local", "--training_data", path,
+            "--minibatch_size", "8", "--records_per_task", "64",
+            "--num_epochs", "1",
+        ])
+    finally:
+        Worker.__init__ = init
+    assert rc == 0
+    losses = [float(x) for x in workers[0].losses]
+    assert len(losses) == 16                      # two tasks of 8 steps
+    assert np.mean(losses[-4:]) < np.mean(losses[:4]) - 0.1
+    registry = metrics_lib.default_registry()
+    assert registry.value("worker_moe_dropped_tokens_total") == 0.0
+    assert 0.0 < registry.value(
+        "worker_moe_routed_here_ratio", layer="layer_1/moe/routed"
+    ) < 1.0

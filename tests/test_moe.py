@@ -116,8 +116,12 @@ def test_gradients_flow_to_all_param_groups():
 
 def test_load_balancing_loss_sown_and_trained():
     layer, params, x = _layer()
-    _, state = layer.apply(params, x, mutable=["intermediates"])
-    (lb_loss,) = state["intermediates"]["moe_aux_loss"]
+    # `init` sows too (only flax's own "intermediates" is exempt): apply
+    # on the parameters alone, as the Trainer does (`split_variables`)
+    _, state = layer.apply(
+        {"params": params["params"]}, x, mutable=["aux_loss"]
+    )
+    (lb_loss,) = state["aux_loss"]["moe_aux_loss"]
     # coef * E * sum(density*proxy) >= coef (Cauchy-Schwarz; = at uniform)
     assert float(lb_loss) >= layer.aux_loss_coef * 0.99
 
