@@ -58,13 +58,19 @@ def prefetch_batches(iterator, depth: int = 2, device_stage=None,
     (scripts/check_host_device_boundary.py enforces this).
 
     `device_stage`, when given, adds a second buffering level for the
-    host->device TRANSFER: up to `device_depth` upcoming batches are
-    passed through `device_stage(item)` on the CONSUMER thread before
-    the current batch's result is yielded, so batch k+1's device_put
-    overlaps the caller's execution of batch k (JAX transfers are async
-    — device_put returns as soon as the copy is enqueued).  Staging on
-    the consumer thread honors the trainer's single-device-thread
-    constraint: only ONE thread ever touches device APIs.
+    host->device TRANSFER: batches pass through `device_stage(item)` on
+    the CONSUMER thread (staging there honors the trainer's
+    single-device-thread constraint: only ONE thread ever touches device
+    APIs).  The staging rule: after staging a batch, stage ahead only
+    what the queue ALREADY holds (a look that does not block, up to
+    `device_depth` batches ahead); where it holds nothing, yield the
+    oldest staged batch now.  The consumer never blocks on the queue
+    while it holds a staged batch the caller has not had.  With the
+    producer ahead that is the double buffer: batch k+1's device_put is
+    issued before batch k is yielded and overlaps the caller's execution
+    of batch k (JAX transfers are async — device_put returns as soon as
+    the copy is enqueued).  With the producer behind nothing waits: the
+    first batch of a task goes to the device as soon as it is read.
 
     Exceptions from the iterator re-raise at the consumer; a
     device_stage exception also re-raises at the consumer (in yield
@@ -73,18 +79,23 @@ def prefetch_batches(iterator, depth: int = 2, device_stage=None,
 
     `phase_timer` (common/profiler.PhaseTimer), when given, times both
     ends of the queue, each region with the step (index of the batch in
-    the task) it belongs to.  The consumer's BLOCKED time on the queue is
-    `data_wait` — the signal that says "the input pipeline, not the
-    device, is the bottleneck" — with the queue's depth as the `get`
-    found it; the producer's blocked `put` is `queue_full`, the opposite
-    signal: the pipeline is ahead and the device paces the job.  The
-    iterator's own regions (`read`, `pack`) run on the producer thread
-    under the task the caller's thread is marked with."""
+    the task) it belongs to.  The consumer's time on the queue's `get`
+    is `data_wait`, one span a batch, with the queue's depth as the
+    `get` found it.  It is a wait on the QUEUE: the loop takes batch k+1
+    while the device still works on the steps enqueued before it, so the
+    device is starved only where it has no queued step, which is the
+    head of a task (`data_wait` of step 0); a later step's `data_wait`
+    is the reader's pace under device work.  The producer's blocked
+    `put` is `queue_full`, the opposite signal: the pipeline is ahead
+    and the device paces the job.  The iterator's own regions (`read`,
+    `pack`) run on the producer thread under the task the caller's
+    thread is marked with."""
     import queue
     import threading
 
     q: "queue.Queue" = queue.Queue(maxsize=depth)
     sentinel = object()
+    empty = object()
     stop = threading.Event()
     error = []
     task_id = None if phase_timer is None else phase_timer.marks()[0]
@@ -127,56 +138,61 @@ def prefetch_batches(iterator, depth: int = 2, device_stage=None,
     thread = threading.Thread(target=produce, daemon=True)
     thread.start()
 
-    def consume():
-        step = 0
-        while True:
-            if phase_timer is None:
+    step = 0          # index in the task of the next batch off the queue
+
+    def take(block: bool = True):
+        """The next item off the queue, `sentinel` at its end (a dead
+        reader's exception raises there); with `block` false, `empty`
+        where the queue holds nothing now (this thread is its only
+        taker, so what it holds stays)."""
+        nonlocal step
+        if not block and q.empty():
+            return empty
+        if phase_timer is None:
+            item = q.get()
+        else:
+            # h2d_stage of this batch follows on this thread
+            phase_timer.mark(step=step)
+            with phase_timer.phase("data_wait", depth=q.qsize()) as wait:
                 item = q.get()
-            else:
-                # h2d_stage of this batch follows on this thread
-                phase_timer.mark(step=step)
-                with phase_timer.phase(
-                    "data_wait", depth=q.qsize()
-                ) as wait:
-                    item = q.get()
-                    if item is sentinel:
-                        wait.step = None   # the task's end, not a step
-            if item is sentinel:
-                if error:
-                    raise error[0]
-                return
+                if item is sentinel:
+                    wait.step = None   # the task's end, not a step
+        if item is not sentinel:
             step += 1
-            yield item
+        elif error:
+            raise error[0]
+        return item
 
     try:
         if device_stage is None:
-            yield from consume()
+            while (item := take()) is not sentinel:
+                yield item
             return
         from collections import deque
 
         staged: "deque" = deque()
-        source = consume()
+        failure = None
         while True:
             try:
-                item = next(source)
-            except StopIteration:
+                # block only with nothing staged that the caller waits for
+                item = take(block=not staged)
+                if item is sentinel:
+                    break
+                if item is not empty:
+                    staged.append(device_stage(item))
+                    if len(staged) <= device_depth:
+                        continue    # stage ahead what the queue holds
+            except BaseException as exc:
+                # reader died or the transfer failed: batches already
+                # staged are good transfers — deliver them before
+                # surfacing the failure
+                failure = exc
                 break
-            except BaseException:
-                # reader died: batches already staged are good transfers
-                # — deliver them before surfacing the failure
-                while staged:
-                    yield staged.popleft()
-                raise
-            try:
-                staged.append(device_stage(item))
-            except BaseException:
-                while staged:
-                    yield staged.popleft()
-                raise
-            if len(staged) > device_depth:
-                yield staged.popleft()
+            yield staged.popleft()
         while staged:
             yield staged.popleft()
+        if failure is not None:
+            raise failure
     finally:
         stop.set()
 
@@ -315,32 +331,6 @@ class TaskDataService:
 
         return timed
 
-    # Upper bound on how much of a task's payload the bulk fast path
-    # holds in host memory at once (in batches): bounds worker RSS for
-    # large records_per_shard zoos without giving up the vectorized
-    # parse (ADVICE r4).
-    BULK_CHUNK_BATCHES = 16
-
-    @staticmethod
-    def _bulk_batches(bulk, batch_size: int, feed_bulk: Callable):
-        """Cut one (buffer, sizes) bulk read into per-batch views; the
-        tail (if any) is wrap-padded to the static batch shape."""
-        import numpy as np
-
-        from elasticdl_tpu.parallel.mesh import pad_to_multiple
-
-        buffer, sizes = bulk
-        n = len(sizes)
-        bounds = np.zeros(n + 1, np.int64)
-        np.cumsum(sizes, out=bounds[1:])
-        for i in range(0, n, batch_size):
-            j = min(i + batch_size, n)
-            batch = feed_bulk(buffer[bounds[i]: bounds[j]], sizes[i:j])
-            if j - i == batch_size:
-                yield batch, batch_size
-            else:
-                yield pad_to_multiple(batch, batch_size)
-
     def batches_for_task(
         self,
         task: pb.Task,
@@ -355,10 +345,13 @@ class TaskDataService:
 
         When both the reader exposes a bulk representation
         (`read_records_bulk`) and the zoo a vectorized parser
-        (`feed_bulk(buffer, sizes)`), the task's records move as ONE
-        contiguous uint8 buffer cut into per-batch views — no per-record
-        Python objects on the hot path (at 300K+ examples/s the
-        per-record loop was the host bottleneck, VERDICT r3 weak #2)."""
+        (`feed_bulk(buffer, sizes)`), the records move as contiguous
+        uint8 buffers, one batch a read — no per-record Python objects on
+        the hot path (at 300K+ examples/s the per-record loop was the
+        host bottleneck, VERDICT r3 weak #2).  Each batch is read,
+        packed and yielded before the next is read, so a task's first
+        batch is ready after ONE batch's read and the buffer held at any
+        moment is one batch's."""
         from elasticdl_tpu.parallel.mesh import pad_to_multiple
 
         feed = self._timed_pack(feed)
@@ -366,45 +359,40 @@ class TaskDataService:
         if feed_bulk is not None:
             reader_bulk = getattr(self._reader, "read_records_bulk", None)
             if reader_bulk is not None:
-                # Chunk the bulk read into batch-aligned sub-ranges
-                # (ADVICE r4): reading the WHOLE task payload at once
-                # spikes worker RSS with large records_per_shard — the
-                # buffer held at any moment is now at most
-                # BULK_CHUNK_BATCHES batches, and chunk boundaries stay
-                # batch-aligned so the only partial batch is the task's
-                # own tail (wrap-padded exactly as before).
                 shard = task.shard
-                total = shard.end - shard.start
-                chunk = self.BULK_CHUNK_BATCHES * batch_size
                 used_bulk = False
-                for off in range(0, total, chunk):
+                for start in range(shard.start, shard.end, batch_size):
                     sub = pb.Task(
                         task_id=task.task_id,
                         type=task.type,
                         shard=pb.Shard(
                             name=shard.name,
-                            start=shard.start + off,
-                            end=min(shard.start + off + chunk, shard.end),
+                            start=start,
+                            end=min(start + batch_size, shard.end),
                         ),
                     )
                     with _phase(self.phase_timer, "read"):
                         bulk = reader_bulk(sub)
                     if bulk is None:
                         if used_bulk:
-                            # a reader that served earlier chunks must
+                            # a reader that served earlier batches must
                             # not silently truncate the task mid-stream
                             raise IOError(
                                 f"bulk read failed mid-task at record "
-                                f"{off} of {task.task_id}"
+                                f"{start - shard.start} of {task.task_id}"
                             )
                         # no bulk representation (e.g. unindexed
                         # source): fall to the streaming path
                         break
                     used_bulk = True
-                    yield from self._bulk_batches(
-                        bulk, batch_size, feed_bulk
-                    )
-                if used_bulk or total == 0:
+                    buffer, sizes = bulk
+                    if len(sizes):   # none: the shard outruns its file
+                        # the task's tail (if any) is wrap-padded to the
+                        # static batch shape
+                        yield pad_to_multiple(
+                            feed_bulk(buffer, sizes), batch_size
+                        )
+                if used_bulk or shard.end <= shard.start:
                     return
         records = iter(self._reader.read_records(task))
         while True:

@@ -91,21 +91,43 @@ def test_device_stage_runs_on_consumer_thread_and_preserves_order():
     assert set(staged_on) == {consumer}
 
 
-def test_device_stage_runs_ahead_of_consumption():
-    """With device_depth=1 the hook stages item N+1 while the consumer
-    holds item N: at the moment the FIRST item is yielded, the second
-    must already be staged (that's the double buffer)."""
+@pytest.mark.parametrize("producer", ["ahead", "behind"])
+def test_device_stage_runs_ahead_of_consumption(producer):
+    """The staging rule (device_depth=1).  Producer AHEAD: the hook
+    stages item N+1 while the consumer holds item N, so at the moment
+    the FIRST item is yielded the second is already staged (the double
+    buffer).  Producer BEHIND: a staged item is never held back for the
+    next one's read, so the first item is yielded while the second has
+    not been produced, and the rest follow in order once it is."""
     staged = []
+    produced = []
+    in_queue = threading.Event()   # the queue holds item 1
+    release = threading.Event()    # the reader may go past item 0
 
     def stage(item):
+        if producer == "ahead" and item == 0:
+            assert in_queue.wait(5.0)
         staged.append(item)
         return item
 
-    it = prefetch_batches(iter(range(5)), device_stage=stage,
-                          device_depth=1)
+    def gen():
+        for i in range(5):
+            if producer == "behind" and i == 1:
+                assert release.wait(5.0)
+            produced.append(i)
+            yield i
+            if i == 1:
+                # the producer asks for item 2 once item 1 is queued
+                in_queue.set()
+
+    it = prefetch_batches(gen(), device_stage=stage, device_depth=1)
     first = next(it)
     assert first == 0
-    assert staged[:2] == [0, 1]  # second transfer already issued
+    if producer == "ahead":
+        assert staged == [0, 1]  # second transfer already issued
+    else:
+        assert staged == [0] and produced == [0]
+        release.set()
     assert list(it) == [1, 2, 3, 4]
     assert staged == [0, 1, 2, 3, 4]
 

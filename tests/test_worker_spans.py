@@ -242,6 +242,32 @@ def job(mnist_data, tmp_path_factory):
         spec=spec,
         minibatch_size=MINIBATCH,
     )
+    # Order the head of every task by events, not by the clock: the
+    # task's SECOND read stays open until its step 0 is inside `compute`.
+    # A loop that held the first batch back for the second's read would
+    # never get there.
+    dispatched = {}      # task_id -> step 0's program is being enqueued
+    reads = {}           # task_id -> bulk reads begun
+    held = []            # task_ids whose second read waited, and went on
+    read_bulk = reader.read_records_bulk
+    train_step = worker._owner.trainer.train_step
+
+    def ordered_read(task):
+        reads[task.task_id] = reads.get(task.task_id, 0) + 1
+        if reads[task.task_id] == 2:
+            event = dispatched.setdefault(task.task_id, threading.Event())
+            assert event.wait(60.0), "step 0 waits for the second read"
+            held.append(task.task_id)
+        return read_bulk(task)
+
+    def marked_train_step(*args):
+        task_id, step = _phase_timer.marks()
+        if step == 0:
+            dispatched.setdefault(task_id, threading.Event()).set()
+        return train_step(*args)
+
+    reader.read_records_bulk = ordered_read
+    worker._owner.trainer.train_step = marked_train_step
     trace_dir = str(tmp_path_factory.mktemp("spans_trace"))
     already = len(_phase_timer.spans())
     t0 = time.perf_counter()
@@ -258,6 +284,7 @@ def job(mnist_data, tmp_path_factory):
         "loop": threading.get_native_id(),
         "trace_dir": trace_dir,
         "rate": worker.step_rate.steps_per_sec,
+        "held": held,
     }
 
 
@@ -279,6 +306,32 @@ def test_every_train_step_has_its_wait_and_its_dispatch(job):
             (task_id, step)
             for task_id in tasks for step in range(STEPS_PER_TASK)
         ]
+
+
+def test_a_task_reads_a_batch_a_step_and_feeds_the_first_at_once(job):
+    """One `read` span a step, marked with it; and step 0 is enqueued
+    (`compute` begins) before the task's second read ends: the first
+    batch is not held back for the next one's read."""
+    assert len(job["held"]) == TASKS == len(set(job["held"]))
+    for task_id in job["held"]:
+        mine = [s for s in job["spans"] if s.task_id == task_id]
+        reads = sorted(
+            (s for s in mine if s.name == "read"), key=lambda s: s.start
+        )
+        assert [s.step for s in reads] == list(range(STEPS_PER_TASK))
+        assert all(s.thread != job["loop"] for s in reads)
+        # each batch is packed before the next is read
+        packs = sorted(
+            (s for s in mine if s.name == "pack"), key=lambda s: s.start
+        )
+        assert [s.step for s in packs] == list(range(STEPS_PER_TASK))
+        for read, pack, after in zip(reads, packs, reads[1:] + [None]):
+            assert read.end <= pack.start
+            assert after is None or pack.end <= after.start
+        (first,) = (
+            s for s in mine if s.name == "compute" and s.step == 0
+        )
+        assert first.start < reads[1].end
 
 
 def test_loop_spans_and_producer_spans_come_from_their_threads(job):
