@@ -5,7 +5,7 @@ per-SLO state, fast/slow burn rates, and the window evidence behind
 them — inside Master.snapshot() under the "slo" key, which the
 telemetry server republishes on /varz.  Like `elasticdl top` this is a
 pure HTTP client; `render_slo` is also callable directly on a snapshot
-dict so in-process tests (and bench.py) render the exact bytes the CLI
+dict so in-process tests render the exact bytes the CLI
 would print.
 """
 

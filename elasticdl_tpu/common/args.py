@@ -52,11 +52,8 @@ def add_common_params(parser: argparse.ArgumentParser):
     parser.add_argument("--master_addr", default="", help="host:port of master")
     parser.add_argument("--port", type=pos_int, default=50001)
     parser.add_argument("--num_workers", type=pos_int, default=1)
-    parser.add_argument("--num_minibatches_per_task", type=pos_int, default=8)
-    parser.add_argument("--log_level", default="INFO")
     parser.add_argument("--image_name", default="")
     parser.add_argument("--worker_resource_request", default="cpu=1,memory=4096Mi")
-    parser.add_argument("--worker_resource_limit", default="")
     parser.add_argument("--worker_pod_priority", default="")
     parser.add_argument("--restart_policy", default="Never")
     parser.add_argument(
@@ -65,10 +62,6 @@ def add_common_params(parser: argparse.ArgumentParser):
         "'host_path=/a,mount_path=/b' or 'claim_name=pvc,mount_path=/b'; "
         "multiple entries separated by ';'.  Mounted into the master pod "
         "and every worker pod (e.g. the --compilation_cache_dir volume).",
-    )
-    parser.add_argument("--image_pull_policy", default="IfNotPresent")
-    parser.add_argument(
-        "--need_tf_config", type=str2bool, default=False, nargs="?", const=True
     )
     parser.add_argument(
         "--use_fake_k8s", type=str2bool, default=False,
@@ -435,13 +428,6 @@ def add_train_params(parser: argparse.ArgumentParser):
         "overhead; losses/metrics are still recorded per step.",
     )
     parser.add_argument("--num_epochs", type=pos_int, default=1)
-    parser.add_argument(
-        "--grads_to_wait", type=pos_int, default=1,
-        help="Accepted for reference-CLI compatibility (the sync-PS "
-        "accumulation knob).  Meaningless here: every step is already "
-        "bulk-synchronous over the mesh — gradients from all data "
-        "shards reduce inside the compiled step.",
-    )
     parser.add_argument("--training_data", default="")
     parser.add_argument("--validation_data", default="")
     parser.add_argument("--prediction_data", default="")
@@ -476,7 +462,6 @@ def add_train_params(parser: argparse.ArgumentParser):
         "aggregated eval metrics (master) as TensorBoard event files "
         "under this directory",
     )
-    parser.add_argument("--task_fault_tolerance", type=str2bool, default=True)
     parser.add_argument(
         "--relaunch_on_worker_failure", type=non_neg_int, default=3,
         help="max relaunches per failed worker pod",

@@ -23,8 +23,8 @@ stamps into per-window decompositions, feeds the
 ring of completed lineage records.  Because every boundary is a stamp on
 ONE monotone clock, the seven phase durations sum to the window's
 measured end-to-end staleness (served - ingest) exactly — the
-reconciliation contract docs/OBSERVABILITY.md documents and bench.py
-asserts within 5%.
+reconciliation contract docs/OBSERVABILITY.md documents and
+tests/test_online_pipeline.py asserts within 5%.
 
 Replay attribution: a window replayed after a master restart keeps its
 FIRST-SEEN ingest/seal stamps; the replay stamp only fills them in when
@@ -384,8 +384,8 @@ class WindowLineage:
     def records(self) -> List[dict]:
         """Completed lineage records, oldest first — every field comes
         off the injectable clock, so under a fake clock this list is
-        byte-stable across same-seed chaos replays (bench.py folds it
-        into the canonical trace)."""
+        byte-stable across same-seed chaos replays
+        (tests/online_chaos.py folds it into the canonical trace)."""
         with self._lock:
             return [dict(d) for d in self._completed]
 

@@ -35,8 +35,8 @@ STEP_PHASES = LOOP_PHASES + PRODUCER_PHASES + (
     # tiered embedding store (elasticdl_tpu/store): host-tier gathers for
     # cold rows — on the prefetcher thread when overlapped, on the
     # consumer when a deferred row forces a synchronous gather.  Its
-    # `share` vs `compute` is the cold-tail overlap measurement
-    # bench.py --tiered reports.
+    # `share` vs `compute` is the cold-tail overlap (no cell of the
+    # benchmark reads it yet: ROADMAP.md Reach 7).
     "cold_gather",
 )
 

@@ -9,8 +9,7 @@ per named program and per distinct aval signature:
 - compile wall seconds (``worker_program_compile_seconds{program}``
   histogram, injectable clock so tests replay deterministically);
 - compile / retrace counts and the distinct-signature count;
-- XLA's own cost model (``cost_analysis()`` flops + bytes accessed) —
-  the same numbers bench.py used to compute privately per run.
+- XLA's own cost model (``cost_analysis()`` flops + bytes accessed).
 
 Joining per-program cost against the step-rate telemetry the worker
 already publishes (``bind_step_rate``) turns the static ledger into
@@ -72,8 +71,8 @@ _DEVICE_PEAKS = {
 
 
 def device_peaks() -> Optional[dict]:
-    """Datasheet peak numbers for MFU / bandwidth rooflines, shared by
-    bench.py and live telemetry.  The CPU platform has no peaks (None:
+    """Datasheet peak numbers for MFU / bandwidth rooflines of the live
+    telemetry.  The CPU platform has no peaks (None:
     the ratio gauges read 0.0); an accelerator whose `device_kind` is
     not in the table is an error, never a guessed row."""
     import jax

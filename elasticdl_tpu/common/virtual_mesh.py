@@ -9,7 +9,7 @@ that builds that environment; tests/conftest.py and the driver's
 drift.
 
 `enable_compile_cache` is the single place that decides where compiled
-executables persist; every entry point that compiles (bench.py, the
+executables persist; every entry point that compiles (the
 train / worker / serve mains, the scripts, conftest, `__graft_entry__`,
 `chip_smoke.py`) calls it and none names a directory of its own.
 

@@ -1,6 +1,6 @@
 """Fused embedding arena: every same-`dim` feature table as ONE array.
 
-Motivation (BENCH r05, docs/PERF.md): a model with F separate
+Motivation (docs/PERF.md): a model with F separate
 `DistributedEmbedding` tables issues F gather kernels forward and F
 scatter-add kernels backward per step.  Each kernel pays its own
 dispatch/fusion boundary, and on the row-sharded layout each pays its own

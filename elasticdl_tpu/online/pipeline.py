@@ -29,14 +29,15 @@ backlog.
 Every time-reading collaborator shares ONE injectable clock, and every
 decision maker (task manager, fleet manager, SLO evaluator, policy
 engine, shard map, fault registry) is already deterministic under a
-fake clock — so the chaos variant of `bench.py --online` replays
+fake clock — so the chaos run of tests/test_online_pipeline.py replays
 byte-identically across same-seed runs while a stream stall, a trainer
 kill, a master restart, a shard-handoff fault, and a reload fault land
 mid-loop.
 
 Single-process by design: the serving replicas are in-process servicers
-behind killable clients (the bench_serving_fleet harness shape,
-bench.py), which keeps the full loop runnable in CI seconds.  The
+behind killable clients (the harness shape of
+tests/test_serving_fleet.py), which keeps the full loop runnable in CI
+seconds.  The
 multi-process story reuses the same pieces unchanged — the reader and
 task manager already speak the worker lease protocol.
 """
@@ -128,7 +129,7 @@ class OnlineConfig:
 
 class _KillableClient:
     """In-process serving client with a kill switch standing in for a
-    dead pod (same harness shape as bench_serving_fleet)."""
+    dead pod (the harness shape of tests/test_serving_fleet.py)."""
 
     def __init__(self, servicer):
         self._inner = InProcessServingClient(servicer)
@@ -247,8 +248,9 @@ class OnlinePipeline:
     ):
         # `client_wrapper(rid, client) -> client` interposes on every
         # replica client the router sees (including ones the autoscaler
-        # launches later) — how bench.py --traffic models a replica's
-        # finite per-tick serving capacity without faking the servicer.
+        # launches later) — how scripts/online_summary.py models a
+        # replica's finite per-tick serving capacity without faking the
+        # servicer.
         import jax
 
         from elasticdl_tpu.serving.batcher import DynamicBatcher

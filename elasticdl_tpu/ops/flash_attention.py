@@ -15,10 +15,10 @@ Kernel shape (round 5 — second generation): inputs stay in the model's
 native (B, L, H, D) layout viewed as (B, L, H*D) — a FREE reshape — and
 the grid runs over (B, Lq/BLOCK_Q) with a static per-head loop inside
 each program slicing D-wide column chunks.  The first-generation kernel
-merged to (B*H, L, D) via transposes that cost ~23 ms/step of pure
-layout copies in the BERT bench (docs/BERT_PROFILE.md) and ran more,
-smaller grid programs; this layout measures ~19% faster solo AND deletes
-the transposes.  Each program holds one Q tile resident in VMEM and
+merged to (B*H, L, D) via transposes that were pure layout copies in
+the BERT step and ran more, smaller grid programs; this layout deletes
+the transposes (`PERF.md` §7 has the history; the kernel's time on the
+v5e is `flash_fwd_ms_per_step`, `PERF.md` §5).  Each program holds one Q tile resident in VMEM and
 streams K/V tiles, carrying the running max `m`, normaliser `l` and
 unnormalised accumulator in f32.  Causal masking prunes whole K tiles
 above the diagonal.  The FORWARD is O(L) in HBM (nothing (L, L)-shaped
@@ -175,10 +175,10 @@ def _flash(q, k, v, causal, scale):
 
 
 def _pick_block(length: int) -> int:
-    # 256-512-sized tiles measured 1.6-2x the 128-tile rate on v5e
-    # (docs/BERT_PROFILE.md): per-grid-program overhead dominates these
-    # small-matmul kernels, so fewer/larger programs win.  Blocks must
-    # divide the length (the grid streams whole tiles).
+    # Per-grid-program overhead dominates these small-matmul kernels,
+    # so fewer/larger programs win (256-512-sized tiles beat 128-tiles
+    # on an older machine; `PERF.md` §7).  Blocks must divide the
+    # length (the grid streams whole tiles).
     for cand in (512, 256, 128):
         if length >= cand and length % cand == 0:
             return cand
@@ -189,8 +189,8 @@ def _flash_fwd(q, k, v, causal, scale):
     batch, q_len, heads, dim = q.shape
     k_len = k.shape[1]
     hd = heads * dim
-    # measured optimum at BERT-base shapes: Q tiles of 256 with K
-    # streamed in 512s (10.3 TFLOPs solo vs 9.9 at 512/512)
+    # the optimum at BERT-base shapes on an older machine: Q tiles of
+    # 256 with K streamed in 512s (not measured again on the v5e)
     block_q = 256 if q_len % 256 == 0 else _pick_block(q_len)
     block_k = _pick_block(k_len)
     out3, lse = _pallas_forward(

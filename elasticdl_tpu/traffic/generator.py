@@ -14,8 +14,8 @@ exactly the regime where admission control and autoscaling matter) and
   across platforms (no numpy RNG in the schedule path).
 - `factor(tick)` comes from the profile, a closed TRAFFIC_PROFILES
   vocabulary: `poisson` (flat), `spike` (a step to `spike_factor`x for
-  `spike_ticks` ticks at `spike_at_tick` — the bench.py --traffic
-  scenario), `diurnal` (a sinusoid), `ramp` (linear climb to
+  `spike_ticks` ticks at `spike_at_tick` — the scenario of
+  scripts/online_summary.py's TRAFFIC_SUMMARY), `diurnal` (a sinusoid), `ramp` (linear climb to
   `spike_factor`x over `ramp_ticks`).
 - Request shapes draw from the closed REQUEST_SHAPES batch-row catalog
   and spread round-robin over `clients` logical client loops.  The
@@ -30,7 +30,8 @@ exactly the regime where admission control and autoscaling matter) and
 The generator never imports the router; it calls an injected
 `request_fn(client_id, rows, payload_seed) -> "ok"|"shed"|"failed"`.
 `router_request_fn` adapts a FleetRouter (+ an encode function from the
-model zoo) into that shape for bench.py and the online pipeline.
+model zoo) into that shape for scripts/online_summary.py and the
+online pipeline.
 """
 
 from __future__ import annotations
@@ -135,7 +136,7 @@ def router_request_fn(router, encode_fn,
 class TrafficGenerator:
     """Drives `request_fn` with the seeded open-loop schedule.
 
-    Tests and bench.py call `tick()` by hand (injectable clock-free
+    Tests and scripts/online_summary.py call `tick()` by hand (injectable clock-free
     design: nothing here reads a wall clock); each tick fires the
     `traffic.tick` fault point before offering anything, so chaos can
     stall the load source for a tick without perturbing the schedule

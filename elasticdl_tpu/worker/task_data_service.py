@@ -52,8 +52,9 @@ def prefetch_batches(iterator, depth: int = 2, device_stage=None,
     """Run a host-side batch iterator (reader IO + feed parsing) in a
     background thread, keeping up to `depth` batches ready while the
     caller's thread drives the device — read/parse overlaps compute (the
-    double-buffering every input pipeline wants; measured in bench.py's
-    e2e mode).  Pure host work only: the producer never touches device
+    double-buffering every input pipeline wants; the benchmark's
+    `task_head_wait_ms` and `steady_data_wait_ms` read what is left
+    unoverlapped).  Pure host work only: the producer never touches device
     APIs, so it is safe on every backend including the virtual CPU mesh
     (scripts/check_host_device_boundary.py enforces this).
 

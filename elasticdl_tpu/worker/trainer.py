@@ -18,7 +18,6 @@ split along `data`.  bfloat16 compute keeps the MXU fed; params stay f32.
 from __future__ import annotations
 
 import threading
-import time
 from functools import partial
 from typing import Any, Callable, Dict, Optional
 
@@ -704,68 +703,3 @@ class Trainer:
             "prewarmed train step for %d-device mesh in %.1fs (persistent"
             " cache populated)", count, _time.perf_counter() - t0,
         )
-
-    def timed_steps_per_sec_fused(self, state, batch, iters: int = 40):
-        """Device-honest step rate: ONE jitted program runs `iters`
-        serially-dependent train steps via lax.fori_loop and returns two
-        scalars — the step counter AND an anchor folded from the final
-        params — synced with a value fetch.
-
-        The params ANCHOR is load-bearing: returning only the step
-        counter lets XLA's while-loop simplifier dead-code-eliminate the
-        entire training chain (step+1 does not depend on params), and
-        the 'measured' loop then costs one device round trip regardless
-        of iters.  A scalar folded from the final params forces every
-        iteration's forward+backward+update to execute."""
-        batch = mesh_lib.shard_batch(batch, self.mesh)
-        cache = getattr(self, "_fused_timing_cache", None)
-        if cache is None:
-            cache = self._fused_timing_cache = {}
-        fused = cache.get(iters)
-        if fused is None:
-            # one jitted closure per iters value: a fresh jax.jit each
-            # call would recompile identical shapes on every repeat
-            def multi(s, b):
-                def body(_, s2):
-                    s3, _loss = self.train_step(s2, b)
-                    return s3
-
-                out = jax.lax.fori_loop(0, iters, body, s)
-                # every param leaf: anchoring a subset would let the
-                # partitioner prune the unused leaves' gradient/update
-                # branches (Adam state chains stay live through params)
-                anchor = sum(
-                    leaf.ravel()[0].astype(jnp.float32)
-                    for leaf in jax.tree.leaves(out.params)
-                )
-                # quantized arenas: the int8 planes live in model_state
-                # and the fold chain feeds ONLY them (the carrier is
-                # zeroed) — without anchoring them XLA would DCE the
-                # whole requantize and overstate int8 speed
-                anchor = anchor + sum(
-                    leaf.ravel()[0].astype(jnp.float32)
-                    for leaf in jax.tree.leaves(
-                        out.model_state.get("quantized", {})
-                    )
-                )
-                return out.step, anchor
-
-            fused = cache[iters] = programs.registered_jit(
-                "worker_timed_fused", multi
-            )
-        # warm once per (iters, shapes): compile + first-exec costs; later
-        # repeats (bench medians) skip it — re-warming every repeat would
-        # double the device work under a wall-clock-budgeted driver
-        warmed = getattr(self, "_fused_timing_warmed", None)
-        if warmed is None:
-            warmed = self._fused_timing_warmed = set()
-        key = (iters, tuple(
-            (tuple(x.shape), str(x.dtype))
-            for x in jax.tree.leaves(batch)
-        ))
-        if key not in warmed:
-            jax.device_get(fused(state, batch))
-            warmed.add(key)
-        start = time.perf_counter()
-        jax.device_get(fused(state, batch))
-        return iters / (time.perf_counter() - start)

@@ -3,7 +3,7 @@
 Consumes the synthetic click-stream records the StreamReader windows
 (`data/reader/stream_reader.py`: dicts of user, item, clicked,
 event_unix_s) through the standard zoo contract, so the online
-orchestrator (elasticdl_tpu/online/pipeline.py) and `bench.py --online`
+orchestrator (elasticdl_tpu/online/pipeline.py) and its tests
 train and serve it with the same Trainer/ServingEngine every batch model
 uses.  Deliberately tiny: the online loop's subject is the
 stream→train→reload plumbing, not the model.

@@ -17,8 +17,7 @@ train-to-serve staleness drift, the window-ledger health (armed/lost
 counts plus shard handoffs — lost must stay 0; see docs/ONLINE.md
 exactly-once accounting), and the serving control loop (the seeded
 traffic generator's spike against the autoscaling fleet,
-docs/SERVING.md "Autoscaling & backpressure") without running the full
-bench (`python bench.py --online` / `--traffic`).  A few seconds on
+docs/SERVING.md "Autoscaling & backpressure").  A few seconds on
 CPU: two windows, two in-process replicas, sequential predicts on the
 driver thread.
 
@@ -119,8 +118,8 @@ def traffic_summary(ticks: int = 10, seed: int = SEED,
     `ticks` generator ticks.  Returns the dict behind the
     TRAFFIC_SUMMARY line.
 
-    Each replica sits behind a per-tick capacity gate (the bench's
-    overload model, see `bench._traffic_spike_run`): the in-process
+    Each replica sits behind a per-tick capacity gate (the overload
+    model): the in-process
     engine answers everything a sequential driver offers, so without a
     declared capacity the spike sheds nothing and the control loop
     under test never has to act."""

@@ -78,10 +78,5 @@ python -m scripts.store_summary || true
 # predicts, a few seconds on CPU; non-fatal here — the matching test
 # in tests/test_online_pipeline.py owns the hard assertions.
 python -m scripts.online_summary || true
-# Program-observatory cost line (docs/OBSERVABILITY.md "Program
-# observatory"): a live registry probe (compile/retrace counting) plus
-# the newest archived bench round's cost-model numbers; non-fatal —
-# tests/test_programs.py owns the hard assertions.
-python -m scripts.bench_compare --cost-summary || true
 echo "TIER1_SUMMARY passed=${passed} wall_s=${wall_s} lint_findings=${lint_findings} status=${status}"
 exit "$rc"

@@ -9,8 +9,8 @@ prints one machine-readable line:
         per_chip_cache_bytes=<b/8>
 
 `scripts/run_tests.sh` emits it next to TIER1_SUMMARY so CI can watch
-cache efficacy drift without running the full bench
-(`python bench.py tiered`).  No jax, no devices: the whole check is
+cache efficacy drift (no cell of the benchmark runs the tiered store
+yet: ROADMAP.md Reach 7).  No jax, no devices: the whole check is
 host math, which is the point — a cache-policy regression shows up
 here in well under a second.  The byte fields are the ISSUE-18 analytic
 model (store/cache.py cache_value_bytes_per_row): fp32 vs int8 device
@@ -25,9 +25,9 @@ from __future__ import annotations
 
 import numpy as np
 
-# Deliberately mirrors the bench's zipfian config (bench.py
-# bench_tiered): a skewed stream where a 4k-row cache over a ~8k-row
-# working vocabulary should hold the hot head (hit rate >= 0.9).
+# The canonical zipfian config: a skewed stream where a 4k-row cache
+# over a ~8k-row working vocabulary should hold the hot head (hit rate
+# >= 0.9).
 NUM_FIELDS = 26
 BATCH = 128
 STEPS = 60

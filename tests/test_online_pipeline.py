@@ -115,10 +115,10 @@ def test_chaos_replay_is_byte_identical():
     with all scheduled faults fired, zero failed predicts, zero lost
     windows, and zero duplicated window offsets (docs/ONLINE.md
     "Determinism under chaos")."""
-    import bench
+    from tests.online_chaos import online_chaos_run
 
-    trace_a, summary_a = bench._online_chaos_run(17)
-    trace_b, summary_b = bench._online_chaos_run(17)
+    trace_a, summary_a = online_chaos_run(17)
+    trace_b, summary_b = online_chaos_run(17)
     assert trace_a == trace_b
     assert summary_a["all_faults_fired"]
     assert summary_a["failed_requests"] == 0

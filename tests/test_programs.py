@@ -3,9 +3,7 @@ observatory"): deterministic compile telemetry under a fake clock,
 signature/retrace counting, thread-safe concurrent first compiles, the
 bucket-missing-engine recompile-storm drill capturing exactly one
 byte-stable incident bundle, the prewarm-compiles-<=-buckets regression
-guard, the `elasticdl programs`/`top`/`trace` surfaces, and
-scripts/bench_compare.py (fragment recovery, adjacent-round regression
-verdict, the COST_SUMMARY line)."""
+guard, and the `elasticdl programs`/`top`/`trace` surfaces."""
 
 import json
 import os
@@ -19,7 +17,6 @@ from elasticdl_tpu.common import events
 from elasticdl_tpu.common import metrics as metrics_lib
 from elasticdl_tpu.common import programs
 from elasticdl_tpu.common.flight import FlightRecorder
-from scripts import bench_compare
 
 
 class FakeClock:
@@ -399,74 +396,3 @@ def test_trace_renders_programs_track_and_compile_summary():
     assert "xla compiles: 2 across 2 programs" in text
     assert "STORMS=1" in text
 
-
-# ---- scripts/bench_compare.py --------------------------------------------
-
-
-def _write_round(tmp_path, n, metrics=None, tail="", rc=0):
-    lines = [
-        json.dumps({"metric": name, "value": value})
-        for name, value in (metrics or {}).items()
-    ]
-    doc = {
-        "n": n, "cmd": "python bench.py deepfm", "rc": rc,
-        "tail": "\n".join(lines) + tail,
-        "parsed": None,
-    }
-    path = tmp_path / f"BENCH_r{n:02d}.json"
-    path.write_text(json.dumps(doc))
-    return str(path)
-
-
-def test_bench_compare_recovers_truncated_fragments(tmp_path):
-    full = "deepfm_criteo_train_examples_per_sec"
-    _write_round(tmp_path, 3, metrics={full: 300000.0})
-    # r04's only metric line lost its head to the driver's tail cap
-    _write_round(
-        tmp_path, 4,
-        tail='amples_per_sec", "value": 150000.0, "unit": "examples',
-    )
-    rounds = bench_compare.load_rounds(
-        str(tmp_path / "BENCH_r0*.json")
-    )
-    assert [r["n"] for r in rounds] == [3, 4]
-    assert rounds[1]["metrics"][full] == 150000.0
-
-
-def test_bench_compare_regression_verdict_is_adjacent_rounds(tmp_path):
-    name = "deepfm_criteo_train_examples_per_sec"
-    # r01 is the known DCE-inflated async number: r02->r03 is flat, so
-    # no verdict fires even though r03 is far below r01's peak
-    _write_round(tmp_path, 1, metrics={name: 8.2e6})
-    _write_round(tmp_path, 2, metrics={name: 3.0e5})
-    _write_round(tmp_path, 3, metrics={name: 2.9e5})
-    pattern = str(tmp_path / "BENCH_r0*.json")
-    assert bench_compare.main(["--rounds-glob", pattern]) == 0
-
-    _write_round(tmp_path, 4, metrics={name: 1.0e5})  # 0.34x adjacent
-    assert bench_compare.main(["--rounds-glob", pattern]) == 1
-    traj = bench_compare.trajectory(bench_compare.load_rounds(pattern))
-    regs = bench_compare.regressions(traj, 0.5)
-    assert [r["metric"] for r in regs] == [name]
-    assert regs[0]["prev_round"] == 3 and regs[0]["last_round"] == 4
-
-
-def test_cost_summary_line_probes_the_registry(tmp_path):
-    _write_round(
-        tmp_path, 5,
-        tail='\n"mfu": 0.0015, '
-             '"step_bytes_accessed_xla_costmodel": 353523597312.0',
-    )
-    rounds = bench_compare.load_rounds(str(tmp_path / "BENCH_r0*.json"))
-    line = bench_compare.cost_summary(rounds)
-    # one probe program at two shapes: 2 compiles, 1 beyond the first
-    assert line.startswith("COST_SUMMARY programs=1 recompiles=1 ")
-    assert "mfu=0.0015" in line
-    assert "bytes_per_step=353523597312.0" in line
-
-
-def test_cost_summary_dashes_without_archived_rounds():
-    line = bench_compare.cost_summary([])
-    assert line == (
-        "COST_SUMMARY programs=1 recompiles=1 mfu=- bytes_per_step=-"
-    )

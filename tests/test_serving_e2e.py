@@ -33,6 +33,11 @@ from elasticdl_tpu.worker.trainer import TrainState
 
 MODEL_DEF = "mnist.mnist_functional_api.custom_model"
 BUCKETS = (2, 8)
+# closed-loop clients of the mixed-traffic test, at most max(BUCKETS)
+# rows a request
+CLIENTS = 6
+# a bound on a hang, never a term of an outcome
+HANG_BOUND_S = 60.0
 
 
 class _Stack:
@@ -54,7 +59,15 @@ class _Stack:
         self.engine = ServingEngine.from_checkpoint(
             self.ckpt_dir, self.spec, self.sample, buckets=BUCKETS
         )
-        self.batcher = DynamicBatcher(self.engine, max_latency_s=0.005)
+        # The queue admits every client's largest request at once.  At
+        # the default bound (4 x 8 = 32 rows) the six clients' 48 could
+        # not all wait out a stall (the swap's restore holds the CPU
+        # backend's execution lock), and which request was shed
+        # OVERLOADED was a matter of timing: 1 request in ~1,900.
+        self.batcher = DynamicBatcher(
+            self.engine, max_latency_s=0.005,
+            max_queue_rows=CLIENTS * max(BUCKETS),
+        )
         self.reloader = CheckpointReloader(
             self.engine, self.ckpt_dir, poll_interval_s=0.05
         )
@@ -74,7 +87,7 @@ class _Stack:
         self.saver.save(state, force=True)
         self.saver.wait_until_finished()
 
-    def wait_for(self, predicate, timeout=15.0):
+    def wait_for(self, predicate, timeout=HANG_BOUND_S):
         deadline = time.monotonic() + timeout
         while time.monotonic() < deadline:
             if predicate():
@@ -100,22 +113,27 @@ def test_mixed_concurrent_traffic_with_midstream_hot_swap(stack):
     sizes through gRPC, a checkpoint swap landing mid-traffic — every
     request succeeds, no bucket recompiles, and responses attribute
     their model step."""
-    results, lock = [], threading.Lock()
+    results, errors, lock = [], [], threading.Lock()
     # Clients send at least 12 requests each, then KEEP sending until
-    # someone observes the post-swap generation (bounded by a deadline):
-    # on a loaded box the save + reloader poll can land after 72 quick
-    # requests would have drained, which starved the mid-swap assertion.
+    # the post-swap generation has been OBSERVED in a response: no
+    # deadline decides what they saw.  `stop` is set only when the
+    # reloader never adopts step 2, and then the test fails on that.
     saw_swap = threading.Event()
-    deadline = time.monotonic() + 20.0
+    stop = threading.Event()
 
     def client(seed):
         rng = np.random.RandomState(seed)
         sent = 0
-        while True:
+        while not stop.is_set():
             sent += 1
             rows = int(rng.choice([1, 2, 3, 5, 8]))
             x = rng.rand(rows, 784).astype(np.float32)
-            resp = stack.stub.predict(make_predict_request(x))
+            try:
+                resp = stack.stub.predict(make_predict_request(x))
+            except Exception as exc:
+                with lock:
+                    errors.append(repr(exc))
+                return
             preds = (
                 from_tensor_proto(resp.predictions)
                 if resp.code == spb.SERVING_OK else None
@@ -124,20 +142,28 @@ def test_mixed_concurrent_traffic_with_midstream_hot_swap(stack):
                 results.append((resp.code, resp.model_step, rows, preds))
             if resp.code == spb.SERVING_OK and resp.model_step == 2:
                 saw_swap.set()
-            if sent >= 12 and (saw_swap.is_set()
-                               or time.monotonic() > deadline):
+            if sent >= 12 and saw_swap.is_set():
                 return
 
     threads = [
-        threading.Thread(target=client, args=(i,)) for i in range(6)
+        threading.Thread(target=client, args=(i,), daemon=True)
+        for i in range(CLIENTS)
     ]
     for t in threads:
         t.start()
     # land a new checkpoint while traffic is in flight
     stack.save_step(2, scale=2.0)
+    swapped = stack.wait_for(lambda: stack.engine.step == 2)
+    if not swapped:
+        stop.set()
     for t in threads:
-        t.join()
-    assert stack.wait_for(lambda: stack.engine.step == 2)
+        t.join(HANG_BOUND_S)
+    assert swapped, (
+        f"the reloader never adopted step 2 (still serving step "
+        f"{stack.engine.step}; last error: {stack.reloader.last_error})"
+    )
+    assert not any(t.is_alive() for t in threads)
+    assert not errors
 
     codes = [code for code, _, _, _ in results]
     assert codes == [spb.SERVING_OK] * len(codes)  # ZERO failed requests
