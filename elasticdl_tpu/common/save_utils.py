@@ -707,6 +707,47 @@ class CheckpointSaver:
         return restored
 
     def _restore_with_shims(self, step: int, abstract: Any) -> Any:
+        """`_restore_renamed`, and where that fails on a template whose
+        model sows step metrics (`layers/moe.py: STEP_METRICS`, the LAST
+        step's scalars, not trained state), once more without them: a
+        checkpoint written before the model sowed any (a narrow-row
+        embedding sows its share of distinct rows since PR 32) restores
+        with the collection as zeros, as `init` leaves it."""
+        import jax
+        import jax.numpy as jnp
+
+        from elasticdl_tpu.layers.moe import STEP_METRICS
+
+        model_state = getattr(abstract, "model_state", None)
+        try:
+            return self._restore_renamed(step, abstract)
+        except Exception as first:
+            if not isinstance(model_state, dict) or (
+                STEP_METRICS not in model_state
+            ):
+                raise
+            rest = {
+                k: v for k, v in model_state.items() if k != STEP_METRICS
+            }
+            try:
+                restored = self._restore_renamed(
+                    step, abstract.replace(model_state=rest)
+                )
+            except Exception:
+                raise first from None   # the first failure is the real one
+        logger.info(
+            "checkpoint step %d holds no step metrics; zeros restored",
+            step,
+        )
+        sown = jax.tree.map(
+            lambda x: jnp.zeros(x.shape, x.dtype, device=x.sharding),
+            model_state[STEP_METRICS],
+        )
+        return restored.replace(
+            model_state={**restored.model_state, STEP_METRICS: sown}
+        )
+
+    def _restore_renamed(self, step: int, abstract: Any) -> Any:
         """StandardRestore, with a legacy-key migration fallback: round 4
         renamed the GPipe stack param `stack` -> `gpipe_stack` (ADVICE
         r4) — a pre-rename checkpoint restores by renaming the keys in

@@ -16,18 +16,22 @@ an isolated table of that capacity.  The arena parameter is named
 "embedding" so `embedding_param_sharding` row-shards it over the mesh
 `model` axis exactly like individual tables.
 
-The VJP stays the plain gather/scatter-add pair
-(`embedding.py:_lookup`) per the round-4 re-measurement
-(docs/embedding_design_note.md): the scatter is the ceiling; fancier
-backwards lost.  Note the round-5 finding also stands: do NOT fuse
-tables of DIFFERENT dims into one padded arena — lane padding eats the
-win.  One arena per distinct dim.
+The VJP is `embedding.py:_lookup`'s: a PROMISE_IN_BOUNDS gather forward
+and `scatter_add_rows` backward, which combines the batch's duplicate
+rows and scatters the DISTINCT ones in chunks (PR 32,
+docs/embedding_design_note.md: the chip's scatter costs ~100 ns per
+update of its static update count, so 1.7M updates cost 178 ms whatever
+the ids, and the distinct 2% of them cost 33 with the combine).  A
+(rows, 1) arena and rows of 512 bytes and more keep XLA's plain scatter.
+The round-5 finding also stands: do NOT fuse tables of DIFFERENT dims
+into one padded arena — lane padding eats the win.  One arena per
+distinct dim.
 
 Quantized storage (`arena_dtype="int8"`, docs/PERF.md "Quantized
 arena"): rows live as int8 codes with a per-row fp32 scale — a second
 plane alongside the arena — and are dequantized INSIDE the fused
 gather, so the step still issues one (code+scale) gather and one
-scatter-add regardless of feature count while the dominant
+backward regardless of feature count while the dominant
 bytes-accessed term shrinks ~4x.  The gradient/optimizer path stays
 fp32: a zero fp32 "carrier" parameter keeps the trainable name/shape,
 `_grad_tap` routes the scatter-add gradient into it, and
@@ -51,9 +55,10 @@ import numpy as np
 
 from elasticdl_tpu.layers.embedding import (
     _PIB,
-    _lookup,
     hash_ids,
     hash_ids_host,
+    lookup_rows,
+    scatter_add_rows,
 )
 
 ARENA_DTYPES = ("float32", "int8")
@@ -121,7 +126,7 @@ def stochastic_round(x, key):
 
 
 @jax.custom_vjp
-def _grad_tap(carrier, flat_ids):
+def _grad_tap(carrier, flat_ids, order=None):
     """Gradient collector for the quantized arena.
 
     Forward contributes exact ZEROS shaped like the gather output —
@@ -129,23 +134,22 @@ def _grad_tap(carrier, flat_ids):
     away and never reads the fp32 carrier's bytes; the int8 planes are
     the only table bytes the forward touches.  Backward scatter-adds
     the output cotangent into the carrier's shape — the same
-    scatter-add `_lookup` produces for an fp32 table — so the optimizer
+    scatter-add `_lookup` produces for an fp32 table
+    (`scatter_add_rows`; `order` as `_lookup`'s) — so the optimizer
     sees an ordinary fp32 embedding gradient on the zero carrier and
     `fold_quantized_updates` later folds the resulting delta into the
     codes."""
     return jnp.zeros(flat_ids.shape + (carrier.shape[1],), carrier.dtype)
 
 
-def _grad_tap_fwd(carrier, flat_ids):
-    return _grad_tap(carrier, flat_ids), (carrier, flat_ids)
+def _grad_tap_fwd(carrier, flat_ids, order):
+    return _grad_tap(carrier, flat_ids), (carrier, flat_ids, order)
 
 
 def _grad_tap_bwd(residuals, g):
-    carrier, flat_ids = residuals
-    dcarrier = (
-        jnp.zeros(carrier.shape, g.dtype).at[flat_ids].add(g, mode=_PIB)
-    )
-    return dcarrier.astype(carrier.dtype), None
+    carrier, flat_ids, order = residuals
+    dcarrier = scatter_add_rows(carrier.shape, flat_ids, g, order)
+    return dcarrier.astype(carrier.dtype), None, None
 
 
 _grad_tap.defvjp(_grad_tap_fwd, _grad_tap_bwd)
@@ -176,7 +180,7 @@ class EmbeddingArena(nn.Module):
     Call with a dict {name: int ids of any shape (..., )}; returns
     {name: (..., output_dim)} vectors.  All features' ids are hashed
     into arena rows, concatenated, and looked up with ONE `_lookup`
-    (one gather forward, one scatter-add backward).
+    (one gather forward, one `scatter_add_rows` backward).
 
     Call with `prehashed=True` and a single int32 array of arena rows
     (host-hashed via `arena_rows_host` / the dedup'd wire format) to
@@ -231,7 +235,9 @@ class EmbeddingArena(nn.Module):
                     q8.at[flat_rows].get(mode=_PIB),
                     scale.at[flat_rows].get(mode=_PIB),
                 )
-                return deq + _grad_tap(carrier, flat_rows)
+                return deq + lookup_rows(
+                    self, carrier, flat_rows, _grad_tap
+                )
         else:
             table = self.param(
                 "embedding",
@@ -241,7 +247,7 @@ class EmbeddingArena(nn.Module):
             )
 
             def lookup(flat_rows):
-                return _lookup(table, flat_rows)
+                return lookup_rows(self, table, flat_rows)
 
         if prehashed:
             rows = jnp.asarray(ids)
@@ -378,7 +384,9 @@ class TieredArena(nn.Module):
                     q8.at[flat_rows].get(mode=_PIB),
                     scale.at[flat_rows].get(mode=_PIB),
                 )
-                return deq + _grad_tap(carrier, flat_rows)
+                return deq + lookup_rows(
+                    self, carrier, flat_rows, _grad_tap
+                )
         else:
             # Same initializer as the flat arena: a slot that is never
             # admitted before first use behaves like a fresh flat-arena
@@ -391,7 +399,7 @@ class TieredArena(nn.Module):
             )
 
             def lookup(flat_rows):
-                return _lookup(table, flat_rows)
+                return lookup_rows(self, table, flat_rows)
 
         rows = jnp.asarray(slots)
         flat = rows.reshape(-1)

@@ -44,6 +44,7 @@ from elasticdl_tpu.common.export import (
     read_export_meta,
 )
 from elasticdl_tpu.common.log_utils import get_logger
+from elasticdl_tpu.layers.moe import STEP_METRICS
 from elasticdl_tpu.parallel import mesh as mesh_lib
 from elasticdl_tpu.worker.trainer import (
     model_has_train_kwarg,
@@ -216,6 +217,10 @@ class ServingEngine:
             lambda: spec.model.init(jax.random.PRNGKey(0), x, **kwargs)
         )
         init_shapes = dict(init_shapes)
+        # the last train step's scalars (layers/moe.py): nothing a
+        # forward reads, and an export from before the model sowed any
+        # has none
+        init_shapes.pop(STEP_METRICS, None)
         template = {
             "params": {"params": init_shapes.pop("params")},
             "model_state": init_shapes,

@@ -71,6 +71,15 @@ _moe_dropped = metrics_lib.default_registry().counter(
     "slots routed to a held expert that got no row (sorted dispatch has "
     "a worst-case buffer: stays 0)",
 )
+# What a narrow-row lookup sows there (layers/embedding.py: lookup_rows):
+# the share of the batch's looked-up rows that are distinct, by table.
+_arena_distinct = metrics_lib.default_registry().gauge(
+    "worker_arena_distinct_rows_ratio",
+    "distinct table rows / looked-up rows of the batch (what the "
+    "embedding backward scatters over what it was handed), last step of "
+    "the task",
+    labelnames=("table",),
+)
 # Step-phase attribution (ISSUE 5) and spans (ISSUE 24): the process's
 # one PhaseTimer, shared by the threaded and SPMD loops.  Module-level
 # for the same __new__ reason as the counters above.
@@ -541,6 +550,8 @@ class Worker:
                     _moe_gauges[name].labels(layer=layer).set(value)
                 elif name == "dropped_tokens":
                     _moe_dropped.inc(value)
+                elif name == "distinct_rows_ratio":
+                    _arena_distinct.labels(table=layer).set(value)
                 else:
                     scalars["train/" + path] = value
             self._summary.scalars(scalars, step=self._owner.step)

@@ -147,3 +147,133 @@ def test_gradients_flow_only_through_looked_up_rows():
     g = np.asarray(grads["params"]["embedding"])
     nonzero_rows = set(np.nonzero(np.abs(g).sum(axis=1))[0].tolist())
     assert nonzero_rows == {3, 7}
+
+
+# ---- the distinct-row backward (`scatter_add_rows`) -----------------------
+
+
+def _zipf(exponent, rows):
+    return lambda rng, n: (rng.zipf(exponent, n) % rows).astype(np.int32)
+
+
+# (ids of n updates, table rows, row width, CHUNK patched in)
+_SCATTER_CASES = {
+    "all_distinct": (
+        lambda rng, n: rng.permutation(8192)[:n].astype(np.int32),
+        8192, 16, 65536),
+    "zipf_1.5": (_zipf(1.5, 4096), 4096, 16, 65536),
+    "zipf_1.05": (_zipf(1.05, 4096), 4096, 16, 65536),
+    "one_id_for_every_update": (
+        lambda rng, n: np.full(n, 17, np.int32), 64, 16, 65536),
+    "distinct_an_exact_multiple_of_chunk": (
+        lambda rng, n: np.repeat(rng.permutation(4096)[:512], n // 512)
+        .astype(np.int32)[rng.permutation(n)], 4096, 16, 128),
+    "distinct_over_several_chunks": (_zipf(1.05, 4096), 4096, 16, 100),
+    "width_1_plain_path": (_zipf(1.5, 4096), 4096, 1, 256),
+    "width_2": (_zipf(1.5, 4096), 4096, 2, 256),
+    "ids_at_the_last_row": (
+        lambda rng, n: np.where(
+            rng.random(n) < 0.5, 4095, rng.integers(0, 4096, n)
+        ).astype(np.int32), 4096, 16, 64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_SCATTER_CASES))
+def test_scatter_add_rows_matches_a_float64_sum(case, monkeypatch):
+    from elasticdl_tpu.layers import embedding
+
+    make_ids, rows, width, chunk = _SCATTER_CASES[case]
+    monkeypatch.setattr(embedding, "CHUNK", chunk)
+    rng = np.random.default_rng(3)
+    n = 2048
+    ids = make_ids(rng, n)
+    assert ids.dtype == np.int32 and ids.shape == (n,)
+    distinct = len(np.unique(ids))
+    if case == "distinct_an_exact_multiple_of_chunk":
+        assert distinct == 4 * chunk
+    if case == "distinct_over_several_chunks":
+        assert distinct > 3 * chunk and distinct % chunk
+    g = rng.standard_normal((n, width), dtype=np.float32)
+    want = np.zeros((rows, width), np.float64)
+    np.add.at(want, ids, g.astype(np.float64))
+    got = np.asarray(jax.jit(
+        lambda i, u: embedding.scatter_add_rows((rows, width), i, u)
+    )(ids, g))
+    plain = np.asarray(jnp.zeros((rows, width)).at[ids].add(g))
+    # a tree sum of each run: at least as near the float64 sum as the
+    # plain scatter's serial one, up to a rounding of the largest total
+    ulp = np.abs(want).max() * 2.0 ** -23
+    assert np.abs(got - want).max() <= np.abs(plain - want).max() + 2 * ulp
+    np.testing.assert_allclose(got, want, atol=16 * ulp, rtol=0)
+    # rows no id named stay exactly zero
+    untouched = np.setdiff1d(np.arange(rows), ids)
+    assert not got[untouched].any()
+
+
+def _primitives(jaxpr, inside_loop=False, out=None):
+    """{(primitive name, inside a while loop)} over a jaxpr and every
+    jaxpr nested in it."""
+    out = set() if out is None else out
+    for eqn in jaxpr.eqns:
+        out.add((eqn.primitive.name, inside_loop))
+        for value in eqn.params.values():
+            nested = inside_loop or eqn.primitive.name == "while"
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, nested, out)
+    return out
+
+
+def test_wide_rows_keep_the_plain_scatter_and_narrow_rows_sort_and_loop():
+    ids = jnp.arange(64, dtype=jnp.int32) % 7
+
+    def backward_of(width):
+        layer = DistributedEmbedding(32, width, hash_input=False)
+        params = layer.init(jax.random.PRNGKey(0), ids)
+        found = _primitives(jax.make_jaxpr(jax.grad(
+            lambda p: jnp.sum(layer.apply(p, ids) ** 2)
+        ))(params).jaxpr)
+        return found, params
+
+    wide, params = backward_of(2048)
+    assert ("scatter-add", False) in wide
+    assert not {name for name, _ in wide} & {"sort", "while"}
+    assert "step_metrics" not in params            # a wide lookup sows none
+    narrow, params = backward_of(16)
+    assert ("sort", False) in narrow
+    assert ("scatter-add", True) in narrow         # inside the chunk loop
+    assert ("scatter-add", False) not in narrow
+    assert "step_metrics" in params
+
+
+def test_lookup_sows_the_share_of_distinct_rows():
+    layer = DistributedEmbedding(512, 8)
+    rng = np.random.default_rng(5)
+    ids = (rng.zipf(1.3, (64, 5)) % 1000).astype(np.int32)
+    variables = layer.init(jax.random.PRNGKey(0), ids)
+    _, sown = layer.apply(variables, ids, mutable=["step_metrics"])
+    rows = np.asarray(hash_ids(jnp.asarray(ids), 512))
+    assert float(sown["step_metrics"]["distinct_rows_ratio"]) == (
+        pytest.approx(len(np.unique(rows)) / rows.size)
+    )
+    # outside a step that takes the collection nothing is sown
+    assert layer.apply(variables, ids).shape == (64, 5, 8)
+
+
+def test_trainer_fetches_the_distinct_share_with_the_loss():
+    from elasticdl_tpu.worker.sync import ModelOwner
+
+    trainer = Trainer(
+        model=TinyEmbedModel.build(), optimizer=optax.adam(1e-2),
+        loss_fn=_loss,
+    )
+    owner = ModelOwner(trainer)
+    batch = _batch(1)
+    loss = owner.train_batch(batch)
+    value, sown = owner.fetch_loss(loss)
+    rows = np.asarray(hash_ids(jnp.asarray(batch["features"]), 256))
+    assert value == pytest.approx(float(loss))
+    assert sown["embedding_bag/distinct_rows_ratio"] == pytest.approx(
+        len(np.unique(rows)) / rows.size
+    )

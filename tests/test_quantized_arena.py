@@ -112,6 +112,57 @@ def test_forward_parity_fp32_vs_int8_on_integer_rows():
         )
 
 
+def test_int8_carrier_takes_the_fp32_arenas_backward(monkeypatch):
+    """`_grad_tap`'s backward is the fp32 table's (`scatter_add_rows`):
+    duplicates combined, the distinct rows walked in chunks, the share
+    of distinct rows sown under the int8 arena's own path."""
+    from elasticdl_tpu.layers import embedding
+
+    monkeypatch.setattr(embedding, "CHUNK", 16)   # several trips
+    ids = _ids(seed=4, batch=256)                 # 1,024 lookups, 96 rows
+    weights = {
+        name: jnp.asarray(np.random.RandomState(5).randn(
+            *np.shape(value), DIM).astype(np.float32))
+        for name, value in ids.items()
+    }
+
+    def grad_of(arena_dtype):
+        arena = _arena(arena_dtype)
+        variables = arena.init(jax.random.PRNGKey(0), ids)
+        rest = {k: v for k, v in variables.items() if k != "params"}
+
+        def loss(params):
+            out, sown = arena.apply(
+                {"params": params, **rest}, ids, mutable=["step_metrics"]
+            )
+            return sum(
+                jnp.sum(out[name] * weights[name]) for name in out
+            ), sown["step_metrics"]["distinct_rows_ratio"]
+
+        grad, ratio = jax.grad(loss, has_aux=True)(variables["params"])
+        return np.asarray(grad["embedding"]), float(ratio)
+
+    g32, ratio32 = grad_of("float32")
+    g8, ratio8 = grad_of("int8")
+    rows = _arena("float32").arena_rows_host(
+        {k: v.reshape(len(v), -1) for k, v in ids.items()}
+    )
+    assert ratio32 == ratio8 == pytest.approx(
+        len(np.unique(rows)) / rows.size
+    )
+    assert len(np.unique(rows)) > 4 * 16
+    np.testing.assert_array_equal(g8, g32)
+    want = np.zeros(g32.shape, np.float64)
+    np.add.at(
+        want, rows.reshape(-1),
+        np.concatenate([
+            np.asarray(weights[name], np.float64).reshape(256, -1, DIM)
+            for name, _ in FEATS
+        ], axis=1).reshape(-1, DIM),
+    )
+    np.testing.assert_allclose(g32, want, atol=1e-5)
+
+
 def test_bad_arena_dtype_rejected():
     with pytest.raises(ValueError, match="arena_dtype"):
         _arena("int4").init(jax.random.PRNGKey(0), _ids())
@@ -269,6 +320,28 @@ def test_dtype_mismatch_is_a_clear_error_not_an_aval_crash(tmp_path):
     with pytest.raises(ArenaDtypeMismatch):
         saver.maybe_restore(template)
     saver.close()
+
+
+def test_checkpoint_from_before_the_model_sowed_step_metrics(tmp_path):
+    """The share of distinct rows rides in `model_state`; a checkpoint
+    written when DeepFM's was empty still restores, the scalars as
+    `init` leaves them."""
+    _, trainer = _deepfm_trainer("float32")
+    state = _trained_state(trainer)
+    assert set(state.model_state) == {"step_metrics"}
+    saver = CheckpointSaver(str(tmp_path / "ckpt"), async_save=False)
+    assert saver.save(state.replace(model_state={}), force=True)
+    saver.wait_until_finished()
+    saver.close()
+    saver = CheckpointSaver(str(tmp_path / "ckpt"), async_save=False)
+    restored = saver.maybe_restore(state)
+    saver.close()
+    assert int(restored.step) == int(state.step)
+    assert jax.tree.leaves(restored.model_state) == [0.0]
+    for got, want in zip(
+        jax.tree.leaves(restored.params), jax.tree.leaves(state.params)
+    ):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_checkpoint_migrates_int8_to_fp32(tmp_path):

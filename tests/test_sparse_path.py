@@ -210,13 +210,52 @@ def test_arena_matches_per_feature_tables_bit_exact():
         return sum(jnp.sum(v * v) for v in vecs.values())
 
     arena_grad = jax.grad(arena_loss)(arena_params)["params"]["embedding"]
+    # to a few ulps, not bit for bit: the backward sums each run of equal
+    # rows as a tree over its positions in the SORTED batch, and a row's
+    # position differs between the two layouts
     offset = 0
     for name, cap in feats:
-        np.testing.assert_array_equal(
+        np.testing.assert_allclose(
             np.asarray(arena_grad[offset:offset + cap]),
             np.asarray(per_feature_grads[name]),
+            rtol=4 * 2.0 ** -23, atol=1e-7,
         )
         offset += cap
+
+
+def test_arena_sows_the_share_of_distinct_rows_per_table():
+    """Both wire paths (hashed on the device, prehashed on the host) sow
+    distinct rows / looked-up rows; a (rows, 1) table keeps XLA's scalar
+    scatter and sows nothing."""
+    from elasticdl_tpu.layers.arena import EmbeddingArena
+
+    feats = (("x", 32), ("y", 96))
+    rng = np.random.RandomState(2)
+    ids = {
+        "x": (rng.zipf(1.5, size=(64,)) % 5000).astype(np.int32),
+        "y": (rng.zipf(1.5, size=(64, 2)) % 5000).astype(np.int32),
+    }
+    assert "step_metrics" not in EmbeddingArena(feats, 1).init(
+        jax.random.PRNGKey(0), ids
+    )
+    for dim in (16, 2):
+        arena = EmbeddingArena(feats, dim)
+        variables = arena.init(jax.random.PRNGKey(0), ids)
+        rows = arena.arena_rows_host(
+            {k: v.reshape(64, -1) for k, v in ids.items()}
+        )
+        want = len(np.unique(rows)) / rows.size
+        assert want < 0.5
+        _, sown = arena.apply(variables, ids, mutable=["step_metrics"])
+        assert float(
+            sown["step_metrics"]["distinct_rows_ratio"]
+        ) == pytest.approx(want)
+        _, sown = arena.apply(
+            variables, rows, prehashed=True, mutable=["step_metrics"]
+        )
+        assert float(
+            sown["step_metrics"]["distinct_rows_ratio"]
+        ) == pytest.approx(want)
 
 
 def test_arena_prehashed_matches_hashed_path():

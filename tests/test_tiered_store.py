@@ -595,8 +595,15 @@ def parity():
 
 
 def test_parity_losses_bitwise_equal(parity):
-    for fl, tl in parity["losses"]:
-        assert fl == tl  # bitwise: same program, same admitted values
+    # the forward is bitwise the same program on the same admitted
+    # values; the steps after the first also carry the backward, which
+    # sums a row's duplicates as a tree over their positions in the
+    # SORTED batch (layers/embedding.py: scatter_add_rows), and a cache
+    # slot sorts elsewhere than its table row: a few ulps
+    losses = parity["losses"]
+    assert losses[0][0] == losses[0][1]
+    for fl, tl in losses:
+        assert fl == pytest.approx(tl, rel=4 * 2.0 ** -23)
 
 
 def test_parity_trained_rows_bitwise_equal(parity):
@@ -613,7 +620,11 @@ def test_parity_trained_rows_bitwise_equal(parity):
         np.arange(NUM_FIELDS)[None, :], probe["features"]["sparse"],
         parity["cap"],
     )
-    np.testing.assert_array_equal(flat_emb[rows], tier_emb[slots])
+    # to a few ulps of the largest trained value (see the losses' test)
+    np.testing.assert_allclose(
+        flat_emb[rows], tier_emb[slots], rtol=0,
+        atol=4 * 2.0 ** -23 * np.abs(flat_emb[rows]).max(),
+    )
 
 
 def test_parity_predict_within_few_ulp(parity):
