@@ -266,10 +266,11 @@ def flash_shapes_ok(q_shape, k_shape, causal: bool = False) -> bool:
     """Whether (B, L, H, D) q/k shapes satisfy a kernel's constraints.
     The resident kernel: tile shapes (L multiple of 128 or a sub-128
     multiple of 8, D <= 128) AND per-program K/V VMEM residency (k_len *
-    H * D within _MAX_KV_BLOCK_ELEMENTS).  Past those, CAUSAL
-    self-attention at a head width of whole lane tiles goes to the
-    streaming kernel (`stream_shapes_ok`), which holds one K/V tile at a
-    time.  Callers dispatch on THIS instead of catching ValueError from
+    H * D within _MAX_KV_BLOCK_ELEMENTS), q and k of one head count.  Past
+    those, CAUSAL self-attention at a head width of whole lane tiles goes
+    to the streaming kernel (`stream_shapes_ok`), which holds one K/V tile
+    at a time and takes grouped keys and values (q's heads a multiple of
+    k's).  Callers dispatch on THIS instead of catching ValueError from
     `flash_attention` — a blanket except around a traced call swallowed
     an unrelated shard_map vma error for a full round and silently
     downgraded the bench to the O(L^2) reference path (round-5 profile
@@ -287,7 +288,8 @@ def _resident_shapes_ok(q_shape, k_shape) -> bool:
 
     heads, dim = q_shape[2], q_shape[3]
     return not (
-        bad(q_shape[1])
+        heads != k_shape[2]
+        or bad(q_shape[1])
         or bad(k_shape[1])
         or dim > 128
         or k_shape[1] * heads * dim > _MAX_KV_BLOCK_ELEMENTS
@@ -331,7 +333,7 @@ def flash_attention(
             f"D={q.shape[3]}"
         )
     if not _resident_shapes_ok(q.shape, k.shape):
-        return _stream(q, k, v, float(scale))
+        return _stream(q, k, v, float(scale), None)
     return _flash(q, k, v, causal, scale)
 
 
@@ -345,97 +347,154 @@ def flash_attention(
 # in plain lax: nothing (B, H, L, L)-shaped exists for more than one tile
 # row, the tiles above the diagonal are never computed, and the backward
 # rebuilds each row's probabilities from the saved log-sum-exp.
+#
+# Both forms here take GROUPED keys and values (q of H heads over k, v of
+# Hkv, H a multiple of Hkv: query head h reads K/V head h // (H / Hkv))
+# and a WINDOW (query t sees keys s with t - window < s <= t): K/V are
+# never repeated to H heads, and keys outside the band are neither
+# computed nor read.
 
 _BLOCKED_TILE = 512
 
 
-def _tile_rows(length: int, tile: int):
-    """[(start, end)] of the query tiles; the keys of a row are [0, end)."""
+def _tile_rows(length: int, tile: int, window: Optional[int] = None):
+    """[(first key, start, end)] of the query tiles [start, end); the
+    keys of a row are [first key, end)."""
     tile = min(tile, length)
-    return [(s, min(s + tile, length)) for s in range(0, length, tile)]
+    return [
+        (0 if window is None else max(0, s - window + 1), s,
+         min(s + tile, length))
+        for s in range(0, length, tile)
+    ]
 
 
-def _row_logits(q_row, k_pre, start, scale):
-    """(B, H, rows, keys) f32 logits of one tile row, masked causally."""
+def _row_logits(q_row, k_pre, start, first, scale, window):
+    """(B, Hkv, G, rows, keys) f32 logits of one tile row of grouped
+    queries (B, rows, Hkv, G, D) against keys (B, keys, Hkv, D) that
+    begin at position `first`, masked to the causal band."""
     logits = jnp.einsum(
-        "bqhd,bkhd->bhqk", q_row, k_pre, preferred_element_type=jnp.float32
+        "bqhgd,bkhd->bhgqk", q_row, k_pre,
+        preferred_element_type=jnp.float32,
     ) * scale
     q_pos = start + jnp.arange(q_row.shape[1])[:, None]
-    k_pos = jnp.arange(k_pre.shape[1])[None, :]
-    return jnp.where(q_pos >= k_pos, logits, _NEG_INF)
+    k_pos = first + jnp.arange(k_pre.shape[1])[None, :]
+    seen = q_pos >= k_pos
+    if window is not None:
+        seen &= q_pos - k_pos < window
+    return jnp.where(seen, logits, _NEG_INF)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _blocked(q, k, v, scale, tile):
-    return _blocked_fwd(q, k, v, scale, tile)[0]
+def _grouped(q, kv_heads: int):
+    """(B, L, H, D) -> (B, L, Hkv, H / Hkv, D): a free view."""
+    batch, length, heads, dim = q.shape
+    return q.reshape(batch, length, kv_heads, heads // kv_heads, dim)
 
 
-def _blocked_fwd(q, k, v, scale, tile):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _blocked(q, k, v, scale, tile, window):
+    return _blocked_fwd(q, k, v, scale, tile, window)[0]
+
+
+def _blocked_fwd(q, k, v, scale, tile, window):
     outs, lses = [], []
-    for start, end in _tile_rows(q.shape[1], tile):
-        logits = _row_logits(q[:, start:end], k[:, :end], start, scale)
+    q5 = _grouped(q, k.shape[2])
+    for first, start, end in _tile_rows(q.shape[1], tile, window):
+        logits = _row_logits(
+            q5[:, start:end], k[:, first:end], start, first, scale, window
+        )
         m = logits.max(axis=-1, keepdims=True)
         p = jnp.exp(logits - m)
         l = p.sum(axis=-1, keepdims=True)
         out = jnp.einsum(
-            "bhqk,bkhd->bqhd", p.astype(v.dtype), v[:, :end],
+            "bhgqk,bkhd->bqhgd", p.astype(v.dtype), v[:, first:end],
             preferred_element_type=jnp.float32,
-        ) / l[..., 0].transpose(0, 2, 1)[..., None]
+        ) / l[..., 0].transpose(0, 3, 1, 2)[..., None]
         outs.append(out.astype(q.dtype))
-        lses.append((m + jnp.log(l))[..., 0])           # (B, H, rows)
-    out = jnp.concatenate(outs, axis=1)
-    lse = jnp.concatenate(lses, axis=2)
+        lses.append((m + jnp.log(l))[..., 0])           # (B, Hkv, G, rows)
+    out = jnp.concatenate(outs, axis=1).reshape(*q.shape[:3], v.shape[-1])
+    lse = jnp.concatenate(lses, axis=3)
     return out, (q, k, v, out, lse)
 
 
-def _blocked_bwd(scale, tile, residuals, g):
+def _blocked_bwd(scale, tile, window, residuals, g):
     q, k, v, out, lse = residuals
     g = g.astype(q.dtype)
     delta = (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
-    delta = delta.transpose(0, 2, 1)                    # (B, H, L)
+    delta = _grouped(delta[..., None], k.shape[2])[..., 0].transpose(
+        0, 2, 3, 1
+    )                                                   # (B, Hkv, G, L)
+    q5, g5 = _grouped(q, k.shape[2]), _grouped(g, k.shape[2])
     dqs = []
     dk = jnp.zeros(k.shape, jnp.float32)
     dv = jnp.zeros(v.shape, jnp.float32)
-    for start, end in _tile_rows(q.shape[1], tile):
-        q_row, g_row = q[:, start:end], g[:, start:end]
-        logits = _row_logits(q_row, k[:, :end], start, scale)
-        p = jnp.exp(logits - lse[:, :, start:end, None])
+    for first, start, end in _tile_rows(q.shape[1], tile, window):
+        q_row, g_row = q5[:, start:end], g5[:, start:end]
+        logits = _row_logits(
+            q_row, k[:, first:end], start, first, scale, window
+        )
+        p = jnp.exp(logits - lse[..., start:end, None])
         dp = jnp.einsum(
-            "bqhd,bkhd->bhqk", g_row, v[:, :end],
+            "bqhgd,bkhd->bhgqk", g_row, v[:, first:end],
             preferred_element_type=jnp.float32,
         )
-        ds = (p * (dp - delta[:, :, start:end, None]) * scale).astype(q.dtype)
+        ds = (p * (dp - delta[..., start:end, None]) * scale).astype(q.dtype)
         dqs.append(jnp.einsum(
-            "bhqk,bkhd->bqhd", ds, k[:, :end],
+            "bhgqk,bkhd->bqhgd", ds, k[:, first:end],
             preferred_element_type=jnp.float32,
         ).astype(q.dtype))
-        dk = dk.at[:, :end].add(jnp.einsum(
-            "bhqk,bqhd->bkhd", ds, q_row, preferred_element_type=jnp.float32,
+        dk = dk.at[:, first:end].add(jnp.einsum(
+            "bhgqk,bqhgd->bkhd", ds, q_row,
+            preferred_element_type=jnp.float32,
         ))
-        dv = dv.at[:, :end].add(jnp.einsum(
-            "bhqk,bqhd->bkhd", p.astype(q.dtype), g_row,
+        dv = dv.at[:, first:end].add(jnp.einsum(
+            "bhgqk,bqhgd->bkhd", p.astype(q.dtype), g_row,
             preferred_element_type=jnp.float32,
         ))
     return (
-        jnp.concatenate(dqs, axis=1), dk.astype(k.dtype), dv.astype(v.dtype)
+        jnp.concatenate(dqs, axis=1).reshape(q.shape), dk.astype(k.dtype),
+        dv.astype(v.dtype),
     )
 
 
 _blocked.defvjp(_blocked_fwd, _blocked_bwd)
 
 
+def _grouped_shapes_ok(q_shape, k_shape, v_shape) -> bool:
+    """Self-attention over grouped keys and values: one batch and length,
+    q's heads a multiple of k's, k and v alike (v's width apart)."""
+    return (
+        tuple(q_shape[:2]) == tuple(k_shape[:2])
+        and tuple(k_shape[:3]) == tuple(v_shape[:3])
+        and q_shape[3] == k_shape[3]
+        and k_shape[2] > 0 and q_shape[2] % k_shape[2] == 0
+    )
+
+
 def blocked_causal_attention(q, k, v, scale: Optional[float] = None,
-                             tile: int = _BLOCKED_TILE):
-    """Causal self-attention, q/k/v (B, L, H, D) -> (B, L, H, D), any head
-    width, one row of `tile` queries at a time (module comment above)."""
-    if q.shape != k.shape or k.shape[:3] != v.shape[:3]:
+                             tile: int = _BLOCKED_TILE,
+                             window: Optional[int] = None):
+    """Causal self-attention, q (B, L, H, D) over k (B, L, Hkv, D) and
+    v (B, L, Hkv, Dv) -> (B, L, H, Dv), any head width, one row of
+    `tile` queries at a time (module comment above)."""
+    if not _grouped_shapes_ok(q.shape, k.shape, v.shape):
         raise ValueError(
-            f"blocked_causal_attention is self-attention: q {q.shape}, "
-            f"k {k.shape}, v {v.shape} must agree (v's width apart)"
+            f"blocked_causal_attention is self-attention over grouped "
+            f"keys and values: q {q.shape}, k {k.shape}, v {v.shape} must "
+            f"agree (v's width and a whole group of q's heads a K/V head "
+            f"apart)"
         )
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    return _blocked(q, k, v, float(scale), int(tile))
+    return _blocked(q, k, v, float(scale), int(tile), _band(window, q))
+
+
+def _band(window, q) -> Optional[int]:
+    """`window` as the kernels take it: None where it hides no key."""
+    if window is None or window >= q.shape[1]:
+        return None
+    if window < 1:
+        raise ValueError(f"a window of {window} keys sees nothing")
+    return int(window)
 
 
 # ---- the streaming kernel: causal, head width a multiple of 128 ----------
@@ -450,6 +509,13 @@ def blocked_causal_attention(q, k, v, scale: Optional[float] = None,
 # The backward is two kernels from the saved log-sum-exp, as the blocked
 # form above but tile by tile: dK/dV with the key tile resident and the
 # query tiles streaming, dQ with the query tile resident.
+#
+# Grouped K/V: query head h takes the K/V column block h // group through
+# the index map; the dK/dV grid runs over the K/V heads and its inner axis
+# over (query head of the group, query tile), so a K/V head's gradient
+# sums over its group in scratch.  A window: the inner axis is as long as
+# the band (`_band_steps` tiles), the tiles outside it are never visited,
+# and both of its edges are masked in the tile (`_mask_tile`).
 
 _STREAM_TILE = 512
 _LANES = 128
@@ -464,20 +530,60 @@ def _stream_tiles(length: int):
 
 def stream_shapes_ok(q_shape, k_shape, v_shape) -> bool:
     """Whether the streaming kernel takes causal self-attention at these
-    (B, L, H, D) shapes: q, k and v alike, L whole 128-tiles, D whole
-    lane tiles."""
+    (B, L, H, D) shapes: k and v alike at q's batch, length and width, q's
+    heads a multiple of theirs (grouped keys and values; the same count
+    is a group of one), L whole 128-tiles, D whole lane tiles.  A window
+    asks nothing more."""
     return (
-        tuple(q_shape) == tuple(k_shape) == tuple(v_shape)
+        _grouped_shapes_ok(q_shape, k_shape, v_shape)
+        and tuple(k_shape) == tuple(v_shape)
         and q_shape[3] % _LANES == 0
         and _stream_tiles(q_shape[1]) is not None
     )
 
 
-def _causal_tile(s, i, j, tile):
+def _band_steps(num: int, tile: int, window: Optional[int]) -> int:
+    """Tiles of the other kind one tile meets: all `num` under the causal
+    mask alone (those past the diagonal are skipped in place), under a
+    window the diagonal's and the ceil((window - 1) / tile) before it."""
+    if window is None:
+        return num
+    return min(num, -(-(window - 1) // tile) + 1)
+
+
+def _mask_tile(s, i, j, tile, window):
     """Mask logits of query tile i against key tile j."""
     q_pos = i * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     k_pos = j * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+    if window is None:
+        return jnp.where(q_pos >= k_pos, s, _NEG_INF)
+    return jnp.where(
+        (q_pos >= k_pos) & (q_pos - k_pos < window), s, _NEG_INF
+    )
+
+
+def _key_tile(i, y, steps, window):
+    """The key tile inner step y of query tile i holds: step y is key tile
+    y under the causal mask alone; the band's `steps` tiles end on the
+    diagonal."""
+    return y if window is None else i - (steps - 1) + y
+
+
+def _key_live(i, j, window):
+    """Whether key tile j is one query tile i meets: under the causal
+    mask alone the steps past the diagonal are skipped, under a window
+    those before the sequence's first tile."""
+    return j <= i if window is None else j >= 0
+
+
+def _query_tile(j, x, window):
+    """The query tile inner step x of key tile j holds; the band's tiles
+    begin on the diagonal."""
+    return x if window is None else j + x
+
+
+def _query_live(i, j, num, window):
+    return i >= j if window is None else i < num
 
 
 def _dot(a, b, contract):
@@ -487,23 +593,28 @@ def _dot(a, b, contract):
 
 
 def _stream_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
-                       acc_sc, *, scale: float, tile: int, num_k: int):
-    i, j = pl.program_id(2), pl.program_id(3)
+                       acc_sc, *, scale: float, tile: int, steps: int,
+                       window):
+    i, y = pl.program_id(2), pl.program_id(3)
+    j = _key_tile(i, y, steps, window)
 
-    @pl.when(j == 0)
+    @pl.when(y == 0)
     def _():
         m_sc[...] = jnp.full(m_sc.shape, _NEG_INF, jnp.float32)
         l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
         acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
 
-    @pl.when(j <= i)
+    @pl.when(_key_live(i, j, window))
     def _():
         q, k, v = q_ref[0], k_ref[0], v_ref[0]
-        s = _causal_tile(
-            _dot(q, k, ((1,), (1,))) * scale, i, j, tile
+        s = _mask_tile(
+            _dot(q, k, ((1,), (1,))) * scale, i, j, tile, window
         )                                               # (tile, tile)
         m_prev = m_sc[:, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        # a row the band hides from this whole tile reads exp(0) here;
+        # the diagonal's tile comes last, holds a key of every row and
+        # scales what such a row gathered by exp(-1e30 - m) = 0
         p = jnp.exp(s - m_new)
         correction = jnp.exp(m_prev - m_new)
         l_new = l_sc[:, :1] * correction + p.sum(axis=-1, keepdims=True)
@@ -513,7 +624,7 @@ def _stream_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
         m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
         l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
 
-    @pl.when(j == num_k - 1)
+    @pl.when(y == steps - 1)
     def _():
         l = l_sc[:, :1]
         o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
@@ -522,19 +633,23 @@ def _stream_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
 
 def _stream_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                        dk_ref, dv_ref, dk_sc, dv_sc, *, scale: float,
-                       tile: int, num_q: int):
-    j, i = pl.program_id(2), pl.program_id(3)
+                       tile: int, num: int, steps: int, group: int,
+                       window):
+    j, x = pl.program_id(2), pl.program_id(3)
+    # the inner axis: the group's query heads in turn, `steps` query
+    # tiles each
+    i = _query_tile(j, x if group == 1 else x % steps, window)
 
-    @pl.when(i == 0)
+    @pl.when(x == 0)
     def _():
         dk_sc[...] = jnp.zeros(dk_sc.shape, jnp.float32)
         dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
 
-    @pl.when(i >= j)
+    @pl.when(_query_live(i, j, num, window))
     def _():
         q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
-        s = _causal_tile(
-            _dot(q, k, ((1,), (1,))) * scale, i, j, tile
+        s = _mask_tile(
+            _dot(q, k, ((1,), (1,))) * scale, i, j, tile, window
         )
         p = jnp.exp(s - lse_ref[0, 0])                  # (tile q, tile k)
         dv_sc[...] += _dot(p.astype(g.dtype), g, ((0,), (0,)))
@@ -542,7 +657,7 @@ def _stream_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         ds = (p * (dp - delta_ref[0, 0]) * scale).astype(q.dtype)
         dk_sc[...] += _dot(ds, q, ((0,), (0,)))
 
-    @pl.when(i == num_q - 1)
+    @pl.when(x == group * steps - 1)
     def _():
         dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
@@ -550,25 +665,26 @@ def _stream_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 
 def _stream_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                       dq_ref, dq_sc, *, scale: float, tile: int,
-                      num_k: int):
-    i, j = pl.program_id(2), pl.program_id(3)
+                      steps: int, window):
+    i, y = pl.program_id(2), pl.program_id(3)
+    j = _key_tile(i, y, steps, window)
 
-    @pl.when(j == 0)
+    @pl.when(y == 0)
     def _():
         dq_sc[...] = jnp.zeros(dq_sc.shape, jnp.float32)
 
-    @pl.when(j <= i)
+    @pl.when(_key_live(i, j, window))
     def _():
         q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
-        s = _causal_tile(
-            _dot(q, k, ((1,), (1,))) * scale, i, j, tile
+        s = _mask_tile(
+            _dot(q, k, ((1,), (1,))) * scale, i, j, tile, window
         )
         p = jnp.exp(s - lse_ref[0, 0])
         dp = _dot(g, v, ((1,), (1,)))
         ds = (p * (dp - delta_ref[0, 0]) * scale).astype(k.dtype)
         dq_sc[...] += _dot(ds, k, ((1,), (0,)))
 
-    @pl.when(j == num_k - 1)
+    @pl.when(y == steps - 1)
     def _():
         dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
 
@@ -596,97 +712,153 @@ def _stream_call(kernel, grid, in_specs, out_specs, out_shape, scratch,
     )(*operands)
 
 
+def _same_head(h, x, y):
+    return h
+
+
 def _stream_specs(tile: int, dim: int):
     """Block specs by role, for a grid (batch, head, outer tile, inner
-    tile): `row` follows the query tile and `col` the key tile, each
-    clamped to the causal triangle so a skipped step moves nothing."""
-    def tiles(which):
+    step): `which` gives the tile a block follows, clamped to the tiles
+    its kernel visits so that a skipped step moves nothing, and `head`
+    the column block (the grid's own head unless said)."""
+    def tiles(which, head=_same_head):
         return pl.BlockSpec(
-            (1, tile, dim), lambda b, h, x, y: (b, which(x, y), h)
+            (1, tile, dim),
+            lambda b, h, x, y: (b, which(x, y), head(h, x, y)),
         )
 
-    def per_row(which):
+    def per_row(which, head=_same_head):
         return pl.BlockSpec(
-            (1, 1, tile, 1), lambda b, h, x, y: (b, h, which(x, y), 0)
+            (1, 1, tile, 1),
+            lambda b, h, x, y: (b, head(h, x, y), which(x, y), 0),
         )
 
     return tiles, per_row
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _stream(q, k, v, scale):
-    return _stream_fwd(q, k, v, scale)[0]
+def _stream_names(window) -> str:
+    """The kernels' names in the trace: a windowed call is timed apart."""
+    return "causal_attention" if window is None else "window_attention"
 
 
-def _stream_fwd(q, k, v, scale):
+def _resident_row(i, y):
+    return i
+
+
+def _streamed_keys(steps: int, window):
+    """Index of the key tile a (query tile i, inner step y) block holds:
+    keys past the diagonal stay on the diagonal's tile, keys before the
+    band's first tile on tile 0."""
+    def keys(i, y):
+        j = _key_tile(i, y, steps, window)
+        return jnp.minimum(i, j) if window is None else jnp.maximum(j, 0)
+
+    return keys
+
+
+def _kv_head(group: int):
+    """The K/V column block of the grid's query head."""
+    return _same_head if group == 1 else (lambda h, x, y: h // group)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _stream(q, k, v, scale, window):
+    return _stream_fwd(q, k, v, scale, window)[0]
+
+
+def _stream_fwd(q, k, v, scale, window):
     batch, length, heads, dim = q.shape
+    group = heads // k.shape[2]
     tile = _stream_tiles(length)
     num = length // tile
+    steps = _band_steps(num, tile, window)
     tiles, per_row = _stream_specs(tile, dim)
+    keys, kv_head = _streamed_keys(steps, window), _kv_head(group)
     flat = (batch, length, heads * dim)
-    # grid (b, h, i over queries, j over keys): keys past the diagonal
-    # stay on the diagonal's tile
+    # grid (b, h, i over queries, y over the keys i meets)
     out, lse = _stream_call(
         functools.partial(
-            _stream_fwd_kernel, scale=scale, tile=tile, num_k=num
+            _stream_fwd_kernel, scale=scale, tile=tile, steps=steps,
+            window=window,
         ),
-        (batch, heads, num, num),
-        [tiles(lambda i, j: i), tiles(lambda i, j: jnp.minimum(i, j)),
-         tiles(lambda i, j: jnp.minimum(i, j))],
-        [tiles(lambda i, j: i), per_row(lambda i, j: i)],
+        (batch, heads, num, steps),
+        [tiles(_resident_row), tiles(keys, kv_head), tiles(keys, kv_head)],
+        [tiles(_resident_row), per_row(_resident_row)],
         [(flat, q.dtype), ((batch, heads, length, 1), jnp.float32)],
         [pltpu.VMEM((tile, _LANES), jnp.float32),
          pltpu.VMEM((tile, _LANES), jnp.float32),
          pltpu.VMEM((tile, dim), jnp.float32)],
-        [t.reshape(flat) for t in (q, k, v)],
-        "causal_attention_fwd",
+        [t.reshape(*t.shape[:2], -1) for t in (q, k, v)],
+        _stream_names(window) + "_fwd",
     )
     out = out.reshape(q.shape)
     return out, (q, k, v, out, lse)
 
 
-def _stream_bwd(scale, residuals, g):
+def _stream_bwd(scale, window, residuals, g):
     q, k, v, out, lse = residuals
     batch, length, heads, dim = q.shape
+    kv_heads = k.shape[2]
+    group = heads // kv_heads
     tile = _stream_tiles(length)
     num = length // tile
+    steps = _band_steps(num, tile, window)
     tiles, per_row = _stream_specs(tile, dim)
     flat = (batch, length, heads * dim)
+    flat_kv = (batch, length, kv_heads * dim)
     g = g.astype(q.dtype)
     delta = (
         (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
         .transpose(0, 2, 1)[..., None]
     )                                                   # (B, H, L, 1)
-    operands = [t.reshape(flat) for t in (q, k, v, g)] + [lse, delta]
-    # grid (b, h, j over keys, i over queries): queries before the
-    # diagonal stay on the diagonal's tile
+    operands = [
+        t.reshape(*t.shape[:2], -1) for t in (q, k, v, g)
+    ] + [lse, delta]
+    # grid (b, K/V head, j over keys, x over the group's heads times the
+    # query tiles j meets): queries before the diagonal stay on the
+    # diagonal's tile, queries past the last tile on the last
+    def in_group(j, x):
+        i = _query_tile(j, x if group == 1 else x % steps, window)
+        return jnp.maximum(i, j) if window is None else jnp.minimum(
+            i, num - 1
+        )
+
+    q_head = _same_head if group == 1 else (
+        lambda h, j, x: h * group + x // steps
+    )
+
+    def resident_key(j, x):
+        return j
+
     dk, dv = _stream_call(
         functools.partial(
-            _stream_dkv_kernel, scale=scale, tile=tile, num_q=num
+            _stream_dkv_kernel, scale=scale, tile=tile, num=num,
+            steps=steps, group=group, window=window,
         ),
-        (batch, heads, num, num),
-        [tiles(lambda j, i: jnp.maximum(i, j)), tiles(lambda j, i: j),
-         tiles(lambda j, i: j), tiles(lambda j, i: jnp.maximum(i, j)),
-         per_row(lambda j, i: jnp.maximum(i, j)),
-         per_row(lambda j, i: jnp.maximum(i, j))],
-        [tiles(lambda j, i: j), tiles(lambda j, i: j)],
-        [(flat, k.dtype), (flat, v.dtype)],
+        (batch, kv_heads, num, group * steps),
+        [tiles(in_group, q_head), tiles(resident_key),
+         tiles(resident_key), tiles(in_group, q_head),
+         per_row(in_group, q_head), per_row(in_group, q_head)],
+        [tiles(resident_key), tiles(resident_key)],
+        [(flat_kv, k.dtype), (flat_kv, v.dtype)],
         [pltpu.VMEM((tile, dim), jnp.float32),
          pltpu.VMEM((tile, dim), jnp.float32)],
-        operands, "causal_attention_dkv",
+        operands, _stream_names(window) + "_dkv",
     )
+    keys, kv_head = _streamed_keys(steps, window), _kv_head(group)
     (dq,) = _stream_call(
         functools.partial(
-            _stream_dq_kernel, scale=scale, tile=tile, num_k=num
+            _stream_dq_kernel, scale=scale, tile=tile, steps=steps,
+            window=window,
         ),
-        (batch, heads, num, num),
-        [tiles(lambda i, j: i), tiles(lambda i, j: jnp.minimum(i, j)),
-         tiles(lambda i, j: jnp.minimum(i, j)), tiles(lambda i, j: i),
-         per_row(lambda i, j: i), per_row(lambda i, j: i)],
-        [tiles(lambda i, j: i)],
+        (batch, heads, num, steps),
+        [tiles(_resident_row), tiles(keys, kv_head), tiles(keys, kv_head),
+         tiles(_resident_row), per_row(_resident_row),
+         per_row(_resident_row)],
+        [tiles(_resident_row)],
         [(flat, q.dtype)],
         [pltpu.VMEM((tile, dim), jnp.float32)],
-        operands, "causal_attention_dq",
+        operands, _stream_names(window) + "_dq",
     )
     return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
@@ -694,16 +866,20 @@ def _stream_bwd(scale, residuals, g):
 _stream.defvjp(_stream_fwd, _stream_bwd)
 
 
-def causal_attention(q, k, v, scale: Optional[float] = None):
-    """Causal self-attention for a decoder's train step, (B, L, H, D) ->
-    (B, L, H, Dv), at any length and head width: the one entry a model
-    calls.  The streaming Pallas kernel where the shapes tile
-    (`stream_shapes_ok`), the blocked lax form elsewhere (the same
-    mathematics, one row of query tiles at a time)."""
+def causal_attention(q, k, v, scale: Optional[float] = None,
+                     window: Optional[int] = None):
+    """Causal self-attention for a decoder's train step, q (B, L, H, D)
+    over k, v (B, L, Hkv, D), H a multiple of Hkv (grouped keys and
+    values: query head h reads K/V head h // (H / Hkv)) -> (B, L, H, Dv),
+    at any length and head width: the one entry a model calls.  With
+    `window`, query t sees the keys s with t - window < s <= t.  The
+    streaming Pallas kernels where the shapes tile (`stream_shapes_ok`),
+    the blocked lax form elsewhere (the same mathematics, one row of
+    query tiles at a time)."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     from elasticdl_tpu.parallel.mesh import in_export_mode
 
     if stream_shapes_ok(q.shape, k.shape, v.shape) and not in_export_mode():
-        return _stream(q, k, v, float(scale))
-    return blocked_causal_attention(q, k, v, scale)
+        return _stream(q, k, v, float(scale), _band(window, q))
+    return blocked_causal_attention(q, k, v, scale, window=window)

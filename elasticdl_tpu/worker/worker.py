@@ -71,6 +71,14 @@ _moe_dropped = metrics_lib.default_registry().counter(
     "slots routed to a held expert that got no row (sorted dispatch has "
     "a worst-case buffer: stays 0)",
 )
+# What a gated attention layer sows there (model_zoo/laguna/laguna.py): the
+# mean of its per-head sigmoid output gate.
+_attention_gate = metrics_lib.default_registry().gauge(
+    "worker_attention_gate_mean_ratio",
+    "mean of the attention layer's sigmoid output gate over tokens and "
+    "heads, last step of the task (a gate that closes silences its layer)",
+    labelnames=("layer",),
+)
 # What a narrow-row lookup sows there (layers/embedding.py: lookup_rows):
 # the share of the batch's looked-up rows that are distinct, by table.
 _arena_distinct = metrics_lib.default_registry().gauge(
@@ -552,6 +560,8 @@ class Worker:
                     _moe_dropped.inc(value)
                 elif name == "distinct_rows_ratio":
                     _arena_distinct.labels(table=layer).set(value)
+                elif name == "gate_mean":
+                    _attention_gate.labels(layer=layer).set(value)
                 else:
                     scalars["train/" + path] = value
             self._summary.scalars(scalars, step=self._owner.step)

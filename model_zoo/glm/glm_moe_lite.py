@@ -39,62 +39,23 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
-import optax
 
-from elasticdl_tpu.layers.embedding import (
-    DistributedEmbedding,
-    embedding_param_sharding,
-)
-from elasticdl_tpu.layers.moe import (
-    AUX_LOSS,
-    RoutedExperts,
-    moe_param_sharding,
-    sow_step_metric,
-)
+from elasticdl_tpu.layers.embedding import DistributedEmbedding
+from elasticdl_tpu.layers.moe import AUX_LOSS, sow_step_metric
 from elasticdl_tpu.ops.flash_attention import causal_attention
 from model_zoo.bert.bert_finetune import feed, feed_bulk  # noqa: F401
-
-# Tokens whose logits exist at once in the cross-entropy.
-CE_BLOCK = 2048
-
-
-def rms_norm(x, scale, eps: float):
-    """Statistics in float32 whatever `x` is; float32 out."""
-    x = x.astype(jnp.float32)
-    return x * jax.lax.rsqrt(
-        jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps
-    ) * scale
-
-
-class RMSNorm(nn.Module):
-    eps: float = 1e-5
-    dtype: jnp.dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        return rms_norm(x, scale, self.eps).astype(self.dtype)
-
-
-def rotary(x, theta: float):
-    """Rotary embedding over the last axis of (B, L, H, R), position =
-    index along L, the HALVES pairing: column i turns with column
-    i + R/2 (the row of the catalog does not say which pairing the
-    checkpoint uses; with seeded weights the two differ by a permutation
-    of columns).  float32 inside."""
-    length, width = x.shape[1], x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
-    angle = jnp.arange(length, dtype=jnp.float32)[:, None] * inv_freq[None]
-    cos, sin = jnp.cos(angle)[None, :, None], jnp.sin(angle)[None, :, None]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    ).astype(x.dtype)
-
-
-def dense(features: int, name: str, dtype):
-    return nn.Dense(features, use_bias=False, name=name, dtype=dtype)
+from model_zoo.common.decoder import (  # noqa: F401
+    MoEFFN,
+    RMSNorm,
+    SwiGLU,
+    dense,
+    eval_metrics_fn,
+    loss,
+    optimizer,
+    param_sharding,
+    rotary,
+    shifted_nll,
+)
 
 
 class MLA(nn.Module):
@@ -155,53 +116,6 @@ class MLA(nn.Module):
             )
 
 
-class SwiGLU(nn.Module):
-    """(silu(x Wg) * (x Wu)) Wd, gate and up in one kernel."""
-
-    hidden: int
-    width: int
-    dtype: jnp.dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        gate, up = jnp.split(
-            dense(2 * self.width, "gate_up", self.dtype)(x), 2, axis=-1
-        )
-        return dense(self.hidden, "down", self.dtype)(nn.silu(gate) * up)
-
-
-class MoEFFN(nn.Module):
-    """The shared expert, computed by every holder alike, plus this
-    holder's part of the routed experts."""
-
-    hidden: int
-    num_experts: int
-    top_k: int
-    expert_width: int
-    shared_experts: int
-    held_experts: Optional[Tuple[int, int]]
-    routed_scaling: float
-    bias_update_rate: float
-    dtype: jnp.dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        with jax.named_scope("glm/moe"):
-            routed = RoutedExperts(
-                num_experts=self.num_experts, top_k=self.top_k,
-                ffn_dim=self.expert_width, held_experts=self.held_experts,
-                routed_scaling=self.routed_scaling,
-                bias_update_rate=self.bias_update_rate, dtype=self.dtype,
-                name="routed",
-            )(x)
-            with jax.named_scope("shared"):
-                shared = SwiGLU(
-                    self.hidden, self.shared_experts * self.expert_width,
-                    self.dtype, name="shared",
-                )(x)
-            return (routed + shared.astype(jnp.float32)).astype(self.dtype)
-
-
 class Block(nn.Module):
     """One pre-norm decoder block; `moe` picks its feed-forward."""
 
@@ -259,32 +173,6 @@ class GLMConfig:
     remat: bool
 
 
-def blocked_nll(h, head_kernel, targets, dtype, block: int = CE_BLOCK):
-    """(tokens,) float32 negative log-likelihood of `targets` under
-    softmax(h @ head_kernel), `block` tokens' logits at a time, each block
-    rebuilt in the backward: nothing (tokens, vocab)-shaped is ever held.
-    The kernel is cast inside the block so that its gradient sums over
-    the blocks in its own float32."""
-    tokens, hidden = h.shape
-    if tokens % block:
-        block = tokens
-
-    @jax.checkpoint
-    def one(args):
-        h_block, t_block = args
-        logits = jnp.dot(
-            h_block.astype(dtype), head_kernel.astype(dtype),
-            preferred_element_type=jnp.float32,
-        )
-        picked = jnp.take_along_axis(logits, t_block[:, None], axis=1)[:, 0]
-        return jax.nn.logsumexp(logits, axis=-1) - picked
-
-    return jax.lax.map(one, (
-        h.reshape(tokens // block, block, hidden),
-        targets.reshape(tokens // block, block),
-    )).reshape(tokens)
-
-
 class GLMMoELite(nn.Module):
     config: GLMConfig
 
@@ -292,7 +180,6 @@ class GLMMoELite(nn.Module):
     def __call__(self, features):
         c = self.config
         ids = features["input_ids"].astype(jnp.int32)        # (B, L)
-        batch, length = ids.shape
         embed = DistributedEmbedding(
             c.vocab_size, c.hidden, hash_input=False, name="token_embedding"
         )
@@ -306,15 +193,7 @@ class GLMMoELite(nn.Module):
         )
 
         def nll(h, shift):
-            """Per-position loss against the ids `shift` places on; the
-            positions with no such id read 0."""
-            targets = jnp.roll(ids, -shift, axis=1)
-            with jax.named_scope("glm/head_ce"):
-                out = blocked_nll(
-                    h.reshape(batch * length, c.hidden), head,
-                    targets.reshape(-1), c.dtype,
-                ).reshape(batch, length)
-            return out[:, :length - shift]
+            return shifted_nll(h, head, ids, shift, c.dtype, "glm/head_ce")
 
         main = nll(RMSNorm(c.eps, c.dtype, name="final_norm")(x), 1)
         sow_step_metric(self, "main_loss", main.mean())
@@ -368,31 +247,3 @@ def custom_model(
         mtp_loss_weight=mtp_loss_weight, rope_theta=rope_theta, eps=eps,
         dtype=jnp.bfloat16 if bf16 else jnp.float32, remat=remat,
     ))
-
-
-def loss(labels, predictions):
-    """`predictions` are the model's per-position negative
-    log-likelihoods of the next token; the record's label byte is not
-    used."""
-    return predictions.mean()
-
-
-def optimizer(lr: float = 1e-4):
-    return optax.adam(lr)
-
-
-def eval_metrics_fn():
-    return {
-        "perplexity": lambda labels, predictions: float(
-            np.exp(np.mean(predictions))
-        ),
-    }
-
-
-def param_sharding(path, value):
-    """Expert stacks over `expert`, the token embedding over `model`;
-    everything else replicated."""
-    spec = moe_param_sharding(path, value)
-    if spec is not None:
-        return spec
-    return embedding_param_sharding(path, value)

@@ -161,3 +161,158 @@ def test_blocked_causal_attention_any_width(dims):
         )
         for got, ref in zip(grads(fn), want):
             np.testing.assert_allclose(got, ref, rtol=2e-3, atol=2e-4)
+
+
+# ---- grouped keys and values, and a window, through `causal_attention` ----
+
+
+def _dense_band(q, k, v, window):
+    """Causal softmax attention as one dense masked product: q's head h
+    over K/V head h // group, query t over keys s with t - window < s <= t
+    (no window: every s <= t)."""
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    t = jnp.arange(q.shape[1])
+    seen = t[:, None] >= t[None, :]
+    if window is not None:
+        seen &= t[:, None] - t[None, :] < window
+    weights = jax.nn.softmax(jnp.where(seen, logits, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+
+
+def _grouped_qkv(group, length=384, dim=128, kv_heads=1, seed=5):
+    rng = np.random.RandomState(seed)
+
+    def draw(heads):
+        return jnp.asarray(
+            rng.randn(1, length, heads, dim).astype(np.float32) * 0.3
+        )
+
+    return draw(group * kv_heads), draw(kv_heads), draw(kv_heads)
+
+
+# three tiles of 128 a sequence: a window under a tile, of one tile, over
+# a tile, and one that hides nothing
+BANDS = [
+    pytest.param(group, window, id=f"group{group}-window{window}")
+    for group in (1, 6, 8) for window in (None, 100, 128, 200, 384)
+]
+
+
+@pytest.mark.parametrize("group, window", BANDS)
+def test_grouped_window_forward(group, window):
+    from elasticdl_tpu.ops.flash_attention import (
+        causal_attention,
+        stream_shapes_ok,
+    )
+
+    q, k, v = _grouped_qkv(group)
+    assert stream_shapes_ok(q.shape, k.shape, v.shape)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(
+            causal_attention(q, k, v, window=window),
+            _dense_band(q, k, v, window), rtol=2e-4, atol=2e-4,
+        )
+
+
+@pytest.mark.parametrize("group, window", BANDS)
+def test_grouped_window_gradients(group, window):
+    from elasticdl_tpu.ops.flash_attention import causal_attention
+
+    q, k, v = _grouped_qkv(group)
+
+    def grads(fn):
+        return jax.grad(
+            lambda q, k, v: (fn(q, k, v) ** 2).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        got = grads(lambda q, k, v: causal_attention(q, k, v, window=window))
+        want = grads(lambda q, k, v: _dense_band(q, k, v, window))
+    for name, a, b in zip("qkv", got, want):
+        np.testing.assert_allclose(
+            a, b, rtol=5e-4, atol=5e-4, err_msg=f"d{name}"
+        )
+
+
+@pytest.mark.parametrize("window", [None, 24, 40, 100])
+def test_blocked_form_takes_groups_and_a_window(window):
+    """Shapes that do not tile (width 16, 100 positions) go the blocked
+    way, two K/V heads under six query heads, rows of 40."""
+    from elasticdl_tpu.ops.flash_attention import (
+        blocked_causal_attention,
+        causal_attention,
+        stream_shapes_ok,
+    )
+
+    q, k, v = _grouped_qkv(3, length=100, dim=16, kv_heads=2)
+    assert not stream_shapes_ok(q.shape, k.shape, v.shape)
+
+    def grads(fn):
+        return jax.grad(
+            lambda q, k, v: (fn(q, k, v) ** 2).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    want = grads(lambda q, k, v: _dense_band(q, k, v, window))
+    for fn in (
+        lambda q, k, v: causal_attention(q, k, v, window=window),
+        lambda q, k, v: blocked_causal_attention(
+            q, k, v, tile=40, window=window
+        ),
+    ):
+        np.testing.assert_allclose(
+            fn(q, k, v), _dense_band(q, k, v, window), rtol=2e-4, atol=2e-5
+        )
+        for got, ref in zip(grads(fn), want):
+            np.testing.assert_allclose(got, ref, rtol=2e-3, atol=2e-4)
+
+
+def _kernel_names(q, k, v, window):
+    import re
+
+    from elasticdl_tpu.ops.flash_attention import causal_attention
+
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: causal_attention(q, k, v, window=window).sum(),
+        argnums=(0, 1, 2),
+    ))(q, k, v)
+    return sorted(set(re.findall(r"\b\w+_attention_\w+\b", str(jaxpr))))
+
+
+def test_kernel_names_in_the_trace():
+    """The metrics find the kernels by name: a decoder's shape of one
+    head count and no window (width 256) keeps the names it had, a window
+    that hides nothing is no window, and a windowed call is named apart."""
+    plain = ["causal_attention_dkv", "causal_attention_dq",
+             "causal_attention_fwd"]
+    wide = _grouped_qkv(2, length=256, dim=256, kv_heads=2)[1:] * 2
+    assert _kernel_names(*wide[:3], window=None) == plain
+    q, k, v = _grouped_qkv(6, length=256)
+    assert _kernel_names(q, k, v, window=None) == plain
+    assert _kernel_names(q, k, v, window=256) == plain
+    assert _kernel_names(q, k, v, window=200) == [
+        "window_attention_dkv", "window_attention_dq",
+        "window_attention_fwd",
+    ]
+
+
+def test_grouped_admission_rule():
+    from elasticdl_tpu.ops.flash_attention import (
+        causal_attention,
+        flash_shapes_ok,
+        stream_shapes_ok,
+    )
+
+    q, kv = (2, 8192, 64, 128), (2, 8192, 8, 128)
+    assert stream_shapes_ok(q, kv, kv) and flash_shapes_ok(q, kv, causal=True)
+    assert not flash_shapes_ok(q, kv)                      # not causal
+    assert not stream_shapes_ok(q, (2, 8192, 7, 128), (2, 8192, 7, 128))
+    assert not stream_shapes_ok(q, kv, (2, 8192, 8, 64))   # v's width
+    # a resident shape of unequal head counts is not the resident kernel's
+    assert not flash_shapes_ok((2, 128, 8, 64), (2, 128, 2, 64))
+    a, b, c = _grouped_qkv(3, length=64, dim=16)
+    with pytest.raises(ValueError, match="sees nothing"):
+        causal_attention(a, b, c, window=0)
+    with pytest.raises(ValueError, match="grouped"):
+        causal_attention(a, jnp.concatenate([b, b], axis=2)[:, :, :2], c)

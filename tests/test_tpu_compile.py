@@ -1,0 +1,79 @@
+"""The streaming attention kernels compiled for the chip WITHOUT the chip:
+the TPU's compiler is installed here and compiles for a described v5e, so
+what Mosaic refuses (a slice off the tiling, too much fast memory) fails
+here and not in a chip call.  Interpret-mode tests cannot show that.
+Nothing runs, so nothing here is a result or a time.
+
+The topology is described inside a fixture, never at import: only one
+process may hold the TPU's library, and every xdist worker imports every
+test file.  Keep such tests in THIS file."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from elasticdl_tpu.ops import flash_attention as fa
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable for a described chip is written to the persistent
+    # cache and cannot be read back without one: keep it out
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+# (q heads, K/V heads, head width, batch, length, window): the GLM cell's
+# call, the Laguna cell's full layers and its window layers
+SHAPES = [
+    pytest.param(20, 20, 256, 4, 4096, None, id="glm-mla"),
+    pytest.param(48, 8, 128, 2, 8192, None, id="laguna-full"),
+    pytest.param(64, 8, 128, 2, 8192, 512, id="laguna-window"),
+]
+
+
+@pytest.mark.parametrize("heads, kv_heads, dim, batch, length, window", SHAPES)
+def test_streaming_kernels_compile_for_the_chip(
+    one_chip, monkeypatch, heads, kv_heads, dim, batch, length, window
+):
+    # the kernels ask the default backend (the CPU here) whether to run
+    # interpreted: steer them to Mosaic for the described chip
+    monkeypatch.setattr(fa, "use_interpret", lambda: False)
+
+    def shaped(h):
+        return jax.ShapeDtypeStruct(
+            (batch, length, h, dim), jnp.bfloat16, sharding=one_chip
+        )
+
+    def grads(q, k, v):
+        return jax.grad(
+            lambda q, k, v: fa.causal_attention(
+                q, k, v, window=window
+            ).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    compiled = jax.jit(grads).lower(
+        shaped(heads), shaped(kv_heads), shaped(kv_heads)
+    ).compile()
+    text = compiled.as_text()
+    kind = "causal" if window is None else "window"
+    for kernel in ("fwd", "dkv", "dq"):
+        assert f"{kind}_attention_{kernel}" in text
+    assert text.count("tpu_custom_call") >= 3
+    # K/V stay at their own head count: nothing repeated to the query's
+    assert f"bf16[{batch},{length},{kv_heads * dim}]" in text
