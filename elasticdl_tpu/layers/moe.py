@@ -29,10 +29,16 @@ weigh, top-k of ALL experts, and a layer that is told which experts it
 holds (`held_experts`) and computes their part of the result.  One-hot
 dispatch cannot stand there (16,384 tokens x 64 experts x capacity), so
 the slots routed to held experts are SORTED by expert into one buffer of
-static worst-case size, the group sizes travel as data, and a grouped
-matrix product (`grouped_matmul`) does work in proportion to the rows
-routed: no token is dropped at any imbalance and nothing recompiles when
-the loads change.  Both layers count router load with `expert_loads`.
+static worst-case size (tokens x top_k rows), the group sizes travel as
+data, and the buffer is WALKED, `CHUNK` rows a trip, only as far as its
+last live row (`routed_walk`): the gather, the grouped products
+(`grouped_matmul`), SwiGLU, the slot weights and the scatter-add all
+cost what the rows routed here cost, rounded up to a chunk, forward and
+backward.  What still follows the worst case is index arithmetic: the
+sort, one int32 / float32 entry a slot, and the backward's four zeroed
+buffers.  No token is dropped at any imbalance and nothing recompiles
+when the loads change.  Both layers count router load with
+`expert_loads`.
 """
 
 from __future__ import annotations
@@ -42,6 +48,7 @@ from typing import Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import PartitionSpec as P
 
 
@@ -84,6 +91,188 @@ def grouped_matmul(lhs, rhs, group_sizes):
         jax.lax.ragged_dot(jnp.where(live, lhs, 0), rhs, group_sizes),
         0,
     )
+
+
+# Rows of the sorted buffer one trip of `routed_walk` works on.  Placed by
+# PR 34's chip probe (v5e; one layer, forward + backward under remat, ms,
+# whole-buffer form | CHUNK 4,096 / 8,192 / 16,384 / 32,768 with the
+# stacks' transposes still inside the loop) at 16,384 tokens x 2,048:
+# top-4, 8 held of 64, width 1,536 (65,536 slots) with an eighth of the
+# slots here 42 | 24 / 23 / 26 / 34, a quarter 46 | 35 / 32 / 31 / 38,
+# all 72 | 101 / 87 / 82 / 79; top-8, 32 held of 256, width 512 (131,072
+# slots) 67 | 41 / 36 / 34 / 39, 70 | 63 / 53 / 53 / 56, 88 | 165 / 124
+# / 110 / 100.  A trip pays ~0.5 ms for each scatter-add into the
+# (tokens, hidden) float32 carry whatever its rows (and 87 ns a row), so
+# small chunks lose at full load what they win at low load; as committed
+# 16,384 reads 24 / 29 / 54 / 79 and 29 / 47 / 67 / 100 at an eighth /
+# a quarter / a half / all (PERF.md section 6).
+CHUNK = 16384
+# every index of the walk is a token's, in bounds by construction
+_PIB = "promise_in_bounds"
+
+
+def _swiglu(gate_up):
+    gate, up = jnp.split(gate_up, 2, axis=-1)
+    return nn.silu(gate) * up
+
+
+def _chunks(slots: int):
+    """(rows a trip, trips over the whole worst-case buffer)."""
+    chunk = min(CHUNK, slots)
+    return chunk, -(-slots // chunk)
+
+
+def _trips(rows, chunk: int):
+    """Chunks that hold the buffer's first `rows` rows."""
+    return (rows + chunk - 1) // chunk
+
+
+def _chunk_of(c, chunk, top_k, order, weights, group_sizes):
+    """Chunk `c` of the sorted buffer: (the routing slot of each of its
+    rows, that slot's token, its weight, how many of the chunk's rows
+    each group owns)."""
+    ends = jnp.cumsum(group_sizes)
+    into = lambda at: jnp.clip(at - c * chunk, 0, chunk)  # noqa: E731
+    slot = lax.dynamic_slice(order, (c * chunk,), (chunk,))
+    return (
+        slot, slot // top_k, weights.at[slot].get(mode=_PIB)[:, None],
+        into(ends) - into(ends - group_sizes),
+    )
+
+
+@jax.custom_vjp
+def routed_walk(tokens, w_gate_up, w_down, order, weights, group_sizes):
+    """sum over the sorted buffer's rows r < sum(group_sizes) of
+    weights[order[r]] * SwiGLU_{group of r}(tokens[order[r] // top_k]),
+    scattered to that token: (n, hidden) float32.
+
+    The buffer (`order`: the routing slots, token-major, sorted by group,
+    the slots of no group last; `weights`: one a slot, unsorted) is walked
+    CHUNK rows a trip for ceil(rows / CHUNK) trips, a count the DEVICE
+    reads from `group_sizes`: a chunk past the last live row is neither
+    gathered, multiplied, activated, weighted nor scattered, forward or
+    backward, and one program serves every load.  A chunk's dead tail
+    (the last live chunk's) is masked by `grouped_matmul`.  A dynamic trip
+    count has no reverse-mode rule, hence the hand-written backward below:
+    the same chunks, each one's forward rebuilt (the residuals are the
+    arguments, so a rematerialised block's second forward is dead code)
+    and its inputs to the stacks' gradients written to buffers that are
+    zero where no trip went; the two stack gradients are ONE ragged
+    product each after the walk, whose cost follows the rows.
+    """
+    return _walk(tokens, w_gate_up, w_down, order, weights, group_sizes)
+
+
+def _walk(tokens, w_gate_up, w_down, order, weights, group_sizes):
+    slots = order.shape[0]
+    top_k = slots // tokens.shape[0]
+    chunk, total = _chunks(slots)
+    order = jnp.pad(order, (0, total * chunk - slots))
+
+    def trip(c, out):
+        _, at, weight, sizes = _chunk_of(
+            c, chunk, top_k, order, weights, group_sizes
+        )
+        with jax.named_scope("dispatch"):
+            rows = tokens.at[at].get(mode=_PIB)
+        with jax.named_scope("experts"):
+            expert_out = grouped_matmul(
+                _swiglu(grouped_matmul(rows, w_gate_up, sizes)),
+                w_down, sizes,
+            )
+        with jax.named_scope("combine"):
+            return out.at[at].add(
+                expert_out.astype(jnp.float32) * weight, mode=_PIB
+            )
+
+    return lax.fori_loop(
+        0, _trips(group_sizes.sum(), chunk), trip,
+        jnp.zeros(tokens.shape, jnp.float32),
+    )
+
+
+def _walk_fwd(*args):
+    return _walk(*args), args
+
+
+def _walk_bwd(args, g):
+    tokens, w_gate_up, w_down, order, weights, group_sizes = args
+    slots = order.shape[0]
+    top_k = slots // tokens.shape[0]
+    chunk, total = _chunks(slots)
+    # the padding's rows are dead; its slot 0 repeats, so the weights'
+    # gradient may promise distinct slots only where nothing is padded
+    padded = total * chunk - slots
+    order = jnp.pad(order, (0, padded))
+    dtype = tokens.dtype
+    # transposed once, outside the loop (inside it they are two copies of
+    # the stacks a trip)
+    w_gate_up_t, w_down_t = (
+        jnp.swapaxes(w, 1, 2) for w in (w_gate_up, w_down)
+    )
+
+    def trip(c, carry):
+        d_tokens, d_weights, saved = carry
+        slot, at, weight, sizes = _chunk_of(
+            c, chunk, top_k, order, weights, group_sizes
+        )
+        with jax.named_scope("dispatch"):
+            rows = tokens.at[at].get(mode=_PIB)
+            g_rows = g.at[at].get(mode=_PIB)
+        with jax.named_scope("experts"):
+            # a product's transpose to its rows is the product with the
+            # stack transposed, under the same masks
+            gate_up = grouped_matmul(rows, w_gate_up, sizes)
+            act, pull_gate_up = jax.vjp(_swiglu, gate_up)
+            expert_out = grouped_matmul(act, w_down, sizes)
+            d_out = (g_rows * weight).astype(dtype)
+            (d_gate_up,) = pull_gate_up(
+                grouped_matmul(d_out, w_down_t, sizes)
+            )
+            d_rows = grouped_matmul(d_gate_up, w_gate_up_t, sizes)
+        with jax.named_scope("combine"):
+            d_tokens = d_tokens.at[at].add(
+                d_rows.astype(jnp.float32), mode=_PIB
+            )
+            d_weights = d_weights.at[slot].add(
+                (expert_out.astype(jnp.float32) * g_rows).sum(axis=1),
+                mode=_PIB, unique_indices=not padded,
+            )
+            saved = tuple(
+                lax.dynamic_update_slice(buffer, part, (c * chunk, 0))
+                for buffer, part in zip(
+                    saved, (rows, d_gate_up, act, d_out)
+                )
+            )
+        return d_tokens, d_weights, saved
+
+    ffn = w_down.shape[1]
+    d_tokens, d_weights, (rows, d_gate_up, act, d_out) = lax.fori_loop(
+        0, _trips(group_sizes.sum(), chunk), trip,
+        (
+            jnp.zeros(tokens.shape, jnp.float32),
+            jnp.zeros(weights.shape, jnp.float32),
+            tuple(
+                jnp.zeros((total * chunk, width), dtype)
+                for width in (tokens.shape[1], 2 * ffn, ffn, tokens.shape[1])
+            ),
+        ),
+    )
+    with jax.named_scope("experts"):
+        # rows past the last group are zero in all four buffers, so the
+        # plain product needs none of `grouped_matmul`'s masks
+        (d_w_gate_up,) = jax.vjp(
+            lambda w: lax.ragged_dot(rows, w, group_sizes), w_gate_up
+        )[1](d_gate_up)
+        (d_w_down,) = jax.vjp(
+            lambda w: lax.ragged_dot(act, w, group_sizes), w_down
+        )[1](d_out)
+    return (
+        d_tokens.astype(dtype), d_w_gate_up, d_w_down, None, d_weights, None
+    )
+
+
+routed_walk.defvjp(_walk_fwd, _walk_bwd)
 
 
 class MoEMLP(nn.Module):
@@ -206,6 +395,11 @@ class RoutedExperts(nn.Module):
 
     Expert stacks are `expert_w_gate_up` (gate and up fused) and
     `expert_w_down`, no biases; `moe_param_sharding` shards them.
+
+    Cost: the router, top-k and sort over all tokens x top_k slots, then
+    `routed_walk` over ceil(rows routed here / CHUNK) chunks; it sows
+    `live_chunks_ratio` (chunks walked / chunks of the worst case; 1.0 =
+    the walk saved nothing) beside `routed_here_ratio`.
     """
 
     num_experts: int
@@ -257,41 +451,31 @@ class RoutedExperts(nn.Module):
             # slots of absent experts sort past every held group
             key = jnp.where(held, local, count).reshape(-1)
             order = jnp.argsort(key, stable=True)
-            token_of = order // k
-            group_sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(
-                1
-            )[:count]
+            group_sizes = loads[first:first + count].astype(jnp.int32)
             rows = group_sizes.sum()
-            slot_weight = weights.reshape(-1)[order][:, None]
-            sorted_tokens = tokens.astype(self.dtype)[token_of]
 
-        with jax.named_scope("experts"):
-            w_gate_up = self.param(
-                "expert_w_gate_up", nn.initializers.lecun_normal(),
-                (count, hidden, 2 * self.ffn_dim), jnp.float32,
-            )
-            w_down = self.param(
-                "expert_w_down", nn.initializers.lecun_normal(),
-                (count, self.ffn_dim, hidden), jnp.float32,
-            )
-            gate_up = grouped_matmul(
-                sorted_tokens, w_gate_up.astype(self.dtype), group_sizes
-            )
-            gate, up = jnp.split(gate_up, 2, axis=-1)
-            expert_out = grouped_matmul(
-                nn.silu(gate) * up, w_down.astype(self.dtype), group_sizes
-            )
-
-        with jax.named_scope("combine"):
-            # rows past the last group are zero (`grouped_matmul`)
-            out = jnp.zeros((n, hidden), jnp.float32).at[token_of].add(
-                expert_out.astype(jnp.float32) * slot_weight
-            )
+        w_gate_up = self.param(
+            "expert_w_gate_up", nn.initializers.lecun_normal(),
+            (count, hidden, 2 * self.ffn_dim), jnp.float32,
+        )
+        w_down = self.param(
+            "expert_w_down", nn.initializers.lecun_normal(),
+            (count, self.ffn_dim, hidden), jnp.float32,
+        )
+        out = routed_walk(
+            tokens.astype(self.dtype), w_gate_up.astype(self.dtype),
+            w_down.astype(self.dtype), order, weights.reshape(-1),
+            group_sizes,
+        )
+        chunk, total = _chunks(n * k)
 
         sow_step_metric(
             self, "expert_load_imbalance_ratio", loads.max() / loads.mean()
         )
         sow_step_metric(self, "routed_here_ratio", rows / (n * k))
+        sow_step_metric(
+            self, "live_chunks_ratio", _trips(rows, chunk) / total
+        )
         sow_step_metric(self, "dropped_tokens", held.sum() - rows)
         return out.reshape(*lead, hidden)
 

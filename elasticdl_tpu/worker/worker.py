@@ -65,6 +65,13 @@ _moe_gauges = {
         "last step of the task",
         labelnames=("layer",),
     ),
+    "live_chunks_ratio": metrics_lib.default_registry().gauge(
+        "worker_moe_live_chunks_ratio",
+        "chunks of the sorted buffer the layer walked / chunks of its "
+        "worst case (layers/moe.py: routed_walk), last step of the task; "
+        "1.0 means the walk saved nothing",
+        labelnames=("layer",),
+    ),
 }
 _moe_dropped = metrics_lib.default_registry().counter(
     "worker_moe_dropped_tokens_total",

@@ -3,7 +3,9 @@ routed expert layer (layers/moe.py: RoutedExperts) at tiny widths on the
 CPU, seeded weights: against the plain float32 reference leaf by leaf,
 the selection bias and its update, the shares of an expert-parallel
 deployment adding up to the uncut layer, no dropped token at any load,
-one compile across loads, and a two-task job through the CLI."""
+one compile across loads, the chunked walk of the sorted buffer against
+the whole-buffer form at every kind of load, and a two-task job through
+the CLI."""
 
 import os
 import threading
@@ -16,6 +18,7 @@ import pytest
 
 from benchmarks import datagen, trees
 from benchmarks.reference import glm_moe_lite as reference
+from elasticdl_tpu.layers import moe
 from elasticdl_tpu.layers.moe import (
     AUX_LOSS,
     ROUTER_STATE,
@@ -314,6 +317,136 @@ def test_one_compile_across_loads():
     assert len(traces) == 1 and len(shares) > 1
 
 
+# ---- the walk of the sorted buffer ----------------------------------------
+
+WALK_CHUNK = 48          # 64 tokens x top-2 = 128 slots: 3 chunks, 16 padded
+# rows each held expert (0-3 of 16) gets: none here; exactly one chunk; one
+# chunk + 1 row; a ragged last chunk, group 1 (rows 30-59) across the
+# boundary at 48; every slot here
+WALK_LOADS = {
+    "none": (0, 0, 0, 0),
+    "one_chunk": (20, 0, 28, 0),
+    "one_chunk_and_a_row": (20, 0, 28, 1),
+    "ragged_straddling": (30, 30, 10, 7),
+    "every_slot": (32, 32, 32, 32),
+}
+
+
+def steered(loads, tokens=64, hidden=32, experts=16):
+    """(tokens, a router kernel) that send exactly `loads[e]` slots to
+    expert e < 4 and every other slot to experts 4-15: token t's top 2
+    are slots t and t + tokens of one list of experts, its first 16
+    features are the router's logits."""
+    slots = [e for e, rows in enumerate(loads) for _ in range(rows)]
+    slots += [4 + i % 12 for i in range(2 * tokens - len(slots))]
+    x = 0.3 * np.random.RandomState(2).randn(tokens, hidden)
+    for t in range(tokens):
+        first, second = slots[t], slots[tokens + t]
+        assert first != second
+        x[t, [first, second]] += 3.0
+    kernel = np.zeros((hidden, experts), np.float32)
+    kernel[:experts] = 2.0 * np.eye(experts)
+    return jnp.asarray(x.astype(np.float32)), jnp.asarray(kernel)
+
+
+def whole_buffer(params, x, held=(0, 4), top_k=2):
+    """The plain statement: the layer over its WHOLE worst-case buffer,
+    every row gathered, multiplied, activated, weighted and scattered."""
+    first, count = held
+    scores = jax.nn.sigmoid(jnp.dot(
+        x, params["router_kernel"], precision=jax.lax.Precision.HIGHEST
+    ))
+    _, idx = jax.lax.top_k(jax.lax.stop_gradient(scores), top_k)
+    picked = jnp.take_along_axis(scores, idx, axis=1)
+    weights = 1.8 * picked / picked.sum(axis=1, keepdims=True)
+    local = idx - first
+    key = jnp.where((local >= 0) & (local < count), local, count).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    token_of = order // top_k
+    group_sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    gate, up = jnp.split(moe.grouped_matmul(
+        x[token_of], params["expert_w_gate_up"], group_sizes
+    ), 2, axis=-1)
+    expert_out = moe.grouped_matmul(
+        jax.nn.silu(gate) * up, params["expert_w_down"], group_sizes
+    )
+    return jnp.zeros_like(x).at[token_of].add(
+        expert_out * weights.reshape(-1)[order][:, None]
+    )
+
+
+def walked(loads):
+    """(the layer's apply over (params, tokens), params, tokens, rows
+    routed here) of one steered load."""
+    layer = routed_layer(held=(0, 4), experts=16)
+    x, kernel = steered(loads)
+    variables = layer.init(jax.random.PRNGKey(0), x)
+    params = dict(variables["params"], router_kernel=kernel)
+
+    def apply(params, x):
+        out, sown = layer.apply(
+            {**variables, "params": params}, x, mutable=[STEP_METRICS]
+        )
+        return out, sown[STEP_METRICS]
+
+    return apply, params, x, sum(loads)
+
+
+@pytest.mark.parametrize("load", sorted(WALK_LOADS))
+def test_the_walk_is_the_whole_buffer_at_every_load(load, monkeypatch):
+    monkeypatch.setattr(moe, "CHUNK", WALK_CHUNK)
+    apply, params, x, rows = walked(WALK_LOADS[load])
+    cotangent = tokens_of(seed=5)
+
+    def through(function):
+        def loss(params, x):
+            out, sown = function(params, x)
+            return (out * cotangent).sum(), (out, sown)
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+
+    with jax.default_matmul_precision("highest"):
+        (_, (out, metrics)), (d_params, d_x) = through(apply)(params, x)
+        (_, (want, _)), (want_params, want_x) = through(
+            lambda params, x: (whole_buffer(params, x), None)
+        )(params, x)
+    assert float(metrics["routed_here_ratio"]) == rows / 128
+    assert float(metrics["dropped_tokens"]) == 0.0
+    assert float(metrics["live_chunks_ratio"]) == pytest.approx(
+        -(-rows // WALK_CHUNK) / 3
+    )
+    assert bool(np.abs(np.asarray(out)).sum() > 0) == bool(rows)
+    close = dict(rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(out, want, **close)
+    np.testing.assert_allclose(d_x, want_x, **close)
+    for leaf in ("router_kernel", "expert_w_gate_up", "expert_w_down"):
+        np.testing.assert_allclose(
+            d_params[leaf], want_params[leaf], err_msg=leaf, **close
+        )
+
+
+def test_the_walk_is_one_program_across_loads(monkeypatch):
+    monkeypatch.setattr(moe, "CHUNK", WALK_CHUNK)
+    apply = walked(WALK_LOADS["none"])[0]
+    traces = []
+
+    def scalar(params, x):
+        out, metrics = apply(params, x)
+        return out.sum(), metrics["live_chunks_ratio"]
+
+    @jax.jit
+    def run(params, x):
+        traces.append(1)
+        return jax.grad(scalar, has_aux=True)(params, x)
+
+    chunks = []
+    for load in sorted(WALK_LOADS):
+        _, params, x, _ = walked(WALK_LOADS[load])
+        _, ratio = run(params, x)
+        chunks.append(round(float(ratio) * 3))
+    assert len(traces) == 1
+    assert sorted(chunks) == [0, 1, 2, 2, 3]
+
+
 # ---- through the system ---------------------------------------------------
 
 
@@ -341,6 +474,8 @@ def test_trainer_adds_the_mtp_loss_and_carries_step_metrics(seeded):
     assert value == pytest.approx(float(loss))
     assert metrics["layer_1/moe/routed/dropped_tokens"] == 0.0
     assert 0.0 < metrics["layer_1/moe/routed/routed_here_ratio"] < 1.0
+    # a buffer no longer than one chunk: one trip, the whole of it
+    assert metrics["layer_1/moe/routed/live_chunks_ratio"] == 1.0
     assert metrics["mtp_block/moe/routed/expert_load_imbalance_ratio"] >= 1.0
 
 
@@ -387,3 +522,6 @@ def test_cli_job_of_two_tasks_with_a_falling_loss(tmp_path):
     assert 0.0 < registry.value(
         "worker_moe_routed_here_ratio", layer="layer_1/moe/routed"
     ) < 1.0
+    assert registry.value(
+        "worker_moe_live_chunks_ratio", layer="layer_1/moe/routed"
+    ) == 1.0

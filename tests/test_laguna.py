@@ -19,6 +19,7 @@ import pytest
 
 from benchmarks import datagen, trees
 from benchmarks.reference import laguna as reference
+from elasticdl_tpu.layers import moe
 from elasticdl_tpu.layers.moe import (
     AUX_LOSS,
     ROUTER_STATE,
@@ -325,6 +326,60 @@ def test_the_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(total, want, rtol=2e-4, atol=2e-5)
     # and no share alone is the layer
     assert np.abs(np.asarray(part) - np.asarray(want)).max() > 1e-2
+
+
+def test_the_walk_at_this_models_routing(monkeypatch):
+    """Top-8 of 256 with 32 held in the middle (the cell's routing): the
+    layer walking its 512-slot buffer 48 rows a trip against the
+    reference's every-held-expert-over-all-tokens form, output and every
+    gradient."""
+    monkeypatch.setattr(moe, "CHUNK", 48)
+    config = dict(CONFIG, held_experts=[32, 32], num_experts_per_tok=8,
+                  num_experts_published=256)
+    sizes = reference.sizes_of(config, None)
+    x = jnp.asarray(
+        np.random.RandomState(1).randn(64, 32).astype(np.float32)
+    )
+    layer = RoutedExperts(
+        num_experts=256, top_k=8, ffn_dim=16, held_experts=(32, 32),
+        routed_scaling=2.5,
+    )
+    variables = layer.init(jax.random.PRNGKey(3), x)
+    cotangent = jnp.asarray(
+        np.random.RandomState(2).randn(64, 32).astype(np.float32)
+    )
+
+    def ours(params, x):
+        out, sown = layer.apply(
+            {**variables, "params": params}, x, mutable=[STEP_METRICS]
+        )
+        return (out * cotangent).sum(), (out, sown[STEP_METRICS])
+
+    def plain(params, x):
+        out = reference.routed(x, params, sizes, lambda t: t)
+        return (out * cotangent).sum(), (out, None)
+
+    with jax.default_matmul_precision("highest"):
+        (_, (out, metrics)), grads = jax.value_and_grad(
+            ours, argnums=(0, 1), has_aux=True
+        )(variables["params"], x)
+        (_, (want, _)), want_grads = jax.value_and_grad(
+            plain, argnums=(0, 1), has_aux=True
+        )(variables["params"], x)
+    rows = round(float(metrics["routed_here_ratio"]) * 512)
+    assert 48 < rows < 512 - 48           # some chunks walked, some not
+    assert float(metrics["live_chunks_ratio"]) == pytest.approx(
+        -(-rows // 48) / 11
+    )
+    assert float(metrics["dropped_tokens"]) == 0.0
+    np.testing.assert_allclose(out, want, rtol=2e-4, atol=2e-5)
+    for ours_leaf, want_leaf in zip(
+        jax.tree_util.tree_leaves(grads),
+        jax.tree_util.tree_leaves(want_grads),
+    ):
+        np.testing.assert_allclose(
+            ours_leaf, want_leaf, rtol=2e-4, atol=2e-5
+        )
 
 
 # ---- through the system ---------------------------------------------------
