@@ -381,7 +381,7 @@ class RoutedExperts(nn.Module):
 
         s = sigmoid(x Wr)                     float32, all `num_experts`
         S = top_k(s + b)                      b selects, never weighs
-        w_i = routed_scaling * s_i / sum_{j in S} s_j      (i in S)
+        w_i = routed_scaling * s_i / (sum_{j in S} s_j + renorm_eps)
         out = sum_{i in S, i held here} w_i SwiGLU_i(x)
 
     num_experts:      the router's width (every expert of the layer)
@@ -392,6 +392,8 @@ class RoutedExperts(nn.Module):
     bias_update_rate: gamma of loss-free balancing (arXiv:2408.15664):
                       b_i += gamma * sign(mean load - load_i) after each
                       train step, in ROUTER_STATE; 0 keeps b as it is
+    renorm_eps:       added to the renormalisation's denominator (1e-6 in
+                      `lfm2_moe`); 0.0 adds nothing to the program
 
     Expert stacks are `expert_w_gate_up` (gate and up fused) and
     `expert_w_down`, no biases; `moe_param_sharding` shards them.
@@ -409,6 +411,7 @@ class RoutedExperts(nn.Module):
     routed_scaling: float = 1.0
     bias_update_rate: float = 0.0
     dtype: jnp.dtype = jnp.float32
+    renorm_eps: float = 0.0
 
     @nn.compact
     def __call__(self, x):
@@ -434,9 +437,10 @@ class RoutedExperts(nn.Module):
                 jax.lax.stop_gradient(scores) + bias.value, k
             )                                               # (n, k)
             picked = jnp.take_along_axis(scores, idx, axis=1)
-            weights = self.routed_scaling * picked / picked.sum(
-                axis=1, keepdims=True
-            )
+            total = picked.sum(axis=1, keepdims=True)
+            if self.renorm_eps:
+                total = total + self.renorm_eps
+            weights = self.routed_scaling * picked / total
             loads = expert_loads(idx, self.num_experts)
             if (self.bias_update_rate
                     and not self.is_initializing()

@@ -497,7 +497,7 @@ def _band(window, q) -> Optional[int]:
     return int(window)
 
 
-# ---- the streaming kernel: causal, head width a multiple of 128 ----------
+# ---- the streaming kernel: causal, head width a multiple of 128, or 64 ----
 #
 # Inputs stay (B, L, H*D) (the free view of the model's layout) and the
 # grid walks (batch, head, query tile, key tile): a program holds ONE
@@ -516,9 +516,17 @@ def _band(window, q) -> Optional[int]:
 # sums over its group in scratch.  A window: the inner axis is as long as
 # the band (`_band_steps` tiles), the tiles outside it are never visited,
 # and both of its edges are masked in the tile (`_mask_tile`).
+#
+# A head of HALF a lane tile (D = 64) is no column block of (B, L, H*D):
+# a block's last dimension is whole lane tiles or the whole array's.  Such
+# a call goes head-major, (B, H, L, D), whose last dimension IS the head:
+# the same kernels over the same grid hold (tile, 64) blocks, the index
+# maps name the head on its own axis, and the transposes in and out are
+# layout copies in XLA (eight streams a layer, forward and backward).
 
 _STREAM_TILE = 512
 _LANES = 128
+_HALF_HEAD = _LANES // 2
 
 
 def _stream_tiles(length: int):
@@ -532,12 +540,12 @@ def stream_shapes_ok(q_shape, k_shape, v_shape) -> bool:
     """Whether the streaming kernel takes causal self-attention at these
     (B, L, H, D) shapes: k and v alike at q's batch, length and width, q's
     heads a multiple of theirs (grouped keys and values; the same count
-    is a group of one), L whole 128-tiles, D whole lane tiles.  A window
-    asks nothing more."""
+    is a group of one), L whole 128-tiles, D whole lane tiles or half of
+    one (64, which goes head-major).  A window asks nothing more."""
     return (
         _grouped_shapes_ok(q_shape, k_shape, v_shape)
         and tuple(k_shape) == tuple(v_shape)
-        and q_shape[3] % _LANES == 0
+        and (q_shape[3] % _LANES == 0 or q_shape[3] == _HALF_HEAD)
         and _stream_tiles(q_shape[1]) is not None
     )
 
@@ -720,8 +728,15 @@ def _stream_specs(tile: int, dim: int):
     """Block specs by role, for a grid (batch, head, outer tile, inner
     step): `which` gives the tile a block follows, clamped to the tiles
     its kernel visits so that a skipped step moves nothing, and `head`
-    the column block (the grid's own head unless said)."""
+    the column block (the grid's own head unless said).  A head that is
+    no whole lane tile is a block of the head-major (B, H, L, D) view,
+    its head axis squeezed: the kernels see (1, tile, D) either way."""
     def tiles(which, head=_same_head):
+        if dim % _LANES:
+            return pl.BlockSpec(
+                (1, None, tile, dim),
+                lambda b, h, x, y: (b, head(h, x, y), which(x, y), 0),
+            )
         return pl.BlockSpec(
             (1, tile, dim),
             lambda b, h, x, y: (b, which(x, y), head(h, x, y)),
@@ -761,6 +776,29 @@ def _kv_head(group: int):
     return _same_head if group == 1 else (lambda h, x, y: h // group)
 
 
+def _stream_view(t):
+    """(B, L, H, D) as the kernels' operand: the free (B, L, H*D) view at
+    a head of whole lane tiles, head-major (B, H, L, D) below that."""
+    if t.shape[3] % _LANES:
+        return t.transpose(0, 2, 1, 3)
+    return t.reshape(*t.shape[:2], -1)
+
+
+def _stream_shape(shape):
+    """The shape `_stream_view` gives a (B, L, H, D) array."""
+    batch, length, heads, dim = shape
+    if dim % _LANES:
+        return (batch, heads, length, dim)
+    return (batch, length, heads * dim)
+
+
+def _stream_unview(t, shape):
+    """A kernel's output back as (B, L, H, D) = `shape`."""
+    if shape[3] % _LANES:
+        return t.transpose(0, 2, 1, 3)
+    return t.reshape(shape)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _stream(q, k, v, scale, window):
     return _stream_fwd(q, k, v, scale, window)[0]
@@ -774,7 +812,7 @@ def _stream_fwd(q, k, v, scale, window):
     steps = _band_steps(num, tile, window)
     tiles, per_row = _stream_specs(tile, dim)
     keys, kv_head = _streamed_keys(steps, window), _kv_head(group)
-    flat = (batch, length, heads * dim)
+    flat = _stream_shape(q.shape)
     # grid (b, h, i over queries, y over the keys i meets)
     out, lse = _stream_call(
         functools.partial(
@@ -788,10 +826,10 @@ def _stream_fwd(q, k, v, scale, window):
         [pltpu.VMEM((tile, _LANES), jnp.float32),
          pltpu.VMEM((tile, _LANES), jnp.float32),
          pltpu.VMEM((tile, dim), jnp.float32)],
-        [t.reshape(*t.shape[:2], -1) for t in (q, k, v)],
+        [_stream_view(t) for t in (q, k, v)],
         _stream_names(window) + "_fwd",
     )
-    out = out.reshape(q.shape)
+    out = _stream_unview(out, q.shape)
     return out, (q, k, v, out, lse)
 
 
@@ -804,16 +842,13 @@ def _stream_bwd(scale, window, residuals, g):
     num = length // tile
     steps = _band_steps(num, tile, window)
     tiles, per_row = _stream_specs(tile, dim)
-    flat = (batch, length, heads * dim)
-    flat_kv = (batch, length, kv_heads * dim)
+    flat, flat_kv = _stream_shape(q.shape), _stream_shape(k.shape)
     g = g.astype(q.dtype)
     delta = (
         (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
         .transpose(0, 2, 1)[..., None]
     )                                                   # (B, H, L, 1)
-    operands = [
-        t.reshape(*t.shape[:2], -1) for t in (q, k, v, g)
-    ] + [lse, delta]
+    operands = [_stream_view(t) for t in (q, k, v, g)] + [lse, delta]
     # grid (b, K/V head, j over keys, x over the group's heads times the
     # query tiles j meets): queries before the diagonal stay on the
     # diagonal's tile, queries past the last tile on the last
@@ -860,7 +895,10 @@ def _stream_bwd(scale, window, residuals, g):
         [pltpu.VMEM((tile, dim), jnp.float32)],
         operands, _stream_names(window) + "_dq",
     )
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    return (
+        _stream_unview(dq, q.shape), _stream_unview(dk, k.shape),
+        _stream_unview(dv, v.shape),
+    )
 
 
 _stream.defvjp(_stream_fwd, _stream_bwd)
@@ -873,7 +911,8 @@ def causal_attention(q, k, v, scale: Optional[float] = None,
     values: query head h reads K/V head h // (H / Hkv)) -> (B, L, H, Dv),
     at any length and head width: the one entry a model calls.  With
     `window`, query t sees the keys s with t - window < s <= t.  The
-    streaming Pallas kernels where the shapes tile (`stream_shapes_ok`),
+    streaming Pallas kernels where the shapes tile (`stream_shapes_ok`:
+    heads of whole lane tiles, or of 64),
     the blocked lax form elsewhere (the same mathematics, one row of
     query tiles at a time)."""
     if scale is None:

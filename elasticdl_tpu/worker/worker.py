@@ -86,6 +86,14 @@ _attention_gate = metrics_lib.default_registry().gauge(
     "heads, last step of the task (a gate that closes silences its layer)",
     labelnames=("layer",),
 )
+# What a gated short convolution sows there (model_zoo/lfm2/lfm2_moe.py):
+# the RMS of the operator's output over its input's.
+_short_conv_out = metrics_lib.default_registry().gauge(
+    "worker_short_conv_out_rms_ratio",
+    "RMS of the conv operator's output over the RMS of its (normed) "
+    "input, last step of the task (gates that close silence the layer)",
+    labelnames=("layer",),
+)
 # What a narrow-row lookup sows there (layers/embedding.py: lookup_rows):
 # the share of the batch's looked-up rows that are distinct, by table.
 _arena_distinct = metrics_lib.default_registry().gauge(
@@ -569,6 +577,8 @@ class Worker:
                     _arena_distinct.labels(table=layer).set(value)
                 elif name == "gate_mean":
                     _attention_gate.labels(layer=layer).set(value)
+                elif name == "out_rms_ratio":
+                    _short_conv_out.labels(layer=layer).set(value)
                 else:
                     scalars["train/" + path] = value
             self._summary.scalars(scalars, step=self._owner.step)

@@ -87,7 +87,8 @@ class SwiGLU(nn.Module):
 
 class MoEFFN(nn.Module):
     """The shared expert, computed by every holder alike, plus this
-    holder's part of the routed experts."""
+    holder's part of the routed experts; `shared_experts` 0 builds no
+    shared expert."""
 
     hidden: int
     num_experts: int
@@ -99,6 +100,7 @@ class MoEFFN(nn.Module):
     bias_update_rate: float
     dtype: jnp.dtype = jnp.float32
     trace_scope: str = "glm/moe"
+    renorm_eps: float = 0.0
 
     @nn.compact
     def __call__(self, x):
@@ -108,8 +110,10 @@ class MoEFFN(nn.Module):
                 ffn_dim=self.expert_width, held_experts=self.held_experts,
                 routed_scaling=self.routed_scaling,
                 bias_update_rate=self.bias_update_rate, dtype=self.dtype,
-                name="routed",
+                renorm_eps=self.renorm_eps, name="routed",
             )(x)
+            if not self.shared_experts:
+                return routed.astype(self.dtype)
             with jax.named_scope("shared"):
                 shared = SwiGLU(
                     self.hidden, self.shared_experts * self.expert_width,

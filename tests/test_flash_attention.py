@@ -316,3 +316,115 @@ def test_grouped_admission_rule():
         causal_attention(a, b, c, window=0)
     with pytest.raises(ValueError, match="grouped"):
         causal_attention(a, jnp.concatenate([b, b], axis=2)[:, :, :2], c)
+
+
+# ---- a head of half a lane tile (64): the head-major streaming layout ----
+
+HALF_HEADS = [
+    pytest.param(group, window, id=f"group{group}-window{window}")
+    for group in (1, 4) for window in (None, 200)
+]
+
+
+@pytest.mark.parametrize("group, window", HALF_HEADS)
+def test_half_lane_head_forward(group, window):
+    from elasticdl_tpu.ops.flash_attention import (
+        causal_attention,
+        stream_shapes_ok,
+    )
+
+    q, k, v = _grouped_qkv(group, dim=64, kv_heads=2)
+    assert stream_shapes_ok(q.shape, k.shape, v.shape)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(
+            causal_attention(q, k, v, window=window),
+            _dense_band(q, k, v, window), rtol=2e-4, atol=2e-4,
+        )
+
+
+@pytest.mark.parametrize("group, window", HALF_HEADS)
+def test_half_lane_head_gradients(group, window):
+    from elasticdl_tpu.ops.flash_attention import causal_attention
+
+    q, k, v = _grouped_qkv(group, dim=64, kv_heads=2)
+
+    def grads(fn):
+        return jax.grad(
+            lambda q, k, v: (fn(q, k, v) ** 2).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        got = grads(lambda q, k, v: causal_attention(q, k, v, window=window))
+        want = grads(lambda q, k, v: _dense_band(q, k, v, window))
+    for name, a, b in zip("qkv", got, want):
+        np.testing.assert_allclose(
+            a, b, rtol=5e-4, atol=5e-4, err_msg=f"d{name}"
+        )
+
+
+def test_half_lane_head_admission_and_names():
+    """Heads of 64 run in the kernels the metrics read by name, not in
+    the blocked form; other widths under a lane tile still go blocked."""
+    from elasticdl_tpu.ops.flash_attention import (
+        flash_shapes_ok,
+        stream_shapes_ok,
+    )
+
+    q, kv = (4, 8192, 32, 64), (4, 8192, 8, 64)
+    assert stream_shapes_ok(q, kv, kv) and flash_shapes_ok(q, kv, causal=True)
+    assert not stream_shapes_ok((4, 8192, 32, 32), (4, 8192, 8, 32),
+                                (4, 8192, 8, 32))
+    assert not stream_shapes_ok((4, 8192, 32, 192), (4, 8192, 8, 192),
+                                (4, 8192, 8, 192))
+    assert not stream_shapes_ok((4, 8200, 32, 64), (4, 8200, 8, 64),
+                                (4, 8200, 8, 64))
+    q, k, v = _grouped_qkv(4, length=256, dim=64, kv_heads=2)
+    assert _kernel_names(q, k, v, window=None) == [
+        "causal_attention_dkv", "causal_attention_dq",
+        "causal_attention_fwd",
+    ]
+
+
+# sha256 of str(make_jaxpr(value_and_grad(causal_attention ...))) at the
+# cells' bfloat16 shapes, as the commit before heads of 64 gave them: the
+# GLM cell's (4, 4096, 20, 256) and the Laguna cell's full and window
+# layers.  No cell that was there can move through `flash_attention.py`
+# while these hold; a change that means to move one states the new text.
+CELL_JAXPRS = [
+    pytest.param(
+        (4, 4096, 20, 256), 20, None,
+        "6885b5dce4a6b93b932bc75bf99801eda3e6e53d6a904baa53715487dd0a47a3",
+        id="glm-mla",
+    ),
+    pytest.param(
+        (2, 8192, 48, 128), 8, None,
+        "df5c1370525275357c2f32b163393cc5aa9ef2159a224fa0712723df09bb5a7b",
+        id="laguna-full",
+    ),
+    pytest.param(
+        (2, 8192, 64, 128), 8, 512,
+        "579349d766f4a7404cc034387ad120d5c792248832ed77cc5eafdfe00c1a8280",
+        id="laguna-window",
+    ),
+]
+
+
+@pytest.mark.parametrize("q_shape, kv_heads, window, digest", CELL_JAXPRS)
+def test_cells_attention_jaxpr_is_the_parents(q_shape, kv_heads, window,
+                                              digest):
+    import hashlib
+
+    from elasticdl_tpu.ops.flash_attention import causal_attention
+
+    def shaped(heads):
+        return jax.ShapeDtypeStruct(
+            (*q_shape[:2], heads, q_shape[3]), jnp.bfloat16
+        )
+
+    text = str(jax.make_jaxpr(jax.value_and_grad(
+        lambda q, k, v: causal_attention(
+            q, k, v, window=window
+        ).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2),
+    ))(shaped(q_shape[2]), shaped(kv_heads), shaped(kv_heads)))
+    assert hashlib.sha256(text.encode()).hexdigest() == digest

@@ -77,3 +77,61 @@ def test_streaming_kernels_compile_for_the_chip(
     assert text.count("tpu_custom_call") >= 3
     # K/V stay at their own head count: nothing repeated to the query's
     assert f"bf16[{batch},{length},{kv_heads * dim}]" in text
+
+
+def test_half_lane_head_kernels_compile_for_the_chip(one_chip, monkeypatch):
+    """The LFM2 cell's attention: 32 query heads of 64 over 8 K/V heads at
+    (4, 8192), the head-major layout of a head of half a lane tile."""
+    monkeypatch.setattr(fa, "use_interpret", lambda: False)
+
+    def shaped(h):
+        return jax.ShapeDtypeStruct(
+            (4, 8192, h, 64), jnp.bfloat16, sharding=one_chip
+        )
+
+    def grads(q, k, v):
+        return jax.grad(
+            lambda q, k, v: fa.causal_attention(
+                q, k, v
+            ).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    text = jax.jit(grads).lower(
+        shaped(32), shaped(8), shaped(8)
+    ).compile().as_text()
+    for kernel in ("fwd", "dkv", "dq"):
+        assert f"causal_attention_{kernel}" in text
+    assert text.count("tpu_custom_call") >= 3
+    # K/V go head-major at their own head count: nothing repeated to 32
+    assert "bf16[4,8,8192,64]" in text and "bf16[4,32,8192,64]" in text
+
+
+def test_short_conv_kernels_compile_for_the_chip(one_chip, monkeypatch):
+    """The LFM2 cell's conv pass: (4, 8192, 3 x 2048) under 3 taps, the
+    forward alone (a gradient drops it) and the backward."""
+    from elasticdl_tpu.ops import short_conv
+
+    monkeypatch.setattr(short_conv, "use_interpret", lambda: False)
+    bcu = jax.ShapeDtypeStruct(
+        (4, 8192, 6144), jnp.bfloat16, sharding=one_chip
+    )
+    weight = jax.ShapeDtypeStruct((3, 2048), jnp.float32, sharding=one_chip)
+    assert short_conv.short_conv_shapes_ok(bcu.shape, weight.shape)
+    forward = jax.jit(short_conv.gated_short_conv).lower(
+        bcu, weight
+    ).compile().as_text()
+    assert "short_conv_fwd" in forward and "tpu_custom_call" in forward
+    assert "bf16[4,8192,2048]" in forward
+
+    def grads(bcu, weight):
+        return jax.grad(
+            lambda a, b: short_conv.gated_short_conv(a, b).astype(
+                jnp.float32
+            ).sum(), argnums=(0, 1),
+        )(bcu, weight)
+
+    backward = jax.jit(grads).lower(bcu, weight).compile().as_text()
+    assert "short_conv_bwd" in backward and "tpu_custom_call" in backward
+    # d(weight) leaves the kernel as one (8, d) partial a program
+    assert "f32[4,32,8,2048]" in backward
