@@ -40,6 +40,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -353,8 +354,26 @@ def flash_attention(
 # and a WINDOW (query t sees keys s with t - window < s <= t): K/V are
 # never repeated to H heads, and keys outside the band are neither
 # computed nor read.
+#
+# Both forms NAME the forward's two results (`checkpoint_name`) where they
+# are made: the output and the log-sum-exp are all the backward needs of
+# the forward besides its arguments, and the forward is the costliest
+# thing in a decoder block to run twice (31.8 and 40.8 ms a call in the
+# long-context cells, `PERF.md` section 6, PR 36).  A caller that
+# rematerialises a block saves the two by name (`SAVED_NAMES`;
+# `model_zoo/common/decoder.py: remat_block`) and its remat rebuilds the
+# rest of the block, q, k and v among it, but not this call: the forward
+# runs once a step.  Outside a `jax.checkpoint` a name is the identity.
 
 _BLOCKED_TILE = 512
+SAVED_NAMES = ("attention_core_out", "attention_core_lse")
+
+
+def _named(out, lse):
+    """The forward's two results under the names a remat saves them by."""
+    return tuple(
+        checkpoint_name(t, name) for t, name in zip((out, lse), SAVED_NAMES)
+    )
 
 
 def _tile_rows(length: int, tile: int, window: Optional[int] = None):
@@ -411,8 +430,10 @@ def _blocked_fwd(q, k, v, scale, tile, window):
         ) / l[..., 0].transpose(0, 3, 1, 2)[..., None]
         outs.append(out.astype(q.dtype))
         lses.append((m + jnp.log(l))[..., 0])           # (B, Hkv, G, rows)
-    out = jnp.concatenate(outs, axis=1).reshape(*q.shape[:3], v.shape[-1])
-    lse = jnp.concatenate(lses, axis=3)
+    out, lse = _named(
+        jnp.concatenate(outs, axis=1).reshape(*q.shape[:3], v.shape[-1]),
+        jnp.concatenate(lses, axis=3),
+    )
     return out, (q, k, v, out, lse)
 
 
@@ -829,6 +850,9 @@ def _stream_fwd(q, k, v, scale, window):
         [_stream_view(t) for t in (q, k, v)],
         _stream_names(window) + "_fwd",
     )
+    # named as they leave the kernel (module comment of the blocked form):
+    # with the two saved, a block's remat has no use for this call
+    out, lse = _named(out, lse)
     out = _stream_unview(out, q.shape)
     return out, (q, k, v, out, lse)
 

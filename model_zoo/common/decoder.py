@@ -3,8 +3,9 @@ glm_moe_lite.py`, `model_zoo/laguna/laguna.py`): RMSNorm, rotary's turn,
 the bias-free dense layer and SwiGLU, the routed block around
 `layers/moe.py: RoutedExperts` with its shared expert, the cross-entropy
 taken in blocks of tokens, the per-position losses against the ids
-shifted, and the zoo functions a next-token model shares (`loss`,
-`optimizer`, `eval_metrics_fn`, `param_sharding`)."""
+shifted, the blocks' rematerialisation (`remat_block`), and the zoo
+functions a next-token model shares (`loss`, `optimizer`,
+`eval_metrics_fn`, `param_sharding`)."""
 
 from __future__ import annotations
 
@@ -18,6 +19,7 @@ import optax
 
 from elasticdl_tpu.layers.embedding import embedding_param_sharding
 from elasticdl_tpu.layers.moe import RoutedExperts, moe_param_sharding
+from elasticdl_tpu.ops.flash_attention import SAVED_NAMES
 
 # Tokens whose logits exist at once in the cross-entropy.
 CE_BLOCK = 2048
@@ -120,6 +122,20 @@ class MoEFFN(nn.Module):
                     self.dtype, name="shared",
                 )(x)
             return (routed + shared.astype(jnp.float32)).astype(self.dtype)
+
+
+def remat_block(block_cls):
+    """`block_cls` rebuilt in the backward but for the attention core's
+    output and log-sum-exp, which stay from the forward (bfloat16 out +
+    float32 lse a layer: 134-268 MB in the cells): the remat rebuilds
+    the projections, rotary and norms that make q, k and v, and the
+    streaming forward kernel, the block's costliest operation, runs once
+    a step (`ops/flash_attention.py: SAVED_NAMES`).  A block with no
+    attention in it saves nothing."""
+    return nn.remat(
+        block_cls,
+        policy=jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES),
+    )
 
 
 def blocked_nll(h, head_kernel, targets, dtype, block: int = CE_BLOCK):

@@ -23,9 +23,13 @@ What makes it a citizen of THIS system rather than a port:
   model hands back per-position negative log-likelihoods: the logits
   (16,384 tokens x 19,360 rows in float32 are 1.27 GB, twice with MTP)
   never leave a cross-entropy taken in blocks of tokens;
-- every block rematerialises in the backward (`remat`), the MTP loss is a
-  sown auxiliary objective, and router load and the two losses ride in
-  STEP_METRICS to the worker's one fetch a task.
+- every block rematerialises in the backward (`remat`) but for the
+  attention core's output and log-sum-exp, which stay from the forward
+  (`decoder.remat_block`: 169 MB a layer at the cell's shape buys the
+  streaming forward kernel, a block's costliest operation, run once a
+  step and not twice); the MTP loss is a sown auxiliary objective, and
+  router load and the two losses ride in STEP_METRICS to the worker's
+  one fetch a task.
 
 Record format: seq_len int32 token ids | 1 label byte (ignored), the
 fixed-width record `model_zoo/bert` reads.
@@ -53,6 +57,7 @@ from model_zoo.common.decoder import (  # noqa: F401
     loss,
     optimizer,
     param_sharding,
+    remat_block,
     rotary,
     shifted_nll,
 )
@@ -183,7 +188,7 @@ class GLMMoELite(nn.Module):
         embed = DistributedEmbedding(
             c.vocab_size, c.hidden, hash_input=False, name="token_embedding"
         )
-        block_cls = nn.remat(Block) if c.remat else Block
+        block_cls = remat_block(Block) if c.remat else Block
         x = embed(ids).astype(c.dtype)
         for i in range(c.num_layers):
             x = block_cls(c, moe=i >= c.dense_layers, name=f"layer_{i}")(x)

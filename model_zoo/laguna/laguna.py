@@ -20,6 +20,10 @@ its kind (`full_attention` | `sliding_attention`), its query heads and
 whether its feed-forward is dense or routed.  K/V stay at their 8 heads
 all the way into the kernels (`ops/flash_attention.py: causal_attention`
 takes grouped K/V), and a window layer's kernels visit the band only.
+With `remat` every block is rebuilt in the backward but for the attention
+core's output and log-sum-exp, which stay from the forward
+(`decoder.remat_block`: 201 MB a full layer and 268 MB a window layer at
+the cell's shape, so that the forward kernel runs once a step).
 
 Record format: seq_len int32 token ids | 1 label byte (ignored), the
 fixed-width record `model_zoo/bert` reads.
@@ -49,6 +53,7 @@ from model_zoo.common.decoder import (  # noqa: F401
     loss,
     optimizer,
     param_sharding,
+    remat_block,
     rotary_turn,
     shifted_nll,
 )
@@ -217,7 +222,7 @@ class Laguna(nn.Module):
     def __call__(self, features):
         c = self.config
         ids = features["input_ids"].astype(jnp.int32)        # (B, L)
-        block_cls = nn.remat(Block) if c.remat else Block
+        block_cls = remat_block(Block) if c.remat else Block
         x = DistributedEmbedding(
             c.vocab_size, c.hidden, hash_input=False, name="token_embedding"
         )(ids).astype(c.dtype)

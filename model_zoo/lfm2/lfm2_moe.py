@@ -23,7 +23,11 @@ leaf (tests/test_lfm2.py).  What it shares with the zoo's other decoders
 What a layer is comes from static per-layer tuples (`Lfm2Config.layers`):
 its kind (`conv` | `full_attention`) and whether its feed-forward is
 dense or routed, read from the PUBLISHED `layer_types` and
-`num_dense_layers` at the published indices in `layers`.
+`num_dense_layers` at the published indices in `layers`.  With `remat`
+every block is rebuilt in the backward but for an attention layer's core
+output and log-sum-exp, which stay from the forward
+(`decoder.remat_block`: the forward kernel runs once a step); a conv
+block saves nothing.
 
 Record format: seq_len int32 token ids | 1 label byte (ignored), the
 fixed-width record `model_zoo/bert` reads.
@@ -52,6 +56,7 @@ from model_zoo.common.decoder import (  # noqa: F401
     loss,
     optimizer,
     param_sharding,
+    remat_block,
     rotary,
     shifted_nll,
 )
@@ -198,7 +203,7 @@ class Lfm2Moe(nn.Module):
     def __call__(self, features):
         c = self.config
         ids = features["input_ids"].astype(jnp.int32)        # (B, L)
-        block_cls = nn.remat(Block) if c.remat else Block
+        block_cls = remat_block(Block) if c.remat else Block
         embedding = DistributedEmbedding(
             c.vocab_size, c.hidden, hash_input=False, name="token_embedding"
         )

@@ -2,6 +2,7 @@
 gradients, causal and full, odd shapes.  Off-TPU the SAME kernel runs in
 Pallas interpret mode, so this exercises the real kernel code path."""
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +10,7 @@ import pytest
 
 from elasticdl_tpu.ops.flash_attention import flash_attention
 from elasticdl_tpu.ops.ring_attention import full_attention_reference
+from model_zoo.common.decoder import remat_block
 
 
 def _qkv(batch=2, length=256, heads=4, dim=32, seed=0):
@@ -386,24 +388,27 @@ def test_half_lane_head_admission_and_names():
 
 
 # sha256 of str(make_jaxpr(value_and_grad(causal_attention ...))) at the
-# cells' bfloat16 shapes, as the commit before heads of 64 gave them: the
-# GLM cell's (4, 4096, 20, 256) and the Laguna cell's full and window
-# layers.  No cell that was there can move through `flash_attention.py`
-# while these hold; a change that means to move one states the new text.
+# cells' bfloat16 shapes: the GLM cell's (4, 4096, 20, 256) and the Laguna
+# cell's full and window layers.  No cell that was there can move through
+# `flash_attention.py` while these hold; a change that means to move one
+# states the new text.  RE-RECORDED ON PURPOSE by the change that names
+# the forward's output and log-sum-exp (`SAVED_NAMES`): two `name`
+# equations a call, nothing else (the commit before gave 6885b5dc...,
+# df5c1370..., 579349d7...).
 CELL_JAXPRS = [
     pytest.param(
         (4, 4096, 20, 256), 20, None,
-        "6885b5dce4a6b93b932bc75bf99801eda3e6e53d6a904baa53715487dd0a47a3",
+        "e328d4982a0a52a9386987f63602213af3e136f7aadfa968393240c4d50792d7",
         id="glm-mla",
     ),
     pytest.param(
         (2, 8192, 48, 128), 8, None,
-        "df5c1370525275357c2f32b163393cc5aa9ef2159a224fa0712723df09bb5a7b",
+        "8ddd5a42a9bc5b5b88a5e59c9ea9163c6212b568013150f3b7ce3d8311ac3106",
         id="laguna-full",
     ),
     pytest.param(
         (2, 8192, 64, 128), 8, 512,
-        "579349d766f4a7404cc034387ad120d5c792248832ed77cc5eafdfe00c1a8280",
+        "f4461dfacdedcfd222d4263256502b84681939cf7d9cc4eacfd54e48db24c52d",
         id="laguna-window",
     ),
 ]
@@ -428,3 +433,96 @@ def test_cells_attention_jaxpr_is_the_parents(q_shape, kv_heads, window,
         argnums=(0, 1, 2),
     ))(shaped(q_shape[2]), shaped(kv_heads), shaped(kv_heads)))
     assert hashlib.sha256(text.encode()).hexdigest() == digest
+
+
+# ---- the forward's results saved across a block's remat -------------------
+#
+# (query heads, K/V heads, head width, window) over 256 positions: the
+# three cells' attention calls in small (a head of 256, grouped heads of
+# 128 without and with a window, heads of 64 head-major)
+SAVED_SHAPES = [
+    pytest.param(2, 2, 256, None, id="width256"),
+    pytest.param(4, 2, 128, None, id="width128-grouped"),
+    pytest.param(4, 2, 128, 128, id="width128-window"),
+    pytest.param(4, 2, 64, None, id="width64-head-major"),
+]
+
+
+def _block_grad_jaxpr(remat, heads, kv_heads, dim, window, length=256):
+    """The text of the gradient's jaxpr of projections + `causal_attention`
+    + projection, the block rematerialised by `remat`."""
+    from elasticdl_tpu.ops.flash_attention import causal_attention
+
+    class Block(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            def project(count, name):
+                return nn.Dense(count * dim, use_bias=False, name=name)(
+                    x
+                ).reshape(*x.shape[:2], count, dim)
+
+            out = causal_attention(
+                project(heads, "q"), project(kv_heads, "k"),
+                project(kv_heads, "v"), window=window,
+            )
+            return x + nn.Dense(x.shape[-1], use_bias=False, name="o")(
+                out.reshape(*x.shape[:2], -1)
+            )
+
+    block = remat(Block)()
+    x = jnp.zeros((1, length, 32), jnp.float32)
+    params = jax.eval_shape(block.init, jax.random.PRNGKey(0), x)
+    return str(jax.make_jaxpr(jax.grad(
+        lambda params, x: block.apply(params, x).sum(), argnums=(0, 1)
+    ))(params, x))
+
+
+@pytest.mark.parametrize("remat, forwards", [
+    pytest.param(remat_block, 1, id="names-saved"),
+    pytest.param(nn.remat, 2, id="plain-remat"),
+])
+@pytest.mark.parametrize("heads, kv_heads, dim, window", SAVED_SHAPES)
+def test_remat_block_runs_the_forward_kernel_once(
+    heads, kv_heads, dim, window, remat, forwards
+):
+    """Under `remat_block`'s policy a block's gradient holds ONE forward
+    kernel an attention layer, under a plain remat two: the guard that the
+    names stay on the kernel's own outputs (a name further down, on the
+    projected output say, would save nothing the backward reads)."""
+    import re
+
+    text = _block_grad_jaxpr(remat, heads, kv_heads, dim, window)
+    calls = {
+        kernel: len(re.findall(rf"name=\w+_attention_{kernel}\b", text))
+        for kernel in ("fwd", "dkv", "dq")
+    }
+    assert calls == {"fwd": forwards, "dkv": 1, "dq": 1}
+
+
+@pytest.mark.parametrize("remat, exps", [
+    pytest.param(remat_block, 2, id="names-saved"),
+    pytest.param(nn.remat, 3, id="plain-remat"),
+])
+def test_blocked_form_carries_the_same_names(remat, exps):
+    """The blocked lax form (shapes that do not tile, export) names the
+    same two results, so `remat_block` saves them there too: its one tile
+    row's probabilities are built in the forward and in the backward, not
+    a third time by the remat."""
+    from elasticdl_tpu.ops.flash_attention import (
+        SAVED_NAMES,
+        blocked_causal_attention,
+        causal_attention,
+    )
+
+    small, tiled = _grouped_qkv(2, length=64, dim=16), _grouped_qkv(1, 256)
+    for attention, qkv, kernel in (
+        (blocked_causal_attention, small, False),
+        (causal_attention, small, False),
+        (causal_attention, tiled, True),
+    ):
+        forward = str(jax.make_jaxpr(attention)(*qkv))
+        assert ("causal_attention_fwd" in forward) == kernel
+        for name in SAVED_NAMES:
+            assert forward.count(f"name[name={name}]") == 1
+    text = _block_grad_jaxpr(remat, 4, 2, 16, None, length=64)
+    assert "pallas_call" not in text and text.count("= exp ") == exps

@@ -7,6 +7,7 @@ one compile across loads, the chunked walk of the sorted buffer against
 the whole-buffer form at every kind of load, and a two-task job through
 the CLI."""
 
+import functools
 import os
 import threading
 import types
@@ -26,6 +27,7 @@ from elasticdl_tpu.layers.moe import (
     RoutedExperts,
 )
 from model_zoo.glm import glm_moe_lite as zoo
+from tests import remat_cases
 
 CONFIG = dict(
     hidden_size=32, num_hidden_layers=3, first_k_dense_replace=1,
@@ -113,6 +115,32 @@ def test_float32_matches_reference_leaf_by_leaf(seeded):
     for name, want in seeded.want.items():
         error = np.linalg.norm(got[name] - want) / np.linalg.norm(want)
         assert error < 1e-4, (name, error)
+
+
+@pytest.fixture(scope="module")
+def saved_core(seeded):
+    """bf16 -> (loss, gradients) of the model as the cells run it."""
+    return functools.lru_cache(None)(lambda bf16: loss_and_grads(
+        model_of(CONFIG, bf16=bf16), seeded.variables, seeded.ids
+    ))
+
+
+@pytest.mark.parametrize("bf16", [False, True], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("other", remat_cases.OTHERS)
+def test_saving_the_attention_core_changes_no_bit(seeded, saved_core,
+                                                  monkeypatch, other, bf16):
+    """`remat=True` against the plain `nn.remat` every commit before ran
+    (bit for bit) and against no remat at all (to 1e-5 of a leaf: XLA on the
+    CPU fuses an MLA block inside a remat's computation otherwise than
+    outside one, the plain remat's gradients differ by the same 1e-6)."""
+    remat_cases.assert_saving_changes_nothing(
+        zoo, monkeypatch, other,
+        lambda remat: loss_and_grads(
+            model_of(CONFIG, bf16=bf16, remat=remat), seeded.variables,
+            seeded.ids,
+        ),
+        saved_core(bf16), no_remat_limit=1e-5,
+    )
 
 
 def test_bfloat16_inside_the_twins_rule(seeded):

@@ -7,6 +7,7 @@ expert-parallel deployment adding up to the uncut layer, the sown gate
 through the Trainer, the published sizes' parameter count, and a two-task
 job through the CLI."""
 
+import functools
 import json
 import os
 import threading
@@ -27,6 +28,7 @@ from elasticdl_tpu.layers.moe import (
     RoutedExperts,
 )
 from model_zoo.laguna import laguna as zoo
+from tests import remat_cases
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 ROPES = {
@@ -180,6 +182,30 @@ def test_the_window_and_the_groups_are_seen(seeded):
     out = model.apply(seeded.variables, {"input_ids": seeded.ids},
                       mutable=MUTABLE)[0]
     assert abs(float(out.mean()) - seeded.want_loss) > 1e-4
+
+
+@pytest.fixture(scope="module")
+def saved_core(seeded):
+    """bf16 -> (loss, gradients) of the model as the cells run it."""
+    return functools.lru_cache(None)(lambda bf16: loss_and_grads(
+        model_of(CONFIG, bf16=bf16), seeded.variables, seeded.ids
+    ))
+
+
+@pytest.mark.parametrize("bf16", [False, True], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("other", remat_cases.OTHERS)
+def test_saving_the_attention_core_changes_no_bit(seeded, saved_core,
+                                                  monkeypatch, other, bf16):
+    """`remat=True` against the plain `nn.remat` every commit before ran
+    and against no remat at all, bit for bit."""
+    remat_cases.assert_saving_changes_nothing(
+        zoo, monkeypatch, other,
+        lambda remat: loss_and_grads(
+            model_of(CONFIG, bf16=bf16, remat=remat), seeded.variables,
+            seeded.ids,
+        ),
+        saved_core(bf16),
+    )
 
 
 def test_bfloat16_inside_the_twins_rule(seeded):

@@ -8,6 +8,7 @@ deployment adding up to the uncut layer, the short convolution's kernels
 against its shifted form, the sown gauge through the Trainer, the
 published sizes' parameter count, and a two-task job through the CLI."""
 
+import functools
 import json
 import os
 import re
@@ -30,6 +31,7 @@ from elasticdl_tpu.layers.moe import (
 from elasticdl_tpu.ops import short_conv
 from model_zoo.common.decoder import MoEFFN
 from model_zoo.lfm2 import lfm2_moe as zoo
+from tests import remat_cases
 
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 # the published pattern's first eight entries; the cut's layers 0, 2..5;
@@ -191,6 +193,30 @@ def test_each_part_of_the_mathematics_is_seen(seeded):
     )
     shift = np.abs(np.asarray(with_eps - without)).max()
     assert 0.0 < shift < 1e-5 * np.abs(np.asarray(without)).max()
+
+
+@pytest.fixture(scope="module")
+def saved_core(seeded):
+    """bf16 -> (loss, gradients) of the model as the cells run it."""
+    return functools.lru_cache(None)(lambda bf16: loss_and_grads(
+        model_of(CONFIG, bf16=bf16), seeded.variables, seeded.ids
+    ))
+
+
+@pytest.mark.parametrize("bf16", [False, True], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("other", remat_cases.OTHERS)
+def test_saving_the_attention_core_changes_no_bit(seeded, saved_core,
+                                                  monkeypatch, other, bf16):
+    """`remat=True` against the plain `nn.remat` every commit before ran
+    and against no remat at all, bit for bit."""
+    remat_cases.assert_saving_changes_nothing(
+        zoo, monkeypatch, other,
+        lambda remat: loss_and_grads(
+            model_of(CONFIG, bf16=bf16, remat=remat), seeded.variables,
+            seeded.ids,
+        ),
+        saved_core(bf16),
+    )
 
 
 def test_bfloat16_inside_the_twins_rule(seeded):
