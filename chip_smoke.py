@@ -141,8 +141,7 @@ def preflight():
         f"(from JAX_COMPILATION_CACHE_DIR: "
         f"{bool(os.environ.get('JAX_COMPILATION_CACHE_DIR'))}, "
         f"entries: {cache_entries(cache_dir)}) "
-        f"native_scanner={native_io.available()} "
-        f"peaks={programs.device_peaks()}",
+        f"native_scanner={native_io.available()}",
     )
     if platform != "tpu":
         raise SystemExit(

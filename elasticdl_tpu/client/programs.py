@@ -31,7 +31,7 @@ def _eng(value: float) -> str:
 
 def render_programs(summary: dict) -> str:
     """One report frame from a ProgramRegistry.summary() dict: headline
-    totals + live roofline ratios, then a row per named program."""
+    totals, then a row per named program."""
     lines = [
         "elasticdl programs — {n} programs, {c} compiles, "
         "{s} signatures, {st} storms".format(
@@ -39,11 +39,6 @@ def render_programs(summary: dict) -> str:
             c=summary.get("compiles_total", 0),
             s=summary.get("signatures_total", 0),
             st=summary.get("storms_total", 0),
-        ),
-        "live: mfu={mfu:.3f} hbm={hbm:.3f} bytes/s={bw}".format(
-            mfu=summary.get("mfu", 0.0),
-            hbm=summary.get("hbm_utilization", 0.0),
-            bw=_eng(summary.get("bytes_per_sec", 0.0)),
         ),
         "program".ljust(24) + "compiles".rjust(9) + "sigs".rjust(6)
         + "budget".rjust(7) + "storms".rjust(7) + "c_p50".rjust(9)

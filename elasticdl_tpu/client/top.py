@@ -208,14 +208,11 @@ def render(varz: dict, serving_varz: Optional[dict] = None,
     if programs and programs.get("programs"):
         lines.append(
             "programs: n={n} compiles={compiles} sigs={sigs} "
-            "storms={storms} mfu={mfu:.3f} "
-            "bw={bw:.2e}B/s".format(
+            "storms={storms}".format(
                 n=programs.get("programs", 0),
                 compiles=programs.get("compiles_total", 0),
                 sigs=programs.get("signatures_total", 0),
                 storms=programs.get("storms_total", 0),
-                mfu=programs.get("mfu", 0.0),
-                bw=programs.get("bytes_per_sec", 0.0),
             )
         )
     resilience = snapshot.get("resilience", {})

@@ -40,6 +40,46 @@ STEP_PHASES = LOOP_PHASES + PRODUCER_PHASES + (
     "cold_gather",
 )
 
+#: The device-scope vocabulary (docs/OBSERVABILITY.md "Device scope
+#: catalogue"): the `jax.named_scope`s by which a train step's device
+#: time is told apart.  A compiled instruction belongs to the INNERMOST
+#: entry on its `op_name` path (`catalogue_scope`), so `dispatch` inside
+#: `glm/moe` is `dispatch` and what `glm/moe` does outside its inner
+#: scopes is `glm/moe`; together they tile the step
+#: (`scope_unattributed_share` is the check).  Every entry is read by a
+#: per-layer metric of the benchmark (PERF.md section 3 names it) and by
+#: the operator's `scope_ms.json` summary; a scope nothing reads does
+#: not enter.
+DEVICE_SCOPES = (
+    # worker/trainer.py: optimizer.update + apply_updates (+ the int8
+    # arena's fold)
+    "train/optimizer",
+    # layers/embedding.py: the forward gather; the backward's two halves
+    "arena/lookup", "arena/combine", "arena/scatter",
+    # model_zoo/deepfm: everything after the lookups
+    "deepfm/tower",
+    # layers/moe.py, inside a model's `<model>/moe`
+    "router", "dispatch", "experts", "combine",
+    # model_zoo/common/decoder.py: the shared expert
+    "shared",
+    "glm/embed", "glm/norm", "glm/mla/proj", "glm/mla/core", "glm/mla/out",
+    "glm/dense_ffn", "glm/moe", "glm/mtp", "glm/head_ce",
+    "laguna/embed", "laguna/norm", "laguna/attn_full", "laguna/attn_window",
+    "laguna/gate", "laguna/dense_ffn", "laguna/moe", "laguna/head_ce",
+    "lfm2/embed", "lfm2/norm", "lfm2/short_conv", "lfm2/attn",
+    "lfm2/dense_ffn", "lfm2/moe", "lfm2/head_ce",
+)
+
+#: Kernels the TPU's compiler makes from ONE primitive and names after
+#: it, dropping the path the primitive was traced under (`lax.ragged_dot`
+#: becomes `%ragged-dot-none.N` with `op_name="ragged-dot-none"`): the
+#: scope this program calls that primitive under, by the instruction
+#: name's prefix.  `layers/moe.py: grouped_matmul` and `_walk_bwd`'s two
+#: stack gradients are the only callers, all under `experts`
+#: (tests/test_tpu_compile.py compiles one for the chip and finds it
+#: there).
+COMPILER_NAMED_SCOPES = {"ragged-dot": "experts"}
+
 #: Records the span ring keeps before the oldest fall out.  A train step
 #: makes about five (measured: 4.6 in the benchmark's DeepFM cell), so
 #: this is some 7,000 steps back.
@@ -419,6 +459,165 @@ class LatencyHistogram:
         }
 
 
+_SCOPE_PARTS = tuple(tuple(s.split("/")) for s in DEVICE_SCOPES)
+
+
+def _ends_at(parts, wanted) -> int:
+    """Index just past the LAST place `wanted` lies in `parts` as a
+    contiguous run, 0 where it does not."""
+    width = len(wanted)
+    for start in range(len(parts) - width, -1, -1):
+        if parts[start:start + width] == wanted:
+            return start + width
+    return 0
+
+
+def catalogue_scope(scope: str) -> str:
+    """The innermost `DEVICE_SCOPES` entry on the path `scope`
+    (`layer_1/glm/moe/routed/dispatch` -> `dispatch`), "" for none."""
+    parts = tuple(scope.split("/"))
+    best, best_key = "", (0, 0)
+    for entry, wanted in zip(DEVICE_SCOPES, _SCOPE_PARTS):
+        key = (_ends_at(parts, wanted), len(wanted))
+        if key[0] and key > best_key:
+            best, best_key = entry, key
+    return best
+
+
+def instruction_name(hlo_text: str) -> str:
+    """`%fusion.3 = f32[8,16]{...} fusion(...)` -> `fusion.3`: the name
+    a trace event and the compiled text share."""
+    return hlo_text.split(" ", 1)[0].lstrip("%")
+
+
+def device_ms_by_scope(op_seconds, table, scopes=None, phase=None,
+                       exclude_ops=None) -> dict:
+    """Device time of a trace's operations by the program's scopes.
+
+    `op_seconds` is {an operation's HLO text (or bare instruction name):
+    time}, `table` the program's scope table (`ProgramRegistry.
+    scope_table`).  Joined by the instruction's name; LEAVES only: a
+    `while`, `conditional` or `call` lasts as long as the operations
+    inside it, which the trace names too.  Kept are the leaves that
+    belong to one of `scopes` (None keeps all): a catalogue entry names
+    the leaves whose INNERMOST entry it is (`combine` is not the
+    `experts` inside the loop `combine` wraps), any other name (a
+    module's, `layer_1`) the leaves with it on their path; in `phase`
+    (`forward`, `backward`, `rebuild`; None keeps all); whose text
+    matches none of the `exclude_ops` patterns.  Returns, in
+    `op_seconds`' unit,
+
+        by_scope   {(innermost catalogue entry or "", phase): time}
+        unjoined   time of operations the table does not hold (counted
+                   whatever the filters: nothing says where they belong)
+        mixed      time, within by_scope, of fusions whose fused
+                   instructions lie in more than one catalogue entry:
+                   how far a by-scope number can be off
+        mixed_ops  {instruction name: time} of those fusions
+    """
+    import re
+
+    exclude = [re.compile(p) for p in exclude_ops or ()]
+    entries = set(scopes or ()) & set(DEVICE_SCOPES)
+    named = [tuple(s.split("/")) for s in scopes or () if s not in entries]
+    by_scope, mixed_ops, unjoined = {}, {}, 0.0
+    for text, seconds in op_seconds.items():
+        name = instruction_name(text)
+        row = table.get(name)
+        if row is None:
+            unjoined += seconds
+            continue
+        if row.container or (phase is not None and row.phase != phase):
+            continue
+        if scopes and row.entry not in entries:
+            parts = tuple(row.scope.split("/"))
+            if not any(_ends_at(parts, other) for other in named):
+                continue
+        if any(p.search(text) for p in exclude):
+            continue
+        key = (row.entry, row.phase)
+        by_scope[key] = by_scope.get(key, 0.0) + seconds
+        if len(row.fused) > 1:
+            mixed_ops[name] = mixed_ops.get(name, 0.0) + seconds
+    return {
+        "by_scope": by_scope, "unjoined": unjoined,
+        "mixed": sum(mixed_ops.values()), "mixed_ops": mixed_ops,
+    }
+
+
+def scope_summary(op_seconds, table, steps: int) -> dict:
+    """{scope: {"ms_per_step", "rebuilt_share"}} of one capture: each
+    catalogue scope's device milliseconds a step and the share of them
+    that JAX's remat spent rebuilding the forward; `(no scope)` is what
+    lies under no catalogue entry, `(unjoined)` what the table lacks."""
+    whole = device_ms_by_scope(op_seconds, table)
+    scale = 1e3 / max(int(steps), 1)
+    total, rebuilt = {"(unjoined)": whole["unjoined"]}, {}
+    for (scope, phase), seconds in whole["by_scope"].items():
+        scope = scope or "(no scope)"
+        total[scope] = total.get(scope, 0.0) + seconds
+        if phase == "rebuild":
+            rebuilt[scope] = rebuilt.get(scope, 0.0) + seconds
+    return {
+        scope: {
+            "ms_per_step": seconds * scale,
+            "rebuilt_share": rebuilt.get(scope, 0.0) / seconds,
+        }
+        for scope, seconds in sorted(total.items(), key=lambda kv: -kv[1])
+        if seconds
+    }
+
+
+def xla_op_seconds(log_dir: str) -> dict:
+    """{operation's HLO text: seconds} summed over the `XLA Ops` line of
+    the first device plane of the newest capture under `log_dir`; {}
+    where there is none (the CPU backend writes no device plane)."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(os.path.join(
+        log_dir, "plugins", "profile", "*", "*.xplane.pb"
+    )))
+    seconds: dict = {}
+    for plane in ProfileData.from_file(paths[-1]).planes if paths else ():
+        if seconds or not plane.name.startswith("/device:"):
+            continue
+        for line in plane.lines:
+            for event in line.events if line.name == "XLA Ops" else ():
+                seconds[event.name] = (
+                    seconds.get(event.name, 0.0) + event.duration_ns * 1e-9
+                )
+    return seconds
+
+
+def _write_scope_summary(log_dir: str, steps: int) -> None:
+    """`scope_ms.json` beside the capture and one log line a scope: the
+    captured train steps' device time by `DEVICE_SCOPES`."""
+    import json
+    import os
+
+    from elasticdl_tpu.common import programs
+
+    op_seconds = xla_op_seconds(log_dir)
+    registry = programs.default_program_registry()
+    # the fused program is compiled only where it is the one that runs
+    table = registry.scope_table(
+        "worker_train_step"
+    ) or registry.scope_table("worker_train_step_many")
+    if not op_seconds or not table or not steps:
+        return
+    summary = scope_summary(op_seconds, table, steps)
+    with open(os.path.join(log_dir, "scope_ms.json"), "w") as f:
+        json.dump({"steps": steps, "scopes": summary}, f, indent=1)
+    for scope, row in summary.items():
+        logger.info(
+            "device scope %-22s %9.2f ms a step, rebuilt %4.1f%%", scope,
+            row["ms_per_step"], 100.0 * row["rebuilt_share"],
+        )
+
+
 @contextlib.contextmanager
 def trace(log_dir: str):
     """Capture a JAX profiler trace viewable in TensorBoard/Perfetto:
@@ -426,15 +625,26 @@ def trace(log_dir: str):
         with profiler.trace("/tmp/trace"):
             state, loss = trainer.train_on_batch(state, batch)
             jax.block_until_ready(loss)
+
+    and, where the capture holds device operations of the train step,
+    its device time by named scope (`scope_ms.json` in `log_dir` and one
+    log line a scope: docs/OBSERVABILITY.md "Device scope catalogue").
     """
     import jax
 
+    steps_before = process_phase_timer().steps
     jax.profiler.start_trace(log_dir)
     try:
         yield
     finally:
         jax.profiler.stop_trace()
         logger.info("Profiler trace written to %s", log_dir)
+        try:
+            _write_scope_summary(
+                log_dir, process_phase_timer().steps - steps_before
+            )
+        except Exception:   # a summary must never end the job it sums
+            logger.exception("no scope summary for %s", log_dir)
 
 
 @contextlib.contextmanager

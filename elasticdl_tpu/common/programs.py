@@ -11,12 +11,13 @@ per named program and per distinct aval signature:
 - compile / retrace counts and the distinct-signature count;
 - XLA's own cost model (``cost_analysis()`` flops + bytes accessed).
 
-Joining per-program cost against the step-rate telemetry the worker
-already publishes (``bind_step_rate``) turns the static ledger into
-live ``worker_program_bytes_per_sec`` / ``worker_mfu_ratio`` /
-``worker_hbm_utilization_ratio`` gauges: the memory-wall numbers the
-perf roadmap is navigated by, visible on /varz while training runs
-instead of once per bench round.
+It also keeps, for the latest observed compile of each program, the
+abstract arguments it was compiled for, and builds ON REQUEST the
+program's scope table (:meth:`ProgramRegistry.scope_table`): every
+instruction a device trace can name, with the ``jax.named_scope`` path,
+the phase (forward / backward / remat's rebuilt forward) and the opcode
+its compiled text gives it.  ``profiler.device_ms_by_scope`` joins a
+trace's per-operation seconds to it.
 
 Retrace detection closes the loop: a program whose distinct-signature
 count exceeds its declared budget (serving-engine buckets declare
@@ -45,12 +46,17 @@ one per signature, cache it, record its compile, and harvest
 from __future__ import annotations
 
 import hashlib
+import re
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
 from elasticdl_tpu.common import events
 from elasticdl_tpu.common import metrics as metrics_lib
+from elasticdl_tpu.common import profiler
+from elasticdl_tpu.common.log_utils import get_logger
+
+logger = get_logger(__name__)
 
 # How long a compile-seconds sample list is kept per program (for the
 # ledger's p50/p99; the histogram metric keeps the full distribution).
@@ -60,34 +66,203 @@ _COMPILE_SAMPLES_KEPT = 256
 # aval signature, NOT Python hash() — byte-stable across processes.
 _DIGEST_CHARS = 12
 
+# ---- the scope table ------------------------------------------------------
+#
+# The compiled text gives every instruction the name stack it was traced
+# under (`metadata={op_name="..."}`), e.g.
+#
+#   jit(step)/jvp(M)/layer_1/glm/mla/proj/q/dot_general            forward
+#   jit(step)/transpose(jvp(M))/jvp(M)/checkpoint/layer_1/
+#       glm/dense_ffn/up/dot_general                                backward
+#   jit(step)/transpose(jvp(M))/jvp(M)/checkpoint/
+#       rematted_computation/layer_0/glm/dense_ffn/tanh             rebuild
+#   jit(step)/jvp(M)/glm/head_ce/while/body/closed_call/dot_general in a loop
+#   jit(step)/train/optimizer/sub
+#
+# and a device trace names every operation by its instruction, so the
+# instruction's name joins the two.  The markers below are what JAX
+# 0.9 writes (`jax/_src/ad_checkpoint.py`, `interpreters/ad.py`);
+# `tests/test_scope_table.py` finds each in a step compiled by the
+# installed JAX, so a JAX that renames one fails a test and no metric
+# silently reads 0.
 
-# Datasheet peaks keyed by the exact `device_kind` string the chip
-# reports.  One row per kind that has actually been read off a device:
-#   "TPU v5 lite" — Google Cloud documentation, "TPU v5e": 197 TFLOP/s
-#   bf16, 819 GB/s HBM per chip.
-_DEVICE_PEAKS = {
-    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
-}
+REBUILD_MARKER = "rematted_computation"   # remat's second forward
+BACKWARD_MARKER = "transpose("            # the transposed (backward) pass
+CHECKPOINT_MARKER = "checkpoint"          # a remat block, either pass
+PHASES = ("forward", "backward", "rebuild")
+# an instruction whose device time is its children's, which the trace
+# names one by one: counting it too counts them twice
+CONTAINER_OPCODES = frozenset(("while", "conditional", "call"))
+
+# path components that are structure, not scopes (module names such as
+# `layer_1` are scopes: a rule may ask for one layer)
+_STRUCTURAL = frozenset((
+    CHECKPOINT_MARKER, REBUILD_MARKER, "while", "body", "cond",
+    "closed_call", "custom_vjp_call", "custom_vjp_call_jaxpr",
+    "custom_jvp_call",
+))
+_BRANCH = re.compile(r"branch_\d+_fun")
+_JIT_COMPONENT = re.compile(r"(?:^|/)p?jit\([^()]*\)")
+_WRAPPER_OPEN = re.compile(r"\w+\(")      # jvp( transpose( vmap( ...
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%(\S+) = (.*)$")
+_OPCODE = re.compile(r"^.*? ([\w-]+)\(")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_CALLS = re.compile(r"calls=%([^\s,)}]+)")
+_TO_APPLY = re.compile(r"to_apply=%([^\s,)}]+)")
+# the computations of a `while`, a `call`, a `conditional`
+_CALLEES = re.compile(
+    r"(?:body|condition|to_apply|true_computation|false_computation)="
+    r"%([^\s,)}]+)"
+)
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_NAMES = re.compile(r"%([^\s,(){}]+)")
 
 
-def device_peaks() -> Optional[dict]:
-    """Datasheet peak numbers for MFU / bandwidth rooflines of the live
-    telemetry.  The CPU platform has no peaks (None:
-    the ratio gauges read 0.0); an accelerator whose `device_kind` is
-    not in the table is an error, never a guessed row."""
-    import jax
+class ScopeRow(NamedTuple):
+    """One instruction a device trace can name.  `scope` is its
+    `op_name` path without `jit(...)`, the transform wrappers, the
+    structural components and the final primitive; `entry` the innermost
+    `profiler.DEVICE_SCOPES` entry on that path ("" = none); `fused`,
+    for a fusion, the catalogue entries of its fused instructions (more
+    than one: the fusion spans scopes and is charged whole to the scope
+    of its own metadata)."""
 
-    device = jax.devices()[0]
-    if device.platform == "cpu":
-        return None
-    peaks = _DEVICE_PEAKS.get(device.device_kind)
-    if peaks is None:
-        raise ValueError(
-            f"no peaks row for device_kind {device.device_kind!r} "
-            f"(platform {device.platform!r}); add it to "
-            "programs._DEVICE_PEAKS with its source"
+    opcode: str
+    computation: str
+    container: bool
+    scope: str
+    phase: str
+    entry: str
+    fused: Tuple[str, ...]
+
+
+def split_op_name(op_name: str) -> Tuple[str, str]:
+    """(scope, phase) of one `op_name`; ("", "") for a name JAX did not
+    write (the phase of such an instruction is not known).  Instructions
+    XLA merged carry several names joined by `;`: the first stands for
+    all."""
+    first = op_name.split(";", 1)[0]
+    if not first.startswith(("jit(", "pjit(")):
+        return "", ""    # a parameter's path or a compiler-made name
+    if REBUILD_MARKER in first:
+        phase = "rebuild"
+    elif BACKWARD_MARKER in first:
+        phase = "backward"
+    else:
+        phase = "forward"
+    path = _WRAPPER_OPEN.sub("", _JIT_COMPONENT.sub("", first))
+    parts = [c for c in path.replace(")", "").split("/") if c][:-1]
+    return "/".join(
+        c for c in parts
+        if c not in _STRUCTURAL and not _BRANCH.fullmatch(c)
+    ), phase
+
+
+def parse_scope_table(hlo_text: str) -> Dict[str, ScopeRow]:
+    """{instruction name: ScopeRow} of a compiled module's text, for the
+    instructions a trace can name: those of the entry, loop, branch and
+    called computations.  A fused computation's (and a reducer's)
+    instructions never run on their own; they only give their fusion
+    its `fused` entries."""
+    lines = hlo_text.split("\n")
+    inner = set()   # computations whose instructions no trace names
+    for line in lines:
+        if "calls=%" in line:
+            inner.update(_CALLS.findall(line))
+        elif "to_apply=%" in line and " call(" not in line:
+            inner.update(_TO_APPLY.findall(line))
+    split_cache: Dict[str, Tuple[str, str, str]] = {}
+
+    def located(line):
+        found = _OP_NAME.search(line)
+        if found is None:
+            return "", "", ""
+        op_name = found.group(1)
+        if op_name not in split_cache:
+            scope, phase = split_op_name(op_name)
+            split_cache[op_name] = (
+                scope, phase, profiler.catalogue_scope(scope)
+            )
+        return split_cache[op_name]
+
+    table: Dict[str, ScopeRow] = {}
+    # inner computation -> the (entry, phase) pairs of its instructions
+    located_in: Dict[str, set] = {}
+    called_by: Dict[str, str] = {}    # loop or branch -> its container
+    first_user: Dict[str, str] = {}   # the text is in schedule order
+    pathless = []
+    computation, is_inner = "", False
+    for line in lines:
+        if not line.startswith(" "):
+            header = _COMPUTATION.match(line)
+            if header is not None:
+                computation = header.group(1)
+                is_inner = computation in inner
+                if is_inner:
+                    located_in[computation] = set()
+            continue
+        if is_inner:
+            _, phase, entry = located(line)
+            if entry:
+                located_in[computation].add((entry, phase))
+            for callee in _CALLS.findall(line):   # a fusion in a fusion
+                located_in[computation] |= located_in.get(callee, set())
+            continue
+        instruction = _INSTRUCTION.match(line)
+        if instruction is None:
+            continue
+        name, rest = instruction.groups()
+        opcode = _OPCODE.match(rest)
+        for operand in _NAMES.findall(rest, opcode.end() if opcode else 0):
+            first_user.setdefault(operand, name)
+        opcode = opcode.group(1) if opcode else ""
+        scope, phase, entry = located(rest)
+        fused: Tuple[str, ...] = ()
+        if opcode == "fusion":
+            inside = set().union(*(
+                located_in.get(c, ()) for c in _CALLS.findall(rest)
+            ))
+            fused = tuple(sorted({e for e, _ in inside}))
+            if not scope and len(fused) == 1:
+                # no path of its own, and its instructions agree on one
+                phases = {p for _, p in inside}
+                scope = entry = fused[0]
+                phase = phases.pop() if len(phases) == 1 else ""
+        elif opcode in CONTAINER_OPCODES:
+            branches = ",".join(_BRANCHES.findall(rest)).replace("%", "")
+            for callee in _CALLEES.findall(rest) + branches.split(","):
+                called_by[callee.strip()] = name
+        if not scope:
+            pathless.append(name)
+        table[name] = ScopeRow(
+            opcode, computation, opcode in CONTAINER_OPCODES,
+            scope, phase, entry, fused,
         )
-    return peaks
+    # What the compiler made itself has no path (layout copies, zeroed
+    # buffers, asynchronous halves): it is charged to its first user,
+    # the operation it was made for, else to the loop or branch it lies
+    # in.  Users and containers come later in the text, so the reversed
+    # order has settled them, pathless ones included, before they are
+    # asked.  A kernel the compiler names after a primitive takes the
+    # scope this program calls that primitive under.
+    for name in reversed(pathless):
+        row = table[name]
+        owner = table.get(first_user.get(name, ""))
+        if owner is None or not owner.scope:
+            owner = table.get(called_by.get(row.computation, ""))
+        if owner is not None and owner.scope:
+            row = row._replace(
+                scope=owner.scope, phase=owner.phase, entry=owner.entry
+            )
+        for prefix, entry in profiler.COMPILER_NAMED_SCOPES.items():
+            if name.startswith(prefix):
+                row = row._replace(
+                    scope=f"{row.scope}/{entry}".lstrip("/"), entry=entry
+                )
+        table[name] = row
+    return table
 
 
 def cost_analysis_dict(compiled) -> dict:
@@ -163,6 +338,23 @@ def describe_avals(args, limit: int = 8) -> str:
     return ", ".join(parts)
 
 
+def abstract_arguments(args):
+    """`args` with every array leaf replaced by its ShapeDtypeStruct
+    (sharding and weak type kept); reads no data, so donated (deleted)
+    arrays are fine."""
+    import jax
+
+    def abstract(x):
+        if getattr(x, "shape", None) is None or not hasattr(x, "dtype"):
+            return x
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=getattr(x, "sharding", None),
+            weak_type=bool(getattr(x, "weak_type", False)),
+        )
+
+    return jax.tree_util.tree_map(abstract, args)
+
+
 def _has_tracers(args) -> bool:
     import jax
 
@@ -202,7 +394,6 @@ class ProgramRegistry:
         self.storm_window_s = float(storm_window_s)
         self._lock = threading.Lock()
         self._programs: Dict[str, dict] = {}
-        self._rates: Dict[str, Tuple[Callable[[], float], int]] = {}
         self._on_storm = on_storm
         reg = metrics or metrics_lib.default_registry()
         self._compile_hist = reg.histogram(
@@ -225,21 +416,16 @@ class ProgramRegistry:
             "recompile storms (signature budget blown within the window)",
             labelnames=("program",),
         )
-        reg.gauge_fn(
-            "worker_program_bytes_per_sec",
-            lambda: self.live()["bytes_per_sec"],
-            "cost-model bytes/s across rate-bound programs (cost x rate)",
+        self._scope_builds_total = reg.counter(
+            "worker_program_scope_table_builds_total",
+            "scope tables built on request (a compile of its own, counted "
+            "nowhere else)",
+            labelnames=("program",),
         )
-        reg.gauge_fn(
-            "worker_mfu_ratio",
-            lambda: self.live()["mfu"],
-            "cost-model flops/s over the device datasheet peak (0 off-TPU)",
-        )
-        reg.gauge_fn(
-            "worker_hbm_utilization_ratio",
-            lambda: self.live()["hbm_utilization"],
-            "cost-model bytes/s over the device HBM roof (0 off-TPU)",
-        )
+        # name -> (RegisteredProgram, abstract arguments) of the latest
+        # observed compile, and the table built from it
+        self._scope_sources: Dict[str, Tuple[Any, tuple]] = {}
+        self._scope_tables: Dict[str, Dict[str, "ScopeRow"]] = {}
 
     # -- recording ----------------------------------------------------
 
@@ -322,52 +508,56 @@ class ProgramRegistry:
             except Exception:
                 pass
 
-    def bind_step_rate(
-        self,
-        name: str,
-        rate_fn: Callable[[], float],
-        steps_per_execution: int = 1,
-    ) -> None:
-        """Join a program's per-execution cost against a live step rate
-        (optimizer steps/sec).  `steps_per_execution` scales fused
-        programs whose one execution advances K steps."""
+    # -- the scope table ---------------------------------------------
+
+    def keep_compiled_for(self, program: "RegisteredProgram", args) -> None:
+        """Remember what `program` was just compiled for: shape, dtype
+        and sharding of every leaf, no array.  Called on an OBSERVED
+        compile only; the latest one of a name is the one a trace
+        shows.  The program itself (its function's closure: a model, an
+        optimizer) stays alive with it until the name compiles again:
+        the table is asked for after the job that ran it has ended."""
+        abstract = abstract_arguments(args)
         with self._lock:
-            self._rates[name] = (rate_fn, max(int(steps_per_execution), 1))
+            self._scope_sources[program.name] = (program, abstract)
+            self._scope_tables.pop(program.name, None)
+
+    def scope_table(self, name: str) -> Optional[Dict[str, ScopeRow]]:
+        """`parse_scope_table` of program `name` as last compiled, built
+        on the first request and kept; None for a program this process
+        has not compiled (or cannot compile ahead of time).  The build
+        lowers and compiles from the kept abstract arguments: with the
+        persistent cache warm that loads the executable that ran.  It
+        takes seconds and a text of many megabytes, so never ask on the
+        hot path; it is not a compile of the job (no ledger entry, no
+        storm)."""
+        with self._lock:
+            table = self._scope_tables.get(name)
+            source = self._scope_sources.get(name)
+        if table is not None or source is None:
+            return table
+        program, abstract = source
+        start = time.perf_counter()
+        try:
+            text = program.compiled_text(*abstract)
+        except Exception:
+            logger.exception("scope table of %s: cannot compile", name)
+            return None
+        compiled_s = time.perf_counter() - start
+        table = parse_scope_table(text)
+        self._scope_builds_total.labels(program=name).inc()
+        logger.info(
+            "scope table of %s: %d instructions from %.1f MB of text, "
+            "compile or cache load %.1fs, parse %.1fs", name, len(table),
+            len(text) / 1e6, compiled_s,
+            time.perf_counter() - start - compiled_s,
+        )
+        with self._lock:
+            if self._scope_sources.get(name) is source:
+                self._scope_tables[name] = table
+        return table
 
     # -- views --------------------------------------------------------
-
-    def live(self) -> dict:
-        """Live cost x rate attribution across rate-bound programs."""
-        with self._lock:
-            bound = list(self._rates.items())
-            latest: Dict[str, dict] = {}
-            for name, _ in bound:
-                rec = self._programs.get(name)
-                if rec and rec["latest"] is not None:
-                    latest[name] = dict(rec["signatures"][rec["latest"]])
-        flops_rate = bytes_rate = 0.0
-        for name, (rate_fn, spe) in bound:
-            cost = latest.get(name)
-            if not cost:
-                continue
-            try:
-                rate = float(rate_fn() or 0.0)
-            except Exception:
-                rate = 0.0
-            flops_rate += cost["flops"] * rate / spe
-            bytes_rate += cost["bytes"] * rate / spe
-        # no rate-bound program means this process runs none (the
-        # master): asking for peaks would initialise a backend there and
-        # take the chips its workers need
-        peaks = device_peaks() if bound else None
-        return {
-            "flops_per_sec": flops_rate,
-            "bytes_per_sec": bytes_rate,
-            "mfu": flops_rate / peaks["bf16_flops"] if peaks else 0.0,
-            "hbm_utilization": (
-                bytes_rate / peaks["hbm_bytes_per_s"] if peaks else 0.0
-            ),
-        }
 
     def ledger(self) -> dict:
         """Per-program ledger: compiles, signatures, budget, storms,
@@ -398,26 +588,22 @@ class ProgramRegistry:
         return out
 
     def summary(self) -> dict:
-        """The /varz "programs" payload: headline totals + live rates +
-        the full ledger (what `elasticdl programs` renders)."""
+        """The /varz "programs" payload: headline totals + the full
+        ledger (what `elasticdl programs` renders)."""
         led = self.ledger()
-        live = self.live()
         return {
             "programs": len(led),
             "compiles_total": sum(p["compiles"] for p in led.values()),
             "signatures_total": sum(p["signatures"] for p in led.values()),
             "storms_total": sum(p["storms"] for p in led.values()),
-            "mfu": round(live["mfu"], 6),
-            "bytes_per_sec": round(live["bytes_per_sec"], 1),
-            "hbm_utilization": round(live["hbm_utilization"], 6),
             "ledger": led,
         }
 
     def forensics(self) -> dict:
         """The incident-bundle `programs.json` section.  Ledger minus
-        live rates and compile wall-time quantiles — both mix in
-        wall-clock state, and bundles must be byte-identical across
-        same-seed runs (the flight-recorder discipline)."""
+        compile wall-time quantiles — they mix in wall-clock state, and
+        bundles must be byte-identical across same-seed runs (the
+        flight-recorder discipline)."""
         led = self.ledger()
         return {"ledger": {
             name: {
@@ -502,7 +688,13 @@ class RegisteredProgram:
             tls.cell = prev
         if cell:
             self._record(sig, max(clock() - start, 0.0), avals, cost=None)
+            self._registry.keep_compiled_for(self, args)
         return out
+
+    def compiled_text(self, *args) -> str:
+        """The optimized HLO text of the executable for `args` (arrays
+        or ShapeDtypeStructs), compiled outside the ledger."""
+        return self._jitted.lower(*args).compile().as_text()
 
     def aot_compile(self, *args):
         """Build (once per signature) the AOT executable — the prewarm

@@ -168,6 +168,54 @@ def arena_rows(features: Tuple[Tuple[str, int], ...]) -> int:
     return sum(int(capacity) for _, capacity in features)
 
 
+def _looked_up(arena, ids, prehashed, lookup):
+    """`EmbeddingArena.__call__` past its parameters: the ids hashed
+    into arena rows, ONE `lookup` over all of them, the pads masked."""
+    if prehashed:
+        rows = jnp.asarray(ids)
+        return lookup(rows.reshape(-1)).reshape(
+            rows.shape + (arena.output_dim,)
+        )
+    if set(ids) != {name for name, _ in arena.features}:
+        raise ValueError(
+            f"arena expects ids for {[n for n, _ in arena.features]}, "
+            f"got {sorted(ids)}"
+        )
+    # Per-feature hashed rows, flattened per example and concatenated:
+    # the single gather's id stream.  Pure index arithmetic — XLA
+    # fuses it into the gather; no extra kernels.
+    batch = None
+    parts, valids, shapes = [], [], []
+    offset = 0
+    for name, capacity in arena.features:
+        x = jnp.asarray(ids[name])
+        if batch is None:
+            batch = x.shape[0]
+        valid = x != arena.pad_id
+        rows = hash_ids(
+            jnp.where(valid, x, 0), capacity, mix=arena.hash_input
+        ) + jnp.int32(offset)
+        parts.append(rows.reshape(batch, -1))
+        valids.append(valid.reshape(batch, -1))
+        shapes.append(x.shape)
+        offset += int(capacity)
+    all_rows = jnp.concatenate(parts, axis=1)          # (B, sum k_i)
+    all_valid = jnp.concatenate(valids, axis=1)
+    vecs = lookup(all_rows.reshape(-1)).reshape(
+        all_rows.shape + (arena.output_dim,)
+    )
+    vecs = jnp.where(all_valid[..., None], vecs, 0.0)
+    out, col = {}, 0
+    for (name, _), shape in zip(arena.features, shapes):
+        k = int(np.prod(shape[1:], dtype=np.int64)) if len(shape) > 1 \
+            else 1
+        out[name] = vecs[:, col: col + k].reshape(
+            shape + (arena.output_dim,)
+        )
+        col += k
+    return out
+
+
 class EmbeddingArena(nn.Module):
     """N per-feature embedding tables fused into one parameter.
 
@@ -249,49 +297,10 @@ class EmbeddingArena(nn.Module):
             def lookup(flat_rows):
                 return lookup_rows(self, table, flat_rows)
 
-        if prehashed:
-            rows = jnp.asarray(ids)
-            return lookup(rows.reshape(-1)).reshape(
-                rows.shape + (self.output_dim,)
-            )
-        if set(ids) != {name for name, _ in self.features}:
-            raise ValueError(
-                f"arena expects ids for {[n for n, _ in self.features]}, "
-                f"got {sorted(ids)}"
-            )
-        # Per-feature hashed rows, flattened per example and concatenated:
-        # the single gather's id stream.  Pure index arithmetic — XLA
-        # fuses it into the gather; no extra kernels.
-        batch = None
-        parts, valids, shapes = [], [], []
-        offset = 0
-        for name, capacity in self.features:
-            x = jnp.asarray(ids[name])
-            if batch is None:
-                batch = x.shape[0]
-            valid = x != self.pad_id
-            rows = hash_ids(
-                jnp.where(valid, x, 0), capacity, mix=self.hash_input
-            ) + jnp.int32(offset)
-            parts.append(rows.reshape(batch, -1))
-            valids.append(valid.reshape(batch, -1))
-            shapes.append(x.shape)
-            offset += int(capacity)
-        all_rows = jnp.concatenate(parts, axis=1)          # (B, sum k_i)
-        all_valid = jnp.concatenate(valids, axis=1)
-        vecs = lookup(all_rows.reshape(-1)).reshape(
-            all_rows.shape + (self.output_dim,)
-        )
-        vecs = jnp.where(all_valid[..., None], vecs, 0.0)
-        out, col = {}, 0
-        for (name, _), shape in zip(self.features, shapes):
-            k = int(np.prod(shape[1:], dtype=np.int64)) if len(shape) > 1 \
-                else 1
-            out[name] = vecs[:, col: col + k].reshape(
-                shape + (self.output_dim,)
-            )
-            col += k
-        return out
+        # hashing, the gather and the masks are one scope (they fuse):
+        # profiler.DEVICE_SCOPES
+        with jax.named_scope("arena/lookup"):
+            return _looked_up(self, ids, prehashed, lookup)
 
     # ---- host-side helpers (packers / equivalence tests) ---------------
 

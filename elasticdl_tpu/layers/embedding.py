@@ -137,7 +137,8 @@ def scatter_add_rows(shape, flat_ids, g, order=None):
     """
     n = flat_ids.shape[0]
     if not distinct_row_path(shape, g.dtype, n):
-        return jnp.zeros(shape, g.dtype).at[flat_ids].add(g, mode=_PIB)
+        with jax.named_scope("arena/scatter"):
+            return jnp.zeros(shape, g.dtype).at[flat_ids].add(g, mode=_PIB)
     chunk = min(CHUNK, n)
     with jax.named_scope("arena/combine"):
         sorted_ids, perm = order if order is not None else row_order(
@@ -183,7 +184,8 @@ def _lookup(table, flat_ids, order=None):
     PROMISE_IN_BOUNDS makes that explicit.  `order` is
     `row_order(flat_ids)` or None; only the backward reads it.
     """
-    return table.at[flat_ids].get(mode=_PIB)
+    with jax.named_scope("arena/lookup"):
+        return table.at[flat_ids].get(mode=_PIB)
 
 
 def _lookup_fwd(table, flat_ids, order):
@@ -211,12 +213,14 @@ def lookup_rows(module, table, flat_ids, lookup=_lookup):
     nothing reads the order and XLA drops the sort."""
     if not distinct_row_path(table.shape, table.dtype, flat_ids.shape[0]):
         return lookup(table, flat_ids)
-    order = row_order(flat_ids)
-    sow_step_metric(
-        module, "distinct_rows_ratio",
-        distinct_rows(order) / flat_ids.shape[0],
-    )
-    return lookup(table, flat_ids, order)
+    # the forward's sort is the lookup's cost (profiler.DEVICE_SCOPES)
+    with jax.named_scope("arena/lookup"):
+        order = row_order(flat_ids)
+        sow_step_metric(
+            module, "distinct_rows_ratio",
+            distinct_rows(order) / flat_ids.shape[0],
+        )
+        return lookup(table, flat_ids, order)
 
 
 def hash_ids(ids: jnp.ndarray, capacity: int, mix: bool = True) -> jnp.ndarray:

@@ -167,13 +167,14 @@ def _walk(tokens, w_gate_up, w_down, order, weights, group_sizes):
     slots = order.shape[0]
     top_k = slots // tokens.shape[0]
     chunk, total = _chunks(slots)
-    order = jnp.pad(order, (0, total * chunk - slots))
+    with jax.named_scope("dispatch"):
+        order = jnp.pad(order, (0, total * chunk - slots))
 
     def trip(c, out):
-        _, at, weight, sizes = _chunk_of(
-            c, chunk, top_k, order, weights, group_sizes
-        )
         with jax.named_scope("dispatch"):
+            _, at, weight, sizes = _chunk_of(
+                c, chunk, top_k, order, weights, group_sizes
+            )
             rows = tokens.at[at].get(mode=_PIB)
         with jax.named_scope("experts"):
             expert_out = grouped_matmul(
@@ -185,10 +186,12 @@ def _walk(tokens, w_gate_up, w_down, order, weights, group_sizes):
                 expert_out.astype(jnp.float32) * weight, mode=_PIB
             )
 
-    return lax.fori_loop(
-        0, _trips(group_sizes.sum(), chunk), trip,
-        jnp.zeros(tokens.shape, jnp.float32),
-    )
+    # the loop itself is `combine`'s: its carry is the sum
+    with jax.named_scope("combine"):
+        return lax.fori_loop(
+            0, _trips(group_sizes.sum(), chunk), trip,
+            jnp.zeros(tokens.shape, jnp.float32),
+        )
 
 
 def _walk_fwd(*args):
@@ -203,20 +206,22 @@ def _walk_bwd(args, g):
     # the padding's rows are dead; its slot 0 repeats, so the weights'
     # gradient may promise distinct slots only where nothing is padded
     padded = total * chunk - slots
-    order = jnp.pad(order, (0, padded))
+    with jax.named_scope("dispatch"):
+        order = jnp.pad(order, (0, padded))
     dtype = tokens.dtype
     # transposed once, outside the loop (inside it they are two copies of
     # the stacks a trip)
-    w_gate_up_t, w_down_t = (
-        jnp.swapaxes(w, 1, 2) for w in (w_gate_up, w_down)
-    )
+    with jax.named_scope("experts"):
+        w_gate_up_t, w_down_t = (
+            jnp.swapaxes(w, 1, 2) for w in (w_gate_up, w_down)
+        )
 
     def trip(c, carry):
         d_tokens, d_weights, saved = carry
-        slot, at, weight, sizes = _chunk_of(
-            c, chunk, top_k, order, weights, group_sizes
-        )
         with jax.named_scope("dispatch"):
+            slot, at, weight, sizes = _chunk_of(
+                c, chunk, top_k, order, weights, group_sizes
+            )
             rows = tokens.at[at].get(mode=_PIB)
             g_rows = g.at[at].get(mode=_PIB)
         with jax.named_scope("experts"):
@@ -247,17 +252,20 @@ def _walk_bwd(args, g):
         return d_tokens, d_weights, saved
 
     ffn = w_down.shape[1]
-    d_tokens, d_weights, (rows, d_gate_up, act, d_out) = lax.fori_loop(
-        0, _trips(group_sizes.sum(), chunk), trip,
-        (
-            jnp.zeros(tokens.shape, jnp.float32),
-            jnp.zeros(weights.shape, jnp.float32),
-            tuple(
-                jnp.zeros((total * chunk, width), dtype)
-                for width in (tokens.shape[1], 2 * ffn, ffn, tokens.shape[1])
+    # the loop itself is `combine`'s: its carries are the sums and the
+    # buffers it fills
+    with jax.named_scope("combine"):
+        d_tokens, d_weights, (rows, d_gate_up, act, d_out) = lax.fori_loop(
+            0, _trips(group_sizes.sum(), chunk), trip,
+            (
+                jnp.zeros(tokens.shape, jnp.float32),
+                jnp.zeros(weights.shape, jnp.float32),
+                tuple(
+                    jnp.zeros((total * chunk, width), dtype) for width in
+                    (tokens.shape[1], 2 * ffn, ffn, tokens.shape[1])
+                ),
             ),
-        ),
-    )
+        )
     with jax.named_scope("experts"):
         # rows past the last group are zero in all four buffers, so the
         # plain product needs none of `grouped_matmul`'s masks
@@ -267,9 +275,9 @@ def _walk_bwd(args, g):
         (d_w_down,) = jax.vjp(
             lambda w: lax.ragged_dot(act, w, group_sizes), w_down
         )[1](d_out)
-    return (
-        d_tokens.astype(dtype), d_w_gate_up, d_w_down, None, d_weights, None
-    )
+    with jax.named_scope("combine"):
+        d_tokens = d_tokens.astype(dtype)
+    return d_tokens, d_w_gate_up, d_w_down, None, d_weights, None
 
 
 routed_walk.defvjp(_walk_fwd, _walk_bwd)
@@ -416,7 +424,8 @@ class RoutedExperts(nn.Module):
     @nn.compact
     def __call__(self, x):
         *lead, hidden = x.shape
-        tokens = x.reshape(-1, hidden)
+        with jax.named_scope("dispatch"):
+            tokens = x.reshape(-1, hidden)
         n, k = tokens.shape[0], self.top_k
         first, count = self.held_experts or (0, self.num_experts)
 
@@ -466,9 +475,14 @@ class RoutedExperts(nn.Module):
             "expert_w_down", nn.initializers.lecun_normal(),
             (count, self.ffn_dim, hidden), jnp.float32,
         )
+        with jax.named_scope("dispatch"):
+            tokens = tokens.astype(self.dtype)
+        with jax.named_scope("experts"):
+            # the stacks' casts are the experts' cost
+            w_gate_up = w_gate_up.astype(self.dtype)
+            w_down = w_down.astype(self.dtype)
         out = routed_walk(
-            tokens.astype(self.dtype), w_gate_up.astype(self.dtype),
-            w_down.astype(self.dtype), order, weights.reshape(-1),
+            tokens, w_gate_up, w_down, order, weights.reshape(-1),
             group_sizes,
         )
         chunk, total = _chunks(n * k)
@@ -481,7 +495,8 @@ class RoutedExperts(nn.Module):
             self, "live_chunks_ratio", _trips(rows, chunk) / total
         )
         sow_step_metric(self, "dropped_tokens", held.sum() - rows)
-        return out.reshape(*lead, hidden)
+        with jax.named_scope("combine"):
+            return out.reshape(*lead, hidden)
 
 
 def moe_param_sharding(path, value) -> Optional[P]:

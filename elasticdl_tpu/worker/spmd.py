@@ -37,7 +37,6 @@ import jax
 import numpy as np
 
 from elasticdl_tpu.common import profiler as profiler_lib
-from elasticdl_tpu.common import programs as programs_lib
 from elasticdl_tpu.common import resilience
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.model_handler import ModelSpec, resolve_wire_format
@@ -221,14 +220,6 @@ class SPMDWorker:
         from elasticdl_tpu.common.summary import SummaryWriter
 
         self.step_rate = profiler_lib.SyncedStepRate()
-        # cost x rate join for the live MFU/bandwidth gauges — each rank
-        # binds its own process's registry (per-process /metrics)
-        programs_lib.default_program_registry().bind_step_rate(
-            "worker_train_step_many"
-            if self.steps_per_execution > 1 else "worker_train_step",
-            lambda: self.step_rate.steps_per_sec,
-            steps_per_execution=self.steps_per_execution,
-        )
         self._summary = SummaryWriter(
             tensorboard_dir if (tensorboard_dir and process_id == 0) else None
         )

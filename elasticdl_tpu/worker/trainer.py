@@ -344,17 +344,21 @@ class Trainer:
                 state.params, state.model_state,
                 batch["features"], batch["labels"],
             )
-            updates, opt_state = self.optimizer.update(
-                grads, state.opt_state, state.params
-            )
-            params = optax.apply_updates(state.params, updates)
-            # Quantized arenas: fold the carrier's delta back into the
-            # int8 planes with stochastic rounding and zero the carrier.
-            # Trace-time no-op when no "quantized" collection exists, so
-            # the fp32 path stays bit-identical (layers/arena.py).
-            params, new_model_state = fold_quantized_updates(
-                params, new_model_state, state.step
-            )
+            # one of profiler.DEVICE_SCOPES: the update's device time is
+            # told from the backward's by this name
+            with jax.named_scope("train/optimizer"):
+                updates, opt_state = self.optimizer.update(
+                    grads, state.opt_state, state.params
+                )
+                params = optax.apply_updates(state.params, updates)
+                # Quantized arenas: fold the carrier's delta back into
+                # the int8 planes with stochastic rounding and zero the
+                # carrier.  Trace-time no-op when no "quantized"
+                # collection exists, so the fp32 path stays bit-identical
+                # (layers/arena.py).
+                params, new_model_state = fold_quantized_updates(
+                    params, new_model_state, state.step
+                )
             return (
                 TrainState(
                     step=state.step + 1,

@@ -22,7 +22,6 @@ import numpy as np
 from elasticdl_tpu.common import events
 from elasticdl_tpu.common import metrics as metrics_lib
 from elasticdl_tpu.common import profiler as profiler_lib
-from elasticdl_tpu.common import programs as programs_lib
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.model_handler import ModelSpec, resolve_wire_format
 from elasticdl_tpu.proto import elasticdl_pb2 as pb
@@ -290,16 +289,6 @@ class Worker:
         from elasticdl_tpu.common.summary import SummaryWriter
 
         self.step_rate = profiler_lib.SyncedStepRate()
-        # Join the live step rate against the per-program cost model
-        # (docs/OBSERVABILITY.md "Program observatory"): the dominant
-        # train program — fused when steps_per_execution > 1 — feeds the
-        # worker_program_bytes_per_sec / worker_mfu_ratio gauges.
-        programs_lib.default_program_registry().bind_step_rate(
-            "worker_train_step_many"
-            if self.steps_per_execution > 1 else "worker_train_step",
-            lambda: self.step_rate.steps_per_sec,
-            steps_per_execution=self.steps_per_execution,
-        )
         self._summary = SummaryWriter(tensorboard_dir or None)
         # --profile_dir: capture ONE task's device trace (Perfetto/XPlane,
         # TensorBoard-readable) then stop — always-on tracing would drag

@@ -115,13 +115,17 @@ class MoEFFN(nn.Module):
                 renorm_eps=self.renorm_eps, name="routed",
             )(x)
             if not self.shared_experts:
-                return routed.astype(self.dtype)
+                with jax.named_scope("combine"):
+                    return routed.astype(self.dtype)
             with jax.named_scope("shared"):
                 shared = SwiGLU(
                     self.hidden, self.shared_experts * self.expert_width,
                     self.dtype, name="shared",
                 )(x)
-            return (routed + shared.astype(jnp.float32)).astype(self.dtype)
+            with jax.named_scope("combine"):
+                return (
+                    routed + shared.astype(jnp.float32)
+                ).astype(self.dtype)
 
 
 def remat_block(block_cls):

@@ -19,6 +19,7 @@ Record format (TFRecord payload): 13 float32 dense | 26 int32 sparse ids |
 from __future__ import annotations
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
@@ -184,10 +185,11 @@ class DeepFM(nn.Module):
             arena_dtype=self.arena_dtype,
         ), field_ids, prehashed)
 
-        return deepfm_tail(
-            emb, first, features["dense"], self.mlp_dims,
-            self.compute_dtype,
-        )
+        with jax.named_scope("deepfm/tower"):
+            return deepfm_tail(
+                emb, first, features["dense"], self.mlp_dims,
+                self.compute_dtype,
+            )
 
 
 def custom_model(

@@ -130,12 +130,19 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x):
         c = self.config
-        x = x + MLA(
+        # norms and residual sums are `glm/norm`: with the scopes of MLA
+        # and the feed-forward they tile the block
+        # (profiler.DEVICE_SCOPES)
+        with jax.named_scope("glm/norm"):
+            y = RMSNorm(c.eps, c.dtype, name="attn_norm")(x)
+        y = MLA(
             c.hidden, c.heads, c.q_lora_rank, c.kv_lora_rank,
             c.qk_nope_head_dim, c.qk_rope_head_dim, c.v_head_dim,
             c.rope_theta, c.eps, c.dtype, name="mla",
-        )(RMSNorm(c.eps, c.dtype, name="attn_norm")(x))
-        y = RMSNorm(c.eps, c.dtype, name="ffn_norm")(x)
+        )(y)
+        with jax.named_scope("glm/norm"):
+            x = x + y
+            y = RMSNorm(c.eps, c.dtype, name="ffn_norm")(x)
         if self.moe:
             y = MoEFFN(
                 c.hidden, c.num_experts, c.top_k, c.expert_width,
@@ -145,7 +152,8 @@ class Block(nn.Module):
         else:
             with jax.named_scope("glm/dense_ffn"):
                 y = SwiGLU(c.hidden, c.dense_width, c.dtype, name="mlp")(y)
-        return x + y
+        with jax.named_scope("glm/norm"):
+            return x + y
 
 
 @dataclasses.dataclass(frozen=True)
@@ -189,7 +197,8 @@ class GLMMoELite(nn.Module):
             c.vocab_size, c.hidden, hash_input=False, name="token_embedding"
         )
         block_cls = remat_block(Block) if c.remat else Block
-        x = embed(ids).astype(c.dtype)
+        with jax.named_scope("glm/embed"):
+            x = embed(ids).astype(c.dtype)
         for i in range(c.num_layers):
             x = block_cls(c, moe=i >= c.dense_layers, name=f"layer_{i}")(x)
         head = self.param(
@@ -200,7 +209,9 @@ class GLMMoELite(nn.Module):
         def nll(h, shift):
             return shifted_nll(h, head, ids, shift, c.dtype, "glm/head_ce")
 
-        main = nll(RMSNorm(c.eps, c.dtype, name="final_norm")(x), 1)
+        with jax.named_scope("glm/norm"):
+            final = RMSNorm(c.eps, c.dtype, name="final_norm")(x)
+        main = nll(final, 1)
         sow_step_metric(self, "main_loss", main.mean())
         if c.mtp_layers:
             with jax.named_scope("glm/mtp"):

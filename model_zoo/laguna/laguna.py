@@ -156,9 +156,10 @@ class GatedGroupedAttention(nn.Module):
             # a gate that closes silences its layer
             sow_step_metric(self, "gate_mean", gate.mean())
             out = out * gate[..., None]
-        return dense(self.hidden, "o", self.dtype)(
-            out.reshape(batch, length, heads * dim)
-        )
+        with jax.named_scope(scope):
+            return dense(self.hidden, "o", self.dtype)(
+                out.reshape(batch, length, heads * dim)
+            )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -197,12 +198,19 @@ class Block(nn.Module):
         c = self.config
         kind, heads, routed = self.layer
         windowed = kind == WINDOW
-        x = x + GatedGroupedAttention(
+        # norms and residual sums are `laguna/norm`: with the scopes of
+        # attention and the feed-forward they tile the block
+        # (profiler.DEVICE_SCOPES)
+        with jax.named_scope("laguna/norm"):
+            y = RMSNorm(c.eps, c.dtype, name="attn_norm")(x)
+        y = GatedGroupedAttention(
             c.hidden, heads, c.kv_heads, c.head_dim,
             c.window if windowed else None,
             c.window_rope if windowed else c.full_rope, c.dtype, name="attn",
-        )(RMSNorm(c.eps, c.dtype, name="attn_norm")(x))
-        y = RMSNorm(c.eps, c.dtype, name="ffn_norm")(x)
+        )(y)
+        with jax.named_scope("laguna/norm"):
+            x = x + y
+            y = RMSNorm(c.eps, c.dtype, name="ffn_norm")(x)
         if routed:
             y = MoEFFN(
                 c.hidden, c.num_experts, c.top_k, c.expert_width,
@@ -212,7 +220,8 @@ class Block(nn.Module):
         else:
             with jax.named_scope("laguna/dense_ffn"):
                 y = SwiGLU(c.hidden, c.dense_width, c.dtype, name="mlp")(y)
-        return x + y
+        with jax.named_scope("laguna/norm"):
+            return x + y
 
 
 class Laguna(nn.Module):
@@ -223,19 +232,20 @@ class Laguna(nn.Module):
         c = self.config
         ids = features["input_ids"].astype(jnp.int32)        # (B, L)
         block_cls = remat_block(Block) if c.remat else Block
-        x = DistributedEmbedding(
-            c.vocab_size, c.hidden, hash_input=False, name="token_embedding"
-        )(ids).astype(c.dtype)
+        with jax.named_scope("laguna/embed"):
+            x = DistributedEmbedding(
+                c.vocab_size, c.hidden, hash_input=False,
+                name="token_embedding",
+            )(ids).astype(c.dtype)
         for i, layer in enumerate(c.layers):
             x = block_cls(c, layer, name=f"layer_{i}")(x)
         head = self.param(
             "lm_head_kernel", nn.initializers.lecun_normal(),
             (c.hidden, c.vocab_size),
         )
-        return shifted_nll(
-            RMSNorm(c.eps, c.dtype, name="final_norm")(x), head, ids, 1,
-            c.dtype, "laguna/head_ce",
-        )
+        with jax.named_scope("laguna/norm"):
+            x = RMSNorm(c.eps, c.dtype, name="final_norm")(x)
+        return shifted_nll(x, head, ids, 1, c.dtype, "laguna/head_ce")
 
 
 def custom_model(

@@ -173,7 +173,11 @@ class Block(nn.Module):
     def __call__(self, x):
         c = self.config
         kind, routed = self.layer
-        y = RMSNorm(c.eps, c.dtype, name="op_norm")(x)
+        # norms and residual sums are `lfm2/norm`: with the scopes of the
+        # operator and the feed-forward they tile the block
+        # (profiler.DEVICE_SCOPES)
+        with jax.named_scope("lfm2/norm"):
+            y = RMSNorm(c.eps, c.dtype, name="op_norm")(x)
         if kind == CONV:
             y = GatedShortConv(c.hidden, c.conv_kernel, c.dtype,
                                name="conv")(y)
@@ -182,8 +186,9 @@ class Block(nn.Module):
                 c.hidden, c.heads, c.kv_heads, c.hidden // c.heads,
                 c.rope_theta, c.eps, c.dtype, name="attn",
             )(y)
-        x = x + y
-        y = RMSNorm(c.eps, c.dtype, name="ffn_norm")(x)
+        with jax.named_scope("lfm2/norm"):
+            x = x + y
+            y = RMSNorm(c.eps, c.dtype, name="ffn_norm")(x)
         if routed:
             y = MoEFFN(
                 c.hidden, c.num_experts, c.top_k, c.expert_width, 0,
@@ -193,7 +198,8 @@ class Block(nn.Module):
         else:
             with jax.named_scope("lfm2/dense_ffn"):
                 y = SwiGLU(c.hidden, c.dense_width, c.dtype, name="mlp")(y)
-        return x + y
+        with jax.named_scope("lfm2/norm"):
+            return x + y
 
 
 class Lfm2Moe(nn.Module):
@@ -207,15 +213,15 @@ class Lfm2Moe(nn.Module):
         embedding = DistributedEmbedding(
             c.vocab_size, c.hidden, hash_input=False, name="token_embedding"
         )
-        x = embedding(ids).astype(c.dtype)
+        with jax.named_scope("lfm2/embed"):
+            x = embedding(ids).astype(c.dtype)
         for i, layer in enumerate(c.layers):
             x = block_cls(c, layer, name=f"layer_{i}")(x)
         # the tied head: the table read again, one leaf with two gradients
         table = embedding.variables["params"]["embedding"]
-        return shifted_nll(
-            RMSNorm(c.eps, c.dtype, name="final_norm")(x), table.T, ids, 1,
-            c.dtype, "lfm2/head_ce",
-        )
+        with jax.named_scope("lfm2/norm"):
+            x = RMSNorm(c.eps, c.dtype, name="final_norm")(x)
+        return shifted_nll(x, table.T, ids, 1, c.dtype, "lfm2/head_ce")
 
 
 def custom_model(

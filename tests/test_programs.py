@@ -174,39 +174,6 @@ def test_forensics_is_clock_free():
     assert rec["compiles"] == 1
 
 
-def test_device_peaks_is_an_exact_table(monkeypatch):
-    """CPU: explicitly no peaks.  A known device_kind: its row.  An
-    accelerator the table has never seen: an error, not a neighbour's
-    row and not a silent None that drops MFU."""
-    from types import SimpleNamespace
-
-    assert programs.device_peaks() is None  # the suite runs on CPU
-
-    def fake(kind):
-        device = SimpleNamespace(platform="tpu", device_kind=kind)
-        monkeypatch.setattr(jax, "devices", lambda: [device])
-
-    fake("TPU v5 lite")
-    assert programs.device_peaks()["bf16_flops"] == 197e12
-    fake("TPU v5")
-    with pytest.raises(ValueError, match="no peaks row.*'TPU v5'"):
-        programs.device_peaks()
-
-
-def test_live_gauges_do_not_touch_a_backend_without_bound_programs(
-    monkeypatch,
-):
-    """The master scrapes the same gauges but runs no program: reading
-    them must not call jax.devices() (on a TPU host that would take the
-    chips its worker children need)."""
-    def boom():
-        raise AssertionError("live() initialised a backend")
-
-    monkeypatch.setattr(jax, "devices", boom)
-    reg = programs.ProgramRegistry(metrics=metrics_lib.MetricsRegistry())
-    assert reg.live()["mfu"] == 0.0
-
-
 def test_default_registry_is_a_process_singleton():
     assert (
         programs.default_program_registry()
@@ -321,6 +288,10 @@ def test_varz_json_carries_the_programs_summary():
     doc = json.loads(server.varz_json())
     assert "programs" in doc
     assert "ledger" in doc["programs"]
+    # cost over the roof is not a utilisation: no ratio rides here
+    assert not {"mfu", "hbm_utilization", "bytes_per_sec"} & set(
+        doc["programs"]
+    )
 
 
 def test_render_programs_table():
@@ -336,6 +307,7 @@ def test_render_programs_table():
     assert "1 programs, 1 compiles, 1 signatures, 0 storms" in out
     assert "worker_train_step" in out
     assert "float32[2]" in out
+    assert "mfu" not in out and "bytes/s" not in out
     assert "(no programs registered" in render_programs({})
 
 
@@ -344,13 +316,9 @@ def test_top_renders_the_programs_line():
 
     frame = render({"programs": {
         "programs": 2, "compiles_total": 5, "signatures_total": 3,
-        "storms_total": 1, "mfu": 0.25, "bytes_per_sec": 1e9,
-        "hbm_utilization": 0.1, "ledger": {},
+        "storms_total": 1, "ledger": {},
     }})
-    assert (
-        "programs: n=2 compiles=5 sigs=3 storms=1 mfu=0.250 "
-        "bw=1.00e+09B/s" in frame
-    )
+    assert "programs: n=2 compiles=5 sigs=3 storms=1\n" in frame + "\n"
     # an empty observatory stays off the frame
     assert "programs:" not in render({})
 

@@ -135,3 +135,58 @@ def test_short_conv_kernels_compile_for_the_chip(one_chip, monkeypatch):
     assert "short_conv_bwd" in backward and "tpu_custom_call" in backward
     # d(weight) leaves the kernel as one (8, d) partial a program
     assert "f32[4,32,8,2048]" in backward
+
+
+def test_the_scope_table_reads_a_text_compiled_for_the_chip(one_chip):
+    """The chip's compiled text differs from the CPU's where the parser
+    looks: tiled layouts with brackets of their own
+    (`{1,0:T(8,128)(2,1)S(1)}`) before the opcode, tuple-shaped loops,
+    and `lax.ragged_dot` turned into a kernel the compiler names itself
+    (`%ragged-dot-none.N`, its path dropped), which
+    `profiler.COMPILER_NAMED_SCOPES` puts back under `experts`, where
+    `layers/moe.py` calls it."""
+    from elasticdl_tpu.common import profiler, programs
+    from elasticdl_tpu.layers.moe import grouped_matmul
+
+    def step(x, w, sizes):
+        def loss(x, w):
+            with jax.named_scope("dispatch"):
+                rows = jnp.tanh(x)
+            with jax.named_scope("experts"):
+                return grouped_matmul(rows, w, sizes).astype(
+                    jnp.float32
+                ).sum()
+
+        with jax.named_scope("glm/moe"):
+            d_x, d_w = jax.grad(loss, argnums=(0, 1))(x, w)
+            # a trip count the device reads, as `routed_walk`'s
+            with jax.named_scope("combine"):
+                return d_w, jax.lax.fori_loop(
+                    0, sizes.sum() // 256,
+                    lambda i, acc: acc + d_x.astype(jnp.float32) * i,
+                    jnp.zeros(d_x.shape, jnp.float32),
+                )
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(step).lower(
+        shaped((1024, 256), jnp.bfloat16),
+        shaped((4, 256, 512), jnp.bfloat16), shaped((4,), jnp.int32),
+    ).compile().as_text()
+    assert ":T(8,128)" in text
+    table = programs.parse_scope_table(text)
+    assert table and all(row.opcode for row in table.values())
+    loops = [r for r in table.values() if r.opcode == "while"]
+    assert loops and all(r.container for r in loops)
+    assert {r.entry for r in loops} == {"combine"}
+    kernels = {
+        name: row for name, row in table.items()
+        if name.startswith(tuple(profiler.COMPILER_NAMED_SCOPES))
+    }
+    assert kernels, sorted(
+        n for n, r in table.items() if r.opcode == "custom-call"
+    )
+    assert {row.entry for row in kernels.values()} == {"experts"}
+    assert all(row.opcode == "custom-call" for row in kernels.values())
+    assert {"forward", "backward"} <= {row.phase for row in table.values()}
