@@ -521,33 +521,71 @@ def _band(window, q) -> Optional[int]:
 # ---- the streaming kernel: causal, head width a multiple of 128, or 64 ----
 #
 # Inputs stay (B, L, H*D) (the free view of the model's layout) and the
-# grid walks (batch, head, query tile, key tile): a program holds ONE
-# (tile, D) block of Q, K and V (D = 256 is two lane tiles, so a head is
+# forward's grid walks (batch, head, query tile, key tile): a program holds
+# ONE (tile, D) block of Q, K and V (D = 256 is two lane tiles, so a head is
 # a column block and no head loop is needed), K/V tiles stream through
 # VMEM with the running max, normaliser and accumulator in scratch, and
 # tiles above the diagonal neither compute nor move (their block index is
 # clamped to the last tile needed, which Pallas does not fetch again).
-# The backward is two kernels from the saved log-sum-exp, as the blocked
-# form above but tile by tile: dK/dV with the key tile resident and the
-# query tiles streaming, dQ with the query tile resident.
+#
+# The backward is ONE kernel from the saved log-sum-exp, as the blocked
+# form above but tile by tile.  Its grid is (batch, K/V head, the group's
+# query heads x query tiles, key tiles the query tile meets), the two inner
+# axes sequential: a program holds one (query tile, key tile) pair with
+# the QUERY tile resident and the key tiles streaming, rebuilds the tile's
+# logits, probabilities, dP and dS once, and feeds all three gradients
+# from them (five products and one elementwise pass a tile).  dQ gathers
+# in a (tile, D) float32 scratch and leaves at the query tile's last key
+# step.
+# dK and dV gather in float32 scratch over the WHOLE length of the K/V
+# head, a key tile's rows at `pl.ds(j * tile, tile)`, and leave once a
+# (batch, K/V head), after the group's last query tile: 2 x L x D x 4
+# bytes of scratch and two (L, D) output blocks, double-buffered (16 MiB
+# at (8192, 128) and (4096, 256) in bfloat16, 24 in float32 as
+# `stream_backward_vmem_bytes` reckons), which is why the call names
+# `_STREAM_VMEM_LIMIT` and why
+# `stream_shapes_ok` refuses a length whose scratch would not fit under
+# it.  A key tile's dK and dV receive their terms by (query head of the
+# group, then query tile) and a query tile's dQ by key tile.
+# THE NAME: the one backward kernel is `causal_attention_dkv` /
+# `window_attention_dkv` although it carries dQ as well, because the
+# benchmark's rules (`benchmarks/layer_metrics/*.json`) find the kernels
+# as `(causal|window)_attention_(fwd|dkv|dq)` and a PR that changes the
+# kernel may not edit them; the rename to `_bwd` waits for a `benchmark`
+# PR (`ROADMAP.md`, Reach, metrics).
 #
 # Grouped K/V: query head h takes the K/V column block h // group through
-# the index map; the dK/dV grid runs over the K/V heads and its inner axis
-# over (query head of the group, query tile), so a K/V head's gradient
-# sums over its group in scratch.  A window: the inner axis is as long as
+# the index map; the backward's grid runs over the K/V heads and its third
+# axis over (query head of the group, query tile), so a K/V head's gradient
+# sums over its group in scratch.  A window: the key axis is as long as
 # the band (`_band_steps` tiles), the tiles outside it are never visited,
 # and both of its edges are masked in the tile (`_mask_tile`).
 #
 # A head of HALF a lane tile (D = 64) is no column block of (B, L, H*D):
 # a block's last dimension is whole lane tiles or the whole array's.  Such
 # a call goes head-major, (B, H, L, D), whose last dimension IS the head:
-# the same kernels over the same grid hold (tile, 64) blocks, the index
+# the same kernels over the same grids hold (tile, 64) blocks, the index
 # maps name the head on its own axis, and the transposes in and out are
 # layout copies in XLA (eight streams a layer, forward and backward).
 
 _STREAM_TILE = 512
 _LANES = 128
 _HALF_HEAD = _LANES // 2
+# What the backward kernel may hold in VMEM (the v5e has 128 MiB; the
+# compiler's own default is 16), and the part of it left to the tiles'
+# blocks and a tile's temporaries: at the cells' shapes the kernel compiles
+# under 24 MiB with 16 MiB held for the whole length, and is refused 16.
+_STREAM_VMEM_LIMIT = 64 * 1024 * 1024
+_STREAM_TILE_ROOM = 16 * 1024 * 1024
+
+
+def stream_backward_vmem_bytes(length: int, dim: int) -> int:
+    """Bytes of VMEM the backward kernel holds for the WHOLE length of one
+    K/V head at the widest operands it takes (float32): dK and dV in
+    float32 scratch and their two output blocks, double-buffered, every
+    row padded to whole lane tiles."""
+    padded = -(-dim // _LANES) * _LANES
+    return 2 * length * padded * (4 + 2 * 4)
 
 
 def _stream_tiles(length: int):
@@ -562,12 +600,17 @@ def stream_shapes_ok(q_shape, k_shape, v_shape) -> bool:
     (B, L, H, D) shapes: k and v alike at q's batch, length and width, q's
     heads a multiple of theirs (grouped keys and values; the same count
     is a group of one), L whole 128-tiles, D whole lane tiles or half of
-    one (64, which goes head-major).  A window asks nothing more."""
+    one (64, which goes head-major), and L x D no more than the backward's
+    whole-length scratch may hold under `_STREAM_VMEM_LIMIT` (16,384
+    positions at a head of 128, 8,192 at 256).  A window asks nothing
+    more."""
     return (
         _grouped_shapes_ok(q_shape, k_shape, v_shape)
         and tuple(k_shape) == tuple(v_shape)
         and (q_shape[3] % _LANES == 0 or q_shape[3] == _HALF_HEAD)
         and _stream_tiles(q_shape[1]) is not None
+        and stream_backward_vmem_bytes(q_shape[1], q_shape[3])
+        <= _STREAM_VMEM_LIMIT - _STREAM_TILE_ROOM
     )
 
 
@@ -603,16 +646,6 @@ def _key_live(i, j, window):
     mask alone the steps past the diagonal are skipped, under a window
     those before the sequence's first tile."""
     return j <= i if window is None else j >= 0
-
-
-def _query_tile(j, x, window):
-    """The query tile inner step x of key tile j holds; the band's tiles
-    begin on the diagonal."""
-    return x if window is None else j + x
-
-
-def _query_live(i, j, num, window):
-    return i >= j if window is None else i < num
 
 
 def _dot(a, b, contract):
@@ -660,43 +693,23 @@ def _stream_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
         lse_ref[0, 0] = m_sc[:, :1] + jnp.log(l)
 
 
-def _stream_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_sc, dv_sc, *, scale: float,
-                       tile: int, num: int, steps: int, group: int,
-                       window):
-    j, x = pl.program_id(2), pl.program_id(3)
-    # the inner axis: the group's query heads in turn, `steps` query
-    # tiles each
-    i = _query_tile(j, x if group == 1 else x % steps, window)
+def _stream_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                       dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc, *,
+                       scale: float, tile: int, num: int, steps: int,
+                       group: int, window):
+    """The whole backward of one (query tile, key tile) pair: the logits,
+    probabilities, dP and dS are rebuilt once and feed dV, dK and dQ.  It
+    runs under the name `*_attention_dkv` (module comment above)."""
+    x, y = pl.program_id(2), pl.program_id(3)
+    # the outer axis: the group's query heads in turn, `num` query tiles
+    # each
+    i = x % num
+    j = _key_tile(i, y, steps, window)
 
-    @pl.when(x == 0)
+    @pl.when((x == 0) & (y == 0))
     def _():
         dk_sc[...] = jnp.zeros(dk_sc.shape, jnp.float32)
         dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
-
-    @pl.when(_query_live(i, j, num, window))
-    def _():
-        q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
-        s = _mask_tile(
-            _dot(q, k, ((1,), (1,))) * scale, i, j, tile, window
-        )
-        p = jnp.exp(s - lse_ref[0, 0])                  # (tile q, tile k)
-        dv_sc[...] += _dot(p.astype(g.dtype), g, ((0,), (0,)))
-        dp = _dot(g, v, ((1,), (1,)))
-        ds = (p * (dp - delta_ref[0, 0]) * scale).astype(q.dtype)
-        dk_sc[...] += _dot(ds, q, ((0,), (0,)))
-
-    @pl.when(x == group * steps - 1)
-    def _():
-        dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
-        dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
-
-
-def _stream_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                      dq_ref, dq_sc, *, scale: float, tile: int,
-                      steps: int, window):
-    i, y = pl.program_id(2), pl.program_id(3)
-    j = _key_tile(i, y, steps, window)
 
     @pl.when(y == 0)
     def _():
@@ -708,18 +721,29 @@ def _stream_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         s = _mask_tile(
             _dot(q, k, ((1,), (1,))) * scale, i, j, tile, window
         )
-        p = jnp.exp(s - lse_ref[0, 0])
+        p = jnp.exp(s - lse_ref[0, 0])                  # (tile q, tile k)
+        keys = pl.ds(pl.multiple_of(j * tile, tile), tile)
+        dv_sc[keys, :] += _dot(p.astype(g.dtype), g, ((0,), (0,)))
         dp = _dot(g, v, ((1,), (1,)))
-        ds = (p * (dp - delta_ref[0, 0]) * scale).astype(k.dtype)
+        ds = (p * (dp - delta_ref[0, 0]) * scale).astype(q.dtype)
+        dk_sc[keys, :] += _dot(ds, q, ((0,), (0,)))
         dq_sc[...] += _dot(ds, k, ((1,), (0,)))
 
     @pl.when(y == steps - 1)
     def _():
         dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
 
+    @pl.when((x == group * num - 1) & (y == steps - 1))
+    def _():
+        dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
+
 
 def _stream_call(kernel, grid, in_specs, out_specs, out_shape, scratch,
-                 operands, name):
+                 operands, name, inner_axes: int = 1,
+                 vmem_limit: Optional[int] = None):
+    """One streaming kernel over a four-axis grid whose last `inner_axes`
+    carry scratch from step to step."""
     vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
     return pl.pallas_call(
         kernel,
@@ -733,8 +757,10 @@ def _stream_call(kernel, grid, in_specs, out_specs, out_shape, scratch,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
-                "parallel", "parallel", "parallel", "arbitrary"
+                ("parallel",) * (4 - inner_axes)
+                + ("arbitrary",) * inner_axes
             ),
+            vmem_limit_bytes=vmem_limit,
         ),
         interpret=use_interpret(),
         name=name,
@@ -746,20 +772,21 @@ def _same_head(h, x, y):
 
 
 def _stream_specs(tile: int, dim: int):
-    """Block specs by role, for a grid (batch, head, outer tile, inner
-    step): `which` gives the tile a block follows, clamped to the tiles
-    its kernel visits so that a skipped step moves nothing, and `head`
-    the column block (the grid's own head unless said).  A head that is
-    no whole lane tile is a block of the head-major (B, H, L, D) view,
-    its head axis squeezed: the kernels see (1, tile, D) either way."""
-    def tiles(which, head=_same_head):
+    """Block specs by role, for a grid (batch, head, outer step, inner
+    step): `which` gives the block of `rows` rows (a tile unless said) a
+    spec follows, clamped to the tiles its kernel visits so that a
+    skipped step moves nothing, and `head` the column block (the grid's
+    own head unless said).  A head that is no whole lane tile is a block
+    of the head-major (B, H, L, D) view, its head axis squeezed: the
+    kernels see (1, rows, D) either way."""
+    def tiles(which, head=_same_head, rows=tile):
         if dim % _LANES:
             return pl.BlockSpec(
-                (1, None, tile, dim),
+                (1, None, rows, dim),
                 lambda b, h, x, y: (b, head(h, x, y), which(x, y), 0),
             )
         return pl.BlockSpec(
-            (1, tile, dim),
+            (1, rows, dim),
             lambda b, h, x, y: (b, which(x, y), head(h, x, y)),
         )
 
@@ -872,52 +899,39 @@ def _stream_bwd(scale, window, residuals, g):
         (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
         .transpose(0, 2, 1)[..., None]
     )                                                   # (B, H, L, 1)
-    operands = [_stream_view(t) for t in (q, k, v, g)] + [lse, delta]
-    # grid (b, K/V head, j over keys, x over the group's heads times the
-    # query tiles j meets): queries before the diagonal stay on the
-    # diagonal's tile, queries past the last tile on the last
-    def in_group(j, x):
-        i = _query_tile(j, x if group == 1 else x % steps, window)
-        return jnp.maximum(i, j) if window is None else jnp.minimum(
-            i, num - 1
-        )
+    # grid (b, K/V head, x over the group's heads times the query tiles,
+    # y over the keys the query tile meets)
+    def row(x, y):
+        return x % num
 
-    q_head = _same_head if group == 1 else (
-        lambda h, j, x: h * group + x // steps
-    )
+    def q_head(h, x, y):
+        return h * group + x // num
 
-    def resident_key(j, x):
-        return j
+    streamed = _streamed_keys(steps, window)
 
-    dk, dv = _stream_call(
+    def keys(x, y):
+        return streamed(row(x, y), y)
+
+    def whole(x, y):
+        return 0
+
+    dq, dk, dv = _stream_call(
         functools.partial(
-            _stream_dkv_kernel, scale=scale, tile=tile, num=num,
+            _stream_bwd_kernel, scale=scale, tile=tile, num=num,
             steps=steps, group=group, window=window,
         ),
-        (batch, kv_heads, num, group * steps),
-        [tiles(in_group, q_head), tiles(resident_key),
-         tiles(resident_key), tiles(in_group, q_head),
-         per_row(in_group, q_head), per_row(in_group, q_head)],
-        [tiles(resident_key), tiles(resident_key)],
-        [(flat_kv, k.dtype), (flat_kv, v.dtype)],
+        (batch, kv_heads, group * num, steps),
+        [tiles(row, q_head), tiles(keys), tiles(keys), tiles(row, q_head),
+         per_row(row, q_head), per_row(row, q_head)],
+        [tiles(row, q_head), tiles(whole, rows=length),
+         tiles(whole, rows=length)],
+        [(flat, q.dtype), (flat_kv, k.dtype), (flat_kv, v.dtype)],
         [pltpu.VMEM((tile, dim), jnp.float32),
-         pltpu.VMEM((tile, dim), jnp.float32)],
-        operands, _stream_names(window) + "_dkv",
-    )
-    keys, kv_head = _streamed_keys(steps, window), _kv_head(group)
-    (dq,) = _stream_call(
-        functools.partial(
-            _stream_dq_kernel, scale=scale, tile=tile, steps=steps,
-            window=window,
-        ),
-        (batch, heads, num, steps),
-        [tiles(_resident_row), tiles(keys, kv_head), tiles(keys, kv_head),
-         tiles(_resident_row), per_row(_resident_row),
-         per_row(_resident_row)],
-        [tiles(_resident_row)],
-        [(flat, q.dtype)],
-        [pltpu.VMEM((tile, dim), jnp.float32)],
-        operands, _stream_names(window) + "_dq",
+         pltpu.VMEM((length, dim), jnp.float32),
+         pltpu.VMEM((length, dim), jnp.float32)],
+        [_stream_view(t) for t in (q, k, v, g)] + [lse, delta],
+        _stream_names(window) + "_dkv", inner_axes=2,
+        vmem_limit=_STREAM_VMEM_LIMIT,
     )
     return (
         _stream_unview(dq, q.shape), _stream_unview(dk, k.shape),

@@ -47,14 +47,27 @@ def test_short_sequence_single_tile():
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
 
 
-@pytest.mark.parametrize("causal, shape", CASES)
+# the streaming backward's whole-length scratch: four tiles of 128 at a
+# head of 256, one K/V head under a group of two query heads
+GRADIENT_CASES = CASES + [
+    pytest.param(True, dict(batch=1, length=512, heads=2, dim=256,
+                            kv_heads=1), id="causal-width256-four-tiles"),
+]
+
+
+@pytest.mark.parametrize("causal, shape", GRADIENT_CASES)
 def test_gradients_match_reference(causal, shape):
-    q, k, v = _qkv(**(shape or dict(batch=1, length=128, heads=2, dim=16)))
+    shape = dict(shape or dict(batch=1, length=128, heads=2, dim=16))
+    kv_heads = shape.pop("kv_heads", shape.get("heads", 4))
+    q, k, v = _qkv(**shape)
+    k, v = k[:, :, :kv_heads], v[:, :, :kv_heads]
+    group = q.shape[2] // kv_heads
 
     def loss_flash(q, k, v):
         return (flash_attention(q, k, v, causal=causal) ** 2).sum()
 
     def loss_ref(q, k, v):
+        k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
         return (full_attention_reference(q, k, v, causal=causal) ** 2).sum()
 
     grads = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
@@ -197,19 +210,32 @@ def _grouped_qkv(group, length=384, dim=128, kv_heads=1, seed=5):
 # three tiles of 128 a sequence: a window under a tile, of one tile, over
 # a tile, and one that hides nothing
 BANDS = [
-    pytest.param(group, window, id=f"group{group}-window{window}")
+    pytest.param(group, window, {}, id=f"group{group}-window{window}")
     for group in (1, 6, 8) for window in (None, 100, 128, 200, 384)
+]
+# four tiles and more under a group: a key tile's dK and dV are summed over
+# the group's heads and over query tiles in the backward kernel's
+# whole-length scratch, under the causal mask alone and under a window
+# narrower than a tile (the band is two tiles, the first of them clipped);
+# two K/V heads, so the scratch is cleared between them
+LONG_BANDS = [
+    pytest.param(3, None, dict(length=512, kv_heads=2),
+                 id="group3-four-tiles"),
+    pytest.param(3, 100, dict(length=512, kv_heads=2),
+                 id="group3-four-tiles-window100"),
+    pytest.param(2, 300, dict(length=2048, kv_heads=2),
+                 id="group2-four-tiles-of-512-window300"),
 ]
 
 
-@pytest.mark.parametrize("group, window", BANDS)
-def test_grouped_window_forward(group, window):
+@pytest.mark.parametrize("group, window, shape", BANDS)
+def test_grouped_window_forward(group, window, shape):
     from elasticdl_tpu.ops.flash_attention import (
         causal_attention,
         stream_shapes_ok,
     )
 
-    q, k, v = _grouped_qkv(group)
+    q, k, v = _grouped_qkv(group, **shape)
     assert stream_shapes_ok(q.shape, k.shape, v.shape)
     with jax.default_matmul_precision("highest"):
         np.testing.assert_allclose(
@@ -218,11 +244,11 @@ def test_grouped_window_forward(group, window):
         )
 
 
-@pytest.mark.parametrize("group, window", BANDS)
-def test_grouped_window_gradients(group, window):
+@pytest.mark.parametrize("group, window, shape", BANDS + LONG_BANDS)
+def test_grouped_window_gradients(group, window, shape):
     from elasticdl_tpu.ops.flash_attention import causal_attention
 
-    q, k, v = _grouped_qkv(group)
+    q, k, v = _grouped_qkv(group, **shape)
 
     def grads(fn):
         return jax.grad(
@@ -285,17 +311,17 @@ def _kernel_names(q, k, v, window):
 def test_kernel_names_in_the_trace():
     """The metrics find the kernels by name: a decoder's shape of one
     head count and no window (width 256) keeps the names it had, a window
-    that hides nothing is no window, and a windowed call is named apart."""
-    plain = ["causal_attention_dkv", "causal_attention_dq",
-             "causal_attention_fwd"]
+    that hides nothing is no window, and a windowed call is named apart.
+    TWO kernels a call: `_dkv` is the whole backward and carries dQ too
+    (no `_dq`), under the name the metrics' rules match."""
+    plain = ["causal_attention_dkv", "causal_attention_fwd"]
     wide = _grouped_qkv(2, length=256, dim=256, kv_heads=2)[1:] * 2
     assert _kernel_names(*wide[:3], window=None) == plain
     q, k, v = _grouped_qkv(6, length=256)
     assert _kernel_names(q, k, v, window=None) == plain
     assert _kernel_names(q, k, v, window=256) == plain
     assert _kernel_names(q, k, v, window=200) == [
-        "window_attention_dkv", "window_attention_dq",
-        "window_attention_fwd",
+        "window_attention_dkv", "window_attention_fwd",
     ]
 
 
@@ -323,19 +349,26 @@ def test_grouped_admission_rule():
 # ---- a head of half a lane tile (64): the head-major streaming layout ----
 
 HALF_HEADS = [
-    pytest.param(group, window, id=f"group{group}-window{window}")
+    pytest.param(group, window, {}, id=f"group{group}-window{window}")
     for group in (1, 4) for window in (None, 200)
+]
+# four tiles under a group of four, the window narrower than a tile: the
+# whole-length scratch at a head of half a lane tile
+LONG_HALF_HEADS = [
+    pytest.param(4, None, dict(length=512), id="group4-four-tiles"),
+    pytest.param(4, 100, dict(length=512),
+                 id="group4-four-tiles-window100"),
 ]
 
 
-@pytest.mark.parametrize("group, window", HALF_HEADS)
-def test_half_lane_head_forward(group, window):
+@pytest.mark.parametrize("group, window, shape", HALF_HEADS)
+def test_half_lane_head_forward(group, window, shape):
     from elasticdl_tpu.ops.flash_attention import (
         causal_attention,
         stream_shapes_ok,
     )
 
-    q, k, v = _grouped_qkv(group, dim=64, kv_heads=2)
+    q, k, v = _grouped_qkv(group, dim=64, kv_heads=2, **shape)
     assert stream_shapes_ok(q.shape, k.shape, v.shape)
     with jax.default_matmul_precision("highest"):
         np.testing.assert_allclose(
@@ -344,11 +377,12 @@ def test_half_lane_head_forward(group, window):
         )
 
 
-@pytest.mark.parametrize("group, window", HALF_HEADS)
-def test_half_lane_head_gradients(group, window):
+@pytest.mark.parametrize("group, window, shape",
+                         HALF_HEADS + LONG_HALF_HEADS)
+def test_half_lane_head_gradients(group, window, shape):
     from elasticdl_tpu.ops.flash_attention import causal_attention
 
-    q, k, v = _grouped_qkv(group, dim=64, kv_heads=2)
+    q, k, v = _grouped_qkv(group, dim=64, kv_heads=2, **shape)
 
     def grads(fn):
         return jax.grad(
@@ -382,34 +416,41 @@ def test_half_lane_head_admission_and_names():
                                 (4, 8200, 8, 64))
     q, k, v = _grouped_qkv(4, length=256, dim=64, kv_heads=2)
     assert _kernel_names(q, k, v, window=None) == [
-        "causal_attention_dkv", "causal_attention_dq",
-        "causal_attention_fwd",
+        "causal_attention_dkv", "causal_attention_fwd",
     ]
 
 
 # sha256 of str(make_jaxpr(value_and_grad(causal_attention ...))) at the
-# cells' bfloat16 shapes: the GLM cell's (4, 4096, 20, 256) and the Laguna
-# cell's full and window layers.  No cell that was there can move through
-# `flash_attention.py` while these hold; a change that means to move one
-# states the new text.  RE-RECORDED ON PURPOSE by the change that names
-# the forward's output and log-sum-exp (`SAVED_NAMES`): two `name`
-# equations a call, nothing else (the commit before gave 6885b5dc...,
-# df5c1370..., 579349d7...).
+# cells' bfloat16 shapes: the GLM cell's (4, 4096, 20, 256), the Laguna
+# cell's full and window layers and the LFM2 cell's (4, 8192, 32 over 8,
+# 64).  No cell that was there can move through `flash_attention.py` while
+# these hold; a change that means to move one states the new text.
+# RE-RECORDED ON PURPOSE by the change that makes the streaming backward ONE
+# kernel (`_stream_bwd_kernel` under the name `*_attention_dkv`, dQ among
+# its outputs; no `*_attention_dq` call): the forward's equations are the
+# parent's, the backward's two `pallas_call`s became one (the commit before
+# gave e328d498..., 8ddd5a42..., f4461dfa... for the first three; the LFM2
+# cell's shape had no digest).
 CELL_JAXPRS = [
     pytest.param(
         (4, 4096, 20, 256), 20, None,
-        "e328d4982a0a52a9386987f63602213af3e136f7aadfa968393240c4d50792d7",
+        "1aa8b25f913108e1c2fba10a69c8411d337da604cb0bbd2abfa6b3da763a57b8",
         id="glm-mla",
     ),
     pytest.param(
         (2, 8192, 48, 128), 8, None,
-        "8ddd5a42a9bc5b5b88a5e59c9ea9163c6212b568013150f3b7ce3d8311ac3106",
+        "f13ce3df152249e7b17cd04c38bc4edeb5f5700f753f40e28571d43a39941044",
         id="laguna-full",
     ),
     pytest.param(
         (2, 8192, 64, 128), 8, 512,
-        "f4461dfacdedcfd222d4263256502b84681939cf7d9cc4eacfd54e48db24c52d",
+        "d3b59eb5dda7b242757306722be46b3b58ae3c3651abba01913969fd4a95a318",
         id="laguna-window",
+    ),
+    pytest.param(
+        (4, 8192, 32, 64), 8, None,
+        "30a0a61eed3125133f00025a9c46322185368cc3bdb72b95cddf75790f3a642e",
+        id="lfm2-gqa",
     ),
 ]
 
@@ -496,7 +537,7 @@ def test_remat_block_runs_the_forward_kernel_once(
         kernel: len(re.findall(rf"name=\w+_attention_{kernel}\b", text))
         for kernel in ("fwd", "dkv", "dq")
     }
-    assert calls == {"fwd": forwards, "dkv": 1, "dq": 1}
+    assert calls == {"fwd": forwards, "dkv": 1, "dq": 0}
 
 
 @pytest.mark.parametrize("remat, exps", [

@@ -44,19 +44,16 @@ SHAPES = [
     pytest.param(48, 8, 128, 2, 8192, None, id="laguna-full"),
     pytest.param(64, 8, 128, 2, 8192, 512, id="laguna-window"),
 ]
+# the LFM2 cell's call (a head of half a lane tile, head-major)
+LFM2_SHAPE = (32, 8, 64, 4, 8192, None)
 
 
-@pytest.mark.parametrize("heads, kv_heads, dim, batch, length, window", SHAPES)
-def test_streaming_kernels_compile_for_the_chip(
-    one_chip, monkeypatch, heads, kv_heads, dim, batch, length, window
-):
-    # the kernels ask the default backend (the CPU here) whether to run
-    # interpreted: steer them to Mosaic for the described chip
-    monkeypatch.setattr(fa, "use_interpret", lambda: False)
-
+def _backward_text(one_chip, heads, kv_heads, dim, batch, length, window,
+                   dtype=jnp.bfloat16):
+    """The compiled text of `causal_attention`'s gradient at a shape."""
     def shaped(h):
         return jax.ShapeDtypeStruct(
-            (batch, length, h, dim), jnp.bfloat16, sharding=one_chip
+            (batch, length, h, dim), dtype, sharding=one_chip
         )
 
     def grads(q, k, v):
@@ -67,14 +64,31 @@ def test_streaming_kernels_compile_for_the_chip(
             argnums=(0, 1, 2),
         )(q, k, v)
 
-    compiled = jax.jit(grads).lower(
+    return jax.jit(grads).lower(
         shaped(heads), shaped(kv_heads), shaped(kv_heads)
-    ).compile()
-    text = compiled.as_text()
-    kind = "causal" if window is None else "window"
-    for kernel in ("fwd", "dkv", "dq"):
+    ).compile().as_text()
+
+
+def _assert_two_kernels(text, kind):
+    """A forward kernel and ONE backward kernel, named `_dkv` for the
+    metrics' rules although it carries dQ; no `_dq` call."""
+    for kernel in ("fwd", "dkv"):
         assert f"{kind}_attention_{kernel}" in text
-    assert text.count("tpu_custom_call") >= 3
+    assert "_attention_dq" not in text
+    assert text.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("heads, kv_heads, dim, batch, length, window", SHAPES)
+def test_streaming_kernels_compile_for_the_chip(
+    one_chip, monkeypatch, heads, kv_heads, dim, batch, length, window
+):
+    # the kernels ask the default backend (the CPU here) whether to run
+    # interpreted: steer them to Mosaic for the described chip
+    monkeypatch.setattr(fa, "use_interpret", lambda: False)
+    text = _backward_text(
+        one_chip, heads, kv_heads, dim, batch, length, window
+    )
+    _assert_two_kernels(text, "causal" if window is None else "window")
     # K/V stay at their own head count: nothing repeated to the query's
     assert f"bf16[{batch},{length},{kv_heads * dim}]" in text
 
@@ -83,28 +97,64 @@ def test_half_lane_head_kernels_compile_for_the_chip(one_chip, monkeypatch):
     """The LFM2 cell's attention: 32 query heads of 64 over 8 K/V heads at
     (4, 8192), the head-major layout of a head of half a lane tile."""
     monkeypatch.setattr(fa, "use_interpret", lambda: False)
-
-    def shaped(h):
-        return jax.ShapeDtypeStruct(
-            (4, 8192, h, 64), jnp.bfloat16, sharding=one_chip
-        )
-
-    def grads(q, k, v):
-        return jax.grad(
-            lambda q, k, v: fa.causal_attention(
-                q, k, v
-            ).astype(jnp.float32).sum(),
-            argnums=(0, 1, 2),
-        )(q, k, v)
-
-    text = jax.jit(grads).lower(
-        shaped(32), shaped(8), shaped(8)
-    ).compile().as_text()
-    for kernel in ("fwd", "dkv", "dq"):
-        assert f"causal_attention_{kernel}" in text
-    assert text.count("tpu_custom_call") >= 3
+    text = _backward_text(one_chip, *LFM2_SHAPE)
+    _assert_two_kernels(text, "causal")
     # K/V go head-major at their own head count: nothing repeated to 32
     assert "bf16[4,8,8192,64]" in text and "bf16[4,32,8192,64]" in text
+
+
+MIB = 1024 * 1024
+
+
+@pytest.mark.parametrize(
+    "heads, kv_heads, dim, batch, length, window",
+    SHAPES + [pytest.param(*LFM2_SHAPE, id="lfm2-gqa")],
+)
+def test_backward_scratch_of_the_cells_under_the_vmem_limit(
+    one_chip, monkeypatch, heads, kv_heads, dim, batch, length, window
+):
+    """The backward kernel holds dK and dV of a K/V head's WHOLE length in
+    VMEM: 2 x L x D float32 of scratch and two (L, D) output blocks,
+    double-buffered.  The four cells' shapes, reckoned from (L, D): 8 MiB
+    of scratch each (LFM2's rows of 64 pad to a lane tile), 24 with the
+    blocks at float32, under the limit the call names less the tiles'
+    room.  The compiles above run under that limit: named 16 MiB, the
+    compiler's own default, the same kernel is refused."""
+    assert fa._STREAM_VMEM_LIMIT == 64 * MIB
+    assert fa._STREAM_TILE_ROOM == 16 * MIB
+    scratch = 2 * length * max(dim, 128) * 4
+    assert scratch == 8 * MIB
+    assert fa.stream_backward_vmem_bytes(length, dim) == 3 * scratch
+    assert 3 * scratch <= fa._STREAM_VMEM_LIMIT - fa._STREAM_TILE_ROOM
+    monkeypatch.setattr(fa, "use_interpret", lambda: False)
+    # admitted all the same (the rule would send it the blocked way)
+    monkeypatch.setattr(fa, "_STREAM_VMEM_LIMIT", 16 * MIB)
+    monkeypatch.setattr(fa, "stream_shapes_ok", lambda *shapes: True)
+    with pytest.raises(Exception, match="vmem"):
+        _backward_text(one_chip, heads, kv_heads, dim, batch, length, window)
+
+
+def test_a_longer_sequence_fails_here_and_not_on_the_chip(
+    one_chip, monkeypatch
+):
+    """The longest the streaming kernels admit, 16,384 positions at a head
+    of 128 in float32 (48 MiB for the whole length), compiles under the
+    limit; twice that is refused by `stream_shapes_ok` (96 MiB), so the
+    call takes the blocked form and no kernel is asked for what the chip
+    would refuse."""
+    monkeypatch.setattr(fa, "use_interpret", lambda: False)
+    edge, past = (1, 16384, 2, 128), (1, 32768, 2, 128)
+    assert fa.stream_backward_vmem_bytes(16384, 128) == 48 * MIB
+    assert fa.stream_shapes_ok(edge, (1, 16384, 1, 128), (1, 16384, 1, 128))
+    assert not fa.stream_shapes_ok(
+        past, (1, 32768, 1, 128), (1, 32768, 1, 128)
+    )
+    assert fa.stream_shapes_ok((1, 8192, 2, 256), (1, 8192, 2, 256),
+                               (1, 8192, 2, 256))
+    assert not fa.stream_shapes_ok((1, 16384, 2, 256), (1, 16384, 2, 256),
+                                   (1, 16384, 2, 256))
+    text = _backward_text(one_chip, 2, 1, 128, 1, 16384, None, jnp.float32)
+    _assert_two_kernels(text, "causal")
 
 
 def test_short_conv_kernels_compile_for_the_chip(one_chip, monkeypatch):
