@@ -68,6 +68,10 @@ DEVICE_SCOPES = (
     "laguna/gate", "laguna/dense_ffn", "laguna/moe", "laguna/head_ce",
     "lfm2/embed", "lfm2/norm", "lfm2/short_conv", "lfm2/attn",
     "lfm2/dense_ffn", "lfm2/moe", "lfm2/head_ce",
+    "kimi/embed", "kimi/norm", "kimi/kda/proj", "kimi/kda/conv",
+    "kimi/kda/gate", "kimi/kda/core", "kimi/kda/out", "kimi/mla/proj",
+    "kimi/mla/core", "kimi/mla/out", "kimi/dense_ffn", "kimi/moe",
+    "kimi/head_ce",
 )
 
 #: Kernels the TPU's compiler makes from ONE primitive and names after

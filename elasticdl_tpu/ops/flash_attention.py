@@ -579,13 +579,20 @@ _STREAM_VMEM_LIMIT = 64 * 1024 * 1024
 _STREAM_TILE_ROOM = 16 * 1024 * 1024
 
 
-def stream_backward_vmem_bytes(length: int, dim: int) -> int:
+def _lane_tiles(dim: int) -> int:
+    """`dim` rounded up to whole lane tiles."""
+    return -(-dim // _LANES) * _LANES
+
+
+def stream_backward_vmem_bytes(length: int, dim: int,
+                               v_dim: Optional[int] = None) -> int:
     """Bytes of VMEM the backward kernel holds for the WHOLE length of one
-    K/V head at the widest operands it takes (float32): dK and dV in
-    float32 scratch and their two output blocks, double-buffered, every
-    row padded to whole lane tiles."""
-    padded = -(-dim // _LANES) * _LANES
-    return 2 * length * padded * (4 + 2 * 4)
+    K/V head at the widest operands it takes (float32): dK (`dim` wide)
+    and dV (`v_dim` wide, `dim` unless said) in float32 scratch and their
+    two output blocks, double-buffered, every row padded to whole lane
+    tiles."""
+    columns = _lane_tiles(dim) + _lane_tiles(dim if v_dim is None else v_dim)
+    return length * columns * (4 + 2 * 4)
 
 
 def _stream_tiles(length: int):
@@ -597,21 +604,36 @@ def _stream_tiles(length: int):
 
 def stream_shapes_ok(q_shape, k_shape, v_shape) -> bool:
     """Whether the streaming kernel takes causal self-attention at these
-    (B, L, H, D) shapes: k and v alike at q's batch, length and width, q's
-    heads a multiple of theirs (grouped keys and values; the same count
-    is a group of one), L whole 128-tiles, D whole lane tiles or half of
-    one (64, which goes head-major), and L x D no more than the backward's
-    whole-length scratch may hold under `_STREAM_VMEM_LIMIT` (16,384
-    positions at a head of 128, 8,192 at 256).  A window asks nothing
-    more."""
+    (B, L, H, D) shapes: k at q's batch, length and width, v at k's head
+    count, q's heads a multiple of theirs (grouped keys and values; the
+    same count is a group of one), L whole 128-tiles, the VALUE width
+    whole lane tiles (the key width is then its own: one that is no whole
+    number of lane tiles is padded with zero columns, `_padded_keys`), or
+    both widths half a lane tile (64, which goes head-major), and L x
+    (D + Dv) no more than the backward's whole-length scratch may hold
+    under `_STREAM_VMEM_LIMIT` (16,384 positions at heads of 128, 8,192 at
+    256, or at 192 padded to 256 over 128).  A window asks nothing more."""
+    dim, v_dim = q_shape[3], v_shape[3]
     return (
         _grouped_shapes_ok(q_shape, k_shape, v_shape)
-        and tuple(k_shape) == tuple(v_shape)
-        and (q_shape[3] % _LANES == 0 or q_shape[3] == _HALF_HEAD)
+        and (v_dim % _LANES == 0 or dim == v_dim == _HALF_HEAD)
         and _stream_tiles(q_shape[1]) is not None
-        and stream_backward_vmem_bytes(q_shape[1], q_shape[3])
+        and stream_backward_vmem_bytes(q_shape[1], dim, v_dim)
         <= _STREAM_VMEM_LIMIT - _STREAM_TILE_ROOM
     )
+
+
+def _padded_keys(q, k):
+    """q and k with zero columns up to whole lane tiles where their width
+    is none and is not the head-major 64: q k^T is the same number, the
+    padded columns' gradient is dropped by the pad's own transpose, and v
+    keeps its width (padding it too would double the PV and dV
+    products)."""
+    dim = q.shape[3]
+    if dim % _LANES == 0 or dim == _HALF_HEAD:
+        return q, k
+    pad = ((0, 0), (0, 0), (0, 0), (0, _lane_tiles(dim) - dim))
+    return jnp.pad(q, pad), jnp.pad(k, pad)
 
 
 def _band_steps(num: int, tile: int, window: Optional[int]) -> int:
@@ -771,15 +793,15 @@ def _same_head(h, x, y):
     return h
 
 
-def _stream_specs(tile: int, dim: int):
+def _stream_specs(tile: int):
     """Block specs by role, for a grid (batch, head, outer step, inner
-    step): `which` gives the block of `rows` rows (a tile unless said) a
-    spec follows, clamped to the tiles its kernel visits so that a
-    skipped step moves nothing, and `head` the column block (the grid's
-    own head unless said).  A head that is no whole lane tile is a block
-    of the head-major (B, H, L, D) view, its head axis squeezed: the
+    step): `which` gives the block of `rows` rows (a tile unless said) of
+    `dim` columns a spec follows, clamped to the tiles its kernel visits
+    so that a skipped step moves nothing, and `head` the column block (the
+    grid's own head unless said).  A head that is no whole lane tile is a
+    block of the head-major (B, H, L, D) view, its head axis squeezed: the
     kernels see (1, rows, D) either way."""
-    def tiles(which, head=_same_head, rows=tile):
+    def tiles(dim, which, head=_same_head, rows=tile):
         if dim % _LANES:
             return pl.BlockSpec(
                 (1, None, rows, dim),
@@ -854,13 +876,14 @@ def _stream(q, k, v, scale, window):
 
 def _stream_fwd(q, k, v, scale, window):
     batch, length, heads, dim = q.shape
+    v_dim = v.shape[3]
     group = heads // k.shape[2]
     tile = _stream_tiles(length)
     num = length // tile
     steps = _band_steps(num, tile, window)
-    tiles, per_row = _stream_specs(tile, dim)
+    tiles, per_row = _stream_specs(tile)
     keys, kv_head = _streamed_keys(steps, window), _kv_head(group)
-    flat = _stream_shape(q.shape)
+    flat = _stream_shape((batch, length, heads, v_dim))
     # grid (b, h, i over queries, y over the keys i meets)
     out, lse = _stream_call(
         functools.partial(
@@ -868,32 +891,33 @@ def _stream_fwd(q, k, v, scale, window):
             window=window,
         ),
         (batch, heads, num, steps),
-        [tiles(_resident_row), tiles(keys, kv_head), tiles(keys, kv_head)],
-        [tiles(_resident_row), per_row(_resident_row)],
+        [tiles(dim, _resident_row), tiles(dim, keys, kv_head),
+         tiles(v_dim, keys, kv_head)],
+        [tiles(v_dim, _resident_row), per_row(_resident_row)],
         [(flat, q.dtype), ((batch, heads, length, 1), jnp.float32)],
         [pltpu.VMEM((tile, _LANES), jnp.float32),
          pltpu.VMEM((tile, _LANES), jnp.float32),
-         pltpu.VMEM((tile, dim), jnp.float32)],
+         pltpu.VMEM((tile, v_dim), jnp.float32)],
         [_stream_view(t) for t in (q, k, v)],
         _stream_names(window) + "_fwd",
     )
     # named as they leave the kernel (module comment of the blocked form):
     # with the two saved, a block's remat has no use for this call
     out, lse = _named(out, lse)
-    out = _stream_unview(out, q.shape)
+    out = _stream_unview(out, (batch, length, heads, v_dim))
     return out, (q, k, v, out, lse)
 
 
 def _stream_bwd(scale, window, residuals, g):
     q, k, v, out, lse = residuals
     batch, length, heads, dim = q.shape
-    kv_heads = k.shape[2]
+    kv_heads, v_dim = k.shape[2], v.shape[3]
     group = heads // kv_heads
     tile = _stream_tiles(length)
     num = length // tile
     steps = _band_steps(num, tile, window)
-    tiles, per_row = _stream_specs(tile, dim)
-    flat, flat_kv = _stream_shape(q.shape), _stream_shape(k.shape)
+    tiles, per_row = _stream_specs(tile)
+    flat = _stream_shape(q.shape)
     g = g.astype(q.dtype)
     delta = (
         (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
@@ -921,14 +945,16 @@ def _stream_bwd(scale, window, residuals, g):
             steps=steps, group=group, window=window,
         ),
         (batch, kv_heads, group * num, steps),
-        [tiles(row, q_head), tiles(keys), tiles(keys), tiles(row, q_head),
-         per_row(row, q_head), per_row(row, q_head)],
-        [tiles(row, q_head), tiles(whole, rows=length),
-         tiles(whole, rows=length)],
-        [(flat, q.dtype), (flat_kv, k.dtype), (flat_kv, v.dtype)],
+        [tiles(dim, row, q_head), tiles(dim, keys), tiles(v_dim, keys),
+         tiles(v_dim, row, q_head), per_row(row, q_head),
+         per_row(row, q_head)],
+        [tiles(dim, row, q_head), tiles(dim, whole, rows=length),
+         tiles(v_dim, whole, rows=length)],
+        [(flat, q.dtype), (_stream_shape(k.shape), k.dtype),
+         (_stream_shape(v.shape), v.dtype)],
         [pltpu.VMEM((tile, dim), jnp.float32),
          pltpu.VMEM((length, dim), jnp.float32),
-         pltpu.VMEM((length, dim), jnp.float32)],
+         pltpu.VMEM((length, v_dim), jnp.float32)],
         [_stream_view(t) for t in (q, k, v, g)] + [lse, delta],
         _stream_names(window) + "_dkv", inner_axes=2,
         vmem_limit=_STREAM_VMEM_LIMIT,
@@ -945,18 +971,20 @@ _stream.defvjp(_stream_fwd, _stream_bwd)
 def causal_attention(q, k, v, scale: Optional[float] = None,
                      window: Optional[int] = None):
     """Causal self-attention for a decoder's train step, q (B, L, H, D)
-    over k, v (B, L, Hkv, D), H a multiple of Hkv (grouped keys and
-    values: query head h reads K/V head h // (H / Hkv)) -> (B, L, H, Dv),
-    at any length and head width: the one entry a model calls.  With
-    `window`, query t sees the keys s with t - window < s <= t.  The
-    streaming Pallas kernels where the shapes tile (`stream_shapes_ok`:
-    heads of whole lane tiles, or of 64),
-    the blocked lax form elsewhere (the same mathematics, one row of
-    query tiles at a time)."""
+    over k (B, L, Hkv, D) and v (B, L, Hkv, Dv), H a multiple of Hkv
+    (grouped keys and values: query head h reads K/V head h // (H / Hkv))
+    -> (B, L, H, Dv), at any length and head widths: the one entry a
+    model calls.  With `window`, query t sees the keys s with t - window
+    < s <= t.  The streaming Pallas kernels where the shapes tile
+    (`stream_shapes_ok`: values of whole lane tiles under keys of any
+    width, or both of 64), the blocked lax form elsewhere (the same
+    mathematics, one row of query tiles at a time)."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     from elasticdl_tpu.parallel.mesh import in_export_mode
 
     if stream_shapes_ok(q.shape, k.shape, v.shape) and not in_export_mode():
-        return _stream(q, k, v, float(scale), _band(window, q))
+        return _stream(
+            *_padded_keys(q, k), v, float(scale), _band(window, q)
+        )
     return blocked_causal_attention(q, k, v, scale, window=window)

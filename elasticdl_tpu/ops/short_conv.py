@@ -14,6 +14,14 @@ from every other fusion, and `shifted_short_conv`, the same mathematics
 as K shifted multiply-adds in plain `jnp`, elsewhere (the arrangement of
 `ops/flash_attention.py: causal_attention`).
 
+`silu_short_conv` is the UNGATED form a linear-attention layer puts
+after its q, k and v projections, `y = silu(conv_K(u))` over all the
+columns of u at once (`silu_short_conv_fwd` / `silu_short_conv_bwd`, the
+shifted form `shifted_silu_conv` elsewhere): its columns are independent,
+so its grid also walks blocks of columns, and its backward rebuilds z in
+the tile and in the `_HALO` rows after it (whose taps reach back into the
+tile) for silu's slope.
+
 Kernel shape: the grid walks (batch, L / tile); a program holds `tile`
 whole rows of `bcu` (all 3d columns: one contiguous read) and the
 `_HALO` rows before them, from which the K - 1 rows left of the tile
@@ -194,7 +202,7 @@ def _call(kernel, grid, in_specs, out_specs, out_shape, operands, name):
             for shape, dtype in out_shape
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=("parallel",) * len(grid),
             vmem_limit_bytes=_VMEM_LIMIT,
         ),
         interpret=use_interpret(), name=name,
@@ -280,6 +288,171 @@ def _short_conv_bwd(residuals, g):
 
 
 _short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)
+
+
+# ---- the ungated form: silu(conv_K(u)) ------------------------------------
+
+# columns of a program's block
+_SILU_COLUMNS = 512
+
+
+def silu_conv_shapes_ok(u_shape, weight_shape) -> bool:
+    """Whether the kernels take (batch, L, d) under a (K, d) kernel."""
+    taps, width = weight_shape
+    return (
+        len(u_shape) == 3 and u_shape[2] == width
+        and width % _LANES == 0
+        and _tile(u_shape[1]) is not None
+        and 1 <= taps <= _PARTIAL_ROWS
+    )
+
+
+def shifted_silu_conv(u, weight):
+    """The plain form: K shifted multiply-adds, float32 inside."""
+    taps = weight.shape[0]
+    length = u.shape[1]
+    x = jnp.pad(u.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    z = sum(
+        weight[k].astype(jnp.float32) * x[:, k:k + length]
+        for k in range(taps)
+    )
+    return jax.nn.silu(z).astype(u.dtype)
+
+
+def _conv(x, before, w, taps: int):
+    """sum_k w_k x[t - (K - 1) + k] of a tile's rows."""
+    return sum(
+        w[k:k + 1] * _shift_down(x, before, taps - 1 - k)
+        for k in range(taps)
+    )
+
+
+def _silu_slope(z):
+    s = jax.nn.sigmoid(z)
+    return s * (1.0 + z * (1.0 - s))
+
+
+def _silu_fwd_kernel(u_ref, before_ref, w_ref, y_ref, *, taps: int):
+    x = u_ref[0].astype(jnp.float32)
+    before = jnp.where(
+        pl.program_id(1) == 0, 0.0, before_ref[0].astype(jnp.float32)
+    )
+    z = _conv(x, before, w_ref[...].astype(jnp.float32), taps)
+    y_ref[0] = (z * jax.nn.sigmoid(z)).astype(y_ref.dtype)
+
+
+def _silu_bwd_kernel(u_ref, before_ref, after_ref, g_ref, g_after_ref,
+                     w_ref, du_ref, dw_ref, *, taps: int, tiles: int):
+    x = u_ref[0].astype(jnp.float32)
+    tile = x.shape[0]
+    before = jnp.where(
+        pl.program_id(1) == 0, 0.0, before_ref[0].astype(jnp.float32)
+    )
+    w = w_ref[...].astype(jnp.float32)
+    dz = g_ref[0].astype(jnp.float32) * _silu_slope(
+        _conv(x, before, w, taps)
+    )
+    # the rows after the tile: their taps reach back into the tile
+    z_after = _conv(
+        after_ref[0].astype(jnp.float32), x[tile - _HALO:], w, taps
+    )
+    dz_after = jnp.where(
+        pl.program_id(1) == tiles - 1, 0.0,
+        g_after_ref[0].astype(jnp.float32) * _silu_slope(z_after),
+    )
+    du = 0.0
+    for k in range(taps):
+        s = taps - 1 - k
+        du = du + w[k:k + 1] * _shift_up(dz, dz_after, s)
+        dw_ref[0, 0, k:k + 1, :] = (dz * _shift_down(x, before, s)).sum(
+            axis=0, keepdims=True
+        )
+    if taps < _PARTIAL_ROWS:
+        dw_ref[0, 0, taps:, :] = jnp.zeros(
+            (_PARTIAL_ROWS - taps, x.shape[1]), jnp.float32
+        )
+    du_ref[0] = du.astype(du_ref.dtype)
+
+
+def _silu_specs(tile: int, tiles: int, columns: int):
+    """`_specs` over a grid (batch, tile, column block)."""
+    per_tile = tile // _HALO
+    own = pl.BlockSpec((1, tile, columns), lambda b, i, c: (b, i, c))
+    before = pl.BlockSpec(
+        (1, _HALO, columns),
+        lambda b, i, c: (b, jnp.maximum(i * per_tile - 1, 0), c),
+    )
+    after = pl.BlockSpec(
+        (1, _HALO, columns),
+        lambda b, i, c: (
+            b, jnp.minimum((i + 1) * per_tile, tiles * per_tile - 1), c
+        ),
+    )
+    return own, before, after
+
+
+def _silu_grid(u, weight):
+    batch, length, width = u.shape
+    tile = _tile(length)
+    columns = _SILU_COLUMNS if width % _SILU_COLUMNS == 0 else _LANES
+    tiles = length // tile
+    taps = pl.BlockSpec((weight.shape[0], columns), lambda b, i, c: (0, c))
+    return (
+        (batch, tiles, width // columns), tiles, columns, taps,
+        _silu_specs(tile, tiles, columns),
+    )
+
+
+@jax.custom_vjp
+def _silu_conv(u, weight):
+    return _silu_conv_fwd(u, weight)[0]
+
+
+def _silu_conv_fwd(u, weight):
+    grid, _, _, taps, (own, before, _) = _silu_grid(u, weight)
+    (y,) = _call(
+        functools.partial(_silu_fwd_kernel, taps=weight.shape[0]),
+        grid, [own, before, taps], [own], [(u.shape, u.dtype)],
+        [u, u, weight], "silu_short_conv_fwd",
+    )
+    return y, (u, weight)
+
+
+def _silu_conv_bwd(residuals, g):
+    u, weight = residuals
+    grid, tiles, columns, taps, (own, before, after) = _silu_grid(u, weight)
+    g = g.astype(u.dtype)
+    du, partials = _call(
+        functools.partial(
+            _silu_bwd_kernel, taps=weight.shape[0], tiles=tiles
+        ),
+        grid, [own, before, after, own, after, taps],
+        [own,
+         pl.BlockSpec(
+             (1, 1, _PARTIAL_ROWS, columns), lambda b, i, c: (b, i, 0, c)
+         )],
+        [(u.shape, u.dtype),
+         ((u.shape[0], tiles, _PARTIAL_ROWS, u.shape[2]), jnp.float32)],
+        [u, u, u, g, g, weight], "silu_short_conv_bwd",
+    )
+    return du, partials.sum(axis=(0, 1))[:weight.shape[0]].astype(
+        weight.dtype
+    )
+
+
+_silu_conv.defvjp(_silu_conv_fwd, _silu_conv_bwd)
+
+
+def silu_short_conv(u, weight):
+    """silu(causal_depthwise_conv_K(u)) of u (batch, L, d) under weight
+    (K, d) -> (batch, L, d), in `u`'s type with float32 inside: the
+    Pallas kernels where the shapes tile (`silu_conv_shapes_ok`), the
+    shifted `jnp` form elsewhere."""
+    from elasticdl_tpu.parallel.mesh import in_export_mode
+
+    if silu_conv_shapes_ok(u.shape, weight.shape) and not in_export_mode():
+        return _silu_conv(u, weight)
+    return shifted_silu_conv(u, weight)
 
 
 def gated_short_conv(bcu, weight):

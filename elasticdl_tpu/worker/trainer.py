@@ -91,11 +91,19 @@ def split_variables(variables):
     """`model.init`'s variables as ({"params": ...}, model_state): the
     optimizer sees only the former; what `init` sowed into the ephemeral
     collections is dropped, so a step never adds init's values to its
-    own."""
+    own, and STEP_METRICS (the LAST step's scalars) holds zeros, no step
+    having run.  Called INSIDE the init program: nothing it returns then
+    depends on the forward that `init` traced, so the compiler drops that
+    forward (a decoder's at 16,384 tokens, kernels and all: half of the
+    init program's compile time and of its executable)."""
     variables = dict(variables)
     params = {"params": variables.pop("params")}
     for collection in _EPHEMERAL:
         variables.pop(collection, None)
+    if moe_layers.STEP_METRICS in variables:
+        variables[moe_layers.STEP_METRICS] = jax.tree.map(
+            jnp.zeros_like, variables[moe_layers.STEP_METRICS]
+        )
     return params, variables
 
 
@@ -203,12 +211,12 @@ class Trainer:
         # ONE program, not an op-by-op walk of the model's forward: a
         # decoder's eager init at 16,384 tokens dispatched (and compiled)
         # every operation of its blocks on the way to its parameters.
-        params, model_state = split_variables(programs.registered_jit(
+        params, model_state = programs.registered_jit(
             "worker_init_state",
-            lambda rng, features: self.model.init(
+            lambda rng, features: split_variables(self.model.init(
                 rng, self._cast(features), **kwargs
-            ),
-        )(rng, jax.tree.map(np.asarray, sample_features)))
+            )),
+        )(rng, jax.tree.map(np.asarray, sample_features))
         state = TrainState(
             step=jnp.zeros((), jnp.int32),
             params=params,

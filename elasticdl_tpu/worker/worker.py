@@ -49,9 +49,23 @@ _tasks_counter = metrics_lib.default_registry().counter(
     "tasks processed, by outcome",
     labelnames=("result",),
 )
-# What a routed expert layer sows into STEP_METRICS (layers/moe.py), read
-# once a task with the loss: leaf name -> gauge by layer.
+# What a routed expert layer sows into STEP_METRICS (layers/moe.py), and
+# what a KDA layer sows there (model_zoo/kimi/kimi_linear.py), read once a
+# task with the loss: leaf name -> gauge by layer.
 _moe_gauges = {
+    "kda_decay_mean_ratio": metrics_lib.default_registry().gauge(
+        "worker_kda_decay_mean_ratio",
+        "mean of a KDA layer's per-channel decay exp(g) over tokens, heads "
+        "and channels, last step of the task (0 forgets everything, 1 "
+        "nothing: a decay that collapses is silent in the loss for long)",
+        labelnames=("layer",),
+    ),
+    "kda_beta_mean_ratio": metrics_lib.default_registry().gauge(
+        "worker_kda_beta_mean_ratio",
+        "mean of a KDA layer's write strength sigmoid(x Wb) over tokens "
+        "and heads, last step of the task",
+        labelnames=("layer",),
+    ),
     "expert_load_imbalance_ratio": metrics_lib.default_registry().gauge(
         "worker_moe_expert_load_imbalance_ratio",
         "largest router load over the mean load, over all the router's "

@@ -19,7 +19,11 @@ import optax
 
 from elasticdl_tpu.layers.embedding import embedding_param_sharding
 from elasticdl_tpu.layers.moe import RoutedExperts, moe_param_sharding
-from elasticdl_tpu.ops.flash_attention import SAVED_NAMES
+from elasticdl_tpu.ops import flash_attention, kda
+
+# What a rematerialised block keeps from its forward, by name: ONE policy
+# for every decoder of the zoo.
+SAVED_NAMES = flash_attention.SAVED_NAMES + kda.SAVED_NAMES
 
 # Tokens whose logits exist at once in the cross-entropy.
 CE_BLOCK = 2048
@@ -66,6 +70,12 @@ def rotary(x, theta: float):
     width = x.shape[-1]
     inv_freq = theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
     return rotary_turn(x, inv_freq)
+
+
+def tap_init(key, shape, dtype=jnp.float32):
+    """A depthwise kernel (K, d): uniform in +-1 / sqrt(K), K its fan-in."""
+    bound = shape[0] ** -0.5
+    return jax.random.uniform(key, shape, dtype, -bound, bound)
 
 
 def dense(features: int, name: str, dtype):
@@ -134,8 +144,10 @@ def remat_block(block_cls):
     float32 lse a layer: 134-268 MB in the cells): the remat rebuilds
     the projections, rotary and norms that make q, k and v, and the
     streaming forward kernel, the block's costliest operation, runs once
-    a step (`ops/flash_attention.py: SAVED_NAMES`).  A block with no
-    attention in it saves nothing."""
+    a step (`ops/flash_attention.py: SAVED_NAMES`).  What the chunked
+    scan of a linear-attention layer names joins the same policy
+    (`ops/kda.py: SAVED_NAMES`, which says what it keeps and why).  A
+    block with neither in it saves nothing."""
     return nn.remat(
         block_cls,
         policy=jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES),

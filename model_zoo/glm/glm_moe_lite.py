@@ -10,7 +10,10 @@ arXiv:2412.19437 section 2.2, which this family follows).
 
 The layer equations are written out in `benchmarks/reference/
 glm_moe_lite.py`, the plain float32 reference this model is held to leaf
-by leaf (tests/test_glm_moe_lite.py).
+by leaf (tests/test_glm_moe_lite.py).  The attention layer is
+`model_zoo/common/mla.py: MLA` (a low-rank query with its norm, rotary on
+the 64-column part, scopes `glm/mla/*`), shared with the zoo's other
+latent-attention decoder.
 
 What makes it a citizen of THIS system rather than a port:
 
@@ -46,7 +49,6 @@ import jax.numpy as jnp
 
 from elasticdl_tpu.layers.embedding import DistributedEmbedding
 from elasticdl_tpu.layers.moe import AUX_LOSS, sow_step_metric
-from elasticdl_tpu.ops.flash_attention import causal_attention
 from model_zoo.bert.bert_finetune import feed, feed_bulk  # noqa: F401
 from model_zoo.common.decoder import (  # noqa: F401
     MoEFFN,
@@ -58,67 +60,9 @@ from model_zoo.common.decoder import (  # noqa: F401
     optimizer,
     param_sharding,
     remat_block,
-    rotary,
     shifted_nll,
 )
-
-
-class MLA(nn.Module):
-    """Multi-head latent attention for TRAINING: keys and values are
-    materialised per head from the latent (no cache, no absorbed form);
-    the rotary part of the key is ONE head shared by all."""
-
-    hidden: int
-    heads: int
-    q_lora_rank: int
-    kv_lora_rank: int
-    qk_nope_head_dim: int
-    qk_rope_head_dim: int
-    v_head_dim: int
-    rope_theta: float
-    eps: float
-    dtype: jnp.dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        batch, length, _ = x.shape
-        heads, nope, rope = (
-            self.heads, self.qk_nope_head_dim, self.qk_rope_head_dim
-        )
-        with jax.named_scope("glm/mla/proj"):
-            cq = RMSNorm(self.eps, self.dtype, name="q_a_norm")(
-                dense(self.q_lora_rank, "q_a", self.dtype)(x)
-            )
-            q = dense(heads * (nope + rope), "q_b", self.dtype)(cq).reshape(
-                batch, length, heads, nope + rope
-            )
-            ckv, k_rope = jnp.split(
-                dense(self.kv_lora_rank + rope, "kv_a", self.dtype)(x),
-                [self.kv_lora_rank], axis=-1,
-            )
-            ckv = RMSNorm(self.eps, self.dtype, name="kv_a_norm")(ckv)
-            k_nope, v = jnp.split(
-                dense(heads * (nope + self.v_head_dim), "kv_b", self.dtype)(
-                    ckv
-                ).reshape(batch, length, heads, nope + self.v_head_dim),
-                [nope], axis=-1,
-            )
-            q_nope, q_rope = jnp.split(q, [nope], axis=-1)
-            q = jnp.concatenate(
-                [q_nope, rotary(q_rope, self.rope_theta)], axis=-1
-            )
-            k_rope = rotary(k_rope[:, :, None, :], self.rope_theta)
-            k = jnp.concatenate(
-                [k_nope, jnp.broadcast_to(
-                    k_rope, (batch, length, heads, rope)
-                )], axis=-1,
-            )
-        with jax.named_scope("glm/mla/core"):
-            out = causal_attention(q, k, v, scale=(nope + rope) ** -0.5)
-        with jax.named_scope("glm/mla/out"):
-            return dense(self.hidden, "o", self.dtype)(
-                out.reshape(batch, length, heads * self.v_head_dim)
-            )
+from model_zoo.common.mla import MLA
 
 
 class Block(nn.Module):

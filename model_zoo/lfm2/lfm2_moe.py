@@ -59,6 +59,7 @@ from model_zoo.common.decoder import (  # noqa: F401
     remat_block,
     rotary,
     shifted_nll,
+    tap_init,
 )
 
 CONV, FULL = "conv", "full_attention"
@@ -66,12 +67,6 @@ CONV, FULL = "conv", "full_attention"
 PUBLISHED_LAYER_TYPES = tuple(
     FULL if i % 4 == 2 else CONV for i in range(40)
 )
-
-
-def tap_init(key, shape, dtype=jnp.float32):
-    """A depthwise kernel (K, d): uniform in +-1 / sqrt(K), K its fan-in."""
-    bound = shape[0] ** -0.5
-    return jax.random.uniform(key, shape, dtype, -bound, bound)
 
 
 def rms(x):

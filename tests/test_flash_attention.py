@@ -567,3 +567,89 @@ def test_blocked_form_carries_the_same_names(remat, exps):
             assert forward.count(f"name[name={name}]") == 1
     text = _block_grad_jaxpr(remat, 4, 2, 16, None, length=64)
     assert "pallas_call" not in text and text.count("= exp ") == exps
+
+
+# ---- keys and values of different widths (latent attention) --------------
+#
+# (key width, value width, heads, K/V heads, window): 192 over 128 is the
+# no-position MLA's (128 + 64 key columns, padded to 256 inside the op);
+# 256 over 128 needs no padding; 192 grouped and under a window
+UNEQUAL = [
+    pytest.param(192, 128, 2, 2, None, id="192-over-128"),
+    pytest.param(256, 128, 2, 1, None, id="256-over-128-grouped"),
+    pytest.param(192, 128, 4, 2, 128, id="192-over-128-grouped-window"),
+]
+
+
+def _dense_unequal(q, k, v, window):
+    group = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    t = jnp.arange(q.shape[1])
+    seen = t[:, None] >= t[None, :]
+    if window is not None:
+        seen &= t[:, None] - t[None, :] < window
+    weights = jax.nn.softmax(jnp.where(seen, logits, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", weights, v)
+
+
+@pytest.mark.parametrize("dk, dv, heads, kv_heads, window", UNEQUAL)
+def test_streaming_kernels_take_a_key_width_of_their_own(dk, dv, heads,
+                                                         kv_heads, window):
+    """The streaming kernels in interpret mode against the dense masked
+    softmax: forward and the three gradients, each at its own width."""
+    from elasticdl_tpu.ops.flash_attention import (
+        causal_attention,
+        stream_shapes_ok,
+    )
+
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(keys[0], (1, 256, heads, dk)) * 0.3
+    k = jax.random.normal(keys[1], (1, 256, kv_heads, dk)) * 0.3
+    v = jax.random.normal(keys[2], (1, 256, kv_heads, dv))
+    weight = jax.random.normal(keys[3], (1, 256, heads, dv))
+    assert stream_shapes_ok(q.shape, k.shape, v.shape)
+    assert _kernel_names(q, k, v, window=window) == [
+        f"{'causal' if window is None else 'window'}_attention_dkv",
+        f"{'causal' if window is None else 'window'}_attention_fwd",
+    ]
+    np.testing.assert_allclose(
+        causal_attention(q, k, v, window=window),
+        _dense_unequal(q, k, v, window), rtol=2e-5, atol=2e-5,
+    )
+
+    def grads(fn):
+        return jax.grad(
+            lambda q, k, v: (fn(q, k, v) * weight).sum(), argnums=(0, 1, 2)
+        )(q, k, v)
+
+    got = grads(lambda q, k, v: causal_attention(q, k, v, window=window))
+    want = grads(lambda q, k, v: _dense_unequal(q, k, v, window))
+    for name, a, b in zip("qkv", got, want):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(
+            a, b, rtol=5e-4, atol=5e-4, err_msg=f"d{name}"
+        )
+
+
+def test_unequal_widths_admission_and_scratch():
+    """A value width of whole lane tiles under any key width; a padded key
+    counts at its padded width against the backward's whole-length
+    scratch (8,192 positions are the most a 256-padded key takes over a
+    value of 128 ... and of 256)."""
+    from elasticdl_tpu.ops.flash_attention import (
+        stream_backward_vmem_bytes,
+        stream_shapes_ok,
+    )
+
+    q, v = (2, 8192, 32, 192), (2, 8192, 32, 128)
+    assert stream_shapes_ok(q, q, v)                         # the cell's
+    assert stream_backward_vmem_bytes(8192, 192, 128) == 36 * 1024 * 1024
+    assert stream_backward_vmem_bytes(8192, 128) == (
+        stream_backward_vmem_bytes(8192, 128, 128)
+    )
+    assert not stream_shapes_ok((2, 16384, 32, 192), (2, 16384, 32, 192),
+                                (2, 16384, 32, 128))         # 72 MiB
+    assert not stream_shapes_ok(q, q, (2, 8192, 32, 192))    # v off the lanes
+    assert not stream_shapes_ok(q, q, (2, 8192, 32, 64))
+    assert not stream_shapes_ok(q, (2, 8192, 32, 128), v)    # k is not q's

@@ -553,3 +553,55 @@ def test_cli_job_of_two_tasks_with_a_falling_loss(tmp_path):
     assert registry.value(
         "worker_moe_live_chunks_ratio", layer="layer_1/moe/routed"
     ) == 1.0
+
+
+# ---- MLA lives in model_zoo/common/mla.py ---------------------------------
+
+# sha256 of the sorted "leaf path + shape" lines and of the gradient's jaxpr
+# (object addresses blanked) of a tiny GLM, recorded at the commit before
+# `MLA` moved out of `model_zoo/glm/glm_moe_lite.py`.
+GLM_TREE_DIGEST = (
+    "9b2a900c0e4119196b3602646c0cc728d1d053fb0a84cb68ad7b2930d9cee186"
+)
+GLM_JAXPR_DIGEST = (
+    "70b7e590b3dc114e9e550064dd4062dd72358eb5edecb83ba43530cd0e357432"
+)
+
+
+def test_glm_keeps_its_tree_and_jaxpr_through_the_shared_mla():
+    """`MLA` moved to `model_zoo/common/mla.py` with a switch for the
+    rotation, an optional low-rank query and a scope prefix: GLM's
+    parameter tree (so its checkpoint keys) and the jaxpr of its gradient
+    are the ones the commit before gave (digests recorded there; object
+    addresses in the text blanked)."""
+    import hashlib
+    import re
+
+    from model_zoo.common.mla import MLA
+
+    assert zoo.MLA is MLA
+    model = zoo.custom_model(
+        hidden=64, num_layers=2, dense_layers=1, heads=2, q_lora_rank=32,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=16,
+        v_head_dim=32, dense_width=128, expert_width=32, num_experts=4,
+        top_k=2, vocab_size=128, bf16=True, remat=True,
+    )
+    feats = {"input_ids": jnp.zeros((2, 128), jnp.int32)}
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0), feats)
+    params = shapes["params"]
+    paths = sorted(
+        name + str(leaf.shape) for name, leaf in trees.flat(params).items()
+    )
+    state = {k: v for k, v in shapes.items() if k != "params"}
+
+    def loss(p):
+        out, _ = model.apply({"params": p, **state}, feats, mutable=True)
+        return out.mean()
+
+    text = re.sub(
+        r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(jax.grad(loss))(params))
+    )
+    assert hashlib.sha256("\n".join(paths).encode()).hexdigest() == (
+        GLM_TREE_DIGEST
+    )
+    assert hashlib.sha256(text.encode()).hexdigest() == GLM_JAXPR_DIGEST

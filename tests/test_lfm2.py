@@ -464,6 +464,110 @@ def test_short_conv_admission_names_and_types():
     assert dbcu.dtype == jnp.bfloat16 and dweight.dtype == jnp.float32
 
 
+# ---- the ungated entry: silu(conv_K(u)) -----------------------------------
+
+# (batch, L, d, K): three tiles of 16, two of 256 at four taps over two
+# blocks of 512 columns, one tile alone, eight taps (the most)
+SILU_SHAPES = [(2, 48, 128, 4), (1, 512, 1024, 4), (1, 16, 128, 2),
+               (1, 64, 256, 8)]
+
+
+@pytest.mark.parametrize("batch, length, width, taps", SILU_SHAPES)
+def test_silu_conv_kernels_match_the_shifted_form(batch, length, width,
+                                                  taps):
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    u = jax.random.normal(keys[0], (batch, length, width))
+    weight = jax.random.normal(keys[1], (taps, width)) * 0.5
+    g = jax.random.normal(keys[2], u.shape)
+    assert short_conv.silu_conv_shapes_ok(u.shape, weight.shape)
+    np.testing.assert_allclose(
+        short_conv.silu_short_conv(u, weight),
+        short_conv.shifted_silu_conv(u, weight), rtol=1e-5, atol=1e-5,
+    )
+
+    def grads(fn):
+        return jax.grad(
+            lambda a, b: (fn(a, b) * g).sum(), argnums=(0, 1)
+        )(u, weight)
+
+    for name, got, want in zip(
+        ("du", "d(weight)"), grads(short_conv.silu_short_conv),
+        grads(short_conv.shifted_silu_conv),
+    ):
+        np.testing.assert_allclose(
+            got, want, rtol=1e-4, atol=1e-4, err_msg=name
+        )
+
+
+def test_silu_conv_halo_names_and_types():
+    """Zeros stand left of t = 0 and a later tile's first rows see the
+    tile before them; the kernels carry the names the benchmark's rule
+    for the short conv finds; bfloat16 in, bfloat16 out."""
+    u = jax.random.normal(jax.random.PRNGKey(8), (1, 48, 128))
+    w = jax.random.normal(jax.random.PRNGKey(9), (4, 128))
+    y = np.asarray(short_conv.silu_short_conv(u, w)[0])
+    x, taps = np.asarray(u[0]), np.asarray(w)
+
+    def silu(z):
+        return z / (1.0 + np.exp(-z))
+
+    np.testing.assert_allclose(y[0], silu(taps[3] * x[0]), rtol=1e-5,
+                               atol=1e-6)
+    np.testing.assert_allclose(
+        y[16], silu(taps[3] * x[16] + taps[2] * x[15] + taps[1] * x[14]
+                    + taps[0] * x[13]), rtol=1e-5, atol=1e-6,
+    )
+    ok = short_conv.silu_conv_shapes_ok
+    assert ok((2, 8192, 12288), (4, 12288))                 # the cell's
+    assert not ok((2, 8200, 12288), (4, 12288))
+    assert not ok((2, 8192, 12288), (4, 4096))
+    assert not ok((2, 8192, 96), (4, 96))
+    assert not ok((2, 8192, 12288), (9, 12288))
+    assert short_conv.silu_short_conv(u[:, :, :32], w[:, :32]).shape == (
+        1, 48, 32,
+    )                                                       # the jnp form
+    u16 = u.astype(jnp.bfloat16)
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda a, b: short_conv.silu_short_conv(a, b).astype(
+            jnp.float32
+        ).sum(), argnums=(0, 1),
+    ))(u16, w))
+    names = sorted(set(re.findall(r"\b\w*short_conv_(?:fwd|bwd)\b", jaxpr)))
+    assert names == ["silu_short_conv_bwd", "silu_short_conv_fwd"]
+    for name in names:
+        assert re.match(r"^(\w+_)?short_conv_(fwd|bwd)$", name)
+    du, dw = jax.grad(
+        lambda a, b: short_conv.silu_short_conv(a, b).astype(
+            jnp.float32
+        ).sum(), argnums=(0, 1),
+    )(u16, w)
+    assert du.dtype == jnp.bfloat16 and dw.dtype == jnp.float32
+    assert short_conv.silu_short_conv(u16, w).dtype == jnp.bfloat16
+
+
+# sha256 of str(make_jaxpr(grad(gated_short_conv ...))) at the LFM2 cell's
+# bfloat16 shape (4, 8192, 6144) under (3, 2048), recorded at the commit
+# before `silu_short_conv` joined the file: the gated entry did not move.
+GATED_JAXPR = (
+    "d08b105fa5d35519f7e31c45005bd126bfd74c8de2f5e4f5108cce5e4e633404"
+)
+
+
+def test_gated_short_conv_jaxpr_is_the_parents():
+    import hashlib
+
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda a, b: short_conv.gated_short_conv(a, b).astype(
+            jnp.float32
+        ).sum(), argnums=(0, 1),
+    ))(
+        jax.ShapeDtypeStruct((4, 8192, 6144), jnp.bfloat16),
+        jax.ShapeDtypeStruct((3, 2048), jnp.float32),
+    ))
+    assert "0x" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == GATED_JAXPR
+
+
 # ---- through the system ---------------------------------------------------
 
 

@@ -187,6 +187,60 @@ def test_short_conv_kernels_compile_for_the_chip(one_chip, monkeypatch):
     assert "f32[4,32,8,2048]" in backward
 
 
+def test_kimi_cell_kernels_compile_for_the_chip(one_chip, monkeypatch):
+    """The Kimi-Linear cell's three new calls at its shapes: the chunked
+    scan (2, 8192, 32 heads of 128), the SiLU conv over the fused q | k |
+    v (2, 8192, 12288) under 4 taps, and latent attention at keys of 192
+    over values of 128 (padded to 256 INSIDE the op: v keeps its width)."""
+    from elasticdl_tpu.ops import kda, short_conv
+
+    for module in (fa, kda, short_conv):
+        monkeypatch.setattr(module, "use_interpret", lambda: False)
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def text_of(fn, *args):
+        def grads(*args):
+            return jax.grad(
+                lambda *a: fn(*a).astype(jnp.float32).sum(),
+                argnums=tuple(range(len(args))),
+            )(*args)
+
+        return jax.jit(grads).lower(*args).compile().as_text()
+
+    head = (2, 8192, 32, 128)
+    assert kda.kda_shapes_ok(head, head, head)
+    scan = text_of(
+        kda.kda, shaped(head), shaped(head), shaped(head),
+        shaped(head, jnp.float32), shaped(head[:3], jnp.float32),
+    )
+    assert "kda_chunk_fwd" in scan and "kda_chunk_bwd" in scan
+    # the states the chunks start from, float32, transposed (dv, dk)
+    assert "f32[2,32,128,128,128]" in scan
+
+    u, taps = (2, 8192, 12288), (4, 12288)
+    assert short_conv.silu_conv_shapes_ok(u, taps)
+    conv = text_of(
+        short_conv.silu_short_conv, shaped(u), shaped(taps, jnp.float32)
+    )
+    assert "silu_short_conv_bwd" in conv and "tpu_custom_call" in conv
+    forward = jax.jit(short_conv.silu_short_conv).lower(
+        shaped(u), shaped(taps, jnp.float32)
+    ).compile().as_text()
+    assert "silu_short_conv_fwd" in forward
+
+    q, v = (2, 8192, 32, 192), (2, 8192, 32, 128)
+    assert fa.stream_shapes_ok(q, q, v)
+    attention = text_of(
+        fa.causal_attention, shaped(q), shaped(q), shaped(v)
+    )
+    _assert_two_kernels(attention, "causal")
+    # keys at 256 columns a head, values and the output at their own 128
+    assert "bf16[2,8192,8192]" in attention
+    assert "bf16[2,8192,4096]" in attention
+
+
 def test_the_scope_table_reads_a_text_compiled_for_the_chip(one_chip):
     """The chip's compiled text differs from the CPU's where the parser
     looks: tiled layouts with brackets of their own
