@@ -524,9 +524,10 @@ def _band(window, q) -> Optional[int]:
 # forward's grid walks (batch, head, query tile, key tile): a program holds
 # ONE (tile, D) block of Q, K and V (D = 256 is two lane tiles, so a head is
 # a column block and no head loop is needed), K/V tiles stream through
-# VMEM with the running max, normaliser and accumulator in scratch, and
-# tiles above the diagonal neither compute nor move (their block index is
-# clamped to the last tile needed, which Pallas does not fetch again).
+# VMEM with the running max, normaliser ((tile, 1) each) and accumulator
+# in scratch, and tiles above the diagonal neither compute nor move (their
+# block index is clamped to the last tile needed, which Pallas does not
+# fetch again).
 #
 # The backward is ONE kernel from the saved log-sum-exp, as the blocked
 # form above but tile by tile.  Its grid is (batch, K/V head, the group's
@@ -560,6 +561,26 @@ def _band(window, q) -> Optional[int]:
 # sums over its group in scratch.  A window: the key axis is as long as
 # the band (`_band_steps` tiles), the tiles outside it are never visited,
 # and both of its edges are masked in the tile (`_mask_tile`).
+#
+# WHAT PACES A TILE (measured on the v5e, `PERF.md` section 6, PR 43) is
+# the cross-lane unit: the two row reductions of a (tile, tile) score
+# tile, the broadcasts of a (tile, 1) column along the lanes and the
+# transposed operands of the backward's products.  The vector unit has
+# slack under them.  So EVERY visited tile is masked with both compares
+# (`_mask_tile`) and EVERY score tile is multiplied by `scale`, although
+# only the diagonal's tile and the band's far tile hold an element to
+# hide and a power-of-two scale could ride on the (tile, D) query block:
+# taking both out of every tile moved no kernel by 1%, and a tile body
+# branched by the edges that cross it cost 0.4-0.6%.  And so the
+# forward's running max and normaliser are (tile, 1) scratch, read and
+# written as they are: broadcast to a lane tile at every key step, as
+# they were, they cost a sixth of the forward.  And so the ORDER of the
+# independent statements of a tile body is part of the kernel: the
+# forward's row sum stands after the P V product and the backward's three
+# gathering products stand last, the plain one first, so that the
+# cross-lane work runs under a product (the same arithmetic in another
+# order costs 7-11% more).  What is left of the forward is the row max and
+# the row sum, a fifth of it each (`ROADMAP.md`, Speed 2).
 #
 # A head of HALF a lane tile (D = 64) is no column block of (B, L, H*D):
 # a block's last dimension is whole lane tiles or the whole array's.  Such
@@ -694,25 +715,26 @@ def _stream_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
         s = _mask_tile(
             _dot(q, k, ((1,), (1,))) * scale, i, j, tile, window
         )                                               # (tile, tile)
-        m_prev = m_sc[:, :1]
+        m_prev = m_sc[...]                              # (tile, 1)
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         # a row the band hides from this whole tile reads exp(0) here;
         # the diagonal's tile comes last, holds a key of every row and
         # scales what such a row gathered by exp(-1e30 - m) = 0
         p = jnp.exp(s - m_new)
         correction = jnp.exp(m_prev - m_new)
-        l_new = l_sc[:, :1] * correction + p.sum(axis=-1, keepdims=True)
         acc_sc[...] = acc_sc[...] * correction + _dot(
             p.astype(v.dtype), v, ((1,), (0,))
         )
-        m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
-        l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
+        m_sc[...] = m_new
+        # the row sum AFTER the product it does not feed: it crosses the
+        # lanes while the MXU works (7% of this kernel, as above)
+        l_sc[...] = l_sc[...] * correction + p.sum(axis=-1, keepdims=True)
 
     @pl.when(y == steps - 1)
     def _():
-        l = l_sc[:, :1]
+        l = l_sc[...]
         o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_sc[:, :1] + jnp.log(l)
+        lse_ref[0, 0] = m_sc[...] + jnp.log(l)
 
 
 def _stream_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
@@ -744,12 +766,16 @@ def _stream_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
             _dot(q, k, ((1,), (1,))) * scale, i, j, tile, window
         )
         p = jnp.exp(s - lse_ref[0, 0])                  # (tile q, tile k)
-        keys = pl.ds(pl.multiple_of(j * tile, tile), tile)
-        dv_sc[keys, :] += _dot(p.astype(g.dtype), g, ((0,), (0,)))
         dp = _dot(g, v, ((1,), (1,)))
         ds = (p * (dp - delta_ref[0, 0]) * scale).astype(q.dtype)
-        dk_sc[keys, :] += _dot(ds, q, ((0,), (0,)))
+        # the three gathering products LAST, the plain one first: the
+        # compiler schedules near the order it is given, and the two
+        # transposed operands then cross the lanes under a product (8-11%
+        # of this kernel at heads of 64 and 128, `PERF.md` section 6, PR 43)
         dq_sc[...] += _dot(ds, k, ((1,), (0,)))
+        keys = pl.ds(pl.multiple_of(j * tile, tile), tile)
+        dk_sc[keys, :] += _dot(ds, q, ((0,), (0,)))
+        dv_sc[keys, :] += _dot(p.astype(g.dtype), g, ((0,), (0,)))
 
     @pl.when(y == steps - 1)
     def _():
@@ -895,8 +921,8 @@ def _stream_fwd(q, k, v, scale, window):
          tiles(v_dim, keys, kv_head)],
         [tiles(v_dim, _resident_row), per_row(_resident_row)],
         [(flat, q.dtype), ((batch, heads, length, 1), jnp.float32)],
-        [pltpu.VMEM((tile, _LANES), jnp.float32),
-         pltpu.VMEM((tile, _LANES), jnp.float32),
+        [pltpu.VMEM((tile, 1), jnp.float32),
+         pltpu.VMEM((tile, 1), jnp.float32),
          pltpu.VMEM((tile, v_dim), jnp.float32)],
         [_stream_view(t) for t in (q, k, v)],
         _stream_names(window) + "_fwd",

@@ -181,13 +181,14 @@ def test_blocked_causal_attention_any_width(dims):
 # ---- grouped keys and values, and a window, through `causal_attention` ----
 
 
-def _dense_band(q, k, v, window):
+def _dense_band(q, k, v, window, scale=None):
     """Causal softmax attention as one dense masked product: q's head h
     over K/V head h // group, query t over keys s with t - window < s <= t
     (no window: every s <= t)."""
     group = q.shape[2] // k.shape[2]
     k, v = (jnp.repeat(t, group, axis=2) for t in (k, v))
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    scale = q.shape[-1] ** -0.5 if scale is None else scale
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     t = jnp.arange(q.shape[1])
     seen = t[:, None] >= t[None, :]
     if window is not None:
@@ -422,58 +423,70 @@ def test_half_lane_head_admission_and_names():
 
 # sha256 of str(make_jaxpr(value_and_grad(causal_attention ...))) at the
 # cells' bfloat16 shapes: the GLM cell's (4, 4096, 20, 256), the Laguna
-# cell's full and window layers and the LFM2 cell's (4, 8192, 32 over 8,
-# 64).  No cell that was there can move through `flash_attention.py` while
-# these hold; a change that means to move one states the new text.
-# RE-RECORDED ON PURPOSE by the change that makes the streaming backward ONE
-# kernel (`_stream_bwd_kernel` under the name `*_attention_dkv`, dQ among
-# its outputs; no `*_attention_dq` call): the forward's equations are the
-# parent's, the backward's two `pallas_call`s became one (the commit before
-# gave e328d498..., 8ddd5a42..., f4461dfa... for the first three; the LFM2
-# cell's shape had no digest).
+# cell's full and window layers, the LFM2 cell's (4, 8192, 32 over 8, 64)
+# and the Kimi-Linear cell's keys of 192 over values of 128.  No cell that
+# was there can move through `flash_attention.py` while these hold; a
+# change that means to move one states the new text.
+# RE-RECORDED ON PURPOSE by the change that holds the forward's running
+# max and normaliser (tile, 1)-shaped, written as they are and not
+# broadcast to 128 lanes at every key step (two scratch shapes and the
+# reads and writes of them in the forward's body), and that puts the
+# backward's three gathering products last, dQ's first: the same
+# equations in another order (the commit before gave 1aa8b25f...,
+# f13ce3df..., d3b59eb5..., 30a0a61e... for the first four and
+# b8711448... at the Kimi cell's shape, which had no digest here).
 CELL_JAXPRS = [
     pytest.param(
-        (4, 4096, 20, 256), 20, None,
-        "1aa8b25f913108e1c2fba10a69c8411d337da604cb0bbd2abfa6b3da763a57b8",
+        (4, 4096, 20, 256), 20, 256, None,
+        "c5ef0d80548c4f837f4c2435c96988a054ae2f6156fab786ca1f029a161e1b69",
         id="glm-mla",
     ),
     pytest.param(
-        (2, 8192, 48, 128), 8, None,
-        "f13ce3df152249e7b17cd04c38bc4edeb5f5700f753f40e28571d43a39941044",
+        (2, 8192, 48, 128), 8, 128, None,
+        "653ef899f51c2bc04e5bb736c46a2da7909aea00852684029eb1ab9710515609",
         id="laguna-full",
     ),
     pytest.param(
-        (2, 8192, 64, 128), 8, 512,
-        "d3b59eb5dda7b242757306722be46b3b58ae3c3651abba01913969fd4a95a318",
+        (2, 8192, 64, 128), 8, 128, 512,
+        "8b2c32b9dc6902070a79c8c80c74313649c93e2eb03daac1b3ea458282268ad0",
         id="laguna-window",
     ),
     pytest.param(
-        (4, 8192, 32, 64), 8, None,
-        "30a0a61eed3125133f00025a9c46322185368cc3bdb72b95cddf75790f3a642e",
+        (4, 8192, 32, 64), 8, 64, None,
+        "34fc62060da6b41712dadb0cd6e07b90dafdc6db214562b0b6700e1b222195b6",
         id="lfm2-gqa",
+    ),
+    pytest.param(
+        (2, 8192, 32, 192), 32, 128, None,
+        "16f9c3e0d3a1220675bcc131a39bbb52da8d8ae3c9c11aa0e4ba7cb00d108454",
+        id="kimi-mla",
     ),
 ]
 
 
-@pytest.mark.parametrize("q_shape, kv_heads, window, digest", CELL_JAXPRS)
-def test_cells_attention_jaxpr_is_the_parents(q_shape, kv_heads, window,
-                                              digest):
+def _cell_jaxpr_digest(q_shape, kv_heads, v_dim, window):
     import hashlib
 
     from elasticdl_tpu.ops.flash_attention import causal_attention
 
-    def shaped(heads):
-        return jax.ShapeDtypeStruct(
-            (*q_shape[:2], heads, q_shape[3]), jnp.bfloat16
-        )
+    def shaped(heads, dim):
+        return jax.ShapeDtypeStruct((*q_shape[:2], heads, dim), jnp.bfloat16)
 
     text = str(jax.make_jaxpr(jax.value_and_grad(
         lambda q, k, v: causal_attention(
             q, k, v, window=window
         ).astype(jnp.float32).sum(),
         argnums=(0, 1, 2),
-    ))(shaped(q_shape[2]), shaped(kv_heads), shaped(kv_heads)))
-    assert hashlib.sha256(text.encode()).hexdigest() == digest
+    ))(shaped(q_shape[2], q_shape[3]), shaped(kv_heads, q_shape[3]),
+       shaped(kv_heads, v_dim)))
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("q_shape, kv_heads, v_dim, window, digest",
+                         CELL_JAXPRS)
+def test_cells_attention_jaxpr_is_the_parents(q_shape, kv_heads, v_dim,
+                                              window, digest):
+    assert _cell_jaxpr_digest(q_shape, kv_heads, v_dim, window) == digest
 
 
 # ---- the forward's results saved across a block's remat -------------------
@@ -653,3 +666,278 @@ def test_unequal_widths_admission_and_scratch():
     assert not stream_shapes_ok(q, q, (2, 8192, 32, 192))    # v off the lanes
     assert not stream_shapes_ok(q, q, (2, 8192, 32, 64))
     assert not stream_shapes_ok(q, (2, 8192, 32, 128), v)    # k is not q's
+
+
+# ---- the tile body, bit for bit ------------------------------------------
+#
+# The reference is the tile body the kernels had before PR 43, as kernels of
+# this file run through the module's own calls: every tile masked with both
+# compares, every score tile scaled, the running max and normaliser read
+# from lane 0 of their scratch and written back broadcast over all of it at
+# every key step.  What a change moves out of the (tile, tile) body or
+# stops broadcasting may not change a bit of the forward or of the three
+# gradients.
+
+
+def _every_edge_mask(s, i, j, tile, window):
+    q_pos = i * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_pos = j * tile + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    seen = q_pos >= k_pos
+    if window is not None:
+        seen &= q_pos - k_pos < window
+    return jnp.where(seen, s, -1e30)
+
+
+def _every_tile_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc,
+                           acc_sc, *, scale, tile, steps, window, **_):
+    from jax.experimental import pallas as pl
+
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    i, y = pl.program_id(2), pl.program_id(3)
+    j = fa._key_tile(i, y, steps, window)
+
+    @pl.when(y == 0)
+    def _():
+        m_sc[...] = jnp.full(m_sc.shape, -1e30, jnp.float32)
+        l_sc[...] = jnp.zeros(l_sc.shape, jnp.float32)
+        acc_sc[...] = jnp.zeros(acc_sc.shape, jnp.float32)
+
+    @pl.when(fa._key_live(i, j, window))
+    def _():
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]
+        s = _every_edge_mask(
+            fa._dot(q, k, ((1,), (1,))) * scale, i, j, tile, window
+        )
+        m_prev = m_sc[:, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        correction = jnp.exp(m_prev - m_new)
+        l_new = l_sc[:, :1] * correction + p.sum(axis=-1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * correction + fa._dot(
+            p.astype(v.dtype), v, ((1,), (0,))
+        )
+        m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
+        l_sc[...] = jnp.broadcast_to(l_new, l_sc.shape)
+
+    @pl.when(y == steps - 1)
+    def _():
+        l = l_sc[:, :1]
+        o_ref[0] = (acc_sc[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_sc[:, :1] + jnp.log(l)
+
+
+def _every_tile_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                           dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc, *,
+                           scale, tile, num, steps, group, window, **_):
+    from jax.experimental import pallas as pl
+
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    x, y = pl.program_id(2), pl.program_id(3)
+    i = x % num
+    j = fa._key_tile(i, y, steps, window)
+
+    @pl.when((x == 0) & (y == 0))
+    def _():
+        dk_sc[...] = jnp.zeros(dk_sc.shape, jnp.float32)
+        dv_sc[...] = jnp.zeros(dv_sc.shape, jnp.float32)
+
+    @pl.when(y == 0)
+    def _():
+        dq_sc[...] = jnp.zeros(dq_sc.shape, jnp.float32)
+
+    @pl.when(fa._key_live(i, j, window))
+    def _():
+        q, k, v, g = q_ref[0], k_ref[0], v_ref[0], g_ref[0]
+        s = _every_edge_mask(
+            fa._dot(q, k, ((1,), (1,))) * scale, i, j, tile, window
+        )
+        p = jnp.exp(s - lse_ref[0, 0])
+        keys = pl.ds(pl.multiple_of(j * tile, tile), tile)
+        dv_sc[keys, :] += fa._dot(p.astype(g.dtype), g, ((0,), (0,)))
+        dp = fa._dot(g, v, ((1,), (1,)))
+        ds = (p * (dp - delta_ref[0, 0]) * scale).astype(q.dtype)
+        dk_sc[keys, :] += fa._dot(ds, q, ((0,), (0,)))
+        dq_sc[...] += fa._dot(ds, k, ((1,), (0,)))
+
+    @pl.when(y == steps - 1)
+    def _():
+        dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
+
+    @pl.when((x == group * num - 1) & (y == steps - 1))
+    def _():
+        dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
+
+
+def _tile_case(length, window, heads, kv_heads, dk, dv, scale,
+               dtype=jnp.bfloat16):
+    name = f"L{length}-w{window}-h{heads}over{kv_heads}-d{dk}over{dv}"
+    return pytest.param(
+        length, window, heads, kv_heads, dk, dv, scale, dtype,
+        id=name + (f"-scale{scale}" if scale else "")
+        + ("" if dtype == jnp.bfloat16 else f"-{jnp.dtype(dtype).name}"),
+    )
+
+
+# tiles of 512: tiles wholly under the diagonal and the diagonal's own;
+# windows with both edges in the diagonal tile (100, 300), an edge on the
+# tile grid (512, 1024, 1536: whole tiles inside the band from 1024 on) and
+# off it (700); heads of 64 (head-major), 128, 256 and keys of 192 over
+# values of 128 (padded to 256 inside the op); scales that are powers of
+# two (0.125, 0.0625) and that are not, by the head width's default and
+# passed explicitly
+TILE_BODY_CASES = [
+    _tile_case(1024, None, 2, 1, 128, 128, None),
+    _tile_case(2048, None, 2, 1, 128, 128, None),
+    _tile_case(2048, None, 2, 1, 128, 128, 0.125),
+    _tile_case(2048, None, 2, 2, 128, 128, None, dtype=jnp.float32),
+    _tile_case(2048, 100, 2, 1, 128, 128, None),
+    _tile_case(2048, 300, 4, 2, 128, 128, None),
+    _tile_case(2048, 512, 2, 1, 128, 128, None),
+    _tile_case(2048, 700, 2, 1, 128, 128, None),
+    _tile_case(2048, 1024, 2, 1, 128, 128, None),
+    _tile_case(2048, 1536, 2, 1, 128, 128, 0.125),
+    _tile_case(2048, None, 4, 2, 64, 64, None),
+    _tile_case(2048, 700, 2, 1, 64, 64, None),
+    _tile_case(2048, 1536, 2, 1, 64, 64, 0.1),
+    _tile_case(1024, None, 2, 2, 256, 256, None),
+    _tile_case(2048, 300, 1, 1, 256, 256, None),
+    _tile_case(2048, None, 2, 2, 192, 128, None),
+    _tile_case(2048, 1024, 2, 1, 192, 128, 0.0625),
+]
+
+
+class _EagerRef:
+    """A kernel's Ref as a plain array read and written operation by
+    operation."""
+
+    def __init__(self, value):
+        self.value = value
+
+    shape = property(lambda self: self.value.shape)
+    dtype = property(lambda self: self.value.dtype)
+
+    @staticmethod
+    def _index(idx):
+        from jax.experimental import pallas as pl
+
+        idx = idx if isinstance(idx, tuple) else (idx,)
+        return tuple(
+            slice(t.start, t.start + t.size) if isinstance(t, pl.Slice)
+            else t
+            for t in idx
+        )
+
+    def __getitem__(self, idx):
+        return self.value[self._index(idx)]
+
+    def __setitem__(self, idx, new):
+        new = jnp.asarray(new, self.value.dtype)
+        self.value = self.value.at[self._index(idx)].set(new)
+
+
+def _eager_stream_call(monkeypatch):
+    """`_stream_call` as a Python loop over the grid with every operation
+    of the kernel body a computation of its own.  Pallas's interpret mode
+    compiles a body as ONE program, and XLA's CPU backend then fuses
+    `dot * scale` into what reads it, one rounding for two, differently
+    as the body around it differs: a last bit that is the CPU compiler's,
+    not the kernels' (on the chip the kernels agree with the parent's to
+    the bit at the cells' shapes: `PERF.md` section 6, PR 43).  Here
+    nothing is fused, so equal means the same arithmetic."""
+    from jax.experimental import pallas as pl
+
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    point = []
+    monkeypatch.setattr(pl, "program_id", lambda axis: point[axis])
+    monkeypatch.setattr(
+        pl, "when", lambda cond: lambda body: body() if bool(cond) else None
+    )
+    monkeypatch.setattr(pl, "multiple_of", lambda x, _: x)
+
+    def blocks(spec):
+        at = spec.index_map(*point)
+        return tuple(
+            slice(a, a + 1) if size is None else slice(a * size, (a + 1) * size)
+            for a, size in zip(at, spec.block_shape)
+        ), tuple(
+            0 if size is None else slice(None) for size in spec.block_shape
+        )
+
+    def call(kernel, grid, in_specs, out_specs, out_shape, scratch, operands,
+             name, inner_axes=1, vmem_limit=None):
+        outs = [jnp.zeros(shape, dtype) for shape, dtype in out_shape]
+        held = [_EagerRef(jnp.zeros(t.shape, t.dtype)) for t in scratch]
+        for at in np.ndindex(*grid):
+            point[:] = [int(a) for a in at]
+            refs = []
+            for spec, array in zip(in_specs + out_specs,
+                                   list(operands) + outs):
+                where, squeeze = blocks(spec)
+                refs.append(_EagerRef(array[where][squeeze]))
+            kernel(*refs, *held)
+            for n, (spec, ref) in enumerate(
+                zip(out_specs, refs[len(in_specs):])
+            ):
+                where, squeeze = blocks(spec)
+                outs[n] = outs[n].at[where].set(
+                    jnp.expand_dims(ref.value, [
+                        axis for axis, how in enumerate(squeeze) if how == 0
+                    ])
+                )
+        return outs
+
+    monkeypatch.setattr(fa, "_stream_call", call)
+
+
+@pytest.mark.parametrize(
+    "length, window, heads, kv_heads, dk, dv, scale, dtype", TILE_BODY_CASES
+)
+def test_streaming_kernels_keep_the_tile_body_to_the_bit(
+    monkeypatch, length, window, heads, kv_heads, dk, dv, scale, dtype
+):
+    """Forward (output and log-sum-exp) and all three gradients of the
+    streaming kernels, BIT for bit against the tile body above, both run
+    operation by operation (`_eager_stream_call`), as `causal_attention`
+    calls them."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    keys = jax.random.split(jax.random.PRNGKey(length + dk), 4)
+    q = jax.random.normal(keys[0], (1, length, heads, dk), dtype)
+    k = jax.random.normal(keys[1], (1, length, kv_heads, dk), dtype)
+    v = jax.random.normal(keys[2], (1, length, kv_heads, dv), dtype)
+    g = jax.random.normal(keys[3], (1, length, heads, dv), dtype)
+    assert fa.stream_shapes_ok(q.shape, k.shape, v.shape)
+    assert fa._stream_tiles(length) == 512
+    _eager_stream_call(monkeypatch)
+    scale = float(dk ** -0.5 if scale is None else scale)
+    band = fa._band(window, q)
+    padded = (*fa._padded_keys(q, k), v)
+
+    def run():
+        out, residuals = fa._stream_fwd(*padded, scale, band)
+        return (out, residuals[-1], *fa._stream_bwd(scale, band, residuals, g))
+
+    got = run()
+    monkeypatch.setattr(fa, "_stream_fwd_kernel", _every_tile_fwd_kernel)
+    monkeypatch.setattr(fa, "_stream_bwd_kernel", _every_tile_bwd_kernel)
+    want = run()
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        a, b = (np.asarray(t.astype(jnp.float32)) for t in (a, b))
+        assert np.isfinite(b).all() and np.abs(b).max() > 0, name
+        assert np.array_equal(a, b), (
+            f"{name}: {np.abs(a - b).max()} apart at most, "
+            f"{(a != b).sum()} of {a.size} elements differ"
+        )
+    # and it is the attention the dense form computes
+    np.testing.assert_allclose(
+        np.asarray(got[0].astype(jnp.float32)),
+        np.asarray(_dense_band(
+            *(t.astype(jnp.float32) for t in (q, k, v)), window, scale
+        )),
+        rtol=3e-2, atol=3e-2,
+    )

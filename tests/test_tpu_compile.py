@@ -103,6 +103,24 @@ def test_half_lane_head_kernels_compile_for_the_chip(one_chip, monkeypatch):
     assert "bf16[4,8,8192,64]" in text and "bf16[4,32,8192,64]" in text
 
 
+# a window of any width: both edges in the diagonal tile, an edge off the
+# tile grid, whole tiles inside the band; at a head of 64 (head-major) and
+# of 128
+ANY_WINDOW = [
+    pytest.param(window, dim, id=f"window{window}-width{dim}")
+    for window in (100, 700, 1536) for dim in (64, 128)
+]
+
+
+@pytest.mark.parametrize("window, dim", ANY_WINDOW)
+def test_a_window_of_any_width_compiles_for_the_chip(
+    one_chip, monkeypatch, window, dim
+):
+    monkeypatch.setattr(fa, "use_interpret", lambda: False)
+    text = _backward_text(one_chip, 8, 2, dim, 1, 4096, window)
+    _assert_two_kernels(text, "window")
+
+
 MIB = 1024 * 1024
 
 
