@@ -72,6 +72,9 @@ DEVICE_SCOPES = (
     "kimi/kda/gate", "kimi/kda/core", "kimi/kda/out", "kimi/mla/proj",
     "kimi/mla/core", "kimi/mla/out", "kimi/dense_ffn", "kimi/moe",
     "kimi/head_ce",
+    "granite/embed", "granite/norm", "granite/ssm/proj", "granite/ssm/conv",
+    "granite/ssm/core", "granite/ssm/gated_norm", "granite/ssm/out",
+    "granite/attn", "granite/dense_ffn", "granite/head_ce",
 )
 
 #: Kernels the TPU's compiler makes from ONE primitive and names after

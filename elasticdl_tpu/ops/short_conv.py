@@ -14,13 +14,14 @@ from every other fusion, and `shifted_short_conv`, the same mathematics
 as K shifted multiply-adds in plain `jnp`, elsewhere (the arrangement of
 `ops/flash_attention.py: causal_attention`).
 
-`silu_short_conv` is the UNGATED form a linear-attention layer puts
-after its q, k and v projections, `y = silu(conv_K(u))` over all the
-columns of u at once (`silu_short_conv_fwd` / `silu_short_conv_bwd`, the
-shifted form `shifted_silu_conv` elsewhere): its columns are independent,
-so its grid also walks blocks of columns, and its backward rebuilds z in
-the tile and in the `_HALO` rows after it (whose taps reach back into the
-tile) for silu's slope.
+`silu_short_conv` is the UNGATED form a linear-attention or state-space
+layer puts after its input projections, `y = silu(conv_K(u) + b)` over all
+the columns of u at once, the bias b (d,) optional (`silu_short_conv_fwd`
+/ `silu_short_conv_bwd`, the shifted form `shifted_silu_conv` elsewhere):
+its columns are independent, so its grid also walks blocks of columns, and
+its backward rebuilds z in the tile and in the `_HALO` rows after it
+(whose taps reach back into the tile) for silu's slope; d(bias), a column
+sum of dz, leaves in the row after the taps' of the d(weight) partial.
 
 Kernel shape: the grid walks (batch, L / tile); a program holds `tile`
 whole rows of `bcu` (all 3d columns: one contiguous read) and the
@@ -296,18 +297,19 @@ _short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)
 _SILU_COLUMNS = 512
 
 
-def silu_conv_shapes_ok(u_shape, weight_shape) -> bool:
-    """Whether the kernels take (batch, L, d) under a (K, d) kernel."""
+def silu_conv_shapes_ok(u_shape, weight_shape, biased: bool = False) -> bool:
+    """Whether the kernels take (batch, L, d) under a (K, d) kernel; a
+    bias wants a row of the d(weight) partial after the taps'."""
     taps, width = weight_shape
     return (
         len(u_shape) == 3 and u_shape[2] == width
         and width % _LANES == 0
         and _tile(u_shape[1]) is not None
-        and 1 <= taps <= _PARTIAL_ROWS
+        and 1 <= taps <= _PARTIAL_ROWS - biased
     )
 
 
-def shifted_silu_conv(u, weight):
+def shifted_silu_conv(u, weight, bias=None):
     """The plain form: K shifted multiply-adds, float32 inside."""
     taps = weight.shape[0]
     length = u.shape[1]
@@ -316,6 +318,8 @@ def shifted_silu_conv(u, weight):
         weight[k].astype(jnp.float32) * x[:, k:k + length]
         for k in range(taps)
     )
+    if bias is not None:
+        z = z + bias.astype(jnp.float32)
     return jax.nn.silu(z).astype(u.dtype)
 
 
@@ -332,30 +336,41 @@ def _silu_slope(z):
     return s * (1.0 + z * (1.0 - s))
 
 
-def _silu_fwd_kernel(u_ref, before_ref, w_ref, y_ref, *, taps: int):
+def _silu_fwd_kernel(u_ref, before_ref, w_ref, *rest, taps: int):
+    """`rest` is (y,) or, with a bias, (bias, y)."""
+    *bias, y_ref = rest
     x = u_ref[0].astype(jnp.float32)
     before = jnp.where(
         pl.program_id(1) == 0, 0.0, before_ref[0].astype(jnp.float32)
     )
     z = _conv(x, before, w_ref[...].astype(jnp.float32), taps)
+    if bias:
+        z = z + bias[0][...].astype(jnp.float32)
     y_ref[0] = (z * jax.nn.sigmoid(z)).astype(y_ref.dtype)
 
 
 def _silu_bwd_kernel(u_ref, before_ref, after_ref, g_ref, g_after_ref,
-                     w_ref, du_ref, dw_ref, *, taps: int, tiles: int):
+                     w_ref, *rest, taps: int, tiles: int):
+    """`rest` is (du, dw) or, with a bias, (bias, du, dw): d(bias) is the
+    row after the taps' of the dw partial."""
+    *bias, du_ref, dw_ref = rest
     x = u_ref[0].astype(jnp.float32)
     tile = x.shape[0]
     before = jnp.where(
         pl.program_id(1) == 0, 0.0, before_ref[0].astype(jnp.float32)
     )
     w = w_ref[...].astype(jnp.float32)
-    dz = g_ref[0].astype(jnp.float32) * _silu_slope(
-        _conv(x, before, w, taps)
-    )
+    g = g_ref[0].astype(jnp.float32)
+    z = _conv(x, before, w, taps)
+    if bias:
+        z = z + bias[0][...].astype(jnp.float32)
+    dz = g * _silu_slope(z)
     # the rows after the tile: their taps reach back into the tile
     z_after = _conv(
         after_ref[0].astype(jnp.float32), x[tile - _HALO:], w, taps
     )
+    if bias:
+        z_after = z_after + bias[0][...].astype(jnp.float32)
     dz_after = jnp.where(
         pl.program_id(1) == tiles - 1, 0.0,
         g_after_ref[0].astype(jnp.float32) * _silu_slope(z_after),
@@ -367,9 +382,13 @@ def _silu_bwd_kernel(u_ref, before_ref, after_ref, g_ref, g_after_ref,
         dw_ref[0, 0, k:k + 1, :] = (dz * _shift_down(x, before, s)).sum(
             axis=0, keepdims=True
         )
-    if taps < _PARTIAL_ROWS:
-        dw_ref[0, 0, taps:, :] = jnp.zeros(
-            (_PARTIAL_ROWS - taps, x.shape[1]), jnp.float32
+    used = taps
+    if bias:
+        dw_ref[0, 0, taps:taps + 1, :] = dz.sum(axis=0, keepdims=True)
+        used += 1
+    if used < _PARTIAL_ROWS:
+        dw_ref[0, 0, used:, :] = jnp.zeros(
+            (_PARTIAL_ROWS - used, x.shape[1]), jnp.float32
         )
     du_ref[0] = du.astype(du_ref.dtype)
 
@@ -394,65 +413,112 @@ def _silu_specs(tile: int, tiles: int, columns: int):
 def _silu_grid(u, weight):
     batch, length, width = u.shape
     tile = _tile(length)
-    columns = _SILU_COLUMNS if width % _SILU_COLUMNS == 0 else _LANES
+    columns = next(
+        c for c in (_SILU_COLUMNS, 256, _LANES) if width % c == 0
+    )
     tiles = length // tile
     taps = pl.BlockSpec((weight.shape[0], columns), lambda b, i, c: (0, c))
+    offset = pl.BlockSpec((1, columns), lambda b, i, c: (0, c))
     return (
-        (batch, tiles, width // columns), tiles, columns, taps,
+        (batch, tiles, width // columns), tiles, columns, taps, offset,
         _silu_specs(tile, tiles, columns),
     )
 
 
-@jax.custom_vjp
-def _silu_conv(u, weight):
-    return _silu_conv_fwd(u, weight)[0]
-
-
-def _silu_conv_fwd(u, weight):
-    grid, _, _, taps, (own, before, _) = _silu_grid(u, weight)
+def _silu_forward(u, weight, bias=None):
+    """`bias` (1, d) or None: the same kernel with or without the ref."""
+    grid, _, _, taps, offset, (own, before, _) = _silu_grid(u, weight)
+    biased = bias is not None
     (y,) = _call(
         functools.partial(_silu_fwd_kernel, taps=weight.shape[0]),
-        grid, [own, before, taps], [own], [(u.shape, u.dtype)],
-        [u, u, weight], "silu_short_conv_fwd",
+        grid, [own, before, taps] + [offset] * biased, [own],
+        [(u.shape, u.dtype)], [u, u, weight] + [bias] * biased,
+        "silu_short_conv_fwd",
     )
-    return y, (u, weight)
+    return y
 
 
-def _silu_conv_bwd(residuals, g):
-    u, weight = residuals
-    grid, tiles, columns, taps, (own, before, after) = _silu_grid(u, weight)
+def _silu_backward(u, weight, g, bias=None):
+    """(du, the (8, d) float32 sum of the programs' partials: the taps'
+    rows, then the bias's)."""
+    grid, tiles, columns, taps, offset, (own, before, after) = _silu_grid(
+        u, weight
+    )
+    biased = bias is not None
     g = g.astype(u.dtype)
     du, partials = _call(
         functools.partial(
             _silu_bwd_kernel, taps=weight.shape[0], tiles=tiles
         ),
-        grid, [own, before, after, own, after, taps],
+        grid,
+        [own, before, after, own, after, taps] + [offset] * biased,
         [own,
          pl.BlockSpec(
              (1, 1, _PARTIAL_ROWS, columns), lambda b, i, c: (b, i, 0, c)
          )],
         [(u.shape, u.dtype),
          ((u.shape[0], tiles, _PARTIAL_ROWS, u.shape[2]), jnp.float32)],
-        [u, u, u, g, g, weight], "silu_short_conv_bwd",
+        [u, u, u, g, g, weight] + [bias] * biased,
+        "silu_short_conv_bwd",
     )
-    return du, partials.sum(axis=(0, 1))[:weight.shape[0]].astype(
-        weight.dtype
-    )
+    return du, partials.sum(axis=(0, 1))
+
+
+@jax.custom_vjp
+def _silu_conv(u, weight):
+    return _silu_forward(u, weight)
+
+
+def _silu_conv_fwd(u, weight):
+    return _silu_forward(u, weight), (u, weight)
+
+
+def _silu_conv_bwd(residuals, g):
+    u, weight = residuals
+    du, partial = _silu_backward(u, weight, g)
+    return du, partial[:weight.shape[0]].astype(weight.dtype)
 
 
 _silu_conv.defvjp(_silu_conv_fwd, _silu_conv_bwd)
 
 
-def silu_short_conv(u, weight):
-    """silu(causal_depthwise_conv_K(u)) of u (batch, L, d) under weight
-    (K, d) -> (batch, L, d), in `u`'s type with float32 inside: the
-    Pallas kernels where the shapes tile (`silu_conv_shapes_ok`), the
-    shifted `jnp` form elsewhere."""
+@jax.custom_vjp
+def _silu_conv_biased(u, weight, bias):
+    return _silu_forward(u, weight, bias[None])
+
+
+def _silu_conv_biased_fwd(u, weight, bias):
+    return _silu_forward(u, weight, bias[None]), (u, weight, bias)
+
+
+def _silu_conv_biased_bwd(residuals, g):
+    u, weight, bias = residuals
+    taps = weight.shape[0]
+    du, partial = _silu_backward(u, weight, g, bias[None])
+    return (
+        du, partial[:taps].astype(weight.dtype),
+        partial[taps].astype(bias.dtype),
+    )
+
+
+_silu_conv_biased.defvjp(_silu_conv_biased_fwd, _silu_conv_biased_bwd)
+
+
+def silu_short_conv(u, weight, bias=None):
+    """silu(causal_depthwise_conv_K(u) + bias) of u (batch, L, d) under
+    weight (K, d) and the optional bias (d,) -> (batch, L, d), in `u`'s
+    type with float32 inside: the Pallas kernels where the shapes tile
+    (`silu_conv_shapes_ok`), the shifted `jnp` form elsewhere."""
     from elasticdl_tpu.parallel.mesh import in_export_mode
 
-    if silu_conv_shapes_ok(u.shape, weight.shape) and not in_export_mode():
+    biased = bias is not None
+    if silu_conv_shapes_ok(u.shape, weight.shape, biased) and (
+        not in_export_mode()
+    ):
+        if biased:
+            return _silu_conv_biased(u, weight, bias)
         return _silu_conv(u, weight)
-    return shifted_silu_conv(u, weight)
+    return shifted_silu_conv(u, weight, bias)
 
 
 def gated_short_conv(bcu, weight):

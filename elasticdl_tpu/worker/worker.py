@@ -50,9 +50,18 @@ _tasks_counter = metrics_lib.default_registry().counter(
     labelnames=("result",),
 )
 # What a routed expert layer sows into STEP_METRICS (layers/moe.py), and
-# what a KDA layer sows there (model_zoo/kimi/kimi_linear.py), read once a
-# task with the loss: leaf name -> gauge by layer.
+# what a KDA layer (model_zoo/kimi/kimi_linear.py) and a Mamba-2 layer
+# (model_zoo/granite/granite_hybrid.py) sow there, read once a task with
+# the loss: leaf name -> gauge by layer.
 _moe_gauges = {
+    "ssm_state_kept_ratio": metrics_lib.default_registry().gauge(
+        "worker_ssm_state_kept_ratio",
+        "mean over heads and chunks of exp(sum of log a over a chunk of "
+        "256 tokens) of a state-space layer, last step of the task: the "
+        "share of a state that outlives a chunk (0: the carried path "
+        "does no work at these weights; 1: nothing is ever forgotten)",
+        labelnames=("layer",),
+    ),
     "kda_decay_mean_ratio": metrics_lib.default_registry().gauge(
         "worker_kda_decay_mean_ratio",
         "mean of a KDA layer's per-channel decay exp(g) over tokens, heads "

@@ -1,6 +1,7 @@
 """What the zoo's decoder language models have in common (`model_zoo/glm/
-glm_moe_lite.py`, `model_zoo/laguna/laguna.py`): RMSNorm, rotary's turn,
-the bias-free dense layer and SwiGLU, the routed block around
+glm_moe_lite.py`, `model_zoo/laguna/laguna.py`): RMSNorm and its gated form, rotary's turn,
+the seeds of a decay (`a_log_init`, `dt_bias_init`), the bias-free dense
+layer and SwiGLU, the routed block around
 `layers/moe.py: RoutedExperts` with its shared expert, the cross-entropy
 taken in blocks of tokens, the per-position losses against the ids
 shifted, the blocks' rematerialisation (`remat_block`), and the zoo
@@ -19,11 +20,11 @@ import optax
 
 from elasticdl_tpu.layers.embedding import embedding_param_sharding
 from elasticdl_tpu.layers.moe import RoutedExperts, moe_param_sharding
-from elasticdl_tpu.ops import flash_attention, kda
+from elasticdl_tpu.ops import flash_attention, kda, ssd
 
 # What a rematerialised block keeps from its forward, by name: ONE policy
 # for every decoder of the zoo.
-SAVED_NAMES = flash_attention.SAVED_NAMES + kda.SAVED_NAMES
+SAVED_NAMES = flash_attention.SAVED_NAMES + kda.SAVED_NAMES + ssd.SAVED_NAMES
 
 # Tokens whose logits exist at once in the cross-entropy.
 CE_BLOCK = 2048
@@ -45,6 +46,21 @@ class RMSNorm(nn.Module):
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
         return rms_norm(x, scale, self.eps).astype(self.dtype)
+
+
+class GatedRMSNorm(nn.Module):
+    """rms_norm(y * silu(z)) * scale over the WHOLE last axis: the gate
+    first, then one norm across every channel (a state-space mixer's
+    output norm with one group)."""
+
+    eps: float = 1e-5
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, y, z):
+        scale = self.param("scale", nn.initializers.ones, (y.shape[-1],))
+        gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        return rms_norm(gated, scale, self.eps).astype(self.dtype)
 
 
 def rotary_turn(x, inv_freq, factor: float = 1.0):
@@ -76,6 +92,19 @@ def tap_init(key, shape, dtype=jnp.float32):
     """A depthwise kernel (K, d): uniform in +-1 / sqrt(K), K its fan-in."""
     bound = shape[0] ** -0.5
     return jax.random.uniform(key, shape, dtype, -bound, bound)
+
+
+def a_log_init(key, shape, dtype=jnp.float32):
+    """log A, A uniform in [1, 16] a head, as the family's code seeds it."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def dt_bias_init(key, shape, dtype=jnp.float32):
+    """softplus^-1(dt), dt log-uniform in [1e-3, 1e-1], as the family's
+    code seeds it: the decay is neither 0 nor 1 at the seeded weights."""
+    low, high = np.log(1e-3), np.log(1e-1)
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, low, high))
+    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 def dense(features: int, name: str, dtype):

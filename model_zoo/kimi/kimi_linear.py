@@ -53,7 +53,9 @@ from model_zoo.common.decoder import (  # noqa: F401
     MoEFFN,
     RMSNorm,
     SwiGLU,
+    a_log_init,
     dense,
+    dt_bias_init,
     eval_metrics_fn,
     loss,
     optimizer,
@@ -72,19 +74,6 @@ PUBLISHED_KDA_LAYERS = tuple(
     i for i in range(1, 28) if i not in PUBLISHED_FULL_ATTN_LAYERS
 )
 L2_EPS = 1e-6
-
-
-def a_log_init(key, shape, dtype=jnp.float32):
-    """log A, A uniform in [1, 16] a head, as the family's code seeds it."""
-    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
-
-
-def dt_bias_init(key, shape, dtype=jnp.float32):
-    """softplus^-1(dt), dt log-uniform in [1e-3, 1e-1], as the family's
-    code seeds it: the decay is neither 0 nor 1 at the seeded weights."""
-    low, high = np.log(1e-3), np.log(1e-1)
-    dt = jnp.exp(jax.random.uniform(key, shape, dtype, low, high))
-    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 class KDA(nn.Module):

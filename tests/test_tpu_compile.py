@@ -259,6 +259,62 @@ def test_kimi_cell_kernels_compile_for_the_chip(one_chip, monkeypatch):
     assert "bf16[2,8192,4096]" in attention
 
 
+def test_granite_cell_kernels_compile_for_the_chip(one_chip, monkeypatch):
+    """The granite cell's three calls at its shapes: the state-space scan
+    (1, 8192, 64 heads of 64 over 128 state columns, ONE B and C a
+    token), the biased SiLU conv over x | B | C (1, 8192, 4352) under 4
+    taps, and attention at 32 / 8 heads of 64 (head-major)."""
+    from elasticdl_tpu.ops import short_conv, ssd
+
+    for module in (fa, ssd, short_conv):
+        monkeypatch.setattr(module, "use_interpret", lambda: False)
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def text_of(fn, *args):
+        def grads(*args):
+            return jax.grad(
+                lambda *a: fn(*a).astype(jnp.float32).sum(),
+                argnums=tuple(range(len(args))),
+            )(*args)
+
+        return jax.jit(grads).lower(*args).compile().as_text()
+
+    x, shared = (1, 8192, 64, 64), (1, 8192, 1, 128)
+    assert ssd.ssd_shapes_ok(x, shared)
+    scan = text_of(
+        ssd.ssd, shaped(x), shaped(x[:3], jnp.float32),
+        shaped((64,), jnp.float32), shaped(shared), shaped(shared),
+        shaped((64,), jnp.float32),
+    )
+    assert "ssd_fwd" in scan and "ssd_bwd" in scan
+    # the states the 32 chunks start from, float32, all heads' rows
+    assert "f32[1,32,4096,128]" in scan
+
+    u, taps = (1, 8192, 4352), (4, 4352)
+    assert short_conv.silu_conv_shapes_ok(u, taps, True)
+    conv = text_of(
+        short_conv.silu_short_conv, shaped(u), shaped(taps, jnp.float32),
+        shaped(taps[1:], jnp.float32),
+    )
+    assert "silu_short_conv_bwd" in conv and "tpu_custom_call" in conv
+    # the taps' four rows and the bias's one of every program's partial
+    assert "f32[1,32,8,4352]" in conv
+    forward = jax.jit(short_conv.silu_short_conv).lower(
+        shaped(u), shaped(taps, jnp.float32), shaped(taps[1:], jnp.float32)
+    ).compile().as_text()
+    assert "silu_short_conv_fwd" in forward
+
+    q, kv = (1, 8192, 32, 64), (1, 8192, 8, 64)
+    assert fa.stream_shapes_ok(q, kv, kv)
+    attention = text_of(
+        lambda q, k, v: fa.causal_attention(q, k, v, scale=0.015625),
+        shaped(q), shaped(kv), shaped(kv),
+    )
+    _assert_two_kernels(attention, "causal")
+
+
 def test_the_scope_table_reads_a_text_compiled_for_the_chip(one_chip):
     """The chip's compiled text differs from the CPU's where the parser
     looks: tiled layouts with brackets of their own
