@@ -17,6 +17,8 @@ an isolated table of that capacity.  The arena parameter is named
 `model` axis exactly like individual tables.
 
 The VJP is `embedding.py:_lookup`'s: a PROMISE_IN_BOUNDS gather forward
+(of the batch's DISTINCT rows, expanded from a compact buffer, where the
+rows are narrow and the ids many: PR 48, `embedding.py: _gather_rows`)
 and `scatter_add_rows` backward, which combines the batch's duplicate
 rows and scatters the DISTINCT ones in chunks (PR 32,
 docs/embedding_design_note.md: the chip's scatter costs ~100 ns per

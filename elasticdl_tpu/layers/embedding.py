@@ -51,8 +51,32 @@ CHUNK = 16384
 _WIDE_ROW_BYTES = 512
 
 
+# The forward's distinct-row route (`compact_lookup_path`, `_gather_rows`).
+# The most trips of its loop, so the rows of its compact buffer: a batch
+# with more distinct rows takes the plain gather, on the device.  What
+# makes the route fast is WHERE the compact buffer lives: up to 131,072
+# rows (8 CHUNKs; 64 MiB padded to lanes) XLA keeps it row-major in the
+# chip's fast memory and 1.7M rows expand from it in 3.1 ms + a 1.4 ms
+# layout copy; at 425,984 rows and over the loop's result stays in HBM,
+# where a row costs what it costs in the 2.1 GB table (38 against 40 ms).
+# PR 48's chip probe, v5e (docs/embedding_design_note.md).
+_COMPACT_CHUNKS = 8
+# A lookup of fewer ids than this many CHUNKs keeps the plain gather,
+# statically.  Probed with the route's own sort counted, plain | route at
+# 2% distinct | route with the buffer nearly full, ms, width 16: 65,536
+# ids 2.2 | 1.7 | 3.0; 131,072: 3.7 | 2.0 | 3.6; 262,144: 6.7 | 2.8 | 6.9;
+# 524,288: 12.7 | 4.5 | 7.0; 1,703,936: 40.0 | 11.7 | 14.1 (width 1: 26.0
+# | 11.5 | 13.0): from 16 CHUNKs on it never loses.
+_COMPACT_MIN_CHUNKS = 16
+
+
+def _narrow_row(shape, dtype) -> bool:
+    return shape[1] * jnp.dtype(dtype).itemsize < _WIDE_ROW_BYTES
+
+
 def distinct_row_path(shape, dtype, updates: int) -> bool:
-    """True where `scatter_add_rows` combines duplicates first.  A static
+    """True where `scatter_add_rows` combines duplicates first: the
+    BACKWARD's rule (the forward's is `compact_lookup_path`).  A static
     test on the table's row (and that there is an update at all), so a
     table's path never changes at run time and the other tables compile
     to the plain scatter's HLO.
@@ -69,15 +93,37 @@ def distinct_row_path(shape, dtype, updates: int) -> bool:
     by itself: 17 ms for the 1.7M updates) and wins too: 54 against 62
     for the two DeepFM tables in one program with the order shared.
     """
-    width = shape[1]
-    return updates > 0 and (
-        1 < width and width * jnp.dtype(dtype).itemsize < _WIDE_ROW_BYTES
+    return updates > 0 and 1 < shape[1] and _narrow_row(shape, dtype)
+
+
+def compact_lookup_path(shape, dtype, lookups: int) -> bool:
+    """True where `_lookup`'s FORWARD may read the table at the batch's
+    distinct rows only and expand them (`_gather_rows`).  Static, like
+    `distinct_row_path`: by the table's row (rows of 512 bytes and more
+    lower to the plain gather's program) and by the count of ids
+    (`_COMPACT_MIN_CHUNKS`).  A one-element row is on it (its BACKWARD
+    is not on the distinct-row path): v5e, 1.7M lookups at 2% distinct
+    rows, 26.0 ms plain against 5.9 beside a wider table that shares
+    the sorts, 11.5 alone.  A count that is a symbol (a model exported
+    for any batch size) is no count to hold against the rule: plain."""
+    return (
+        _narrow_row(shape, dtype)
+        and not jax.export.is_symbolic_dim(lookups)
+        and lookups >= _COMPACT_MIN_CHUNKS * CHUNK
     )
+
+
+def compact_limit() -> int:
+    """The most distinct rows the forward's route takes: the rows of its
+    compact buffer, and what the device holds the batch's count
+    against."""
+    return _COMPACT_CHUNKS * CHUNK
 
 
 def row_order(flat_ids):
     """(the ids sorted, the permutation that sorts them): the one sort
-    the distinct-row backward and its counter share."""
+    the distinct-row backward, the forward's route and their counters
+    share."""
     flat_ids = flat_ids.astype(jnp.int32)
     return lax.sort(
         (flat_ids, lax.iota(jnp.int32, flat_ids.shape[0])), num_keys=1,
@@ -95,6 +141,18 @@ def _run_ends(sorted_ids):
 def distinct_rows(order):
     """How many distinct rows `order` (from `row_order`) holds."""
     return _run_ends(order[0]).sum()
+
+
+def _run_ends_at(ends, chunk):
+    """int32 (n rounded up to `chunk`,): the positions of the run ends
+    first, rising, and n (past the end) after them."""
+    n = ends.shape[0]
+    return jnp.pad(
+        lax.sort(
+            jnp.where(ends, lax.iota(jnp.int32, n), n), is_stable=False
+        ),
+        (0, -n % chunk), constant_values=n,
+    )
 
 
 def _combine_runs(sorted_ids, g):
@@ -115,7 +173,7 @@ def _combine_runs(sorted_ids, g):
     return g
 
 
-def scatter_add_rows(shape, flat_ids, g, order=None):
+def scatter_add_rows(shape, flat_ids, g, order=None, ends_at=None):
     """zeros(shape).at[flat_ids].add(g), scattering the batch's DISTINCT
     rows where `distinct_row_path` says so.
 
@@ -133,7 +191,9 @@ def scatter_add_rows(shape, flat_ids, g, order=None):
     three quarters distinct, which no batch with a field of few values
     comes near.  Rounds 2-3's collapse lost because its "head-only"
     scatter still held N updates (docs/embedding_design_note.md).
-    `order` is `row_order(flat_ids)` where the caller already holds it.
+    `order` is `row_order(flat_ids)` where the caller already holds it,
+    and `ends_at` the run ends' positions where the forward's route
+    already compacted them (`_gather_rows`).
     """
     n = flat_ids.shape[0]
     if not distinct_row_path(shape, g.dtype, n):
@@ -147,13 +207,9 @@ def scatter_add_rows(shape, flat_ids, g, order=None):
         totals = _combine_runs(sorted_ids, g.at[perm].get(mode=_PIB))
         ends = _run_ends(sorted_ids)
         distinct = ends.sum()
-        # positions of the run totals first, n (past the end) after them
-        ends_at = jnp.pad(
-            lax.sort(
-                jnp.where(ends, lax.iota(jnp.int32, n), n), is_stable=False
-            ),
-            (0, -n % chunk), constant_values=n,
-        )
+        # positions of the run totals first
+        if ends_at is None:
+            ends_at = _run_ends_at(ends, chunk)
     # a trip's padding goes to rows past the table's end, distinct and
     # rising like the live ones, and is dropped there
     past = shape[0] + lax.iota(jnp.int32, chunk)
@@ -175,6 +231,98 @@ def scatter_add_rows(shape, flat_ids, g, order=None):
         )
 
 
+# Columns of the view a one-element row is expanded through; the compact
+# buffer's 8 CHUNKs of rows are whole rows of it.
+_VIEW = 16
+
+
+def _expand_rows(compact, run_of):
+    """compact[run_of].  A one-element row goes through a view of
+    `_VIEW` columns: rows of 16 gathered at run_of / 16 and the column
+    picked by a compare and an integer sum over the rows' bits (so -0.0
+    stays -0.0).  A SCALAR gather costs 9 ns an element even from fast
+    memory (15.4 ms for 1.7M, no better than from the table), this 3.1
+    + 1.4 ms (PR 48's probe)."""
+    if compact.shape[1] > 1:
+        return compact.at[run_of].get(mode=_PIB)
+    bits = lax.bitcast_convert_type(
+        compact.reshape(-1, _VIEW).at[run_of // _VIEW].get(mode=_PIB),
+        jnp.dtype(f"int{8 * compact.dtype.itemsize}"),
+    )
+    picked = lax.iota(jnp.int32, _VIEW)[None, :] == (run_of % _VIEW)[:, None]
+    return lax.bitcast_convert_type(
+        jnp.where(picked, bits, 0).sum(
+            axis=1, keepdims=True, dtype=bits.dtype
+        ),
+        compact.dtype,
+    )
+
+
+def _gather_rows(table, flat_ids, order):
+    """(table[flat_ids], the run ends' positions or None): `_lookup`'s
+    forward.
+
+    On the chip a gathered row costs by the MEMORY it is read from, not
+    by the ids: 23.4 ns from `f32[33554432,16]` in HBM at 2%, 21.5% and
+    97.5% distinct rows alike (40.0 ms for 1,703,936 lookups; 15.3 ns
+    an element, 26.0 ms, from the `(rows, 1)` table), 1.8 ns from a
+    buffer XLA keeps in fast memory.  So where `compact_lookup_path`
+    says so and `order` = `row_order(flat_ids)` is at hand, the table
+    is read at the batch's DISTINCT rows only: the run ends' positions
+    are compacted to the front (`ends_at`: the sort the backward made
+    until PR 48; it is handed on to `scatter_add_rows`), a loop of
+    ceil(distinct / CHUNK) trips gathers CHUNK table rows a trip (0.5
+    ms) into a buffer of `compact_limit()` rows, and every lookup
+    expands from there at `run_of`, the index of its run, which a
+    two-operand sort keyed by the permutation carries back to the ids'
+    own order (1.8 ms; a 1.7M scalar scatter does it in 8.7).  One
+    `lax.cond` on the count takes the plain gather where the batch
+    holds more distinct rows than the buffer: there the route has cost
+    its running sum and the `run_of` sort (1.3 + 1.8 ms at 1.7M ids)
+    for nothing.  Whole, 1.7M lookups at 2% distinct rows: 10.9 ms
+    against 40.0.  The rows are copies either way: the values are the
+    plain gather's to the bit.
+    """
+    def plain():
+        return table.at[flat_ids].get(mode=_PIB)
+
+    with jax.named_scope("arena/lookup"):
+        if order is None or not compact_lookup_path(
+            table.shape, table.dtype, flat_ids.shape[0]
+        ):
+            return plain(), None
+        sorted_ids, perm = order
+        ends = _run_ends(sorted_ids)
+        distinct = ends.sum()
+        ends_at = _run_ends_at(ends, CHUNK)
+        # the run a sorted position lies in, carried back to the ids'
+        # own order by a sort keyed by the permutation.  Outside the
+        # `cond`, where two tables looked up by the same ids share it
+        # as they share the order
+        run_id = jnp.cumsum(ends, dtype=jnp.int32) - ends
+        run_of = lax.sort((perm, run_id), num_keys=1, is_stable=False)[1]
+
+        def trip(c, compact):
+            at = lax.dynamic_slice(ends_at, (c * CHUNK,), (CHUNK,))
+            # a trip's padding reads the last id's row again
+            rows = sorted_ids.at[at].get(mode="clip")
+            return lax.dynamic_update_slice(
+                compact,
+                table.at[rows].get(mode=_PIB, indices_are_sorted=True),
+                (c * CHUNK, 0),
+            )
+
+        def compacted():
+            return _expand_rows(lax.fori_loop(
+                0, (distinct + CHUNK - 1) // CHUNK, trip,
+                jnp.zeros((compact_limit(), table.shape[1]), table.dtype),
+            ), run_of)
+
+        return lax.cond(
+            distinct <= compact_limit(), compacted, plain
+        ), ends_at
+
+
 @jax.custom_vjp
 def _lookup(table, flat_ids, order=None):
     """Gather rows; backward is `scatter_add_rows`.
@@ -182,21 +330,23 @@ def _lookup(table, flat_ids, order=None):
     The FORWARD's custom part: ids are hashed mod capacity by
     construction, so the gather's bounds branch is provably dead —
     PROMISE_IN_BOUNDS makes that explicit.  `order` is
-    `row_order(flat_ids)` or None; only the backward reads it.
+    `row_order(flat_ids)` or None: the backward combines by it, and
+    the forward reads the table at the batch's distinct rows by it
+    where `compact_lookup_path` says so (`_gather_rows`).
     """
-    with jax.named_scope("arena/lookup"):
-        return table.at[flat_ids].get(mode=_PIB)
+    return _gather_rows(table, flat_ids, order)[0]
 
 
 def _lookup_fwd(table, flat_ids, order):
     # the table itself is the residual (a reference, not a copy): only
     # its shape/dtype are read in the backward
-    return _lookup(table, flat_ids), (table, flat_ids, order)
+    out, ends_at = _gather_rows(table, flat_ids, order)
+    return out, (table, flat_ids, order, ends_at)
 
 
 def _lookup_bwd(residuals, g):
-    table, flat_ids, order = residuals
-    dtable = scatter_add_rows(table.shape, flat_ids, g, order)
+    table, flat_ids, order, ends_at = residuals
+    dtable = scatter_add_rows(table.shape, flat_ids, g, order, ends_at)
     return dtable.astype(table.dtype), None, None
 
 
@@ -205,21 +355,36 @@ _lookup.defvjp(_lookup_fwd, _lookup_bwd)
 
 def lookup_rows(module, table, flat_ids, lookup=_lookup):
     """`lookup(table, flat_ids)` (`_lookup`, or the int8 arena's
-    `_grad_tap`) from inside a flax `module`.  On the distinct-row path
-    the ids are sorted HERE, once: the backward takes the order, and the
-    module sows `distinct_rows_ratio` (distinct rows / looked-up rows)
-    into STEP_METRICS, where it rides to the task's one fetch
-    (`worker_arena_distinct_rows_ratio{table}`).  Outside a train step
-    nothing reads the order and XLA drops the sort."""
-    if not distinct_row_path(table.shape, table.dtype, flat_ids.shape[0]):
+    `_grad_tap`) from inside a flax `module`.  Where the backward is on
+    the distinct-row path or the forward on its route the ids are
+    sorted HERE, once: both take the order, and the module sows into
+    STEP_METRICS, where they ride to the task's one fetch,
+    `distinct_rows_ratio` (distinct rows / looked-up rows:
+    `worker_arena_distinct_rows_ratio{table}`) and, where the forward is
+    on its route, `lookup_compact` (which side of the `cond` this step
+    took: 1.0 where it expanded the batch's distinct rows, 0.0 where it
+    gathered plainly: `worker_arena_lookup_compact_ratio{table}`; a
+    lookup the static rule keeps plain says nothing).  Two tables
+    looked up by the same ids (DeepFM's) sort them once: XLA merges the
+    equal sorts.  Outside a train step the forward's route still reads
+    the order where it is taken; elsewhere nothing does and XLA drops
+    the sort."""
+    n = flat_ids.shape[0]
+    # `_grad_tap`'s forward gathers nothing
+    compact = lookup is _lookup and compact_lookup_path(
+        table.shape, table.dtype, n
+    )
+    if not (compact or distinct_row_path(table.shape, table.dtype, n)):
         return lookup(table, flat_ids)
     # the forward's sort is the lookup's cost (profiler.DEVICE_SCOPES)
     with jax.named_scope("arena/lookup"):
         order = row_order(flat_ids)
-        sow_step_metric(
-            module, "distinct_rows_ratio",
-            distinct_rows(order) / flat_ids.shape[0],
-        )
+        distinct = distinct_rows(order)
+        sow_step_metric(module, "distinct_rows_ratio", distinct / n)
+        if compact:
+            sow_step_metric(
+                module, "lookup_compact", distinct <= compact_limit()
+            )
         return lookup(table, flat_ids, order)
 
 

@@ -125,6 +125,15 @@ _arena_distinct = metrics_lib.default_registry().gauge(
     "the task",
     labelnames=("table",),
 )
+# ... and whether that lookup's forward read the table at the distinct
+# rows only (layers/embedding.py: compact_lookup_path) or gathered plainly.
+_arena_compact = metrics_lib.default_registry().gauge(
+    "worker_arena_lookup_compact_ratio",
+    "1 where the table's forward lookup gathered the batch's distinct "
+    "rows and expanded them, 0 where it gathered every looked-up row "
+    "from the table, last step of the task",
+    labelnames=("table",),
+)
 # Step-phase attribution (ISSUE 5) and spans (ISSUE 24): the process's
 # one PhaseTimer, shared by the threaded and SPMD loops.  Module-level
 # for the same __new__ reason as the counters above.
@@ -587,6 +596,8 @@ class Worker:
                     _moe_dropped.inc(value)
                 elif name == "distinct_rows_ratio":
                     _arena_distinct.labels(table=layer).set(value)
+                elif name == "lookup_compact":
+                    _arena_compact.labels(table=layer).set(value)
                 elif name == "gate_mean":
                     _attention_gate.labels(layer=layer).set(value)
                 elif name == "out_rms_ratio":

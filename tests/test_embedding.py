@@ -238,7 +238,7 @@ def test_wide_rows_keep_the_plain_scatter_and_narrow_rows_sort_and_loop():
 
     wide, params = backward_of(2048)
     assert ("scatter-add", False) in wide
-    assert not {name for name, _ in wide} & {"sort", "while"}
+    assert not {name for name, _ in wide} & {"sort", "while", "cond"}
     assert "step_metrics" not in params            # a wide lookup sows none
     narrow, params = backward_of(16)
     assert ("sort", False) in narrow
@@ -276,4 +276,234 @@ def test_trainer_fetches_the_distinct_share_with_the_loss():
     assert value == pytest.approx(float(loss))
     assert sown["embedding_bag/distinct_rows_ratio"] == pytest.approx(
         len(np.unique(rows)) / rows.size
+    )
+
+
+# ---- the distinct-row forward (`_gather_rows`, PR 48) ----------------------
+
+# constants patched in: trips of 64 rows, the route from 256 ids on, a
+# compact buffer of 512 rows
+_ROUTE = {"CHUNK": 64, "_COMPACT_MIN_CHUNKS": 4, "_COMPACT_CHUNKS": 8}
+
+
+def _take_the_route(monkeypatch, **changed):
+    from elasticdl_tpu.layers import embedding
+
+    for name, value in {**_ROUTE, **changed}.items():
+        monkeypatch.setattr(embedding, name, value)
+    return embedding
+
+
+def _exactly(distinct):
+    return lambda rng, n: np.resize(
+        rng.permutation(4096)[:distinct], n
+    ).astype(np.int32)[rng.permutation(n)]
+
+
+# (ids of n lookups into 4,096 rows, n, which side of the `cond`)
+_LOOKUP_CASES = {
+    "zipf_1.5": (_zipf(1.5, 4096), 2048, 1.0),
+    "flat": (lambda rng, n: rng.integers(0, 4096, n).astype(np.int32),
+             2048, 0.0),
+    "all_distinct": (
+        lambda rng, n: rng.permutation(4096)[:n].astype(np.int32), 2048, 0.0),
+    "all_one_row": (lambda rng, n: np.full(n, 4095, np.int32), 2048, 1.0),
+    "with_pads": (
+        lambda rng, n: np.where(
+            rng.random(n) < 0.2, -1, rng.zipf(1.5, n) % 4096
+        ).astype(np.int32), 2048, 1.0),
+    "n_no_multiple_of_chunk": (_zipf(1.5, 4096), 2000, 1.0),
+    "distinct_fill_the_buffer": (_exactly(512), 2048, 1.0),
+    "distinct_one_over_the_buffer": (_exactly(513), 2048, 0.0),
+    "one_trip": (_exactly(5), 2048, 1.0),
+}
+
+
+@pytest.mark.parametrize("width", [1, 2, 16, 64])
+@pytest.mark.parametrize("case", sorted(_LOOKUP_CASES))
+def test_lookup_is_the_plain_gather_to_the_bit(case, width, monkeypatch):
+    """Forward and gradient through `lookup_rows` against `table[ids]`
+    and the backward with no order handed to it (a one-element row's:
+    the plain scatter-add), on both sides of the `cond`, said by the sown
+    `lookup_compact`."""
+    embedding = _take_the_route(monkeypatch)
+    make_ids, n, compact = _LOOKUP_CASES[case]
+    rng = np.random.default_rng(11)
+    ids = make_ids(rng, n)
+    layer = DistributedEmbedding(4096, width, hash_input=False)
+    table = jnp.asarray(
+        rng.standard_normal((4096, width), dtype=np.float32)
+    ).at[7].set(-0.0)
+    weight = jnp.asarray(rng.standard_normal((n, width), dtype=np.float32))
+
+    def loss(params):
+        out, sown = layer.apply(params, ids, mutable=["step_metrics"])
+        return jnp.sum(out * weight), (out, sown["step_metrics"])
+
+    (_, (out, sown)), grads = jax.jit(
+        jax.value_and_grad(loss, has_aux=True)
+    )({"params": {"embedding": table}})
+    rows = np.maximum(ids, 0)
+    masked = jnp.where((ids != -1)[:, None], weight, 0.0)
+    want_grad = jax.jit(
+        lambda g: embedding.scatter_add_rows(table.shape, rows, g)
+    )(masked)
+
+    def bits(x):
+        return np.asarray(x).view(np.int32)
+
+    np.testing.assert_array_equal(
+        bits(out), bits(jnp.where((ids != -1)[:, None], table[rows], 0.0))
+    )
+    np.testing.assert_array_equal(
+        bits(grads["params"]["embedding"]), bits(want_grad)
+    )
+    assert float(sown["lookup_compact"]) == compact
+    assert float(sown["distinct_rows_ratio"]) == pytest.approx(
+        len(np.unique(rows)) / n
+    )
+
+
+@pytest.mark.parametrize("width", [1, 16])
+def test_few_ids_lower_to_the_plain_gather(width, monkeypatch):
+    _take_the_route(monkeypatch)
+    ids = jnp.arange(255, dtype=jnp.int32) % 7      # one under 4 chunks
+    layer = DistributedEmbedding(32, width, hash_input=False)
+    params = layer.init(jax.random.PRNGKey(0), ids)
+    found = {name for name, _ in _primitives(jax.make_jaxpr(
+        lambda p: layer.apply(p, ids, mutable=["step_metrics"])
+    )(params).jaxpr)}
+    assert "gather" in found and not found & {"while", "cond"}
+    # the static rule's plain gather says nothing of a route; a
+    # one-element row has no order to sort for either
+    sown = params.get("step_metrics", {})
+    assert set(sown) == ({"distinct_rows_ratio"} if width > 1 else set())
+    assert ("sort" in found) == (width > 1)
+    many = jnp.arange(256, dtype=jnp.int32) % 7
+    found = {name for name, _ in _primitives(jax.make_jaxpr(
+        lambda p: layer.apply(p, many, mutable=["step_metrics"])
+    )(params).jaxpr)}
+    assert {"sort", "while", "cond"} <= found
+    assert set(layer.init(jax.random.PRNGKey(0), many)["step_metrics"]) == {
+        "distinct_rows_ratio", "lookup_compact"
+    }
+
+
+# sha256 of the program a WIDE row's lookup and its gradient lower to
+# over 1,024 ids (64 CHUNKs as patched: no count of ids brings a wide
+# row onto the route), recorded at the commit before the forward had a
+# route (61a50ed)
+_WIDE_ROW_PROGRAMS = {
+    (128, "float32"):
+        "601b9f0dbf378fe5edd28da2792507d975be9245b4dd7bb098c596186dd81867",
+    (2048, "float32"):
+        "93e5277a903e98386c938462b4a3a1dc6f6a10faa5c875c1aef743e1d2d426fc",
+    (256, "bfloat16"):
+        "3d88ab6d898aeb78a4abe72ec0f211863c523ce9e8eb836ee432bc7474f2086f",
+}
+
+
+@pytest.mark.parametrize("width,dtype", sorted(_WIDE_ROW_PROGRAMS))
+def test_a_wide_rows_lookup_lowers_to_the_program_it_had(
+    width, dtype, monkeypatch
+):
+    import hashlib
+    import re
+
+    _take_the_route(monkeypatch, CHUNK=16)
+    layer = DistributedEmbedding(
+        64, width, hash_input=False, param_dtype=jnp.dtype(dtype)
+    )
+    ids = jnp.arange(1024, dtype=jnp.int32) % 7
+    shapes = jax.eval_shape(layer.init, jax.random.PRNGKey(0), ids)
+    assert set(shapes) == {"params"}
+
+    def loss(params):
+        out, _ = layer.apply({"params": params}, ids, mutable=True)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = re.sub(
+        r"(@[A-Za-z_]\w*?)_\d+\b", r"\1",
+        jax.jit(jax.value_and_grad(loss)).lower(shapes["params"]).as_text(),
+    )
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        _WIDE_ROW_PROGRAMS[width, dtype]
+    )
+    found = {name for name, _ in _primitives(
+        jax.make_jaxpr(jax.value_and_grad(loss))(shapes["params"]).jaxpr
+    )}
+    assert not found & {"sort", "while", "cond"}
+
+
+def test_sharded_table_matches_replicated_on_the_route(monkeypatch):
+    """The forward's loop gathers from a table row-sharded over `model`
+    as the backward's loop scatters into one."""
+    _take_the_route(monkeypatch, CHUNK=16)         # 64 ids on, 128 rows
+    devices = jax.devices()
+    mesh_sharded = mesh_lib.create_mesh(devices, data=4, model=2)
+    mesh_single = mesh_lib.create_mesh(devices[:1], data=1)
+
+    def train(mesh, sharding):
+        trainer = Trainer(
+            model=TinyEmbedModel.build(), optimizer=optax.adam(1e-2),
+            loss_fn=_loss, mesh=mesh, param_sharding_fn=sharding,
+        )
+        # 5 ids an example over 64 values: every batch stays in the buffer
+        batches = [
+            {**_batch(i), "features": _batch(i)["features"] % 64}
+            for i in range(3)
+        ]
+        state = trainer.init_state(
+            jax.random.PRNGKey(0), batches[0]["features"]
+        )
+        losses = []
+        for batch in batches:
+            state, loss = trainer.train_on_batch(state, batch)
+            losses.append(float(loss))
+        took = state.model_state["step_metrics"]["embedding_bag"][
+            "lookup_compact"]
+        return losses, state, float(took)
+
+    losses_sh, state_sh, took_sh = train(
+        mesh_sharded, embedding_param_sharding
+    )
+    losses_rep, state_rep, took_rep = train(mesh_single, None)
+    assert took_sh == took_rep == 1.0
+    np.testing.assert_allclose(losses_sh, losses_rep, rtol=2e-4)
+    for a, b in zip(
+        jax.tree.leaves(state_sh.params), jax.tree.leaves(state_rep.params)
+    ):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
+def test_trainer_fetches_the_lookups_route_with_the_loss(monkeypatch):
+    """`lookup_compact` rides to the task's one fetch beside the distinct
+    share (32 x 5 ids over ~120 rows: on the route by the static rule,
+    more rows than the 32 of the buffer as patched: the plain side)."""
+    from elasticdl_tpu.worker.sync import ModelOwner
+
+    _take_the_route(monkeypatch, CHUNK=4)
+    owner = ModelOwner(Trainer(
+        model=TinyEmbedModel.build(), optimizer=optax.adam(1e-2),
+        loss_fn=_loss,
+    ))
+    _, sown = owner.fetch_loss(owner.train_batch(_batch(1)))
+    assert sown["embedding_bag/lookup_compact"] == 0.0
+
+
+def test_a_symbolic_count_of_ids_keeps_the_plain_gather():
+    """A model exported for any batch size has no count to hold against
+    the static rule (`tests/test_saved_model_export.py` exports DeepFM
+    that way)."""
+    from elasticdl_tpu.layers import embedding
+
+    (batch,) = jax.export.symbolic_shape("b")
+    assert not embedding.compact_lookup_path(
+        (4096, 16), jnp.float32, 26 * batch
+    )
+    assert embedding.compact_lookup_path(
+        (4096, 16), jnp.float32, embedding._COMPACT_MIN_CHUNKS * embedding.CHUNK
+    )
+    assert not embedding.compact_lookup_path(
+        (4096, 128), jnp.float32, 1 << 30
     )

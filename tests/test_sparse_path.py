@@ -382,3 +382,141 @@ def test_host_hash_replica_is_bit_exact():
         hash_ids(field_offset_ids(jnp.asarray(sparse)), 4096, mix=True)
     )
     np.testing.assert_array_equal(host, device)
+
+
+# ---- the forward's distinct-row route through the arena (PR 48) -----------
+
+
+def _patched_route(monkeypatch, chunk=16, most=8, least=4):
+    from elasticdl_tpu.layers import embedding
+
+    monkeypatch.setattr(embedding, "CHUNK", chunk)
+    monkeypatch.setattr(embedding, "_COMPACT_CHUNKS", most)
+    monkeypatch.setattr(embedding, "_COMPACT_MIN_CHUNKS", least)
+
+
+@pytest.mark.parametrize("dim", [1, 2, 16])
+@pytest.mark.parametrize("wire", ["hashed", "prehashed"])
+@pytest.mark.parametrize("values", [40, 5000])
+def test_arena_on_the_route_is_the_plain_gather_to_the_bit(
+    values, wire, dim, monkeypatch
+):
+    """Both wire paths, few values a field (the compact buffer) and many
+    (the `cond`'s plain side): the vectors are the table's rows, pads
+    zero, and the sown `lookup_compact` says which side ran."""
+    from elasticdl_tpu.layers.arena import EmbeddingArena
+
+    _patched_route(monkeypatch)
+    feats = (("x", 256), ("y", 768))
+    rng = np.random.RandomState(3)
+    ids = {
+        "x": rng.randint(0, values, size=(64,)).astype(np.int32),
+        "y": rng.randint(0, values, size=(64, 2)).astype(np.int32),
+    }
+    if wire == "hashed":
+        ids["y"][::7, 0] = -1                       # pads
+    arena = EmbeddingArena(feats, dim)
+    variables = arena.init(jax.random.PRNGKey(0), ids)
+    table = np.asarray(variables["params"]["embedding"])
+    clean = {k: np.where(v == -1, 0, v) for k, v in ids.items()}
+    rows = arena.arena_rows_host(
+        {k: v.reshape(64, -1) for k, v in clean.items()}
+    )
+    distinct = len(np.unique(rows))
+    assert (distinct <= 128) == (values == 40)
+    if wire == "hashed":
+        out, sown = jax.jit(lambda v: arena.apply(
+            v, ids, mutable=["step_metrics"]
+        ))(variables)
+        got = np.concatenate(
+            [np.asarray(out[k]).reshape(64, -1, dim) for k in ("x", "y")],
+            axis=1,
+        )
+        valid = np.concatenate(
+            [(ids[k] != -1).reshape(64, -1) for k in ("x", "y")], axis=1
+        )
+    else:
+        got, sown = jax.jit(lambda v: arena.apply(
+            v, rows, prehashed=True, mutable=["step_metrics"]
+        ))(variables)
+        got, valid = np.asarray(got), np.ones(rows.shape, bool)
+    want = np.where(valid[..., None], table[rows], 0.0).astype(np.float32)
+    np.testing.assert_array_equal(got.view(np.int32), want.view(np.int32))
+    assert float(sown["step_metrics"]["lookup_compact"]) == float(
+        values == 40
+    )
+    assert float(sown["step_metrics"]["distinct_rows_ratio"]) == (
+        pytest.approx(distinct / rows.size)
+    )
+
+
+def test_deepfm_step_sorts_its_ids_once_for_both_tables(monkeypatch):
+    """`fm_embedding` and `fm_linear` look the same (B, 26) rows up: the
+    compiled train step holds ONE sort of the ids, one of the run ends
+    and one that carries the runs back, not one a table (the CPU's
+    scalar scatter sorts nothing of its own)."""
+    import optax
+
+    from model_zoo.deepfm import deepfm_functional_api as zoo
+
+    _patched_route(monkeypatch, chunk=64, most=104, least=4)
+    model = zoo.custom_model(vocab_capacity=4096, embed_dim=16)
+    rng = np.random.RandomState(0)
+    features = {
+        "dense": rng.rand(64, 13).astype(np.float32),
+        "sparse": (rng.zipf(1.5, size=(64, 26)) % 1000).astype(np.int32),
+    }
+    labels = rng.randint(0, 2, size=64).astype(np.int32)
+    params = model.init(jax.random.PRNGKey(0), features)["params"]
+    optimizer = optax.adam(1e-3)
+
+    def step(params, opt_state):
+        def loss(p):
+            out, sown = model.apply(
+                {"params": p}, features, mutable=["step_metrics"]
+            )
+            return zoo.loss(labels, out), sown["step_metrics"]
+
+        (value, sown), grads = jax.value_and_grad(loss, has_aux=True)(params)
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, value, sown
+
+    compiled = jax.jit(step).lower(params, optimizer.init(params)).compile()
+    assert compiled.as_text().count(" sort(") == 3
+    _, _, _, sown = compiled(params, optimizer.init(params))
+    assert float(sown["fm_embedding"]["lookup_compact"]) == 1.0
+    assert float(sown["fm_linear"]["lookup_compact"]) == 1.0
+    assert float(sown["fm_linear"]["distinct_rows_ratio"]) == float(
+        sown["fm_embedding"]["distinct_rows_ratio"]
+    )
+
+
+@pytest.mark.parametrize("metric,gauge", [
+    ("arena_distinct_rows_share", "worker_arena_distinct_rows_ratio"),
+    ("arena_lookup_compact_share", "worker_arena_lookup_compact_ratio"),
+])
+def test_the_benchmark_reads_the_lookups_gauges(metric, gauge):
+    """The two data files of PR 48 under the manifest's own loader: the
+    DeepFM cell reports both, by the reader that takes a gauge of the
+    program's registry, under the layer's name."""
+    from benchmarks import manifest
+    from elasticdl_tpu.common import metrics as metrics_lib
+    from elasticdl_tpu.worker import worker  # noqa: F401  (the gauges)
+
+    cell = manifest.resolve_cell(
+        manifest.load_manifest(), "deepfm-criteo-kaggle.train-stream"
+    )
+    (entry,) = [m for m in cell.per_layer if m["name"] == metric]
+    spec = manifest.load_layer_metric(cell, metric)
+    assert spec["name"] == metric and spec["reader"] == "registry_gauge"
+    assert spec["layer"] == entry["layer"] == "kernels, sparse"
+    assert spec["moves"] == entry["moves"] == "train_examples_per_s"
+    assert entry["workloads"] == ["deepfm-criteo-kaggle.train-stream"]
+    assert spec["params"] == {"metric": gauge, "stat": "mean"}
+    reader = manifest.import_by_name("readers", spec["reader"])
+    family = metrics_lib.default_registry().gauge(gauge, labelnames=("table",))
+    family.labels(table="a").set(1.0)
+    family.labels(table="b").set(0.0)
+    values = list(family.child_values().values())
+    assert {0.0, 1.0} <= set(values)
+    assert reader.read(spec["params"], {}) == sum(values) / len(values)
