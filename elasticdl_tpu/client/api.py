@@ -180,18 +180,6 @@ def _train_local(args, job_type: str = "train") -> int:
             registry=metrics_lib.default_registry(),
             phase_timer=_phase_timer,
         )
-        if getattr(args, "steps_per_execution", 1) != 1:
-            # Fused multi-step (ISSUE 18c): the K steps run as one
-            # uninterruptible scan, so per-batch eager plans are
-            # impossible — the trainer plans ONE admission block over
-            # the union of the K batches' rows at train time, which
-            # requires the raw sparse batches (deferred mode) rather
-            # than pre-planned slots.
-            tiered_store.enable_deferred_prepare()
-            logger.info(
-                "Tiered store: deferred block planning for "
-                "steps_per_execution=%d", args.steps_per_execution,
-            )
         if args.num_workers != 1:
             # Multi-worker path: N feed producers cannot keep the strict
             # batch-order invariant eager planning needs, so planning is
@@ -247,7 +235,6 @@ def _train_local(args, job_type: str = "train") -> int:
             spec=spec,
             minibatch_size=args.minibatch_size,
             model_owner=owner,
-            steps_per_execution=getattr(args, "steps_per_execution", 1),
             compact_wire=getattr(args, "compact_wire", False),
             wire_format=getattr(args, "wire_format", ""),
             tensorboard_dir=tb_dir,

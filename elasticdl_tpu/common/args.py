@@ -421,12 +421,6 @@ def add_model_params(parser: argparse.ArgumentParser):
 
 def add_train_params(parser: argparse.ArgumentParser):
     parser.add_argument("--minibatch_size", type=pos_int, default=64)
-    parser.add_argument(
-        "--steps_per_execution", type=pos_int, default=1,
-        help="Dispatch this many train steps as ONE compiled program "
-        "(lax.scan over a batch stack).  Amortizes per-dispatch "
-        "overhead; losses/metrics are still recorded per step.",
-    )
     parser.add_argument("--num_epochs", type=pos_int, default=1)
     parser.add_argument("--training_data", default="")
     parser.add_argument("--validation_data", default="")

@@ -608,11 +608,9 @@ def _write_scope_summary(log_dir: str, steps: int) -> None:
     from elasticdl_tpu.common import programs
 
     op_seconds = xla_op_seconds(log_dir)
-    registry = programs.default_program_registry()
-    # the fused program is compiled only where it is the one that runs
-    table = registry.scope_table(
+    table = programs.default_program_registry().scope_table(
         "worker_train_step"
-    ) or registry.scope_table("worker_train_step_many")
+    )
     if not op_seconds or not table or not steps:
         return
     summary = scope_summary(op_seconds, table, steps)

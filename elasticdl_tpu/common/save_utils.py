@@ -708,15 +708,15 @@ class CheckpointSaver:
 
     def _restore_with_shims(self, step: int, abstract: Any) -> Any:
         """`_restore_renamed`, and where that fails on a template whose
-        model sows step metrics (`layers/moe.py: STEP_METRICS`, the LAST
-        step's scalars, not trained state), once more without them: a
+        model sows step metrics (`layers/step_metrics.py: STEP_METRICS`,
+        the LAST step's scalars, not trained state), once more without them: a
         checkpoint written before the model sowed any (a narrow-row
         embedding sows its share of distinct rows since PR 32) restores
         with the collection as zeros, as `init` leaves it."""
         import jax
         import jax.numpy as jnp
 
-        from elasticdl_tpu.layers.moe import STEP_METRICS
+        from elasticdl_tpu.layers.step_metrics import STEP_METRICS
 
         model_state = getattr(abstract, "model_state", None)
         try:

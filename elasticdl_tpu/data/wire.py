@@ -168,8 +168,7 @@ def is_packed_uint24(arr) -> bool:
 # consumes rows directly (DistributedEmbedding prehashed=True, skipping
 # the on-device hash/mod).  Padding keeps shapes static under jit:
 # `DedupPacker` grows its pad caps monotonically (quantum-rounded with
-# headroom), so consecutive batches share shapes — the contract
-# steps_per_execution's np.stack grouping relies on.
+# headroom), so consecutive batches share shapes.
 
 DEDUP_ESCAPE = 255
 DEDUP_KEYS = frozenset(
@@ -404,10 +403,9 @@ class DedupPacker:
     """pack_rows_dedup with STICKY pad caps: the unique/exception planes
     are padded to caps that only grow (headroom-scaled, quantum-rounded),
     so consecutive batches of the same shape produce identical array
-    shapes — jit compiles once, and steps_per_execution's np.stack
-    grouping (which requires equal shapes within a group) holds.  A
-    batch overflowing its cap grows it (one recompile); with the default
-    25% headroom that happens at most a couple of times per run."""
+    shapes — jit compiles once.  A batch overflowing its cap grows it
+    (one recompile); with the default 25% headroom that happens at most
+    a couple of times per run."""
 
     def __init__(self, quantum: int = 4096, headroom: float = 1.25):
         self.quantum = int(quantum)

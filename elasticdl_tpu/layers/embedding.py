@@ -29,13 +29,40 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from elasticdl_tpu.layers.moe import sow_step_metric
+from elasticdl_tpu.common import metrics as metrics_lib
+from elasticdl_tpu.layers import step_metrics
+from elasticdl_tpu.layers.step_metrics import sow_step_metric
 
 # Knuth's multiplicative hash constant (2^32 / phi); enough mixing to
 # de-cluster sequential ids before the mod.
 _MIX = 2654435761
 
 _PIB = lax.GatherScatterMode.PROMISE_IN_BOUNDS
+
+# What a narrow-row lookup sows into STEP_METRICS (`lookup_rows`): the
+# share of the batch's looked-up rows that are distinct, by table.
+step_metrics.declare(
+    "distinct_rows_ratio",
+    metrics_lib.default_registry().gauge(
+        "worker_arena_distinct_rows_ratio",
+        "distinct table rows / looked-up rows of the batch (what the "
+        "embedding backward scatters over what it was handed), last step of "
+        "the task",
+        labelnames=("table",),
+    ),
+)
+# ... and whether that lookup's forward read the table at the distinct
+# rows only (`compact_lookup_path`) or gathered plainly.
+step_metrics.declare(
+    "lookup_compact",
+    metrics_lib.default_registry().gauge(
+        "worker_arena_lookup_compact_ratio",
+        "1 where the table's forward lookup gathered the batch's distinct "
+        "rows and expanded them, 0 where it gathered every looked-up row "
+        "from the table, last step of the task",
+        labelnames=("table",),
+    ),
+)
 
 
 # Distinct rows a trip of the backward's scatter loop writes: a constant

@@ -51,24 +51,53 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from elasticdl_tpu.common import metrics as metrics_lib
+from elasticdl_tpu.layers import step_metrics
+from elasticdl_tpu.layers.step_metrics import AUX_LOSS, sow_step_metric
 
-# Collections the Trainer knows (worker/trainer.py): every leaf sown into
-# AUX_LOSS is added to the training objective; STEP_METRICS holds the
-# last step's scalars and rides in `model_state` to the task's one fetch;
-# ROUTER_STATE holds buffers a layer updates itself (no gradient).
-AUX_LOSS = "aux_loss"
-STEP_METRICS = "step_metrics"
+
+# Holds buffers a layer updates itself (no gradient); the two collections
+# the Trainer reads are layers/step_metrics.py's.
 ROUTER_STATE = "router_state"
 
-
-def sow_step_metric(module: nn.Module, name: str, value) -> None:
-    """Keep `value` (the LAST step's, not a history) in STEP_METRICS."""
-    value = jax.lax.stop_gradient(jnp.asarray(value, jnp.float32))
-    module.sow(
-        STEP_METRICS, name, value,
-        reduce_fn=lambda previous, new: new,
-        init_fn=lambda: jnp.zeros(value.shape, jnp.float32),
-    )
+# What a routed expert layer sows into STEP_METRICS (`RoutedExperts`),
+# read once a task with the loss: leaf name -> gauge by layer.
+step_metrics.declare(
+    "expert_load_imbalance_ratio",
+    metrics_lib.default_registry().gauge(
+        "worker_moe_expert_load_imbalance_ratio",
+        "largest router load over the mean load, over all the router's "
+        "outputs, last step of the task",
+        labelnames=("layer",),
+    ),
+)
+step_metrics.declare(
+    "routed_here_ratio",
+    metrics_lib.default_registry().gauge(
+        "worker_moe_routed_here_ratio",
+        "routing slots that chose an expert held here / tokens x top_k, "
+        "last step of the task",
+        labelnames=("layer",),
+    ),
+)
+step_metrics.declare(
+    "live_chunks_ratio",
+    metrics_lib.default_registry().gauge(
+        "worker_moe_live_chunks_ratio",
+        "chunks of the sorted buffer the layer walked / chunks of its "
+        "worst case (layers/moe.py: routed_walk), last step of the task; "
+        "1.0 means the walk saved nothing",
+        labelnames=("layer",),
+    ),
+)
+step_metrics.declare(
+    "dropped_tokens",
+    metrics_lib.default_registry().counter(
+        "worker_moe_dropped_tokens_total",
+        "slots routed to a held expert that got no row (sorted dispatch "
+        "has a worst-case buffer: stays 0)",
+    ),
+)
 
 
 def expert_loads(expert_idx, num_experts: int):

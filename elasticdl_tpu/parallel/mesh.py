@@ -126,13 +126,6 @@ def data_sharding(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P(DATA_AXIS))
 
 
-def stacked_data_sharding(mesh: Mesh) -> NamedSharding:
-    """Sharding for a (K, B, ...) stack of K batches (steps_per_execution
-    dispatch): the scan axis stays whole, the batch axis splits over
-    `data`."""
-    return NamedSharding(mesh, P(None, DATA_AXIS))
-
-
 def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
@@ -237,37 +230,6 @@ def make_global_batch_from_local(
         return jax.make_array_from_callback(shape, sharding, fetch)
 
     return jax.tree.map(to_global, batch)
-
-
-def make_global_batch_stack_from_local(
-    local_batches, mesh: Mesh, global_batch_size: int, local_start: int,
-):
-    """Assemble K local batches into global (K, B, ...) `jax.Array`s
-    sharded P(None, data) — the steps_per_execution stack for the
-    multi-process SPMD path.  Like make_global_batch_from_local, each
-    host provides only its own rows of every batch in the stack."""
-    stacked = jax.tree.map(lambda *xs: np.stack(xs), *local_batches)
-    sharding = stacked_data_sharding(mesh)
-
-    def to_global(x):
-        shape = (x.shape[0], global_batch_size) + x.shape[2:]
-
-        def fetch(idx):
-            bsl = idx[1]
-            start = (0 if bsl.start is None else bsl.start) - local_start
-            stop = (
-                global_batch_size if bsl.stop is None else bsl.stop
-            ) - local_start
-            if start < 0 or stop > x.shape[1]:
-                raise IndexError(
-                    "requested global rows outside this rank's local "
-                    "slice (local_batch_range mismatch)"
-                )
-            return x[idx[0], start:stop]
-
-        return jax.make_array_from_callback(shape, sharding, fetch)
-
-    return jax.tree.map(to_global, stacked)
 
 
 def pad_to_multiple(batch: Dict[str, np.ndarray], multiple: int):

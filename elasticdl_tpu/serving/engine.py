@@ -44,7 +44,7 @@ from elasticdl_tpu.common.export import (
     read_export_meta,
 )
 from elasticdl_tpu.common.log_utils import get_logger
-from elasticdl_tpu.layers.moe import STEP_METRICS
+from elasticdl_tpu.layers.step_metrics import STEP_METRICS
 from elasticdl_tpu.parallel import mesh as mesh_lib
 from elasticdl_tpu.worker.trainer import (
     model_has_train_kwarg,

@@ -133,9 +133,6 @@ class CachePlan:
     # Mesh-sharded seam: per-device sub-plans over the row-sharded slot
     # arena (partition_plan); None on an unsharded (1-device) store.
     sub_plans: Optional[list] = None
-    # Fused multi-step: number of batches this plan's admissions cover
-    # (1 for per-batch plans, K for a steps_per_execution block).
-    block_batches: int = 1
 
 
 class HotRowCache:
@@ -196,9 +193,7 @@ class HotRowCache:
             raise ValueError(
                 f"batch touches {uniq.size} unique rows but the cache "
                 f"holds {self.capacity}; shrink the batch or grow the "
-                f"cache — thrashing within one step is not supported "
-                "(with steps_per_execution > 1 the admission block spans "
-                "the UNION of all K fused batches' rows)"
+                "cache — thrashing within one step is not supported"
             )
         resident = np.fromiter(
             (int(r) in self._slot_of for r in uniq), bool, uniq.size

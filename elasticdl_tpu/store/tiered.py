@@ -145,10 +145,6 @@ class TieredStore:
             self._hit_ratio,
             "Lifetime cache hit fraction of embedding lookups",
         )
-        self._block_plans = self.registry.counter(
-            "store_block_plans_total",
-            "Multi-batch admission plans spanning a fused step block",
-        )
         self.registry.gauge_fn(
             "store_device_cache_bytes",
             lambda: float(self.device_cache_bytes()),
@@ -301,46 +297,6 @@ class TieredStore:
             self._finish_plan_locked(plan, n_new)
         self._publish_plan(plan, n_new)
         return plan.slots, plan
-
-    def prepare_block(self, sparse_list):
-        """Plan ONE admission block covering the UNION of K batches'
-        rows (steps_per_execution > 1, ISSUE 18c): the K fused steps
-        run as one uninterruptible lax.scan, so per-batch plans are
-        impossible (plan k+1 could evict rows batch k still needs,
-        with no apply point between them).  Union planning makes every
-        row of every batch resident for the whole block; evictions are
-        rows OUTSIDE the union, so reading them before the block is
-        exact.  Frequency ranking is recomputed over the union (a
-        per-batch wire ranking doesn't aggregate across batches).
-
-        Returns (slots_list, plan): K slot arrays, one plan whose
-        admit/evict apply once before the block.  Same single-thread
-        batch-order contract as prepare()."""
-        if not sparse_list:
-            raise ValueError("prepare_block needs at least one batch")
-        with self._lock:
-            rows_list = []
-            n_new = 0
-            for sparse in sparse_list:
-                rows, grown = self.host.assign(sparse)
-                rows_list.append(np.asarray(rows))
-                n_new += grown
-            union = np.concatenate([r.reshape(-1) for r in rows_list])
-            plan = self.cache.plan(union)
-            plan.block_batches = len(rows_list)
-            self._finish_plan_locked(plan, n_new)
-        self._publish_plan(plan, n_new)
-        self._block_plans.inc()
-        flat_slots = np.asarray(plan.slots).reshape(-1)
-        slots_list = []
-        offset = 0
-        for rows in rows_list:
-            size = rows.size
-            slots_list.append(
-                flat_slots[offset:offset + size].reshape(rows.shape)
-            )
-            offset += size
-        return slots_list, plan
 
     def _finish_plan_locked(self, plan: CachePlan, n_new: int) -> None:
         plan.growth = n_new
@@ -535,7 +491,6 @@ class TieredStore:
             "cache_dtype": self.cache_dtype,
             "device_cache_bytes": self.device_cache_bytes(),
             "mesh_shards": self.mesh_shards,
-            "block_plans": int(self._block_plans.value()),
             "host_bytes": self.host.nbytes,
             "prefetch_ticks": self.prefetch_ticks,
             "fold_ticks": self.fold_ticks,

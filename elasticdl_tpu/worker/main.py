@@ -227,7 +227,6 @@ def _main(argv=None):
             initial_epoch=cluster.rendezvous_id,
             output_dir=getattr(args, "output", ""),
             wedge_grace_s=args.wedge_grace_s,
-            steps_per_execution=getattr(args, "steps_per_execution", 1),
             compact_wire=getattr(args, "compact_wire", False),
             wire_format=getattr(args, "wire_format", ""),
             tensorboard_dir=tb_dir,
@@ -248,7 +247,6 @@ def _main(argv=None):
             use_bf16=args.use_bf16,
             checkpoint_saver=saver_factory() if saver_factory else None,
             checkpoint_steps=args.checkpoint_steps,
-            steps_per_execution=getattr(args, "steps_per_execution", 1),
             compact_wire=getattr(args, "compact_wire", False),
             wire_format=getattr(args, "wire_format", ""),
             tensorboard_dir=tb_dir,

@@ -147,7 +147,6 @@ class SPMDWorker:
         output_dir: str = "",
         tensorboard_dir: str = "",
         profile_dir: str = "",
-        steps_per_execution: int = 1,
         compact_wire: bool = False,
         wire_format: str = "",
         rpc_policy: Optional[resilience.RetryPolicy] = None,
@@ -177,10 +176,6 @@ class SPMDWorker:
             spec, wire_format, compact_wire, logger
         )
         self.compact_wire = self.wire_format == "compact"
-        # >1 dispatches that many collective train steps as one jitted
-        # scan over a global (K, B, ...) batch stack (deterministic
-        # grouping — identical on every rank)
-        self.steps_per_execution = max(1, int(steps_per_execution))
         self.process_id = process_id
         self.num_processes = num_processes
         self._coordinator = coordinator_address
@@ -601,12 +596,6 @@ class SPMDWorker:
                 self._feed_bulk, local[0], local[1],
             )
         else:  # non-contiguous local rows: every rank reads everything
-            if self.steps_per_execution > 1:
-                logger.warning(
-                    "steps_per_execution=%d ignored: this rank's rows of "
-                    "the data axis are not one contiguous range, so "
-                    "batches dispatch singly", self.steps_per_execution,
-                )
             batches = (
                 (batch, real, False)
                 for batch, real in self._data_service.batches_for_task(
@@ -614,7 +603,9 @@ class SPMDWorker:
                     feed_bulk=self._feed_bulk,
                 )
             )
+        from elasticdl_tpu.worker.sync import fetch_loss
         from elasticdl_tpu.worker.task_data_service import prefetch_batches
+        from elasticdl_tpu.worker.worker import finish_train_task
 
         def mark_recovered():
             if self._recovery_t0 is not None:
@@ -639,105 +630,44 @@ class SPMDWorker:
                     )
                 return mesh_lib.make_global_batch(one_batch, self.mesh)
 
-        def single_step(one_batch, one_is_local, gb=None):
-            _phase_timer.mark(step=steps_done())
-            if gb is None:
-                gb = make_gb(one_batch, one_is_local)
-            self.state, loss = self.trainer.train_on_global_batch(
-                self.state, gb
-            )
-            self.last_loss = loss
-            mark_recovered()
-            _phase_timer.step_done()
-            self._maybe_checkpoint()
+        # Second buffering level: the global batch for step k+1 is
+        # assembled — shard transfers issued — on the consumer thread
+        # while step k's collective executes.  The host batch rides along
+        # untouched: _ensure_state wants host arrays.
+        def device_stage(item):
+            staged_batch, staged_real, staged_is_local = item
+            if self.state is None:
+                # init_state_global (first loop iteration) must be
+                # the mesh's FIRST collective program; assembling
+                # global arrays ahead of it breaks the multi-process
+                # CPU backend used in tests.  Nothing to overlap
+                # before step 1 anyway.
+                return (*item, None)
+            return (*item, make_gb(staged_batch, staged_is_local))
 
-        # steps_per_execution grouping: full groups of slice-local
-        # batches dispatch as ONE scan program over a global (K, B, ...)
-        # stack; tails and non-local batches run single-step, so only
-        # two program shapes ever compile.  The decision is identical on
-        # every rank (same batch stream), keeping the collective in step.
-        # The first post-recovery batch always runs single-step so the
-        # recovery clock measures loss -> FIRST optimizer step, not
-        # loss -> K steps.
-        pending = []
-        # Second buffering level (single-step dispatch only): the global
-        # batch for step k+1 is assembled — shard transfers issued — on
-        # the consumer thread while step k's collective executes.  The
-        # host batch rides along untouched: _ensure_state and the
-        # steps_per_execution grouping path want host arrays.
-        device_stage = None
-        if self.steps_per_execution == 1:
-            def device_stage(item):
-                staged_batch, staged_real, staged_is_local = item
-                if self.state is None:
-                    # init_state_global (first loop iteration) must be
-                    # the mesh's FIRST collective program; assembling
-                    # global arrays ahead of it breaks the multi-process
-                    # CPU backend used in tests.  Nothing to overlap
-                    # before step 1 anyway.
-                    return item
-                return (
-                    staged_batch, staged_real, staged_is_local,
-                    make_gb(staged_batch, staged_is_local),
-                )
         # host read/parse overlaps the collective step (double buffering)
         for item in prefetch_batches(
             batches, device_stage=device_stage, phase_timer=_phase_timer
         ):
-            batch, real, is_local = item[:3]
-            gb = item[3] if len(item) > 3 else None
+            batch, real, is_local, gb = item
             self._ensure_state(batch, global_rows=self.minibatch_size)
             records += real
-            if (
-                is_local
-                and self.steps_per_execution > 1
-                and self._recovery_t0 is None
-            ):
-                pending.append(batch)
-                if len(pending) == self.steps_per_execution:
-                    _phase_timer.mark(step=steps_done())
-                    with _phase_timer.phase("h2d_stage"):
-                        stack = (
-                            mesh_lib.make_global_batch_stack_from_local(
-                                pending, self.mesh,
-                                self.minibatch_size, local[0],
-                            )
-                        )
-                    pending = []
-                    self.state, losses = (
-                        self.trainer.train_on_global_batch_stack(
-                            self.state, stack
-                        )
-                    )
-                    self.last_loss = losses[-1]
-                    mark_recovered()
-                    for _ in range(self.steps_per_execution):
-                        _phase_timer.step_done()
-                    self._maybe_checkpoint(
-                        stride=self.steps_per_execution
-                    )
-                continue
-            # preserve data order: a wrap-padded (non-local) tail batch
-            # must not train before still-pending grouped batches
-            for held in pending:
-                single_step(held, True)
-            pending = []
-            single_step(batch, is_local, gb=gb)
-        for batch in pending:  # task tail: single-step program
-            single_step(batch, True)
+            _phase_timer.mark(step=steps_done())
+            if gb is None:
+                gb = make_gb(batch, is_local)
+            self.state, self.last_loss = self.trainer.train_on_global_batch(
+                self.state, gb
+            )
+            mark_recovered()
+            _phase_timer.step_done()
+            self._maybe_checkpoint()
         _phase_timer.mark(step=None)
         _phase_timer.flush()
         if self.last_loss is not None:
-            # the loop's one synchronised stamp a task (see Worker)
-            with _phase_timer.phase("task_sync") as sync:
-                loss_value = float(np.asarray(self.last_loss))
-            self.step_rate.task_synced(sync.end, steps_done())
-            self._summary.scalars(
-                {
-                    "train/loss": loss_value,
-                    "train/steps_per_sec": self.step_rate.steps_per_sec,
-                },
-                step=int(self.state.step),
+            finish_train_task(
+                self.step_rate, self._summary, steps_done(),
+                fetch=lambda: fetch_loss(self.state, self.last_loss),
+                model_step=lambda: int(self.state.step),
             )
         return records
 
@@ -1001,15 +931,13 @@ class SPMDWorker:
         if self._saver is not None and self.state is not None:
             self._saver.save(self.state, force=force)
 
-    def _maybe_checkpoint(self, stride: int = 1) -> None:
-        # crossing check (not exact modulo): a K-step scan dispatch may
-        # jump past a multiple of checkpoint_steps (worker/sync.py has
-        # the same rule).  Deterministic on step, so all ranks enter the
-        # collective save together.
+    def _maybe_checkpoint(self) -> None:
+        # Deterministic on step, so all ranks enter the collective save
+        # together.
         if (
             self._saver is not None
             and self._checkpoint_steps
-            and int(self.state.step) % self._checkpoint_steps < stride
+            and int(self.state.step) % self._checkpoint_steps == 0
         ):
             self._saver.save(self.state)
 

@@ -21,8 +21,10 @@ import threading
 from typing import Optional
 
 import jax
+import numpy as np
 
 from elasticdl_tpu.common.log_utils import get_logger
+from elasticdl_tpu.layers.step_metrics import STEP_METRICS
 
 logger = get_logger(__name__)
 
@@ -64,8 +66,6 @@ class ModelOwner:
             if self.sample_features is None:
                 # one host row, kept for export signatures (SavedModel
                 # needs the feature structure/shapes/dtypes)
-                import numpy as np
-
                 self.sample_features = jax.tree.map(
                     lambda a: np.asarray(a[:1]), batch["features"]
                 )
@@ -117,46 +117,19 @@ class ModelOwner:
             return loss
 
     def fetch_loss(self, loss):
-        """(the loss as a float, {path: float} of the last step's
-        STEP_METRICS): the task's ONE device fetch.  The scalars a model
-        sows there ride in `model_state`, so they cost a task no second
-        sync and a step none at all; a model that sows none takes the
-        plain loss fetch, outside the lock as it always was."""
-        import jax
-        import numpy as np
-
-        from elasticdl_tpu.layers.moe import STEP_METRICS
+        """`fetch_loss` of this owner's state, serialized with its steps.
+        A model that sows no step metrics takes the plain loss fetch,
+        outside the lock as it always was."""
         from elasticdl_tpu.worker.trainer import run_device_serialized
 
-        sown = None
         with self.lock:
-            if self.state is not None:
-                sown = self.state.model_state.get(STEP_METRICS)
-            if sown is not None:
+            if self.state is not None and (
+                STEP_METRICS in self.state.model_state
+            ):
                 # fetched under the lock: the next step donates the
                 # state's buffers
-                loss, sown = run_device_serialized(
-                    lambda: jax.device_get((loss, sown))
-                )
-        if sown is None:
-            return run_device_serialized(
-                lambda: float(np.asarray(loss))
-            ), {}
-        return float(loss), {
-            "/".join(str(getattr(k, "key", k)) for k in path): float(leaf)
-            for path, leaf in jax.tree_util.tree_leaves_with_path(sown)
-        }
-
-    def train_batch_stack(self, batches):
-        """steps_per_execution path: len(batches) steps in one dispatch
-        (Trainer.train_on_batch_stack); returns the per-step losses."""
-        with self.lock:
-            self.ensure_state(batches[0])
-            self.state, losses = self.trainer.train_on_batch_stack(
-                self.state, batches
-            )
-            self._maybe_checkpoint(stride=len(batches))
-            return losses
+                return run_device_serialized(fetch_loss, self.state, loss)
+        return run_device_serialized(fetch_loss, None, loss)
 
     def stage_batch(self, batch):
         """Start batch's host->device transfer (Trainer.stage_batch) and
@@ -188,17 +161,12 @@ class ModelOwner:
         if self.checkpoint_saver is not None:
             self.checkpoint_saver.wait_until_finished()
 
-    def _maybe_checkpoint(self, stride: int = 1) -> None:
-        """Checkpoint when [step-stride, step] crossed a multiple of
-        checkpoint_steps.  `stride` is the number of steps the last
-        dispatch advanced (steps_per_execution): an exact-modulo check
-        would skip every multiple the K-step jump lands past, stretching
-        the cadence to lcm(K, checkpoint_steps)."""
+    def _maybe_checkpoint(self) -> None:
         if (
             self.checkpoint_saver is not None
             and self.checkpoint_steps
             and self.state is not None
-            and int(self.state.step) % self.checkpoint_steps < stride
+            and int(self.state.step) % self.checkpoint_steps == 0
         ):
             self.checkpoint_saver.save(self.state)
 
@@ -230,6 +198,22 @@ class ModelOwner:
             self.trainer.set_mesh(mesh)
             if self.state is not None:
                 self.state = self.trainer.replace_state(self.state)
+
+
+def fetch_loss(state, loss):
+    """(the loss as a float, {path: float} of the last step's
+    STEP_METRICS): a train task's ONE device fetch.  The scalars a model
+    sows there ride in `state.model_state`, so they cost a task no second
+    sync and a step none at all.  The threaded loop calls it through its
+    `ModelOwner`, the SPMD loop, which holds its own state, directly."""
+    sown = None if state is None else state.model_state.get(STEP_METRICS)
+    if sown is None:
+        return float(np.asarray(loss)), {}
+    loss, sown = jax.device_get((loss, sown))
+    return float(loss), {
+        "/".join(str(getattr(k, "key", k)) for k in path): float(leaf)
+        for path, leaf in jax.tree_util.tree_leaves_with_path(sown)
+    }
 
 
 def snapshot_state(state):

@@ -348,8 +348,8 @@ class TaskDataService:
         (`read_records_bulk`) and the zoo a vectorized parser
         (`feed_bulk(buffer, sizes)`), the records move as contiguous
         uint8 buffers, one batch a read — no per-record Python objects on
-        the hot path (at 300K+ examples/s the per-record loop was the
-        host bottleneck, VERDICT r3 weak #2).  Each batch is read,
+        the hot path, where a per-record loop would bound a wide batch's
+        step rate from the host.  Each batch is read,
         packed and yielded before the next is read, so a task's first
         batch is ready after ONE batch's read and the buffer held at any
         moment is one batch's."""

@@ -18,7 +18,6 @@ split along `data`.  bfloat16 compute keeps the MXU fed; params stay f32.
 from __future__ import annotations
 
 import threading
-from functools import partial
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -31,7 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from elasticdl_tpu.common import metrics as metrics_lib
 from elasticdl_tpu.common import programs
 from elasticdl_tpu.common.log_utils import get_logger
-from elasticdl_tpu.layers import moe as moe_layers
+from elasticdl_tpu.layers import step_metrics
 from elasticdl_tpu.layers.arena import fold_quantized_updates
 from elasticdl_tpu.parallel import mesh as mesh_lib
 
@@ -113,7 +112,7 @@ def device_room(mesh) -> int:
 # AUX_LOSS is an auxiliary objective, already scaled where it was sown
 # (MoE load balancing, a multi-token-prediction loss); "intermediates"
 # is flax's own scratch collection.
-_EPHEMERAL = (moe_layers.AUX_LOSS, "intermediates")
+_EPHEMERAL = (step_metrics.AUX_LOSS, "intermediates")
 
 
 def _sown_aux_loss(sown) -> jnp.ndarray:
@@ -139,9 +138,9 @@ def split_variables(variables):
     params = {"params": variables.pop("params")}
     for collection in _EPHEMERAL:
         variables.pop(collection, None)
-    if moe_layers.STEP_METRICS in variables:
-        variables[moe_layers.STEP_METRICS] = jax.tree.map(
-            jnp.zeros_like, variables[moe_layers.STEP_METRICS]
+    if step_metrics.STEP_METRICS in variables:
+        variables[step_metrics.STEP_METRICS] = jax.tree.map(
+            jnp.zeros_like, variables[step_metrics.STEP_METRICS]
         )
     return params, variables
 
@@ -388,7 +387,7 @@ class Trainer:
                 variables, self._cast(features), mutable=mutable, **kwargs
             )
             updates = dict(updates)
-            sown = updates.pop(moe_layers.AUX_LOSS, {})
+            sown = updates.pop(step_metrics.AUX_LOSS, {})
             updates.pop("intermediates", None)
             new_model_state = updates if updates else model_state
             loss = jnp.asarray(
@@ -437,24 +436,10 @@ class Trainer:
             )
             return preds.astype(jnp.float32)
 
-        def train_step_many(state: TrainState, stacked, room=None):
-            # K serially-dependent train steps in ONE dispatched program
-            # (lax.scan over a (K, B, ...) batch stack).  This is
-            # `steps_per_execution`: per-dispatch overhead is paid once
-            # per K steps, and XLA overlaps the scan's iterations'
-            # transfers and compute.
-            return jax.lax.scan(
-                partial(train_step, room=room), state, stacked
-            )
-
         # Shardings: batch split on `data`; XLA inserts the gradient
         # all-reduce from the sharding propagation (no explicit psum).
         self.train_step = programs.registered_jit(
             "worker_train_step", train_step, donate_argnums=(0,),
-            static_argnums=(2,),
-        )
-        self.train_step_many = programs.registered_jit(
-            "worker_train_step_many", train_step_many, donate_argnums=(0,),
             static_argnums=(2,),
         )
         self.eval_step = programs.registered_jit(
@@ -463,18 +448,18 @@ class Trainer:
 
     # ---- host-side helpers --------------------------------------------
 
-    def _train(self, program, state, batch):
-        """Dispatch a train program on a placed `state`; for a model that
+    def _train(self, state, batch):
+        """Dispatch the train step on a placed `state`; for a model that
         takes it, with the device's room beside, read once a mesh.  A
         step planned into that room that the compiler then finds no
         memory for is compiled again with no room, the lean step every
         device ran before the room was read."""
         if not self._takes_room:
-            return program(state, batch)
+            return self.train_step(state, batch)
         if self._room is None:
             self._room = device_room(self.mesh)
         try:
-            return program(state, batch, self._room)
+            return self.train_step(state, batch, self._room)
         except jax.errors.JaxRuntimeError as error:
             if not self._room or "RESOURCE_EXHAUSTED" not in str(error):
                 raise
@@ -484,7 +469,7 @@ class Trainer:
                 str(error).splitlines()[0],
             )
             self._room = 0
-            return program(state, batch, 0)
+            return self.train_step(state, batch, 0)
 
     def stage_batch(self, batch: Dict[str, np.ndarray]):
         """Start `batch`'s host->device transfer NOW; return the placed
@@ -556,90 +541,10 @@ class Trainer:
         # virtual multi-device CPU backend (see _CPU_EXEC_LOCK).
         def _step():
             sharded = mesh_lib.shard_batch(batch, self.mesh)
-            return self._train(self.train_step, state, sharded)
+            return self._train(state, sharded)
 
         state, loss = self._timed("compute", run_device_serialized, _step)
         return state, loss
-
-    def train_on_batch_stack(self, state, batches):
-        """One dispatch covering len(batches) train steps (jitted
-        lax.scan).  Returns (state, losses) with losses shaped (K,).
-        Batches must share shapes (the data service's static-shape
-        contract guarantees it)."""
-        from elasticdl_tpu.data.wire import is_packed_dedup
-
-        mesh_lib.set_current_mesh(self.mesh)
-
-        # Tiered store under steps_per_execution > 1 (ISSUE 18c): the K
-        # steps run as ONE uninterruptible scan, so admissions are
-        # planned once over the UNION of all K batches' rows and applied
-        # before the block — every step sees its rows resident, folds
-        # land once per block.  Eager per-batch plans are rejected: plan
-        # k+1's evictions could reuse a slot batch k still reads, with
-        # no apply point between the fused steps (client/api.py forces
-        # deferred planning for this reason).
-        if any("__store_plan__" in b for b in batches):
-            raise ValueError(
-                "eager per-batch store plans cannot cover a fused "
-                "multi-step block — use TieredStore.enable_deferred_"
-                "prepare() so the raw sparse batches arrive here and "
-                "one union plan covers the whole block"
-            )
-        if any("__store_sparse__" in b for b in batches):
-            pendings = [b.get("__store_sparse__") for b in batches]
-            batches = [
-                {k: v for k, v in b.items() if k != "__store_sparse__"}
-                for b in batches
-            ]
-            if self.tiered_store is not None:
-                if any(p is None for p in pendings):
-                    raise ValueError(
-                        "mixed store-prepared and raw batches in one "
-                        "fused block"
-                    )
-                slots_list, plan = self.tiered_store.prepare_block(
-                    [sparse for sparse, _ranked in pendings]
-                )
-                for b, slots in zip(batches, slots_list):
-                    features = dict(b["features"])
-                    features["slots"] = slots
-                    b["features"] = features
-                state = self.tiered_store.apply_plan(state, plan)
-
-        stacked = self._timed(
-            "pack",
-            lambda: jax.tree.map(lambda *xs: np.stack(xs), *batches),
-        )
-        sharding = mesh_lib.stacked_data_sharding(self.mesh)
-        repl = mesh_lib.replicated(self.mesh)
-
-        def put(x):
-            if is_packed_dedup(x):
-                # only inverse8 is batch-major under the (K, ...) stack;
-                # the side planes replicate (see mesh.shard_batch)
-                return {
-                    k: jax.device_put(
-                        v, sharding if k == "inverse8" else repl
-                    )
-                    for k, v in x.items()
-                }
-            return jax.device_put(x, sharding)
-
-        def _step():
-            placed = jax.tree.map(put, stacked, is_leaf=is_packed_dedup)
-            return self._train(self.train_step_many, state, placed)
-
-        return self._timed("compute", run_device_serialized, _step)
-
-    def train_on_global_batch_stack(self, state, global_stacked):
-        """K-step scan on an already-assembled global (K, B, ...) stack
-        (mesh.make_global_batch_stack_from_local) — the multi-process
-        steps_per_execution hot path.  Returns (state, losses (K,))."""
-        mesh_lib.set_current_mesh(self.mesh)
-        return self._timed(
-            "compute", run_device_serialized,
-            self._train, self.train_step_many, state, global_stacked,
-        )
 
     def train_on_global_batch(self, state, global_batch):
         """Train step on a batch already assembled into global arrays
@@ -647,7 +552,7 @@ class Trainer:
         mesh_lib.set_current_mesh(self.mesh)
         return self._timed(
             "compute", run_device_serialized,
-            self._train, self.train_step, state, global_batch,
+            self._train, state, global_batch,
         )
 
     def predict_on_global_batch(self, state, global_features):

@@ -24,6 +24,7 @@ from elasticdl_tpu.common import metrics as metrics_lib
 from elasticdl_tpu.common import profiler as profiler_lib
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.model_handler import ModelSpec, resolve_wire_format
+from elasticdl_tpu.layers import step_metrics
 from elasticdl_tpu.proto import elasticdl_pb2 as pb
 from elasticdl_tpu.worker.sync import ModelOwner
 from elasticdl_tpu.worker.task_data_service import TaskDataService
@@ -49,124 +50,37 @@ _tasks_counter = metrics_lib.default_registry().counter(
     "tasks processed, by outcome",
     labelnames=("result",),
 )
-# What a routed expert layer sows into STEP_METRICS (layers/moe.py), and
-# what a KDA layer (model_zoo/kimi/kimi_linear.py) and a Mamba-2 layer
-# (model_zoo/granite/granite_hybrid.py) sow there, read once a task with
-# the loss: leaf name -> gauge by layer.
-_moe_gauges = {
-    "ssm_state_kept_ratio": metrics_lib.default_registry().gauge(
-        "worker_ssm_state_kept_ratio",
-        "mean over heads and chunks of exp(sum of log a over a chunk of "
-        "256 tokens) of a state-space layer, last step of the task: the "
-        "share of a state that outlives a chunk (0: the carried path "
-        "does no work at these weights; 1: nothing is ever forgotten)",
-        labelnames=("layer",),
-    ),
-    "kda_decay_mean_ratio": metrics_lib.default_registry().gauge(
-        "worker_kda_decay_mean_ratio",
-        "mean of a KDA layer's per-channel decay exp(g) over tokens, heads "
-        "and channels, last step of the task (0 forgets everything, 1 "
-        "nothing: a decay that collapses is silent in the loss for long)",
-        labelnames=("layer",),
-    ),
-    "kda_beta_mean_ratio": metrics_lib.default_registry().gauge(
-        "worker_kda_beta_mean_ratio",
-        "mean of a KDA layer's write strength sigmoid(x Wb) over tokens "
-        "and heads, last step of the task",
-        labelnames=("layer",),
-    ),
-    "expert_load_imbalance_ratio": metrics_lib.default_registry().gauge(
-        "worker_moe_expert_load_imbalance_ratio",
-        "largest router load over the mean load, over all the router's "
-        "outputs, last step of the task",
-        labelnames=("layer",),
-    ),
-    "routed_here_ratio": metrics_lib.default_registry().gauge(
-        "worker_moe_routed_here_ratio",
-        "routing slots that chose an expert held here / tokens x top_k, "
-        "last step of the task",
-        labelnames=("layer",),
-    ),
-    "live_chunks_ratio": metrics_lib.default_registry().gauge(
-        "worker_moe_live_chunks_ratio",
-        "chunks of the sorted buffer the layer walked / chunks of its "
-        "worst case (layers/moe.py: routed_walk), last step of the task; "
-        "1.0 means the walk saved nothing",
-        labelnames=("layer",),
-    ),
-}
-_moe_dropped = metrics_lib.default_registry().counter(
-    "worker_moe_dropped_tokens_total",
-    "slots routed to a held expert that got no row (sorted dispatch has "
-    "a worst-case buffer: stays 0)",
-)
-# What a gated attention layer sows there (model_zoo/laguna/laguna.py): the
-# mean of its per-head sigmoid output gate.
-_attention_gate = metrics_lib.default_registry().gauge(
-    "worker_attention_gate_mean_ratio",
-    "mean of the attention layer's sigmoid output gate over tokens and "
-    "heads, last step of the task (a gate that closes silences its layer)",
-    labelnames=("layer",),
-)
-# What a gated short convolution sows there (model_zoo/lfm2/lfm2_moe.py):
-# the RMS of the operator's output over its input's.
-_short_conv_out = metrics_lib.default_registry().gauge(
-    "worker_short_conv_out_rms_ratio",
-    "RMS of the conv operator's output over the RMS of its (normed) "
-    "input, last step of the task (gates that close silence the layer)",
-    labelnames=("layer",),
-)
-# What a narrow-row lookup sows there (layers/embedding.py: lookup_rows):
-# the share of the batch's looked-up rows that are distinct, by table.
-_arena_distinct = metrics_lib.default_registry().gauge(
-    "worker_arena_distinct_rows_ratio",
-    "distinct table rows / looked-up rows of the batch (what the "
-    "embedding backward scatters over what it was handed), last step of "
-    "the task",
-    labelnames=("table",),
-)
-# ... and whether that lookup's forward read the table at the distinct
-# rows only (layers/embedding.py: compact_lookup_path) or gathered plainly.
-_arena_compact = metrics_lib.default_registry().gauge(
-    "worker_arena_lookup_compact_ratio",
-    "1 where the table's forward lookup gathered the batch's distinct "
-    "rows and expanded them, 0 where it gathered every looked-up row "
-    "from the table, last step of the task",
-    labelnames=("table",),
-)
-# Step-phase attribution (ISSUE 5) and spans (ISSUE 24): the process's
-# one PhaseTimer, shared by the threaded and SPMD loops.  Module-level
-# for the same __new__ reason as the counters above.
+# Read by benchmarks/tests/test_granite_cell.py
+# (test_the_gauge_reader_reads_what_the_worker_sets), which only a
+# `benchmark` PR may edit: the sown names' table under the name it had
+# here (ROADMAP.md Reach C has its removal).
+_moe_gauges = step_metrics.declared()
+# The process's one PhaseTimer (per-step phase attribution and the span
+# ring), shared by the threaded and SPMD loops.  Module-level for the
+# same __new__ reason as the counters above.
 _phase_timer = profiler_lib.process_phase_timer()
 
 
-def _same_batch_shapes(a, b) -> bool:
-    """True when two host batches have identical leaf shapes/dtypes —
-    the np.stack compatibility the K-step scan program requires.  Only
-    the dedup wire format ever produces ragged consecutive batches
-    (sticky pad-cap growth, data/wire.py DedupPacker).  Store
-    bookkeeping keys are host-side riders the stacked path strips before
-    stacking (trainer.train_on_batch_stack plans the block from them) —
-    their ragged ranked tuples must not veto an otherwise stackable
-    pair."""
-    import jax
-
-    def _strip(batch):
-        if isinstance(batch, dict) and any(
-                k.startswith("__store_") for k in batch):
-            return {
-                k: v for k, v in batch.items()
-                if not k.startswith("__store_")
-            }
-        return batch
-
-    a, b = _strip(a), _strip(b)
-    la, lb = jax.tree.leaves(a), jax.tree.leaves(b)
-    return len(la) == len(lb) and all(
-        np.shape(x) == np.shape(y)
-        and getattr(x, "dtype", None) == getattr(y, "dtype", None)
-        for x, y in zip(la, lb)
-    )
+def finish_train_task(step_rate, summary, steps: int, fetch, model_step):
+    """The tail of a train task, the threaded loop's and the SPMD loop's.
+    One fetch per TASK, not per step: forcing the loss to host every
+    batch would serialize the device pipeline.  `fetch()` is that fetch
+    (worker/sync.py: fetch_loss); the wait in it is the device work that
+    stood behind the host (`task_sync`) and its end is the loop's one
+    synchronised stamp.  What the model's layers sowed goes to the
+    metrics they declared (layers/step_metrics.py) and the rest, with
+    the loss, to the summary at `model_step()`."""
+    with _phase_timer.phase("task_sync") as sync:
+        loss_value, sown = fetch()
+    step_rate.task_synced(sync.end, steps)
+    _steps_gauge.set(step_rate.steps_per_sec)
+    scalars = {
+        "train/loss": loss_value,
+        "train/steps_per_sec": step_rate.steps_per_sec,
+    }
+    for path, value in step_metrics.publish(sown).items():
+        scalars["train/" + path] = value
+    summary.scalars(scalars, step=model_step())
 
 
 def invoke_callbacks(callbacks, hook: str, *args) -> None:
@@ -253,7 +167,6 @@ class Worker:
         model_owner: Optional[ModelOwner] = None,
         tensorboard_dir: str = "",
         profile_dir: str = "",
-        steps_per_execution: int = 1,
         compact_wire: bool = False,
         wire_format: str = "",
     ):
@@ -268,10 +181,6 @@ class Worker:
             spec, wire_format, compact_wire, logger
         )
         self.compact_wire = self.wire_format == "compact"
-        # >1 dispatches that many train steps as ONE jitted lax.scan
-        # program (Trainer.train_on_batch_stack) — amortizes per-dispatch
-        # overhead.
-        self.steps_per_execution = max(1, int(steps_per_execution))
         self._client = master_client
         self._data_service = TaskDataService(
             master_client, data_reader, worker_id
@@ -507,16 +416,14 @@ class Worker:
         records = 0
         steps = 0
         loss = None
-        pending = []
-        # Second buffering level (single-step dispatch only): batch k+1's
-        # host->device transfer is issued while batch k executes
-        # (ModelOwner.stage_batch; device_put is async on real backends).
-        # The stacked path keeps host batches — np.stack wants numpy.
-        device_stage = None
-        if self.steps_per_execution == 1:
-            def device_stage(item):
-                staged_batch, staged_real = item
-                return self._owner.stage_batch(staged_batch), staged_real
+
+        # Second buffering level: batch k+1's host->device transfer is
+        # issued while batch k executes (ModelOwner.stage_batch;
+        # device_put is async on real backends).
+        def device_stage(item):
+            staged_batch, staged_real = item
+            return self._owner.stage_batch(staged_batch), staged_real
+
         # host read/parse overlaps the device step (double buffering)
         for batch, real in prefetch_batches(
             self._data_service.batches_for_task(
@@ -527,41 +434,6 @@ class Worker:
             phase_timer=_phase_timer,
         ):
             records += real
-            if self.steps_per_execution > 1:
-                # full groups dispatch as one scan program; the task's
-                # tail (< steps_per_execution batches) falls through to
-                # the single-step program below, so only the two K values
-                # {1, steps_per_execution} are ever compiled
-                if pending and not _same_batch_shapes(pending[-1], batch):
-                    # dedup sticky caps can grow between batches; a
-                    # mixed-shape group can't np.stack — drain the held
-                    # batches through the single-step program first
-                    for held in pending:
-                        _phase_timer.mark(step=steps)
-                        loss = self._owner.train_batch(held)
-                        _phase_timer.step_done()
-                        steps += 1
-                        self.losses.append(loss)
-                    pending.clear()
-                pending.append(batch)
-                if len(pending) == self.steps_per_execution:
-                    _phase_timer.mark(step=steps)
-                    losses = self._owner.train_batch_stack(pending)
-                    for _ in pending:
-                        _phase_timer.step_done()
-                        steps += 1
-                    pending.clear()
-                    loss = losses[-1]
-                    # per-step history, as documented: the scan returns
-                    # all K losses (one device array; indexing is lazy)
-                    self.losses.extend(losses)
-                continue
-            _phase_timer.mark(step=steps)
-            loss = self._owner.train_batch(batch)
-            _phase_timer.step_done()
-            steps += 1
-            self.losses.append(loss)
-        for batch in pending:
             _phase_timer.mark(step=steps)
             loss = self._owner.train_batch(batch)
             _phase_timer.step_done()
@@ -574,37 +446,13 @@ class Worker:
             # accumulated phase time (the trace exporter reads these)
             _phase_timer.flush()
         if loss is not None:
-            # One fetch per TASK, not per step: forcing the loss to host
-            # every batch would serialize the device pipeline.  The wait
-            # is the device work that stood behind the host (`task_sync`)
-            # and its end is the loop's one synchronised stamp.
-            with _phase_timer.phase("task_sync") as sync:
+            finish_train_task(
+                self.step_rate, self._summary, steps,
                 # serialized: a device->host fetch racing another
                 # thread's step execution corrupts the CPU backend
-                loss_value, sown = self._owner.fetch_loss(loss)
-            self.step_rate.task_synced(sync.end, steps)
-            _steps_gauge.set(self.step_rate.steps_per_sec)
-            scalars = {
-                "train/loss": loss_value,
-                "train/steps_per_sec": self.step_rate.steps_per_sec,
-            }
-            for path, value in sown.items():
-                layer, _, name = path.rpartition("/")
-                if name in _moe_gauges:
-                    _moe_gauges[name].labels(layer=layer).set(value)
-                elif name == "dropped_tokens":
-                    _moe_dropped.inc(value)
-                elif name == "distinct_rows_ratio":
-                    _arena_distinct.labels(table=layer).set(value)
-                elif name == "lookup_compact":
-                    _arena_compact.labels(table=layer).set(value)
-                elif name == "gate_mean":
-                    _attention_gate.labels(layer=layer).set(value)
-                elif name == "out_rms_ratio":
-                    _short_conv_out.labels(layer=layer).set(value)
-                else:
-                    scalars["train/" + path] = value
-            self._summary.scalars(scalars, step=self._owner.step)
+                fetch=lambda: self._owner.fetch_loss(loss),
+                model_step=lambda: self._owner.step,
+            )
         return records
 
     def _evaluate_task(self, task: pb.Task) -> int:

@@ -48,7 +48,7 @@ import jax
 import jax.numpy as jnp
 
 from elasticdl_tpu.layers.embedding import DistributedEmbedding
-from elasticdl_tpu.layers.moe import AUX_LOSS, sow_step_metric
+from elasticdl_tpu.layers.step_metrics import AUX_LOSS, sow_step_metric
 from model_zoo.bert.bert_finetune import feed, feed_bulk  # noqa: F401
 from model_zoo.common.decoder import (  # noqa: F401
     MoEFFN,

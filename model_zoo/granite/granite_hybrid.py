@@ -47,8 +47,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from elasticdl_tpu.common import metrics as metrics_lib
 from elasticdl_tpu.layers.embedding import DistributedEmbedding
-from elasticdl_tpu.layers.moe import sow_step_metric
+from elasticdl_tpu.layers import step_metrics
+from elasticdl_tpu.layers.step_metrics import sow_step_metric
 from elasticdl_tpu.ops import ssd as ssd_ops
 from elasticdl_tpu.ops.flash_attention import causal_attention
 from elasticdl_tpu.ops.short_conv import silu_short_conv
@@ -85,6 +87,21 @@ def conv_bias_init(taps: int):
         return jax.random.uniform(key, shape, dtype, -bound, bound)
 
     return init
+
+
+# What a Mamba-2 layer sows into STEP_METRICS, read once a task with the
+# loss: leaf name -> gauge by layer.
+step_metrics.declare(
+    "ssm_state_kept_ratio",
+    metrics_lib.default_registry().gauge(
+        "worker_ssm_state_kept_ratio",
+        "mean over heads and chunks of exp(sum of log a over a chunk of "
+        "256 tokens) of a state-space layer, last step of the task: the "
+        "share of a state that outlives a chunk (0: the carried path "
+        "does no work at these weights; 1: nothing is ever forgotten)",
+        labelnames=("layer",),
+    ),
+)
 
 
 class Mamba2(nn.Module):

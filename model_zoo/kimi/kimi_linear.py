@@ -44,8 +44,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from elasticdl_tpu.common import metrics as metrics_lib
 from elasticdl_tpu.layers.embedding import DistributedEmbedding
-from elasticdl_tpu.layers.moe import sow_step_metric
+from elasticdl_tpu.layers import step_metrics
+from elasticdl_tpu.layers.step_metrics import sow_step_metric
 from elasticdl_tpu.ops.kda import kda
 from elasticdl_tpu.ops.short_conv import silu_short_conv
 from model_zoo.bert.bert_finetune import feed, feed_bulk  # noqa: F401
@@ -77,6 +79,29 @@ PUBLISHED_KDA_LAYERS = tuple(
     i for i in range(1, 28) if i not in PUBLISHED_FULL_ATTN_LAYERS
 )
 L2_EPS = 1e-6
+
+
+# What a KDA layer sows into STEP_METRICS, read once a task with the
+# loss: leaf name -> gauge by layer.
+step_metrics.declare(
+    "kda_decay_mean_ratio",
+    metrics_lib.default_registry().gauge(
+        "worker_kda_decay_mean_ratio",
+        "mean of a KDA layer's per-channel decay exp(g) over tokens, heads "
+        "and channels, last step of the task (0 forgets everything, 1 "
+        "nothing: a decay that collapses is silent in the loss for long)",
+        labelnames=("layer",),
+    ),
+)
+step_metrics.declare(
+    "kda_beta_mean_ratio",
+    metrics_lib.default_registry().gauge(
+        "worker_kda_beta_mean_ratio",
+        "mean of a KDA layer's write strength sigmoid(x Wb) over tokens "
+        "and heads, last step of the task",
+        labelnames=("layer",),
+    ),
+)
 
 
 class KDA(nn.Module):

@@ -40,8 +40,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from elasticdl_tpu.common import metrics as metrics_lib
 from elasticdl_tpu.layers.embedding import DistributedEmbedding
-from elasticdl_tpu.layers.moe import sow_step_metric
+from elasticdl_tpu.layers import step_metrics
+from elasticdl_tpu.layers.step_metrics import sow_step_metric
 from elasticdl_tpu.ops.flash_attention import causal_attention
 from model_zoo.bert.bert_finetune import feed, feed_bulk  # noqa: F401
 from model_zoo.common.decoder import (  # noqa: F401
@@ -119,6 +121,19 @@ def partial_rotary(x, rope: Rope):
     return jnp.concatenate(
         [rotary_turn(turned, inv_freq, rope.factor), kept], axis=-1
     )
+
+
+# What a gated attention layer sows into STEP_METRICS: the mean of its
+# per-head sigmoid output gate.
+step_metrics.declare(
+    "gate_mean",
+    metrics_lib.default_registry().gauge(
+        "worker_attention_gate_mean_ratio",
+        "mean of the attention layer's sigmoid output gate over tokens and "
+        "heads, last step of the task (a gate that closes silences its layer)",
+        labelnames=("layer",),
+    ),
+)
 
 
 class GatedGroupedAttention(nn.Module):

@@ -42,8 +42,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from elasticdl_tpu.common import metrics as metrics_lib
 from elasticdl_tpu.layers.embedding import DistributedEmbedding
-from elasticdl_tpu.layers.moe import sow_step_metric
+from elasticdl_tpu.layers import step_metrics
+from elasticdl_tpu.layers.step_metrics import sow_step_metric
 from elasticdl_tpu.ops.flash_attention import causal_attention
 from elasticdl_tpu.ops.short_conv import gated_short_conv
 from model_zoo.bert.bert_finetune import feed, feed_bulk  # noqa: F401
@@ -74,6 +76,19 @@ PUBLISHED_LAYER_TYPES = tuple(
 
 def rms(x):
     return jnp.sqrt(jnp.mean(jnp.square(x.astype(jnp.float32))))
+
+
+# What a gated short convolution sows into STEP_METRICS: the RMS of the
+# operator's output over its input's.
+step_metrics.declare(
+    "out_rms_ratio",
+    metrics_lib.default_registry().gauge(
+        "worker_short_conv_out_rms_ratio",
+        "RMS of the conv operator's output over the RMS of its (normed) "
+        "input, last step of the task (gates that close silence the layer)",
+        labelnames=("layer",),
+    ),
+)
 
 
 class GatedShortConv(nn.Module):

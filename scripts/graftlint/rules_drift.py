@@ -11,7 +11,8 @@ markdown table on the docs side:
    runbook.
 2. **Metric catalogue.**  The tables in docs/OBSERVABILITY.md whose
    first header cell is `metric` vs every literal metric-creation name
-   in `elasticdl_tpu/` (the same extraction GL-METRIC validates).
+   in `elasticdl_tpu/` and `model_zoo/` (the same extraction GL-METRIC
+   validates).
    Label suffixes (`{...}`) are stripped; a documented histogram also
    covers its derived `_bucket`/`_count`/`_sum`/quantile series.
    Abbreviated rows (`` `_failed_total` `` shorthand) are themselves
@@ -39,7 +40,10 @@ import re
 from typing import Dict, FrozenSet, Iterable, List, Optional, Tuple
 
 from scripts.graftlint.core import Finding, Project, Rule, register
-from scripts.graftlint.rules_metrics import iter_metric_creations
+from scripts.graftlint.rules_metrics import (
+    METRIC_ROOTS,
+    iter_metric_creations,
+)
 
 RULE_ID = "GL-DRIFT"
 
@@ -198,11 +202,11 @@ def code_slo_names(project: Project) -> Optional[Dict[str, int]]:
 
 
 def code_metrics(project: Project) -> Dict[str, Tuple[str, int, str]]:
-    """{metric name: (rel, lineno, kind)} over every elasticdl_tpu/
-    module in the project."""
+    """{metric name: (rel, lineno, kind)} over every module under
+    METRIC_ROOTS in the project."""
     out: Dict[str, Tuple[str, int, str]] = {}
     for pf in project.files:
-        if not pf.rel.startswith("elasticdl_tpu/") or pf.tree is None:
+        if not pf.rel.startswith(METRIC_ROOTS) or pf.tree is None:
             continue
         for node, method, name in iter_metric_creations(pf.tree):
             if name is not None and name not in out:
@@ -338,7 +342,7 @@ class DriftRule(Rule):
                     yield Finding(
                         OBSERVABILITY_DOC, lineno, self.id,
                         f"catalogues metric {name!r} that no "
-                        "elasticdl_tpu/ module creates",
+                        "elasticdl_tpu/ or model_zoo/ module creates",
                     )
             for name, (rel, lineno, kind) in sorted(inventory.items()):
                 if name in self.allow_undocumented_metrics:

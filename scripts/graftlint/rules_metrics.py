@@ -2,7 +2,7 @@
 counters, closed span-event and policy-decision vocabularies.
 
 Migrated from scripts/check_metric_names.py (now a shim).  Four
-patterns over elasticdl_tpu/:
+patterns over elasticdl_tpu/ and model_zoo/ (METRIC_ROOTS):
 
 1. **Name discipline.**  Every metric-creation call
    (`*.counter(...)`, `*.gauge(...)`, `*.gauge_fn(...)`,
@@ -85,6 +85,11 @@ from elasticdl_tpu.common.metrics import validate_metric_name  # noqa: E402
 RULE_ID = "GL-METRIC"
 
 CREATION_METHODS = {"counter", "gauge", "gauge_fn", "histogram"}
+
+# Where metrics are created: the framework, and the zoo modules that
+# declare the gauge of a value their layers sow
+# (elasticdl_tpu/layers/step_metrics.py).
+METRIC_ROOTS = ("elasticdl_tpu/", "model_zoo/")
 
 # Modules converted to registry-backed counters: shadow-counter rule on.
 INSTRUMENTED = frozenset({
@@ -434,7 +439,7 @@ class MetricRule(Rule):
         self.shadow_allowlist = frozenset(shadow_allowlist)
 
     def applies(self, pf: ParsedFile) -> bool:
-        return pf.rel.startswith("elasticdl_tpu/")
+        return pf.rel.startswith(METRIC_ROOTS)
 
     def check(self, pf: ParsedFile):
         for lineno, message in find_bad_metric_names(pf.tree):

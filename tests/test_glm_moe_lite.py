@@ -20,12 +20,8 @@ import pytest
 from benchmarks import datagen, trees
 from benchmarks.reference import glm_moe_lite as reference
 from elasticdl_tpu.layers import moe
-from elasticdl_tpu.layers.moe import (
-    AUX_LOSS,
-    ROUTER_STATE,
-    STEP_METRICS,
-    RoutedExperts,
-)
+from elasticdl_tpu.layers.moe import ROUTER_STATE, RoutedExperts
+from elasticdl_tpu.layers.step_metrics import AUX_LOSS, STEP_METRICS
 from model_zoo.glm import glm_moe_lite as zoo
 from tests import remat_cases
 

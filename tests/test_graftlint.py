@@ -441,8 +441,9 @@ def test_lock_allowlisted_class_attr():
 
 
 def _drift_project(doc_overrides=None):
+    # the zoo modules declare the gauges of what their layers sow
     return core.build_project(
-        REPO, ["elasticdl_tpu"], doc_overrides=doc_overrides
+        REPO, ["elasticdl_tpu", "model_zoo"], doc_overrides=doc_overrides
     )
 
 

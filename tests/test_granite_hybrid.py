@@ -23,7 +23,8 @@ import pytest
 
 from benchmarks import datagen, trees
 from benchmarks.reference import granite_hybrid as reference
-from elasticdl_tpu.layers.moe import AUX_LOSS, ROUTER_STATE, STEP_METRICS
+from elasticdl_tpu.layers.moe import ROUTER_STATE
+from elasticdl_tpu.layers.step_metrics import AUX_LOSS, STEP_METRICS
 from elasticdl_tpu.ops import short_conv
 from elasticdl_tpu.ops import ssd as ssd_ops
 from model_zoo.granite import granite_hybrid as zoo

@@ -24,12 +24,8 @@ import pytest
 from benchmarks import datagen, trees
 from benchmarks.reference import kimi_linear as reference
 from benchmarks.reference.glm_moe_lite import swiglu
-from elasticdl_tpu.layers.moe import (
-    AUX_LOSS,
-    ROUTER_STATE,
-    STEP_METRICS,
-    RoutedExperts,
-)
+from elasticdl_tpu.layers.moe import ROUTER_STATE, RoutedExperts
+from elasticdl_tpu.layers.step_metrics import AUX_LOSS, STEP_METRICS
 from elasticdl_tpu.ops import kda as kda_ops
 from elasticdl_tpu.ops import short_conv
 from model_zoo.common.decoder import MoEFFN
@@ -409,7 +405,6 @@ def test_the_init_program_drops_the_forward(seeded):
     compiler drops the forward that flax's `init` traced: no product is
     left, where the cell's init compiled a 16,384-token forward, kernels
     and all, for gauges that the first step overwrites."""
-    from elasticdl_tpu.layers.moe import STEP_METRICS
     from elasticdl_tpu.worker.trainer import split_variables
 
     model = model_of(CONFIG)

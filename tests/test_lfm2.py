@@ -22,12 +22,8 @@ import pytest
 
 from benchmarks import datagen, trees
 from benchmarks.reference import lfm2_moe as reference
-from elasticdl_tpu.layers.moe import (
-    AUX_LOSS,
-    ROUTER_STATE,
-    STEP_METRICS,
-    RoutedExperts,
-)
+from elasticdl_tpu.layers.moe import ROUTER_STATE, RoutedExperts
+from elasticdl_tpu.layers.step_metrics import AUX_LOSS, STEP_METRICS
 from elasticdl_tpu.ops import short_conv
 from model_zoo.common.decoder import MoEFFN
 from model_zoo.lfm2 import lfm2_moe as zoo

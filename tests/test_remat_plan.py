@@ -457,18 +457,20 @@ class FakeProgram:
         return state, 0.0
 
 
-def bare_trainer(room):
+def bare_trainer(room, program):
     trainer = trainer_lib.Trainer.__new__(trainer_lib.Trainer)
     trainer._takes_room, trainer._room = True, room
+    trainer.train_step = program
     return trainer
 
 
 def test_a_plan_the_compiler_finds_no_memory_for_falls_back_to_the_lean_step():
-    trainer, program = bare_trainer(3 << 30), FakeProgram()
-    assert trainer._train(program, "state", "batch") == ("state", 0.0)
+    program = FakeProgram()
+    trainer = bare_trainer(3 << 30, program)
+    assert trainer._train("state", "batch") == ("state", 0.0)
     assert program.rooms == [3 << 30, 0] and trainer._room == 0
     # and stays there: the next step asks for no room
-    trainer._train(program, "state", "batch")
+    trainer._train("state", "batch")
     assert program.rooms == [3 << 30, 0, 0]
 
 
@@ -478,18 +480,17 @@ def test_another_failure_of_the_step_is_not_caught():
             raise jax.errors.JaxRuntimeError("INTERNAL: something else")
 
     with pytest.raises(jax.errors.JaxRuntimeError, match="INTERNAL"):
-        bare_trainer(3 << 30)._train(Failing(), "state", "batch")
+        bare_trainer(3 << 30, Failing())._train("state", "batch")
     # nor a lean step's own out-of-memory
     with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE"):
         class Exhausted(FakeProgram):
             def __call__(self, state, batch, room):
                 raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: hbm")
 
-        bare_trainer(0)._train(Exhausted(), "state", "batch")
+        bare_trainer(0, Exhausted())._train("state", "batch")
 
 
 def test_a_model_that_takes_no_room_is_dispatched_as_before():
     trainer = trainer_lib.Trainer.__new__(trainer_lib.Trainer)
-    assert trainer._train(
-        lambda state, batch: (state, batch), "state", "batch"
-    ) == ("state", "batch")
+    trainer.train_step = lambda state, batch: (state, batch)
+    assert trainer._train("state", "batch") == ("state", "batch")

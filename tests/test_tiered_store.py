@@ -1105,167 +1105,3 @@ def test_store_emits_sub_plans_when_mesh_sharded():
     ) == plan.admit_slots.size
     with pytest.raises(ValueError):
         store.set_mesh_shards(5)  # CACHE_ROWS=32 % 5 != 0
-
-
-def test_prepare_block_unions_batches_and_splits_slots():
-    """Fused multi-step planning: one plan covers the union of K
-    batches, per-batch slot arrays keep their shapes, evictions never
-    touch union rows, and every union row is resident afterwards."""
-    store = TieredStore(
-        {"fm_embedding": DIM, "fm_linear": 1}, NUM_FIELDS, 128
-    )
-    store.host.set_backfill(
-        lambda plane, fields, ids: np.repeat(
-            ids.astype(np.float32)[:, None], store.planes[plane], axis=1
-        )
-    )
-    state = _fake_state(cache_rows=128)
-    # warm the cache so the block's union must evict non-union rows
-    for base in (100, 200, 300, 400):
-        sparse = np.arange(NUM_FIELDS, dtype=np.int64)[None, :] + base
-        slots, warm = store.prepare(sparse)
-        state = store.apply_plan(state, warm)
-    batches = [
-        np.arange(NUM_FIELDS, dtype=np.int64)[None, :] + 1000,
-        np.arange(NUM_FIELDS, dtype=np.int64)[None, :] + 1013,
-        np.arange(NUM_FIELDS, dtype=np.int64)[None, :] + 1000,  # repeat
-    ]
-    slots_list, plan = store.prepare_block(batches)
-    assert plan.block_batches == 3
-    assert len(slots_list) == 3
-    for sparse, slots in zip(batches, slots_list):
-        assert slots.shape == sparse.shape
-    # identical batches plan identical slots
-    np.testing.assert_array_equal(slots_list[0], slots_list[2])
-    union_rows = set(
-        np.concatenate(
-            [store.host.lookup(b).reshape(-1) for b in batches]
-        ).tolist()
-    )
-    assert set(plan.evict_rows.tolist()).isdisjoint(union_rows)
-    state = store.apply_plan(state, plan)
-    resident = {int(r) for r in store.cache.row_of if r >= 0}
-    assert union_rows <= resident
-    assert store.stats()["block_plans"] == 1
-
-
-def test_fused_block_k8_matches_flat_stack_bitwise():
-    """ISSUE 18c: a K-step fused block (one lax.scan, ONE union
-    admission plan) must reproduce the flat arena's losses bitwise —
-    the eager-parity contract extended to steps_per_execution > 1."""
-    from elasticdl_tpu.common.model_handler import get_model_spec
-    from elasticdl_tpu.worker.trainer import Trainer
-
-    cap, dim, cache_rows, ids_per_field, batch, k = 1 << 13, 4, 512, 6, 16, 8
-    rng = np.random.RandomState(3)
-    cand = rng.randint(0, 1 << 22, size=(NUM_FIELDS, ids_per_field * 8))
-    cand_rows = hash_rows(
-        np.repeat(np.arange(NUM_FIELDS)[:, None], cand.shape[1], 1),
-        cand, cap,
-    )
-    seen, sel = set(), np.zeros((NUM_FIELDS, ids_per_field), np.int32)
-    for f in range(NUM_FIELDS):
-        picked = 0
-        for j in range(cand.shape[1]):
-            row = int(cand_rows[f, j])
-            if row not in seen:
-                seen.add(row)
-                sel[f, picked] = cand[f, j]
-                picked += 1
-                if picked == ids_per_field:
-                    break
-        assert picked == ids_per_field
-
-    def batch_at(step):
-        brng = np.random.RandomState(4000 + step)
-        pick = brng.randint(0, ids_per_field, (batch, NUM_FIELDS))
-        return {
-            "features": {
-                "dense": brng.rand(batch, 13).astype(np.float32),
-                "sparse": sel[np.arange(NUM_FIELDS)[None, :], pick],
-            },
-            "labels": brng.randint(0, 2, batch).astype(np.int32),
-        }
-
-    def trainer_for(model_def, model_params):
-        spec = get_model_spec("model_zoo", model_def,
-                              model_params=model_params)
-        return Trainer(
-            model=spec.model, optimizer=spec.optimizer, loss_fn=spec.loss,
-            param_sharding_fn=spec.param_sharding,
-        )
-
-    flat_tr = trainer_for(
-        "deepfm.deepfm_functional_api.custom_model",
-        f"vocab_capacity={cap};embed_dim={dim}",
-    )
-    tier_tr = trainer_for(
-        "deepfm.deepfm_tiered.custom_model",
-        f"cache_rows={cache_rows};embed_dim={dim}",
-    )
-    b0 = batch_at(0)
-    flat_state = flat_tr.init_state(jax.random.PRNGKey(0), b0["features"])
-    tier_state = tier_tr.init_state(
-        jax.random.PRNGKey(0),
-        {"dense": b0["features"]["dense"],
-         "slots": np.zeros((batch, NUM_FIELDS), np.int32)},
-    )
-    flat_init = {
-        name: np.array(
-            flat_state.params["params"][name]["embedding"], np.float32
-        )
-        for name in ("fm_embedding", "fm_linear")
-    }
-    store = TieredStore(
-        {"fm_embedding": dim, "fm_linear": 1}, NUM_FIELDS, cache_rows
-    )
-    store.host.set_backfill(
-        lambda plane, fields, ids: flat_init[plane][
-            hash_rows(fields, ids, cap)
-        ]
-    )
-    store.enable_deferred_prepare()
-    tier_tr.tiered_store = store
-
-    batches = [batch_at(s) for s in range(k)]
-    flat_state, flat_losses = flat_tr.train_on_batch_stack(
-        flat_state, batches
-    )
-    tier_state, tier_losses = tier_tr.train_on_batch_stack(
-        tier_state,
-        [store.attach({"features": dict(b["features"]),
-                       "labels": b["labels"]}) for b in batches],
-    )
-    np.testing.assert_array_equal(
-        np.asarray(jax.device_get(flat_losses)),
-        np.asarray(jax.device_get(tier_losses)),
-    )
-    assert store.stats()["block_plans"] == 1
-
-
-def test_stack_rejects_eagerly_planned_store_batches():
-    """A batch that already carries `__store_plan__` cannot join a fused
-    block: its plan assumed per-step admission order."""
-    from elasticdl_tpu.common.model_handler import get_model_spec
-    from elasticdl_tpu.worker.trainer import Trainer
-
-    spec = get_model_spec(
-        "model_zoo", "deepfm.deepfm_tiered.custom_model",
-        model_params="cache_rows=512;embed_dim=4",
-    )
-    tr = Trainer(model=spec.model, optimizer=spec.optimizer,
-                 loss_fn=spec.loss,
-                 param_sharding_fn=spec.param_sharding)
-    store = TieredStore(
-        {"fm_embedding": 4, "fm_linear": 1}, NUM_FIELDS, 512
-    )
-    tr.tiered_store = store
-    sparse = np.arange(NUM_FIELDS, dtype=np.int64)[None, :]
-    b = store.attach({
-        "features": {"dense": np.zeros((1, 13), np.float32),
-                     "sparse": sparse},
-        "labels": np.zeros(1, np.int32),
-    })
-    assert "__store_plan__" in b
-    with pytest.raises(ValueError, match="fused multi-step"):
-        tr.train_on_batch_stack(None, [b, b])
