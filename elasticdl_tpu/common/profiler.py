@@ -75,6 +75,9 @@ DEVICE_SCOPES = (
     "granite/embed", "granite/norm", "granite/ssm/proj", "granite/ssm/conv",
     "granite/ssm/core", "granite/ssm/gated_norm", "granite/ssm/out",
     "granite/attn", "granite/dense_ffn", "granite/head_ce",
+    "nemotron/embed", "nemotron/norm", "nemotron/ssm/proj",
+    "nemotron/ssm/conv", "nemotron/ssm/core", "nemotron/ssm/gated_norm",
+    "nemotron/ssm/out", "nemotron/attn", "nemotron/moe", "nemotron/head_ce",
 )
 
 #: Kernels the TPU's compiler makes from ONE primitive and names after

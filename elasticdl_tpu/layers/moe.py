@@ -32,9 +32,11 @@ the slots routed to held experts are SORTED by expert into one buffer of
 static worst-case size (tokens x top_k rows), the group sizes travel as
 data, and the buffer is WALKED, `CHUNK` rows a trip, only as far as its
 last live row (`routed_walk`): the gather, the grouped products
-(`grouped_matmul`), SwiGLU, the slot weights and the scatter-add all
-cost what the rows routed here cost, rounded up to a chunk, forward and
-backward.  What still follows the worst case is index arithmetic: the
+(`grouped_matmul`), the expert's activation (its form, `FORMS`, is an
+argument of the walk: gated SwiGLU over a fused gate-and-up stack, or a
+squared ReLU over an up stack alone), the slot weights and the scatter-add
+all cost what the rows routed here cost, rounded up to a chunk, forward
+and backward.  What still follows the worst case is index arithmetic: the
 sort, one int32 / float32 entry a slot, and the backward's four zeroed
 buffers.  No token is dropped at any imbalance and nothing recompiles
 when the loads change.  Both layers count router load with
@@ -43,6 +45,7 @@ when the loads change.  Both layers count router load with
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import flax.linen as nn
@@ -145,6 +148,20 @@ def _swiglu(gate_up):
     return nn.silu(gate) * up
 
 
+def _relu2(up):
+    return jnp.square(nn.relu(up))
+
+
+# An expert is (first stack, activation, second stack).  Its form names the
+# activation: -> (the first stack's leaf, that stack's width in `ffn_dim`s,
+# the activation from its output to the second stack's input).
+SWIGLU, RELU2 = "swiglu", "relu2"
+FORMS = {
+    SWIGLU: ("expert_w_gate_up", 2, _swiglu),   # (silu(x Wg) * (x Wu)) Wd
+    RELU2: ("expert_w_up", 1, _relu2),          # (relu(x Wu))^2 Wd: no gate
+}
+
+
 def _chunks(slots: int):
     """(rows a trip, trips over the whole worst-case buffer)."""
     chunk = min(CHUNK, slots)
@@ -157,14 +174,17 @@ def _trips(rows, chunk: int):
 
 
 def walk_bytes(
-    tokens: int, hidden: int, top_k: int, ffn_dim: int, itemsize: int
+    tokens: int, hidden: int, top_k: int, ffn_dim: int, itemsize: int,
+    form: str = SWIGLU,
 ) -> int:
     """What `routed_walk`'s backward holds at once, from its own shapes:
-    the four (slots, width) buffers it fills for the stacks' gradients,
-    three float32 (tokens, hidden) sums (the forward's, its cotangent,
-    d_tokens) and one chunk's rows in flight, values and gradients."""
+    the four (slots, width) buffers it fills for the stacks' gradients
+    (the rows, the first stack's output's gradient at the form's width,
+    the activation, the output's gradient), three float32 (tokens, hidden)
+    sums (the forward's, its cotangent, d_tokens) and one chunk's rows in
+    flight, values and gradients."""
     chunk, total = _chunks(tokens * top_k)
-    widths = 2 * hidden + 3 * ffn_dim
+    widths = 2 * hidden + (FORMS[form][1] + 1) * ffn_dim
     return (
         (total + 2) * chunk * widths * itemsize + 3 * tokens * hidden * 4
     )
@@ -183,11 +203,14 @@ def _chunk_of(c, chunk, top_k, order, weights, group_sizes):
     )
 
 
-@jax.custom_vjp
-def routed_walk(tokens, w_gate_up, w_down, order, weights, group_sizes):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def routed_walk(tokens, w_first, w_down, order, weights, group_sizes,
+                form=SWIGLU):
     """sum over the sorted buffer's rows r < sum(group_sizes) of
-    weights[order[r]] * SwiGLU_{group of r}(tokens[order[r] // top_k]),
-    scattered to that token: (n, hidden) float32.
+    weights[order[r]] * Expert_{group of r}(tokens[order[r] // top_k]),
+    scattered to that token: (n, hidden) float32; an expert is act(x
+    w_first) w_down, `form` naming the activation and with it the first
+    stack's width (`FORMS`).
 
     The buffer (`order`: the routing slots, token-major, sorted by group,
     the slots of no group last; `weights`: one a slot, unsorted) is walked
@@ -203,10 +226,11 @@ def routed_walk(tokens, w_gate_up, w_down, order, weights, group_sizes):
     zero where no trip went; the two stack gradients are ONE ragged
     product each after the walk, whose cost follows the rows.
     """
-    return _walk(tokens, w_gate_up, w_down, order, weights, group_sizes)
+    return _walk(tokens, w_first, w_down, order, weights, group_sizes, form)
 
 
-def _walk(tokens, w_gate_up, w_down, order, weights, group_sizes):
+def _walk(tokens, w_first, w_down, order, weights, group_sizes, form):
+    activation = FORMS[form][2]
     slots = order.shape[0]
     top_k = slots // tokens.shape[0]
     chunk, total = _chunks(slots)
@@ -221,7 +245,7 @@ def _walk(tokens, w_gate_up, w_down, order, weights, group_sizes):
             rows = tokens.at[at].get(mode=_PIB)
         with jax.named_scope("experts"):
             expert_out = grouped_matmul(
-                _swiglu(grouped_matmul(rows, w_gate_up, sizes)),
+                activation(grouped_matmul(rows, w_first, sizes)),
                 w_down, sizes,
             )
         with jax.named_scope("combine"):
@@ -238,11 +262,12 @@ def _walk(tokens, w_gate_up, w_down, order, weights, group_sizes):
 
 
 def _walk_fwd(*args):
-    return _walk(*args), args
+    return _walk(*args), args[:6]
 
 
-def _walk_bwd(args, g):
-    tokens, w_gate_up, w_down, order, weights, group_sizes = args
+def _walk_bwd(form, args, g):
+    tokens, w_first, w_down, order, weights, group_sizes = args
+    activation = FORMS[form][2]
     slots = order.shape[0]
     top_k = slots // tokens.shape[0]
     chunk, total = _chunks(slots)
@@ -255,8 +280,8 @@ def _walk_bwd(args, g):
     # transposed once, outside the loop (inside it they are two copies of
     # the stacks a trip)
     with jax.named_scope("experts"):
-        w_gate_up_t, w_down_t = (
-            jnp.swapaxes(w, 1, 2) for w in (w_gate_up, w_down)
+        w_first_t, w_down_t = (
+            jnp.swapaxes(w, 1, 2) for w in (w_first, w_down)
         )
 
     def trip(c, carry):
@@ -270,14 +295,14 @@ def _walk_bwd(args, g):
         with jax.named_scope("experts"):
             # a product's transpose to its rows is the product with the
             # stack transposed, under the same masks
-            gate_up = grouped_matmul(rows, w_gate_up, sizes)
-            act, pull_gate_up = jax.vjp(_swiglu, gate_up)
+            first = grouped_matmul(rows, w_first, sizes)
+            act, pull_first = jax.vjp(activation, first)
             expert_out = grouped_matmul(act, w_down, sizes)
             d_out = (g_rows * weight).astype(dtype)
-            (d_gate_up,) = pull_gate_up(
+            (d_first,) = pull_first(
                 grouped_matmul(d_out, w_down_t, sizes)
             )
-            d_rows = grouped_matmul(d_gate_up, w_gate_up_t, sizes)
+            d_rows = grouped_matmul(d_first, w_first_t, sizes)
         with jax.named_scope("combine"):
             d_tokens = d_tokens.at[at].add(
                 d_rows.astype(jnp.float32), mode=_PIB
@@ -289,7 +314,7 @@ def _walk_bwd(args, g):
             saved = tuple(
                 lax.dynamic_update_slice(buffer, part, (c * chunk, 0))
                 for buffer, part in zip(
-                    saved, (rows, d_gate_up, act, d_out)
+                    saved, (rows, d_first, act, d_out)
                 )
             )
         return d_tokens, d_weights, saved
@@ -298,29 +323,30 @@ def _walk_bwd(args, g):
     # the loop itself is `combine`'s: its carries are the sums and the
     # buffers it fills
     with jax.named_scope("combine"):
-        d_tokens, d_weights, (rows, d_gate_up, act, d_out) = lax.fori_loop(
+        d_tokens, d_weights, (rows, d_first, act, d_out) = lax.fori_loop(
             0, _trips(group_sizes.sum(), chunk), trip,
             (
                 jnp.zeros(tokens.shape, jnp.float32),
                 jnp.zeros(weights.shape, jnp.float32),
                 tuple(
                     jnp.zeros((total * chunk, width), dtype) for width in
-                    (tokens.shape[1], 2 * ffn, ffn, tokens.shape[1])
+                    (tokens.shape[1], w_first.shape[2], ffn,
+                     tokens.shape[1])
                 ),
             ),
         )
     with jax.named_scope("experts"):
         # rows past the last group are zero in all four buffers, so the
         # plain product needs none of `grouped_matmul`'s masks
-        (d_w_gate_up,) = jax.vjp(
-            lambda w: lax.ragged_dot(rows, w, group_sizes), w_gate_up
-        )[1](d_gate_up)
+        (d_w_first,) = jax.vjp(
+            lambda w: lax.ragged_dot(rows, w, group_sizes), w_first
+        )[1](d_first)
         (d_w_down,) = jax.vjp(
             lambda w: lax.ragged_dot(act, w, group_sizes), w_down
         )[1](d_out)
     with jax.named_scope("combine"):
         d_tokens = d_tokens.astype(dtype)
-    return d_tokens, d_w_gate_up, d_w_down, None, d_weights, None
+    return d_tokens, d_w_first, d_w_down, None, d_weights, None
 
 
 routed_walk.defvjp(_walk_fwd, _walk_bwd)
@@ -427,13 +453,13 @@ class MoEMLP(nn.Module):
 
 
 class RoutedExperts(nn.Module):
-    """Sigmoid top-k routed SwiGLU experts, this holder's part:
-    (..., hidden) -> (..., hidden) in float32.
+    """Sigmoid top-k routed experts, this holder's part: (..., hidden)
+    -> (..., hidden) in float32.
 
         s = sigmoid(x Wr)                     float32, all `num_experts`
         S = top_k(s + b)                      b selects, never weighs
         w_i = routed_scaling * s_i / (sum_{j in S} s_j + renorm_eps)
-        out = sum_{i in S, i held here} w_i SwiGLU_i(x)
+        out = sum_{i in S, i held here} w_i Expert_i(x)
 
     num_experts:      the router's width (every expert of the layer)
     held_experts:     (first, count) of the experts whose weights live
@@ -445,9 +471,12 @@ class RoutedExperts(nn.Module):
                       train step, in ROUTER_STATE; 0 keeps b as it is
     renorm_eps:       added to the renormalisation's denominator (1e-6 in
                       `lfm2_moe`); 0.0 adds nothing to the program
+    form:             an expert's activation (`FORMS`): `swiglu`, (silu(x
+                      Wg) * (x Wu)) Wd, or `relu2`, (relu(x Wu))^2 Wd
 
-    Expert stacks are `expert_w_gate_up` (gate and up fused) and
-    `expert_w_down`, no biases; `moe_param_sharding` shards them.
+    Expert stacks are the form's first stack (`expert_w_gate_up`, gate
+    and up fused, or `expert_w_up`) and `expert_w_down`, no biases;
+    `moe_param_sharding` shards them.
 
     Cost: the router, top-k and sort over all tokens x top_k slots, then
     `routed_walk` over ceil(rows routed here / CHUNK) chunks; it sows
@@ -463,10 +492,12 @@ class RoutedExperts(nn.Module):
     bias_update_rate: float = 0.0
     dtype: jnp.dtype = jnp.float32
     renorm_eps: float = 0.0
+    form: str = SWIGLU
 
     @nn.compact
     def __call__(self, x):
         *lead, hidden = x.shape
+        first_name, first_width, _ = FORMS[self.form]
         with jax.named_scope("dispatch"):
             tokens = x.reshape(-1, hidden)
         n, k = tokens.shape[0], self.top_k
@@ -510,9 +541,9 @@ class RoutedExperts(nn.Module):
             group_sizes = loads[first:first + count].astype(jnp.int32)
             rows = group_sizes.sum()
 
-        w_gate_up = self.param(
-            "expert_w_gate_up", nn.initializers.lecun_normal(),
-            (count, hidden, 2 * self.ffn_dim), jnp.float32,
+        w_first = self.param(
+            first_name, nn.initializers.lecun_normal(),
+            (count, hidden, first_width * self.ffn_dim), jnp.float32,
         )
         w_down = self.param(
             "expert_w_down", nn.initializers.lecun_normal(),
@@ -522,11 +553,11 @@ class RoutedExperts(nn.Module):
             tokens = tokens.astype(self.dtype)
         with jax.named_scope("experts"):
             # the stacks' casts are the experts' cost
-            w_gate_up = w_gate_up.astype(self.dtype)
+            w_first = w_first.astype(self.dtype)
             w_down = w_down.astype(self.dtype)
         out = routed_walk(
-            tokens, w_gate_up, w_down, order, weights.reshape(-1),
-            group_sizes,
+            tokens, w_first, w_down, order, weights.reshape(-1),
+            group_sizes, self.form,
         )
         chunk, total = _chunks(n * k)
 

@@ -30,13 +30,17 @@ whose q and k are a head's own, this is one masked product a head and
 three products ALL heads share an operand of.
 
 Kernel shape: the grid walks (batch, chunk, lane tile), the chunk axis
-sequential and the lane tiles inside it, so that C B^T is formed ONCE a
-chunk (at its first tiles, into scratch) and dB and dC, which are sums
-over the heads of a group, are summed in scratch over the chunk's tiles
-and written at its last.  Operands stay (B, L, H*P) (the free view of the
-model's layout); a program takes `_TILES` lane tiles of them (two heads of
-64 a tile), each head's masked product taken against its whole tile and
-kept in its own lanes.  The tiles as a grid axis, not one loop in the
+sequential and the lane tiles inside it, a GROUP's tiles in a row, so that
+C B^T is formed ONCE a chunk and group (at the group's first tiles, into
+scratch) and dB and dC, which are sums over the heads of a group, are
+summed in scratch over the group's tiles and written at its last into the
+group's columns.  Operands stay (B, L, H*P) and (B, L, G*N) (the free
+views of the model's layout); a program takes `_TILES` lane tiles of them
+(two heads of 64 a tile) and its group's N columns of B and C, each head's
+masked product taken against its whole tile and kept in its own lanes.
+The tiles a program takes must divide the tiles of a group
+(`ssd_shapes_ok`): eight groups of eight heads of 64 are four tiles each,
+and grid step t IS group t.  The tiles as a grid axis, not one loop in the
 body, keep the kernel's code small (all 32 unrolled were 52 MB of the
 step's executable, a quarter of the machine's compile cache).  The states
 of all heads live in one (tiles, 128, N) float32 scratch, and the forward
@@ -85,15 +89,21 @@ SAVED_NAMES = ()
 
 def ssd_shapes_ok(x_shape, b_shape, chunk: int = CHUNK) -> bool:
     """Whether the kernels take x (B, L, H, P) under B and C (B, L, G, N):
-    one group, heads that fill whole lane tiles (a head half a tile or a
-    whole one), state columns of whole lane tiles, whole chunks."""
+    heads that fill whole lane tiles (a head half a tile or a whole one),
+    groups whose heads fill whole grid steps (the lane tiles a step takes
+    divide the tiles of a group), state columns of whole lane tiles, whole
+    chunks."""
     if len(x_shape) != 4 or len(b_shape) != 4:
         return False
     _, length, heads, dim = x_shape
+    groups = b_shape[2]
+    if groups < 1 or heads % groups or (heads * dim) % _LANES:
+        return False
+    step = _tiles_a_step(heads * dim // _LANES) * _LANES
     return (
-        b_shape[2] == 1 and tuple(b_shape[:2]) == tuple(x_shape[:2])
+        tuple(b_shape[:2]) == tuple(x_shape[:2])
         and dim <= _LANES and _LANES % dim == 0 and dim % 8 == 0
-        and (heads * dim) % _LANES == 0
+        and (heads // groups * dim) % step == 0
         and b_shape[3] % _LANES == 0
         and chunk % _LANES == 0 and length % chunk == 0
     )
@@ -254,11 +264,19 @@ def _decay(down, across):
     return jnp.exp(jnp.minimum(down - across, 0.0))
 
 
+def _place(per: int, steps: int):
+    """The grid step's place among the `per` steps of its group of heads
+    (the step itself where one group has all `steps`)."""
+    step = pl.program_id(2)
+    return step if per == steps else step % per
+
+
 def _fwd_kernel(x_ref, dt_ref, g_ref, gt_ref, b_ref, c_ref, d_ref,
-                y_ref, states_ref, state_sc, cb_sc, *, dim: int, group: int):
+                y_ref, states_ref, state_sc, cb_sc, *, dim: int, group: int,
+                per: int, steps: int):
     dtype = x_ref.dtype
 
-    @pl.when(pl.program_id(2) == 0)
+    @pl.when(_place(per, steps) == 0)
     def _():
         cb_sc[...] = _masked_product(c_ref, b_ref, dtype)
 
@@ -296,17 +314,25 @@ def _fwd_kernel(x_ref, dt_ref, g_ref, gt_ref, b_ref, c_ref, d_ref,
 def _bwd_kernel(x_ref, dt_ref, g_ref, gt_ref, b_ref, c_ref, d_ref,
                 states_ref, dy_ref, dx_ref, ddt_ref, dg_ref, dgt_ref,
                 db_ref, dc_ref, dd_ref, d_state_sc, cb_sc, d_cb_sc, db_sc,
-                dc_sc, *, dim: int, group: int, steps: int):
+                dc_sc, *, dim: int, group: int, per: int, steps: int):
     dtype = x_ref.dtype
 
-    @pl.when(pl.program_id(2) == 0)
+    def chunk_starts():
+        # all heads' a chunk: summed over every step of it
+        ddt_ref[...] = jnp.zeros(ddt_ref.shape, jnp.float32)
+        dg_ref[...] = jnp.zeros(dg_ref.shape, jnp.float32)
+
+    @pl.when(_place(per, steps) == 0)
     def _():
         cb_sc[...] = _masked_product(c_ref, b_ref, dtype)
         d_cb_sc[...] = jnp.zeros(d_cb_sc.shape, jnp.float32)
         db_sc[...] = jnp.zeros(db_sc.shape, jnp.float32)
         dc_sc[...] = jnp.zeros(dc_sc.shape, jnp.float32)
-        ddt_ref[...] = jnp.zeros(ddt_ref.shape, jnp.float32)
-        dg_ref[...] = jnp.zeros(dg_ref.shape, jnp.float32)
+        if per == steps:
+            chunk_starts()
+
+    if per != steps:
+        pl.when(pl.program_id(2) == 0)(chunk_starts)
 
     size = x_ref.shape[1]
     tile = _Tile(dim, size)
@@ -384,7 +410,7 @@ def _bwd_kernel(x_ref, dt_ref, g_ref, gt_ref, b_ref, c_ref, d_ref,
         )
         dd_ref[0, 0, :, lanes] = (dy * x).sum(axis=0, keepdims=True)
 
-    @pl.when(pl.program_id(2) == steps - 1)
+    @pl.when(_place(per, steps) == per - 1)
     def _():
         # the decay is 1 above the diagonal, where C B^T is not read
         total = d_cb_sc[...]
@@ -428,12 +454,13 @@ def _tiles_a_step(tiles: int) -> int:
 
 
 def _specs(chunks: int, chunk: int, heads: int, columns: int, wide: int,
-           reverse: bool):
-    """Block specs of a grid (batch, chunk step, group of lane tiles) by
-    role, `wide` the group's lanes: a chunk of the group's lanes of a (B,
-    L, H*P) operand, of the whole of a (B,
-    L, N) one, of ALL heads' scalars with tokens down (B, L, H) and across
-    (B, H, L) (they stay in place over a chunk's tiles, which pick their
+           per: int, steps: int, reverse: bool):
+    """Block specs of a grid (batch, chunk step, step of lane tiles) by
+    role, `wide` the step's lanes: a chunk of the step's lanes of a (B,
+    L, H*P) operand, of its group's N columns of a (B, L, G*N) one (a
+    group has `per` of the `steps`; one group has them all), of ALL
+    heads' scalars with tokens down (B, L, H) and across (B, H, L) (they
+    stay in place over a chunk's tiles, which pick their
     heads' columns and rows), the tile's rows of the (B, chunks, H*P, N)
     states, its lanes of the (1, H*P) skip and of the (B, chunks, 1, H*P)
     partial of its gradient; `reverse` walks the chunks from the last."""
@@ -443,7 +470,8 @@ def _specs(chunks: int, chunk: int, heads: int, columns: int, wide: int,
     return dict(
         lanes=pl.BlockSpec((1, chunk, wide), lambda b, n, t: (b, at(n), t)),
         shared=pl.BlockSpec(
-            (1, chunk, columns), lambda b, n, t: (b, at(n), 0)
+            (1, chunk, columns),
+            lambda b, n, t: (b, at(n), 0 if per == steps else t // per),
         ),
         down=pl.BlockSpec((1, chunk, heads), lambda b, n, t: (b, at(n), 0)),
         across=pl.BlockSpec((1, heads, chunk), lambda b, n, t: (b, 0, at(n))),
@@ -461,17 +489,22 @@ def _specs(chunks: int, chunk: int, heads: int, columns: int, wide: int,
 # callable is built once a shape, so jax traces the kernel's body once a
 # process and not once a layer and pass (`ops/kda.py: _forward_call`).
 @functools.lru_cache(maxsize=None)
-def _forward_call(batch, length, heads, dim, columns, chunk, dtype, vma,
-                  interpret):
+def _forward_call(batch, length, heads, dim, columns, groups, chunk, dtype,
+                  vma, interpret):
     chunks, width = length // chunk, heads * dim
     tiles = width // _LANES
     group = _tiles_a_step(tiles)
+    steps = tiles // group
+    per = steps // groups
     spec = _specs(
-        chunks, chunk, heads, columns, group * _LANES, reverse=False
+        chunks, chunk, heads, columns, group * _LANES, per, steps,
+        reverse=False,
     )
     return _call(
-        functools.partial(_fwd_kernel, dim=dim, group=group),
-        (batch, chunks, tiles // group),
+        functools.partial(
+            _fwd_kernel, dim=dim, group=group, per=per, steps=steps
+        ),
+        (batch, chunks, steps),
         [spec["lanes"], spec["down"], spec["down"], spec["across"],
          spec["shared"], spec["shared"], spec["skip"]],
         [spec["lanes"], spec["states"]],
@@ -484,20 +517,23 @@ def _forward_call(batch, length, heads, dim, columns, chunk, dtype, vma,
 
 
 @functools.lru_cache(maxsize=None)
-def _backward_call(batch, length, heads, dim, columns, chunk, dtypes, vma,
-                   interpret):
+def _backward_call(batch, length, heads, dim, columns, groups, chunk, dtypes,
+                   vma, interpret):
     chunks, width = length // chunk, heads * dim
     tiles = width // _LANES
     group = _tiles_a_step(tiles)
+    steps = tiles // group
+    per = steps // groups
     spec = _specs(
-        chunks, chunk, heads, columns, group * _LANES, reverse=True
+        chunks, chunk, heads, columns, group * _LANES, per, steps,
+        reverse=True,
     )
     down = ((batch, length, heads), jnp.float32)
     return _call(
         functools.partial(
-            _bwd_kernel, dim=dim, group=group, steps=tiles // group
+            _bwd_kernel, dim=dim, group=group, per=per, steps=steps
         ),
-        (batch, chunks, tiles // group),
+        (batch, chunks, steps),
         [spec["lanes"], spec["down"], spec["down"], spec["across"],
          spec["shared"], spec["shared"], spec["skip"], spec["states"],
          spec["lanes"]],
@@ -505,8 +541,8 @@ def _backward_call(batch, length, heads, dim, columns, chunk, dtypes, vma,
          spec["shared"], spec["shared"], spec["partial"]],
         [((batch, length, width), dtypes[0]), down, down,
          ((batch, heads, length), jnp.float32),
-         ((batch, length, columns), dtypes[1]),
-         ((batch, length, columns), dtypes[2]),
+         ((batch, length, groups * columns), dtypes[1]),
+         ((batch, length, groups * columns), dtypes[2]),
          ((batch, chunks, 1, width), jnp.float32)],
         [pltpu.VMEM((tiles, _LANES, columns), jnp.float32),
          pltpu.VMEM((chunk, chunk), jnp.float32),
@@ -525,12 +561,26 @@ def _running_sums(dt, A, chunk: int):
     ).reshape(batch, length, heads)
 
 
+def _by_column(t):
+    """B or C (B, L, G, N) as the kernels read it, (B, L, G*N): a free
+    view (of one group the slice it was, so that a one-group call traces
+    to what it traced to before groups entered)."""
+    if t.shape[2] == 1:
+        return t[:, :, 0]
+    return t.reshape(*t.shape[:2], -1)
+
+
+def _by_group(t, shape):
+    """`_by_column`'s way back."""
+    return t[:, :, None] if shape[2] == 1 else t.reshape(shape)
+
+
 def _operands(x, dt, A, B, C, D, chunk):
     batch, length, heads, dim = x.shape
     G = _running_sums(dt, A, chunk)
     return [
         x.reshape(batch, length, heads * dim), dt, G, G.transpose(0, 2, 1),
-        B[:, :, 0], C[:, :, 0], jnp.repeat(D, dim)[None],
+        _by_column(B), _by_column(C), jnp.repeat(D, dim)[None],
     ]
 
 
@@ -543,8 +593,8 @@ def _ssd_fwd(x, dt, A, B, C, D, chunk):
     batch, length, heads, dim = x.shape
     operands = _operands(x, dt, A, B, C, D, chunk)
     out, boundary = _forward_call(
-        batch, length, heads, dim, B.shape[3], chunk, jnp.dtype(x.dtype),
-        _vma(operands), use_interpret(),
+        batch, length, heads, dim, B.shape[3], B.shape[2], chunk,
+        jnp.dtype(x.dtype), _vma(operands), use_interpret(),
     )(*operands)
     return out.reshape(x.shape), (x, dt, A, B, C, D, boundary)
 
@@ -557,7 +607,7 @@ def _ssd_bwd(chunk, residuals, d_out):
         d_out.astype(x.dtype).reshape(batch, length, heads * dim),
     ]
     dx, ddt, dG, dG_t, dB, dC, dD = _backward_call(
-        batch, length, heads, dim, B.shape[3], chunk,
+        batch, length, heads, dim, B.shape[3], B.shape[2], chunk,
         tuple(jnp.dtype(t.dtype) for t in (x, B, C)), _vma(operands),
         use_interpret(),
     )(*operands)
@@ -572,7 +622,7 @@ def _ssd_bwd(chunk, residuals, d_out):
     return (
         dx.reshape(x.shape), ddt + A * d_log,
         (d_log * dt).sum(axis=(0, 1)).astype(A.dtype),
-        dB[:, :, None], dC[:, :, None],
+        _by_group(dB, B.shape), _by_group(dC, C.shape),
         dD.reshape(-1, heads, dim).sum(axis=(0, 2)).astype(D.dtype),
     )
 

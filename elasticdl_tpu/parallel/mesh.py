@@ -232,18 +232,34 @@ def make_global_batch_from_local(
     return jax.tree.map(to_global, batch)
 
 
+# What a tiered store's feed hangs on a batch (store/tiered.py: attach):
+# an admission plan, or in deferred mode the raw sparse batch with its
+# ranking.  Host bookkeeping, not rows of the batch: `pad_to_multiple`
+# carries them around the pad as `Trainer.stage_batch` does around the
+# shard.
+STORE_KEYS = ("__store_plan__", "__store_sparse__")
+
+
 def pad_to_multiple(batch: Dict[str, np.ndarray], multiple: int):
     """Pad batch leading dim up to a multiple (wrapping existing rows) so
     shapes stay static under jit; returns (padded_batch, real_count)."""
+    carried = {k: batch[k] for k in STORE_KEYS if k in batch}
+    if carried:
+        batch = {k: v for k, v in batch.items() if k not in carried}
     sizes = {x.shape[0] for x in jax.tree.leaves(batch)}
     assert len(sizes) == 1, "ragged batch"
     n = sizes.pop()
     if n % multiple == 0:
-        return batch, n
+        return {**batch, **carried} if carried else batch, n
     target = ((n + multiple - 1) // multiple) * multiple
     reps = (target + n - 1) // n
 
     def pad(x):
         return np.concatenate([x] * reps, axis=0)[:target]
 
-    return jax.tree.map(pad, batch), n
+    if "__store_sparse__" in carried:
+        # the wrapped rows repeat ids, so the feed's ranking no longer
+        # counts the batch: the trainer's `prepare` ranks it again
+        sparse, _ = carried["__store_sparse__"]
+        carried["__store_sparse__"] = (pad(np.asarray(sparse)), None)
+    return {**jax.tree.map(pad, batch), **carried}, n

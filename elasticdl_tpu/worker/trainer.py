@@ -491,7 +491,7 @@ class Trainer:
         # as a leaf and try to device_put it), reattach on a copy after.
         carried = {
             k: batch[k]
-            for k in ("__store_plan__", "__store_sparse__")
+            for k in mesh_lib.STORE_KEYS
             if k in batch
         }
         if carried:

@@ -1,7 +1,11 @@
-"""What the zoo's decoder language models have in common (`model_zoo/glm/
-glm_moe_lite.py`, `model_zoo/laguna/laguna.py`): RMSNorm and its gated form, rotary's turn,
-the seeds of a decay (`a_log_init`, `dt_bias_init`), the bias-free dense
-layer and SwiGLU, the routed block around
+"""What the zoo's six decoder language models have in common
+(`model_zoo/glm/glm_moe_lite.py`, `laguna/laguna.py`, `lfm2/lfm2_moe.py`,
+`kimi/kimi_linear.py`, `granite/granite_hybrid.py`,
+`nemotron/nemotron_h.py`): RMSNorm and its gated form (one statistic a
+group of channels), rotary's turn, the seeds of a decay (`a_log_init`,
+`dt_bias_init`), the bias-free dense layer, SwiGLU and the non-gated
+squared-ReLU MLP, grouped-query attention without positions
+(`GroupedAttention`), the routed block around
 `layers/moe.py: RoutedExperts` with its shared expert, the cross-entropy
 taken in blocks of tokens, the per-position losses against the ids
 shifted, the blocks' rematerialisation (`remat_block`, and what more of
@@ -23,6 +27,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from elasticdl_tpu.layers.embedding import embedding_param_sharding
 from elasticdl_tpu.layers.moe import (
+    RELU2,
+    SWIGLU,
     RoutedExperts,
     moe_param_sharding,
     walk_bytes,
@@ -57,18 +63,26 @@ class RMSNorm(nn.Module):
 
 
 class GatedRMSNorm(nn.Module):
-    """rms_norm(y * silu(z)) * scale over the WHOLE last axis: the gate
-    first, then one norm across every channel (a state-space mixer's
-    output norm with one group)."""
+    """rms_norm(y * silu(z)) * scale: the gate first, then the norm, ONE
+    statistic for each of `groups` equal runs of the last axis's channels
+    (a state-space mixer's output norm: a statistic a group of heads) and
+    one learned scale over all of them; with one group the norm is over
+    the whole last axis."""
 
     eps: float = 1e-5
     dtype: jnp.dtype = jnp.float32
+    groups: int = 1
 
     @nn.compact
     def __call__(self, y, z):
         scale = self.param("scale", nn.initializers.ones, (y.shape[-1],))
         gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-        return rms_norm(gated, scale, self.eps).astype(self.dtype)
+        if self.groups == 1:
+            return rms_norm(gated, scale, self.eps).astype(self.dtype)
+        by_group = (*gated.shape[:-1], self.groups, -1)
+        return rms_norm(
+            gated.reshape(by_group), scale.reshape(self.groups, -1), self.eps
+        ).reshape(gated.shape).astype(self.dtype)
 
 
 def rotary_turn(x, inv_freq, factor: float = 1.0):
@@ -143,10 +157,66 @@ class SwiGLU(nn.Module):
         return dense(self.hidden, "down", self.dtype)(nn.silu(gate) * up)
 
 
+class ReLU2MLP(nn.Module):
+    """(relu(x Wu))^2 Wd: no gate projection.  `up` carries the `gate_up`
+    name (the MLP's first product, which `remat_blocks` may keep)."""
+
+    hidden: int
+    width: int
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        up = dense(self.width, "up", self.dtype, GATE_UP)(x)
+        return dense(self.hidden, "down", self.dtype)(
+            jnp.square(nn.relu(up))
+        )
+
+
+# The MLP of an expert's form (`layers/moe.py: FORMS`): the shared expert
+# is what a routed one is.
+MLP_OF_FORM = {SWIGLU: SwiGLU, RELU2: ReLU2MLP}
+
+
+class GroupedAttention(nn.Module):
+    """`heads` query heads over `kv_heads` key/value heads, causal, no
+    positions and no norms, the logits times `scale`."""
+
+    hidden: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    scale: float
+    dtype: jnp.dtype = jnp.float32
+    trace_scope: str = "attn"
+
+    @nn.compact
+    def __call__(self, x):
+        batch, length, _ = x.shape
+        heads, kv_heads, dim = self.heads, self.kv_heads, self.head_dim
+        with jax.named_scope(self.trace_scope):
+            q, k, v = (
+                dense(count * dim, name, self.dtype, MIXER_IN)(x).reshape(
+                    batch, length, count, dim
+                )
+                for name, count in (
+                    ("q", heads), ("k", kv_heads), ("v", kv_heads)
+                )
+            )
+            out = flash_attention.causal_attention(
+                q, k, v, scale=self.scale
+            )
+            return dense(self.hidden, "o", self.dtype, MIXER_OUT)(
+                out.reshape(batch, length, heads * dim)
+            )
+
+
 class MoEFFN(nn.Module):
     """The shared expert, computed by every holder alike, plus this
     holder's part of the routed experts; `shared_experts` 0 builds no
-    shared expert."""
+    shared expert.  The shared expert is `shared_experts * expert_width`
+    wide, or `shared_width` where the model gives it a width of its own;
+    `form` is every expert's, routed and shared alike."""
 
     hidden: int
     num_experts: int
@@ -159,6 +229,8 @@ class MoEFFN(nn.Module):
     dtype: jnp.dtype = jnp.float32
     trace_scope: str = "glm/moe"
     renorm_eps: float = 0.0
+    form: str = SWIGLU
+    shared_width: Optional[int] = None
 
     @nn.compact
     def __call__(self, x):
@@ -168,14 +240,16 @@ class MoEFFN(nn.Module):
                 ffn_dim=self.expert_width, held_experts=self.held_experts,
                 routed_scaling=self.routed_scaling,
                 bias_update_rate=self.bias_update_rate, dtype=self.dtype,
-                renorm_eps=self.renorm_eps, name="routed",
+                renorm_eps=self.renorm_eps, form=self.form, name="routed",
             )(x)
             if not self.shared_experts:
                 with jax.named_scope("combine"):
                     return routed.astype(self.dtype)
             with jax.named_scope("shared"):
-                shared = SwiGLU(
-                    self.hidden, self.shared_experts * self.expert_width,
+                shared = MLP_OF_FORM[self.form](
+                    self.hidden,
+                    self.shared_width
+                    or self.shared_experts * self.expert_width,
                     self.dtype, name="shared",
                 )(x)
             with jax.named_scope("combine"):
@@ -340,13 +414,14 @@ def lean_step_bytes(
 
 
 def routed_walks(
-    x, routed: Sequence[bool], top_k: int, expert_width: int
+    x, routed: Sequence[bool], top_k: int, expert_width: int,
+    form: str = SWIGLU,
 ) -> Tuple[int, ...]:
     """For each block what its routed walk holds over `x` (B, L, hidden)
     (`layers/moe.py: walk_bytes`), 0 for a block that is not routed."""
     walk = walk_bytes(
         x.shape[0] * x.shape[1], x.shape[-1], top_k, expert_width,
-        x.dtype.itemsize,
+        x.dtype.itemsize, form,
     )
     return tuple(walk if is_routed else 0 for is_routed in routed)
 

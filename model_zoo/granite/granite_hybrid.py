@@ -20,9 +20,11 @@ granite_hybrid.py`, the plain float32 reference this model is held to
 leaf by leaf (tests/test_granite_hybrid.py), its Mamba-2 the token-by-token
 recurrence.  What it shares with the zoo's other decoders (norms, SwiGLU,
 the blocked cross-entropy, the gated norm) is `model_zoo/common/
-decoder.py`; the scan is `ops/ssd.py: ssd`, the convolution
-`ops/short_conv.py: silu_short_conv` with its bias, attention's core
-`ops/flash_attention.py: causal_attention` over grouped K/V.
+decoder.py`; the Mamba-2 mixer, which `model_zoo/nemotron/nemotron_h.py`
+shares, is `model_zoo/common/mamba.py` (the scan `ops/ssd.py: ssd`, the
+convolution `ops/short_conv.py: silu_short_conv` with its bias);
+attention's core is `ops/flash_attention.py: causal_attention` over
+grouped K/V.
 
 What a layer is comes from the PUBLISHED `layer_types` (`mamba` |
 `attention`), read at the published indices in `layers`.  The four
@@ -47,152 +49,26 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from elasticdl_tpu.common import metrics as metrics_lib
 from elasticdl_tpu.layers.embedding import DistributedEmbedding
-from elasticdl_tpu.layers import step_metrics
-from elasticdl_tpu.layers.step_metrics import sow_step_metric
-from elasticdl_tpu.ops import ssd as ssd_ops
-from elasticdl_tpu.ops.flash_attention import causal_attention
-from elasticdl_tpu.ops.short_conv import silu_short_conv
 from model_zoo.bert.bert_finetune import feed, feed_bulk  # noqa: F401
 from model_zoo.common.decoder import (  # noqa: F401
-    MIXER_IN,
-    MIXER_OUT,
-    GatedRMSNorm,
+    GroupedAttention,
     RMSNorm,
     SwiGLU,
-    a_log_init,
-    dense,
-    dt_bias_init,
     eval_metrics_fn,
     loss,
     optimizer,
     param_sharding,
     remat_blocks,
     shifted_nll,
-    tap_init,
 )
+from model_zoo.common.mamba import Mamba2
 
 MAMBA, ATTENTION = "mamba", "attention"
 # the published pattern: attention at the sixth layer of every ten
 PUBLISHED_LAYER_TYPES = tuple(
     ATTENTION if i % 10 == 5 else MAMBA for i in range(40)
 )
-
-
-def conv_bias_init(taps: int):
-    """Uniform in +-1 / sqrt(K), the taps' own bound (their fan-in)."""
-    def init(key, shape, dtype=jnp.float32):
-        bound = taps ** -0.5
-        return jax.random.uniform(key, shape, dtype, -bound, bound)
-
-    return init
-
-
-# What a Mamba-2 layer sows into STEP_METRICS, read once a task with the
-# loss: leaf name -> gauge by layer.
-step_metrics.declare(
-    "ssm_state_kept_ratio",
-    metrics_lib.default_registry().gauge(
-        "worker_ssm_state_kept_ratio",
-        "mean over heads and chunks of exp(sum of log a over a chunk of "
-        "256 tokens) of a state-space layer, last step of the task: the "
-        "share of a state that outlives a chunk (0: the carried path "
-        "does no work at these weights; 1: nothing is ever forgotten)",
-        labelnames=("layer",),
-    ),
-)
-
-
-class Mamba2(nn.Module):
-    """`heads` heads of `head_dim` channels over `state` state columns,
-    B and C shared by the heads of each of `groups` groups, x, B and C
-    through one `taps`-tap causal depthwise conv with a bias."""
-
-    hidden: int
-    heads: int
-    head_dim: int
-    state: int
-    groups: int
-    taps: int
-    eps: float
-    dtype: jnp.dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        batch, length, _ = x.shape
-        heads, dim = self.heads, self.head_dim
-        inner, shared = heads * dim, self.groups * self.state
-        with jax.named_scope("granite/ssm/proj"):
-            z, xbc, dt = jnp.split(
-                dense(
-                    2 * inner + 2 * shared + heads, "in_proj", self.dtype,
-                    MIXER_IN,
-                )(x), [inner, 2 * inner + 2 * shared], axis=-1,
-            )
-        with jax.named_scope("granite/ssm/conv"):
-            weight = self.param(
-                "conv_kernel", tap_init, (self.taps, inner + 2 * shared)
-            )
-            bias = self.param(
-                "conv_bias", conv_bias_init(self.taps), (inner + 2 * shared,)
-            )
-            xs, b, c = jnp.split(
-                silu_short_conv(xbc, weight, bias), [inner, inner + shared],
-                axis=-1,
-            )
-        with jax.named_scope("granite/ssm/core"):
-            a_log = self.param("A_log", a_log_init, (heads,))
-            dt_bias = self.param("dt_bias", dt_bias_init, (heads,))
-            skip = self.param("D", nn.initializers.ones, (heads,))
-            dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias)
-            rate = -jnp.exp(a_log)
-            # what share of a state outlives a chunk: whether the carried
-            # path does work at the weights the run has
-            chunk = ssd_ops.CHUNK if length % ssd_ops.CHUNK == 0 else length
-            sow_step_metric(self, "ssm_state_kept_ratio", jnp.exp(
-                (rate * dt).reshape(batch, -1, chunk, heads).sum(axis=2)
-            ).mean())
-            by_group = (batch, length, self.groups, self.state)
-            y = ssd_ops.ssd(
-                xs.reshape(batch, length, heads, dim), dt, rate,
-                b.reshape(by_group), c.reshape(by_group), skip,
-            ).reshape(batch, length, inner)
-        with jax.named_scope("granite/ssm/gated_norm"):
-            # the gate FIRST, then one norm over all the channels
-            y = GatedRMSNorm(self.eps, self.dtype, name="norm")(y, z)
-        with jax.named_scope("granite/ssm/out"):
-            return dense(self.hidden, "out_proj", self.dtype, MIXER_OUT)(y)
-
-
-class GroupedAttention(nn.Module):
-    """`heads` query heads over `kv_heads` key/value heads, causal, no
-    positions and no norms, the logits times `scale`."""
-
-    hidden: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    scale: float
-    dtype: jnp.dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        batch, length, _ = x.shape
-        heads, kv_heads, dim = self.heads, self.kv_heads, self.head_dim
-        with jax.named_scope("granite/attn"):
-            q, k, v = (
-                dense(count * dim, name, self.dtype, MIXER_IN)(x).reshape(
-                    batch, length, count, dim
-                )
-                for name, count in (
-                    ("q", heads), ("k", kv_heads), ("v", kv_heads)
-                )
-            )
-            out = causal_attention(q, k, v, scale=self.scale)
-            return dense(self.hidden, "o", self.dtype, MIXER_OUT)(
-                out.reshape(batch, length, heads * dim)
-            )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -238,12 +114,13 @@ class Block(nn.Module):
         if self.kind == MAMBA:
             y = Mamba2(
                 c.hidden, c.mamba_heads, c.mamba_head_dim, c.mamba_state,
-                c.mamba_groups, c.conv_kernel, c.eps, c.dtype, name="mamba",
+                c.mamba_groups, c.conv_kernel, c.eps, c.dtype, "granite/ssm",
+                name="mamba",
             )(y)
         else:
             y = GroupedAttention(
                 c.hidden, c.heads, c.kv_heads, c.hidden // c.heads,
-                c.attention_multiplier, c.dtype, name="attn",
+                c.attention_multiplier, c.dtype, "granite/attn", name="attn",
             )(y)
         with jax.named_scope("granite/norm"):
             x = x + c.residual_multiplier * y

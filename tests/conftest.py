@@ -25,3 +25,22 @@ from elasticdl_tpu.common.virtual_mesh import (  # noqa: E402
 )
 
 enable_compile_cache()
+
+import gc  # noqa: E402
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _drop_compiled_programs_after_each_module():
+    """A compiled CPU executable keeps its code mapped for as long as a
+    jit cache holds it, and a test process that runs two hundred modules'
+    worth of them reaches the kernel's limit on memory maps
+    (`vm.max_map_count`, 65,530: a worker read 56,740 at the end of a
+    whole run, and one over the limit dies of a segmentation fault inside
+    the next compile).  No module reuses another's programs."""
+    yield
+    import jax
+
+    jax.clear_caches()
+    gc.collect()

@@ -27,6 +27,7 @@ from elasticdl_tpu.layers.moe import ROUTER_STATE
 from elasticdl_tpu.layers.step_metrics import AUX_LOSS, STEP_METRICS
 from elasticdl_tpu.ops import short_conv
 from elasticdl_tpu.ops import ssd as ssd_ops
+from model_zoo.common import decoder, mamba
 from model_zoo.granite import granite_hybrid as zoo
 from tests import remat_cases
 
@@ -204,9 +205,9 @@ def _rotated(monkeypatch):
     """A rotary turn of q and k that the published model does not have."""
     from model_zoo.common.decoder import rotary
 
-    plain = zoo.causal_attention
+    plain = decoder.flash_attention.causal_attention
     monkeypatch.setattr(
-        zoo, "causal_attention",
+        decoder.flash_attention, "causal_attention",
         lambda q, k, v, scale: plain(
             rotary(q, 1e4), rotary(k, 1e4), v, scale=scale
         ),
@@ -216,7 +217,7 @@ def _rotated(monkeypatch):
 def _norm_before_gate(monkeypatch):
     from model_zoo.common.decoder import rms_norm
 
-    class NormThenGate(zoo.GatedRMSNorm):
+    class NormThenGate(mamba.GatedRMSNorm):
         @zoo.nn.compact
         def __call__(self, y, z):
             scale = self.param(
@@ -226,20 +227,21 @@ def _norm_before_gate(monkeypatch):
                 rms_norm(y, scale, self.eps) * jax.nn.silu(z)
             ).astype(self.dtype)
 
-    monkeypatch.setattr(zoo, "GatedRMSNorm", NormThenGate)
+    monkeypatch.setattr(mamba, "GatedRMSNorm", NormThenGate)
 
 
 def _no_conv_bias(monkeypatch):
-    plain = zoo.silu_short_conv
+    plain = mamba.silu_short_conv
     monkeypatch.setattr(
-        zoo, "silu_short_conv", lambda u, w, b: plain(u, w)
+        mamba, "silu_short_conv", lambda u, w, b: plain(u, w)
     )
 
 
 def _one_tap_dropped(monkeypatch):
-    plain = zoo.silu_short_conv
+    plain = mamba.silu_short_conv
     monkeypatch.setattr(
-        zoo, "silu_short_conv", lambda u, w, b: plain(u, w.at[0].set(0.0), b)
+        mamba, "silu_short_conv",
+        lambda u, w, b: plain(u, w.at[0].set(0.0), b),
     )
 
 

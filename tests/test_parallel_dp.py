@@ -86,3 +86,28 @@ def test_pad_to_multiple_wraps_and_reports_true_count():
     np.testing.assert_array_equal(
         padded["features"][5:], batch["features"][:3]
     )
+
+
+@pytest.mark.parametrize("rows", [4, 5])
+@pytest.mark.parametrize("key", mesh_lib.STORE_KEYS)
+def test_pad_to_multiple_carries_a_stores_bookkeeping(rows, key):
+    """A tiered store's feed hangs an admission plan (any object) or the
+    raw sparse batch with its ranking (arrays of another length) on the
+    batch: neither is a row of the batch.  Without this every task of a
+    Local tiered job failed in the pad (`ragged batch`), and the tests of
+    that job passed on counters an earlier test had left."""
+    sparse = np.arange(rows * 3).reshape(rows, 3)
+    ranking = (np.arange(7), np.arange(7))
+    carried = object() if key == "__store_plan__" else (sparse, ranking)
+    batch = {"features": np.arange(rows * 2.0).reshape(rows, 2), key: carried}
+    padded, real = mesh_lib.pad_to_multiple(batch, 4)
+    assert real == rows
+    assert padded["features"].shape == (-(-rows // 4) * 4, 2)
+    if key == "__store_plan__" or rows == 4:
+        assert padded[key] is carried
+    else:
+        # wrapped rows repeat ids: the ranking is the trainer's to redo
+        wrapped, ranked = padded[key]
+        assert ranked is None
+        np.testing.assert_array_equal(wrapped[:rows], sparse)
+        np.testing.assert_array_equal(wrapped[rows:], sparse[:3])

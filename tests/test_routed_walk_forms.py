@@ -1,0 +1,186 @@
+"""`layers/moe.py: routed_walk` with the expert's FORM an argument: the
+gated `swiglu` over a fused gate-and-up stack and the non-gated `relu2`
+over an up stack alone, each against a per-expert dense sum (forward and
+every gradient) at loads that leave a dead tail, an empty expert, no row
+and every row; what the backward holds, by form; the leaves a layer of
+each form builds; and the `swiglu` walk at a sibling cell's shape traced
+to what it was traced to before the form entered."""
+
+import hashlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from elasticdl_tpu.layers import moe
+
+TOKENS, TOP_K, HIDDEN, FFN, HELD = 64, 2, 32, 24, 4
+CHUNK = 48               # 128 slots: 3 chunks, 16 rows padded
+# rows each held expert gets: a dead tail in the last live chunk with an
+# EMPTY expert between two others; no row here; a chunk and one row;
+# every slot here
+LOADS = {
+    "dead_tail_and_empty_expert": (20, 0, 9, 0),
+    "none": (0, 0, 0, 0),
+    "one_chunk_and_a_row": (20, 0, 28, 1),
+    "every_slot": (32, 32, 32, 32),
+}
+ACTIVATIONS = {
+    moe.SWIGLU: lambda h: jax.nn.silu(h[..., :FFN]) * h[..., FFN:],
+    moe.RELU2: lambda h: jnp.square(jax.nn.relu(h)),
+}
+
+
+def given(form, loads, seed=0):
+    """Tokens, the form's two stacks, and a routing that sends exactly
+    `loads[e]` slots to held expert e and the rest to absent experts
+    (key `HELD`), in the layer's own terms: `order` (the slots sorted by
+    key), one weight a slot, the group sizes."""
+    rng = np.random.RandomState(seed)
+    slots = TOKENS * TOP_K
+    key = np.full((slots,), HELD, np.int32)
+    here = rng.permutation(slots)[:sum(loads)]
+    key[here] = np.repeat(np.arange(HELD), loads)
+    width = moe.FORMS[form][1] * FFN
+    return dict(
+        tokens=jnp.asarray(rng.randn(TOKENS, HIDDEN), jnp.float32),
+        w_first=jnp.asarray(0.2 * rng.randn(HELD, HIDDEN, width), jnp.float32),
+        w_down=jnp.asarray(0.2 * rng.randn(HELD, FFN, HIDDEN), jnp.float32),
+        weights=jnp.asarray(rng.rand(slots) + 0.5, jnp.float32),
+        order=jnp.argsort(jnp.asarray(key), stable=True),
+        group_sizes=jnp.asarray(loads, jnp.int32), key=key,
+    )
+
+
+def dense_sum(form, tokens, w_first, w_down, weights, key):
+    """Every held expert over ALL tokens, times the weight of the slots
+    that chose it (zero where none did): no sort, no walk."""
+    act = ACTIVATIONS[form]
+    by_token = weights.reshape(TOKENS, TOP_K)
+    out = jnp.zeros_like(tokens)
+    for e in range(HELD):
+        weight = jnp.where(key.reshape(TOKENS, TOP_K) == e, by_token, 0.0)
+        out = out + weight.sum(axis=1)[:, None] * (
+            act(tokens @ w_first[e]) @ w_down[e]
+        )
+    return out
+
+
+@pytest.mark.parametrize("form", sorted(moe.FORMS))
+@pytest.mark.parametrize("load", sorted(LOADS))
+def test_a_walk_of_either_form_is_the_per_expert_dense_sum(
+        load, form, monkeypatch):
+    monkeypatch.setattr(moe, "CHUNK", CHUNK)
+    g = given(form, LOADS[load])
+    cotangent = jnp.asarray(
+        np.random.RandomState(5).randn(TOKENS, HIDDEN), jnp.float32
+    )
+    leaves = ("tokens", "w_first", "w_down", "weights")
+
+    def through(function):
+        return jax.value_and_grad(
+            lambda *a: (function(*a) * cotangent).sum(),
+            argnums=(0, 1, 2, 3),
+        )(*(g[name] for name in leaves))
+
+    with jax.default_matmul_precision("highest"):
+        out = moe.routed_walk(
+            g["tokens"], g["w_first"], g["w_down"], g["order"],
+            g["weights"], g["group_sizes"], form,
+        )
+        want = dense_sum(form, *(g[name] for name in leaves), g["key"])
+        _, got_grads = through(lambda t, a, b, w: moe.routed_walk(
+            t, a, b, g["order"], w, g["group_sizes"], form
+        ))
+        _, want_grads = through(
+            lambda t, a, b, w: dense_sum(form, t, a, b, w, g["key"])
+        )
+    assert out.dtype == jnp.float32
+    assert bool(np.abs(np.asarray(out)).sum() > 0) == bool(sum(LOADS[load]))
+    close = dict(rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(out, want, **close)
+    for name, got, ref in zip(leaves, got_grads, want_grads):
+        np.testing.assert_allclose(got, ref, err_msg=name, **close)
+    # an expert no slot chose gets no gradient, a slot of no held expert
+    # no weight's
+    for e, rows in enumerate(LOADS[load]):
+        got = np.abs(np.asarray(got_grads[1][e])).sum()
+        assert bool(got > 0) == bool(rows)
+    assert not np.asarray(got_grads[3])[g["key"] == HELD].any()
+
+
+def test_the_forms_differ_and_the_default_is_swiglu():
+    g = given(moe.RELU2, LOADS["one_chunk_and_a_row"])
+    args = (g["tokens"], g["w_first"][..., :FFN], g["w_down"], g["order"],
+            g["weights"], g["group_sizes"])
+    fused = jnp.concatenate([args[1], args[1]], axis=-1)
+    squared = moe.routed_walk(*args, moe.RELU2)
+    gated = moe.routed_walk(args[0], fused, *args[2:], moe.SWIGLU)
+    assert np.abs(np.asarray(squared - gated)).max() > 0.01
+    np.testing.assert_array_equal(
+        moe.routed_walk(args[0], fused, *args[2:]), gated
+    )
+    with pytest.raises(KeyError):
+        moe.routed_walk(*args, "gelu")
+
+
+def test_walk_bytes_by_form():
+    """The backward's four buffers take the form's widths: (hidden, 2
+    ffn, ffn, hidden) gated, (hidden, ffn, ffn, hidden) squared."""
+    tokens, hidden, top_k, ffn = 16384, 2688, 6, 1856
+    chunk, total = moe._chunks(tokens * top_k)
+    assert (chunk, total) == (16384, 6)
+    sums = 3 * tokens * hidden * 4
+    assert moe.walk_bytes(tokens, hidden, top_k, ffn, 2) == (
+        (total + 2) * chunk * (2 * hidden + 3 * ffn) * 2 + sums
+    )
+    assert moe.walk_bytes(tokens, hidden, top_k, ffn, 2, moe.RELU2) == (
+        (total + 2) * chunk * (2 * hidden + 2 * ffn) * 2 + sums
+    )
+    # the GLM cell's layer, as before the form entered
+    assert moe.walk_bytes(16384, 2048, 4, 1536, 2) == (
+        6 * 16384 * (2 * 2048 + 3 * 1536) * 2 + 3 * 16384 * 2048 * 4
+    )
+
+
+@pytest.mark.parametrize("form, first", [
+    (moe.SWIGLU, ("expert_w_gate_up", (4, HIDDEN, 2 * FFN))),
+    (moe.RELU2, ("expert_w_up", (4, HIDDEN, FFN))),
+])
+def test_a_layer_builds_its_forms_stacks(form, first):
+    layer = moe.RoutedExperts(
+        num_experts=16, top_k=TOP_K, ffn_dim=FFN, held_experts=(0, 4),
+        form=form,
+    )
+    x = jnp.zeros((2, 8, HIDDEN))
+    shapes = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)["params"]
+    assert {name: leaf.shape for name, leaf in shapes.items()} == {
+        "router_kernel": (HIDDEN, 16), first[0]: first[1],
+        "expert_w_down": (4, FFN, HIDDEN),
+    }
+
+
+# sha256 of str(make_jaxpr(grad(routed_walk ...))) at the GLM cell's
+# bfloat16 shape (16,384 tokens of 2,048, 8 held experts 1,536 wide, top-4:
+# 65,536 slots), recorded at the commit before the form entered
+# (1daa31a): the four routed cells' walk traces to what it traced to.
+SWIGLU_JAXPR = (
+    "b6b6b0df946fc301f1f36d1bf05b107c4b73728266de328ee7a7e96c9ace8af4"
+)
+
+
+def test_the_swiglu_walk_is_the_parents():
+    shaped = jax.ShapeDtypeStruct
+    text = str(jax.make_jaxpr(jax.grad(
+        lambda t, a, b, o, w, g: moe.routed_walk(t, a, b, o, w, g).sum(),
+        argnums=(0, 1, 2, 4),
+    ))(
+        shaped((16384, 2048), jnp.bfloat16),
+        shaped((8, 2048, 3072), jnp.bfloat16),
+        shaped((8, 1536, 2048), jnp.bfloat16),
+        shaped((65536,), jnp.int32), shaped((65536,), jnp.float32),
+        shaped((8,), jnp.int32),
+    ))
+    assert "0x" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == SWIGLU_JAXPR

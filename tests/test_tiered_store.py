@@ -849,6 +849,10 @@ def test_local_multiworker_run_uses_deferred_planning(tmp_path):
     assert store.deferred_prepare
     stats = store.stats()
     assert stats["growth_rows"] > 0
+    # THIS store's, where `growth_rows` is a registry counter that an
+    # earlier test's store may have raised: rows sit in the cache only
+    # if the trainer planned and ran steps
+    assert stats["cache_occupancy_rows"] > 0
     assert stats["hit_rate"] > 0.5
     assert stats["cold_gather_overlap_share"] == 0.0
 

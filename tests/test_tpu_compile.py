@@ -315,6 +315,80 @@ def test_granite_cell_kernels_compile_for_the_chip(one_chip, monkeypatch):
     _assert_two_kernels(attention, "causal")
 
 
+def test_nemotron_cell_kernels_compile_for_the_chip(one_chip, monkeypatch):
+    """The Nemotron cell's four calls at its shapes: the state-space scan
+    at EIGHT groups (2, 8192, 64 heads of 64 over 128 state columns, grid
+    step t group t), the biased SiLU conv over x | B | C (2, 8192, 6144)
+    under 4 taps, attention at 32 / 2 heads of 128 (16 query heads a K/V
+    head), and one routed layer of eight held squared-ReLU experts 1,856
+    wide through `routed_walk` in the `relu2` form."""
+    from elasticdl_tpu.layers import moe
+    from elasticdl_tpu.ops import short_conv, ssd
+
+    for module in (fa, ssd, short_conv):
+        monkeypatch.setattr(module, "use_interpret", lambda: False)
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def text_of(fn, *args, argnums=None):
+        def grads(*args):
+            return jax.grad(
+                lambda *a: fn(*a).astype(jnp.float32).sum(),
+                argnums=argnums or tuple(range(len(args))),
+            )(*args)
+
+        return jax.jit(grads).lower(*args).compile().as_text()
+
+    x, shared = (2, 8192, 64, 64), (2, 8192, 8, 128)
+    assert ssd.ssd_shapes_ok(x, shared)
+    scan = text_of(
+        ssd.ssd, shaped(x), shaped(x[:3], jnp.float32),
+        shaped((64,), jnp.float32), shaped(shared), shaped(shared),
+        shaped((64,), jnp.float32),
+    )
+    assert "ssd_fwd" in scan and "ssd_bwd" in scan
+    # B, C and their gradients at every group's columns, nothing repeated
+    # to the heads; the states the 32 chunks start from, all heads' rows
+    assert "bf16[2,8192,1024]" in scan
+    assert "f32[2,32,4096,128]" in scan
+
+    u, taps = (2, 8192, 6144), (4, 6144)
+    assert short_conv.silu_conv_shapes_ok(u, taps, True)
+    conv = text_of(
+        short_conv.silu_short_conv, shaped(u), shaped(taps, jnp.float32),
+        shaped(taps[1:], jnp.float32),
+    )
+    assert "silu_short_conv_bwd" in conv and "tpu_custom_call" in conv
+
+    q, kv = (2, 8192, 32, 128), (2, 8192, 2, 128)
+    assert fa.stream_shapes_ok(q, kv, kv)
+    attention = text_of(
+        lambda q, k, v: fa.causal_attention(q, k, v, scale=128 ** -0.5),
+        shaped(q), shaped(kv), shaped(kv),
+    )
+    _assert_two_kernels(attention, "causal")
+    # K/V stay two heads wide: nothing repeated to the 32 query heads
+    assert "bf16[2,8192,256]" in attention
+
+    tokens, hidden, width, top_k, held = 16384, 2688, 1856, 6, 8
+    walk = text_of(
+        lambda t, up, down, order, weights, sizes: moe.routed_walk(
+            t, up, down, order, weights, sizes, moe.RELU2
+        ),
+        shaped((tokens, hidden)), shaped((held, hidden, width)),
+        shaped((held, width, hidden)), shaped((tokens * top_k,), jnp.int32),
+        shaped((tokens * top_k,), jnp.float32), shaped((held,), jnp.int32),
+        argnums=(0, 1, 2, 4),
+    )
+    # two products an expert forward, rebuilt in the backward, two to the
+    # rows and one to each stack: eight grouped kernels, the up stack's at
+    # the expert's own width (no gate beside it)
+    assert walk.count("= bf16[16384,1856]") >= 2
+    assert "bf16[16384,3712]" not in walk
+    assert "ragged-dot" in walk
+
+
 def test_the_scope_table_reads_a_text_compiled_for_the_chip(one_chip):
     """The chip's compiled text differs from the CPU's where the parser
     looks: tiled layouts with brackets of their own
