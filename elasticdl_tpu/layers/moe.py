@@ -56,7 +56,11 @@ from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.common import metrics as metrics_lib
 from elasticdl_tpu.layers import step_metrics
-from elasticdl_tpu.layers.step_metrics import AUX_LOSS, sow_step_metric
+from elasticdl_tpu.layers.step_metrics import (
+    AUX_LOSS,
+    STEP_METRICS,
+    sow_step_metric,
+)
 
 
 # Holds buffers a layer updates itself (no gradient); the two collections
@@ -101,6 +105,16 @@ step_metrics.declare(
         "has a worst-case buffer: stays 0)",
     ),
 )
+# A constant of the layer's shapes and `TILE`, known as the layer is traced:
+# set there, on the host (as `worker_remat_kept_ratio` is), and never sown,
+# so no step carries it and no fetch reads it.
+padded_work_ratio = metrics_lib.default_registry().gauge(
+    "worker_moe_padded_work_ratio",
+    "multiply-adds of the grouped products at the widths the walk pads "
+    "them to (layers/moe.py: TILE) / at the layer's own widths, minus "
+    "1; 0.0 means nothing is padded",
+    labelnames=("layer",),
+)
 
 
 def expert_loads(expert_idx, num_experts: int):
@@ -142,6 +156,77 @@ CHUNK = 16384
 # every index of the walk is a token's, in bounds by construction
 _PIB = "promise_in_bounds"
 
+# What a width of a grouped product is padded up to a whole multiple of
+# (`_padded`).  The chip's `ragged_dot` kernel tiles each of (rows,
+# contraction, columns) with the largest of 512 / 256 / 128 that DIVIDES
+# it (the compiled text says which: `ragged_dot_tiling="512,128,128"` at
+# Nemotron's 2,688 x 1,856, `512,512,512` at GLM's 2,048 x 1,536,
+# `512,256,512` at Kimi's 2,304 x 1,024, the same for rows x stack, rows
+# x transposed stack and the stacks' gradients), and a 128-wide tile runs
+# at a third of the pace of a 256-wide one.  Placed by PR 51's chip probe
+# (v5e; Nemotron's layer alone, 16,384 tokens of 2,688, top-6, 8 held of
+# 128, `relu2`, forward + backward under remat, three traced calls; ms,
+# at 0.0625 / 0.2 / 0.65 of the slots here: the eight `ragged-dot`s [of
+# them rows x up-shaped stack | rows x down-shaped stack | the two stack
+# gradients at 0.2], the whole walk):
+#   ffn x hidden  (tile)   products               [at 0.2]            walk
+#   1,856 x 2,688 (none)   18.44 / 44.01 / 129.67 [12.6 | 13.8 | 17.6] 29.4 / 61.0 / 158.9
+#   1,920 x 2,688 (128)    18.41 / 43.98 / 129.69 [12.6 | 13.8 | 17.6] 29.8 / 61.0 / 157.9
+#   2,048 x 2,688          8.46 / 20.22 / 59.39   [5.2 | 7.4 | 7.7]    20.0 / 37.3 / 87.6
+#   1,920 x 2,816          12.32 / 29.39 / 86.53  [9.7 | 8.4 | 11.3]   24.6 / 47.6 / 116.4
+#   2,048 x 2,816 (256)    6.03 / 14.33 / 42.01   [4.2 | 5.0 | 5.1]    18.2 / 32.4 / 71.8
+#   2,048 x 3,072 (512)    5.09 / 12.00 / 35.11   [4.0 | 4.0 | 4.0]    17.7 / 30.6 / 65.7
+# (GLM's 2,048 x 1,536 gated layer, top-4, unpadded: 2.96 / 6.44 / 17.70,
+# 12.0 / 15.4 / 37.4).  A whole lane tile alone (1,920) buys nothing; all
+# three kinds of product want the same shapes; over the layer's own
+# multiply-adds the products read 10-15% of the MXU's peak unpadded,
+# 31-46% at 256, 37-55% at 512 (GLM's 40-69%).  512 is 6-9% better on
+# this walk at the two higher loads but would pad Kimi's 2,304 to 2,560,
+# where the same probe reads the walk 13.5 / 38.7 / 63.0 unpadded and
+# 15.8 / 39.6 / 62.6 padded (0.03 / 0.3 / 0.55 of its slots): its
+# products gain 6-9% and the pads and wider rows give it back.  At 256
+# every sibling's widths are whole and its program the unpadded one
+# (PERF.md section 6, PR 51).
+TILE = 256
+
+
+def _whole(dim: int) -> int:
+    """`dim` rounded up to a whole number of `TILE`s."""
+    return -(-dim // TILE) * TILE
+
+
+def padded_work(hidden: int, ffn_dim: int) -> float:
+    """Multiply-adds of an expert's products at the padded widths over
+    those at its own, minus 1 (both products are hidden x ffn a row)."""
+    return _whole(hidden) * _whole(ffn_dim) / (hidden * ffn_dim) - 1.0
+
+
+def _zeros_to(x, shape):
+    """`x` with zeros after it up to `shape`; `x` itself where it has it."""
+    if x.shape == tuple(shape):
+        return x
+    return jnp.pad(x, [(0, to - n) for n, to in zip(x.shape, shape)])
+
+
+def _cut_to(x, shape):
+    """The leading `shape` of `x`; `x` itself where it has it."""
+    if x.shape == tuple(shape):
+        return x
+    return x[tuple(slice(0, n) for n in shape)]
+
+
+def _first_as(w, parts: int, hidden: int, ffn: int, fit):
+    """A first stack, or its gradient, as (experts, hidden, parts x ffn),
+    EACH of its `parts` (`swiglu`'s gate and up, which the activation
+    splits apart) brought to `ffn` columns on its own by `fit` (`_zeros_to`
+    or `_cut_to`); `w` itself where it has that shape."""
+    if w.shape[1:] == (hidden, parts * ffn):
+        return w
+    by_part = w.reshape(*w.shape[:2], parts, -1)
+    return fit(by_part, (w.shape[0], hidden, parts, ffn)).reshape(
+        w.shape[0], hidden, parts * ffn
+    )
+
 
 def _swiglu(gate_up):
     gate, up = jnp.split(gate_up, 2, axis=-1)
@@ -180,11 +265,12 @@ def walk_bytes(
     """What `routed_walk`'s backward holds at once, from its own shapes:
     the four (slots, width) buffers it fills for the stacks' gradients
     (the rows, the first stack's output's gradient at the form's width,
-    the activation, the output's gradient), three float32 (tokens, hidden)
+    the activation, the output's gradient; each at the width the products
+    are padded to, `TILE`), three float32 (tokens, hidden)
     sums (the forward's, its cotangent, d_tokens) and one chunk's rows in
     flight, values and gradients."""
     chunk, total = _chunks(tokens * top_k)
-    widths = 2 * hidden + (FORMS[form][1] + 1) * ffn_dim
+    widths = 2 * _whole(hidden) + (FORMS[form][1] + 1) * _whole(ffn_dim)
     return (
         (total + 2) * chunk * widths * itemsize + 3 * tokens * hidden * 4
     )
@@ -224,30 +310,54 @@ def routed_walk(tokens, w_first, w_down, order, weights, group_sizes,
     arguments, so a rematerialised block's second forward is dead code)
     and its inputs to the stacks' gradients written to buffers that are
     zero where no trip went; the two stack gradients are ONE ragged
-    product each after the walk, whose cost follows the rows.
+    product each after the walk, whose cost follows the rows.  Every
+    grouped product runs at widths padded with zeros to whole `TILE`s
+    (`_padded`: the tokens and the stacks once a walk, the buffers
+    allocated so), and what the padding got is cut off before a value or
+    a gradient leaves: the arguments and the results keep their shapes.
     """
     return _walk(tokens, w_first, w_down, order, weights, group_sizes, form)
+
+
+def _padded(tokens, w_first, w_down, form):
+    """(tokens, the two stacks) with zeros up to whole `TILE`s along the
+    hidden and the expert's width: the shapes the grouped products run at.
+    Zero columns of the first stack give zero columns of its output, which
+    the activation keeps zero (relu(0)^2 = 0, silu(0) * 0 = 0) and which
+    meet zero rows of the second; zero columns of the tokens meet zero
+    rows of the first stack.  Each is the argument itself where its
+    dimensions are whole already."""
+    hidden, ffn = _whole(tokens.shape[1]), _whole(w_down.shape[1])
+    return (
+        _zeros_to(tokens, (tokens.shape[0], hidden)),
+        _first_as(w_first, FORMS[form][1], hidden, ffn, _zeros_to),
+        _zeros_to(w_down, (w_down.shape[0], ffn, hidden)),
+    )
 
 
 def _walk(tokens, w_first, w_down, order, weights, group_sizes, form):
     activation = FORMS[form][2]
     slots = order.shape[0]
+    hidden = tokens.shape[1]
     top_k = slots // tokens.shape[0]
     chunk, total = _chunks(slots)
     with jax.named_scope("dispatch"):
         order = jnp.pad(order, (0, total * chunk - slots))
+    # once a walk, outside the loop: the pads are the products' cost
+    with jax.named_scope("experts"):
+        wide, w_first, w_down = _padded(tokens, w_first, w_down, form)
 
     def trip(c, out):
         with jax.named_scope("dispatch"):
             _, at, weight, sizes = _chunk_of(
                 c, chunk, top_k, order, weights, group_sizes
             )
-            rows = tokens.at[at].get(mode=_PIB)
+            rows = wide.at[at].get(mode=_PIB)
         with jax.named_scope("experts"):
-            expert_out = grouped_matmul(
+            expert_out = _cut_to(grouped_matmul(
                 activation(grouped_matmul(rows, w_first, sizes)),
                 w_down, sizes,
-            )
+            ), (chunk, hidden))
         with jax.named_scope("combine"):
             return out.at[at].add(
                 expert_out.astype(jnp.float32) * weight, mode=_PIB
@@ -267,8 +377,9 @@ def _walk_fwd(*args):
 
 def _walk_bwd(form, args, g):
     tokens, w_first, w_down, order, weights, group_sizes = args
-    activation = FORMS[form][2]
+    parts, activation = FORMS[form][1:]
     slots = order.shape[0]
+    hidden, ffn = tokens.shape[1], w_down.shape[1]
     top_k = slots // tokens.shape[0]
     chunk, total = _chunks(slots)
     # the padding's rows are dead; its slot 0 repeats, so the weights'
@@ -277,9 +388,10 @@ def _walk_bwd(form, args, g):
     with jax.named_scope("dispatch"):
         order = jnp.pad(order, (0, padded))
     dtype = tokens.dtype
-    # transposed once, outside the loop (inside it they are two copies of
-    # the stacks a trip)
+    # padded (`_padded`) and transposed once, outside the loop (inside it
+    # they are two copies of the stacks a trip)
     with jax.named_scope("experts"):
+        wide, w_first, w_down = _padded(tokens, w_first, w_down, form)
         w_first_t, w_down_t = (
             jnp.swapaxes(w, 1, 2) for w in (w_first, w_down)
         )
@@ -290,19 +402,25 @@ def _walk_bwd(form, args, g):
             slot, at, weight, sizes = _chunk_of(
                 c, chunk, top_k, order, weights, group_sizes
             )
-            rows = tokens.at[at].get(mode=_PIB)
+            rows = wide.at[at].get(mode=_PIB)
             g_rows = g.at[at].get(mode=_PIB)
         with jax.named_scope("experts"):
             # a product's transpose to its rows is the product with the
             # stack transposed, under the same masks
             first = grouped_matmul(rows, w_first, sizes)
             act, pull_first = jax.vjp(activation, first)
-            expert_out = grouped_matmul(act, w_down, sizes)
-            d_out = (g_rows * weight).astype(dtype)
+            expert_out = _cut_to(
+                grouped_matmul(act, w_down, sizes), (chunk, hidden)
+            )
+            d_out = _zeros_to(
+                (g_rows * weight).astype(dtype), rows.shape
+            )
             (d_first,) = pull_first(
                 grouped_matmul(d_out, w_down_t, sizes)
             )
-            d_rows = grouped_matmul(d_first, w_first_t, sizes)
+            d_rows = _cut_to(
+                grouped_matmul(d_first, w_first_t, sizes), (chunk, hidden)
+            )
         with jax.named_scope("combine"):
             d_tokens = d_tokens.at[at].add(
                 d_rows.astype(jnp.float32), mode=_PIB
@@ -319,9 +437,9 @@ def _walk_bwd(form, args, g):
             )
         return d_tokens, d_weights, saved
 
-    ffn = w_down.shape[1]
     # the loop itself is `combine`'s: its carries are the sums and the
-    # buffers it fills
+    # buffers it fills, at the products' widths so that the stacks'
+    # gradients below read them as they are
     with jax.named_scope("combine"):
         d_tokens, d_weights, (rows, d_first, act, d_out) = lax.fori_loop(
             0, _trips(group_sizes.sum(), chunk), trip,
@@ -330,20 +448,23 @@ def _walk_bwd(form, args, g):
                 jnp.zeros(weights.shape, jnp.float32),
                 tuple(
                     jnp.zeros((total * chunk, width), dtype) for width in
-                    (tokens.shape[1], w_first.shape[2], ffn,
-                     tokens.shape[1])
+                    (wide.shape[1], w_first.shape[2], w_down.shape[1],
+                     wide.shape[1])
                 ),
             ),
         )
     with jax.named_scope("experts"):
         # rows past the last group are zero in all four buffers, so the
-        # plain product needs none of `grouped_matmul`'s masks
+        # plain product needs none of `grouped_matmul`'s masks; what the
+        # padding's zeros got is cut off
         (d_w_first,) = jax.vjp(
             lambda w: lax.ragged_dot(rows, w, group_sizes), w_first
         )[1](d_first)
         (d_w_down,) = jax.vjp(
             lambda w: lax.ragged_dot(act, w, group_sizes), w_down
         )[1](d_out)
+        d_w_first = _first_as(d_w_first, parts, hidden, ffn, _cut_to)
+        d_w_down = _cut_to(d_w_down, (w_down.shape[0], ffn, hidden))
     with jax.named_scope("combine"):
         d_tokens = d_tokens.astype(dtype)
     return d_tokens, d_w_first, d_w_down, None, d_weights, None
@@ -481,7 +602,9 @@ class RoutedExperts(nn.Module):
     Cost: the router, top-k and sort over all tokens x top_k slots, then
     `routed_walk` over ceil(rows routed here / CHUNK) chunks; it sows
     `live_chunks_ratio` (chunks walked / chunks of the worst case; 1.0 =
-    the walk saved nothing) beside `routed_here_ratio`.
+    the walk saved nothing) beside `routed_here_ratio`; what the walk's
+    padding adds to the products (`padded_work`) is a constant of the
+    shapes, set in `worker_moe_padded_work_ratio` on the host.
     """
 
     num_experts: int
@@ -569,6 +692,14 @@ class RoutedExperts(nn.Module):
             self, "live_chunks_ratio", _trips(rows, chunk) / total
         )
         sow_step_metric(self, "dropped_tokens", held.sum() - rows)
+        # as a step that keeps the four above is traced: an `init` and the
+        # trace that plans a block alone (`decoder.block_shapes`, which
+        # keeps none) may not know the layer by its path in the model
+        if (self.is_mutable_collection(STEP_METRICS)
+                and not self.is_initializing()):
+            padded_work_ratio.labels(layer="/".join(self.path)).set(
+                padded_work(hidden, self.ffn_dim)
+            )
         with jax.named_scope("combine"):
             return out.reshape(*lead, hidden)
 

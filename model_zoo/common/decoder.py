@@ -33,6 +33,7 @@ from elasticdl_tpu.layers.moe import (
     moe_param_sharding,
     walk_bytes,
 )
+from elasticdl_tpu.layers.step_metrics import STEP_METRICS
 from elasticdl_tpu.ops import flash_attention, kda, ssd
 from elasticdl_tpu.worker.trainer import remat_kept_ratio
 
@@ -364,8 +365,10 @@ def block_shapes(block: nn.Module, shape, dtype) -> BlockShapes:
     block's code and no count of it kept beside the code."""
     x = jax.ShapeDtypeStruct(shape, dtype)
     variables = jax.eval_shape(block.init, jax.random.PRNGKey(0), x)
+    # (the block is no layer of a model here: what it would sow for the
+    # step's metrics is dropped, and a gauge set beside them stays unset)
     traced = jax.make_jaxpr(
-        lambda v, x: block.apply(v, x, mutable=True)
+        lambda v, x: block.apply(v, x, mutable=nn.DenyList(STEP_METRICS))
     )(variables, x)
     products, saved, made = {}, 0, 0
     for jaxpr, eqn in _equations(traced.jaxpr):

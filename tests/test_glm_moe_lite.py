@@ -568,12 +568,17 @@ GLM_PROGRAM_DIGEST = (
 )
 
 
-def test_glm_keeps_its_tree_and_program_through_the_shared_mla():
+def test_glm_keeps_its_tree_and_program_through_the_shared_mla(monkeypatch):
     """`MLA` moved to `model_zoo/common/mla.py` with a switch for the
     rotation, an optional low-rank query and a scope prefix: GLM's
     parameter tree (so its checkpoint keys) and the program of its
-    gradient are the ones the commits before gave."""
+    gradient are the ones the commits before gave (at a tile of the
+    routed walk's products that this model's 64 and 32 are whole
+    multiples of, as the cell's 2,048 and 1,536 are of `moe.TILE`: the
+    UNPADDED program, `tests/test_remat_plan.py` holds the padded one)."""
     import hashlib
+
+    monkeypatch.setattr(moe, "TILE", 8)
 
     from model_zoo.common.mla import MLA
 

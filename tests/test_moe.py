@@ -1,13 +1,16 @@
 """Mixture-of-Experts layer: routing numerics, capacity semantics,
 expert-parallel sharding over the mesh `expert` axis, gradient flow."""
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from elasticdl_tpu.layers import moe
 from elasticdl_tpu.layers.moe import MoEMLP, moe_param_sharding
+from elasticdl_tpu.layers.step_metrics import STEP_METRICS
 from elasticdl_tpu.parallel import mesh as mesh_lib
 
 
@@ -185,3 +188,46 @@ def test_moe_bert_trains_end_to_end():
         if first is None:
             first = float(loss)
     assert float(loss) < first
+
+
+@pytest.mark.parametrize("hidden, ffn, form, padded", [
+    # Nemotron's layer: 2,688 = 10.5 x 256, 1,856 = 7.25 x 256
+    (2688, 1856, moe.RELU2, 2816 * 2048 / (2688 * 1856) - 1),
+    (84, 58, moe.SWIGLU, 256 * 256 / (84 * 58) - 1),
+    # GLM's and LFM2's layer, Kimi's: whole tiles, nothing padded
+    (2048, 1536, moe.SWIGLU, 0.0),
+    (2304, 1024, moe.SWIGLU, 0.0),
+])
+def test_a_routed_layer_sets_what_its_padding_costs(
+        hidden, ffn, form, padded, monkeypatch):
+    """`worker_moe_padded_work_ratio`: the grouped products' multiply-adds
+    at whole tiles over those at the layer's own widths, minus 1; 0.0
+    where the widths are whole already.  A constant of the shapes: set on
+    the host under the layer's path as a step that keeps the sown four is
+    traced, and no leaf of what the step carries."""
+    monkeypatch.setattr(moe, "TILE", 256)
+
+    class Block(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return moe.RoutedExperts(
+                num_experts=4, top_k=2, ffn_dim=ffn, held_experts=(1, 1),
+                form=form, name="routed",
+            )(x)
+
+    x = jax.ShapeDtypeStruct((1, 8, hidden), jnp.float32)
+    moe.padded_work_ratio.reset()
+    variables = jax.eval_shape(Block().init, jax.random.PRNGKey(0), x)
+    assert moe.padded_work_ratio.child_values() == {}        # not by `init`
+    for mutable, children in (
+        (nn.DenyList(STEP_METRICS), {}),       # `decoder.block_shapes`'
+        ([STEP_METRICS], {("routed",): pytest.approx(padded, rel=1e-6)}),
+    ):
+        out, kept = jax.eval_shape(
+            lambda v, x: Block().apply(v, x, mutable=mutable), variables, x
+        )
+        assert out.shape == x.shape
+        assert moe.padded_work_ratio.child_values() == children
+    assert "padded_work_ratio" not in kept[STEP_METRICS]["routed"]
+    got = moe.padded_work_ratio.value(layer="routed")
+    assert (got > 0) == (padded > 0)

@@ -605,6 +605,9 @@ def test_trainer_carries_each_layer_kinds_gauges(seeded):
         assert metrics[f"{path}/expert_load_imbalance_ratio"] >= 1.0
         assert 0.0 < metrics[f"{path}/routed_here_ratio"] < 1.0
         assert metrics[f"{path}/live_chunks_ratio"] == 1.0
+        # tiny widths; a constant, set as the step was traced and not sown
+        assert moe.padded_work_ratio.value(layer=path) > 0.0
+        assert f"{path}/padded_work_ratio" not in metrics
         assert metrics[f"{path}/dropped_tokens"] == 0
     assert not any(name.startswith("layer_3/") for name in metrics)  # `*`
 

@@ -20,6 +20,7 @@ import pytest
 
 from elasticdl_tpu.common import metrics as metrics_lib
 from elasticdl_tpu.common.model_handler import get_model_spec
+from elasticdl_tpu.layers import moe
 from elasticdl_tpu.worker import trainer as trainer_lib
 from model_zoo.common import decoder
 from model_zoo.common.decoder import (
@@ -366,15 +367,50 @@ PARENT_DIGESTS = {
 
 
 @pytest.mark.parametrize("name", ZOOS)
-def test_with_no_room_the_steps_program_is_the_parents(name):
+def test_with_no_room_the_steps_program_is_the_parents(name, monkeypatch):
     """No room is given (and the Trainer reads none where
     `memory_stats()` is None): a name no policy lists lowers to nothing,
     one policy object serves every block, and the gradient's program is
     the one the commit before lowered (GLM's is held the same way in
-    `tests/test_glm_moe_lite.py`)."""
+    `tests/test_glm_moe_lite.py`).  The test models' widths (64, 32)
+    stand for their cells', whole tiles of the routed walk's products:
+    at a tile they are whole multiples of, the walk pads nothing.  So
+    what is held here is the UNPADDED program; the padded one of a whole
+    model is `test_at_the_shipped_tile_a_models_walks_run_at_whole_tiles`'."""
+    monkeypatch.setattr(moe, "TILE", 8)
     tests = importlib.import_module(f"tests.test_{name}")
     model = tests.model_of(tests.CONFIG, bf16=True, remat=True)
     assert remat_cases.grad_program_digest(model) == PARENT_DIGESTS[name]
+
+
+@pytest.mark.parametrize(
+    "name", ["laguna", "lfm2", "kimi_linear", "glm_moe_lite", "nemotron_h"]
+)
+def test_at_the_shipped_tile_a_models_walks_run_at_whole_tiles(name):
+    """What the two digests above do NOT hold (theirs is the unpadded
+    program): at the module's own `moe.TILE` a test model's 64 and 32
+    are no whole tiles, and every grouped product of its whole gradient,
+    remat and all, runs at whole tiles, while the gradient keeps the
+    parameters' shapes."""
+    from tests.test_routed_walk_forms import equations
+
+    tests = importlib.import_module(f"tests.test_{name}")
+    model = tests.model_of(tests.CONFIG, bf16=True, remat=True)
+    features = {"input_ids": jnp.zeros((2, 128), jnp.int32)}
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0), features)
+    state = {k: v for k, v in shapes.items() if k != "params"}
+    closed, grads = jax.make_jaxpr(jax.grad(lambda params: model.apply(
+        {"params": params, **state}, features, mutable=True
+    )[0].astype(jnp.float32).mean()), return_shape=True)(shapes["params"])
+    products = equations(closed.jaxpr, "ragged_dot_general")
+    assert products
+    for eqn in products:
+        rows, stack = (operand.aval.shape for operand in eqn.invars[:2])
+        assert rows[-1] % moe.TILE == 0, (rows, stack)
+        assert all(dim % moe.TILE == 0 for dim in stack[-2:]), (rows, stack)
+    assert jax.tree.map(lambda g: g.shape, grads) == jax.tree.map(
+        lambda p: p.shape, shapes["params"]
+    )
 
 
 @pytest.mark.parametrize("name", ZOOS + ["glm_moe_lite"])

@@ -3,8 +3,10 @@ gated `swiglu` over a fused gate-and-up stack and the non-gated `relu2`
 over an up stack alone, each against a per-expert dense sum (forward and
 every gradient) at loads that leave a dead tail, an empty expert, no row
 and every row; what the backward holds, by form; the leaves a layer of
-each form builds; and the `swiglu` walk at a sibling cell's shape traced
-to what it was traced to before the form entered."""
+each form builds; the `swiglu` walk at a sibling cell's shape traced
+to what it was traced to before the form entered; and the grouped
+products' operands padded to whole `TILE`s (every case above at a tile
+that pads both axes, one, and neither)."""
 
 import hashlib
 
@@ -67,11 +69,21 @@ def dense_sum(form, tokens, w_first, w_down, weights, key):
     return out
 
 
+# HIDDEN 32 and FFN 24 against the tile: both axes padded far (the module's
+# own tile), both padded to 40 (neither a multiple of 20), the expert's
+# width alone (to 32), neither
+TILES = {"module": None, "both": 20, "ffn_only": 16, "neither": 8}
+
+
+@pytest.mark.parametrize("tile", sorted(TILES))
 @pytest.mark.parametrize("form", sorted(moe.FORMS))
 @pytest.mark.parametrize("load", sorted(LOADS))
 def test_a_walk_of_either_form_is_the_per_expert_dense_sum(
-        load, form, monkeypatch):
+        load, form, tile, monkeypatch):
     monkeypatch.setattr(moe, "CHUNK", CHUNK)
+    if TILES[tile]:
+        monkeypatch.setattr(moe, "TILE", TILES[tile])
+    assert (moe.padded_work(HIDDEN, FFN) > 0) == (tile != "neither")
     g = given(form, LOADS[load])
     cotangent = jnp.asarray(
         np.random.RandomState(5).randn(TOKENS, HIDDEN), jnp.float32
@@ -101,6 +113,8 @@ def test_a_walk_of_either_form_is_the_per_expert_dense_sum(
     close = dict(rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(out, want, **close)
     for name, got, ref in zip(leaves, got_grads, want_grads):
+        # the padding is cut off before a cotangent leaves the walk
+        assert (got.shape, got.dtype) == (g[name].shape, g[name].dtype)
         np.testing.assert_allclose(got, ref, err_msg=name, **close)
     # an expert no slot chose gets no gradient, a slot of no held expert
     # no weight's
@@ -128,7 +142,7 @@ def test_the_forms_differ_and_the_default_is_swiglu():
 def test_walk_bytes_by_form():
     """The backward's four buffers take the form's widths: (hidden, 2
     ffn, ffn, hidden) gated, (hidden, ffn, ffn, hidden) squared."""
-    tokens, hidden, top_k, ffn = 16384, 2688, 6, 1856
+    tokens, hidden, top_k, ffn = 16384, 2048, 6, 1536   # whole tiles
     chunk, total = moe._chunks(tokens * top_k)
     assert (chunk, total) == (16384, 6)
     sums = 3 * tokens * hidden * 4
@@ -139,6 +153,24 @@ def test_walk_bytes_by_form():
         (total + 2) * chunk * (2 * hidden + 2 * ffn) * 2 + sums
     )
     # the GLM cell's layer, as before the form entered
+    assert moe.walk_bytes(16384, 2048, 4, 1536, 2) == (
+        6 * 16384 * (2 * 2048 + 3 * 1536) * 2 + 3 * 16384 * 2048 * 4
+    )
+
+
+@pytest.mark.parametrize("tile, hidden, ffn", [
+    (128, 2688, 1920), (256, 2816, 2048), (512, 3072, 2048),
+])
+def test_walk_bytes_at_the_padded_widths(tile, hidden, ffn, monkeypatch):
+    """The four buffers are allocated at whole tiles (the sums stay the
+    tokens' own width); a layer whose widths are whole reads as before."""
+    monkeypatch.setattr(moe, "TILE", tile)
+    tokens, top_k = 16384, 6
+    chunk, total = moe._chunks(tokens * top_k)
+    assert moe.walk_bytes(tokens, 2688, top_k, 1856, 2, moe.RELU2) == (
+        (total + 2) * chunk * (2 * hidden + 2 * ffn) * 2
+        + 3 * tokens * 2688 * 4
+    )
     assert moe.walk_bytes(16384, 2048, 4, 1536, 2) == (
         6 * 16384 * (2 * 2048 + 3 * 1536) * 2 + 3 * 16384 * 2048 * 4
     )
@@ -184,3 +216,71 @@ def test_the_swiglu_walk_is_the_parents():
     ))
     assert "0x" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == SWIGLU_JAXPR
+
+
+def walk_jaxpr(form, tokens, hidden, ffn, top_k, held=8):
+    """The jaxpr of the walk's forward and backward at a cell's bfloat16
+    shape (abstract: nothing is computed)."""
+    shaped = jax.ShapeDtypeStruct
+    slots = tokens * top_k
+    return jax.make_jaxpr(jax.grad(
+        lambda t, a, b, o, w, g: moe.routed_walk(t, a, b, o, w, g, form).sum(),
+        argnums=(0, 1, 2, 4),
+    ))(
+        shaped((tokens, hidden), jnp.bfloat16),
+        shaped((held, hidden, moe.FORMS[form][1] * ffn), jnp.bfloat16),
+        shaped((held, ffn, hidden), jnp.bfloat16),
+        shaped((slots,), jnp.int32), shaped((slots,), jnp.float32),
+        shaped((held,), jnp.int32),
+    )
+
+
+def equations(jaxpr, name):
+    """Every equation called `name`, those of inner jaxprs (the loops',
+    a `pjit`'s, a `custom_vjp`'s) included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            found.append(eqn)
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    found.extend(equations(inner, name))
+    return found
+
+
+def test_nemotrons_grouped_products_run_at_whole_tiles():
+    """16,384 tokens of 2,688, eight `relu2` experts 1,856 wide, top-6:
+    every `ragged_dot` of the walk, forward, backward and the two stack
+    gradients, has operands whose every width is whole tiles, and the
+    four cotangents the arguments' shapes."""
+    closed = walk_jaxpr(moe.RELU2, 16384, 2688, 1856, 6)
+    products = equations(closed.jaxpr, "ragged_dot_general")
+    # eight on the device; `jax.vjp` of a stack's gradient traces its
+    # forward too, dead code
+    assert len(products) >= 8
+    widths = {
+        dim for eqn in products for operand in eqn.invars[:2]
+        for dim in operand.aval.shape[-2:] if dim != 16384 * 6
+    }
+    assert widths and all(dim % moe.TILE == 0 for dim in widths), widths
+    assert moe._whole(1856) in widths and 1856 not in widths
+    assert [(v.aval.shape, v.aval.dtype) for v in closed.jaxpr.outvars] == [
+        ((16384, 2688), jnp.bfloat16), ((8, 2688, 1856), jnp.bfloat16),
+        ((8, 1856, 2688), jnp.bfloat16), ((98304,), jnp.float32),
+    ]
+
+
+@pytest.mark.parametrize("cell, shape", [
+    ("glm_lfm2", (moe.SWIGLU, 16384, 2048, 1536, 4)),
+    ("laguna", (moe.SWIGLU, 16384, 2048, 512, 8)),
+    ("kimi", (moe.SWIGLU, 16384, 2304, 1024, 8)),
+])
+def test_a_walk_at_whole_tiles_pads_no_stack(cell, shape):
+    """The siblings' widths are whole tiles: the only `pad` of the walk is
+    the sorted buffer's (one axis), none of a stack or of the tokens."""
+    closed = walk_jaxpr(*shape)
+    pads = equations(closed.jaxpr, "pad")
+    assert all(eqn.invars[0].aval.ndim == 1 for eqn in pads), pads
+    assert moe.padded_work(shape[2], shape[3]) == 0.0

@@ -8,6 +8,8 @@ The topology is described inside a fixture, never at import: only one
 process may hold the TPU's library, and every xdist worker imports every
 test file.  Keep such tests in THIS file."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -381,12 +383,18 @@ def test_nemotron_cell_kernels_compile_for_the_chip(one_chip, monkeypatch):
         shaped((tokens * top_k,), jnp.float32), shaped((held,), jnp.int32),
         argnums=(0, 1, 2, 4),
     )
-    # two products an expert forward, rebuilt in the backward, two to the
-    # rows and one to each stack: eight grouped kernels, the up stack's at
-    # the expert's own width (no gate beside it)
-    assert walk.count("= bf16[16384,1856]") >= 2
-    assert "bf16[16384,3712]" not in walk
-    assert "ragged-dot" in walk
+    # two products an expert forward (dead here: only gradients leave),
+    # rebuilt in the backward, two to the rows and one to each stack: six
+    # grouped kernels, the up stack's at the expert's own width (no gate
+    # beside it) padded to whole tiles (`moe.TILE`), which the compiler
+    # then tiles no narrower than that
+    ffn = moe._whole(width)
+    assert walk.count(f"= bf16[16384,{ffn}]") >= 2
+    assert "= bf16[16384,1856]" not in walk
+    assert f"bf16[16384,{2 * ffn}]" not in walk
+    tilings = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', walk)
+    assert len(tilings) >= 6
+    assert all(int(t) >= moe.TILE for tiling in tilings for t in tiling)
 
 
 def test_the_scope_table_reads_a_text_compiled_for_the_chip(one_chip):
