@@ -63,6 +63,50 @@ class RMSNorm(nn.Module):
         return rms_norm(x, scale, self.eps).astype(self.dtype)
 
 
+# A statistic a GROUP of channels, taken where the channels lie.  The plain
+# way, `rms_norm` over a (..., groups, channels / groups) view, costs a
+# layer's arrays their layout on the chip: a (2, 8192, 4096) float32 array
+# is tiled (8 tokens x 128 channels), the view's reduce wants (8 groups x
+# 128 channels), and XLA copies the whole float32 gated product from one
+# tiling to the other before each of the three reduces (forward, the
+# remat's forward, backward), writes the spread-back statistic out as a
+# whole (..., 8, 512) array and copies that back three times: 10.5 GB of
+# traffic a layer where one group moves 3.3.  Here the sum of squares and
+# the spread-back are products with a 0/1 (channels, groups) matrix, so
+# every whole array stays (tokens, channels) with the channels along the
+# lanes; at `precision="highest"` the products are exact in float32 (the
+# matrix is 0s and 1s; the default would round the squares to bfloat16).
+# Placed by PR 52's chip probe (v5e; Nemotron's Mamba-2 layer alone, (2,
+# 8192) tokens of 2,688, 4,096 channels in 8 groups, forward + backward
+# under the zoo's remat, three traced calls; ms a layer: the whole layer |
+# `ssm/gated_norm` | copies and fusions without a scope | `ssm/out`):
+#   `rms_norm` over the view                   55.90 | 9.73 | 2.47 | 7.48
+#   0/1 products (this)                        45.86 | 3.89 | 0.01 | 6.17
+#   the sums a product, `jnp.repeat` back      49.85 | 5.20 | 0.83 | 7.64
+#   static 512-wide slices, autodiff           48.46 | 4.98 | 2.13 | 6.44
+#   the same, sliced before the gate           46.75 | 3.91 | 1.08 | 6.30
+#   `lax.reduce_window` + `jnp.repeat`         55.15 | 9.10 | 0.83 | 9.17
+#   slices in a jnp `custom_vjp`               49.53 | 4.53 | 1.29 | 7.64
+#   Pallas, a (512 rows, group) block          45.66 | 2.28 | 1.32 | 5.97
+# The kernel pair ties it (XLA copies the z slice out for them, twice a
+# step) and would need this form beside it where a group is no whole lane
+# tile (PERF.md section 6, PR 52).
+def grouped_rms_norm(x, scale, eps: float, groups: int):
+    """`rms_norm` with one statistic for each of `groups` equal runs of
+    the last axis's channels; float32 out."""
+    x = x.astype(jnp.float32)
+    width = x.shape[-1]
+    member = (
+        jnp.arange(width)[:, None] // (width // groups) == jnp.arange(groups)
+    ).astype(jnp.float32)
+    mean = jnp.dot(jnp.square(x), member, precision="highest") / (
+        width // groups
+    )
+    return x * jnp.dot(
+        jax.lax.rsqrt(mean + eps), member.T, precision="highest"
+    ) * scale
+
+
 class GatedRMSNorm(nn.Module):
     """rms_norm(y * silu(z)) * scale: the gate first, then the norm, ONE
     statistic for each of `groups` equal runs of the last axis's channels
@@ -80,10 +124,9 @@ class GatedRMSNorm(nn.Module):
         gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
         if self.groups == 1:
             return rms_norm(gated, scale, self.eps).astype(self.dtype)
-        by_group = (*gated.shape[:-1], self.groups, -1)
-        return rms_norm(
-            gated.reshape(by_group), scale.reshape(self.groups, -1), self.eps
-        ).reshape(gated.shape).astype(self.dtype)
+        return grouped_rms_norm(
+            gated, scale, self.eps, self.groups
+        ).astype(self.dtype)
 
 
 def rotary_turn(x, inv_freq, factor: float = 1.0):

@@ -8,6 +8,7 @@ The topology is described inside a fixture, never at import: only one
 process may hold the TPU's library, and every xdist worker imports every
 test file.  Keep such tests in THIS file."""
 
+import math
 import re
 
 import jax
@@ -395,6 +396,70 @@ def test_nemotron_cell_kernels_compile_for_the_chip(one_chip, monkeypatch):
     tilings = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', walk)
     assert len(tilings) >= 6
     assert all(int(t) >= moe.TILE for tiling in tilings for t in tiling)
+
+
+def _whole_arrays_off_the_channels(text, size=2 * 8192 * 4096):
+    """(the `copy` / `transpose` instructions whose result has `size`
+    elements, the arrays of that many elements whose layout's minor axis
+    is not the last) in a compiled text."""
+    copies, off_lanes = [], set()
+    for line in text.splitlines():
+        found = re.match(
+            r"\s*(?:ROOT )?%[\w.\-]+ = (\w+)\[([\d,]+)\]\{([\d,]+)[^ ]* "
+            r"([\w\-]+)\(", line,
+        )
+        if not found:
+            continue
+        dtype, dims, layout, op = found.groups()
+        dims = [int(d) for d in dims.split(",")]
+        if math.prod(dims) != size:
+            continue
+        if op in ("copy", "transpose"):
+            copies.append(f"{dtype}{dims}")
+        if int(layout.split(",")[0]) != len(dims) - 1:
+            off_lanes.add(f"{dtype}{dims}{{{layout}}}")
+    return copies, sorted(off_lanes)
+
+
+@pytest.mark.parametrize("form", ["grouped", "view"])
+def test_the_grouped_gated_norm_keeps_the_channels_along_the_lanes(
+    one_chip, monkeypatch, form
+):
+    """The Nemotron cell's gated norm, (2, 8192, 4096) in 8 groups of 512,
+    followed by the (4,096 x 2,688) out projection, forward and gradient:
+    no whole-array copy or transpose, and no whole array whose minor axis
+    is not the channels.  The control is the view form, `rms_norm` over
+    (..., 8, 512): XLA lays its arrays out tokens-minor and copies them
+    (in the cell's whole step: three float32 copies a layer from the (8
+    tokens x 128 channels) tiling to (8 groups x 128 channels))."""
+    from model_zoo.common import decoder
+
+    if form == "view":
+        def view(x, scale, eps, groups):
+            by_group = (*x.shape[:-1], groups, -1)
+            return decoder.rms_norm(
+                x.reshape(by_group), scale.reshape(groups, -1), eps
+            ).reshape(x.shape)
+
+        monkeypatch.setattr(decoder, "grouped_rms_norm", view)
+    norm = decoder.GatedRMSNorm(1e-5, jnp.bfloat16, 8)
+
+    def loss(y, z, scale, kernel):
+        out = jnp.dot(norm.apply({"params": {"scale": scale}}, y, z), kernel)
+        return jnp.square(out.astype(jnp.float32)).mean()
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
+        shaped((2, 8192, 4096)), shaped((2, 8192, 4096)),
+        shaped((4096,), jnp.float32), shaped((4096, 2688)),
+    ).compile().as_text()
+    copies, off_lanes = _whole_arrays_off_the_channels(text)
+    if form == "grouped":
+        assert not copies and not off_lanes, (copies, off_lanes)
+    else:
+        assert copies and off_lanes
 
 
 def test_the_scope_table_reads_a_text_compiled_for_the_chip(one_chip):
