@@ -78,6 +78,10 @@ DEVICE_SCOPES = (
     "nemotron/embed", "nemotron/norm", "nemotron/ssm/proj",
     "nemotron/ssm/conv", "nemotron/ssm/core", "nemotron/ssm/gated_norm",
     "nemotron/ssm/out", "nemotron/attn", "nemotron/moe", "nemotron/head_ce",
+    "qwen3_next/embed", "qwen3_next/norm", "qwen3_next/gdn/proj",
+    "qwen3_next/gdn/conv", "qwen3_next/gdn/decay", "qwen3_next/gdn/core",
+    "qwen3_next/gdn/out", "qwen3_next/attn", "qwen3_next/moe",
+    "qwen3_next/head_ce",
 )
 
 #: Kernels the TPU's compiler makes from ONE primitive and names after

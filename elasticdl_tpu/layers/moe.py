@@ -246,6 +246,14 @@ FORMS = {
     RELU2: ("expert_w_up", 1, _relu2),          # (relu(x Wu))^2 Wd: no gate
 }
 
+# The router's scores over its float32 logits (n, num_experts), chosen
+# like `FORMS`: each expert's own sigmoid, or one softmax over all.
+SIGMOID, SOFTMAX = "sigmoid", "softmax"
+SCORES = {
+    SIGMOID: jax.nn.sigmoid,
+    SOFTMAX: functools.partial(jax.nn.softmax, axis=-1),
+}
+
 
 def _chunks(slots: int):
     """(rows a trip, trips over the whole worst-case buffer)."""
@@ -574,10 +582,10 @@ class MoEMLP(nn.Module):
 
 
 class RoutedExperts(nn.Module):
-    """Sigmoid top-k routed experts, this holder's part: (..., hidden)
-    -> (..., hidden) in float32.
+    """Top-k routed experts, this holder's part: (..., hidden) ->
+    (..., hidden) in float32.
 
-        s = sigmoid(x Wr)                     float32, all `num_experts`
+        s = sigmoid(x Wr) | softmax(x Wr)     float32, all `num_experts`
         S = top_k(s + b)                      b selects, never weighs
         w_i = routed_scaling * s_i / (sum_{j in S} s_j + renorm_eps)
         out = sum_{i in S, i held here} w_i Expert_i(x)
@@ -594,6 +602,10 @@ class RoutedExperts(nn.Module):
                       `lfm2_moe`); 0.0 adds nothing to the program
     form:             an expert's activation (`FORMS`): `swiglu`, (silu(x
                       Wg) * (x Wu)) Wd, or `relu2`, (relu(x Wu))^2 Wd
+    scores:           the router's scores (`SCORES`): `sigmoid`, each
+                      expert's own, or `softmax` over all `num_experts`;
+                      softmax scores are picked as they are: no selection
+                      bias b and no ROUTER_STATE buffer
 
     Expert stacks are the form's first stack (`expert_w_gate_up`, gate
     and up fused, or `expert_w_up`) and `expert_w_down`, no biases;
@@ -616,11 +628,13 @@ class RoutedExperts(nn.Module):
     dtype: jnp.dtype = jnp.float32
     renorm_eps: float = 0.0
     form: str = SWIGLU
+    scores: str = SIGMOID
 
     @nn.compact
     def __call__(self, x):
         *lead, hidden = x.shape
         first_name, first_width, _ = FORMS[self.form]
+        score_fn, biased = SCORES[self.scores], self.scores == SIGMOID
         with jax.named_scope("dispatch"):
             tokens = x.reshape(-1, hidden)
         n, k = tokens.shape[0], self.top_k
@@ -631,24 +645,25 @@ class RoutedExperts(nn.Module):
                 "router_kernel", nn.initializers.lecun_normal(),
                 (hidden, self.num_experts), jnp.float32,
             )
-            bias = self.variable(
-                ROUTER_STATE, "e_score_correction_bias",
-                lambda: jnp.zeros((self.num_experts,), jnp.float32),
-            )
-            scores = jax.nn.sigmoid(jnp.dot(
+            scores = score_fn(jnp.dot(
                 tokens.astype(jnp.float32), w_router,
                 precision=jax.lax.Precision.HIGHEST,
             ))
-            _, idx = jax.lax.top_k(
-                jax.lax.stop_gradient(scores) + bias.value, k
-            )                                               # (n, k)
+            selection = jax.lax.stop_gradient(scores)
+            if biased:
+                bias = self.variable(
+                    ROUTER_STATE, "e_score_correction_bias",
+                    lambda: jnp.zeros((self.num_experts,), jnp.float32),
+                )
+                selection = selection + bias.value
+            _, idx = jax.lax.top_k(selection, k)            # (n, k)
             picked = jnp.take_along_axis(scores, idx, axis=1)
             total = picked.sum(axis=1, keepdims=True)
             if self.renorm_eps:
                 total = total + self.renorm_eps
             weights = self.routed_scaling * picked / total
             loads = expert_loads(idx, self.num_experts)
-            if (self.bias_update_rate
+            if (self.bias_update_rate and biased
                     and not self.is_initializing()
                     and self.is_mutable_collection(ROUTER_STATE)):
                 bias.value = bias.value + self.bias_update_rate * jnp.sign(

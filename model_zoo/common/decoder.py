@@ -1,12 +1,15 @@
-"""What the zoo's six decoder language models have in common
+"""What the zoo's seven decoder language models have in common
 (`model_zoo/glm/glm_moe_lite.py`, `laguna/laguna.py`, `lfm2/lfm2_moe.py`,
 `kimi/kimi_linear.py`, `granite/granite_hybrid.py`,
-`nemotron/nemotron_h.py`): RMSNorm and its gated form (one statistic a
-group of channels), rotary's turn, the seeds of a decay (`a_log_init`,
-`dt_bias_init`), the bias-free dense layer, SwiGLU and the non-gated
-squared-ReLU MLP, grouped-query attention without positions
-(`GroupedAttention`), the routed block around
-`layers/moe.py: RoutedExperts` with its shared expert, the cross-entropy
+`nemotron/nemotron_h.py`, `qwen3_next/qwen3_next.py`): RMSNorm (its scale
+plain or zero-centred) and its gated form (one statistic a group of
+channels, the gate before the norm or after it), rotary's turn whole or
+over a head's first columns (`Rope`, `partial_rotary`), the seeds of a
+decay (`a_log_init`, `dt_bias_init`), the bias-free dense layer, SwiGLU
+and the non-gated squared-ReLU MLP, grouped-query attention
+(`GroupedAttention`: no positions, no norms and no gate unless asked
+for), the routed block around `layers/moe.py: RoutedExperts` with its
+shared expert (gated where asked for), the cross-entropy
 taken in blocks of tokens, the per-position losses against the ids
 shifted, the blocks' rematerialisation (`remat_block`, and what more of
 a block it keeps where the device has room: `remat_blocks`), and the zoo
@@ -25,21 +28,49 @@ import numpy as np
 import optax
 from jax.ad_checkpoint import checkpoint_name
 
+from elasticdl_tpu.common import metrics as metrics_lib
+from elasticdl_tpu.layers import step_metrics
 from elasticdl_tpu.layers.embedding import embedding_param_sharding
 from elasticdl_tpu.layers.moe import (
     RELU2,
+    SIGMOID,
     SWIGLU,
     RoutedExperts,
     moe_param_sharding,
     walk_bytes,
 )
-from elasticdl_tpu.layers.step_metrics import STEP_METRICS
-from elasticdl_tpu.ops import flash_attention, kda, ssd
+from elasticdl_tpu.layers.step_metrics import STEP_METRICS, sow_step_metric
+from elasticdl_tpu.ops import flash_attention, gdn, kda, ssd
 from elasticdl_tpu.worker.trainer import remat_kept_ratio
 
 # What a rematerialised block keeps from its forward, by name: ONE policy
 # for every decoder of the zoo.
-SAVED_NAMES = flash_attention.SAVED_NAMES + kda.SAVED_NAMES + ssd.SAVED_NAMES
+SAVED_NAMES = (
+    flash_attention.SAVED_NAMES + kda.SAVED_NAMES + ssd.SAVED_NAMES
+    + gdn.SAVED_NAMES
+)
+
+# What the gates below sow into STEP_METRICS (a gate that closes silences
+# its layer): leaf name -> gauge by layer.
+step_metrics.declare(
+    "query_gate_mean_ratio",
+    metrics_lib.default_registry().gauge(
+        "worker_attention_query_gate_mean_ratio",
+        "mean of an attention layer's query-wide sigmoid output gate over "
+        "tokens, heads and a head's columns, last step of the task (a gate "
+        "that closes silences its layer)",
+        labelnames=("layer",),
+    ),
+)
+step_metrics.declare(
+    "shared_gate_mean_ratio",
+    metrics_lib.default_registry().gauge(
+        "worker_moe_shared_gate_mean_ratio",
+        "mean of a routed layer's sigmoid gate on its shared expert over "
+        "tokens, last step of the task (0: the shared expert is silent)",
+        labelnames=("layer",),
+    ),
+)
 
 # Tokens whose logits exist at once in the cross-entropy.
 CE_BLOCK = 2048
@@ -54,12 +85,21 @@ def rms_norm(x, scale, eps: float):
 
 
 class RMSNorm(nn.Module):
+    """`zero_centred`: the learned `scale` is w of a scale 1 + w, seeded
+    off 0 so that the two forms differ at the seeded weights."""
+
     eps: float = 1e-5
     dtype: jnp.dtype = jnp.float32
+    zero_centred: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        if self.zero_centred:
+            scale = 1.0 + self.param(
+                "scale", nn.initializers.normal(0.1), (x.shape[-1],)
+            )
+        else:
+            scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
         return rms_norm(x, scale, self.eps).astype(self.dtype)
 
 
@@ -108,25 +148,38 @@ def grouped_rms_norm(x, scale, eps: float, groups: int):
 
 
 class GatedRMSNorm(nn.Module):
-    """rms_norm(y * silu(z)) * scale: the gate first, then the norm, ONE
-    statistic for each of `groups` equal runs of the last axis's channels
-    (a state-space mixer's output norm: a statistic a group of heads) and
-    one learned scale over all of them; with one group the norm is over
-    the whole last axis."""
+    """rms_norm(y * silu(z)) * scale with `gate_first` (a state-space
+    mixer's output norm), rms_norm(y) * scale * silu(z) without (a delta
+    rule's): ONE statistic for each of `groups` equal runs of the last
+    axis's channels (a statistic a group of heads, or a head) and one
+    learned scale over all of them, or, with `shared_scale`, one over a
+    group's channels that every group shares; with one group the norm is
+    over the whole last axis."""
 
     eps: float = 1e-5
     dtype: jnp.dtype = jnp.float32
     groups: int = 1
+    gate_first: bool = True
+    shared_scale: bool = False
 
     @nn.compact
     def __call__(self, y, z):
-        scale = self.param("scale", nn.initializers.ones, (y.shape[-1],))
-        gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+        width = y.shape[-1]
+        if self.shared_scale:
+            scale = jnp.tile(self.param(
+                "scale", nn.initializers.ones, (width // self.groups,)
+            ), self.groups)
+        else:
+            scale = self.param("scale", nn.initializers.ones, (width,))
+        y = y.astype(jnp.float32)
+        gate = jax.nn.silu(z.astype(jnp.float32))
+        if self.gate_first:
+            y = y * gate
         if self.groups == 1:
-            return rms_norm(gated, scale, self.eps).astype(self.dtype)
-        return grouped_rms_norm(
-            gated, scale, self.eps, self.groups
-        ).astype(self.dtype)
+            y = rms_norm(y, scale, self.eps)
+        else:
+            y = grouped_rms_norm(y, scale, self.eps, self.groups)
+        return (y if self.gate_first else y * gate).astype(self.dtype)
 
 
 def rotary_turn(x, inv_freq, factor: float = 1.0):
@@ -152,6 +205,37 @@ def rotary(x, theta: float):
     width = x.shape[-1]
     inv_freq = theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
     return rotary_turn(x, inv_freq)
+
+
+class Rope(NamedTuple):
+    """One kind of layer's rotary table: the first `columns` of a head
+    turn at `inv_freq` (a tuple, so that a config hashes), cos and sin
+    times `factor`."""
+
+    columns: int
+    inv_freq: Tuple[float, ...]
+    factor: float
+
+
+def plain_rope(head_dim: int, theta: float, share: float = 1.0) -> Rope:
+    """theta ** (-2i / R) over the first R = `share` of a head's
+    columns."""
+    columns = int(head_dim * share)
+    inv_freq = float(theta) ** (
+        -np.arange(0, columns, 2, dtype=np.float64) / columns
+    )
+    return Rope(columns, tuple(inv_freq.tolist()), 1.0)
+
+
+def partial_rotary(x, rope: Rope):
+    """Turn the first `rope.columns` columns of (B, L, H, D)."""
+    inv_freq = jnp.asarray(rope.inv_freq, jnp.float32)
+    if rope.columns == x.shape[-1]:
+        return rotary_turn(x, inv_freq, rope.factor)
+    turned, kept = jnp.split(x, [rope.columns], axis=-1)
+    return jnp.concatenate(
+        [rotary_turn(turned, inv_freq, rope.factor), kept], axis=-1
+    )
 
 
 def tap_init(key, shape, dtype=jnp.float32):
@@ -223,8 +307,12 @@ MLP_OF_FORM = {SWIGLU: SwiGLU, RELU2: ReLU2MLP}
 
 
 class GroupedAttention(nn.Module):
-    """`heads` query heads over `kv_heads` key/value heads, causal, no
-    positions and no norms, the logits times `scale`."""
+    """`heads` query heads over `kv_heads` key/value heads, causal, the
+    logits times `scale`.  As it stands: no positions, no norms, no gate.
+    `qk_norm_eps` norms q and k a head (a zero-centred scale each, `q_norm`
+    and `k_norm`) before `rope` turns a head's first columns; with
+    `query_gate` the q projection is twice as wide, a head's columns split
+    q | gate, and the output is times sigmoid(gate), element by element."""
 
     hidden: int
     heads: int
@@ -233,6 +321,9 @@ class GroupedAttention(nn.Module):
     scale: float
     dtype: jnp.dtype = jnp.float32
     trace_scope: str = "attn"
+    qk_norm_eps: Optional[float] = None
+    rope: Optional[Rope] = None
+    query_gate: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -240,16 +331,32 @@ class GroupedAttention(nn.Module):
         heads, kv_heads, dim = self.heads, self.kv_heads, self.head_dim
         with jax.named_scope(self.trace_scope):
             q, k, v = (
-                dense(count * dim, name, self.dtype, MIXER_IN)(x).reshape(
-                    batch, length, count, dim
+                dense(count * width, name, self.dtype, MIXER_IN)(x).reshape(
+                    batch, length, count, width
                 )
-                for name, count in (
-                    ("q", heads), ("k", kv_heads), ("v", kv_heads)
+                for name, count, width in (
+                    ("q", heads, dim * (1 + self.query_gate)),
+                    ("k", kv_heads, dim), ("v", kv_heads, dim),
                 )
             )
+            if self.query_gate:
+                q, gate = jnp.split(q, 2, axis=-1)
+            if self.qk_norm_eps is not None:
+                q, k = (
+                    RMSNorm(self.qk_norm_eps, self.dtype, True, name=name)(t)
+                    for name, t in (("q_norm", q), ("k_norm", k))
+                )
+            if self.rope is not None:
+                q, k = partial_rotary(q, self.rope), partial_rotary(
+                    k, self.rope
+                )
             out = flash_attention.causal_attention(
                 q, k, v, scale=self.scale
             )
+            if self.query_gate:
+                gate = jax.nn.sigmoid(gate.astype(jnp.float32))
+                sow_step_metric(self, "query_gate_mean_ratio", gate.mean())
+                out = (out * gate).astype(self.dtype)
             return dense(self.hidden, "o", self.dtype, MIXER_OUT)(
                 out.reshape(batch, length, heads * dim)
             )
@@ -260,7 +367,9 @@ class MoEFFN(nn.Module):
     holder's part of the routed experts; `shared_experts` 0 builds no
     shared expert.  The shared expert is `shared_experts * expert_width`
     wide, or `shared_width` where the model gives it a width of its own;
-    `form` is every expert's, routed and shared alike."""
+    `form` is every expert's, routed and shared alike, `scores` the
+    router's (`layers/moe.py: SCORES`); with `shared_gate` the shared
+    expert's output is times one sigmoid a token of the layer's input."""
 
     hidden: int
     num_experts: int
@@ -275,6 +384,8 @@ class MoEFFN(nn.Module):
     renorm_eps: float = 0.0
     form: str = SWIGLU
     shared_width: Optional[int] = None
+    scores: str = SIGMOID
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -284,7 +395,8 @@ class MoEFFN(nn.Module):
                 ffn_dim=self.expert_width, held_experts=self.held_experts,
                 routed_scaling=self.routed_scaling,
                 bias_update_rate=self.bias_update_rate, dtype=self.dtype,
-                renorm_eps=self.renorm_eps, form=self.form, name="routed",
+                renorm_eps=self.renorm_eps, form=self.form,
+                scores=self.scores, name="routed",
             )(x)
             if not self.shared_experts:
                 with jax.named_scope("combine"):
@@ -296,6 +408,17 @@ class MoEFFN(nn.Module):
                     or self.shared_experts * self.expert_width,
                     self.dtype, name="shared",
                 )(x)
+                if self.shared_gate:
+                    # one number a token: sigmoid(x w_sg)
+                    gate = jax.nn.sigmoid(
+                        dense(1, "shared_gate", self.dtype)(x).astype(
+                            jnp.float32
+                        )
+                    )
+                    sow_step_metric(
+                        self, "shared_gate_mean_ratio", gate.mean()
+                    )
+                    shared = gate * shared
             with jax.named_scope("combine"):
                 return (
                     routed + shared.astype(jnp.float32)

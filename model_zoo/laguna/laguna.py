@@ -51,30 +51,21 @@ from model_zoo.common.decoder import (  # noqa: F401
     MIXER_OUT,
     MoEFFN,
     RMSNorm,
+    Rope,
     SwiGLU,
     dense,
     eval_metrics_fn,
     loss,
     optimizer,
     param_sharding,
+    partial_rotary,
+    plain_rope,
     remat_blocks,
     routed_walks,
-    rotary_turn,
     shifted_nll,
 )
 
 FULL, WINDOW = "full_attention", "sliding_attention"
-
-
-@dataclasses.dataclass(frozen=True)
-class Rope:
-    """One kind of layer's rotary table: the first `columns` of a head
-    turn at `inv_freq` (a tuple, so that the config hashes), cos and sin
-    times `factor`."""
-
-    columns: int
-    inv_freq: Tuple[float, ...]
-    factor: float
 
 
 def rope_of(parameters: dict, head_dim: int) -> Rope:
@@ -84,11 +75,13 @@ def rope_of(parameters: dict, head_dim: int) -> Rope:
     context stay, those that turn less than `beta_slow` times are divided
     by `factor`, a linear ramp between; cos and sin times
     `attention_factor`."""
-    columns = int(head_dim * parameters.get("partial_rotary_factor", 1))
     theta = float(parameters["rope_theta"])
-    plain = theta ** (-np.arange(0, columns, 2, dtype=np.float64) / columns)
+    rope = plain_rope(
+        head_dim, theta, parameters.get("partial_rotary_factor", 1)
+    )
     if parameters.get("rope_type", "default") == "default":
-        return Rope(columns, tuple(plain.tolist()), 1.0)
+        return rope
+    columns, plain = rope.columns, np.asarray(rope.inv_freq)
     if parameters["rope_type"] != "yarn":
         raise ValueError(f"rope_type {parameters['rope_type']!r}")
     original = parameters["original_max_position_embeddings"]
@@ -109,17 +102,6 @@ def rope_of(parameters: dict, head_dim: int) -> Rope:
     return Rope(
         columns, tuple(inv_freq.tolist()),
         float(parameters["attention_factor"]),
-    )
-
-
-def partial_rotary(x, rope: Rope):
-    """Turn the first `rope.columns` columns of (B, L, H, D)."""
-    inv_freq = jnp.asarray(rope.inv_freq, jnp.float32)
-    if rope.columns == x.shape[-1]:
-        return rotary_turn(x, inv_freq, rope.factor)
-    turned, kept = jnp.split(x, [rope.columns], axis=-1)
-    return jnp.concatenate(
-        [rotary_turn(turned, inv_freq, rope.factor), kept], axis=-1
     )
 
 

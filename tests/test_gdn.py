@@ -1,0 +1,218 @@
+"""The gated delta rule with ONE decay a head and value heads that share
+key heads (elasticdl_tpu/ops/gdn.py): the chunked `jnp` form and the
+Pallas kernels (interpreted here) against the token-by-token recurrence of
+the plain reference (`benchmarks/reference/qwen3_next.py:
+delta_recurrence`, which shares none of the chunked algebra), forward and
+all five gradients, at one and at two value heads a key head, at no decay,
+at a mild one and at one so strong that `1 / exp(G)` would overflow; a
+length that is no whole number of chunks; the norms inside the op; the
+tie to `ops/kda.py` (the scalar op is `kda` with g broadcast over the
+channels); the admission rule, the names and the types."""
+
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.reference.qwen3_next import delta_recurrence
+from elasticdl_tpu.ops import gdn as gdn_ops
+from elasticdl_tpu.ops import kda as kda_ops
+
+
+def recurrence(q, k, v, g, beta):
+    """q, k (B, L, H_k, D), v (B, L, H_v, D), g and beta (B, L, H_v)
+    through the one-head recurrence, value head h on key head h // r."""
+    ratio = v.shape[2] // q.shape[2]
+    q, k = (jnp.repeat(t, ratio, axis=2) for t in (q, k))
+    one = jax.vmap(jax.vmap(delta_recurrence, in_axes=1, out_axes=1))
+    with jax.default_matmul_precision("highest"):
+        return one(q, k, v, g, beta)
+
+
+def inputs(batch, length, key_heads, ratio, dim, g_min, seed=0,
+           dtype=jnp.float32):
+    """q and k L2-normed a head, as a model hands them over; g uniform in
+    [g_min, 0] a token and value head."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    heads = key_heads * ratio
+    key_shape = (batch, length, key_heads, dim)
+    shape = (batch, length, heads, dim)
+
+    def normed(key, scale):
+        x = jax.random.normal(key, key_shape)
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True) * scale
+
+    return (
+        normed(keys[0], dim ** -0.5).astype(dtype),
+        normed(keys[1], 1.0).astype(dtype),
+        jax.random.normal(keys[2], shape).astype(dtype),
+        g_min * jax.random.uniform(keys[3], shape[:3]),
+        jax.nn.sigmoid(jax.random.normal(keys[4], shape[:3])),
+        jax.random.normal(keys[5], shape),
+    )
+
+
+def value_and_grads(fn, q, k, v, g, beta, weight):
+    with jax.default_matmul_precision("highest"):
+        return jax.value_and_grad(
+            lambda *a: (fn(*a) * weight).sum(), argnums=(0, 1, 2, 3, 4)
+        )(q, k, v, g, beta)
+
+
+def assert_close(got, want, limit, what):
+    error = float(
+        jnp.linalg.norm(got - want) / (jnp.linalg.norm(want) + 1e-30)
+    )
+    assert error < limit, (what, error)
+
+
+# g down to -20 a token: over a chunk of 64 the running sum reaches -1280
+# and exp(+1280) is far past float32 (and float64)
+DECAYS = [
+    pytest.param(0.0, id="no-decay"),
+    pytest.param(-1.0, id="mild"),
+    pytest.param(-20.0, id="strong"),
+]
+FORMS = [
+    pytest.param(gdn_ops.chunked_gdn, id="jnp"),
+    pytest.param(gdn_ops._gdn, id="kernels"),
+]
+RATIOS = [pytest.param(1, id="r1"), pytest.param(2, id="r2")]
+NAMES = ("dq", "dk", "dv", "dg", "dbeta")
+
+
+@pytest.mark.parametrize("g_min", DECAYS)
+@pytest.mark.parametrize("ratio", RATIOS)
+@pytest.mark.parametrize("form", FORMS)
+def test_chunked_forms_match_the_recurrence(form, ratio, g_min):
+    """Two chunks of two key heads of 128: the output, and the gradient
+    of a weighted sum of it by q, k, v, g and beta."""
+    args = inputs(1, 128, 2, ratio, 128, g_min)
+    assert gdn_ops.gdn_shapes_ok(*(a.shape for a in args[:3]))
+    want_out = recurrence(*args[:5])
+    out = form(*args[:5])
+    assert np.isfinite(np.asarray(out)).all()
+    assert_close(out, want_out, 5e-5, "o")
+    _, want = value_and_grads(recurrence, *args)
+    _, got = value_and_grads(form, *args)
+    for name, a, b in zip(NAMES, got, want):
+        assert a.shape == b.shape and np.isfinite(np.asarray(a)).all()
+        assert_close(a, b, 2e-4, name)
+
+
+@pytest.mark.parametrize("ratio", RATIOS)
+def test_the_entry_pads_a_length_that_is_no_whole_chunk(ratio):
+    """80 positions go the `jnp` form, padded to 128 with tokens that
+    leave the state alone; the outputs and gradients are the first 80's."""
+    args = inputs(2, 80, 2, ratio, 16, -2.0, seed=1)
+    assert not gdn_ops.gdn_shapes_ok(*(a.shape for a in args[:3]))
+    out = gdn_ops.gdn(*args[:5])
+    assert out.shape == (2, 80, 2 * ratio, 16)
+    assert_close(out, recurrence(*args[:5]), 5e-5, "o")
+    _, want = value_and_grads(recurrence, *args)
+    _, got = value_and_grads(gdn_ops.gdn, *args)
+    for name, a, b in zip(NAMES, got, want):
+        assert_close(a, b, 2e-4, name)
+    # a chunk of another size is the same number
+    assert_close(
+        gdn_ops.chunked_gdn(*args[:5], chunk=16), out, 5e-5, "chunk 16"
+    )
+
+
+@pytest.mark.parametrize("form", FORMS)
+def test_the_l2_norms_inside_the_op(form):
+    """`qk_norm` = (eps, q's scale): raw q and k go in, the op norms them
+    once a key head; the recurrence on operands normed outside is the
+    same number, and so are the gradients by the RAW q and k."""
+    q, k, v, g, beta, weight = inputs(1, 128, 2, 2, 128, -1.0, seed=4)
+    q, k = 3.0 * q + 0.1, 0.5 * k - 0.05           # no unit rows
+    norm = (1e-6, 128 ** -0.5)
+
+    def plain(q, k, v, g, beta):
+        return recurrence(
+            kda_ops.l2_normed(q, *norm), kda_ops.l2_normed(k, norm[0]),
+            v, g, beta,
+        )
+
+    def inside(q, k, v, g, beta):
+        return form(q, k, v, g, beta, norm)
+
+    assert_close(inside(q, k, v, g, beta), plain(q, k, v, g, beta), 5e-5, "o")
+    _, want = value_and_grads(plain, q, k, v, g, beta, weight)
+    _, got = value_and_grads(inside, q, k, v, g, beta, weight)
+    for name, a, b in zip(NAMES, got, want):
+        assert_close(a, b, 2e-4, name)
+
+
+def test_the_scalar_op_is_kda_with_g_broadcast_over_the_channels():
+    """What ties the new case to the old: one decay a head, handed to
+    `ops/kda.py` as 128 equal channels with q and k repeated to the value
+    heads, is the same output and the same gradients (dg the sum over the
+    channels)."""
+    q, k, v, g, beta, weight = inputs(1, 128, 2, 2, 128, -1.0, seed=7)
+
+    def through_kda(q, k, v, g, beta):
+        q, k = (jnp.repeat(t, 2, axis=2) for t in (q, k))
+        wide = jnp.broadcast_to(g[..., None], (*g.shape, q.shape[-1]))
+        return kda_ops.kda(q, k, v, wide, beta)
+
+    assert_close(
+        gdn_ops.gdn(q, k, v, g, beta), through_kda(q, k, v, g, beta), 5e-5,
+        "o",
+    )
+    _, want = value_and_grads(through_kda, q, k, v, g, beta, weight)
+    _, got = value_and_grads(gdn_ops.gdn, q, k, v, g, beta, weight)
+    for name, a, b in zip(NAMES, got, want):
+        assert_close(a, b, 2e-4, name)
+
+
+def test_bfloat16_operands_keep_float32_state_and_types():
+    """bfloat16 q, k, v: the output is bfloat16, dg and dbeta float32, and
+    the numbers those of the recurrence on the same rounded operands to
+    bfloat16's rounding."""
+    args = inputs(1, 128, 2, 2, 128, -1.0, seed=2, dtype=jnp.bfloat16)
+    out = gdn_ops.gdn(*args[:5])
+    assert out.dtype == jnp.bfloat16
+    want = recurrence(*(a.astype(jnp.float32) for a in args[:5]))
+    assert_close(out.astype(jnp.float32), want, 2e-2, "o")
+    grads = jax.grad(
+        lambda *a: (gdn_ops.gdn(*a).astype(jnp.float32) * args[5]).sum(),
+        argnums=(0, 1, 2, 3, 4),
+    )(*args[:5])
+    assert [x.dtype for x in grads] == [
+        jnp.bfloat16, jnp.bfloat16, jnp.bfloat16, jnp.float32, jnp.float32
+    ]
+    assert [x.shape for x in grads] == [a.shape for a in args[:5]]
+
+
+@pytest.mark.parametrize("shapes,ok", [
+    (((2, 128, 16, 128), (2, 128, 32, 128)), True),
+    (((2, 128, 32, 128), (2, 128, 32, 128)), True),
+    (((2, 128, 16, 128), (2, 128, 24, 128)), False),    # no whole ratio
+    (((2, 100, 16, 128), (2, 100, 32, 128)), False),    # no whole chunks
+    (((2, 128, 16, 64), (2, 128, 32, 128)), False),     # no whole lane tile
+])
+def test_the_admission_rule(shapes, ok):
+    qk, v = shapes
+    assert gdn_ops.gdn_shapes_ok(qk, qk, v) is ok
+
+
+def test_the_kernels_carry_their_own_names():
+    """A device trace tells the scalar kernels from KDA's by name
+    (`kda_core_ms_per_step` takes `kda_*fwd|bwd` alone), and what the
+    forward names for a block's remat is kept by no policy."""
+    from model_zoo.common import decoder
+
+    args = inputs(1, 128, 2, 2, 128, -1.0)
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda *a: gdn_ops.gdn(*a).sum(), argnums=(0, 1, 2, 3, 4)
+    ))(*args[:5]))
+    names = sorted(set(re.findall(r"\b\w+_chunk_(?:fwd|bwd)\b", jaxpr)))
+    assert names == ["gdn_chunk_bwd", "gdn_chunk_fwd"]
+    assert not re.search(r"kda_\w*(fwd|bwd)", jaxpr)
+    for name in gdn_ops.RESULT_NAMES:
+        assert f"name={name}" in jaxpr
+    assert gdn_ops.SAVED_NAMES == ()
+    assert not set(gdn_ops.RESULT_NAMES) & set(decoder.SAVED_NAMES)

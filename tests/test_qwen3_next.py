@@ -1,0 +1,942 @@
+"""The Qwen3-Next decoder (model_zoo/qwen3_next/qwen3_next.py) at tiny
+widths on the CPU, seeded weights: the gated delta rule with one decay a
+head and two value heads a key head, grouped attention with QK-norm, a
+rotated quarter and a query-wide gate, softmax-routed experts beside a
+sigmoid-gated shared one, zero-centred norms and the untied head against
+the plain float32 reference leaf by leaf (its delta rule the
+token-by-token recurrence, its experts a dense sum), through the jnp forms
+and through the interpreted kernels; each mechanism alone; the SHARE test
+(every holder's routed part plus the gated shared expert once is the uncut
+layer); controls that each part of the mathematics must fail; bfloat16
+inside the twin's rule; the sown gauges; the published sizes' parameter
+count; and a two-task job through the CLI."""
+
+import functools
+import json
+import os
+import threading
+import types
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks import datagen, trees
+from benchmarks.reference import qwen3_next as reference
+from elasticdl_tpu.layers import moe
+from elasticdl_tpu.layers.step_metrics import AUX_LOSS, STEP_METRICS
+from elasticdl_tpu.ops import gdn as gdn_ops
+from elasticdl_tpu.ops import short_conv
+from model_zoo.common import decoder
+from model_zoo.qwen3_next import qwen3_next as zoo
+from tests import remat_cases
+
+ROOT = os.path.join(os.path.dirname(__file__), "..")
+# one whole period of the published pattern (GDN, GDN, GDN, attention):
+# 2 key heads and 4 value heads of 8, a conv of 4 taps over 48 channels,
+# 4 query heads of 16 (hidden / heads is 8) over 2 K/V heads with the first
+# 4 columns rotated, top-3 of 16 softmax-routed experts 24 wide with 8
+# held, a gated shared expert 24 wide
+CONFIG = dict(
+    hidden_size=32, num_hidden_layers=4, full_attention_interval=4,
+    layers_held=[0, 1, 2, 3], num_attention_heads=4, num_key_value_heads=2,
+    head_dim=16, partial_rotary_factor=0.25, rope_theta=1e7,
+    linear_num_key_heads=2, linear_num_value_heads=4, linear_key_head_dim=8,
+    linear_value_head_dim=8, linear_conv_kernel_dim=4,
+    moe_intermediate_size=24, shared_expert_intermediate_size=24,
+    num_experts=8, num_experts_published=16, num_experts_per_tok=3,
+    held_experts=[4, 8], vocab_size=50, rms_norm_eps=1e-6, use_bf16=True,
+)
+# (`ROUTER_STATE` is no collection of this model: the sigmoid-scored
+# controls and the sibling models fill it)
+MUTABLE = [AUX_LOSS, STEP_METRICS, moe.ROUTER_STATE]
+GDN_LEAVES, ATTENTION_LEAVES, EXPERT_LEAVES = 7, 6, 6
+
+
+def model_of(config, **overrides):
+    sizes = dict(
+        hidden=config["hidden_size"], num_layers=48,
+        full_attention_interval=config["full_attention_interval"],
+        layers=config["layers_held"], heads=config["num_attention_heads"],
+        kv_heads=config["num_key_value_heads"], head_dim=config["head_dim"],
+        partial_rotary_factor=config["partial_rotary_factor"],
+        rope_theta=config["rope_theta"],
+        gdn_key_heads=config["linear_num_key_heads"],
+        gdn_value_heads=config["linear_num_value_heads"],
+        gdn_head_dim=config["linear_key_head_dim"],
+        conv_kernel=config["linear_conv_kernel_dim"],
+        expert_width=config["moe_intermediate_size"],
+        shared_width=config["shared_expert_intermediate_size"],
+        num_experts=config["num_experts_published"],
+        top_k=config["num_experts_per_tok"],
+        held_experts=config["held_experts"],
+        vocab_size=config["vocab_size"], eps=config["rms_norm_eps"],
+        remat=True,
+    )
+    sizes.update(overrides)
+    return zoo.custom_model(**sizes)
+
+
+def ids_of(rows, length=80, seed=0):
+    return np.random.RandomState(seed).randint(
+        0, CONFIG["vocab_size"], (rows, length)
+    ).astype(np.int32)
+
+
+def loss_and_grads(model, variables, ids, room=None):
+    """The objective the Trainer builds: the mean of the model's
+    per-position losses (this model sows no auxiliary loss)."""
+    state = {k: v for k, v in variables.items() if k != "params"}
+
+    def loss_of(params):
+        out, _ = model.apply(
+            {"params": params, **state}, {"input_ids": ids}, mutable=MUTABLE,
+            **({} if room is None else {"room": room}),
+        )
+        return zoo.loss(None, out.astype(jnp.float32))
+
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.value_and_grad(loss_of)(variables["params"])
+    return float(loss), {
+        k: np.asarray(v, np.float32) for k, v in trees.flat(grads).items()
+    }
+
+
+def seeded_of(config, ids):
+    model = model_of(config)
+    variables = model.init(jax.random.PRNGKey(0), {"input_ids": ids})
+    flat = {
+        k: np.asarray(v) for k, v in trees.flat(variables["params"]).items()
+    }
+    want_loss, want = reference.loss_and_grads(
+        flat, {"input_ids": ids}, None, config
+    )
+    return types.SimpleNamespace(
+        ids=ids, variables=variables, flat=flat, want_loss=want_loss,
+        want={k: np.asarray(v) for k, v in want.items()},
+    )
+
+
+@pytest.fixture(scope="module")
+def seeded():
+    # 80 positions: the scan's jnp form pads them to two chunks of 64
+    return seeded_of(CONFIG, ids_of(8, seed=5))
+
+
+def worst_leaf(got, want):
+    assert set(got) == set(want)
+    errors = {
+        name: np.linalg.norm(got[name] - ref) / np.linalg.norm(ref)
+        for name, ref in want.items()
+    }
+    name = max(errors, key=errors.get)
+    return name, errors[name]
+
+
+def test_float32_matches_reference_leaf_by_leaf(seeded):
+    model = model_of(CONFIG)
+    assert list(model.config.layers) == [True, True, True, False]
+    assert set(seeded.variables) == {"params", STEP_METRICS}   # no buffer
+    loss, got = loss_and_grads(model, seeded.variables, seeded.ids)
+    assert abs(loss - seeded.want_loss) < 1e-4 * abs(seeded.want_loss)
+    # two norms a layer beside a GDN mixer's 7 leaves or attention's 6,
+    # and the routed layer's 6 (router, two stacks, the shared expert's
+    # two kernels, its gate); the embedding, the untied head, the final
+    # norm
+    assert len(got) == (
+        3 * GDN_LEAVES + ATTENTION_LEAVES + 4 * (EXPERT_LEAVES + 2) + 3
+    )
+    assert got["layer_0/gdn/qkvz/kernel"].shape == (32, 16 + 16 + 32 + 32)
+    assert got["layer_0/gdn/ba/kernel"].shape == (32, 8)
+    assert got["layer_0/gdn/conv_kernel"].shape == (4, 64)
+    assert got["layer_0/gdn/A_log"].shape == (4,)
+    assert got["layer_0/gdn/o_norm/scale"].shape == (8,)
+    assert got["layer_3/attn/q/kernel"].shape == (32, 4 * 2 * 16)
+    assert got["layer_3/attn/k/kernel"].shape == (32, 32)
+    assert got["layer_3/attn/q_norm/scale"].shape == (16,)
+    assert got["layer_1/moe/routed/router_kernel"].shape == (32, 16)
+    assert got["layer_1/moe/routed/expert_w_gate_up"].shape == (8, 32, 48)
+    assert got["layer_1/moe/shared_gate/kernel"].shape == (32, 1)
+    name, error = worst_leaf(got, seeded.want)
+    assert error < 1e-4, (name, error)
+
+
+def test_kernels_match_reference_leaf_by_leaf():
+    """One key head and two value heads of 128 at 128 positions (two
+    chunks: the state crosses a boundary), the SiLU conv at 512 columns,
+    the streaming attention at two query heads of 128 over one K/V head
+    with 32 columns rotated, and the routed layers, all interpreted
+    here."""
+    from elasticdl_tpu.ops.flash_attention import stream_shapes_ok
+
+    config = dict(
+        CONFIG, hidden_size=128, linear_num_key_heads=1,
+        linear_num_value_heads=2, linear_key_head_dim=128,
+        linear_value_head_dim=128, num_attention_heads=2,
+        num_key_value_heads=1, head_dim=128, layers_held=[2, 3],
+        num_hidden_layers=2,
+    )
+    assert gdn_ops.gdn_shapes_ok(
+        (1, 128, 1, 128), (1, 128, 1, 128), (1, 128, 2, 128)
+    )
+    assert short_conv.silu_conv_shapes_ok((1, 128, 512), (4, 512))
+    assert stream_shapes_ok((1, 128, 2, 128), (1, 128, 1, 128),
+                            (1, 128, 1, 128))
+    seeded = seeded_of(config, ids_of(1, length=128, seed=2))
+    loss, got = loss_and_grads(model_of(config), seeded.variables, seeded.ids)
+    assert abs(loss - seeded.want_loss) < 1e-4 * abs(seeded.want_loss)
+    name, error = worst_leaf(got, seeded.want)
+    assert error < 2e-4, (name, error)
+
+
+# ---- each mechanism alone --------------------------------------------------
+
+
+def test_softmax_scores_renormalise_to_one_over_the_picked():
+    """The router's weights are a softmax over ALL outputs, the top k
+    renormalised: they sum to 1 a token, no buffer selects, and the layer
+    with every expert held is the dense sum of the reference."""
+    hidden, experts, width, top_k = 32, 16, 24, 3
+    x = jnp.asarray(np.random.RandomState(0).randn(2, 24, hidden), jnp.float32)
+    layer = moe.RoutedExperts(
+        num_experts=experts, top_k=top_k, ffn_dim=width, scores=moe.SOFTMAX
+    )
+    variables = layer.init(jax.random.PRNGKey(1), x)
+    assert set(variables) == {"params", STEP_METRICS}
+    params = variables["params"]
+    rows = x.reshape(-1, hidden)
+    with jax.default_matmul_precision("highest"):
+        chosen, weights = reference.routing(
+            rows, params["router_kernel"], top_k
+        )
+        np.testing.assert_allclose(weights.sum(axis=1), 1.0, rtol=1e-6)
+        scores = jax.nn.softmax(rows @ params["router_kernel"], axis=-1)
+        # the picked are the k largest of the softmax, in its proportions
+        np.testing.assert_allclose(
+            weights[:, 0] / weights[:, 1],
+            jnp.take_along_axis(scores, chosen, axis=1)[:, 0]
+            / jnp.take_along_axis(scores, chosen, axis=1)[:, 1], rtol=1e-5,
+        )
+        sizes = reference.sizes_of(dict(
+            CONFIG, num_experts_per_tok=top_k, held_experts=[0, experts],
+        ), None)
+        want = reference.routed(rows, params, sizes, lambda t: t)
+        got, _ = layer.apply(variables, x, mutable=MUTABLE)
+    np.testing.assert_allclose(
+        got.reshape(-1, hidden), want, rtol=2e-5, atol=2e-6
+    )
+    # the sigmoid form of the same weights is another layer
+    other, _ = moe.RoutedExperts(
+        num_experts=experts, top_k=top_k, ffn_dim=width
+    ).apply({"params": params}, x, mutable=MUTABLE)
+    assert np.abs(np.asarray(other) - np.asarray(got)).max() > 1e-3
+
+
+def test_the_shared_experts_gate_is_one_sigmoid_a_token():
+    hidden, width = 32, 24
+    x = jnp.asarray(np.random.RandomState(3).randn(2, 24, hidden), jnp.float32)
+
+    def layer(gated):
+        return decoder.MoEFFN(
+            hidden, 16, 3, width, 1, None, 1.0, 0.0, jnp.float32,
+            "qwen3_next/moe", scores=moe.SOFTMAX, shared_gate=gated,
+        )
+
+    variables = layer(True).init(jax.random.PRNGKey(4), x)
+    params = variables["params"]
+    assert params["shared_gate"]["kernel"].shape == (hidden, 1)
+    ungated = {k: v for k, v in params.items() if k != "shared_gate"}
+    with jax.default_matmul_precision("highest"):
+        with_gate, sown = layer(True).apply(variables, x, mutable=MUTABLE)
+        without, _ = layer(False).apply(
+            {"params": ungated}, x, mutable=MUTABLE
+        )
+        plain = lambda t: t
+        shared = reference.swiglu(x, params["shared"], plain)
+        gate = jax.nn.sigmoid(x @ params["shared_gate"]["kernel"])
+    np.testing.assert_allclose(
+        with_gate - without, (gate - 1.0) * shared, rtol=2e-5, atol=2e-6
+    )
+    assert float(sown[STEP_METRICS]["shared_gate_mean_ratio"]) == (
+        pytest.approx(float(gate.mean()), rel=1e-5)
+    )
+
+
+def test_norm_then_gate_is_not_gate_then_norm():
+    """The delta rule's output norm: the statistic of y alone, a head's
+    columns at a time, ONE scale of a head's width shared by the heads,
+    then the gate; Mamba-2's order on the same numbers is another
+    number."""
+    heads, dim = 4, 8
+    rng = np.random.RandomState(6)
+    y, z = (jnp.asarray(rng.randn(2, 10, heads * dim), jnp.float32)
+            for _ in range(2))
+    scale = jnp.asarray(1.0 + 0.3 * rng.randn(dim), jnp.float32)
+    then_gate = decoder.GatedRMSNorm(
+        1e-6, jnp.float32, heads, gate_first=False, shared_scale=True
+    )
+    assert then_gate.init(jax.random.PRNGKey(0), y, z)["params"][
+        "scale"
+    ].shape == (dim,)
+    got = then_gate.apply({"params": {"scale": scale}}, y, z)
+    by_head = y.reshape(2, 10, heads, dim)
+    want = (
+        by_head * jax.lax.rsqrt(
+            jnp.mean(jnp.square(by_head), axis=-1, keepdims=True) + 1e-6
+        ) * scale * jax.nn.silu(z.reshape(by_head.shape))
+    ).reshape(y.shape)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    first = decoder.GatedRMSNorm(1e-6, jnp.float32, heads).apply(
+        {"params": {"scale": jnp.tile(scale, heads)}}, y, z
+    )
+    assert np.abs(np.asarray(first) - np.asarray(got)).max() > 0.05
+
+
+def test_a_quarter_of_the_head_turns_and_the_rest_passes():
+    rope = decoder.plain_rope(16, 1e7, 0.25)
+    assert rope.columns == 4 and len(rope.inv_freq) == 2
+    assert rope.inv_freq[1] == pytest.approx(1e7 ** -0.5)
+    x = jnp.asarray(np.random.RandomState(8).randn(1, 6, 2, 16), jnp.float32)
+    turned = decoder.partial_rotary(x, rope)
+    np.testing.assert_array_equal(turned[..., 4:], x[..., 4:])
+    np.testing.assert_array_equal(turned[:, 0], x[:, 0])     # position 0
+    assert np.abs(np.asarray(turned[:, 1:, :, :4] - x[:, 1:, :, :4])).max() > 0.1
+    # rotate-half pairing: column i with column i + 2, position 1
+    cos, sin = np.cos(rope.inv_freq[0]), np.sin(rope.inv_freq[0])
+    np.testing.assert_allclose(
+        turned[0, 1, 0, 0], x[0, 1, 0, 0] * cos - x[0, 1, 0, 2] * sin,
+        rtol=1e-5,
+    )
+    # norms do not change under the turn
+    np.testing.assert_allclose(
+        jnp.linalg.norm(turned, axis=-1), jnp.linalg.norm(x, axis=-1),
+        rtol=1e-5,
+    )
+
+
+def test_the_query_wide_gate_and_the_qk_norm():
+    """The q projection is twice as wide, a head's columns q | gate; q and
+    k are normed a head under a 1 + w scale; the output is times
+    sigmoid(gate) element by element: against the reference's layer."""
+    hidden, heads, kv_heads, dim = 32, 4, 2, 16
+    x = jnp.asarray(np.random.RandomState(9).randn(2, 24, hidden), jnp.float32)
+    layer = decoder.GroupedAttention(
+        hidden, heads, kv_heads, dim, dim ** -0.5, jnp.float32,
+        "qwen3_next/attn", qk_norm_eps=1e-6,
+        rope=decoder.plain_rope(dim, 1e7, 0.25), query_gate=True,
+    )
+    variables = layer.init(jax.random.PRNGKey(2), x)
+    params = variables["params"]
+    assert params["q"]["kernel"].shape == (hidden, heads * 2 * dim)
+    assert params["q_norm"]["scale"].shape == (dim,)
+    assert np.abs(np.asarray(params["q_norm"]["scale"])).max() < 0.6   # w
+    sizes = reference.sizes_of(CONFIG, None)
+    with jax.default_matmul_precision("highest"):
+        got, sown = layer.apply(variables, x, mutable=MUTABLE)
+        want = jax.vmap(
+            lambda row: reference.attention(row, params, sizes, lambda t: t)
+        )(x)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    assert 0.0 < float(sown[STEP_METRICS]["query_gate_mean_ratio"]) < 1.0
+    # the plain layer of the other decoders has none of the three
+    plain = decoder.GroupedAttention(
+        hidden, heads, kv_heads, dim, dim ** -0.5, jnp.float32
+    ).init(jax.random.PRNGKey(2), x)["params"]
+    assert set(plain) == {"q", "k", "v", "o"}
+    assert plain["q"]["kernel"].shape == (hidden, heads * dim)
+
+
+# ---- the share: what each of 16 holders computes, and the shared expert ----
+
+
+def test_sixteen_holders_and_one_gated_shared_expert_are_the_uncut_layer():
+    """Expert parallelism's partial sums: the routed parts of all 16
+    holders (two experts of 32 each) plus the GATED shared expert counted
+    ONCE equal the uncut reference's whole expert layer; a holder's own
+    output is its part plus the gated shared expert, as every holder
+    computes it."""
+    hidden, experts, width, top_k, holders = 32, 32, 24, 10, 16
+    each = experts // holders
+    x = jnp.asarray(np.random.RandomState(1).randn(3, 40, hidden), jnp.float32)
+
+    def layer(held):
+        return decoder.MoEFFN(
+            hidden, experts, top_k, width, 1, held, 1.0, 0.0, jnp.float32,
+            "qwen3_next/moe", scores=moe.SOFTMAX, shared_gate=True,
+        )
+
+    whole = layer(None).init(jax.random.PRNGKey(3), x)["params"]
+    assert whole["routed"]["expert_w_gate_up"].shape == (
+        experts, hidden, 2 * width
+    )
+    sizes = reference.sizes_of(dict(
+        CONFIG, num_experts_per_tok=top_k, held_experts=[0, experts],
+    ), None)
+    stacks = ("expert_w_gate_up", "expert_w_down")
+    with jax.default_matmul_precision("highest"):
+        plain = lambda t: t
+        want_shared = jax.vmap(
+            lambda row: reference.gated_shared(row, whole, plain)
+        )(x)
+        want = jax.vmap(lambda row: reference.routed(
+            row, whole["routed"], sizes, plain
+        ))(x) + want_shared
+        parts = []
+        for holder in range(holders):
+            first = holder * each
+            routed = dict(whole["routed"], **{
+                name: whole["routed"][name][first:first + each]
+                for name in stacks
+            })
+            out, _ = layer((first, each)).apply(
+                {"params": dict(whole, routed=routed)}, x, mutable=MUTABLE,
+            )
+            parts.append(out - want_shared)
+    total = sum(parts) + want_shared
+    np.testing.assert_allclose(total, want, rtol=2e-5, atol=2e-6)
+    # no holder alone is the layer, and the shared expert is not nothing
+    assert np.abs(parts[0] + want_shared - want).max() > 1e-3
+    assert np.abs(want_shared).max() > 0.01
+
+
+# ---- controls: each part of the mathematics must fail the comparison ------
+
+
+def _gate_before_norm(monkeypatch):
+    plain = zoo.GatedRMSNorm
+    monkeypatch.setattr(
+        zoo, "GatedRMSNorm",
+        lambda eps, dtype, groups, gate_first, shared_scale, name: plain(
+            eps, dtype, groups, gate_first=True, shared_scale=shared_scale,
+            name=name,
+        ),
+    )
+
+
+def _one_norm_over_all_heads(monkeypatch):
+    """One statistic over all the value heads' columns, the scale tiled."""
+    class Whole(zoo.GatedRMSNorm):
+        @nn.compact
+        def __call__(self, y, z):
+            scale = jnp.tile(self.param(
+                "scale", nn.initializers.ones, (y.shape[-1] // self.groups,)
+            ), self.groups)
+            return (
+                decoder.rms_norm(y, scale, self.eps) * jax.nn.silu(z)
+            ).astype(self.dtype)
+
+    monkeypatch.setattr(zoo, "GatedRMSNorm", Whole)
+
+
+def _plain_scales(monkeypatch):
+    """Every zero-centred norm read as a plain scale w."""
+    plain = decoder.RMSNorm
+
+    class Plain(plain):
+        @nn.compact
+        def __call__(self, x):
+            scale = self.param(
+                "scale", nn.initializers.normal(0.1), (x.shape[-1],)
+            )
+            return decoder.rms_norm(x, scale, self.eps).astype(self.dtype)
+
+    monkeypatch.setattr(zoo, "RMSNorm", Plain)
+
+
+def _values_on_other_key_heads(monkeypatch):
+    """Value head h reading key head h % 2 (interleaved), not h // 2."""
+    plain = zoo.gdn
+
+    def regrouped(q, k, v, g, beta, qk_norm):
+        order = jnp.asarray([0, 2, 1, 3])
+        return plain(
+            q, k, v[:, :, order], g[:, :, order], beta[:, :, order],
+            qk_norm=qk_norm,
+        )[:, :, order]
+
+    monkeypatch.setattr(zoo, "gdn", regrouped)
+
+
+def _decay_dropped(monkeypatch):
+    plain = zoo.gdn
+    monkeypatch.setattr(
+        zoo, "gdn", lambda q, k, v, g, beta, qk_norm: plain(
+            q, k, v, jnp.zeros_like(g), beta, qk_norm=qk_norm
+        ),
+    )
+
+
+def _l2_norms_dropped(monkeypatch):
+    plain = zoo.gdn
+    monkeypatch.setattr(
+        zoo, "gdn", lambda q, k, v, g, beta, qk_norm: plain(
+            q, k, v, g, beta
+        ),
+    )
+
+
+def _no_conv(monkeypatch):
+    monkeypatch.setattr(
+        zoo, "silu_short_conv", lambda u, w: jax.nn.silu(u * w[-1])
+    )
+
+
+def _attention_change(**changes):
+    def change(monkeypatch):
+        plain = zoo.GroupedAttention
+
+        def built(*args, **kwargs):
+            return plain(*args, **{**kwargs, **changes})
+
+        monkeypatch.setattr(zoo, "GroupedAttention", built)
+
+    return change
+
+
+def _gate_a_head(monkeypatch):
+    """sigmoid of the MEAN of a head's gate columns: one gate a head, as
+    `laguna.py`'s attention has it."""
+    plain = jax.nn.sigmoid
+
+    def sigmoid(x):
+        if x.ndim == 4 and x.shape[-1] == CONFIG["head_dim"]:
+            return plain(x.mean(axis=-1, keepdims=True))
+        return plain(x)
+
+    monkeypatch.setattr(decoder.jax.nn, "sigmoid", sigmoid)
+
+
+def _moe_change(**changes):
+    def change(monkeypatch):
+        plain = zoo.MoEFFN
+
+        def built(*args, **kwargs):
+            return plain(*args, **{**kwargs, **changes})
+
+        monkeypatch.setattr(zoo, "MoEFFN", built)
+
+    return change
+
+
+def _weights_not_renormalised(monkeypatch):
+    """w_i = p_i: the sum over the chosen left out."""
+    take = jnp.take_along_axis
+
+    class Unsummed:
+        def __init__(self, picked):
+            self.picked = picked
+
+        def sum(self, axis, keepdims):
+            return jnp.ones_like(self.picked[:, :1])
+
+        def __rmul__(self, scale):
+            return scale * self.picked
+
+    def taken(values, idx, axis):
+        out = take(values, idx, axis=axis)
+        routers = (values.shape[1], idx.shape[1]) == (
+            CONFIG["num_experts_published"], CONFIG["num_experts_per_tok"],
+        )
+        return Unsummed(out) if routers else out
+
+    monkeypatch.setattr(moe.jnp, "take_along_axis", taken)
+
+
+CONTROLS = {
+    "gate_before_norm": _gate_before_norm,
+    "one_norm_over_all_heads": _one_norm_over_all_heads,
+    "norm_scales_not_zero_centred": _plain_scales,
+    "values_on_other_key_heads": _values_on_other_key_heads,
+    "decay_dropped": _decay_dropped,
+    "l2_norms_dropped": _l2_norms_dropped,
+    "conv_dropped": _no_conv,
+    "whole_head_rotated": dict(partial_rotary_factor=1.0),
+    "nothing_rotated": _attention_change(rope=None),
+    "gate_a_head": _gate_a_head,
+    "sigmoid_scores": _moe_change(scores=moe.SIGMOID),
+    "weights_not_renormalised": _weights_not_renormalised,
+}
+
+
+@pytest.mark.parametrize("control", sorted(CONTROLS))
+def test_a_departure_from_the_mathematics_fails_the_comparison(
+        seeded, monkeypatch, control):
+    """The comparison that passes the model fails each of these: the gate
+    before the output norm, one statistic over all heads, plain norm
+    scales, value heads on other key heads, no decay, no L2 norms, no
+    conv, the whole head rotated or none of it, one gate a head, sigmoid
+    scores, weights not renormalised."""
+    change = CONTROLS[control]
+    overrides = change if isinstance(change, dict) else {}
+    if not overrides:
+        change(monkeypatch)
+    loss, got = loss_and_grads(
+        model_of(CONFIG, **overrides), seeded.variables, seeded.ids
+    )
+    name, error = worst_leaf(got, seeded.want)
+    assert (
+        abs(loss - seeded.want_loss) > 1e-3 * abs(seeded.want_loss)
+        or error > 1e-2
+    ), (control, loss, seeded.want_loss, name, error)
+
+
+def test_each_part_of_the_reference_is_seen(seeded):
+    """The reference is held to the model above; this holds it to the
+    configuration: the decay's A and the step's bias, the output norm's
+    scale, a zero-centred scale, the shared gate, another held range,
+    another top-k and another layer list each move what is computed."""
+    features = {"input_ids": seeded.ids}
+
+    def loss_with(config=CONFIG, **leaves):
+        return reference.loss_and_grads(
+            {**seeded.flat, **leaves}, features, None, config
+        )[0]
+
+    for leaf in ("layer_0/gdn/A_log", "layer_1/gdn/dt_bias",
+                 "layer_2/gdn/o_norm/scale", "layer_3/attn/q_norm/scale",
+                 "layer_3/attn/k_norm/scale", "layer_0/mix_norm/scale",
+                 "layer_2/moe/shared_gate/kernel", "final_norm/scale"):
+        assert abs(
+            loss_with(**{leaf: seeded.flat[leaf] + np.log(2.0)})
+            - seeded.want_loss
+        ) > 1e-6, leaf
+    for change in (dict(held_experts=[0, 8]), dict(num_experts_per_tok=2),
+                   dict(partial_rotary_factor=0.5), dict(rope_theta=1e2)):
+        assert abs(
+            loss_with(dict(CONFIG, **change)) - seeded.want_loss
+        ) > 1e-6, change
+    # published layer 4 (a delta-rule layer) in layer 2's place is layer
+    # 2 again; an attention layer in a delta-rule layer's place finds no
+    # attention weights
+    assert loss_with(dict(CONFIG, layers_held=[0, 1, 4, 3])) == (
+        pytest.approx(seeded.want_loss, abs=1e-7)
+    )
+    with pytest.raises(KeyError):
+        loss_with(dict(CONFIG, layers_held=[0, 1, 7, 3]))
+    # the interval is read: at 2, layers 1 and 3 would be attention
+    with pytest.raises(KeyError):
+        loss_with(dict(CONFIG, full_attention_interval=2))
+
+
+def test_the_interval_names_every_layer():
+    model = zoo.custom_model(hidden=32, vocab_size=50)
+    assert len(model.config.layers) == 48
+    assert [i for i, is_gdn in enumerate(model.config.layers)
+            if not is_gdn] == list(range(3, 48, 4))
+    with pytest.raises(ValueError):
+        model_of(CONFIG, layers=[48])
+    with pytest.raises(ValueError):
+        model_of(CONFIG, gdn_key_heads=3)
+    with pytest.raises(ValueError):
+        model_of(CONFIG, kv_heads=3)
+
+
+@pytest.fixture(scope="module")
+def saved_core(seeded):
+    """bf16 -> (loss, gradients) of the model as the cells run it."""
+    return functools.lru_cache(None)(lambda bf16: loss_and_grads(
+        model_of(CONFIG, bf16=bf16), seeded.variables, seeded.ids
+    ))
+
+
+@pytest.mark.parametrize("bf16", [False, True], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("other", remat_cases.OTHERS)
+def test_the_remat_policy_changes_no_bit(seeded, saved_core, monkeypatch,
+                                         other, bf16):
+    """`remat=True` against the plain `nn.remat` and against no remat at
+    all, bit for bit."""
+    remat_cases.assert_saving_changes_nothing(
+        zoo, monkeypatch, other,
+        lambda remat, room=None: loss_and_grads(
+            model_of(CONFIG, bf16=bf16, remat=remat), seeded.variables,
+            seeded.ids, room,
+        ),
+        saved_core(bf16),
+    )
+
+
+def test_bfloat16_inside_the_twins_rule(seeded):
+    """The model computing in bfloat16 is held as the benchmark holds a
+    cell that states it: to the reference's own bfloat16 twin, leaf by
+    leaf and on the angle (`check_gradient`), where the float8 control
+    in the step's place fails."""
+    from benchmarks.drivers import train
+
+    held = types.SimpleNamespace(
+        **{k: getattr(reference, k) for k in dir(reference)
+           if not k.startswith("__")},
+        STATED_RATIO=reference.TWIN_RATIO,
+    )
+    features = {"input_ids": seeded.ids}
+    labels = np.zeros(len(seeded.ids), np.int32)
+    _, got = loss_and_grads(
+        model_of(CONFIG, bf16=True), seeded.variables, seeded.ids
+    )
+    check = train.check_gradient(
+        held, seeded.flat, features, labels, CONFIG, seeded.want, got
+    )
+    assert check["ok"], sorted(
+        check["shares"].items(), key=lambda kv: -kv[1]
+    )[:4]
+    _, control = reference.loss_and_grads(
+        seeded.flat, features, labels, CONFIG, tower="float8_e4m3fn"
+    )
+    control = {k: np.asarray(v, np.float32) for k, v in control.items()}
+    assert not train.check_gradient(
+        held, seeded.flat, features, labels, CONFIG, seeded.want, control
+    )["ok"]
+
+
+def test_published_sizes_hold_what_the_configuration_states():
+    """The parameters of the cut model at the published widths, counted
+    from the built model's shapes: the numbers in the configuration's
+    `deployment` and its `parameters_held`, part by part, and every
+    number of the catalog row under its own key."""
+    with open(os.path.join(
+        ROOT, "benchmarks", "configs", "qwen3-next-80b-a3b.json"
+    )) as f:
+        config = json.load(f)
+    from elasticdl_tpu.common.model_handler import _call_with_params
+
+    model = _call_with_params(
+        zoo.custom_model, config["model_params"].format(**config)
+    )
+    held = config["layers_held"]
+    assert held == [0, 1, 2, 3] and len(held) == config["num_hidden_layers"]
+    c = model.config
+    assert c.layers == (True, True, True, False)
+    assert (c.num_experts, c.top_k, c.held_experts) == (512, 10, (0, 32))
+    assert (c.heads, c.kv_heads, c.head_dim, c.rope.columns) == (
+        16, 2, 256, 64
+    )
+    assert (c.gdn_key_heads, c.gdn_value_heads, c.gdn_head_dim) == (
+        16, 32, 128
+    )
+    assert config["linear_value_head_dim"] == c.gdn_head_dim
+    assert c.rope.inv_freq[-1] == pytest.approx(1e7 ** (-62 / 64))
+    assert c.dtype == jnp.bfloat16 and c.remat and c.eps == 1e-6
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), {"input_ids": jnp.zeros((1, 512), jnp.int32)}
+    ))
+    assert set(shapes) == {"params", STEP_METRICS}
+    flat = {
+        name: int(np.prod(leaf.shape))
+        for name, leaf in trees.flat(shapes["params"]).items()
+    }
+    by_top = {}
+    for name, size in flat.items():
+        top = name.split("/")[0]
+        by_top[top] = by_top.get(top, 0) + size
+    assert by_top == {
+        "layer_0": 138_582_208, "layer_1": 138_582_208,
+        "layer_2": 138_582_208, "layer_3": 132_127_232,
+        "token_embedding": 38_895_616, "lm_head_kernel": 38_895_616,
+        "final_norm": 2_048,
+    }
+
+    def part(prefix):
+        return {
+            k[len(prefix):]: v for k, v in flat.items() if k.startswith(prefix)
+        }
+
+    assert part("layer_0/gdn/") == {
+        "qkvz/kernel": 25_165_824, "ba/kernel": 131_072,
+        "conv_kernel": 32_768, "A_log": 32, "dt_bias": 32,
+        "o_norm/scale": 128, "o/kernel": 8_388_608,
+    }
+    assert part("layer_3/attn/") == {
+        "q/kernel": 16_777_216, "k/kernel": 1_048_576, "v/kernel": 1_048_576,
+        "q_norm/scale": 256, "k_norm/scale": 256, "o/kernel": 8_388_608,
+    }
+    assert part("layer_1/moe/") == {
+        "routed/router_kernel": 1_048_576,
+        "routed/expert_w_gate_up": 32 * 2_097_152,
+        "routed/expert_w_down": 32 * 1_048_576,
+        "shared/gate_up/kernel": 2_097_152, "shared/down/kernel": 1_048_576,
+        "shared_gate/kernel": 2_048,
+    }
+    total = sum(by_top.values())
+    assert total == config["parameters_held"] == 625_667_136
+    assert f"{total:,}" in config["deployment"]
+    assert 16 * total > 0.25 * 16.9e9          # over the floor, held alone
+
+
+# ---- through the system ---------------------------------------------------
+
+
+def test_trainer_carries_each_layer_kinds_gauges(seeded):
+    from elasticdl_tpu.worker.sync import ModelOwner
+    from elasticdl_tpu.worker.trainer import Trainer
+
+    trainer = Trainer(
+        model=model_of(CONFIG), optimizer=zoo.optimizer(1e-3),
+        loss_fn=zoo.loss,
+    )
+    batch = {"features": {"input_ids": seeded.ids},
+             "labels": np.zeros(len(seeded.ids), np.int32)}
+    state = trainer.init_state(jax.random.PRNGKey(0), batch["features"])
+    state, loss = trainer.train_on_batch(state, batch)
+    assert float(loss) == pytest.approx(seeded.want_loss, rel=1e-3)
+    owner = ModelOwner.__new__(ModelOwner)
+    owner.state, owner.lock = state, threading.Lock()
+    value, metrics = owner.fetch_loss(loss)
+    assert value == pytest.approx(float(loss))
+    for layer in range(3):
+        assert 0.0 < metrics[f"layer_{layer}/gdn/gdn_decay_mean_ratio"] < 1.0
+        assert 0.0 < metrics[f"layer_{layer}/gdn/gdn_beta_mean_ratio"] < 1.0
+    assert 0.0 < metrics["layer_3/attn/query_gate_mean_ratio"] < 1.0
+    assert "layer_3/gdn/gdn_decay_mean_ratio" not in metrics
+    for layer in range(4):
+        path = f"layer_{layer}/moe"
+        assert 0.0 < metrics[f"{path}/shared_gate_mean_ratio"] < 1.0
+        assert metrics[f"{path}/routed/expert_load_imbalance_ratio"] >= 1.0
+        assert 0.0 < metrics[f"{path}/routed/routed_here_ratio"] < 1.0
+        assert metrics[f"{path}/routed/dropped_tokens"] == 0
+
+
+def test_cli_job_of_two_tasks_with_a_falling_loss(tmp_path, monkeypatch):
+    from elasticdl_tpu.client.main import main as cli_main
+    from elasticdl_tpu.common import metrics as metrics_lib
+    from elasticdl_tpu.worker.worker import Worker
+    from elasticdl_tpu.worker import trainer as trainer_lib
+
+    # a device with room for every named product: the gauge reads 1
+    monkeypatch.setattr(
+        trainer_lib, "device_room", lambda mesh: remat_cases.ALL_THE_ROOM
+    )
+
+    path = str(tmp_path / "train.tfrecord")
+    datagen.write_task_file(
+        path, 7, {"format": "tokens", "seq_len": 32, "vocab_size": 50},
+        64, 2,
+    )
+    workers = []
+    init = Worker.__init__
+
+    def recording_init(self, *args, **kwargs):
+        init(self, *args, **kwargs)
+        workers.append(self)
+
+    Worker.__init__ = recording_init
+    try:
+        rc = cli_main([
+            "train", "--model_zoo", os.path.join(ROOT, "model_zoo"),
+            "--model_def", "qwen3_next.qwen3_next.custom_model",
+            "--model_params",
+            "hidden=32;layers=[0,1,2,3];heads=4;kv_heads=2;head_dim=16;"
+            "gdn_key_heads=2;gdn_value_heads=4;gdn_head_dim=8;"
+            "expert_width=24;shared_width=24;num_experts=16;top_k=3;"
+            "held_experts=[4,8];vocab_size=50;remat=True;lr=0.03",
+            "--distribution_strategy", "Local", "--training_data", path,
+            "--minibatch_size", "8", "--records_per_task", "64",
+            "--num_epochs", "1",
+        ])
+    finally:
+        Worker.__init__ = init
+    assert rc == 0
+    losses = [float(x) for x in workers[0].losses]
+    assert len(losses) == 16                      # two tasks of 8 steps
+    assert np.mean(losses[-4:]) < np.mean(losses[:4]) - 0.05
+    registry = metrics_lib.default_registry()
+    for layer in range(3):
+        assert 0.0 < registry.value(
+            "worker_gdn_decay_mean_ratio", layer=f"layer_{layer}/gdn"
+        ) < 1.0
+        assert 0.0 < registry.value(
+            "worker_gdn_beta_mean_ratio", layer=f"layer_{layer}/gdn"
+        ) < 1.0
+    assert 0.0 < registry.value(
+        "worker_attention_query_gate_mean_ratio", layer="layer_3/attn"
+    ) < 1.0
+    for layer in range(4):
+        assert 0.0 < registry.value(
+            "worker_moe_shared_gate_mean_ratio", layer=f"layer_{layer}/moe"
+        ) < 1.0
+        assert 0.0 < registry.value(
+            "worker_moe_routed_here_ratio", layer=f"layer_{layer}/moe/routed"
+        ) < 1.0
+    assert registry.value("worker_remat_kept_ratio") == 1.0
+
+
+def test_the_layers_scopes_reach_the_lowered_operations():
+    """The scan's five scopes, attention's and the expert layer's carry
+    the model's prefix into the operations' names, and the scan's gate
+    scope is `decay`: `attn_proj_ms_per_step` takes every `*/gate`."""
+    from elasticdl_tpu.common import profiler
+
+    model = model_of(CONFIG, remat=False)
+    ids = ids_of(1, length=16)
+    variables = model.init(jax.random.PRNGKey(0), {"input_ids": ids})
+    text = jax.jit(
+        lambda v, ids: model.apply(v, {"input_ids": ids}, mutable=MUTABLE)[0]
+    ).lower(variables, ids).as_text(debug_info=True)
+    for scope in ("gdn/proj", "gdn/conv", "gdn/decay", "gdn/core", "gdn/out",
+                  "attn", "moe", "norm", "embed", "head_ce"):
+        assert f"qwen3_next/{scope}" in profiler.DEVICE_SCOPES
+        assert f"qwen3_next/{scope}/" in text, scope
+    for part in ("router", "dispatch", "experts", "shared", "combine"):
+        assert f"qwen3_next/moe/{part}" in text.replace("routed/", ""), part
+    assert "qwen3_next/gdn/gate" not in profiler.DEVICE_SCOPES
+    assert "Scope object" not in text
+
+
+# sha256 of str(make_jaxpr(value_and_grad(loss))) of three sibling cells'
+# models at their published sizes (bfloat16, remat; abstract: nothing
+# runs), the remat policy's address blanked, recorded at the commit before
+# `RoutedExperts` learnt softmax scores, `MoEFFN` the shared gate,
+# `GroupedAttention` its norms, turn and gate, and `GatedRMSNorm` its
+# order (2260048): their programs are the parent's.
+PARENTS_JAXPRS = {
+    "kimi-linear-48b-a3b": (
+        "kimi.kimi_linear", (2, 8192),
+        "64f2357518c6c947da7abd32219eb96d6bbe40024ae89012b815ec5e8f558e64",
+    ),
+    "nemotron-3-nano-30b-a3b": (
+        "nemotron.nemotron_h", (2, 8192),
+        "bdb4d28beba0ee0031e7c8f7e6ff50ba917e5c141e81b47544ef0a04abcbc08a",
+    ),
+    "glm-4.7-flash": (
+        "glm.glm_moe_lite", (4, 4096),
+        "7aa997f0621489a8b3c6f10b44ee4eb219be6ba5753671e23feb463bf198923a",
+    ),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PARENTS_JAXPRS))
+def test_a_sibling_cells_program_is_the_parents(name):
+    import hashlib
+    import importlib
+    import re
+
+    from elasticdl_tpu.common.model_handler import _call_with_params
+
+    module, shape, digest = PARENTS_JAXPRS[name]
+    sibling = importlib.import_module(f"model_zoo.{module}")
+    with open(os.path.join(
+        ROOT, "benchmarks", "configs", f"{name}.json"
+    )) as f:
+        config = json.load(f)
+    model = _call_with_params(
+        sibling.custom_model, config["model_params"].format(**config)
+    )
+    ids = jax.ShapeDtypeStruct(shape, jnp.int32)
+    variables = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), {"input_ids": ids}
+    )
+    state = {k: v for k, v in variables.items() if k != "params"}
+
+    def loss_of(params, state, ids):
+        out, _ = model.apply(
+            {"params": params, **state}, {"input_ids": ids},
+            mutable=MUTABLE,
+        )
+        return sibling.loss(None, out.astype(jnp.float32))
+
+    text = str(jax.make_jaxpr(jax.value_and_grad(loss_of))(
+        variables["params"], state, ids
+    ))
+    text = re.sub(r" at 0x[0-9a-f]+", "", text)
+    assert "0x" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == digest

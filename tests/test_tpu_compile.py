@@ -398,6 +398,83 @@ def test_nemotron_cell_kernels_compile_for_the_chip(one_chip, monkeypatch):
     assert all(int(t) >= moe.TILE for tiling in tilings for t in tiling)
 
 
+def test_qwen3_next_cell_step_compiles_for_the_chip(one_chip, monkeypatch):
+    """The Qwen3-Next cell's whole train step (2 sequences of 8,192, four
+    layers at the published widths, 32 of 512 experts held, Adam, the lean
+    remat policy: no room given) compiled for the described v5e, abstract:
+    the scalar-decay scan's kernels at 32 value heads over 16 key heads
+    (q and k enter at 2,048 columns: no repeated copy), the SiLU conv over
+    8,192 columns, attention at 16 / 2 heads of 256, the routed walk at
+    163,840 slots; and what the configuration's `fifth_layer` states: the
+    step's arguments and temporaries under 0.9 of the chip's bytes."""
+    import json
+    import os
+
+    import optax
+
+    from elasticdl_tpu.common.model_handler import _call_with_params
+    from elasticdl_tpu.layers.step_metrics import AUX_LOSS, STEP_METRICS
+    from elasticdl_tpu.ops import gdn, short_conv
+    from model_zoo.qwen3_next import qwen3_next as zoo
+
+    for module in (fa, gdn, short_conv):
+        monkeypatch.setattr(module, "use_interpret", lambda: False)
+    with open(os.path.join(
+        os.path.dirname(__file__), "..", "benchmarks", "configs",
+        "qwen3-next-80b-a3b.json",
+    )) as f:
+        config = json.load(f)
+    model = _call_with_params(
+        zoo.custom_model, config["model_params"].format(**config)
+    )
+    optimizer = zoo.optimizer(config["learning_rate"])
+    ids = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
+    params = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), {"input_ids": ids}
+    )["params"]
+
+    def step(params, opt_state, ids):
+        def loss_of(params):
+            out, _ = model.apply(
+                {"params": params}, {"input_ids": ids},
+                mutable=[AUX_LOSS, STEP_METRICS],
+            )
+            return zoo.loss(None, out.astype(jnp.float32))
+
+        loss, grads = jax.value_and_grad(loss_of)(params)
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    def placed(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip
+        ), tree)
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        placed(params), placed(jax.eval_shape(optimizer.init, params)),
+        placed(ids),
+    ).compile()
+    text = compiled.as_text()
+    assert "gdn_chunk_fwd" in text and "gdn_chunk_bwd" in text
+    assert not re.search(r"kda_\w*(fwd|bwd)", text)
+    # q and k at their 16 key heads, the states a value head, g over b as
+    # rows of a chunk
+    kernel = next(
+        line for line in text.splitlines()
+        if "gdn_chunk_bwd" in line and "custom-call(" in line
+    )
+    assert "bf16[2,8192,2048]" in kernel and "bf16[2,8192,4096]" in kernel
+    assert "f32[2,32,128,2,64]" in kernel
+    assert "f32[2,32,128,128,128]" in text
+    assert "silu_short_conv_fwd" in text and "silu_short_conv_bwd" in text
+    _assert_two_kernels(text, "causal")
+    assert "ragged-dot" in text and "s32[163840]" in text
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    # `fifth_layer`: 7.508e9 + 5.548e9 = 13.056e9 of at most 15.2e9
+    assert 12.5e9 < held < 0.9 * 16_909_336_064, held
+
+
 def _whole_arrays_off_the_channels(text, size=2 * 8192 * 4096):
     """(the `copy` / `transpose` instructions whose result has `size`
     elements, the arrays of that many elements whose layout's minor axis
