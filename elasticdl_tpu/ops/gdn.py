@@ -27,14 +27,16 @@ so M and P are ONE product through the MXU each and one mask: no exponent
 over channels, no sub-block references, and nothing that can overflow
 (every exponent is a difference G_i - G_j with j <= i, so every D_ij <=
 1).  K K^T and Q K^T, and the optional L2 norms of q and k (`qk_norm`),
-are computed once a KEY head and serve its r value heads; U is found by
-`ops/kda.py`'s substitution (`_solve`), which this module shares with it.
+are computed once a KEY head and serve its r value heads; U and, in the
+backward, dR of (I + A)^T dR = dU are found by `ops/kda.py`'s substitution
+(`_solve`: nothing of (I + A)^-1 is formed, its header says why), the
+systems of all of a grid step's value heads side by side.
 
 Kernel shape: the grid walks (batch, a group of heads, chunk), the chunk
-axis sequential; a step takes `_HEADS` value heads where r = 1 and one
-key head with its r value heads where r > 1, so q and k are read from HBM
-once a key head (the index map picks the key head's column block: no
-repeated copy of q or k exists).  Operands stay (B, L, H*D), the state
+axis sequential; a step takes up to `_HEADS` value heads, whole key heads
+each with its r value heads, so q and k are read from HBM once a key head
+(the index map picks the key head's column block: no repeated copy of q
+or k exists).  Operands stay (B, L, H*D), the state
 lives TRANSPOSED (dv, dk) in float32 scratch, and the forward writes the
 state each chunk starts from; the backward walks the chunks from the
 last, rebuilds a chunk from its inputs and its boundary state, and sums
@@ -54,6 +56,7 @@ the forward kernel makes.
 
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -70,6 +73,7 @@ from elasticdl_tpu.ops.kda import (
     _call,
     _dot,
     _dot_last,
+    _heads_a_step,
     _iota,
     _l2,
     _l2_backward,
@@ -81,23 +85,28 @@ from elasticdl_tpu.ops.kda import (
 _LANES = 128
 # Tokens of a chunk (the substitution's sub-blocks are `ops/kda.py`'s).
 CHUNK = 64
-# Value heads a grid step takes where every value head has its own key
-# head (and the head count divides); with r > 1 a step takes one key head
-# and its r value heads.
-_HEADS = 2
+# Value heads of 128 a grid step takes at most, whole key heads each with
+# its r value heads (`_groups`): their linear systems are solved side by
+# side (1 | 2 | 4 key heads of two value heads read 5.4 | 3.7 | 3.4 ms
+# forward and 9.5 | 9.2 | 8.4 backward at the Qwen3-Next cell's shape;
+# eight pass the kernel's 16 MiB of scoped VMEM).
+_HEADS = 8
 RESULT_NAMES = ("gdn_core_out", "gdn_core_states")
 SAVED_NAMES = ()
 
 
 def gdn_shapes_ok(q_shape, k_shape, v_shape) -> bool:
     """Whether the kernels take q, k (B, L, H_k, dk) and v (B, L, H_v,
-    dv): q and k alike, whole value heads a key head, heads of whole lane
+    dv): q and k alike, whole value heads a key head and no more of them
+    than a grid step takes (`_HEADS` of `_LANES`), heads of whole lane
     tiles, whole chunks."""
     return (
         len(q_shape) == 4 and len(v_shape) == 4
         and tuple(q_shape) == tuple(k_shape)
         and tuple(q_shape[:2]) == tuple(v_shape[:2])
         and v_shape[2] % q_shape[2] == 0
+        and v_shape[2] // q_shape[2] * max(q_shape[3], v_shape[3])
+        <= _HEADS * _LANES
         and q_shape[3] % _LANES == 0 and v_shape[3] % _LANES == 0
         and q_shape[1] % CHUNK == 0
     )
@@ -207,24 +216,31 @@ def _decays(g_row):
     return G, D
 
 
+# What a value head's two halves of a chunk share: the intermediates made
+# before the linear system is solved.
+_Chunk = collections.namedtuple(
+    "_Chunk", "D b M P eq Qg Kb Z ed Kd e_last"
+)
+
+
 def _rebuild(q, k, qk, kk, v, g_row, b_row, state, dtype):
-    """A chunk's intermediates of ONE value head from its inputs (float32
-    values; `qk`, `kk` the key head's Q K^T and K K^T) and the (dv, dk)
-    state it starts from."""
+    """A chunk of ONE value head up to its linear system, from its inputs
+    (float32 values; `qk`, `kk` the key head's Q K^T and K K^T) and the
+    (dv, dk) state it starts from: (what the rest of the chunk reads, the
+    system (A, R) whose solution is U)."""
     size = q.shape[0]
     row, col = _iota((size, size), 0), _iota((size, size), 1)
     G, D = _decays(g_row)
     b = _as_column(b_row)
     M = jnp.where(row > col, kk * D, 0.0)
     P = qk * D
-    A = M * b
     eq = jnp.exp(G)
     Qg, Kb = q * eq, k * eq
     Z = v - _dot(Kb, state, _COLS, dtype)
-    U = _solve(A, b * Z, dtype)
     last = G[-1:]
     ed = jnp.exp(last - G)
-    return D, b, M, P, A, eq, Qg, Kb, Z, U, ed, k * ed, jnp.exp(last)
+    rebuilt = _Chunk(D, b, M, P, eq, Qg, Kb, Z, ed, k * ed, jnp.exp(last))
+    return rebuilt, (M * b, b * Z)
 
 
 def _key_head(q, k, dtype, qk_norm):
@@ -240,40 +256,42 @@ def _key_head(q, k, dtype, qk_norm):
     )
 
 
-def _chunk_forward(q, k, qk, kk, v, g_row, b_row, state, dtype):
+def _chunk_forward(rebuilt, U, state, dtype):
     """(o (C, dv), the next state (dv, dk)), float32."""
-    _, _, _, P, _, _, Qg, _, _, U, _, Kd, e_last = _rebuild(
-        q, k, qk, kk, v, g_row, b_row, state, dtype
+    out = _dot(rebuilt.Qg, state, _COLS, dtype) + _dot(
+        rebuilt.P, U, _ROW_COL, dtype
     )
-    out = _dot(Qg, state, _COLS, dtype) + _dot(P, U, _ROW_COL, dtype)
-    return out, state * e_last + _dot(U, Kd, _ROWS, dtype)
+    return out, state * rebuilt.e_last + _dot(U, rebuilt.Kd, _ROWS, dtype)
 
 
-def _chunk_backward(q, k, qk, kk, v, g_row, b_row, state, d_out, d_next,
-                    dtype):
+def _written_gradient(rebuilt, d_out, d_next, dtype):
+    """dU, the gradient of the rows the chunk writes, from the gradients
+    of its output (O = Qg S + P U) and of the state it leaves (S' = S
+    e^{G_C} + U^T Kd): it does not wait for U."""
+    return _dot(rebuilt.P, d_out, _ROWS, dtype) + _dot(
+        rebuilt.Kd, d_next, _COLS, dtype
+    )
+
+
+def _chunk_backward(q, k, rebuilt, U, dR, state, d_out, d_next, dtype):
     """(dq, dk, dv, dg (1, C), db (1, C), the gradient of the chunk's
     starting state) of ONE value head from the gradients of its output
-    and of the state it leaves; dq and dk are by the (normed) q and k the
-    key head handed over."""
-    D, b, M, P, A, eq, Qg, Kb, Z, U, ed, Kd, e_last = _rebuild(
-        q, k, qk, kk, v, g_row, b_row, state, dtype
-    )
+    and of the state it leaves, U and dR = (I + A)^-T dU; dq and dk are
+    by the (normed) q and k the key head handed over."""
+    D, b, M, P, eq, Qg, Kb, Z, ed, Kd, e_last = rebuilt
     size = q.shape[0]
     row, col = _iota((size, size), 0), _iota((size, size), 1)
     # O = Qg S + P U
     dQg = _dot(d_out, state, _ROW_COL, dtype)
     d_state = _dot(d_out, Qg, _ROWS, dtype)
     dP = jnp.where(row >= col, _dot(d_out, U, _COLS, dtype), 0.0)
-    dU = _dot(P, d_out, _ROWS, dtype)
     # S' = S e^{G_C} + U^T Kd
     d_state = d_state + d_next * e_last
     d_last = (state * d_next).sum(axis=1, keepdims=True).sum(
         axis=0, keepdims=True
     )
     dKd = _dot(U, d_next, _ROW_COL, dtype)
-    dU = dU + _dot(Kd, d_next, _COLS, dtype)
     # U = T R, R = b Z, T = (I + b M)^-1, Z = V - Kb S
-    dR = _solve(A, dU, dtype, transposed=True)
     dA = -jnp.where(row > col, _dot(dR, U, _COLS, dtype), 0.0)
     db = (dR * Z).sum(axis=1, keepdims=True) + (dA * M).sum(
         axis=1, keepdims=True
@@ -321,20 +339,29 @@ def _fwd_kernel(q_ref, k_ref, v_ref, gb_ref, o_ref, states_ref, state_sc,
         state_sc[...] = jnp.zeros(state_sc.shape, jnp.float32)
 
     dtype = q_ref.dtype
-    for kh in range(state_sc.shape[0] // ratio):
+    heads = range(state_sc.shape[0])
+    states = [state_sc[h] for h in heads]
+    rebuilt, systems = [], []
+    for kh in range(len(heads) // ratio):
         q, k, qk, kk, _ = _key_head(
             _head(q_ref, kh, dk), _head(k_ref, kh, dk), dtype, qk_norm
         )
-        # a key head's value heads are independent chains: side by side
-        # they hide each other's latencies
         for h in range(kh * ratio, (kh + 1) * ratio):
-            state = state_sc[h]
-            states_ref[0, h, 0] = state
-            out, state_sc[h] = _chunk_forward(
+            chunk, (A, R) = _rebuild(
                 q, k, qk, kk, _head(v_ref, h, dv), gb_ref[0, h, 0, 0:1],
-                gb_ref[0, h, 0, 1:2], state, dtype,
+                gb_ref[0, h, 0, 1:2], states[h], dtype,
             )
-            o_ref[0, :, h * dv:(h + 1) * dv] = out.astype(o_ref.dtype)
+            rebuilt.append(chunk)
+            systems.append((A, R, False))
+    # the value heads of a step are independent chains: their systems are
+    # solved side by side (`ops/kda.py: _solve`)
+    solved = _solve(systems, dtype)
+    for h in heads:
+        states_ref[0, h, 0] = states[h]
+        out, state_sc[h] = _chunk_forward(
+            rebuilt[h], solved[h], states[h], dtype
+        )
+        o_ref[0, :, h * dv:(h + 1) * dv] = out.astype(o_ref.dtype)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, gb_ref, states_ref, do_ref, dq_ref,
@@ -345,18 +372,38 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gb_ref, states_ref, do_ref, dq_ref,
         d_state_sc[...] = jnp.zeros(d_state_sc.shape, jnp.float32)
 
     dtype = q_ref.dtype
-    for kh in range(d_state_sc.shape[0] // ratio):
-        keys = slice(kh * dk, (kh + 1) * dk)
-        q, k, qk, kk, normed = _key_head(
+    heads = range(d_state_sc.shape[0])
+    states = [states_ref[0, h, 0] for h in heads]
+    d_outs = [_head(do_ref, h, dv) for h in heads]
+    d_nexts = [d_state_sc[h] for h in heads]
+    key_heads, rebuilt, systems, transposed = [], [], [], []
+    for kh in range(len(heads) // ratio):
+        key_heads.append(_key_head(
             _head(q_ref, kh, dk), _head(k_ref, kh, dk), dtype, qk_norm
-        )
+        ))
+        q, k, qk, kk, _ = key_heads[-1]
+        for h in range(kh * ratio, (kh + 1) * ratio):
+            chunk, (A, R) = _rebuild(
+                q, k, qk, kk, _head(v_ref, h, dv), gb_ref[0, h, 0, 0:1],
+                gb_ref[0, h, 0, 1:2], states[h], dtype,
+            )
+            rebuilt.append(chunk)
+            systems.append((A, R, False))
+            transposed.append((
+                A.T, _written_gradient(chunk, d_outs[h], d_nexts[h], dtype),
+                True,
+            ))
+    # a head's two systems, U's and its gradient's transposed one, wait
+    # for nothing of each other: all of a step's are solved side by side
+    solved = _solve(systems + transposed, dtype)
+    for kh, (q, k, _, _, normed) in enumerate(key_heads):
+        keys = slice(kh * dk, (kh + 1) * dk)
         dq = dk_ = None
         for h in range(kh * ratio, (kh + 1) * ratio):
             values = slice(h * dv, (h + 1) * dv)
             dq_h, dk_h, dv_, dg, db, d_state_sc[h] = _chunk_backward(
-                q, k, qk, kk, _head(v_ref, h, dv), gb_ref[0, h, 0, 0:1],
-                gb_ref[0, h, 0, 1:2], states_ref[0, h, 0],
-                _head(do_ref, h, dv), d_state_sc[h], dtype,
+                q, k, rebuilt[h], solved[h], solved[len(heads) + h],
+                states[h], d_outs[h], d_nexts[h], dtype,
             )
             dq = dq_h if dq is None else dq + dq_h
             dk_ = dk_h if dk_ is None else dk_ + dk_h
@@ -370,9 +417,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, gb_ref, states_ref, do_ref, dq_ref,
         dk_ref[0, :, keys] = dk_.astype(dk_ref.dtype)
 
 
-def _groups(key_heads: int, ratio: int):
-    """(key heads, value heads) a grid step takes."""
-    keys = _HEADS if ratio == 1 and key_heads % _HEADS == 0 else 1
+def _groups(key_heads: int, ratio: int, width: int = _LANES):
+    """(key heads, value heads) a grid step takes: `_HEADS` value heads
+    of `_LANES`, as many fewer as a wider head asks, or the largest half
+    of that whose key heads divide the key heads' count (`ops/kda.py:
+    _heads_a_step`, a key head as wide as its value heads together)."""
+    keys = _heads_a_step(key_heads, width * ratio, _HEADS)
     return keys, keys * ratio
 
 
@@ -432,7 +482,7 @@ def _gdn(q, k, v, g, beta, qk_norm=None):
 def _forward_call(batch, length, key_heads, heads, dk, dv, dtype, qk_norm,
                   vma, interpret):
     chunks, ratio = length // CHUNK, heads // key_heads
-    keys, group = _groups(key_heads, ratio)
+    keys, group = _groups(key_heads, ratio, max(dk, dv))
     qk_rows, v_rows, scalars, states = _specs(
         chunks, keys, group, dk, dv, reverse=False
     )
@@ -454,7 +504,7 @@ def _forward_call(batch, length, key_heads, heads, dk, dv, dtype, qk_norm,
 def _backward_call(batch, length, key_heads, heads, dk, dv, dtypes, qk_norm,
                    vma, interpret):
     chunks, ratio = length // CHUNK, heads // key_heads
-    keys, group = _groups(key_heads, ratio)
+    keys, group = _groups(key_heads, ratio, max(dk, dv))
     qk_rows, v_rows, scalars, states = _specs(
         chunks, keys, group, dk, dv, reverse=True
     )

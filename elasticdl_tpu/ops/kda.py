@@ -30,25 +30,44 @@ sub-blocks of `_SUB` rows: a sub-block of rows against the rows BEFORE it
 goes through the MXU with both sides scaled against the sub-block's first
 row (exp(G_i - G_ref) and exp(G_ref - G_j), both <= 0 there), and the
 diagonal sub-blocks are taken a column at a time, elementwise, with the
-exponent clamped at 0 where the mask hides it.  T is never formed: U is
-found by substitution, over the sub-blocks through the MXU and inside a
-sub-block a row at a time in float32 on the vector unit (a Neumann
-product (I - A)(I + A^2)(I + A^4)... over the whole chunk is exact on
-paper and cancels to nothing in float32 where keys align: it turned the
-cell's loss to NaN once the job had learnt its pool).
+exponent clamped at 0 where the mask hides it.
+
+WHAT IS FORMED OF (I + A)^-1: nothing.  U is found by substitution
+(`_solve`), over the sub-blocks through the MXU (the rows found so far,
+rounded to the stated type, times A) and inside a sub-block a row at a
+time in float32 on the vector unit.  Two other ways were measured and
+left out (`PERF.md` section 6, PR 54; `scripts/probe_scan_kernels.py`).
+A Neumann product (I - A)(I + A^2)(I + A^4)... over the whole chunk is
+exact on paper and cancels to nothing in float32 where keys align: it
+turned the cell's loss to NaN once the job had learnt its pool.  T formed
+block by block (the diagonal sub-blocks inverted by the same row steps,
+the rest by merges through the MXU) and applied as products, U = T R and
+dR = T^T dU, holds in float32 but reads two to five times the
+substitution's error at operands of bfloat16 where keys align: in the
+substitution a row rounded for the MXU is what every later sub-block is
+corrected by, so a row's rounding is fed back to the rows after it, and a
+product with T rounds every row unseen.  The backward's transposed system
+(I + A)^T dR = dU is handed to the SAME substitution as the strictly upper
+A^T, one transpose of a (C, C) tile, and runs from the last row up.
+
+A system is a chain of C dependent row steps of a few cycles' work each,
+and what it costs is that chain's latency, not a unit's throughput: the
+systems of a grid step (a head's, and in the backward its transposed one,
+which waits for nothing of U) are solved TOGETHER, a row step of each
+after a row step of the others, in program order (the compiler does not
+interleave heads whose bodies are written one after the other).
 
 Kernel shape: the grid walks (batch, `_HEADS` heads a step, chunk), the
-chunk axis sequential (a step's heads are independent chains of small
-products and hide each other's latencies); operands stay (B, L, H*D)
-(the free view of the model's layout, a head a column block), the state
-lives TRANSPOSED (dv, dk) in float32 scratch so that every decay is a
-broadcast along lanes, and the forward writes the state each chunk
-starts from.  The backward walks the chunks from the last to the first
-with the state's gradient in scratch and rebuilds a chunk's
-intermediates (G, M, P, U) from its inputs and its boundary state: a
-chunked-scan backward.  The state, the running sums, the substitution
-and the optional L2 norms of q and k (`qk_norm`) are float32; the
-operands of every product through the MXU are the stated type.
+chunk axis sequential; operands stay (B, L, H*D) (the free view of the
+model's layout, a head a column block), the state lives TRANSPOSED (dv,
+dk) in float32 scratch so that every decay is a broadcast along lanes,
+and the forward writes the state each chunk starts from.  The backward
+walks the chunks from the last to the first with the state's gradient in
+scratch and rebuilds a chunk's intermediates (G, M, P, U) from its inputs
+and its boundary state: a chunked-scan backward.  The state, the running
+sums, the substitution and the optional L2 norms of q and k (`qk_norm`)
+are float32; the operands of every product through the MXU are the stated
+type.
 
 The forward's two results carry names (`checkpoint_name`): a caller that
 rematerialises a block may save them by name (`model_zoo/common/
@@ -64,6 +83,7 @@ forward calls of 11 ms (`PERF.md` section 6, PR 42).
 
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -78,8 +98,12 @@ _LANES = 128
 # Tokens of a chunk, and rows of a sub-block of it.
 CHUNK = 64
 _SUB = 16
-# Heads a grid step takes (where the head count divides).
-_HEADS = 2
+# Heads of 128 a grid step takes at most (`_heads_a_step`): the step's
+# linear systems are solved side by side, and four heads' fill the vector
+# unit (1 | 2 | 4 read 11.6 | 9.9 | 8.1 ms forward and 22.5 | 21.3 | 17.6
+# backward at the Kimi cell's shape; eight pass the kernel's 16 MiB of
+# scoped VMEM).
+_HEADS = 4
 # The names of the forward's output and of the states the chunks start
 # from, and which of them a block's remat keeps from the forward.
 RESULT_NAMES = ("kda_core_out", "kda_core_states")
@@ -327,62 +351,84 @@ def _column(rows, j: int):
     )
 
 
-def _substitute(own, r0: int, rhs, transposed: bool):
-    """X of (I + B) X = rhs (of (I + B)^T X = rhs with `transposed`), B the
-    diagonal sub-block of the strictly lower A whose rows `own` (`_SUB`,
-    C) are and whose first column is `r0`; rhs (`_SUB`, n).  Substitution
-    a row at a time in float32 on the vector unit: no inverse is formed,
-    so nothing cancels where keys align (|B| near 1)."""
-    if not transposed:
-        # once row j stands, every later row i sheds B[i, j] X_j
-        for j in range(_SUB - 1):
-            rhs = rhs - _column(own, r0 + j) * rhs[j:j + 1]
-        return rhs
-    # X_j = rhs_j - sum_{i > j} B[i, j] X_i, from the last row up
-    at = _iota(rhs.shape, 0)
-    for j in range(_SUB - 2, -1, -1):
-        rhs = rhs - jnp.where(
-            at == j,
-            (_column(own, r0 + j) * rhs).sum(axis=0, keepdims=True), 0.0,
-        )
-    return rhs
+def _solve(systems, dtype):
+    """[X of (I + A) X = R] for every (A, R, upper) of `systems`: A (C, C)
+    strictly lower, or strictly UPPER with `upper` (a backward's
+    transposed system, handed over as the transpose so that both kinds
+    are ONE substitution, run from opposite ends).  Over the sub-blocks
+    the rows found so far, with zeros for the rest, meet A whole through
+    the MXU, so nothing is sliced along the lanes and the diagonal block
+    meets zeros; inside a sub-block a row at a time in float32 on the
+    vector unit: once row j stands, every row after it (before it, with
+    `upper`) sheds A[i, j] X_j.  No inverse is formed, so nothing cancels
+    where keys align (|A| near 1), and a row rounded to the stated type
+    for the MXU is what the rows after it are corrected by.
+
+    The systems advance TOGETHER, a row step of each after a row step of
+    the others: one system is a chain of C dependent steps of a few
+    cycles' work, and chains side by side in program order are what
+    fills the vector unit (the heads of a grid step written one after the
+    other are not interleaved by the compiler: `PERF.md` section 6, PR
+    54)."""
+    size = systems[0][1].shape[0]
+    starts = list(range(0, size, _SUB))
+    found = [{} for _ in systems]
+    for n in range(len(starts)):
+        first, own, rhs = [], [], []
+        for (A, R, upper), mine in zip(systems, found):
+            r0 = starts[-1 - n] if upper else starts[n]
+            block = R[r0:r0 + _SUB]
+            if mine:
+                so_far = jnp.concatenate([
+                    mine.get(at, jnp.zeros_like(block)) for at in starts
+                ], axis=0)
+                block = block - _dot(A, so_far, _ROW_COL, dtype)[
+                    r0:r0 + _SUB
+                ]
+            first.append(r0)
+            own.append(A[r0:r0 + _SUB])
+            rhs.append(block)
+        for step in range(_SUB - 1):
+            for s, (_, _, upper) in enumerate(systems):
+                j = _SUB - 1 - step if upper else step
+                rhs[s] = rhs[s] - _column(own[s], first[s] + j) * rhs[s][
+                    j:j + 1
+                ]
+        for mine, r0, block in zip(found, first, rhs):
+            mine[r0] = block
+    return [
+        jnp.concatenate([mine[at] for at in starts], axis=0)
+        for mine in found
+    ]
 
 
-def _solve(A, R, dtype, transposed: bool = False):
-    """U of (I + A) U = R (of (I + A)^T U = R with `transposed`) for the
-    strictly lower (C, C) A, by substitution over its sub-blocks: the rows
-    found so far, with zeros for the rest, meet A (A^T) whole through the
-    MXU, so nothing is sliced along the lanes and the diagonal block meets
-    zeros; inside a sub-block, `_substitute`."""
-    size = R.shape[0]
-    blocks = list(range(0, size, _SUB))
-    found = {}
-    for r0 in reversed(blocks) if transposed else blocks:
-        rhs = R[r0:r0 + _SUB]
-        if found:
-            so_far = jnp.concatenate([
-                found.get(at, jnp.zeros_like(rhs)) for at in blocks
-            ], axis=0)
-            rhs = rhs - _dot(
-                A, so_far, _ROWS if transposed else _ROW_COL, dtype
-            )[r0:r0 + _SUB]
-        found[r0] = _substitute(A[r0:r0 + _SUB], r0, rhs, transposed)
-    return jnp.concatenate([found[at] for at in blocks], axis=0)
+# What a chunk's two halves share: the (normed) q and k, b, and the
+# intermediates made before the linear system is solved.
+_Chunk = collections.namedtuple(
+    "_Chunk", "q k b G M P eq Qg Kb Z ed Kd e_last normed"
+)
 
 
-def _rebuild(q, k, v, g, b, state, dtype):
-    """A chunk's intermediates from its inputs (float32 values) and the
-    (dv, dk) state it starts from."""
+def _rebuild(q, k, v, g, b, state, dtype, qk_norm=None):
+    """A chunk up to its linear system, from its inputs (float32 values)
+    and the (dv, dk) state it starts from: (what the rest of the chunk
+    reads, the system (A, R) whose solution is U)."""
+    normed = None
+    if qk_norm is not None:
+        q, q_unit, q_factor = _l2(q, *qk_norm)
+        k, k_unit, k_factor = _l2(k, qk_norm[0], 1.0)
+        normed = (q_unit, q_factor, k_unit, k_factor)
     G = _prefix(g)
     M, P = _tri(q, k, G, dtype)
-    A = M * b
     eq = jnp.exp(G)
     Qg, Kb = q * eq, k * eq
     Z = v - _dot(Kb, state, _COLS, dtype)
-    U = _solve(A, b * Z, dtype)
     last = G[-1:]
     ed = jnp.exp(last - G)
-    return G, M, P, A, eq, Qg, Kb, Z, U, ed, k * ed, jnp.exp(last)
+    rebuilt = _Chunk(
+        q, k, b, G, M, P, eq, Qg, Kb, Z, ed, k * ed, jnp.exp(last), normed
+    )
+    return rebuilt, (M * b, b * Z)
 
 
 def _l2(x, eps: float, scale: float):
@@ -397,41 +443,39 @@ def _l2_backward(dy, unit, factor):
     return factor * (dy - unit * (unit * dy).sum(axis=1, keepdims=True))
 
 
-def _chunk_forward(q, k, v, g, b, state, dtype, qk_norm=None):
+def _chunk_forward(rebuilt, U, state, dtype):
     """(o (C, dv), the next state (dv, dk)), float32."""
-    if qk_norm is not None:
-        q, k = _l2(q, *qk_norm)[0], _l2(k, qk_norm[0], 1.0)[0]
-    _, _, P, _, _, Qg, _, _, U, _, Kd, e_last = _rebuild(
-        q, k, v, g, b, state, dtype
+    out = _dot(rebuilt.Qg, state, _COLS, dtype) + _dot(
+        rebuilt.P, U, _ROW_COL, dtype
     )
-    out = _dot(Qg, state, _COLS, dtype) + _dot(P, U, _ROW_COL, dtype)
-    return out, state * e_last + _dot(U, Kd, _ROWS, dtype)
+    return out, state * rebuilt.e_last + _dot(U, rebuilt.Kd, _ROWS, dtype)
 
 
-def _chunk_backward(q, k, v, g, b, state, d_out, d_next, dtype,
-                    qk_norm=None):
+def _written_gradient(rebuilt, d_out, d_next, dtype):
+    """dU, the gradient of the rows the chunk writes, from the gradients
+    of its output (O = Qg S + P U) and of the state it leaves (S' = S
+    e^{G_C} + U^T Kd): it does not wait for U."""
+    return _dot(rebuilt.P, d_out, _ROWS, dtype) + _dot(
+        rebuilt.Kd, d_next, _COLS, dtype
+    )
+
+
+def _chunk_backward(rebuilt, U, dR, state, d_out, d_next, dtype):
     """(dq, dk, dv, dg, db, the gradient of the chunk's starting state)
-    from the gradients of its output and of the state it leaves."""
-    if qk_norm is not None:
-        q, q_unit, q_factor = _l2(q, *qk_norm)
-        k, k_unit, k_factor = _l2(k, qk_norm[0], 1.0)
-    G, M, P, A, eq, Qg, Kb, Z, U, ed, Kd, e_last = _rebuild(
-        q, k, v, g, b, state, dtype
-    )
+    from the gradients of its output and of the state it leaves, U and
+    dR = (I + A)^-T dU."""
+    q, k, b, G, M, P, eq, Qg, Kb, Z, ed, Kd, e_last, normed = rebuilt
     size = G.shape[0]
     row, col = _iota((size, size), 0), _iota((size, size), 1)
     # O = Qg S + P U
     dQg = _dot(d_out, state, _ROW_COL, dtype)
     d_state = _dot(d_out, Qg, _ROWS, dtype)
     dP = jnp.where(row >= col, _dot(d_out, U, _COLS, dtype), 0.0)
-    dU = _dot(P, d_out, _ROWS, dtype)
     # S' = S e^{G_C} + U^T Kd
     d_state = d_state + d_next * e_last
     d_last = (state * d_next).sum(axis=0, keepdims=True)
     dKd = _dot(U, d_next, _ROW_COL, dtype)
-    dU = dU + _dot(Kd, d_next, _COLS, dtype)
     # U = T R, R = b Z, T = (I + b M)^-1, Z = V - Kb S
-    dR = _solve(A, dU, dtype, transposed=True)
     dA = -jnp.where(row > col, _dot(dR, U, _COLS, dtype), 0.0)
     db = (dR * Z).sum(axis=1, keepdims=True) + (dA * M).sum(
         axis=1, keepdims=True
@@ -447,9 +491,9 @@ def _chunk_backward(q, k, v, g, b, state, d_out, d_next, dtype,
     )
     # every g of the chunk is in G_C: e^{G_C} and Kd's exponent
     d_sum = (dKd * Kd).sum(axis=0, keepdims=True) + d_last * e_last
-    if qk_norm is not None:
-        dq = _l2_backward(dq, q_unit, q_factor)
-        dk = _l2_backward(dk, k_unit, k_factor)
+    if normed is not None:
+        dq = _l2_backward(dq, normed[0], normed[1])
+        dk = _l2_backward(dk, normed[2], normed[3])
     return dq, dk, dZ, _prefix(dG, reverse=True) + d_sum, db, d_state
 
 
@@ -467,14 +511,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, states_ref,
     def _():
         state_sc[...] = jnp.zeros(state_sc.shape, jnp.float32)
 
-    # the heads of a step are independent chains: side by side they hide
-    # each other's latencies
-    for h in range(state_sc.shape[0]):
-        state = state_sc[h]
-        states_ref[0, h, 0] = state
-        out, state_sc[h] = _chunk_forward(
+    # the heads of a step are independent chains: their systems are
+    # solved side by side (`_solve`)
+    dtype = q_ref.dtype
+    heads = range(state_sc.shape[0])
+    states = [state_sc[h] for h in heads]
+    rebuilt, systems = zip(*(
+        _rebuild(
             _head(q_ref, h, dk), _head(k_ref, h, dk), _head(v_ref, h, dv),
-            _head(g_ref, h, dk), b_ref[0, h], state, q_ref.dtype, qk_norm,
+            _head(g_ref, h, dk), b_ref[0, h], states[h], dtype, qk_norm,
+        )
+        for h in heads
+    ))
+    solved = _solve([(A, R, False) for A, R in systems], dtype)
+    for h in heads:
+        states_ref[0, h, 0] = states[h]
+        out, state_sc[h] = _chunk_forward(
+            rebuilt[h], solved[h], states[h], dtype
         )
         o_ref[0, :, h * dv:(h + 1) * dv] = out.astype(o_ref.dtype)
 
@@ -486,13 +539,34 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, states_ref, do_ref,
     def _():
         d_state_sc[...] = jnp.zeros(d_state_sc.shape, jnp.float32)
 
-    for h in range(d_state_sc.shape[0]):
+    dtype = q_ref.dtype
+    heads = range(d_state_sc.shape[0])
+    states = [states_ref[0, h, 0] for h in heads]
+    d_outs = [_head(do_ref, h, dv) for h in heads]
+    d_nexts = [d_state_sc[h] for h in heads]
+    rebuilt, systems = zip(*(
+        _rebuild(
+            _head(q_ref, h, dk), _head(k_ref, h, dk), _head(v_ref, h, dv),
+            _head(g_ref, h, dk), b_ref[0, h], states[h], dtype, qk_norm,
+        )
+        for h in heads
+    ))
+    # a head's two systems, U's and its gradient's transposed one, wait
+    # for nothing of each other: all of a step's are solved side by side
+    solved = _solve(
+        [(A, R, False) for A, R in systems] + [
+            (A.T, _written_gradient(rebuilt[h], d_outs[h], d_nexts[h],
+                                    dtype), True)
+            for h, (A, _) in enumerate(systems)
+        ],
+        dtype,
+    )
+    for h in heads:
         keys = slice(h * dk, (h + 1) * dk)
         values = slice(h * dv, (h + 1) * dv)
         dq, dk_, dv_, dg, db, d_state_sc[h] = _chunk_backward(
-            _head(q_ref, h, dk), _head(k_ref, h, dk), _head(v_ref, h, dv),
-            _head(g_ref, h, dk), b_ref[0, h], states_ref[0, h, 0],
-            _head(do_ref, h, dv), d_state_sc[h], q_ref.dtype, qk_norm,
+            rebuilt[h], solved[h], solved[len(heads) + h], states[h],
+            d_outs[h], d_nexts[h], dtype,
         )
         dq_ref[0, :, keys] = dq.astype(dq_ref.dtype)
         dk_ref[0, :, keys] = dk_.astype(dk_ref.dtype)
@@ -524,8 +598,13 @@ def _vma(operands):
     return frozenset().union(*(jax.typeof(x).vma for x in operands))
 
 
-def _heads_a_step(heads: int) -> int:
-    return _HEADS if heads % _HEADS == 0 else 1
+def _heads_a_step(heads: int, width: int = _LANES, most=None) -> int:
+    """`most` (`_HEADS`) heads of `_LANES`, as many fewer as a wider head
+    asks, or the largest half of that which divides the head count."""
+    most = max(1, (most or _HEADS) * _LANES // width)
+    while heads % most:
+        most //= 2
+    return most
 
 
 def _specs(chunks: int, group: int, dk: int, dv: int, reverse: bool):
@@ -567,7 +646,7 @@ def _kda(q, k, v, g, beta, qk_norm=None):
 def _forward_call(batch, length, heads, dk, dv, dtype, qk_norm, vma,
                   interpret):
     chunks = length // CHUNK
-    group = _heads_a_step(heads)
+    group = _heads_a_step(heads, max(dk, dv))
     rows, scalars, states = _specs(chunks, group, dk, dv, reverse=False)
     return _call(
         functools.partial(_fwd_kernel, dk=dk, dv=dv, qk_norm=qk_norm),
@@ -585,7 +664,7 @@ def _forward_call(batch, length, heads, dk, dv, dtype, qk_norm, vma,
 def _backward_call(batch, length, heads, dk, dv, dtypes, qk_norm, vma,
                    interpret):
     chunks = length // CHUNK
-    group = _heads_a_step(heads)
+    group = _heads_a_step(heads, max(dk, dv))
     rows, scalars, states = _specs(chunks, group, dk, dv, reverse=True)
     return _call(
         functools.partial(_bwd_kernel, dk=dk, dv=dv, qk_norm=qk_norm),

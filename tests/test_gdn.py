@@ -193,10 +193,25 @@ def test_bfloat16_operands_keep_float32_state_and_types():
     (((2, 128, 16, 128), (2, 128, 24, 128)), False),    # no whole ratio
     (((2, 100, 16, 128), (2, 100, 32, 128)), False),    # no whole chunks
     (((2, 128, 16, 64), (2, 128, 32, 128)), False),     # no whole lane tile
+    (((2, 128, 2, 128), (2, 128, 32, 128)), False),     # 16 a key head
 ])
 def test_the_admission_rule(shapes, ok):
     qk, v = shapes
     assert gdn_ops.gdn_shapes_ok(qk, qk, v) is ok
+
+
+@pytest.mark.parametrize("key_heads, ratio, width, want", [
+    (16, 2, 128, (4, 8)),          # the cell's: eight value heads a step
+    (32, 1, 128, (8, 8)),
+    (2, 2, 128, (2, 4)),
+    (2, 1, 128, (2, 2)),
+    (3, 2, 128, (1, 2)),           # no half of four divides three
+    (16, 2, 256, (2, 4)),          # a wider head, fewer of them
+    (16, 16, 128, (1, 16)),        # one key head is the least
+])
+def test_the_heads_of_a_grid_step(key_heads, ratio, width, want):
+    assert gdn_ops._groups(key_heads, ratio, width) == want
+    assert key_heads % want[0] == 0
 
 
 def test_the_kernels_carry_their_own_names():

@@ -162,6 +162,71 @@ def test_aligned_keys_do_not_cancel(form):
         assert_close(a, b, 5e-3, name)
 
 
+def chunk_system(kind, g_min, seed):
+    """(A strictly lower (64, 64), R (64, 128)) of one chunk, float32: A =
+    Diag(beta) (K K^T o decay) below the diagonal, as `_rebuild` hands it
+    to `_solve`; `kind` as in `test_aligned_keys_do_not_cancel`."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    k = jax.random.normal(keys[0], (64, 128))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[1], (64, 1)))
+    if kind != "random":
+        k = jnp.broadcast_to(k[:1], k.shape)
+        beta = jnp.full_like(beta, 0.95)
+    if kind == "opposite":
+        k = k * jnp.where(jnp.arange(64) % 2 == 0, 1.0, -1.0)[:, None]
+    G = jnp.cumsum(g_min * jax.random.uniform(keys[2], (64, 1)), axis=0)
+    with jax.default_matmul_precision("highest"):
+        A = jnp.tril(k @ k.T * jnp.exp(jnp.minimum(G - G.T, 0.0)), -1) * beta
+    return A, jax.random.normal(keys[3], (64, 128))
+
+
+SYSTEMS = [
+    pytest.param("random", 0.0, id="random-keys-no-decay"),
+    pytest.param("random", -1.0, id="random-keys-mild"),
+    pytest.param("random", -20.0, id="random-keys-strong"),
+    pytest.param("aligned", 0.0, id="aligned-keys"),
+    pytest.param("aligned", -20.0, id="aligned-keys-strong"),
+    pytest.param("opposite", 0.0, id="opposite-keys"),
+]
+
+
+@pytest.mark.parametrize("kind, g_min", SYSTEMS)
+def test_the_substitution_solves_both_systems_side_by_side(kind, g_min):
+    """`_solve` against `jax.scipy.linalg.solve_triangular`: (I + A) X = R
+    and, handed A^T as a strictly upper system, (I + A)^T X = R, two
+    chunks' four systems advancing together; and each alone is the same
+    number to the bit (side by side is an ORDER of statements, not
+    another arithmetic)."""
+    from jax.scipy.linalg import solve_triangular
+
+    chunks = [chunk_system(kind, g_min, seed) for seed in (5, 6)]
+    systems = [(A, R, False) for A, R in chunks] + [
+        (A.T, R, True) for A, R in chunks
+    ]
+    with jax.default_matmul_precision("highest"):
+        together = kda_ops._solve(systems, jnp.float32)
+        alone = [kda_ops._solve([one], jnp.float32)[0] for one in systems]
+        for (A, R, upper), got, single in zip(systems, together, alone):
+            want = solve_triangular(
+                A + jnp.eye(64), R, lower=not upper, unit_diagonal=True
+            )
+            assert np.isfinite(np.asarray(got)).all()
+            assert_close(got, want, 1e-5, (kind, g_min, upper))
+            assert (np.asarray(got) == np.asarray(single)).all()
+
+
+def test_heads_a_step_divide_the_heads_and_fit_a_wider_head():
+    """Four heads of 128 a grid step, fewer of a wider head, and never a
+    count that does not divide the heads."""
+    assert kda_ops._heads_a_step(32) == 4               # the cell's
+    assert kda_ops._heads_a_step(2) == 2
+    assert kda_ops._heads_a_step(6) == 2
+    assert kda_ops._heads_a_step(3) == 1
+    assert kda_ops._heads_a_step(32, 256) == 2
+    assert kda_ops._heads_a_step(32, 1024) == 1
+
+
 def test_admission_names_and_types():
     ok = kda_ops.kda_shapes_ok
     cell = (2, 8192, 32, 128)

@@ -891,7 +891,9 @@ def test_the_layers_scopes_reach_the_lowered_operations():
 PARENTS_JAXPRS = {
     "kimi-linear-48b-a3b": (
         "kimi.kimi_linear", (2, 8192),
-        "64f2357518c6c947da7abd32219eb96d6bbe40024ae89012b815ec5e8f558e64",
+        # re-recorded on purpose in PR 54: the program holds the KDA
+        # kernels' bodies, whose substitution changed (`ops/kda.py`)
+        "072668fe5ddb9151c6d0f61e044857fad12368a7a5c6ed3db473939e77ab9bad",
     ),
     "nemotron-3-nano-30b-a3b": (
         "nemotron.nemotron_h", (2, 8192),
