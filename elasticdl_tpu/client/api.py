@@ -40,10 +40,13 @@ def predict(args) -> int:
 def _train_local(args, job_type: str = "train") -> int:
     """Master + worker(s) in one process: the zero-cluster path (and the
     dev loop for model-zoo modules)."""
+    from elasticdl_tpu.common import profiler, programs
     from elasticdl_tpu.common.model_handler import get_model_spec
     from elasticdl_tpu.common.virtual_mesh import enable_compile_cache
 
     enable_compile_cache(getattr(args, "compilation_cache_dir", ""))
+    # before the zoo module is imported: what it compiles is seen too
+    programs.install_compile_listeners()
     from elasticdl_tpu.data.reader import create_data_reader
     from elasticdl_tpu.master.main import Master
     from elasticdl_tpu.proto.service import InProcessMasterClient
@@ -154,6 +157,9 @@ def _train_local(args, job_type: str = "train") -> int:
         checkpoint_saver=make_saver(),
         checkpoint_steps=args.checkpoint_steps,
     )
+    # `job_setup` (client/main.py opened it) ends here; the first worker
+    # thread's loop closes `worker_setup` (worker/worker.py: Worker.run)
+    profiler.process_phase_timer().startup("worker_setup")
 
     # Tiered embedding store (elasticdl_tpu/store): a zoo module that
     # exports build_tiered_store() opts into the host-RAM bulk tier +

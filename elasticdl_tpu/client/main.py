@@ -15,8 +15,10 @@ from __future__ import annotations
 
 import argparse
 import sys
+import time
 
 from elasticdl_tpu.common import args as args_lib
+from elasticdl_tpu.common.constants import DistributionStrategy
 
 
 def _build_parser() -> argparse.ArgumentParser:
@@ -146,6 +148,7 @@ def _build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
+    entered = time.perf_counter()
     parser = _build_parser()
     # Strict parsing: a typo'd flag must error, not silently fall back to
     # a default (the master/worker argv wire format stays tolerant via
@@ -154,6 +157,15 @@ def main(argv=None) -> int:
     if not getattr(args, "func", None):
         parser.print_help()
         return 2
+
+    if args.func in ("train", "evaluate", "predict") and (
+        args.distribution_strategy == DistributionStrategy.LOCAL
+    ):
+        # this process is the job: its start-up record begins here
+        # (docs/OBSERVABILITY.md "Start-up catalogue")
+        from elasticdl_tpu.common import profiler
+
+        profiler.process_phase_timer().begin_startup(entered)
 
     from elasticdl_tpu.client import api, image_builder
 

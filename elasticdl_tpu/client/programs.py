@@ -3,7 +3,9 @@
 Every role's telemetry server republishes its process-wide
 ProgramRegistry (common/programs.py) summary under the "programs" varz
 key: per-program compile counts, distinct aval signatures vs declared
-budget, recompile storms, compile-time quantiles, and the XLA cost
+budget, recompile storms, compile-time quantiles, the compiles' seconds
+by stage (trace, lowering, XLA or cache load) with the persistent
+cache's hits and misses, and the XLA cost
 model (flops / bytes per execution) joined with live step rate into
 MFU and bandwidth attribution.  Like `elasticdl top` this is a pure
 HTTP client; `render_programs` is also callable directly on a summary
@@ -42,7 +44,9 @@ def render_programs(summary: dict) -> str:
         ),
         "program".ljust(24) + "compiles".rjust(9) + "sigs".rjust(6)
         + "budget".rjust(7) + "storms".rjust(7) + "c_p50".rjust(9)
-        + "c_p99".rjust(9) + "flops/x".rjust(9) + "bytes/x".rjust(9),
+        + "c_p99".rjust(9) + "trace".rjust(9) + "lower".rjust(9)
+        + "xla".rjust(9) + "cache h/m".rjust(10) + "flops/x".rjust(9)
+        + "bytes/x".rjust(9),
     ]
     ledger = summary.get("ledger", {})
     for name in sorted(ledger):
@@ -60,6 +64,15 @@ def render_programs(summary: dict) -> str:
             + "{:.3f}s".format(
                 rec.get("compile_seconds_p99", 0.0)
             ).rjust(9)
+            # the compiles' seconds by stage, summed (xla: XLA's compile
+            # or the persistent cache's load), and that cache's answers
+            + "".join(
+                "{:.3f}s".format(rec.get(stage + "_seconds", 0.0)).rjust(9)
+                for stage in ("trace", "lower", "xla")
+            )
+            + "{}/{}".format(
+                rec.get("cache_hits", 0), rec.get("cache_misses", 0)
+            ).rjust(10)
             + _eng(rec.get("flops_per_execution", 0.0)).rjust(9)
             + _eng(rec.get("bytes_per_execution", 0.0)).rjust(9)
         )

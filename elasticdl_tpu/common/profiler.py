@@ -11,6 +11,8 @@ same regions on the host plane as `edl:<phase>`).
 from __future__ import annotations
 
 import contextlib
+import functools
+import os
 import threading
 import time
 from collections import deque
@@ -39,6 +41,24 @@ STEP_PHASES = LOOP_PHASES + PRODUCER_PHASES + (
     # benchmark reads it yet: ROADMAP.md Reach 7).
     "cold_gather",
 )
+
+#: The start-up vocabulary (docs/OBSERVABILITY.md "Start-up catalogue"):
+#: what a process does between its start and its loop's first `get_task`,
+#: one after the other on its main path.  `boot`, `job_setup` and
+#: `worker_setup` are recorded by `PhaseTimer.begin_startup()` /
+#: `startup()`, `init_state` and `restore` through `phase()` where the
+#: state is made (lazily, from the first batch's shapes: inside the first
+#: task, on the loop thread).  Their totals feed the labeled gauge
+#: `worker_startup_phase_seconds{phase=...}`, never the per-step
+#: histogram.
+STARTUP_PHASES = (
+    "boot", "job_setup", "init_state", "restore", "worker_setup",
+)
+#: The stages of ONE compile, children of whatever span the compiling
+#: thread is in (`init_state`, `compute`, ...), laid back from jax's own
+#: monitoring events by `common/programs.py`; `attrs` name the `program`
+#: and, on `compile_xla`, the persistent cache's answer (`cache`).
+COMPILE_PHASES = ("compile_trace", "compile_lower", "compile_xla")
 
 #: The device-scope vocabulary (docs/OBSERVABILITY.md "Device scope
 #: catalogue"): the `jax.named_scope`s by which a train step's device
@@ -98,6 +118,34 @@ COMPILER_NAMED_SCOPES = {"ragged-dot": "experts"}
 #: makes about five (measured: 4.6 in the benchmark's DeepFM cell), so
 #: this is some 7,000 steps back.
 SPAN_RING_RECORDS = 32768
+#: Records of the start-up and compile vocabulary kept OUTSIDE the ring,
+#: where no later record pushes them out: a job of a day still shows its
+#: start.  A warm decoder job makes some hundreds before its first step
+#: (three a compile); past this many (a retrace storm late in a long job)
+#: they go into the ring like any other.
+STARTUP_SPAN_RECORDS = 4096
+
+_IMPORTED_AT = time.perf_counter()
+
+
+@functools.lru_cache(maxsize=None)
+def process_start() -> float:
+    """The process's start on `time.perf_counter()`: the OS's own stamp
+    (`/proc/self/stat`, field 22: clock ticks after boot) mapped once
+    onto the spans' clock, so that the interpreter's start and every
+    import lie inside `boot`; the first import of this module where the
+    OS gives none."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the command may hold spaces and brackets: fields after it
+            fields = f.read().rsplit(")", 1)[1].split()
+        started = int(fields[19]) / os.sysconf("SC_CLK_TCK")
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - started
+    except (OSError, ValueError, IndexError, AttributeError):
+        return _IMPORTED_AT
+    if age < 0:
+        return _IMPORTED_AT
+    return min(time.perf_counter() - age, _IMPORTED_AT)
 
 
 class Span(NamedTuple):
@@ -190,12 +238,24 @@ class PhaseTimer:
     and step the calling thread's next spans belong to.  Nothing is
     written anywhere on the hot path.
 
+    Start-up (`STARTUP_PHASES`) and compile stages (`COMPILE_PHASES`)
+    are the same `Span` records through the same `phase()` / `add()`,
+    with totals of their own (a labeled gauge, `startup_gauge`; never
+    the per-step histogram, the `step_phases` event or `snapshot()`).
+    The first `STARTUP_SPAN_RECORDS` of them are kept in a list beside
+    the ring, which `spans()` puts in front of it, so the ring's
+    turnover never drops a job's start; later ones share the ring.
+    `begin_startup()` / `startup()` carry the main path's phases from
+    one function (and thread) to the next: each call closes the phase
+    that is open and opens the next, so they cannot overlap.
+
     Thread-safe: the prefetch producer thread times `read` / `pack` /
     `queue_full` while the consumer loop runs `phase()`/`step_done()`.
     """
 
     def __init__(self, phases=STEP_PHASES, histogram=None,
-                 flush_every: int = 50, ring: int = SPAN_RING_RECORDS):
+                 flush_every: int = 50, ring: int = SPAN_RING_RECORDS,
+                 startup_gauge=None):
         # here, not at import: the master reads this module's vocabulary
         # and never touches jax
         from jax.profiler import TraceAnnotation
@@ -211,6 +271,13 @@ class PhaseTimer:
         self._pending = {p: 0.0 for p in self.phases}     # since last flush
         self._steps = 0
         self._pending_steps = 0
+        self._startup_gauge = startup_gauge   # labeled gauge or None
+        self._startup_totals = {
+            p: 0.0 for p in STARTUP_PHASES + COMPILE_PHASES
+        }
+        self._startup: list = []      # outside the ring: never pushed out
+        self._startup_open = None     # (name, start, native thread id)
+        self._booted = False
 
     def mark(self, task_id=_KEEP, step=_KEEP) -> None:
         """The task and the step (index of the batch in its task) that
@@ -238,7 +305,7 @@ class PhaseTimer:
         return _OpenSpan(self, name, attrs)
 
     def add(self, name: str, seconds: float,
-            start: Optional[float] = None) -> None:
+            start: Optional[float] = None, **attrs) -> None:
         """A region timed by the caller: `seconds` long, begun at `start`
         on `time.perf_counter()` (default: ended now)."""
         seconds = max(0.0, float(seconds))
@@ -248,14 +315,53 @@ class PhaseTimer:
         self._record(Span(
             name, start, start + seconds, marks.native_id,
             marks.task_id, marks.step,
-            marks.open[-1] if marks.open else None, None,
+            marks.open[-1] if marks.open else None, attrs or None,
         ))
+
+    def begin_startup(self, entered: float) -> None:
+        """At a process's entry point (`entered` on `perf_counter()`):
+        `boot`, from the process's start to there, once a process, and
+        `job_setup` opened there."""
+        if not self._booted:
+            self._booted = True
+            self.add("boot", entered - process_start(), process_start())
+        self.startup("job_setup", start=entered)
+
+    def startup(self, name: Optional[str],
+                start: Optional[float] = None) -> None:
+        """Close the main path's open start-up phase, now, and open
+        `name` (None: none; the loop's own spans carry on from here).
+        The closed span belongs to the thread that OPENED it: the loop
+        thread closes `worker_setup`, which the main thread was in."""
+        now = time.perf_counter()
+        with self._lock:
+            closed = self._startup_open
+            self._startup_open = None if name is None else (
+                name, now if start is None else start,
+                threading.get_native_id(),
+            )
+        if closed is not None:
+            self._record(Span(
+                closed[0], closed[1], max(now, closed[1]), closed[2],
+                None, None, None, None,
+            ))
 
     def _record(self, span: Span) -> None:
         name = span.name
+        seconds = span.end - span.start
+        if name in self._startup_totals:
+            with self._lock:
+                self._startup_totals[name] += seconds
+                total = self._startup_totals[name]
+                if len(self._startup) < STARTUP_SPAN_RECORDS:
+                    self._startup.append(span)
+                else:
+                    self._ring.append(span)
+            if self._startup_gauge is not None and name in STARTUP_PHASES:
+                self._startup_gauge.labels(phase=name).set(total)
+            return
         if name not in self._totals:
             return  # unknown phase: attribution must never raise
-        seconds = span.end - span.start
         with self._lock:
             self._totals[name] += seconds
             self._pending[name] += seconds
@@ -267,10 +373,11 @@ class PhaseTimer:
                 pass
 
     def spans(self) -> list:
-        """The ring's records, oldest first (in the order regions ENDED;
-        at most `ring` of them)."""
+        """The kept start-up and compile records (at most
+        `STARTUP_SPAN_RECORDS`), then the ring's (at most `ring`), each
+        oldest first (in the order regions ENDED)."""
         with self._lock:
-            return list(self._ring)
+            return self._startup + list(self._ring)
 
     def step_done(self) -> None:
         """Count one executed step; flush a `step_phases` span event at
@@ -358,7 +465,17 @@ def process_phase_timer() -> PhaseTimer:
             # count 0 instead of disappearing.
             for phase in STEP_PHASES:
                 histogram.labels(phase=phase)
-            _process_timer = PhaseTimer(histogram=histogram)
+            startup_gauge = metrics_lib.default_registry().gauge(
+                "worker_startup_phase_seconds",
+                "wall seconds of the process's start-up by phase "
+                "(profiler.STARTUP_PHASES), summed where one recurs",
+                labelnames=("phase",),
+            )
+            for phase in STARTUP_PHASES:
+                startup_gauge.labels(phase=phase)
+            _process_timer = PhaseTimer(
+                histogram=histogram, startup_gauge=startup_gauge
+            )
         return _process_timer
 
 

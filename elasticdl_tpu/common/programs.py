@@ -6,8 +6,13 @@ by reporting an already-compiled executable via
 :func:`register_compiled` (bench / ad-hoc AOT).  The registry records,
 per named program and per distinct aval signature:
 
-- compile wall seconds (``worker_program_compile_seconds{program}``
-  histogram, injectable clock so tests replay deterministically);
+- the wall seconds of each dispatch that compiled
+  (``worker_program_compile_seconds{program}`` histogram, injectable
+  clock so tests replay deterministically): trace + lowering + XLA's
+  compile or the persistent cache's load, added up;
+- that sum's parts, by stage, as jax's own monitoring events give them
+  ("Compile stages" below): trace, lowering, XLA or cache load, and the
+  persistent cache's hits and misses;
 - compile / retrace counts and the distinct-signature count;
 - XLA's own cost model (``cost_analysis()`` flops + bytes accessed).
 
@@ -265,6 +270,166 @@ def parse_scope_table(hlo_text: str) -> Dict[str, ScopeRow]:
     return table
 
 
+# ---- compile stages -------------------------------------------------------
+#
+# jax (0.9) times every compile itself and hands the seconds to whoever
+# listens (`jax.monitoring`): on the dispatching thread, in this order,
+#
+#   jaxpr_trace_duration            the Python trace, once for every jitted
+#                                   function called INSIDE the traced body
+#                                   (`sin`, `matmul`, ...), then for the
+#                                   outermost, the one that counts, and then
+#                                   for what a lowering rule traces (they END
+#                                   inside the lowering's seconds)
+#   jaxpr_to_mlir_module_duration   the lowering
+#   compile_requests_use_cache      where a persistent cache is on, then
+#   cache_hits                      on a hit, with
+#   cache_retrieval_time_sec        the load's seconds
+#   backend_compile_duration        XLA's compile, or that load
+#
+# (`/jax/compilation_cache/cache_misses` fires only where the new entry is
+# WRITTEN, past the cache's size and compile-time thresholds: a request
+# that no hit follows is the miss here.)  `fun_name` cannot say whose
+# compile it is: every registered program's is `_observed`, the wrapper's,
+# and that name is in the lowered module, so in every persistent-cache key.
+# What can: the events fire on the thread that dispatches, and
+# `RegisteredProgram` says on that thread which program it is dispatching
+# (`_compiling.owner`).  A compile with no owner is unregistered: an eager
+# `jnp` operation, a bare `jax.jit`.  `tests/test_startup_spans.py` finds
+# each event fired by the installed jax, so a jax that renames one fails a
+# test and no counter silently reads 0.
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+XLA_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_REQUEST_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+COMPILE_EVENTS = (
+    TRACE_EVENT, LOWER_EVENT, XLA_EVENT, CACHE_REQUEST_EVENT,
+    CACHE_HIT_EVENT, CACHE_RETRIEVAL_EVENT,
+)
+STAGES = ("trace", "lower", "xla")
+#: the `program` of a compile span that belongs to no registered program
+UNREGISTERED = "(unregistered)"
+
+
+class _Compiling(threading.local):
+    """The compile in progress on this thread, as the listeners know it."""
+
+    owner = None        # (registry, program name) while one dispatches
+    lower = None        # (start, end) of the lowering
+    cache = "off"       # -> "miss" at the request -> "hit"
+    retrieval_s = 0.0
+    stages = None       # {stage: seconds, "cache": ...} summed for `owner`
+
+    def __init__(self):     # once a thread, at its first use
+        # [(start, end)] on perf_counter of the trace events since the
+        # last compile that no later one encloses, in order
+        self.traces = []
+
+    def begin(self, owner):
+        """Arm `owner` (None disarms); returns what was armed before.  A
+        trace left over from before (an `eval_shape`) is not this one's."""
+        previous = self.owner, self.stages
+        self.owner, self.stages = owner, None
+        self.traces, self.lower = [], None
+        return previous
+
+    def end(self, previous) -> Optional[dict]:
+        """Back to `previous`; the stages of what compiled meanwhile."""
+        stages = self.stages
+        self.owner, self.stages = previous
+        self.traces, self.lower = [], None
+        return stages
+
+
+_compiling = _Compiling()
+_TRACES_KEPT = 4096
+_listeners_lock = threading.Lock()
+_listeners_installed = False
+
+
+def _on_duration(event: str, seconds: float, **_kwargs) -> None:
+    if event == TRACE_EVENT:
+        end = time.perf_counter()
+        traces = _compiling.traces
+        # an outer trace ends after the inner ones it encloses: the newest
+        while traces and traces[-1][0] >= end - seconds:
+            traces.pop()
+        traces.append((end - seconds, end))
+        # bounded for a thread that only ever traces (`eval_shape`); what
+        # a lowering traces comes after the compile's own, by the hundred
+        del traces[:-_TRACES_KEPT]
+    elif event == LOWER_EVENT:
+        end = time.perf_counter()
+        _compiling.lower = (end - seconds, end)
+        _compiling.cache, _compiling.retrieval_s = "off", 0.0
+    elif event == CACHE_RETRIEVAL_EVENT:
+        _compiling.retrieval_s = seconds
+    elif event == XLA_EVENT:
+        try:
+            _compile_done(time.perf_counter(), seconds)
+        except Exception:   # a listener must never fail the compile
+            logger.exception("compile stages: not recorded")
+
+
+def _on_event(event: str, **_kwargs) -> None:
+    if event == CACHE_REQUEST_EVENT:
+        _compiling.cache = "miss"
+    elif event == CACHE_HIT_EVENT:
+        _compiling.cache = "hit"
+
+
+def _compile_done(now: float, xla_s: float) -> None:
+    """One compile has ended on this thread: its three stages as child
+    spans of whatever span the thread is in, laid back from each event's
+    stamp by its seconds, and their seconds into the owner's ledger."""
+    state = _compiling
+    traces, lower, cache = state.traces, state.lower, state.cache
+    state.traces, state.lower, state.cache = [], None, "off"
+    # the compile's own trace: the last that ended before its lowering began
+    before = [t for t in traces if lower is None or t[1] <= lower[0]]
+    trace = before[-1] if before else None
+    registry, name = state.owner or (default_program_registry(), None)
+    program = name or UNREGISTERED
+    timer = profiler.process_phase_timer()
+    seconds = dict.fromkeys(STAGES, 0.0)
+    for stage, region in (("trace", trace), ("lower", lower)):
+        if region is not None:
+            seconds[stage] = region[1] - region[0]
+            timer.add("compile_" + stage, seconds[stage], region[0],
+                      program=program)
+    seconds["xla"] = xla_s
+    attrs = {"program": program, "cache": cache}
+    if cache == "hit":
+        attrs["retrieval_s"] = state.retrieval_s
+    timer.add("compile_xla", xla_s, now - xla_s, **attrs)
+    registry.note_compile_stages(name, seconds, cache)
+    if name is not None:
+        summed = state.stages or dict.fromkeys(STAGES, 0.0)
+        for stage in STAGES:
+            summed[stage] += seconds[stage]
+        summed["cache"] = cache
+        state.stages = summed
+
+
+def install_compile_listeners() -> None:
+    """Register the process's listeners on jax's compile events, once
+    however often it is asked (every `RegisteredProgram` asks; a process's
+    entry point asks first, so that what compiles before its first
+    registered program is seen too).  They fire at compiles only."""
+    global _listeners_installed
+    with _listeners_lock:
+        if _listeners_installed:
+            return
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_event_listener(_on_event)
+        _listeners_installed = True
+
+
 def cost_analysis_dict(compiled) -> dict:
     """flops / bytes-accessed from XLA's own cost model."""
     return dict(compiled.cost_analysis() or {})
@@ -369,6 +534,8 @@ def _new_record() -> dict:
         "signatures": {},
         "compiles": 0,
         "compile_seconds": [],
+        "stage_seconds": dict.fromkeys(STAGES, 0.0),
+        "cache": {"hit": 0, "miss": 0},
         "storms": 0,
         "budget": None,
         "latest": None,
@@ -398,8 +565,30 @@ class ProgramRegistry:
         reg = metrics or metrics_lib.default_registry()
         self._compile_hist = reg.histogram(
             "worker_program_compile_seconds",
-            "XLA compile wall seconds per registered program",
+            "wall seconds of a dispatch that compiled, per registered "
+            "program: trace + lowering + XLA compile or cache load",
             min_value=1e-3, max_value=900.0, labelnames=("program",),
+        )
+        self._stage_seconds_total = reg.counter(
+            "worker_program_compile_stage_seconds_total",
+            "seconds of a registered program's compiles by stage (trace, "
+            "lower, xla: XLA's compile or the persistent cache's load)",
+            labelnames=("program", "stage"),
+        )
+        self._cache_requests_total = reg.counter(
+            "worker_program_cache_requests_total",
+            "a registered program's compile requests answered by the "
+            "persistent cache (hit) or compiled (miss)",
+            labelnames=("program", "result"),
+        )
+        self._unregistered_compiles_total = reg.counter(
+            "worker_unregistered_compiles_total",
+            "compiles that belong to no registered program (eager "
+            "operations, bare jax.jit)",
+        )
+        self._unregistered_seconds_total = reg.counter(
+            "worker_unregistered_compile_seconds_total",
+            "trace + lowering + XLA or cache load seconds of those",
         )
         self._compiles_total = reg.counter(
             "worker_program_compiles_total",
@@ -448,11 +637,16 @@ class ProgramRegistry:
         seconds: float,
         cost: Optional[dict] = None,
         avals: str = "",
+        stages: Optional[dict] = None,
     ) -> None:
         """Record one compile of `name` for aval-signature digest
         `signature`.  Called by RegisteredProgram after every AOT
-        compile and by register_compiled for external executables."""
+        compile and by register_compiled for external executables.
+        `stages` ({stage: seconds, "cache": ...}, what the listeners
+        gathered during the dispatch) rides the event; the ledger has
+        it already (`note_compile_stages`)."""
         flops, bytes_ = _flops_bytes(cost or {})
+        stages = stages or {}
         with self._lock:
             rec = self._programs.setdefault(name, _new_record())
             sig = rec["signatures"].setdefault(
@@ -485,7 +679,37 @@ class ProgramRegistry:
             flops=flops,
             bytes=bytes_,
             signatures=n_sigs,
+            **{
+                stage + "_seconds": round(stages.get(stage, 0.0), 4)
+                for stage in STAGES
+            },
+            cache=stages.get("cache", "off"),
         )
+
+    def note_compile_stages(self, name: Optional[str], seconds: dict,
+                            cache: str) -> None:
+        """The stages of one compile, as the process's listeners read
+        them: {stage: seconds} and the persistent cache's answer (`hit`,
+        `miss`, or `off` where none was asked).  `name` None: a compile
+        of no registered program, counted and kept in no record."""
+        if name is None:
+            self._unregistered_compiles_total.inc()
+            self._unregistered_seconds_total.inc(sum(seconds.values()))
+            return
+        with self._lock:
+            rec = self._programs.setdefault(name, _new_record())
+            for stage in STAGES:
+                rec["stage_seconds"][stage] += seconds[stage]
+            if cache in rec["cache"]:
+                rec["cache"][cache] += 1
+        for stage in STAGES:
+            self._stage_seconds_total.labels(
+                program=name, stage=stage
+            ).inc(seconds[stage])
+        if cache != "off":
+            self._cache_requests_total.labels(
+                program=name, result=cache
+            ).inc()
 
     def note_storm(self, name: str, signatures: int, budget: int) -> None:
         """A program blew its signature budget within the window: bump
@@ -581,6 +805,12 @@ class ProgramRegistry:
                     "compile_seconds_total": round(sum(times), 6),
                     "compile_seconds_p50": _quantile(times, 0.5),
                     "compile_seconds_p99": _quantile(times, 0.99),
+                    **{
+                        stage + "_seconds": round(total, 6)
+                        for stage, total in rec["stage_seconds"].items()
+                    },
+                    "cache_hits": rec["cache"]["hit"],
+                    "cache_misses": rec["cache"]["miss"],
                     "flops_per_execution": latest.get("flops", 0.0),
                     "bytes_per_execution": latest.get("bytes", 0.0),
                     "avals": latest.get("avals", ""),
@@ -601,14 +831,15 @@ class ProgramRegistry:
 
     def forensics(self) -> dict:
         """The incident-bundle `programs.json` section.  Ledger minus
-        compile wall-time quantiles — they mix in wall-clock state, and
-        bundles must be byte-identical across same-seed runs (the
-        flight-recorder discipline)."""
+        what mixes in the machine's state — every wall-time field, and
+        the persistent cache's hits and misses (a second run finds what
+        the first wrote) — since bundles must be byte-identical across
+        same-seed runs (the flight-recorder discipline)."""
         led = self.ledger()
         return {"ledger": {
             name: {
                 k: v for k, v in rec.items()
-                if not k.startswith("compile_seconds")
+                if "_seconds" not in k and not k.startswith("cache_")
             }
             for name, rec in led.items()
         }}
@@ -621,8 +852,11 @@ class RegisteredProgram:
     Dispatch is the plain jitted function — unchanged semantics.  The
     wrapped body calls a trace-time hook; a dispatch during which the
     hook fired is a compile, and the wrapper's clock around that
-    dispatch is the recorded compile wall time (trace + XLA compile;
-    execution is dispatched asynchronously).  Calls under an outer
+    dispatch is the recorded compile wall time (trace + lowering + XLA
+    compile or cache load; execution is dispatched asynchronously).
+    While it dispatches, or compiles ahead of time, the process's
+    compile listeners charge what compiles on this thread to its name
+    (`_compiling.owner`).  Calls under an outer
     trace (tracer arguments) inline without activating the hook slot,
     so nested tracing is not miscounted as a compile.
 
@@ -641,8 +875,10 @@ class RegisteredProgram:
     ):
         import jax
 
+        install_compile_listeners()
         self.name = name
         self._registry = registry
+        self._owner = (registry, name)
         self._budget = signature_budget
         self._tls = threading.local()
 
@@ -681,20 +917,29 @@ class RegisteredProgram:
         prev = getattr(tls, "cell", None)
         cell: List[int] = []
         tls.cell = cell
+        armed = _compiling.begin(self._owner)
         start = clock()
         try:
             out = self._jitted(*args)
         finally:
             tls.cell = prev
+            stages = _compiling.end(armed)
         if cell:
-            self._record(sig, max(clock() - start, 0.0), avals, cost=None)
+            self._record(sig, max(clock() - start, 0.0), avals, cost=None,
+                         stages=stages)
             self._registry.keep_compiled_for(self, args)
         return out
 
     def compiled_text(self, *args) -> str:
         """The optimized HLO text of the executable for `args` (arrays
-        or ShapeDtypeStructs), compiled outside the ledger."""
-        return self._jitted.lower(*args).compile().as_text()
+        or ShapeDtypeStructs).  No compile of the ledger's (no count, no
+        signature, no storm); its stages are this program's all the same:
+        a load, where the persistent cache is warm."""
+        armed = _compiling.begin(self._owner)
+        try:
+            return self._jitted.lower(*args).compile().as_text()
+        finally:
+            _compiling.end(armed)
 
     def aot_compile(self, *args):
         """Build (once per signature) the AOT executable — the prewarm
@@ -719,6 +964,7 @@ class RegisteredProgram:
             if sig in self._aot:
                 return self._aot[sig]
         clock = self._registry.clock
+        armed = _compiling.begin(self._owner)
         start = clock()
         try:
             compiled = self._jitted.lower(*args).compile()
@@ -726,17 +972,19 @@ class RegisteredProgram:
             # multi-process backends cannot AOT-compile; cost queries
             # degrade to {} rather than breaking the caller
             compiled = None
+        finally:
+            stages = _compiling.end(armed)
         seconds = max(clock() - start, 0.0)
         with self._lock:
             self._aot[sig] = compiled
         if compiled is not None:
             self._record(
                 sig, seconds, describe_avals(args),
-                cost=cost_analysis_dict(compiled),
+                cost=cost_analysis_dict(compiled), stages=stages,
             )
         return compiled
 
-    def _record(self, sig, seconds, avals, cost) -> None:
+    def _record(self, sig, seconds, avals, cost, stages=None) -> None:
         clock = self._registry.clock
         now = clock()
         with self._lock:
@@ -757,7 +1005,7 @@ class RegisteredProgram:
             churn = len(self._sig_times)
         self._registry.note_compile(
             self.name, signature_digest(sig), seconds,
-            cost=cost, avals=avals,
+            cost=cost, avals=avals, stages=stages,
         )
         if storm:
             self._registry.note_storm(self.name, churn, self._budget)

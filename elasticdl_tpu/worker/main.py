@@ -116,13 +116,23 @@ def main(argv=None):
 
 
 def _main(argv=None):
+    import time
+
+    entered = time.perf_counter()
     args = args_lib.parse_worker_args(argv)
+    # the process's start-up record (docs/OBSERVABILITY.md "Start-up
+    # catalogue"): `boot` up to here, `job_setup` from here
+    from elasticdl_tpu.common import profiler, programs
+
+    startup = profiler.process_phase_timer()
+    startup.begin_startup(entered)
     # A relaunched worker loads the train-step executable from the
     # persistent compile cache instead of recompiling — the biggest
     # single chunk of elastic recovery time.
     from elasticdl_tpu.common.virtual_mesh import enable_compile_cache
 
     enable_compile_cache(args.compilation_cache_dir)
+    programs.install_compile_listeners()
     worker_id = int(
         os.environ.get(WorkerEnv.WORKER_ID, args.worker_id)
     )
@@ -293,6 +303,9 @@ def _main(argv=None):
         # thread would race the training loop's state mutation).
         MaintenanceNoticeWatcher(checker, worker.drain_and_stop).start()
 
+    # `job_setup` ends here; the loop closes `worker_setup` at its first
+    # `get_task` (the SPMD loop after joining the distributed runtime)
+    startup.startup("worker_setup")
     ok = worker.run()
     logger.info("Worker %d exiting (clean=%s)", worker_id, ok)
 

@@ -298,12 +298,15 @@ class SPMDWorker:
                 ),
                 features,
             )
-        self.state = self.trainer.init_state_global(
-            jax.random.PRNGKey(self._seed), features
-        )
+        # start-up spans, as worker/sync.py: ModelOwner.ensure_state's
+        with _phase_timer.phase("init_state"):
+            self.state = self.trainer.init_state_global(
+                jax.random.PRNGKey(self._seed), features
+            )
         self._maybe_prewarm(batch, global_rows)
         if self._saver is not None:
-            restored = self._saver.maybe_restore(self.state)
+            with _phase_timer.phase("restore"):
+                restored = self._saver.maybe_restore(self.state)
             if restored is not None:
                 self.state = restored
                 logger.info(
@@ -408,6 +411,8 @@ class SPMDWorker:
     def run(self) -> bool:
         if self.trainer is None:
             self.setup()
+        # start-up ends where the loop's own spans begin
+        _phase_timer.startup(None)
         seq = 0
         while True:
             if self._preempted:

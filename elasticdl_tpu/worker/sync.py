@@ -23,6 +23,7 @@ from typing import Optional
 import jax
 import numpy as np
 
+from elasticdl_tpu.common import profiler
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.layers.step_metrics import STEP_METRICS
 
@@ -71,11 +72,18 @@ class ModelOwner:
                 )
             if self.state is not None:
                 return
-            self.state = self.trainer.init_state(
-                self._rng, batch["features"]
-            )
+            # start-up spans (profiler.STARTUP_PHASES): the state's
+            # shapes are the first batch's, so they lie in the first task
+            timer = profiler.process_phase_timer()
+            with timer.phase("init_state"):
+                self.state = self.trainer.init_state(
+                    self._rng, batch["features"]
+                )
             if self.checkpoint_saver is not None:
-                restored = self.checkpoint_saver.maybe_restore(self.state)
+                with timer.phase("restore"):
+                    restored = self.checkpoint_saver.maybe_restore(
+                        self.state
+                    )
                 if restored is not None:
                     self.state = restored
                     logger.info("Restored state from checkpoint")

@@ -267,6 +267,8 @@ class Worker:
     def run(self) -> bool:
         """Main loop until the master declares the job finished.  Returns
         True on clean completion."""
+        # start-up ends where the loop's own spans begin
+        _phase_timer.startup(None)
         while True:
             if getattr(self, "_stop_requested", False):
                 logger.info(

@@ -14,6 +14,7 @@ import time
 import pytest
 
 from elasticdl_tpu.common.profiler import (
+    COMPILE_PHASES,
     LOOP_PHASES,
     PRODUCER_PHASES,
     STEP_PHASES,
@@ -396,7 +397,12 @@ def test_the_same_regions_lie_on_the_traces_host_plane(job):
     }
     # one clock: each thread's events in the ring's order, each as long
     # as the ring says within 1 ms (the annotation encloses the stamps)
-    ring = sorted(job["spans"], key=lambda s: s.start)
+    # (a compile's stages are laid back from jax's events by `add()`:
+    # they were never open, so they have no annotation)
+    ring = sorted(
+        (s for s in job["spans"] if s.name not in COMPILE_PHASES),
+        key=lambda s: s.start,
+    )
     traced.sort(key=lambda e: e.start_ns)
     assert [e.name for e in traced] == ["edl:" + s.name for s in ring]
     offset = traced[0].start_ns * 1e-9 - ring[0].start
