@@ -131,6 +131,19 @@ class RMSNorm(nn.Module):
 # The kernel pair ties it (XLA copies the z slice out for them, twice a
 # step) and would need this form beside it where a group is no whole lane
 # tile (PERF.md section 6, PR 52).
+# The same a HEAD of 128 in Kimi's delta-rule layer (`model_zoo/kimi/
+# kimi_linear.py: HeadRMSNorm`, 32 groups), by PR 56's chip probe (v5e; the
+# KDA layer alone, (2, 8192) tokens of 2,304, 32 heads of 128, bfloat16,
+# forward + backward under the zoo's remat, three traced calls; ms a layer:
+# the whole layer | `kimi/kda/out` | `kimi/kda/gate` | copies and fusions
+# without a scope), and beside it the cell's traced step, ms a KDA layer
+# (`out` | `gate` | `core`'s rebuild: the layer alone rebuilds no `Wo` and
+# lays its decay out as the step does not):
+#   norm, gate and decay over the (.., 32, 128) view
+#                              88.16 | 17.60 | 3.42 | 0.00   22.14 | 6.39 | 9.35
+#   norm and gate where the channels lie
+#                              81.62 | 11.10 | 3.53 | 0.00   14.17 | 6.42 | 9.36
+#   and the decay              81.61 | 11.10 | 3.53 | 0.00   14.04 | 4.16 | 8.53
 def grouped_rms_norm(x, scale, eps: float, groups: int):
     """`rms_norm` with one statistic for each of `groups` equal runs of
     the last axis's channels; float32 out."""

@@ -61,6 +61,7 @@ from model_zoo.common.decoder import (  # noqa: F401
     dense,
     dt_bias_init,
     eval_metrics_fn,
+    grouped_rms_norm,
     loss,
     optimizer,
     param_sharding,
@@ -104,9 +105,34 @@ step_metrics.declare(
 )
 
 
+class HeadRMSNorm(nn.Module):
+    """RMSNorm a head over (..., heads x dim): one statistic for each of
+    `heads` runs of the channels, one learned scale of dim that the heads
+    share (the leaf `RMSNorm` over the (..., heads, dim) view owns), taken
+    where the channels lie (`decoder.grouped_rms_norm` says what the view
+    costs on the chip).  `decoder.GatedRMSNorm` is not it: that one takes
+    its gate in float32 before it rounds, this model rounds the norm and
+    gates in `dtype`, as its reference does."""
+
+    eps: float
+    dtype: jnp.dtype
+    heads: int
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param(
+            "scale", nn.initializers.ones, (x.shape[-1] // self.heads,)
+        )
+        return grouped_rms_norm(
+            x, jnp.tile(scale, self.heads), self.eps, self.heads
+        ).astype(self.dtype)
+
+
 class KDA(nn.Module):
     """Kimi Delta Attention: `heads` heads of `head_dim` key and value
-    columns, q, k and v through a `taps`-tap causal depthwise conv."""
+    columns, q, k and v through a `taps`-tap causal depthwise conv.  Every
+    whole array outside `kda(...)` stays (B, L, heads x dim), the channels
+    along the lanes; (B, L, heads, dim) is the kernels' operands only."""
 
     hidden: int
     heads: int
@@ -135,10 +161,11 @@ class KDA(nn.Module):
             f = dense(width, "f_b", self.dtype)(
                 dense(dim, "f_a", self.dtype)(x)
             )
-            g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
-                f.astype(jnp.float32).reshape(by_head)
-                + dt_bias.reshape(heads, dim)
-            )
+            # one A a head, spread over its channels: (B, L, heads, dim) is
+            # the kernels' operand, not the arithmetic's
+            g = (-jnp.repeat(jnp.exp(a_log), dim) * jax.nn.softplus(
+                f.astype(jnp.float32) + dt_bias
+            )).reshape(by_head)
             beta = jax.nn.sigmoid(
                 dense(heads, "b", self.dtype)(x).astype(jnp.float32)
             )
@@ -152,10 +179,13 @@ class KDA(nn.Module):
         with jax.named_scope("kimi/kda/out"):
             gate = dense(width, "g_b", self.dtype)(
                 dense(dim, "g_a", self.dtype)(x)
-            ).reshape(by_head)
-            out = RMSNorm(self.eps, self.dtype, name="o_norm")(out)
+            )
+            # the norm rounds to `dtype` BEFORE the gate, taken in `dtype`
+            out = HeadRMSNorm(self.eps, self.dtype, heads, name="o_norm")(
+                out.reshape(batch, length, width)
+            )
             return dense(self.hidden, "o", self.dtype, MIXER_OUT)(
-                (out * jax.nn.sigmoid(gate)).reshape(batch, length, width)
+                out * jax.nn.sigmoid(gate)
             )
 
 

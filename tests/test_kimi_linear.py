@@ -292,6 +292,191 @@ def test_bfloat16_inside_the_twins_rule(seeded):
     )["ok"]
 
 
+class ViewKDA(zoo.nn.Module):
+    """The KDA layer as it stood before the channels stayed along the
+    lanes, written out: the decay, the output norm (a plain `RMSNorm`) and
+    the output gate over the (B, L, heads, dim) VIEW.  The same leaves
+    under the same names as `zoo.KDA`."""
+
+    hidden: int
+    heads: int
+    head_dim: int
+    taps: int
+    eps: float
+    dtype: jnp.dtype = jnp.float32
+
+    @zoo.nn.compact
+    def __call__(self, x):
+        batch, length, _ = x.shape
+        heads, dim = self.heads, self.head_dim
+        width = heads * dim
+        by_head = (batch, length, heads, dim)
+        qkv = zoo.dense(3 * width, "qkv", self.dtype, zoo.MIXER_IN)(x)
+        weight = self.param("conv_kernel", zoo.tap_init, (self.taps, 3 * width))
+        q, k, v = (
+            t.reshape(by_head) for t in jnp.split(
+                zoo.silu_short_conv(qkv, weight), 3, axis=-1
+            )
+        )
+        a_log = self.param("A_log", zoo.a_log_init, (heads,))
+        dt_bias = self.param("dt_bias", zoo.dt_bias_init, (width,))
+        f = zoo.dense(width, "f_b", self.dtype)(
+            zoo.dense(dim, "f_a", self.dtype)(x)
+        )
+        g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+            f.astype(jnp.float32).reshape(by_head)
+            + dt_bias.reshape(heads, dim)
+        )
+        beta = jax.nn.sigmoid(
+            zoo.dense(heads, "b", self.dtype)(x).astype(jnp.float32)
+        )
+        out = zoo.kda(q, k, v, g, beta, qk_norm=(zoo.L2_EPS, dim ** -0.5))
+        gate = zoo.dense(width, "g_b", self.dtype)(
+            zoo.dense(dim, "g_a", self.dtype)(x)
+        ).reshape(by_head)
+        out = zoo.RMSNorm(self.eps, self.dtype, name="o_norm")(out)
+        return zoo.dense(self.hidden, "o", self.dtype, zoo.MIXER_OUT)(
+            (out * jax.nn.sigmoid(gate)).reshape(batch, length, width)
+        )
+
+
+def kda_layer_pair(heads, dim, dtype=jnp.float32, hidden=32, length=80):
+    """(the zoo's KDA layer, the view form, their one set of seeded
+    leaves with the norm's scale off its seed of ones, x); 80 positions,
+    a whole chunk and a padded one: the scan goes its `jnp` form."""
+    sizes = (hidden, heads, dim, 4, 1e-5, dtype)
+    x = jax.random.normal(jax.random.PRNGKey(1), (1, length, hidden))
+    params = zoo.KDA(*sizes[:5]).init(jax.random.PRNGKey(0), x)["params"]
+    params = dict(params, o_norm={"scale": 1.0 + 0.3 * jax.random.normal(
+        jax.random.PRNGKey(2), (dim,)
+    )})
+    return zoo.KDA(*sizes), ViewKDA(*sizes), params, x
+
+
+def kda_layer_readings(layer, params, x, monkeypatch):
+    """{name: array}: the layer's output and the gradients of a weighted
+    sum of it to every leaf, to x (through the four low-rank gate products
+    and the projections) and to the scan's output (a zero array added to
+    what `kda` returns)."""
+    weight = jax.random.normal(jax.random.PRNGKey(3), x.shape[:2] + (
+        layer.hidden,
+    ))
+
+    def run(patch, params, x, tap):
+        patch.setattr(
+            zoo, "kda", lambda *a, **kw: kda_ops.kda(*a, **kw) + tap.reshape(
+                a[2].shape
+            ).astype(a[2].dtype)
+        )
+        out, _ = layer.apply({"params": params}, x, mutable=[STEP_METRICS])
+        out = out.astype(jnp.float32)
+        return (out * weight).sum(), out
+
+    tap = jnp.zeros(x.shape[:2] + (layer.heads * layer.head_dim,))
+    with monkeypatch.context() as patch, jax.default_matmul_precision(
+        "highest"
+    ):
+        (_, out), (d_params, d_x, d_tap) = jax.value_and_grad(
+            functools.partial(run, patch), argnums=(0, 1, 2), has_aux=True
+        )(params, x, tap)
+    return {
+        "out": np.asarray(out), "d_x": np.asarray(d_x),
+        "d_scan_out": np.asarray(d_tap),
+        **{f"d_{k}": np.asarray(v, np.float32)
+           for k, v in trees.flat(d_params).items()},
+    }
+
+
+def rel_l2(got, want):
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+# the cell's heads and the tiny configuration's
+KDA_HEADS = [pytest.param(32, 128, id="32x128"), pytest.param(2, 16, id="2x16")]
+
+
+@pytest.mark.parametrize("heads, dim", KDA_HEADS)
+def test_the_layer_along_the_lanes_is_the_view_form_in_float32(
+    monkeypatch, heads, dim
+):
+    """Norm a head, output gate and decay taken over (B, L, heads x dim)
+    against the view form written out above: the output, and the
+    gradients to the scan's output, to x, to `o_norm/scale`, `A_log`,
+    `dt_bias` and every other leaf, to 1e-6 of each one's norm (the sums
+    add in another order, no more); 5e-6 for what the scan's backward
+    carries back, whose sums cancel: the last bit of the cotangent it is
+    handed reads 1e-6 to 2e-6 in the decay's leaves."""
+    lanes, view, params, x = kda_layer_pair(heads, dim)
+    got = kda_layer_readings(lanes, params, x, monkeypatch)
+    want = kda_layer_readings(view, params, x, monkeypatch)
+    assert set(got) == set(want) and {
+        "out", "d_x", "d_scan_out", "d_o_norm/scale", "d_A_log", "d_dt_bias",
+        "d_g_a/kernel", "d_g_b/kernel", "d_f_a/kernel", "d_f_b/kernel",
+    } <= set(got)
+    after_the_scan = {
+        "out", "d_scan_out", "d_o_norm/scale", "d_g_a/kernel", "d_g_b/kernel",
+        "d_o/kernel",
+    }
+    for name in want:
+        assert np.linalg.norm(want[name]) > 0, name
+        assert rel_l2(got[name], want[name]) < (
+            1e-6 if name in after_the_scan else 5e-6
+        ), name
+
+
+@pytest.mark.parametrize("heads, dim", KDA_HEADS)
+def test_the_layer_along_the_lanes_in_bfloat16_inside_the_twins_rule(
+    monkeypatch, heads, dim
+):
+    """In bfloat16 the view form is the twin: the layer's distance from
+    the float32 view form is at most `TWIN_RATIO` times the bfloat16 view
+    form's own, reading by reading; and the rounding points are the
+    parent's (the norm rounds BEFORE the gate, the gate is taken in
+    bfloat16): the outputs of the two differ in few places."""
+    _, view32, params, x = kda_layer_pair(heads, dim)
+    lanes, view, _, _ = kda_layer_pair(heads, dim, jnp.bfloat16)
+    want = kda_layer_readings(view32, params, x, monkeypatch)
+    twin = kda_layer_readings(view, params, x, monkeypatch)
+    got = kda_layer_readings(lanes, params, x, monkeypatch)
+    for name in want:
+        assert rel_l2(got[name], want[name]) <= (
+            reference.TWIN_RATIO * rel_l2(twin[name], want[name])
+        ), name
+    # a float32 gate or a later cast would move most elements' last bit
+    assert (got["out"] != twin["out"]).mean() < 0.1
+
+
+def test_the_kda_layer_owns_the_leaves_it_always_did():
+    """Paths and shapes of the layer's parameter tree, and a checkpoint
+    of the view form's tree restored into this one leaf for leaf:
+    `o_norm/scale` is ONE scale of a head's width, `dt_bias` one number a
+    channel, `A_log` one a head."""
+    from flax import serialization
+
+    lanes, view, _, x = kda_layer_pair(2, 16)
+    made = lanes.init(jax.random.PRNGKey(0), x)["params"]
+    old = view.init(jax.random.PRNGKey(0), x)["params"]
+    assert {k: v.shape for k, v in trees.flat(made).items()} == {
+        "A_log": (2,), "dt_bias": (32,), "conv_kernel": (4, 96),
+        "qkv/kernel": (32, 96), "f_a/kernel": (32, 16),
+        "f_b/kernel": (16, 32), "b/kernel": (32, 2), "g_a/kernel": (32, 16),
+        "g_b/kernel": (16, 32), "o_norm/scale": (16,), "o/kernel": (32, 32),
+    }
+    assert len(trees.flat(made)) == KDA_LEAVES
+    assert jax.tree.structure(made) == jax.tree.structure(old)
+    empty = jax.tree.map(jnp.zeros_like, made)
+    restored = serialization.from_bytes(empty, serialization.to_bytes(old))
+    for name, leaf in trees.flat(restored).items():
+        np.testing.assert_array_equal(
+            np.asarray(leaf), np.asarray(trees.flat(old)[name]), name
+        )
+    # and the seeds are the parent's: the same key makes the same leaves
+    for name, leaf in trees.flat(made).items():
+        np.testing.assert_array_equal(
+            np.asarray(leaf), np.asarray(trees.flat(old)[name]), name
+        )
+
+
 def test_published_sizes_hold_what_the_configuration_states():
     """The parameters of the cut model at the published widths, counted
     from the built model's shapes: the numbers in the configuration's

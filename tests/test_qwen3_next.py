@@ -891,9 +891,11 @@ def test_the_layers_scopes_reach_the_lowered_operations():
 PARENTS_JAXPRS = {
     "kimi-linear-48b-a3b": (
         "kimi.kimi_linear", (2, 8192),
-        # re-recorded on purpose in PR 54: the program holds the KDA
-        # kernels' bodies, whose substitution changed (`ops/kda.py`)
-        "072668fe5ddb9151c6d0f61e044857fad12368a7a5c6ed3db473939e77ab9bad",
+        # re-recorded on purpose in PR 54 (the program holds the KDA
+        # kernels' bodies, whose substitution changed: `ops/kda.py`) and
+        # in PR 56 (the KDA layer's norm a head, output gate and decay
+        # over (B, L, heads x dim): `model_zoo/kimi/kimi_linear.py`)
+        "f75e00cd4eb96059c3496d4e509089dd659ecd545698d307d58e0fcdcb1fab19",
     ),
     "nemotron-3-nano-30b-a3b": (
         "nemotron.nemotron_h", (2, 8192),

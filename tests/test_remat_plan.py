@@ -353,7 +353,9 @@ def test_the_lean_estimate_errs_high_in_every_cell(config_name, traffic_name,
 ZOOS = ["granite_hybrid", "laguna", "lfm2", "kimi_linear"]
 # `remat_cases.grad_program_digest` of each model file's test model
 # (bfloat16, remat) at the commit BEFORE the blocks' products had names
-# (198d98a): with no room the step lowers to that commit's program
+# (198d98a): with no room the step lowers to that commit's program.
+# Kimi's is PR 56's, whose KDA layer takes its norm a head, its output gate
+# and its decay over (B, L, heads x dim): the lean program of that layer
 PARENT_DIGESTS = {
     "granite_hybrid":
         "9780f560a54164b1007f1767af322f0b3560e5ebb1922af1c0650514f78c7ddd",
@@ -362,7 +364,7 @@ PARENT_DIGESTS = {
     "lfm2":
         "afb2441f7e217f4ebda6a7c05f5a144ed909c1e925120ec41172c07facf5f524",
     "kimi_linear":
-        "fc5cb384c78aeeb9afef7a11bdb17823ad65003b0fe46817f908b26212f437ad",
+        "7131b7f13bdc1e33cc612e2c47f1a45cd74b145dbb9313df16ff2238920bd822",
 }
 
 

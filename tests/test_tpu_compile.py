@@ -398,46 +398,31 @@ def test_nemotron_cell_kernels_compile_for_the_chip(one_chip, monkeypatch):
     assert all(int(t) >= moe.TILE for tiling in tilings for t in tiling)
 
 
-def test_qwen3_next_cell_step_compiles_for_the_chip(one_chip, monkeypatch):
-    """The Qwen3-Next cell's whole train step (2 sequences of 8,192, four
-    layers at the published widths, 32 of 512 experts held, Adam, the lean
-    remat policy: no room given) compiled for the described v5e, abstract:
-    the scalar-decay scan's kernels at 32 value heads over 16 key heads
-    (q and k enter at 2,048 columns: no repeated copy), the SiLU conv over
-    8,192 columns, attention at 16 / 2 heads of 256, the routed walk at
-    163,840 slots; and what the configuration's `fifth_layer` states: the
-    step's arguments and temporaries under 0.9 of the chip's bytes."""
-    import json
-    import os
-
+def _cell_train_step(one_chip, zoo, config):
+    """A cell's whole train step (2 sequences of 8,192, the
+    configuration's model and Adam, the lean remat policy: no room given)
+    compiled for the described v5e, abstract."""
     import optax
 
     from elasticdl_tpu.common.model_handler import _call_with_params
+    from elasticdl_tpu.layers.moe import ROUTER_STATE
     from elasticdl_tpu.layers.step_metrics import AUX_LOSS, STEP_METRICS
-    from elasticdl_tpu.ops import gdn, short_conv
-    from model_zoo.qwen3_next import qwen3_next as zoo
 
-    for module in (fa, gdn, short_conv):
-        monkeypatch.setattr(module, "use_interpret", lambda: False)
-    with open(os.path.join(
-        os.path.dirname(__file__), "..", "benchmarks", "configs",
-        "qwen3-next-80b-a3b.json",
-    )) as f:
-        config = json.load(f)
     model = _call_with_params(
         zoo.custom_model, config["model_params"].format(**config)
     )
     optimizer = zoo.optimizer(config["learning_rate"])
     ids = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
-    params = jax.eval_shape(
+    state = dict(jax.eval_shape(
         model.init, jax.random.PRNGKey(0), {"input_ids": ids}
-    )["params"]
+    ))
+    params = state.pop("params")
 
-    def step(params, opt_state, ids):
+    def step(params, state, opt_state, ids):
         def loss_of(params):
             out, _ = model.apply(
-                {"params": params}, {"input_ids": ids},
-                mutable=[AUX_LOSS, STEP_METRICS],
+                {"params": params, **state}, {"input_ids": ids},
+                mutable=[AUX_LOSS, STEP_METRICS, ROUTER_STATE],
             )
             return zoo.loss(None, out.astype(jnp.float32))
 
@@ -450,10 +435,40 @@ def test_qwen3_next_cell_step_compiles_for_the_chip(one_chip, monkeypatch):
             x.shape, x.dtype, sharding=one_chip
         ), tree)
 
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
-        placed(params), placed(jax.eval_shape(optimizer.init, params)),
-        placed(ids),
+    return jax.jit(step, donate_argnums=(0, 2)).lower(
+        placed(params), placed(state),
+        placed(jax.eval_shape(optimizer.init, params)), placed(ids),
     ).compile()
+
+
+def _cell_config(name):
+    import json
+    import os
+
+    with open(os.path.join(
+        os.path.dirname(__file__), "..", "benchmarks", "configs",
+        f"{name}.json",
+    )) as f:
+        return json.load(f)
+
+
+def test_qwen3_next_cell_step_compiles_for_the_chip(one_chip, monkeypatch):
+    """The Qwen3-Next cell's whole train step (2 sequences of 8,192, four
+    layers at the published widths, 32 of 512 experts held, Adam, the lean
+    remat policy: no room given) compiled for the described v5e, abstract:
+    the scalar-decay scan's kernels at 32 value heads over 16 key heads
+    (q and k enter at 2,048 columns: no repeated copy), the SiLU conv over
+    8,192 columns, attention at 16 / 2 heads of 256, the routed walk at
+    163,840 slots; and what the configuration's `fifth_layer` states: the
+    step's arguments and temporaries under 0.9 of the chip's bytes."""
+    from elasticdl_tpu.ops import gdn, short_conv
+    from model_zoo.qwen3_next import qwen3_next as zoo
+
+    for module in (fa, gdn, short_conv):
+        monkeypatch.setattr(module, "use_interpret", lambda: False)
+    compiled = _cell_train_step(
+        one_chip, zoo, _cell_config("qwen3-next-80b-a3b")
+    )
     text = compiled.as_text()
     assert "gdn_chunk_fwd" in text and "gdn_chunk_bwd" in text
     assert not re.search(r"kda_\w*(fwd|bwd)", text)
@@ -537,6 +552,76 @@ def test_the_grouped_gated_norm_keeps_the_channels_along_the_lanes(
         assert not copies and not off_lanes, (copies, off_lanes)
     else:
         assert copies and off_lanes
+
+
+@pytest.mark.parametrize("form", ["lanes", "view"])
+def test_kimis_out_path_keeps_the_channels_along_the_lanes(one_chip, form):
+    """The Kimi cell's KDA out path, the scan's (2, 8192, 4096) output in
+    32 heads of 128: the norm a head rounded to bfloat16, times the
+    sigmoid of the gate, through `Wo` (4,096 x 2,304), value and gradient:
+    no whole-array copy or transpose and no whole array off the lanes.
+    The control is the form the layer had: `RMSNorm` and the gate over the
+    (2, 8192, 32, 128) view."""
+    from model_zoo.kimi import kimi_linear as zoo
+
+    by_head = (2, 8192, 32, 128)
+    if form == "lanes":
+        norm = zoo.HeadRMSNorm(1e-5, jnp.bfloat16, 32)
+
+        def gated(out, gate, scale):
+            return norm.apply({"params": {"scale": scale}}, out) * (
+                jax.nn.sigmoid(gate)
+            )
+    else:
+        norm = zoo.RMSNorm(1e-5, jnp.bfloat16)
+
+        def gated(out, gate, scale):
+            return (
+                norm.apply({"params": {"scale": scale}}, out.reshape(by_head))
+                * jax.nn.sigmoid(gate.reshape(by_head))
+            ).reshape(out.shape)
+
+    def loss(out, gate, scale, kernel):
+        return jnp.square(
+            jnp.dot(gated(out, gate, scale), kernel).astype(jnp.float32)
+        ).mean()
+
+    def shaped(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
+        shaped((2, 8192, 4096)), shaped((2, 8192, 4096)),
+        shaped((128,), jnp.float32), shaped((4096, 2304)),
+    ).compile().as_text()
+    copies, off_lanes = _whole_arrays_off_the_channels(text)
+    if form == "lanes":
+        assert not copies and not off_lanes, (copies, off_lanes)
+    else:
+        assert copies and off_lanes
+
+
+def test_kimi_step_keeps_its_kda_layers_along_the_lanes(
+    one_chip, monkeypatch
+):
+    """The Kimi cell's train step over TWO of its KDA layers (published
+    layers 1 and 2, routed; one layer alone picks other layouts), Adam and
+    the lean remat policy, compiled for the described v5e: no whole (2,
+    8192, 4096) array is copied, transposed or laid off the lanes.  The
+    view form held five such copies a KDA layer (four `f32[2048,8,32,128]`
+    feeding `kimi/kda/out/o_norm/reduce_sum`, one under `kimi/kda/gate`);
+    the decay alone over the view holds two."""
+    from elasticdl_tpu.ops import kda, short_conv
+    from model_zoo.kimi import kimi_linear as zoo
+
+    for module in (fa, kda, short_conv):
+        monkeypatch.setattr(module, "use_interpret", lambda: False)
+    text = _cell_train_step(one_chip, zoo, dict(
+        _cell_config("kimi-linear-48b-a3b"), layers_held=[1, 2]
+    )).as_text()
+    assert "kda_chunk_fwd" in text and "kda_chunk_bwd" in text
+    assert "kimi/kda/out/o_norm" in text
+    copies, off_lanes = _whole_arrays_off_the_channels(text)
+    assert not copies and not off_lanes, (copies, off_lanes)
 
 
 def test_the_scope_table_reads_a_text_compiled_for_the_chip(one_chip):
