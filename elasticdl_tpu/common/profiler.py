@@ -102,6 +102,11 @@ DEVICE_SCOPES = (
     "qwen3_next/gdn/conv", "qwen3_next/gdn/decay", "qwen3_next/gdn/core",
     "qwen3_next/gdn/out", "qwen3_next/attn", "qwen3_next/moe",
     "qwen3_next/head_ce",
+    # (`smallthinker/route`, the routing ahead of attention, is a path and
+    # no entry: its leaves are `router`'s and `dispatch`'s, and a rule
+    # that names it reads them by the path)
+    "smallthinker/embed", "smallthinker/norm", "smallthinker/attn_full",
+    "smallthinker/attn_window", "smallthinker/moe", "smallthinker/head_ce",
 )
 
 #: Kernels the TPU's compiler makes from ONE primitive and names after

@@ -449,6 +449,7 @@ class DistributedEmbedding(nn.Module):
                 features).
     hash_input: apply the multiplicative mixer (set False when ids are
                 already uniform, e.g. pre-hashed Criteo features).
+    init_stddev: the seeded table is normal(0, init_stddev).
     """
 
     input_dim: int
@@ -457,12 +458,13 @@ class DistributedEmbedding(nn.Module):
     pad_id: int = -1
     hash_input: bool = True
     param_dtype: jnp.dtype = jnp.float32
+    init_stddev: float = 0.05
 
     @nn.compact
     def __call__(self, ids, prehashed: bool = False):
         table = self.param(
             "embedding",
-            nn.initializers.normal(stddev=0.05),
+            nn.initializers.normal(stddev=self.init_stddev),
             (self.input_dim, self.output_dim),
             self.param_dtype,
         )

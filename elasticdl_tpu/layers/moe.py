@@ -33,9 +33,10 @@ static worst-case size (tokens x top_k rows), the group sizes travel as
 data, and the buffer is WALKED, `CHUNK` rows a trip, only as far as its
 last live row (`routed_walk`): the gather, the grouped products
 (`grouped_matmul`), the expert's activation (its form, `FORMS`, is an
-argument of the walk: gated SwiGLU over a fused gate-and-up stack, or a
-squared ReLU over an up stack alone), the slot weights and the scatter-add
-all cost what the rows routed here cost, rounded up to a chunk, forward
+argument of the walk: gated SwiGLU or ReGLU over a fused gate-and-up
+stack, or a squared ReLU over an up stack alone), the slot weights and the
+scatter-add all cost what the rows routed here cost, rounded up to a chunk,
+forward
 and backward.  What still follows the worst case is index arithmetic: the
 sort, one int32 / float32 entry a slot, and the backward's four zeroed
 buffers.  No token is dropped at any imbalance and nothing recompiles
@@ -237,13 +238,19 @@ def _relu2(up):
     return jnp.square(nn.relu(up))
 
 
+def _reglu(gate_up):
+    gate, up = jnp.split(gate_up, 2, axis=-1)
+    return nn.relu(gate) * up
+
+
 # An expert is (first stack, activation, second stack).  Its form names the
 # activation: -> (the first stack's leaf, that stack's width in `ffn_dim`s,
 # the activation from its output to the second stack's input).
-SWIGLU, RELU2 = "swiglu", "relu2"
+SWIGLU, RELU2, REGLU = "swiglu", "relu2", "reglu"
 FORMS = {
     SWIGLU: ("expert_w_gate_up", 2, _swiglu),   # (silu(x Wg) * (x Wu)) Wd
     RELU2: ("expert_w_up", 1, _relu2),          # (relu(x Wu))^2 Wd: no gate
+    REGLU: ("expert_w_gate_up", 2, _reglu),     # (relu(x Wg) * (x Wu)) Wd
 }
 
 # The router's scores over its float32 logits (n, num_experts), chosen
@@ -331,9 +338,10 @@ def _padded(tokens, w_first, w_down, form):
     """(tokens, the two stacks) with zeros up to whole `TILE`s along the
     hidden and the expert's width: the shapes the grouped products run at.
     Zero columns of the first stack give zero columns of its output, which
-    the activation keeps zero (relu(0)^2 = 0, silu(0) * 0 = 0) and which
-    meet zero rows of the second; zero columns of the tokens meet zero
-    rows of the first stack.  Each is the argument itself where its
+    the activation keeps zero (relu(0)^2 = 0, silu(0) * 0 = 0, relu(0) * 0
+    = 0) and which meet zero rows of the second; zero columns of the tokens
+    meet zero rows of the first stack.  Each is the argument itself where
+    its
     dimensions are whole already."""
     hidden, ffn = _whole(tokens.shape[1]), _whole(w_down.shape[1])
     return (
@@ -585,10 +593,18 @@ class RoutedExperts(nn.Module):
     """Top-k routed experts, this holder's part: (..., hidden) ->
     (..., hidden) in float32.
 
-        s = sigmoid(x Wr) | softmax(x Wr)     float32, all `num_experts`
+        s = sigmoid(r Wr) | softmax(r Wr)     float32, all `num_experts`
         S = top_k(s + b)                      b selects, never weighs
         w_i = routed_scaling * s_i / (sum_{j in S} s_j + renorm_eps)
         out = sum_{i in S, i held here} w_i Expert_i(x)
+
+    r is x, or `route_from` where the call gives one: a tensor of x's shape
+    the router reads IN x'S PLACE (a block's input, where the model routes
+    before its attention), while the experts still read x.  Everything the
+    routing does then (the router's product, the scores, top-k, the sort
+    and the group sizes) hangs on `route_from` alone and lies under the
+    named scope `route_scope` besides `router` and `dispatch`: it waits on
+    nothing the block computes between the two tensors.
 
     num_experts:      the router's width (every expert of the layer)
     held_experts:     (first, count) of the experts whose weights live
@@ -601,11 +617,14 @@ class RoutedExperts(nn.Module):
     renorm_eps:       added to the renormalisation's denominator (1e-6 in
                       `lfm2_moe`); 0.0 adds nothing to the program
     form:             an expert's activation (`FORMS`): `swiglu`, (silu(x
-                      Wg) * (x Wu)) Wd, or `relu2`, (relu(x Wu))^2 Wd
+                      Wg) * (x Wu)) Wd, `reglu`, (relu(x Wg) * (x Wu)) Wd,
+                      or `relu2`, (relu(x Wu))^2 Wd
     scores:           the router's scores (`SCORES`): `sigmoid`, each
                       expert's own, or `softmax` over all `num_experts`;
                       softmax scores are picked as they are: no selection
                       bias b and no ROUTER_STATE buffer
+    route_scope:      the named scope of the routing where the call gives
+                      a `route_from`; not entered without one
 
     Expert stacks are the form's first stack (`expert_w_gate_up`, gate
     and up fused, or `expert_w_up`) and `expert_w_down`, no biases;
@@ -629,9 +648,10 @@ class RoutedExperts(nn.Module):
     renorm_eps: float = 0.0
     form: str = SWIGLU
     scores: str = SIGMOID
+    route_scope: str = "route"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, route_from=None):
         *lead, hidden = x.shape
         first_name, first_width, _ = FORMS[self.form]
         score_fn, biased = SCORES[self.scores], self.scores == SIGMOID
@@ -640,13 +660,20 @@ class RoutedExperts(nn.Module):
         n, k = tokens.shape[0], self.top_k
         first, count = self.held_experts or (0, self.num_experts)
 
-        with jax.named_scope("router"):
+        # the routing's two scopes lie under `route_scope` where the router
+        # has a source of its own
+        ahead = "" if route_from is None else self.route_scope + "/"
+
+        with jax.named_scope(ahead + "router"):
             w_router = self.param(
                 "router_kernel", nn.initializers.lecun_normal(),
                 (hidden, self.num_experts), jnp.float32,
             )
+            source = tokens if route_from is None else route_from.reshape(
+                -1, hidden
+            )
             scores = score_fn(jnp.dot(
-                tokens.astype(jnp.float32), w_router,
+                source.astype(jnp.float32), w_router,
                 precision=jax.lax.Precision.HIGHEST,
             ))
             selection = jax.lax.stop_gradient(scores)
@@ -670,7 +697,7 @@ class RoutedExperts(nn.Module):
                     loads.mean() - loads
                 )
 
-        with jax.named_scope("dispatch"):
+        with jax.named_scope(ahead + "dispatch"):
             local = idx - first
             held = (local >= 0) & (local < count)
             # slots of absent experts sort past every held group

@@ -594,8 +594,10 @@ _LANES = 128
 _HALF_HEAD = _LANES // 2
 # What the backward kernel may hold in VMEM (the v5e has 128 MiB; the
 # compiler's own default is 16), and the part of it left to the tiles'
-# blocks and a tile's temporaries: at the cells' shapes the kernel compiles
-# under 24 MiB with 16 MiB held for the whole length, and is refused 16.
+# blocks and a tile's temporaries: at the 8,192-position cells' shapes the
+# kernel compiles under 24 MiB with 16 MiB held for the whole length, and
+# is refused 16; at 16,384 positions of 128 (32 MiB held in bfloat16, 48 in
+# float32) it compiles under this limit and runs on the chip.
 _STREAM_VMEM_LIMIT = 64 * 1024 * 1024
 _STREAM_TILE_ROOM = 16 * 1024 * 1024
 
@@ -633,7 +635,12 @@ def stream_shapes_ok(q_shape, k_shape, v_shape) -> bool:
     both widths half a lane tile (64, which goes head-major), and L x
     (D + Dv) no more than the backward's whole-length scratch may hold
     under `_STREAM_VMEM_LIMIT` (16,384 positions at heads of 128, 8,192 at
-    256, or at 192 padded to 256 over 128).  A window asks nothing more."""
+    256, or at 192 padded to 256 over 128).  A window asks nothing more.
+    16,384 at heads of 128 is the limit less the tiles' room EXACTLY (48
+    MiB as `stream_backward_vmem_bytes` reckons it, at float32); Mosaic
+    compiles it and the v5e runs it, at 28 query heads over 4 K/V heads
+    in bfloat16 (16 MiB of scratch and 16 of output blocks), with a band
+    of 4,096 and without (PR 57's cell; `PERF.md` section 6)."""
     dim, v_dim = q_shape[3], v_shape[3]
     return (
         _grouped_shapes_ok(q_shape, k_shape, v_shape)

@@ -1,15 +1,17 @@
-"""What the zoo's seven decoder language models have in common
+"""What the zoo's eight decoder language models have in common
 (`model_zoo/glm/glm_moe_lite.py`, `laguna/laguna.py`, `lfm2/lfm2_moe.py`,
 `kimi/kimi_linear.py`, `granite/granite_hybrid.py`,
-`nemotron/nemotron_h.py`, `qwen3_next/qwen3_next.py`): RMSNorm (its scale
+`nemotron/nemotron_h.py`, `qwen3_next/qwen3_next.py`,
+`smallthinker/smallthinker.py`): RMSNorm (its scale
 plain or zero-centred) and its gated form (one statistic a group of
 channels, the gate before the norm or after it), rotary's turn whole or
 over a head's first columns (`Rope`, `partial_rotary`), the seeds of a
 decay (`a_log_init`, `dt_bias_init`), the bias-free dense layer, SwiGLU
 and the non-gated squared-ReLU MLP, grouped-query attention
-(`GroupedAttention`: no positions, no norms and no gate unless asked
-for), the routed block around `layers/moe.py: RoutedExperts` with its
-shared expert (gated where asked for), the cross-entropy
+(`GroupedAttention`: no positions, no band, no norms and no gate unless
+asked for), the routed block around `layers/moe.py: RoutedExperts` with
+its shared expert (gated where asked for) and its routing's source (the
+block's input where asked for), the cross-entropy
 taken in blocks of tokens, the per-position losses against the ids
 shifted, the blocks' rematerialisation (`remat_block`, and what more of
 a block it keeps where the device has room: `remat_blocks`), and the zoo
@@ -321,11 +323,13 @@ MLP_OF_FORM = {SWIGLU: SwiGLU, RELU2: ReLU2MLP}
 
 class GroupedAttention(nn.Module):
     """`heads` query heads over `kv_heads` key/value heads, causal, the
-    logits times `scale`.  As it stands: no positions, no norms, no gate.
-    `qk_norm_eps` norms q and k a head (a zero-centred scale each, `q_norm`
-    and `k_norm`) before `rope` turns a head's first columns; with
-    `query_gate` the q projection is twice as wide, a head's columns split
-    q | gate, and the output is times sigmoid(gate), element by element."""
+    logits times `scale`.  As it stands: no positions, no band, no norms,
+    no gate.  `qk_norm_eps` norms q and k a head (a zero-centred scale
+    each, `q_norm` and `k_norm`) before `rope` turns a head's first
+    columns; with `window`, query t sees the keys s with t - window < s <=
+    t; with `query_gate` the q projection is twice as wide, a head's
+    columns split q | gate, and the output is times sigmoid(gate), element
+    by element."""
 
     hidden: int
     heads: int
@@ -337,6 +341,7 @@ class GroupedAttention(nn.Module):
     qk_norm_eps: Optional[float] = None
     rope: Optional[Rope] = None
     query_gate: bool = False
+    window: Optional[int] = None
 
     @nn.compact
     def __call__(self, x):
@@ -364,7 +369,7 @@ class GroupedAttention(nn.Module):
                     k, self.rope
                 )
             out = flash_attention.causal_attention(
-                q, k, v, scale=self.scale
+                q, k, v, scale=self.scale, window=self.window
             )
             if self.query_gate:
                 gate = jax.nn.sigmoid(gate.astype(jnp.float32))
@@ -382,7 +387,9 @@ class MoEFFN(nn.Module):
     wide, or `shared_width` where the model gives it a width of its own;
     `form` is every expert's, routed and shared alike, `scores` the
     router's (`layers/moe.py: SCORES`); with `shared_gate` the shared
-    expert's output is times one sigmoid a token of the layer's input."""
+    expert's output is times one sigmoid a token of the layer's input.
+    A call that gives `route_from` has the router read that tensor in x's
+    place, under the named scope `route_scope` (`RoutedExperts`)."""
 
     hidden: int
     num_experts: int
@@ -399,9 +406,10 @@ class MoEFFN(nn.Module):
     shared_width: Optional[int] = None
     scores: str = SIGMOID
     shared_gate: bool = False
+    route_scope: str = "route"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, route_from=None):
         with jax.named_scope(self.trace_scope):
             routed = RoutedExperts(
                 num_experts=self.num_experts, top_k=self.top_k,
@@ -409,8 +417,9 @@ class MoEFFN(nn.Module):
                 routed_scaling=self.routed_scaling,
                 bias_update_rate=self.bias_update_rate, dtype=self.dtype,
                 renorm_eps=self.renorm_eps, form=self.form,
-                scores=self.scores, name="routed",
-            )(x)
+                scores=self.scores, route_scope=self.route_scope,
+                name="routed",
+            )(x, route_from)
             if not self.shared_experts:
                 with jax.named_scope("combine"):
                     return routed.astype(self.dtype)

@@ -326,6 +326,78 @@ def test_kernel_names_in_the_trace():
     ]
 
 
+# a group of SEVEN query heads a K/V head (28 over 4: no cell before had an
+# odd group), three tiles of 128: the causal mask alone, a band that ends
+# inside a tile and one of whole tiles
+@pytest.mark.parametrize("window", [None, 200, 256])
+def test_a_group_of_seven_against_the_blocked_form(window):
+    """The streaming kernels against `blocked_causal_attention` (the same
+    mathematics in plain lax, a row of query tiles at a time), forward
+    and all three gradients: a K/V head's dK and dV gather over seven
+    query heads in the backward's scratch, which is cleared between the
+    four K/V heads."""
+    from elasticdl_tpu.ops.flash_attention import (
+        blocked_causal_attention,
+        causal_attention,
+        stream_shapes_ok,
+    )
+
+    q, k, v = _grouped_qkv(7, kv_heads=4, seed=11)
+    assert q.shape == (1, 384, 28, 128) and k.shape == (1, 384, 4, 128)
+    assert stream_shapes_ok(q.shape, k.shape, v.shape)
+    names = _kernel_names(q, k, v, window=window)
+    kind = "causal" if window is None else "window"
+    assert names == [f"{kind}_attention_dkv", f"{kind}_attention_fwd"]
+
+    def through(fn):
+        return jax.value_and_grad(
+            lambda q, k, v: (fn(q, k, v, window=window) ** 2).sum(),
+            argnums=(0, 1, 2),
+        )(q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(
+            causal_attention(q, k, v, window=window),
+            blocked_causal_attention(q, k, v, window=window),
+            rtol=2e-4, atol=2e-4,
+        )
+        (_, got), (_, want) = through(causal_attention), through(
+            blocked_causal_attention
+        )
+    for name, a, b in zip("qkv", got, want):
+        np.testing.assert_allclose(
+            a, b, rtol=5e-4, atol=5e-4, err_msg=f"d{name}"
+        )
+
+
+@pytest.mark.parametrize("window", [None, 4096])
+def test_a_whole_16k_context_takes_the_streaming_kernels(window):
+    """One sequence of 16,384 positions at 28 query heads over 4 K/V heads
+    of 128, the longest the rule admits at that width (its backward's
+    whole-length scratch is the limit less the tiles' room EXACTLY):
+    `causal_attention` goes to the streaming kernels with the band and
+    without, never silently the blocked form, whose operands are O(L^2);
+    a band of 4,096 is nine key tiles of 512 a query tile."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    shapes = [(1, 16384, heads, 128) for heads in (28, 4, 4)]
+    assert fa.stream_shapes_ok(*shapes)
+    assert fa.stream_backward_vmem_bytes(16384, 128) == (
+        fa._STREAM_VMEM_LIMIT - fa._STREAM_TILE_ROOM
+    )
+    assert not fa.stream_shapes_ok(
+        *[(1, 16384 + 512, heads, 128) for heads in (28, 4, 4)]
+    )
+    assert fa._stream_tiles(16384) == 512
+    assert fa._band_steps(32, 512, 4096) == 9
+    assert fa._band_steps(32, 512, None) == 32
+    q, k, v = (jax.ShapeDtypeStruct(s, jnp.bfloat16) for s in shapes)
+    kind = "causal" if window is None else "window"
+    assert _kernel_names(q, k, v, window=window) == [
+        f"{kind}_attention_dkv", f"{kind}_attention_fwd",
+    ]
+
+
 def test_grouped_admission_rule():
     from elasticdl_tpu.ops.flash_attention import (
         causal_attention,

@@ -208,7 +208,7 @@ def _rotated(monkeypatch):
     plain = decoder.flash_attention.causal_attention
     monkeypatch.setattr(
         decoder.flash_attention, "causal_attention",
-        lambda q, k, v, scale: plain(
+        lambda q, k, v, scale, window=None: plain(
             rotary(q, 1e4), rotary(k, 1e4), v, scale=scale
         ),
     )

@@ -231,3 +231,88 @@ def test_a_routed_layer_sets_what_its_padding_costs(
     assert "padded_work_ratio" not in kept[STEP_METRICS]["routed"]
     got = moe.padded_work_ratio.value(layer="routed")
     assert (got > 0) == (padded > 0)
+
+
+# sha256 of str(make_jaxpr(grad(sum of the layer's output))) of one routed
+# layer at the GLM cell's bfloat16 shape (4 x 4,096 tokens of 2,048, top-4
+# of 64 with experts 8-15 held, 1,536 wide), sigmoid scores with the
+# balancing buffer and softmax scores, recorded at the commit before the
+# layer learnt the routing's source (87e4d23).
+PARENTS_LAYER = {
+    moe.SIGMOID:
+        "a7e9590801cd9b73221b4136a09e86312bd31d8c4a3727bdd76c6858ef2031a3",
+    moe.SOFTMAX:
+        "165492cba016256cd63f9807f6c036437c9b15b48da4ec76b9f0e839e64454af",
+}
+
+
+@pytest.mark.parametrize("scores", sorted(PARENTS_LAYER))
+def test_a_layer_given_no_routing_source_is_the_parents(scores):
+    """`RoutedExperts.__call__(x)` with no `route_from` traces to what it
+    traced to before the argument was there."""
+    import hashlib
+
+    layer = moe.RoutedExperts(
+        num_experts=64, top_k=4, ffn_dim=1536, held_experts=(8, 8),
+        routed_scaling=1.8, bias_update_rate=1e-3, dtype=jnp.bfloat16,
+        scores=scores,
+    )
+    x = jax.ShapeDtypeStruct((4, 4096, 2048), jnp.bfloat16)
+    variables = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+
+    def loss(v, x):
+        out, _ = layer.apply(v, x, mutable=[STEP_METRICS, moe.ROUTER_STATE])
+        return out.sum()
+
+    text = str(jax.make_jaxpr(
+        jax.grad(loss, argnums=(0, 1), allow_int=True)
+    )(variables, x))
+    assert "0x" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENTS_LAYER[scores]
+
+
+@pytest.mark.parametrize("form", sorted(moe.FORMS))
+def test_the_routing_source_absent_is_the_rows_themselves(form):
+    """With no `route_from` the router reads the experts' rows: the
+    layer's output and every gradient are bit-equal to the same layer
+    handed its rows as the source, and another source routes otherwise
+    while the experts' weights see the same rows."""
+    rng = np.random.RandomState(0)
+    x = jnp.asarray(rng.randn(2, 24, 32), jnp.float32)
+    other = jnp.asarray(rng.randn(2, 24, 32), jnp.float32)
+    layer = moe.RoutedExperts(
+        num_experts=16, top_k=3, ffn_dim=24, held_experts=(4, 8), form=form,
+        scores=moe.SOFTMAX,
+    )
+    variables = layer.init(jax.random.PRNGKey(1), x)
+
+    def through(source):
+        def loss(params, x):
+            out, sown = layer.apply(
+                {"params": params}, x, source(x), mutable=[STEP_METRICS]
+            )
+            return (out ** 2).sum(), (out, sown[STEP_METRICS])
+
+        (_, (out, sown)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True
+        )(variables["params"], x)
+        return out, sown, grads
+
+    out, sown, grads = through(lambda x: None)
+    same, same_sown, same_grads = through(lambda x: x)
+    np.testing.assert_array_equal(out, same)
+    for a, b in zip(jax.tree.leaves(grads[0]), jax.tree.leaves(same_grads[0])):
+        np.testing.assert_array_equal(a, b)
+    # (handed x twice, x's gradient is the sum of the two uses: the same
+    # number, summed in another order)
+    np.testing.assert_allclose(grads[1], same_grads[1], rtol=1e-5, atol=1e-6)
+    assert jax.tree.map(float, sown) == jax.tree.map(float, same_sown)
+    routed, routed_sown, routed_grads = through(lambda x: other)
+    assert np.abs(np.asarray(routed - out)).max() > 1e-3
+    # the rows' gradient no longer carries the router's
+    assert np.abs(np.asarray(routed_grads[1] - grads[1])).max() > 1e-4
+    assert float(routed_sown["routed_here_ratio"]) != float(
+        sown["routed_here_ratio"]
+    ) or float(routed_sown["expert_load_imbalance_ratio"]) != float(
+        sown["expert_load_imbalance_ratio"]
+    )

@@ -310,7 +310,7 @@ def _rotated(monkeypatch):
     plain = decoder.flash_attention.causal_attention
     monkeypatch.setattr(
         decoder.flash_attention, "causal_attention",
-        lambda q, k, v, scale: plain(
+        lambda q, k, v, scale, window=None: plain(
             decoder.rotary(q, 1e4), decoder.rotary(k, 1e4), v, scale=scale
         ),
     )
@@ -321,7 +321,7 @@ def _other_query_heads_a_kv_head(monkeypatch):
     // 2: another grouping of the same weights."""
     plain = decoder.flash_attention.causal_attention
 
-    def regrouped(q, k, v, scale):
+    def regrouped(q, k, v, scale, window=None):
         order = jnp.asarray([0, 2, 1, 3])
         return plain(q[:, :, order], k, v, scale=scale)[:, :, order]
 

@@ -882,7 +882,7 @@ def test_the_layers_scopes_reach_the_lowered_operations():
     assert "Scope object" not in text
 
 
-# sha256 of str(make_jaxpr(value_and_grad(loss))) of three sibling cells'
+# sha256 of str(make_jaxpr(value_and_grad(loss))) of the sibling cells'
 # models at their published sizes (bfloat16, remat; abstract: nothing
 # runs), the remat policy's address blanked, recorded at the commit before
 # `RoutedExperts` learnt softmax scores, `MoEFFN` the shared gate,
@@ -904,6 +904,26 @@ PARENTS_JAXPRS = {
     "glm-4.7-flash": (
         "glm.glm_moe_lite", (4, 4096),
         "7aa997f0621489a8b3c6f10b44ee4eb219be6ba5753671e23feb463bf198923a",
+    ),
+    # the four below recorded at the commit before `RoutedExperts` and
+    # `MoEFFN` learnt the routing's source, `FORMS` ReGLU and
+    # `GroupedAttention` its band (87e4d23), where the three above read
+    # what they read here
+    "laguna-xs.2": (
+        "laguna.laguna", (2, 8192),
+        "62c29f19d085a8632df6376c347f2b8045af67d8d83f6b5f6c7b5e3930671e3d",
+    ),
+    "lfm2-24b-a2b": (
+        "lfm2.lfm2_moe", (4, 8192),
+        "bd4a5d633f33cfdd2c9e314666a69a5d9f665dbb45bcd4b4533481a58293b2d4",
+    ),
+    "qwen3-next-80b-a3b": (
+        "qwen3_next.qwen3_next", (2, 8192),
+        "e24bc98a692d20739c6fb0bec254c394c64b733d8daf72c209298a7c6753a5d5",
+    ),
+    "granite-4.0-h-micro": (
+        "granite.granite_hybrid", (1, 8192),
+        "10e126326fa9f236e820a2453dde187d8eb5fd188a3cb0507944228da5ef79e5",
     ),
 }
 

@@ -1,6 +1,6 @@
 """`layers/moe.py: routed_walk` with the expert's FORM an argument: the
-gated `swiglu` over a fused gate-and-up stack and the non-gated `relu2`
-over an up stack alone, each against a per-expert dense sum (forward and
+gated `swiglu` and `reglu` over a fused gate-and-up stack and the non-gated
+`relu2` over an up stack alone, each against a per-expert dense sum (forward and
 every gradient) at loads that leave a dead tail, an empty expert, no row
 and every row; what the backward holds, by form; the leaves a layer of
 each form builds; the `swiglu` walk at a sibling cell's shape traced
@@ -31,6 +31,7 @@ LOADS = {
 ACTIVATIONS = {
     moe.SWIGLU: lambda h: jax.nn.silu(h[..., :FFN]) * h[..., FFN:],
     moe.RELU2: lambda h: jnp.square(jax.nn.relu(h)),
+    moe.REGLU: lambda h: jax.nn.relu(h[..., :FFN]) * h[..., FFN:],
 }
 
 
@@ -135,6 +136,17 @@ def test_the_forms_differ_and_the_default_is_swiglu():
     np.testing.assert_array_equal(
         moe.routed_walk(args[0], fused, *args[2:]), gated
     )
+    # rectified where `swiglu` is smooth; gated where `relu2` squares: with
+    # the gate's stack for the up stack too relu(h) h is relu(h)^2, and
+    # with its negative the negative
+    rectified = moe.routed_walk(args[0], fused, *args[2:], moe.REGLU)
+    assert np.abs(np.asarray(rectified - gated)).max() > 0.01
+    np.testing.assert_allclose(rectified, squared, rtol=1e-6, atol=1e-6)
+    mirrored = jnp.concatenate([args[1], -args[1]], axis=-1)
+    np.testing.assert_allclose(
+        moe.routed_walk(args[0], mirrored, *args[2:], moe.REGLU), -squared,
+        rtol=1e-6, atol=1e-6,
+    )
     with pytest.raises(KeyError):
         moe.routed_walk(*args, "gelu")
 
@@ -151,6 +163,15 @@ def test_walk_bytes_by_form():
     )
     assert moe.walk_bytes(tokens, hidden, top_k, ffn, 2, moe.RELU2) == (
         (total + 2) * chunk * (2 * hidden + 2 * ffn) * 2 + sums
+    )
+    # `reglu` is gated: `swiglu`'s widths
+    assert moe.walk_bytes(tokens, hidden, top_k, ffn, 2, moe.REGLU) == (
+        moe.walk_bytes(tokens, hidden, top_k, ffn, 2)
+    )
+    # 16,384 tokens of 2,560, top-6, experts 768 wide (whole tiles): the
+    # walk of one sequence at a 16k context
+    assert moe.walk_bytes(16384, 2560, 6, 768, 2, moe.REGLU) == (
+        8 * 16384 * 7424 * 2 + 3 * 16384 * 2560 * 4
     )
     # the GLM cell's layer, as before the form entered
     assert moe.walk_bytes(16384, 2048, 4, 1536, 2) == (
@@ -179,6 +200,7 @@ def test_walk_bytes_at_the_padded_widths(tile, hidden, ffn, monkeypatch):
 @pytest.mark.parametrize("form, first", [
     (moe.SWIGLU, ("expert_w_gate_up", (4, HIDDEN, 2 * FFN))),
     (moe.RELU2, ("expert_w_up", (4, HIDDEN, FFN))),
+    (moe.REGLU, ("expert_w_gate_up", (4, HIDDEN, 2 * FFN))),
 ])
 def test_a_layer_builds_its_forms_stacks(form, first):
     layer = moe.RoutedExperts(

@@ -47,6 +47,13 @@ SHAPES = [
     pytest.param(48, 8, 128, 2, 8192, None, id="laguna-full"),
     pytest.param(64, 8, 128, 2, 8192, 512, id="laguna-window"),
 ]
+# the SmallThinker cell's calls: ONE sequence of 16,384, the last length the
+# rule admits at heads of 128, a group of SEVEN query heads a K/V head, no
+# band and a band of nine key tiles
+SHAPES_16K = [
+    pytest.param(28, 4, 128, 1, 16384, None, id="smallthinker-full"),
+    pytest.param(28, 4, 128, 1, 16384, 4096, id="smallthinker-window"),
+]
 # the LFM2 cell's call (a head of half a lane tile, head-major)
 LFM2_SHAPE = (32, 8, 64, 4, 8192, None)
 
@@ -81,7 +88,9 @@ def _assert_two_kernels(text, kind):
     assert text.count("tpu_custom_call") >= 2
 
 
-@pytest.mark.parametrize("heads, kv_heads, dim, batch, length, window", SHAPES)
+@pytest.mark.parametrize(
+    "heads, kv_heads, dim, batch, length, window", SHAPES + SHAPES_16K
+)
 def test_streaming_kernels_compile_for_the_chip(
     one_chip, monkeypatch, heads, kv_heads, dim, batch, length, window
 ):
@@ -94,6 +103,13 @@ def test_streaming_kernels_compile_for_the_chip(
     _assert_two_kernels(text, "causal" if window is None else "window")
     # K/V stay at their own head count: nothing repeated to the query's
     assert f"bf16[{batch},{length},{kv_heads * dim}]" in text
+    # (at 16,384 the backward holds 16 MiB of float32 dK and dV scratch
+    # and, in bfloat16, 16 MiB of double-buffered output blocks, 32 of the
+    # 48 MiB `stream_backward_vmem_bytes` reckons at float32: the compile
+    # above ran under `_STREAM_VMEM_LIMIT`)
+    assert fa.stream_backward_vmem_bytes(length, dim) <= (
+        fa._STREAM_VMEM_LIMIT - fa._STREAM_TILE_ROOM
+    )
 
 
 def test_half_lane_head_kernels_compile_for_the_chip(one_chip, monkeypatch):
@@ -398,8 +414,8 @@ def test_nemotron_cell_kernels_compile_for_the_chip(one_chip, monkeypatch):
     assert all(int(t) >= moe.TILE for tiling in tilings for t in tiling)
 
 
-def _cell_train_step(one_chip, zoo, config):
-    """A cell's whole train step (2 sequences of 8,192, the
+def _cell_train_step(one_chip, zoo, config, batch=(2, 8192)):
+    """A cell's whole train step (`batch` sequences by positions, the
     configuration's model and Adam, the lean remat policy: no room given)
     compiled for the described v5e, abstract."""
     import optax
@@ -412,7 +428,7 @@ def _cell_train_step(one_chip, zoo, config):
         zoo.custom_model, config["model_params"].format(**config)
     )
     optimizer = zoo.optimizer(config["learning_rate"])
-    ids = jax.ShapeDtypeStruct((2, 8192), jnp.int32)
+    ids = jax.ShapeDtypeStruct(batch, jnp.int32)
     state = dict(jax.eval_shape(
         model.init, jax.random.PRNGKey(0), {"input_ids": ids}
     ))
@@ -488,6 +504,41 @@ def test_qwen3_next_cell_step_compiles_for_the_chip(one_chip, monkeypatch):
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     # `fifth_layer`: 7.508e9 + 5.548e9 = 13.056e9 of at most 15.2e9
     assert 12.5e9 < held < 0.9 * 16_909_336_064, held
+
+
+def test_smallthinker_cell_step_compiles_for_the_chip(one_chip, monkeypatch):
+    """The SmallThinker cell's whole train step (ONE sequence of 16,384,
+    four layers at the published widths, 8 of 64 experts held, Adam, the
+    lean remat policy: no room given) compiled for the described v5e,
+    abstract: both attention kinds' streaming kernels at a group of seven
+    (one full layer, three band layers: a forward and ONE backward kernel
+    each), the `reglu` walk at 98,304 slots with every product at whole
+    tiles; and what the configuration's `eight_layers` states: the step's
+    arguments and temporaries."""
+    from elasticdl_tpu.layers import moe
+    from model_zoo.smallthinker import smallthinker as zoo
+
+    monkeypatch.setattr(fa, "use_interpret", lambda: False)
+    compiled = _cell_train_step(
+        one_chip, zoo, _cell_config("smallthinker-21b-a3b"), (1, 16384)
+    )
+    text = compiled.as_text()
+    _assert_two_kernels(text, "causal")
+    _assert_two_kernels(text, "window")
+    # q at 28 heads of 128, K/V at their 4: nothing repeated to 28
+    kernel = next(
+        line for line in text.splitlines()
+        if "window_attention_dkv" in line and "custom-call(" in line
+    )
+    assert "bf16[1,16384,3584]" in kernel and "bf16[1,16384,512]" in kernel
+    assert "ragged-dot" in text and "s32[98304]" in text
+    tilings = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', text)
+    assert tilings
+    assert all(int(t) >= moe.TILE for tiling in tilings for t in tiling)
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    # `eight_layers`: 4.447e9 + 5.265e9 = 9.712e9 at four layers
+    assert 9.2e9 < held < 10.2e9, held
 
 
 def _whole_arrays_off_the_channels(text, size=2 * 8192 * 4096):
