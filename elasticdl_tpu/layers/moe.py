@@ -153,6 +153,31 @@ def grouped_matmul(lhs, rhs, group_sizes):
 # small chunks lose at full load what they win at low load; as committed
 # 16,384 reads 24 / 29 / 54 / 79 and 29 / 47 / 67 / 100 at an eighth /
 # a quarter / a half / all (PERF.md section 6).
+# What a trip's scatter-add pays hangs on the carry's WIDTH and on nothing
+# else (PR 58's probe, `scripts/probe_routed_walk.py`: v5e, one layer alone,
+# forward + backward, three traced calls; ms a chunk of 16,384 rows, a pass,
+# the same whether the held experts' loads are even, each group a pass over
+# all the tokens, or one expert holds nine tenths of the rows as one run):
+#   columns   1,536  2,048  2,304  2,560  2,688  3,072  4,096
+#   ms        1.55   2.08   3.01   8.00   2.44   3.12   3.37
+# 2,560 columns pay 8.0 ms where their neighbours pay 2.4-3.0 (1.3 us a live
+# row at 12,288 live rows a chunk): the carry of such tokens is a lane tile
+# wider (`_carry_width`, `SLOW_SCATTER_WIDTHS`) and cut to them after the
+# walk.  Summing a token's k rows from the TOKEN's side instead (a trip
+# writes its rows to a (slots, hidden) buffer; top_k gathers of a row a
+# token and one fused add after the loop) was built and measured: its cost
+# follows the slots, 0.66-0.70 ms a gather of 16,384 rows of 2,560 bfloat16
+# columns, ~6 ms a pass at 98,304 slots whatever the load.  The walk a call,
+# scatter-add a trip | from the token's side, at 0.125 / 0.25 / 0.5 of the
+# slots here:
+#   16,384 x 2,560, top-6  (98,304 slots)  27.98 / 51.88 / 79.85 | 22.40 / 30.53 / 42.91
+#   16,384 x 2,048, top-8  (131,072)       14.51 / 24.71 / 44.93 | 21.68 / 28.17 / 40.79
+#   16,384 x 2,048, top-10 (163,840)       25.14 / 35.27 / 55.56 | 32.35 / 38.72 / 51.51
+#   16,384 x 2,304, top-8  (131,072)       24.44 /   -   / 77.67 | 31.19 / 43.09 / 66.89
+#   16,384 x 2,688, top-6  (98,304)        25.34 /   -   / 73.61 | 32.38 / 46.83 / 71.26
+# It loses 6-7 ms a call at one live chunk at every width but the slow one
+# and wins only past three; at the slow one the wider carry beats it (PERF.md
+# section 6, PR 58).
 CHUNK = 16384
 # every index of the walk is a token's, in bounds by construction
 _PIB = "promise_in_bounds"
@@ -262,6 +287,24 @@ SCORES = {
 }
 
 
+# The widths of a (tokens, width) float32 carry at which the chip's
+# scatter-add of a chunk's rows runs at a quarter of its neighbours' pace
+# (the table above `CHUNK`).  Only what a cell has run is listed: the
+# widths read past 4,096 columns are worse (33 ms a chunk at 5,120, 93 at
+# 5,248, 18 at 7,168) and a lane tile up is no cure there; two carries of
+# half the width are (PERF.md section 7 (52)).
+SLOW_SCATTER_WIDTHS = frozenset({2560})
+
+
+def _carry_width(hidden: int) -> int:
+    """The width of the float32 (tokens, width) carries a trip scatter-adds
+    into: `hidden`, or the next lane tile (128) up that is not a width the
+    chip scatters to slowly."""
+    while hidden in SLOW_SCATTER_WIDTHS:
+        hidden += 128
+    return hidden
+
+
 def _chunks(slots: int):
     """(rows a trip, trips over the whole worst-case buffer)."""
     chunk = min(CHUNK, slots)
@@ -281,13 +324,15 @@ def walk_bytes(
     the four (slots, width) buffers it fills for the stacks' gradients
     (the rows, the first stack's output's gradient at the form's width,
     the activation, the output's gradient; each at the width the products
-    are padded to, `TILE`), three float32 (tokens, hidden)
-    sums (the forward's, its cotangent, d_tokens) and one chunk's rows in
-    flight, values and gradients."""
+    are padded to, `TILE`), three float32 sums of a row a token (the
+    forward's, its cotangent, d_tokens; at the carry's width,
+    `_carry_width`) and one chunk's rows in flight, values and
+    gradients."""
     chunk, total = _chunks(tokens * top_k)
     widths = 2 * _whole(hidden) + (FORMS[form][1] + 1) * _whole(ffn_dim)
     return (
-        (total + 2) * chunk * widths * itemsize + 3 * tokens * hidden * 4
+        (total + 2) * chunk * widths * itemsize
+        + 3 * tokens * _carry_width(hidden) * 4
     )
 
 
@@ -319,7 +364,11 @@ def routed_walk(tokens, w_first, w_down, order, weights, group_sizes,
     reads from `group_sizes`: a chunk past the last live row is neither
     gathered, multiplied, activated, weighted nor scattered, forward or
     backward, and one program serves every load.  A chunk's dead tail
-    (the last live chunk's) is masked by `grouped_matmul`.  A dynamic trip
+    (the last live chunk's) is masked by `grouped_matmul`.  The float32
+    carries a trip scatter-adds into (the sum, and the tokens' gradient in
+    the backward) are `_carry_width(hidden)` wide, zeros past `hidden`, and
+    cut to the tokens after the walk: the chip scatters to some widths at a
+    quarter of the pace of their neighbours.  A dynamic trip
     count has no reverse-mode rule, hence the hand-written backward below:
     the same chunks, each one's forward rebuilt (the residuals are the
     arguments, so a rematerialised block's second forward is dead code)
@@ -357,6 +406,7 @@ def _walk(tokens, w_first, w_down, order, weights, group_sizes, form):
     hidden = tokens.shape[1]
     top_k = slots // tokens.shape[0]
     chunk, total = _chunks(slots)
+    carry_w = _carry_width(hidden)
     with jax.named_scope("dispatch"):
         order = jnp.pad(order, (0, total * chunk - slots))
     # once a walk, outside the loop: the pads are the products' cost
@@ -375,16 +425,18 @@ def _walk(tokens, w_first, w_down, order, weights, group_sizes, form):
                 w_down, sizes,
             ), (chunk, hidden))
         with jax.named_scope("combine"):
+            # (padded as bfloat16: half the bytes of the float32 products)
             return out.at[at].add(
-                expert_out.astype(jnp.float32) * weight, mode=_PIB
+                _zeros_to(expert_out, (chunk, carry_w)).astype(jnp.float32)
+                * weight, mode=_PIB,
             )
 
     # the loop itself is `combine`'s: its carry is the sum
     with jax.named_scope("combine"):
-        return lax.fori_loop(
+        return _cut_to(lax.fori_loop(
             0, _trips(group_sizes.sum(), chunk), trip,
-            jnp.zeros(tokens.shape, jnp.float32),
-        )
+            jnp.zeros((tokens.shape[0], carry_w), jnp.float32),
+        ), tokens.shape)
 
 
 def _walk_fwd(*args):
@@ -398,6 +450,7 @@ def _walk_bwd(form, args, g):
     hidden, ffn = tokens.shape[1], w_down.shape[1]
     top_k = slots // tokens.shape[0]
     chunk, total = _chunks(slots)
+    carry_w = _carry_width(hidden)
     # the padding's rows are dead; its slot 0 repeats, so the weights'
     # gradient may promise distinct slots only where nothing is padded
     padded = total * chunk - slots
@@ -439,7 +492,8 @@ def _walk_bwd(form, args, g):
             )
         with jax.named_scope("combine"):
             d_tokens = d_tokens.at[at].add(
-                d_rows.astype(jnp.float32), mode=_PIB
+                _zeros_to(d_rows, (chunk, carry_w)).astype(jnp.float32),
+                mode=_PIB,
             )
             d_weights = d_weights.at[slot].add(
                 (expert_out.astype(jnp.float32) * g_rows).sum(axis=1),
@@ -460,7 +514,7 @@ def _walk_bwd(form, args, g):
         d_tokens, d_weights, (rows, d_first, act, d_out) = lax.fori_loop(
             0, _trips(group_sizes.sum(), chunk), trip,
             (
-                jnp.zeros(tokens.shape, jnp.float32),
+                jnp.zeros((tokens.shape[0], carry_w), jnp.float32),
                 jnp.zeros(weights.shape, jnp.float32),
                 tuple(
                     jnp.zeros((total * chunk, width), dtype) for width in
@@ -482,7 +536,7 @@ def _walk_bwd(form, args, g):
         d_w_first = _first_as(d_w_first, parts, hidden, ffn, _cut_to)
         d_w_down = _cut_to(d_w_down, (w_down.shape[0], ffn, hidden))
     with jax.named_scope("combine"):
-        d_tokens = d_tokens.astype(dtype)
+        d_tokens = _cut_to(d_tokens, tokens.shape).astype(dtype)
     return d_tokens, d_w_first, d_w_down, None, d_weights, None
 
 

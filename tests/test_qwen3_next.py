@@ -887,7 +887,9 @@ def test_the_layers_scopes_reach_the_lowered_operations():
 # runs), the remat policy's address blanked, recorded at the commit before
 # `RoutedExperts` learnt softmax scores, `MoEFFN` the shared gate,
 # `GroupedAttention` its norms, turn and gate, and `GatedRMSNorm` its
-# order (2260048): their programs are the parent's.
+# order (2260048): their programs are the parent's.  ISSUE 58 expected the
+# six routed entries re-recorded; PR 58's probe found the walk's scatter-add
+# slow at ONE width, 2,560 columns, which none of these has: all seven STAND.
 PARENTS_JAXPRS = {
     "kimi-linear-48b-a3b": (
         "kimi.kimi_linear", (2, 8192),

@@ -2,7 +2,10 @@
 gated `swiglu` and `reglu` over a fused gate-and-up stack and the non-gated
 `relu2` over an up stack alone, each against a per-expert dense sum (forward and
 every gradient) at loads that leave a dead tail, an empty expert, no row
-and every row; what the backward holds, by form; the leaves a layer of
+and every row, at a token whose slots lie in one chunk and in two, and with
+the float32 carries at the tokens' own width and a lane tile wider (where
+the chip scatters to the tokens' width slowly); what the backward holds, by
+form; the leaves a layer of
 each form builds; the `swiglu` walk at a sibling cell's shape traced
 to what it was traced to before the form entered; and the grouped
 products' operands padded to whole `TILE`s (every case above at a tile
@@ -35,16 +38,38 @@ ACTIVATIONS = {
 }
 
 
+def dealt(loads, rng):
+    """A key (the held expert of every slot, `HELD` for an absent one) that
+    sends exactly `loads[e]` slots, chosen at random, to held expert e."""
+    key = np.full((TOKENS * TOP_K,), HELD, np.int32)
+    here = rng.permutation(key.size)[:sum(loads)]
+    key[here] = np.repeat(np.arange(HELD), loads)
+    return key
+
+
+def in_one_chunk_and_in_two():
+    """Token 0 sends BOTH its slots to expert 0 (adjacent rows); tokens 1-10
+    a slot to expert 0 and one to expert 1, both in chunk 0 (rows 2-11 and
+    32-41); tokens 11-30 a slot to expert 0 and one to expert 3, whose 20
+    rows (42-61) cross the chunks' edge at 48: tokens 17-30 are summed from
+    two chunks.  Expert 2 has a load of 0; the last live chunk a dead tail;
+    tokens 31-63 only absent experts."""
+    key = np.full((TOKENS, TOP_K), HELD, np.int32)
+    key[:31, 0] = 0
+    key[0, 1], key[1:11, 1], key[11:31, 1] = 0, 1, 3
+    return key.reshape(-1)
+
+
 def given(form, loads, seed=0):
     """Tokens, the form's two stacks, and a routing that sends exactly
     `loads[e]` slots to held expert e and the rest to absent experts
-    (key `HELD`), in the layer's own terms: `order` (the slots sorted by
-    key), one weight a slot, the group sizes."""
+    (key `HELD`; an array for `loads` is the key itself), in the layer's
+    own terms: `order` (the slots sorted by key), one weight a slot, the
+    group sizes."""
     rng = np.random.RandomState(seed)
     slots = TOKENS * TOP_K
-    key = np.full((slots,), HELD, np.int32)
-    here = rng.permutation(slots)[:sum(loads)]
-    key[here] = np.repeat(np.arange(HELD), loads)
+    key = loads if isinstance(loads, np.ndarray) else dealt(loads, rng)
+    loads = np.bincount(key, minlength=HELD + 1)[:HELD]
     width = moe.FORMS[form][1] * FFN
     return dict(
         tokens=jnp.asarray(rng.randn(TOKENS, HIDDEN), jnp.float32),
@@ -70,22 +95,35 @@ def dense_sum(form, tokens, w_first, w_down, weights, key):
     return out
 
 
+# and a routing that is set, not dealt
+LOADS["in_one_chunk_and_in_two"] = in_one_chunk_and_in_two()
+
 # HIDDEN 32 and FFN 24 against the tile: both axes padded far (the module's
 # own tile), both padded to 40 (neither a multiple of 20), the expert's
 # width alone (to 32), neither
 TILES = {"module": None, "both": 20, "ffn_only": 16, "neither": 8}
 
 
+# the float32 carries a trip scatter-adds into: at the tokens' own width,
+# or a lane tile wider where that width is one the chip scatters to slowly
+# (`moe._carry_width`, `moe.SLOW_SCATTER_WIDTHS`)
+CARRIES = {"own": set(), "a_tile_wider": {HIDDEN}}
+
+
+@pytest.mark.parametrize("carry", sorted(CARRIES))
 @pytest.mark.parametrize("tile", sorted(TILES))
 @pytest.mark.parametrize("form", sorted(moe.FORMS))
 @pytest.mark.parametrize("load", sorted(LOADS))
 def test_a_walk_of_either_form_is_the_per_expert_dense_sum(
-        load, form, tile, monkeypatch):
+        load, form, tile, carry, monkeypatch):
     monkeypatch.setattr(moe, "CHUNK", CHUNK)
+    monkeypatch.setattr(moe, "SLOW_SCATTER_WIDTHS", CARRIES[carry])
+    assert moe._carry_width(HIDDEN) == HIDDEN + 128 * (carry != "own")
     if TILES[tile]:
         monkeypatch.setattr(moe, "TILE", TILES[tile])
     assert (moe.padded_work(HIDDEN, FFN) > 0) == (tile != "neither")
     g = given(form, LOADS[load])
+    loads = np.asarray(g["group_sizes"])
     cotangent = jnp.asarray(
         np.random.RandomState(5).randn(TOKENS, HIDDEN), jnp.float32
     )
@@ -110,7 +148,7 @@ def test_a_walk_of_either_form_is_the_per_expert_dense_sum(
             lambda t, a, b, w: dense_sum(form, t, a, b, w, g["key"])
         )
     assert out.dtype == jnp.float32
-    assert bool(np.abs(np.asarray(out)).sum() > 0) == bool(sum(LOADS[load]))
+    assert bool(np.abs(np.asarray(out)).sum() > 0) == bool(loads.sum())
     close = dict(rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(out, want, **close)
     for name, got, ref in zip(leaves, got_grads, want_grads):
@@ -119,7 +157,7 @@ def test_a_walk_of_either_form_is_the_per_expert_dense_sum(
         np.testing.assert_allclose(got, ref, err_msg=name, **close)
     # an expert no slot chose gets no gradient, a slot of no held expert
     # no weight's
-    for e, rows in enumerate(LOADS[load]):
+    for e, rows in enumerate(loads):
         got = np.abs(np.asarray(got_grads[1][e])).sum()
         assert bool(got > 0) == bool(rows)
     assert not np.asarray(got_grads[3])[g["key"] == HELD].any()
@@ -153,7 +191,8 @@ def test_the_forms_differ_and_the_default_is_swiglu():
 
 def test_walk_bytes_by_form():
     """The backward's four buffers take the form's widths: (hidden, 2
-    ffn, ffn, hidden) gated, (hidden, ffn, ffn, hidden) squared."""
+    ffn, ffn, hidden) gated, (hidden, ffn, ffn, hidden) squared; the three
+    float32 sums the carry's."""
     tokens, hidden, top_k, ffn = 16384, 2048, 6, 1536   # whole tiles
     chunk, total = moe._chunks(tokens * top_k)
     assert (chunk, total) == (16384, 6)
@@ -170,8 +209,11 @@ def test_walk_bytes_by_form():
     )
     # 16,384 tokens of 2,560, top-6, experts 768 wide (whole tiles): the
     # walk of one sequence at a 16k context
+    # ... whose three sums are a lane tile wider than the tokens: the chip
+    # scatters to 2,560 columns at a quarter of its neighbours' pace
+    assert moe._carry_width(2560) == 2688 and moe._carry_width(2048) == 2048
     assert moe.walk_bytes(16384, 2560, 6, 768, 2, moe.REGLU) == (
-        8 * 16384 * 7424 * 2 + 3 * 16384 * 2560 * 4
+        8 * 16384 * 7424 * 2 + 3 * 16384 * 2688 * 4
     )
     # the GLM cell's layer, as before the form entered
     assert moe.walk_bytes(16384, 2048, 4, 1536, 2) == (
@@ -219,6 +261,9 @@ def test_a_layer_builds_its_forms_stacks(form, first):
 # bfloat16 shape (16,384 tokens of 2,048, 8 held experts 1,536 wide, top-4:
 # 65,536 slots), recorded at the commit before the form entered
 # (1daa31a): the four routed cells' walk traces to what it traced to.
+# ISSUE 58 expected to re-record it; PR 58's probe found the scatter-add slow
+# at ONE width, 2,560 columns (`moe.SLOW_SCATTER_WIDTHS`), so it STANDS: the
+# walk at 2,048 columns is still that commit's.
 SWIGLU_JAXPR = (
     "b6b6b0df946fc301f1f36d1bf05b107c4b73728266de328ee7a7e96c9ace8af4"
 )
@@ -240,15 +285,19 @@ def test_the_swiglu_walk_is_the_parents():
     assert hashlib.sha256(text.encode()).hexdigest() == SWIGLU_JAXPR
 
 
-def walk_jaxpr(form, tokens, hidden, ffn, top_k, held=8):
-    """The jaxpr of the walk's forward and backward at a cell's bfloat16
-    shape (abstract: nothing is computed)."""
+def walk_jaxpr(form, tokens, hidden, ffn, top_k, held=8, backward=True):
+    """The jaxpr of the walk's forward and backward (of its forward alone
+    without `backward`) at a cell's bfloat16 shape (abstract: nothing is
+    computed)."""
     shaped = jax.ShapeDtypeStruct
     slots = tokens * top_k
+
+    def walk(t, a, b, o, w, g):
+        return moe.routed_walk(t, a, b, o, w, g, form)
+
     return jax.make_jaxpr(jax.grad(
-        lambda t, a, b, o, w, g: moe.routed_walk(t, a, b, o, w, g, form).sum(),
-        argnums=(0, 1, 2, 4),
-    ))(
+        lambda *args: walk(*args).sum(), argnums=(0, 1, 2, 4),
+    ) if backward else walk)(
         shaped((tokens, hidden), jnp.bfloat16),
         shaped((held, hidden, moe.FORMS[form][1] * ffn), jnp.bfloat16),
         shaped((held, ffn, hidden), jnp.bfloat16),
@@ -306,3 +355,26 @@ def test_a_walk_at_whole_tiles_pads_no_stack(cell, shape):
     pads = equations(closed.jaxpr, "pad")
     assert all(eqn.invars[0].aval.ndim == 1 for eqn in pads), pads
     assert moe.padded_work(shape[2], shape[3]) == 0.0
+
+
+@pytest.mark.parametrize("backward", [False, True])
+@pytest.mark.parametrize("cell, shape", [
+    ("smallthinker", (moe.REGLU, 16384, 2560, 768, 6)),
+    ("top_8_of_the_same_width", (moe.SWIGLU, 16384, 2560, 512, 8)),
+])
+def test_a_slow_width_is_scattered_to_a_tile_wider(cell, shape, backward):
+    """16,384 tokens of 2,560: no trip scatter-adds into a (tokens, 2,560)
+    float32 carry, forward or backward (8.0 ms a chunk on the chip): the
+    carries are 2,688 wide (2.4 ms), and the walk's results keep the
+    tokens' shape.  (At the siblings' widths the walk is the parent's:
+    `test_the_swiglu_walk_is_the_parents`.)"""
+    _, tokens, hidden, _, _ = shape
+    closed = walk_jaxpr(*shape, backward=backward)
+    carries = [
+        eqn.invars[0].aval.shape
+        for eqn in equations(closed.jaxpr, "scatter-add")
+        if eqn.invars[0].aval.ndim == 2
+    ]
+    # a gradient's jaxpr traces the forward too
+    assert carries == [(tokens, 2688)] * (1 + backward)
+    assert closed.jaxpr.outvars[0].aval.shape == (tokens, hidden)

@@ -535,9 +535,18 @@ def test_smallthinker_cell_step_compiles_for_the_chip(one_chip, monkeypatch):
     tilings = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', text)
     assert tilings
     assert all(int(t) >= moe.TILE for tiling in tilings for t in tiling)
+    # 2,560 columns are a slow width to scatter-add to (8.0 ms a chunk on
+    # the chip where 2,688 cost 2.4: `moe.SLOW_SCATTER_WIDTHS`): the walk's
+    # float32 carries are a lane tile wider and nothing scatters into a
+    # (tokens, 2,560) float32 array (PR 58; before it eight such scatters a
+    # step, two a layer)
+    assert moe._carry_width(2560) == 2688
+    assert not re.search(r"= f32\[16384,2560\]\S* scatter\(", text)
+    assert len(re.findall(r"= f32\[16384,2688\]\S* scatter\(", text)) == 8
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
-    # `eight_layers`: 4.447e9 + 5.265e9 = 9.712e9 at four layers
+    # `eight_layers`: 4.447e9 + 5.265e9 = 9.712e9 at four layers before PR
+    # 58, 4.447e9 + 5.244e9 = 9.691e9 with the wider carries
     assert 9.2e9 < held < 10.2e9, held
 
 
