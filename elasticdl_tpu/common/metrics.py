@@ -57,6 +57,7 @@ ALLOWED_UNIT_SUFFIXES = (
     "_steps",  # a step-distance (e.g. cross-replica skew), not a position
     "_epoch",
     "_info",
+    "_nats",  # a loss or an entropy in natural-log units
 )
 
 COUNTER = "counter"

@@ -107,6 +107,14 @@ DEVICE_SCOPES = (
     # that names it reads them by the path)
     "smallthinker/embed", "smallthinker/norm", "smallthinker/attn_full",
     "smallthinker/attn_window", "smallthinker/moe", "smallthinker/head_ce",
+    # model_zoo/ouro: the blocks run inside the trips' loop, whose `while`
+    # counts as its body's operations; `ouro/trips` is what the loop does
+    # beside its blocks (the stacks of what a trip keeps, written forward
+    # and read backward, their layout copies, a weight's gradient summed
+    # over the trips); `ouro/exit` is the gate, the exit distribution, its
+    # entropy and the weighing of the trips' losses
+    "ouro/embed", "ouro/trips", "ouro/norm", "ouro/attn", "ouro/dense_ffn",
+    "ouro/exit", "ouro/head_ce",
 )
 
 #: Kernels the TPU's compiler makes from ONE primitive and names after

@@ -42,7 +42,12 @@ _METRICS: Dict[str, object] = {}
 
 
 def sow_step_metric(module: nn.Module, name: str, value) -> None:
-    """Keep `value` (the LAST step's, not a history) in STEP_METRICS."""
+    """Keep `value` (the LAST step's, not a history) in STEP_METRICS.
+    One value a PATH: a module applied several times a step (a block
+    inside a looped model's trips) sows under one path each time and keeps
+    its LAST application's value.  What has to come out a trip leaves the
+    loop with a trip axis and is sown under a path a trip
+    (`model_zoo/ouro/ouro.py: TripGauges`)."""
     value = jax.lax.stop_gradient(jnp.asarray(value, jnp.float32))
     module.sow(
         STEP_METRICS, name, value,

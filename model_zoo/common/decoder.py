@@ -1,8 +1,8 @@
-"""What the zoo's eight decoder language models have in common
+"""What the zoo's nine decoder language models have in common
 (`model_zoo/glm/glm_moe_lite.py`, `laguna/laguna.py`, `lfm2/lfm2_moe.py`,
 `kimi/kimi_linear.py`, `granite/granite_hybrid.py`,
 `nemotron/nemotron_h.py`, `qwen3_next/qwen3_next.py`,
-`smallthinker/smallthinker.py`): RMSNorm (its scale
+`smallthinker/smallthinker.py`, `ouro/ouro.py`): RMSNorm (its scale
 plain or zero-centred) and its gated form (one statistic a group of
 channels, the gate before the norm or after it), rotary's turn whole or
 over a head's first columns (`Rope`, `partial_rotary`), the seeds of a
@@ -13,8 +13,10 @@ asked for), the routed block around `layers/moe.py: RoutedExperts` with
 its shared expert (gated where asked for) and its routing's source (the
 block's input where asked for), the cross-entropy
 taken in blocks of tokens, the per-position losses against the ids
-shifted, the blocks' rematerialisation (`remat_block`, and what more of
-a block it keeps where the device has room: `remat_blocks`), and the zoo
+shifted (of one state or of several at once), the blocks'
+rematerialisation (`remat_block`, and what more of a block it keeps where
+the device has room, however many times a step the stack is applied:
+`remat_blocks`), and the zoo
 functions a next-token model shares (`loss`, `optimizer`,
 `eval_metrics_fn`, `param_sharding`)."""
 
@@ -284,11 +286,14 @@ def dense(features: int, name: str, dtype, kind: str = ""):
 
 
 class SwiGLU(nn.Module):
-    """(silu(x Wg) * (x Wu)) Wd, gate and up in one kernel."""
+    """(silu(x Wg) * (x Wu)) Wd, gate and up in one kernel.  `down_kind`
+    names the last product for the plan (`FFN_OUT`) in a block that norms
+    the MLP's output, whose backward reads it; unnamed everywhere else."""
 
     hidden: int
     width: int
     dtype: jnp.dtype = jnp.float32
+    down_kind: str = ""
 
     @nn.compact
     def __call__(self, x):
@@ -296,8 +301,11 @@ class SwiGLU(nn.Module):
             dense(2 * self.width, "gate_up", self.dtype, GATE_UP)(x), 2,
             axis=-1,
         )
-        # the block's last product: no backward reads its output
-        return dense(self.hidden, "down", self.dtype)(nn.silu(gate) * up)
+        # the block's last product: no backward reads its output, unless
+        # the block norms it
+        return dense(self.hidden, "down", self.dtype, self.down_kind)(
+            nn.silu(gate) * up
+        )
 
 
 class ReLU2MLP(nn.Module):
@@ -454,11 +462,14 @@ class MoEFFN(nn.Module):
 # a mixer's projections from the residual stream, MLA's two projections
 # up from their latents, the mixer's out-projection, and the `gate_up` of
 # the MLP or of the shared expert.  A block's LAST product (`down`) is
-# read by no backward and is not rebuilt; nothing inside
-# `layers/moe.py: routed_walk` is named (its `custom_vjp` keeps what it
-# keeps).
-MIXER_IN, Q_UP, KV_UP, MIXER_OUT, GATE_UP = PRODUCT_NAMES = (
-    "mixer_in", "mixer_q_up", "mixer_kv_up", "mixer_out", "gate_up"
+# read by no backward and is not rebuilt, so it carries no name, but in a
+# block that norms the MLP's OUTPUT inside the residual branch: the
+# norm's backward reads it, and `SwiGLU(down_kind=FFN_OUT)` names it
+# there.  Nothing inside `layers/moe.py: routed_walk` is named (its
+# `custom_vjp` keeps what it keeps).
+MIXER_IN, Q_UP, KV_UP, MIXER_OUT, GATE_UP, FFN_OUT = PRODUCT_NAMES = (
+    "mixer_in", "mixer_q_up", "mixer_kv_up", "mixer_out", "gate_up",
+    "ffn_out",
 )
 
 # `lean_step_bytes`: the share of the bytes a block's traced forward
@@ -472,8 +483,9 @@ PROGRAM_BYTES = 288 << 20
 
 class Product(NamedTuple):
     """One named product of one block: keeping it holds `size` bytes
-    from the forward to the block's backward, and spares the rebuild
-    `contraction` multiply-adds for each element kept."""
+    from the forward to the block's backward, once for each time a step
+    applies the block, and spares the rebuild `contraction` multiply-adds
+    for each element kept."""
 
     name: str
     size: int
@@ -482,20 +494,27 @@ class Product(NamedTuple):
 
 class BlockShapes(NamedTuple):
     """What a block's traced forward tells the plan: its named products,
-    the bytes of SAVED_NAMES, and the bytes of every value it makes."""
+    the bytes of SAVED_NAMES, the bytes of every value it makes, what
+    the chip's tiling adds to SAVED_NAMES (`tiled_bytes`), and the bytes
+    of the block's weights cast to the type it computes in (0 where it
+    computes in the type they are held in)."""
 
     products: Tuple[Product, ...]
     saved: int
     made: int
+    padding: int = 0
+    cast_weights: int = 0
 
 
 def kept_products(
-    blocks: Sequence[Sequence[Product]], budget: int
+    blocks: Sequence[Sequence[Product]], budget: int, trips: int = 1
 ) -> Tuple[Tuple[str, ...], ...]:
     """For each block the names it keeps under `budget` bytes: the
     products in order of contraction width (the operations a kept byte
     buys), widest first, then of block index, each taken WHOLE if it
-    still fits and passed over if not."""
+    still fits and passed over if not.  A stack applied `trips` times a
+    step holds a kept product once a trip (one policy a block, whatever
+    the trip), so it is kept only where `trips` of it fit."""
     order = sorted(
         (-product.contraction, index, place)
         for index, block in enumerate(blocks)
@@ -504,8 +523,8 @@ def kept_products(
     kept = [[] for _ in blocks]
     for _, index, place in order:
         product = blocks[index][place]
-        if product.size <= budget:
-            budget -= product.size
+        if trips * product.size <= budget:
+            budget -= trips * product.size
             kept[index].append(product.name)
     return tuple(tuple(names) for names in kept)
 
@@ -535,6 +554,23 @@ def _bytes(variables) -> int:
     )
 
 
+def tiled_bytes(variables) -> int:
+    """`_bytes` as the chip lays the arrays out in HBM: the two minor
+    axes in whole tiles of (32 bytes of rows, 128 columns), so that a
+    float32 (B, heads, L, 1) log-sum-exp takes 128 times its values."""
+    total = 0
+    for v in variables:
+        if not hasattr(v.aval, "shape"):
+            continue
+        size = v.aval.dtype.itemsize
+        *lead, rows, columns = (1, 1) + tuple(v.aval.shape)
+        tile = 32 // size
+        total += size * int(np.prod(lead)) * (
+            -(-rows // tile) * tile
+        ) * (-(-columns // 128) * 128)
+    return total
+
+
 def _contraction(jaxpr, variable) -> int:
     """The width the product that made `variable` contracts."""
     for eqn in jaxpr.eqns:
@@ -558,7 +594,7 @@ def block_shapes(block: nn.Module, shape, dtype) -> BlockShapes:
     traced = jax.make_jaxpr(
         lambda v, x: block.apply(v, x, mutable=nn.DenyList(STEP_METRICS))
     )(variables, x)
-    products, saved, made = {}, 0, 0
+    products, saved, made, padding = {}, 0, 0, 0
     for jaxpr, eqn in _equations(traced.jaxpr):
         made += _bytes(eqn.outvars)
         if eqn.primitive.name != "name":
@@ -566,6 +602,7 @@ def block_shapes(block: nn.Module, shape, dtype) -> BlockShapes:
         name, size = eqn.params["name"], _bytes(eqn.outvars)
         if name in SAVED_NAMES:
             saved += size
+            padding += tiled_bytes(eqn.outvars) - size
         elif name in PRODUCT_NAMES:
             # one policy name keeps every product of its kind in the
             # block (q, k and v): they are one product to the plan
@@ -574,12 +611,17 @@ def block_shapes(block: nn.Module, shape, dtype) -> BlockShapes:
                 name, before.size + size,
                 min(before.contraction, _contraction(jaxpr, eqn.invars[0])),
             )
-    return BlockShapes(tuple(products.values()), saved, made)
+    cast = 0 if dtype == jnp.float32 else sum(
+        leaf.size for leaf in jax.tree.leaves(variables.get("params", {}))
+    ) * jnp.dtype(dtype).itemsize
+    return BlockShapes(
+        tuple(products.values()), saved, made, padding, cast
+    )
 
 
 def lean_step_bytes(
     blocks: Sequence[BlockShapes], walks: Sequence[int], x_bytes: int,
-    vocab: int,
+    vocab: int, trips: int = 1,
 ) -> int:
     """A calibrated ESTIMATE (no bound) of what the step holds beside
     its state under the lean policy (nothing kept but SAVED_NAMES):
@@ -588,13 +630,30 @@ def lean_step_bytes(
     logits with its softmax and their gradient, and the program itself.
     A block's working set is `BLOCK_SHARE` of every value its traced
     forward makes and, in a routed block, what the walk's backward holds
-    (`walks`, from `layers/moe.py: walk_bytes`).  Fitted so that it errs
+    (`walks`, from `layers/moe.py: walk_bytes`).  A stack applied
+    `trips` times a step holds every block's saved input and SAVED_NAMES
+    once a TRIP, from that trip's forward to that trip's backward; the
+    working set, the cross-entropy's block and the program stand once.
+    What the chip's tiling adds to SAVED_NAMES (a log-sum-exp is padded
+    128-fold: `BlockShapes.padding`) is inside the fitted constants for
+    ONE application of each block, as the cells they were fitted to hold
+    it, and counted for every further trip (24 applications hold 1.6e9 of
+    it where the fit saw 0.4e9).  A loop over the stack also holds what a
+    straight-line step does not: the blocks' weights cast to the compute
+    type ONCE, ahead of the loop (the compiler hoists what no trip
+    changes), and a trip's emitted state and the final norm's saved input
+    (two `x_bytes` a trip).  Fitted so that it errs
     HIGH against each of the five cells' steps on the chip (`PERF.md`
     section 6 has a row a cell; section 7 what a user sees where it errs
     low)."""
     return (
-        len(blocks) * x_bytes
-        + sum(block.saved for block in blocks)
+        trips * (
+            len(blocks) * x_bytes + sum(block.saved for block in blocks)
+        )
+        + (trips - 1) * sum(block.padding for block in blocks)
+        + (trips > 1) * (
+            sum(block.cast_weights for block in blocks) + 2 * trips * x_bytes
+        )
         + max(
             int(BLOCK_SHARE * block.made) + walk
             for block, walk in zip(blocks, walks)
@@ -646,7 +705,7 @@ def _remat_class(block_cls, kept: Tuple[str, ...]):
 
 def remat_blocks(
     block_cls, config, kinds, x, room: Optional[int], vocab: int,
-    walks: Optional[Sequence[int]] = None,
+    walks: Optional[Sequence[int]] = None, trips: int = 1,
 ):
     """The class to build each block `block_cls(config, kind)` of a model
     from, one a `kind`, where the blocks rematerialise over `x` (B, L,
@@ -660,7 +719,10 @@ def remat_blocks(
     `walks` from `routed_walks` where blocks are routed) comes off the
     room, and `kept_products` spends what is left; the share of the named
     bytes kept is set in `worker_remat_kept_ratio`, on the host, as the
-    step is traced."""
+    step is traced.  `trips` is how many times a step applies the whole
+    stack over one set of weights (a looped model's trips): everything a
+    block holds from its forward to its backward is then held that many
+    times."""
     if room is None:
         return [remat_block(block_cls)] * len(kinds)
     kept, ratio = [()] * len(kinds), 0.0
@@ -673,10 +735,10 @@ def remat_blocks(
         ]
         lean = lean_step_bytes(
             shapes, walks or [0] * len(kinds),
-            x.size * x.dtype.itemsize, vocab,
+            x.size * x.dtype.itemsize, vocab, trips,
         )
         products = [block.products for block in shapes]
-        kept = kept_products(products, max(0, room - lean))
+        kept = kept_products(products, max(0, room - lean), trips)
         held = sum(
             p.size for block, names in zip(products, kept) for p in block
             if p.name in names
@@ -714,15 +776,21 @@ def blocked_nll(h, head_kernel, targets, dtype, block: int = CE_BLOCK):
 
 def shifted_nll(h, head_kernel, ids, shift: int, dtype, scope: str):
     """(B, L - shift) per-position loss of h (B, L, d) against the ids
-    `shift` places on; the positions with no such id are left out."""
+    `shift` places on; the positions with no such id are left out.  `h`
+    may stack several states, (S, B, L, d), each read against the same
+    ids: (S, B, L - shift) then, from ONE blocked pass over all S x B x L
+    rows, so that the head's gradient sums over every state's blocks in
+    one loop."""
     batch, length = ids.shape
+    stacked = h.shape[:-3]
     targets = jnp.roll(ids, -shift, axis=1)
     with jax.named_scope(scope):
         out = blocked_nll(
-            h.reshape(batch * length, h.shape[-1]), head_kernel,
-            targets.reshape(-1), dtype,
-        ).reshape(batch, length)
-    return out[:, :length - shift]
+            h.reshape(-1, h.shape[-1]), head_kernel,
+            jnp.broadcast_to(targets, stacked + targets.shape).reshape(-1),
+            dtype,
+        ).reshape(stacked + (batch, length))
+    return out[..., :length - shift]
 
 
 # ---- the zoo functions of a next-token model ------------------------------

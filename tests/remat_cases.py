@@ -27,7 +27,7 @@ def assert_saving_changes_nothing(zoo, monkeypatch, other, grads_of, want,
     if other == "plain-remat":
         monkeypatch.setattr(
             zoo, "remat_blocks",
-            lambda block_cls, config, kinds, *rest: (
+            lambda block_cls, config, kinds, *rest, **more: (
                 [nn.remat(block_cls)] * len(kinds)
             ),
         )

@@ -309,9 +309,9 @@ def cell_plan(config_name, traffic_name, monkeypatch):
         seen["lean"] = estimate(*args)
         return seen["lean"]
 
-    def recording_rule(blocks, budget):
+    def recording_rule(blocks, budget, *trips):
         seen["named"] = sum(p.size for block in blocks for p in block)
-        return rule(blocks, budget)
+        return rule(blocks, budget, *trips)
 
     monkeypatch.setattr(decoder, "lean_step_bytes", recording_estimate)
     monkeypatch.setattr(decoder, "kept_products", recording_rule)
@@ -532,3 +532,151 @@ def test_a_model_that_takes_no_room_is_dispatched_as_before():
     trainer = trainer_lib.Trainer.__new__(trainer_lib.Trainer)
     trainer.train_step = lambda state, batch: (state, batch)
     assert trainer._train("state", "batch") == ("state", "batch")
+
+
+# ---- a stack applied several times a step (a looped model's trips) --------
+
+
+@pytest.mark.parametrize("budget", [0, 39, 40, 80, 240, 559, EVERYTHING])
+def test_one_trip_is_the_plan_it_was(budget):
+    assert kept_products(BLOCKS, budget, 1) == kept_products(BLOCKS, budget)
+    shapes = [
+        decoder.BlockShapes(BLOCKS[0], 100, 1000),
+        decoder.BlockShapes(BLOCKS[1], 60, 3000, padding=7, cast_weights=9),
+    ]
+    assert decoder.lean_step_bytes(shapes, [0, 5], 10, 8, 1) == (
+        decoder.lean_step_bytes(shapes, [0, 5], 10, 8)
+    ) == (
+        2 * 10 + 160 + int(decoder.BLOCK_SHARE * 3000) + 5
+        + 3 * decoder.CE_BLOCK * 8 * 4 + decoder.PROGRAM_BYTES
+    )
+
+
+def test_four_trips_hold_four_of_every_per_block_term_and_one_of_the_rest():
+    shapes = [
+        decoder.BlockShapes(BLOCKS[0], 100, 1000, padding=11),
+        decoder.BlockShapes(BLOCKS[1], 60, 3000, padding=7, cast_weights=9),
+    ]
+    once = decoder.lean_step_bytes(shapes, [0, 5], 10, 8)
+    per_block = 2 * 10 + 160               # saved inputs and SAVED_NAMES
+    assert decoder.lean_step_bytes(shapes, [0, 5], 10, 8, 4) == (
+        once + 3 * per_block
+        # the further trips' tiling of SAVED_NAMES, the loop's hoisted
+        # casts of the weights and its two stacks of states
+        + 3 * (11 + 7) + 9 + 2 * 4 * 10
+    )
+    # the working set, the cross-entropy's block and the program: once
+    assert once - per_block == (
+        int(decoder.BLOCK_SHARE * 3000) + 5
+        + 3 * decoder.CE_BLOCK * 8 * 4 + decoder.PROGRAM_BYTES
+    )
+
+
+def test_a_product_is_kept_only_where_four_of_it_fit():
+    # one trip keeps both out-projections in 80 bytes; four trips need 320
+    assert kept_products(BLOCKS, 80, 4) == ((), ())
+    assert kept_products(BLOCKS, 4 * 40, 4) == ((MIXER_OUT,), ())
+    assert kept_products(BLOCKS, 4 * 80 - 1, 4) == ((MIXER_OUT,), ())
+    assert kept_products(BLOCKS, 4 * 80, 4) == ((MIXER_OUT,), (MIXER_OUT,))
+    assert kept_products(BLOCKS, 4 * EVERYTHING - 1, 4) != (
+        kept_products(BLOCKS, 4 * EVERYTHING, 4)
+    )
+    for budget in (0, 500, 1000, 2079, 2080):
+        kept = kept_products(BLOCKS, budget, 4)
+        assert 4 * held_bytes(kept) <= budget
+        assert kept == kept_products(BLOCKS, budget // 4, 1)
+
+
+def test_remat_blocks_plans_over_the_trips():
+    x = jnp.zeros(TOY_X.shape, TOY_X.dtype)
+    shapes = decoder.block_shapes(
+        ToyBlock(None, "a", parent=None), TOY_X.shape, TOY_X.dtype
+    )
+    # float32 throughout: no cast to hoist; nothing of the toy is saved
+    assert (shapes.padding, shapes.cast_weights) == (0, 0)
+    estimate = decoder.lean_step_bytes(
+        [shapes, shapes], [0, 0], x.size * 4, 8, 4
+    )
+    assert estimate == decoder.lean_step_bytes(
+        [shapes, shapes], [0, 0], x.size * 4, 8
+    ) + 3 * 2 * x.size * 4 + 2 * 4 * x.size * 4
+    out = decoder.remat_block(ToyBlock, (MIXER_OUT,))
+    lean = decoder.remat_block(ToyBlock)
+    room = estimate + 4 * 2 * TOKENS * 32 * 4
+
+    def classes(room, trips):
+        return decoder.remat_blocks(
+            ToyBlock, None, ["a", "b"], x, room, 8, trips=trips
+        )
+
+    assert classes(room, 4) == [out, out]
+    # still kept bytes over named bytes, whatever the trips
+    assert kept_ratio() == TOKENS * 32 * 4 / sum(
+        p.size for p in shapes.products
+    )
+    assert classes(room - 1, 4) == [out, lean]
+    assert classes(None, 4) == [lean, lean]
+
+
+def test_the_tiling_pads_a_log_sum_exp_128_fold():
+    class Value:
+        def __init__(self, shape, dtype):
+            self.aval = jax.ShapeDtypeStruct(shape, dtype)
+
+    lse = Value((1, 16, 8192, 1), jnp.float32)
+    out = Value((1, 8192, 16, 128), jnp.bfloat16)
+    assert decoder.tiled_bytes([lse]) == 128 * 16 * 8192 * 4
+    assert decoder.tiled_bytes([out]) == 8192 * 16 * 128 * 2
+    # bfloat16 rows go 16 to a tile, float32 rows 8
+    assert decoder.tiled_bytes([Value((3, 130), jnp.bfloat16)]) == (
+        16 * 256 * 2
+    )
+    assert decoder.tiled_bytes([Value((3, 130), jnp.float32)]) == 8 * 256 * 4
+    assert decoder.tiled_bytes([Value((5,), jnp.float32)]) == 8 * 128 * 4
+
+
+def test_the_down_product_is_named_only_where_a_block_asks():
+    x = jax.ShapeDtypeStruct((1, 8, 16), jnp.float32)
+
+    def names(**kwargs):
+        layer = decoder.SwiGLU(16, 24, **kwargs)
+        variables = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+        text = str(jax.make_jaxpr(layer.apply)(variables, x))
+        return re.findall(r"name\[name=(\w+)\]", text)
+
+    assert names() == [GATE_UP]
+    assert names(down_kind=decoder.FFN_OUT) == [GATE_UP, decoder.FFN_OUT]
+    assert decoder.FFN_OUT in decoder.PRODUCT_NAMES
+
+
+def test_several_states_rows_are_the_states_separate_passes():
+    """`shifted_nll` over (S, B, L, d) states against one set of ids is
+    each state's own pass, forward and gradient, and the head's gradient
+    is the sum over the states."""
+    rng = np.random.RandomState(0)
+    states = jnp.asarray(rng.randn(3, 2, 16, 8), jnp.float32)
+    head = jnp.asarray(rng.randn(8, 32), jnp.float32)
+    ids = jnp.asarray(rng.randint(0, 32, (2, 16)), jnp.int32)
+    weights = jnp.asarray(rng.rand(3, 2, 15), jnp.float32)
+
+    def together(states, head):
+        nll = decoder.shifted_nll(states, head, ids, 1, jnp.float32, "ce")
+        return jnp.sum(nll * weights), nll
+
+    def apart(states, head):
+        nll = jnp.stack([
+            decoder.shifted_nll(state, head, ids, 1, jnp.float32, "ce")
+            for state in states
+        ])
+        return jnp.sum(nll * weights), nll
+
+    (_, got), got_grads = jax.value_and_grad(
+        together, argnums=(0, 1), has_aux=True
+    )(states, head)
+    (_, want), want_grads = jax.value_and_grad(
+        apart, argnums=(0, 1), has_aux=True
+    )(states, head)
+    assert got.shape == (3, 2, 15)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for g, w in zip(got_grads, want_grads):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)

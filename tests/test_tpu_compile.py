@@ -46,6 +46,9 @@ SHAPES = [
     pytest.param(20, 20, 256, 4, 4096, None, id="glm-mla"),
     pytest.param(48, 8, 128, 2, 8192, None, id="laguna-full"),
     pytest.param(64, 8, 128, 2, 8192, 512, id="laguna-window"),
+    # the Ouro cell's call, every application of 24: a group of ONE at
+    # heads of 128 (MLA's cores are the only other group-of-one users)
+    pytest.param(16, 16, 128, 1, 8192, None, id="ouro-mha"),
 ]
 # the SmallThinker cell's calls: ONE sequence of 16,384, the last length the
 # rule admits at heads of 128, a group of SEVEN query heads a K/V head, no
@@ -101,6 +104,12 @@ def test_streaming_kernels_compile_for_the_chip(
         one_chip, heads, kv_heads, dim, batch, length, window
     )
     _assert_two_kernels(text, "causal" if window is None else "window")
+    # `causal_attention` takes the streaming kernels at this shape of its
+    # own accord: no silent `blocked_causal_attention`
+    assert fa.stream_shapes_ok(
+        (batch, length, heads, dim), (batch, length, kv_heads, dim),
+        (batch, length, kv_heads, dim),
+    )
     # K/V stay at their own head count: nothing repeated to the query's
     assert f"bf16[{batch},{length},{kv_heads * dim}]" in text
     # (at 16,384 the backward holds 16 MiB of float32 dK and dV scratch
