@@ -365,6 +365,48 @@ def flash_attention(
 # rest of the block, q, k and v among it, but not this call: the forward
 # runs once a step.  Outside a `jax.checkpoint` a name is the identity.
 
+#
+# WHERE THE LOG-SUM-EXP LIES.  The streaming forward saves it LANE-MAJOR,
+# float32 (B, H, 1, L), as the blocked form always has ((B, Hkv, G, L)).
+# The kernels' tile bodies want it a (tile, 1) COLUMN beside the (tile,
+# tile) scores, and until PR 60 the forward wrote that column out as (B, H,
+# L, 1): the chip tiles an array's two minor axes (8, 128), so the array
+# took 128 times its values in HBM, and every rematerialised block of a
+# straight-line step held it from its forward to its backward (537 MB in a
+# Laguna window layer, 235 MB a SmallThinker layer; what a `scan` stacks
+# XLA laid out lane-major by itself, at a relayout copy an application: the
+# Ouro cell's 24).  The row takes 8 times its values by that tiling and the
+# values alone as XLA lays it out (`T(1,128)`: a compile for a described
+# v5e shows it).  The column is turned into the row once a QUERY tile
+# in the forward and back once a query tile in the backward
+# (`_stream_fwd_rows`, `_stream_bwd_rows`: wrappers, the kernels and their
+# tile bodies are as they were and their results the same bits).  Placed by
+# PR 60's chip probe (`scripts/probe_attention_lse.py`, v5e, the two
+# programs alone, bfloat16, five traced calls; ms a call: forward kernel |
+# backward kernel | what the layout costs beside them || MB the saved
+# array takes in HBM as (8, 128) tiles it), at the Ouro cell's call ((1,
+# 8192), 16 | 16 heads), the Laguna cell's window call ((2, 8192), 64 | 8,
+# a band of 512) and the SmallThinker cell's two ((1, 16384), 28 | 4, no
+# band / a band of 4,096):
+#   the column, (B, H, L, 1), the parent's
+#     Ouro 3.746 | 5.382 | 0 || 67      Laguna 7.308 | 8.583 | 0 || 537
+#     SmallThinker 26.647 | 35.999 / 11.680 | 15.465 | 0 || 235
+#   the row, turned in the kernels (this)
+#     Ouro 3.780 | 5.406 | 0 || 4.2     Laguna 7.589 | 9.125 | 0 || 33.6
+#     SmallThinker 26.321 | 35.818 / 11.788 | 15.641 | 0 || 14.7
+#   the column squeezed to (B, H, L) beside the kernels
+#     Ouro 3.746 | 5.382 | 0.16 || 0.5  Laguna 7.308 | 8.583 | 1.53 || 4.2
+#     SmallThinker 26.647 | 35.999 / 11.680 | 15.465 | 0.68 || 2.1
+# (the squeeze and the expand are XLA copies that read or write the PADDED
+# array, 0.06-0.11 ms at 67 MB and 0.71-0.82 at 537: two to four times the
+# turn's cost at every call; and where forward and backward are one
+# straight-line program XLA CANCELS the pair and holds the padded column
+# from kernel to kernel after all, as a compile for a described v5e shows).
+# The turn is one (tile, 128) transpose: 0.14 us a query tile forward, 0.26
+# backward, which a window of two key tiles a query tile feels (+3.8% and
+# +6.3% of Laguna's window kernels) and sixteen do not (+0.9% and +0.4% at
+# Ouro's call).  A Mosaic reshape of the column costs more (3.853 | 5.420
+# and 8.559 | 9.259).
 _BLOCKED_TILE = 512
 SAVED_NAMES = ("attention_core_out", "attention_core_lse")
 
@@ -527,7 +569,8 @@ def _band(window, q) -> Optional[int]:
 # VMEM with the running max, normaliser ((tile, 1) each) and accumulator
 # in scratch, and tiles above the diagonal neither compute nor move (their
 # block index is clamped to the last tile needed, which Pallas does not
-# fetch again).
+# fetch again).  The log-sum-exp leaves a query tile as a (1, tile) row of
+# a (B, H, 1, L) array (the comment above `SAVED_NAMES`).
 #
 # The backward is ONE kernel from the saved log-sum-exp, as the blocked
 # form above but tile by tile.  Its grid is (batch, K/V head, the group's
@@ -794,6 +837,48 @@ def _stream_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
+def _lane_major(column):
+    """A (tile, 1) column as the (1, tile) row of the same values: one
+    (tile, 128) transpose (a Mosaic reshape of the column costs twice as
+    much; the comment above `SAVED_NAMES` has the probe)."""
+    return jnp.broadcast_to(column, (column.shape[0], _LANES)).T[:1]
+
+
+def _sublane_major(row):
+    """A (1, tile) row as the (tile, 1) column of the same values."""
+    return jnp.broadcast_to(row, (_LANES, row.shape[1])).T[:, :1]
+
+
+def _stream_fwd_rows(q_ref, k_ref, v_ref, o_ref, lse_ref, lse_sc, *scratch,
+                     steps: int, **static):
+    """`_stream_fwd_kernel` with its log-sum-exp column caught in scratch
+    (`lse_sc` has the shape of the column block the kernel wrote to, so
+    the kernel indexes it as it did its output) and written LANE-MAJOR, a
+    (1, tile) row once a query tile."""
+    _stream_fwd_kernel(
+        q_ref, k_ref, v_ref, o_ref, lse_sc, *scratch, steps=steps, **static
+    )
+
+    @pl.when(pl.program_id(3) == steps - 1)
+    def _():
+        lse_ref[0, 0] = _lane_major(lse_sc[0, 0])
+
+
+def _stream_bwd_rows(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref, dq_ref,
+                     dk_ref, dv_ref, lse_sc, *scratch, **static):
+    """`_stream_bwd_kernel` over a lane-major log-sum-exp: the row turns
+    into the (tile, 1) column the tile body reads once a query tile, not
+    once a (query tile, key tile) pair."""
+    @pl.when(pl.program_id(3) == 0)
+    def _():
+        lse_sc[0, 0] = _sublane_major(lse_ref[0, 0])
+
+    _stream_bwd_kernel(
+        q_ref, k_ref, v_ref, g_ref, lse_sc, delta_ref, dq_ref, dk_ref,
+        dv_ref, *scratch, **static
+    )
+
+
 def _stream_call(kernel, grid, in_specs, out_specs, out_shape, scratch,
                  operands, name, inner_axes: int = 1,
                  vmem_limit: Optional[int] = None):
@@ -833,7 +918,10 @@ def _stream_specs(tile: int):
     so that a skipped step moves nothing, and `head` the column block (the
     grid's own head unless said).  A head that is no whole lane tile is a
     block of the head-major (B, H, L, D) view, its head axis squeezed: the
-    kernels see (1, rows, D) either way."""
+    kernels see (1, rows, D) either way.  `per_row` is a (tile, 1) column
+    of a float32 (B, H, L, 1) array (the backward's `delta`, which lives
+    one call), `per_lane` a (1, tile) row of a (B, H, 1, L) one (the saved
+    log-sum-exp)."""
     def tiles(dim, which, head=_same_head, rows=tile):
         if dim % _LANES:
             return pl.BlockSpec(
@@ -851,7 +939,13 @@ def _stream_specs(tile: int):
             lambda b, h, x, y: (b, head(h, x, y), which(x, y), 0),
         )
 
-    return tiles, per_row
+    def per_lane(which, head=_same_head):
+        return pl.BlockSpec(
+            (1, 1, 1, tile),
+            lambda b, h, x, y: (b, head(h, x, y), 0, which(x, y)),
+        )
+
+    return tiles, per_row, per_lane
 
 
 def _stream_names(window) -> str:
@@ -914,28 +1008,30 @@ def _stream_fwd(q, k, v, scale, window):
     tile = _stream_tiles(length)
     num = length // tile
     steps = _band_steps(num, tile, window)
-    tiles, per_row = _stream_specs(tile)
+    tiles, _, per_lane = _stream_specs(tile)
     keys, kv_head = _streamed_keys(steps, window), _kv_head(group)
     flat = _stream_shape((batch, length, heads, v_dim))
     # grid (b, h, i over queries, y over the keys i meets)
     out, lse = _stream_call(
         functools.partial(
-            _stream_fwd_kernel, scale=scale, tile=tile, steps=steps,
+            _stream_fwd_rows, scale=scale, tile=tile, steps=steps,
             window=window,
         ),
         (batch, heads, num, steps),
         [tiles(dim, _resident_row), tiles(dim, keys, kv_head),
          tiles(v_dim, keys, kv_head)],
-        [tiles(v_dim, _resident_row), per_row(_resident_row)],
-        [(flat, q.dtype), ((batch, heads, length, 1), jnp.float32)],
-        [pltpu.VMEM((tile, 1), jnp.float32),
+        [tiles(v_dim, _resident_row), per_lane(_resident_row)],
+        [(flat, q.dtype), ((batch, heads, 1, length), jnp.float32)],
+        [pltpu.VMEM((1, 1, tile, 1), jnp.float32),
+         pltpu.VMEM((tile, 1), jnp.float32),
          pltpu.VMEM((tile, 1), jnp.float32),
          pltpu.VMEM((tile, v_dim), jnp.float32)],
         [_stream_view(t) for t in (q, k, v)],
         _stream_names(window) + "_fwd",
     )
     # named as they leave the kernel (module comment of the blocked form):
-    # with the two saved, a block's remat has no use for this call
+    # with the two saved, a block's remat has no use for this call; the
+    # log-sum-exp a (B, H, 1, L) row, 8 times its values in HBM
     out, lse = _named(out, lse)
     out = _stream_unview(out, (batch, length, heads, v_dim))
     return out, (q, k, v, out, lse)
@@ -949,7 +1045,7 @@ def _stream_bwd(scale, window, residuals, g):
     tile = _stream_tiles(length)
     num = length // tile
     steps = _band_steps(num, tile, window)
-    tiles, per_row = _stream_specs(tile)
+    tiles, per_row, per_lane = _stream_specs(tile)
     flat = _stream_shape(q.shape)
     g = g.astype(q.dtype)
     delta = (
@@ -974,18 +1070,19 @@ def _stream_bwd(scale, window, residuals, g):
 
     dq, dk, dv = _stream_call(
         functools.partial(
-            _stream_bwd_kernel, scale=scale, tile=tile, num=num,
+            _stream_bwd_rows, scale=scale, tile=tile, num=num,
             steps=steps, group=group, window=window,
         ),
         (batch, kv_heads, group * num, steps),
         [tiles(dim, row, q_head), tiles(dim, keys), tiles(v_dim, keys),
-         tiles(v_dim, row, q_head), per_row(row, q_head),
+         tiles(v_dim, row, q_head), per_lane(row, q_head),
          per_row(row, q_head)],
         [tiles(dim, row, q_head), tiles(dim, whole, rows=length),
          tiles(v_dim, whole, rows=length)],
         [(flat, q.dtype), (_stream_shape(k.shape), k.dtype),
          (_stream_shape(v.shape), v.dtype)],
-        [pltpu.VMEM((tile, dim), jnp.float32),
+        [pltpu.VMEM((1, 1, tile, 1), jnp.float32),
+         pltpu.VMEM((tile, dim), jnp.float32),
          pltpu.VMEM((length, dim), jnp.float32),
          pltpu.VMEM((length, v_dim), jnp.float32)],
         [_stream_view(t) for t in (q, k, v, g)] + [lse, delta],

@@ -474,11 +474,15 @@ MIXER_IN, Q_UP, KV_UP, MIXER_OUT, GATE_UP, FFN_OUT = PRODUCT_NAMES = (
 
 # `lean_step_bytes`: the share of the bytes a block's traced forward
 # makes that the step holds at once through the block's backward, and
-# the compiled step's own code on the device (79-265 MB in the five
-# cells).  Both are FITTED to the five cells' steps on the chip
-# (`PERF.md` section 6), not derived.
-BLOCK_SHARE = 0.34
-PROGRAM_BYTES = 288 << 20
+# the compiled step's own code on the device (79-265 MB in the cells)
+# with whatever else the estimate does not see.  Both are FITTED, not
+# derived: to the nine decoder cells' LEAN steps read on the chip after
+# PR 60 took the log-sum-exp's padding out of them (`PERF.md` section 6,
+# PR 60, has the row a cell: the estimate errs 0.26e9 high in the Kimi
+# cell, which binds the share, and 0.37-1.97e9 in the others; the share
+# was 0.34 beside 288 MiB while 2.4e9 of padding stood inside Laguna's).
+BLOCK_SHARE = 0.25
+PROGRAM_BYTES = 552 << 20
 
 
 class Product(NamedTuple):
@@ -557,7 +561,9 @@ def _bytes(variables) -> int:
 def tiled_bytes(variables) -> int:
     """`_bytes` as the chip lays the arrays out in HBM: the two minor
     axes in whole tiles of (32 bytes of rows, 128 columns), so that a
-    float32 (B, heads, L, 1) log-sum-exp takes 128 times its values."""
+    float32 (B, heads, L, 1) column takes 128 times its values (what the
+    streaming attention forward saved as its log-sum-exp until PR 60) and
+    the (B, heads, 1, L) row it saves now 8 times them."""
     total = 0
     for v in variables:
         if not hasattr(v.aval, "shape"):
@@ -619,6 +625,18 @@ def block_shapes(block: nn.Module, shape, dtype) -> BlockShapes:
     )
 
 
+def held_trips(trips: int) -> int:
+    """How many trips' worth of what one trip holds from its forward to
+    its backward (saved inputs, SAVED_NAMES, kept products) a step holds
+    at once: every trip's in the loop's stacks and, in a loop, one more,
+    the trip in flight beside its slot in them.  The one looped cell's
+    planned step on the chip holds 2.1e9 beside its lean step and what
+    it keeps (`PERF.md` section 6, PR 60); this term is 1.05e9 of that
+    and the rest stands inside `worker/trainer.py: DEVICE_SHARE`'s tenth,
+    where PR 59 left it."""
+    return trips + (trips > 1)
+
+
 def lean_step_bytes(
     blocks: Sequence[BlockShapes], walks: Sequence[int], x_bytes: int,
     vocab: int, trips: int = 1,
@@ -632,25 +650,26 @@ def lean_step_bytes(
     forward makes and, in a routed block, what the walk's backward holds
     (`walks`, from `layers/moe.py: walk_bytes`).  A stack applied
     `trips` times a step holds every block's saved input and SAVED_NAMES
-    once a TRIP, from that trip's forward to that trip's backward; the
-    working set, the cross-entropy's block and the program stand once.
-    What the chip's tiling adds to SAVED_NAMES (a log-sum-exp is padded
-    128-fold: `BlockShapes.padding`) is inside the fitted constants for
-    ONE application of each block, as the cells they were fitted to hold
-    it, and counted for every further trip (24 applications hold 1.6e9 of
-    it where the fit saw 0.4e9).  A loop over the stack also holds what a
+    once a TRIP, from that trip's forward to that trip's backward, and
+    the trip in flight once more (`held_trips`); the working set, the
+    cross-entropy's block and the program stand once.
+    SAVED_NAMES count at the bytes the chip's tiling gives them (`saved +
+    padding`, `tiled_bytes`) for EVERY application: derived, since PR 60
+    no part of a fit (the attention core's log-sum-exp is a lane-major
+    row, 8 times its values: 4.2 MB an application in the Ouro cell where
+    the column it was took 67).  A loop over the stack also holds what a
     straight-line step does not: the blocks' weights cast to the compute
     type ONCE, ahead of the loop (the compiler hoists what no trip
     changes), and a trip's emitted state and the final norm's saved input
-    (two `x_bytes` a trip).  Fitted so that it errs
-    HIGH against each of the five cells' steps on the chip (`PERF.md`
-    section 6 has a row a cell; section 7 what a user sees where it errs
-    low)."""
+    (two `x_bytes` a trip).  The two constants are fitted so that it
+    errs HIGH against each of the nine decoder cells' lean steps on the
+    chip (`PERF.md` section 6, PR 60, has a row a cell; section 7 what a
+    user sees where it errs low)."""
     return (
-        trips * (
-            len(blocks) * x_bytes + sum(block.saved for block in blocks)
+        held_trips(trips) * (
+            len(blocks) * x_bytes
+            + sum(block.saved + block.padding for block in blocks)
         )
-        + (trips - 1) * sum(block.padding for block in blocks)
         + (trips > 1) * (
             sum(block.cast_weights for block in blocks) + 2 * trips * x_bytes
         )
@@ -678,8 +697,9 @@ def routed_walks(
 
 def remat_block(block_cls, kept: Sequence[str] = ()):
     """`block_cls` rebuilt in the backward but for the attention core's
-    output and log-sum-exp, which stay from the forward (bfloat16 out +
-    float32 lse a layer: 134-268 MB in the cells): the remat rebuilds
+    output and log-sum-exp, which stay from the forward (bfloat16 out and
+    a float32 lane-major lse a layer: 34-268 MB of out and 4-34 MB of lse
+    as the chip tiles it in the cells): the remat rebuilds
     the projections, the rotation and the norms, and the backward
     kernels read what the forward kernel wrote, so that kernel runs once
     a step (`ops/flash_attention.py: SAVED_NAMES`).  What the chunked
@@ -738,7 +758,9 @@ def remat_blocks(
             x.size * x.dtype.itemsize, vocab, trips,
         )
         products = [block.products for block in shapes]
-        kept = kept_products(products, max(0, room - lean), trips)
+        kept = kept_products(
+            products, max(0, room - lean), held_trips(trips)
+        )
         held = sum(
             p.size for block, names in zip(products, kept) for p in block
             if p.name in names

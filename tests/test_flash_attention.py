@@ -2,6 +2,8 @@
 gradients, causal and full, odd shapes.  Off-TPU the SAME kernel runs in
 Pallas interpret mode, so this exercises the real kernel code path."""
 
+import functools
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -507,30 +509,38 @@ def test_half_lane_head_admission_and_names():
 # equations in another order (the commit before gave 1aa8b25f...,
 # f13ce3df..., d3b59eb5..., 30a0a61e... for the first four and
 # b8711448... at the Kimi cell's shape, which had no digest here).
+# RE-RECORDED ON PURPOSE AGAIN by PR 60, which saves the log-sum-exp
+# lane-major: the two kernels run inside the wrappers `_stream_fwd_rows` /
+# `_stream_bwd_rows` (one more scratch each, a transpose once a query tile),
+# their second output and fifth operand are float32 (B, H, 1, L) where they
+# were (B, H, L, 1), and the tile bodies are the same equations (the commit
+# before gave c5ef0d80..., 653ef899..., 8b2c32b9..., 34fc6206...,
+# 16f9c3e0...; `test_the_lane_major_log_sum_exp_keeps_the_parents_bits`
+# holds the results to the parent's bits).
 CELL_JAXPRS = [
     pytest.param(
         (4, 4096, 20, 256), 20, 256, None,
-        "c5ef0d80548c4f837f4c2435c96988a054ae2f6156fab786ca1f029a161e1b69",
+        "1100bf7722163bcdf43b3c698f55c59b9a4e50b4ff9723a8c84f93d98269e1e9",
         id="glm-mla",
     ),
     pytest.param(
         (2, 8192, 48, 128), 8, 128, None,
-        "653ef899f51c2bc04e5bb736c46a2da7909aea00852684029eb1ab9710515609",
+        "54cdad47585364b375d429a8877ce2fa6cb80def94c0905d25776e6e15401e6c",
         id="laguna-full",
     ),
     pytest.param(
         (2, 8192, 64, 128), 8, 128, 512,
-        "8b2c32b9dc6902070a79c8c80c74313649c93e2eb03daac1b3ea458282268ad0",
+        "17457f41ea651c957b36226999608ff1af7b55465ae691f13b26493241b3fe7a",
         id="laguna-window",
     ),
     pytest.param(
         (4, 8192, 32, 64), 8, 64, None,
-        "34fc62060da6b41712dadb0cd6e07b90dafdc6db214562b0b6700e1b222195b6",
+        "77d5ca0047f2bac358148809ef9e7c9b977d17b9619fb9ee49104da9fd4654b4",
         id="lfm2-gqa",
     ),
     pytest.param(
         (2, 8192, 32, 192), 32, 128, None,
-        "16f9c3e0d3a1220675bcc131a39bbb52da8d8ae3c9c11aa0e4ba7cb00d108454",
+        "0a29f40a19651b95d2682c1d34b1f56fee2a5ec8f01b929094128c79fe632e5d",
         id="kimi-mla",
     ),
 ]
@@ -1013,3 +1023,170 @@ def test_streaming_kernels_keep_the_tile_body_to_the_bit(
         )),
         rtol=3e-2, atol=3e-2,
     )
+
+
+# ---- the saved log-sum-exp lane-major, bit for bit (PR 60) ----------------
+#
+# The reference is the PARENT's `_stream_fwd` and `_stream_bwd`: the same two
+# kernels called bare, over a float32 (B, H, L, 1) column that the chip tiles
+# to 128 times its values.  The module now wraps them (`_stream_fwd_rows`,
+# `_stream_bwd_rows`) and saves a (B, H, 1, L) row; the turn between row and
+# column may not change a bit of the output, the log-sum-exp's values or the
+# three gradients.
+
+
+def _column_fwd(fa, q, k, v, scale, window):
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, length, heads, dim = q.shape
+    v_dim, group = v.shape[3], heads // k.shape[2]
+    tile = fa._stream_tiles(length)
+    num = length // tile
+    steps = fa._band_steps(num, tile, window)
+    tiles, per_row, _ = fa._stream_specs(tile)
+    keys, kv_head = fa._streamed_keys(steps, window), fa._kv_head(group)
+    out, lse = fa._stream_call(
+        functools.partial(
+            fa._stream_fwd_kernel, scale=scale, tile=tile, steps=steps,
+            window=window,
+        ),
+        (batch, heads, num, steps),
+        [tiles(dim, fa._resident_row), tiles(dim, keys, kv_head),
+         tiles(v_dim, keys, kv_head)],
+        [tiles(v_dim, fa._resident_row), per_row(fa._resident_row)],
+        [(fa._stream_shape((batch, length, heads, v_dim)), q.dtype),
+         ((batch, heads, length, 1), jnp.float32)],
+        [pltpu.VMEM((tile, 1), jnp.float32),
+         pltpu.VMEM((tile, 1), jnp.float32),
+         pltpu.VMEM((tile, v_dim), jnp.float32)],
+        [fa._stream_view(t) for t in (q, k, v)],
+        fa._stream_names(window) + "_fwd",
+    )
+    return fa._stream_unview(out, (batch, length, heads, v_dim)), lse
+
+
+def _column_bwd(fa, q, k, v, out, lse, g, scale, window):
+    from jax.experimental.pallas import tpu as pltpu
+
+    batch, length, heads, dim = q.shape
+    kv_heads, v_dim = k.shape[2], v.shape[3]
+    group = heads // kv_heads
+    tile = fa._stream_tiles(length)
+    num = length // tile
+    steps = fa._band_steps(num, tile, window)
+    tiles, per_row, _ = fa._stream_specs(tile)
+    g = g.astype(q.dtype)
+    delta = (
+        (g.astype(jnp.float32) * out.astype(jnp.float32)).sum(-1)
+        .transpose(0, 2, 1)[..., None]
+    )
+    streamed = fa._streamed_keys(steps, window)
+
+    def row(x, y):
+        return x % num
+
+    def q_head(h, x, y):
+        return h * group + x // num
+
+    def keys(x, y):
+        return streamed(row(x, y), y)
+
+    def whole(x, y):
+        return 0
+
+    dq, dk, dv = fa._stream_call(
+        functools.partial(
+            fa._stream_bwd_kernel, scale=scale, tile=tile, num=num,
+            steps=steps, group=group, window=window,
+        ),
+        (batch, kv_heads, group * num, steps),
+        [tiles(dim, row, q_head), tiles(dim, keys), tiles(v_dim, keys),
+         tiles(v_dim, row, q_head), per_row(row, q_head),
+         per_row(row, q_head)],
+        [tiles(dim, row, q_head), tiles(dim, whole, rows=length),
+         tiles(v_dim, whole, rows=length)],
+        [(fa._stream_shape(q.shape), q.dtype),
+         (fa._stream_shape(k.shape), k.dtype),
+         (fa._stream_shape(v.shape), v.dtype)],
+        [pltpu.VMEM((tile, dim), jnp.float32),
+         pltpu.VMEM((length, dim), jnp.float32),
+         pltpu.VMEM((length, v_dim), jnp.float32)],
+        [fa._stream_view(t) for t in (q, k, v, g)] + [lse, delta],
+        fa._stream_names(window) + "_dkv", inner_axes=2,
+        vmem_limit=fa._STREAM_VMEM_LIMIT,
+    )
+    return (
+        fa._stream_unview(dq, q.shape), fa._stream_unview(dk, k.shape),
+        fa._stream_unview(dv, v.shape),
+    )
+
+
+# the cells' calls in small, two tiles of 512: a group of 1 (Ouro, the MLA
+# cores), of 7 (SmallThinker) and of 8 (Laguna); a band and none; heads of
+# 64 (head-major), 128 and 256; MLA's keys of 192, padded to 256, over
+# values of 128; two sequences, so that a second batch's rows are their own
+LANE_MAJOR_CASES = [
+    _tile_case(1024, None, 2, 2, 128, 128, None),
+    _tile_case(1024, 700, 7, 1, 128, 128, None),
+    _tile_case(1024, None, 7, 1, 128, 128, None),
+    _tile_case(1024, None, 8, 1, 128, 128, None),
+    _tile_case(1024, 300, 8, 1, 128, 128, None),
+    _tile_case(1024, None, 4, 1, 64, 64, None),
+    _tile_case(1024, 600, 4, 2, 64, 64, None),
+    _tile_case(1024, None, 2, 2, 256, 256, None),
+    _tile_case(1024, 512, 2, 1, 256, 256, None),
+    _tile_case(1024, None, 2, 2, 192, 128, None),
+    _tile_case(1024, None, 2, 2, 128, 128, None, dtype=jnp.float32),
+]
+
+
+@pytest.mark.parametrize(
+    "length, window, heads, kv_heads, dk, dv, scale, dtype", LANE_MAJOR_CASES
+)
+def test_the_lane_major_log_sum_exp_keeps_the_parents_bits(
+    monkeypatch, length, window, heads, kv_heads, dk, dv, scale, dtype
+):
+    """`out`, the log-sum-exp's values, `dq`, `dk` and `dv` of the module's
+    streaming forward and backward, BIT for bit against the same kernels
+    over the parent's (B, H, L, 1) column, both run operation by
+    operation; and what a block holds from forward to backward is the
+    (B, H, 1, L) row: 8 times its values as the chip tiles it, not 128."""
+    from elasticdl_tpu.ops import flash_attention as fa
+    from model_zoo.common.decoder import tiled_bytes
+
+    keys = jax.random.split(jax.random.PRNGKey(heads + dk), 4)
+    q = jax.random.normal(keys[0], (2, length, heads, dk), dtype)
+    k = jax.random.normal(keys[1], (2, length, kv_heads, dk), dtype)
+    v = jax.random.normal(keys[2], (2, length, kv_heads, dv), dtype)
+    g = jax.random.normal(keys[3], (2, length, heads, dv), dtype)
+    assert fa.stream_shapes_ok(q.shape, k.shape, v.shape)
+    _eager_stream_call(monkeypatch)
+    scale, band = float(dk ** -0.5), fa._band(window, q)
+    q, k = fa._padded_keys(q, k)
+
+    out, residuals = fa._stream_fwd(q, k, v, scale, band)
+    lse = residuals[-1]
+    assert lse.shape == (2, heads, 1, length) and lse.dtype == jnp.float32
+    got = (out, lse[:, :, 0], *fa._stream_bwd(scale, band, residuals, g))
+    out, column = _column_fwd(fa, q, k, v, scale, band)
+    assert column.shape == (2, heads, length, 1)
+    want = (
+        out, column[..., 0],
+        *_column_bwd(fa, q, k, v, out, column, g, scale, band),
+    )
+    for name, a, b in zip(("out", "lse", "dq", "dk", "dv"), got, want):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        a, b = (np.asarray(t.astype(jnp.float32)) for t in (a, b))
+        assert np.isfinite(b).all() and np.abs(b).max() > 0, name
+        assert np.array_equal(a, b), (
+            f"{name}: {np.abs(a - b).max()} apart at most, "
+            f"{(a != b).sum()} of {a.size} elements differ"
+        )
+
+    class Value:
+        def __init__(self, array):
+            self.aval = jax.ShapeDtypeStruct(array.shape, array.dtype)
+
+    values = 2 * heads * length * 4
+    assert tiled_bytes([Value(lse)]) == 8 * values
+    assert tiled_bytes([Value(column)]) == 128 * values

@@ -890,6 +890,15 @@ def test_the_layers_scopes_reach_the_lowered_operations():
 # order (2260048): their programs are the parent's.  ISSUE 58 expected the
 # six routed entries re-recorded; PR 58's probe found the walk's scatter-add
 # slow at ONE width, 2,560 columns, which none of these has: all seven STAND.
+# ALL SEVEN RE-RECORDED ON PURPOSE in PR 60: every one of them calls the
+# streaming attention kernels, whose saved log-sum-exp is lane-major now
+# (`ops/flash_attention.py`: the two kernels run inside `_stream_fwd_rows` /
+# `_stream_bwd_rows` and hand a float32 (B, H, 1, L) row where a (B, H, L, 1)
+# column was); nothing else of their programs moved (the commit before gave
+# f75e00cd..., bdb4d28b..., 7aa997f0..., 62c29f19..., bd4a5d63..., e24bc98a...,
+# 10e12632...), and `tests/test_flash_attention.py::
+# test_the_lane_major_log_sum_exp_keeps_the_parents_bits` holds the kernels'
+# results to the parent's bits.
 PARENTS_JAXPRS = {
     "kimi-linear-48b-a3b": (
         "kimi.kimi_linear", (2, 8192),
@@ -897,15 +906,15 @@ PARENTS_JAXPRS = {
         # kernels' bodies, whose substitution changed: `ops/kda.py`) and
         # in PR 56 (the KDA layer's norm a head, output gate and decay
         # over (B, L, heads x dim): `model_zoo/kimi/kimi_linear.py`)
-        "f75e00cd4eb96059c3496d4e509089dd659ecd545698d307d58e0fcdcb1fab19",
+        "8da1426065a2c7e0e6f031ac7ff933b0464db98b9c16d995f325ad71e58ed55c",
     ),
     "nemotron-3-nano-30b-a3b": (
         "nemotron.nemotron_h", (2, 8192),
-        "bdb4d28beba0ee0031e7c8f7e6ff50ba917e5c141e81b47544ef0a04abcbc08a",
+        "7823daedbce08788845e0c4ac65acbc24b74331cb9862a589a5df3e0032e20d9",
     ),
     "glm-4.7-flash": (
         "glm.glm_moe_lite", (4, 4096),
-        "7aa997f0621489a8b3c6f10b44ee4eb219be6ba5753671e23feb463bf198923a",
+        "690fd4a6bff0ccf26f4b2a1bb02a0bd773d88a0a42a9a1da063b3a229393a205",
     ),
     # the four below recorded at the commit before `RoutedExperts` and
     # `MoEFFN` learnt the routing's source, `FORMS` ReGLU and
@@ -913,19 +922,19 @@ PARENTS_JAXPRS = {
     # what they read here
     "laguna-xs.2": (
         "laguna.laguna", (2, 8192),
-        "62c29f19d085a8632df6376c347f2b8045af67d8d83f6b5f6c7b5e3930671e3d",
+        "0287421fb22fe1f15611bddd7d45a75b1c5812f733b5aee16af303e3527fc809",
     ),
     "lfm2-24b-a2b": (
         "lfm2.lfm2_moe", (4, 8192),
-        "bd4a5d633f33cfdd2c9e314666a69a5d9f665dbb45bcd4b4533481a58293b2d4",
+        "43cd4ec71e3658533b4797cb3f6c94d6fb47e724950210771b6c37c873063cbe",
     ),
     "qwen3-next-80b-a3b": (
         "qwen3_next.qwen3_next", (2, 8192),
-        "e24bc98a692d20739c6fb0bec254c394c64b733d8daf72c209298a7c6753a5d5",
+        "e8401b42fbb8be29e958731082aa0b057e99718eb18578227469d0481a6ec794",
     ),
     "granite-4.0-h-micro": (
         "granite.granite_hybrid", (1, 8192),
-        "10e126326fa9f236e820a2453dde187d8eb5fd188a3cb0507944228da5ef79e5",
+        "b223f00c88f729f2fcdbd01da8e34fe12b8ef15f5df9d79a0174cf12ae790b8c",
     ),
 }
 

@@ -3,14 +3,16 @@
 reads off a block's traced forward (`block_shapes`), the room the
 Trainer reads from the device once the state is placed
 (`worker/trainer.py: device_room`) and hands the step as static data,
-the estimate under it (`lean_step_bytes`) against the five decoder
-cells' steps on the chip, and the program with and without room."""
+the estimate under it (`lean_step_bytes`) against the nine decoder
+cells' lean steps on the chip, what the chip's tiling adds to the saved
+names in each, and the program with and without room."""
 
 import importlib
 import json
 import os
 import random
 import re
+from typing import NamedTuple, Sequence, Tuple
 
 import flax.linen as nn
 import jax
@@ -266,23 +268,52 @@ def test_remat_blocks_plans_only_with_a_room():
 # ---- the estimate against the cells' steps on the chip --------------------
 
 # (configuration, traffic, the chip's `memory_peak_bytes` of the lean
-# step: PERF.md section 5, ledger PR 46)
+# step: my chip runs, PR 60, the nine cells with no room given, AFTER the
+# streaming forward's log-sum-exp went lane-major; PERF.md section 6.  The
+# five PR 47 fitted to read 11.301, 12.300, 14.292, 15.324 and 15.753e9
+# with the padded column in them)
 CELLS = [
-    pytest.param("granite-4.0-h-micro", "train-l8192-b1", 11.301e9,
+    pytest.param("granite-4.0-h-micro", "train-l8192-b1", 11.168e9,
                  id="granite"),
-    pytest.param("lfm2-24b-a2b", "train-l8192-b4", 12.300e9, id="lfm2"),
-    pytest.param("glm-4.7-flash", "train-l4096", 14.292e9, id="glm"),
-    pytest.param("kimi-linear-48b-a3b", "train-l8192-b2", 15.324e9,
+    pytest.param("lfm2-24b-a2b", "train-l8192-b4", 11.775e9, id="lfm2"),
+    pytest.param("glm-4.7-flash", "train-l4096", 13.296e9, id="glm"),
+    pytest.param("kimi-linear-48b-a3b", "train-l8192-b2", 14.981e9,
                  id="kimi"),
-    pytest.param("laguna-xs.2", "train-l8192", 15.753e9, id="laguna"),
+    pytest.param("laguna-xs.2", "train-l8192", 13.925e9, id="laguna"),
+    pytest.param("nemotron-3-nano-30b-a3b", "train-l8192-b2-v16k", 13.531e9,
+                 id="nemotron"),
+    pytest.param("qwen3-next-80b-a3b", "train-l8192-b2-v18992", 13.064e9,
+                 id="qwen3-next"),
+    pytest.param("smallthinker-21b-a3b", "train-l16384-b1-v18992", 8.413e9,
+                 id="smallthinker"),
+    pytest.param("ouro-2.6b", "train-l8192-b1-v49152", 11.217e9, id="ouro"),
 ]
 
 
-def cell_plan(config_name, traffic_name, monkeypatch):
+class Value:
+    """What `tiled_bytes` reads of a jaxpr's variable."""
+
+    def __init__(self, shape, dtype):
+        self.aval = jax.ShapeDtypeStruct(shape, dtype)
+
+
+class CellPlan(NamedTuple):
+    state: int
+    lean: int
+    kept: float
+    named: int
+    blocks: Sequence[decoder.BlockShapes]
+    saved: Sequence[Tuple[Tuple[int, ...], object]]
+    ids: Tuple[int, int]
+
+
+def cell_plan(config_name, traffic_name, monkeypatch) -> CellPlan:
     """(state bytes: float32 parameters and Adam's two moments; the lean
-    estimate; the share of the named bytes kept; the named bytes) of a
-    cell's forward traced at its real shapes with the room a chip holding
-    that state would report.  No array is made and nothing is compiled."""
+    estimate; the share of the named bytes kept; the named bytes; each
+    block's `BlockShapes`; the shape and type of every SAVED_NAMES value
+    the blocks' traces met; the batch's (B, L)) of a cell's forward traced
+    at its real shapes with the room a chip holding that state would
+    report.  No array is made and nothing is compiled."""
     def load(kind, name):
         path = os.path.join(ROOT, "benchmarks", kind, name + ".json")
         with open(path) as handle:
@@ -302,12 +333,21 @@ def cell_plan(config_name, traffic_name, monkeypatch):
     state = 12 * sum(
         leaf.size for leaf in jax.tree.leaves(variables["params"])
     )
-    seen = {}
+    seen = {"saved": []}
     estimate, rule = decoder.lean_step_bytes, decoder.kept_products
+    tiled, shapes_of = decoder.tiled_bytes, decoder.block_shapes
+    shapes_of.cache_clear()
 
-    def recording_estimate(*args):
-        seen["lean"] = estimate(*args)
+    def recording_estimate(blocks, *args):
+        seen["lean"], seen["blocks"] = estimate(blocks, *args), blocks
         return seen["lean"]
+
+    def recording_tiling(variables):
+        # `block_shapes` asks for the tiled bytes of each SAVED_NAMES value
+        seen["saved"] += [(v.aval.shape, v.aval.dtype) for v in variables]
+        return tiled(variables)
+
+    monkeypatch.setattr(decoder, "tiled_bytes", recording_tiling)
 
     def recording_rule(blocks, budget, *trips):
         seen["named"] = sum(p.size for block in blocks for p in block)
@@ -327,7 +367,11 @@ def cell_plan(config_name, traffic_name, monkeypatch):
         ),
         variables, ids,
     )
-    return state, seen["lean"], kept_ratio(), seen["named"]
+    shapes_of.cache_clear()
+    return CellPlan(
+        state, seen["lean"], kept_ratio(), seen["named"], seen["blocks"],
+        seen["saved"], ids.shape,
+    )
 
 
 @pytest.mark.parametrize("config_name, traffic_name, chip_peak", CELLS)
@@ -335,17 +379,82 @@ def test_the_lean_estimate_errs_high_in_every_cell(config_name, traffic_name,
                                                    chip_peak, monkeypatch):
     state, lean, kept, named = cell_plan(
         config_name, traffic_name, monkeypatch
-    )
-    assert state + lean > chip_peak
-    # and not so high that the rule is idle where there is room
-    assert state + lean < chip_peak + 1.7e9
-    if config_name.startswith(("kimi", "laguna", "glm")):
-        assert state + lean > 0.9 * CHIP_LIMIT and kept == 0
+    )[:4]
+    assert state + lean > chip_peak + 0.2e9
+    # and not so high that the rule is idle where there is room (the
+    # routed cells with an attention mixer read 1.6-1.9e9 high: their
+    # blocks hold a smaller share of what they make than the scan mixers'
+    # that bind the fit, PERF.md section 7)
+    assert state + lean < chip_peak + 2.0e9
+    if config_name.startswith(("kimi", "laguna")):
+        # Kimi has 0.17e9 under the plan's line and is given nothing;
+        # Laguna has 1.2e9 there since PR 60 and the estimate, 1.6e9 high,
+        # still does not see it
+        # (Kimi's estimate stands within one `ROOM_GRAIN` of the line:
+        # what is left of the room in whole grains holds no product)
+        assert kept == 0
+        assert state + lean > 0.9 * CHIP_LIMIT - trainer_lib.ROOM_GRAIN
+    if config_name.startswith("laguna"):
+        assert state + lean > 0.9 * CHIP_LIMIT
+    if config_name.startswith("glm"):
+        assert state + lean < 0.9 * CHIP_LIMIT and 0 < kept <= 0.1
     if config_name.startswith("granite"):
-        assert 0.6 <= kept <= 0.9
+        assert 0.7 <= kept <= 0.9
         assert state + lean + kept * named <= 0.9 * CHIP_LIMIT
     if config_name.startswith("lfm2"):
-        assert 0.2 <= kept <= 0.6
+        assert 0.35 <= kept <= 0.6
+    if config_name.startswith("qwen3"):
+        assert 0.5 <= kept <= 0.7
+    if config_name.startswith("nemotron"):
+        assert 0.45 <= kept <= 0.6
+    if config_name.startswith("smallthinker"):
+        assert kept == 1.0
+    if config_name.startswith("ouro"):
+        # four trips' worth of what is kept and the trip in flight
+        # (`held_trips`): the plan PR 59's cell ran with
+        assert 0.28 <= kept <= 0.31
+        assert state + lean + 5 * kept * named <= 0.9 * CHIP_LIMIT
+
+
+@pytest.mark.parametrize("config_name, traffic_name, chip_peak", CELLS)
+def test_a_cells_log_sum_exp_is_padded_eightfold_at_most(
+    config_name, traffic_name, chip_peak, monkeypatch
+):
+    """What a cell's attention blocks hold under `attention_core_lse` is
+    the streaming forward's lane-major (B, heads, 1, L) row: the chip's
+    tiling adds at most seven times its values (it added 127 times them
+    to the (B, heads, L, 1) column before PR 60), and a block that saves
+    nothing but the attention core's two names has no other padding."""
+    plan = cell_plan(config_name, traffic_name, monkeypatch)
+    batch, length = plan.ids
+    rows = [
+        shape for shape, dtype in plan.saved
+        if dtype == jnp.float32 and len(shape) == 4
+        and (shape[0], shape[2], shape[3]) == (batch, 1, length)
+    ]
+    assert rows, plan.saved
+    assert not [
+        shape for shape, _ in plan.saved
+        if len(shape) == 4 and shape[-1] == 1 and shape[-2] == length
+    ]
+    for shape in rows:
+        values = batch * shape[1] * length * 4
+        assert decoder.tiled_bytes(
+            [Value(shape, jnp.float32)]
+        ) - values <= 7 * values
+    # the blocks whose every saved value is the attention core's (out,
+    # bfloat16 and whole tiles, and the row): Ouro's, Laguna's, GLM's,
+    # SmallThinker's and LFM2's attention blocks
+    heads = sorted({shape[1] for shape in rows})
+    cores = [
+        block for block in plan.blocks
+        if any(block.padding == 7 * batch * h * length * 4 for h in heads)
+    ]
+    if config_name.startswith(("ouro", "laguna", "glm", "smallthinker")):
+        assert len(cores) == len(plan.blocks)
+    if config_name.startswith("ouro"):
+        # 3.67 MB an application where the column's padding was 67 MB
+        assert all(block.padding < 4.2e6 for block in plan.blocks)
 
 
 # ---- the traced step ------------------------------------------------------
@@ -355,7 +464,11 @@ ZOOS = ["granite_hybrid", "laguna", "lfm2", "kimi_linear"]
 # (bfloat16, remat) at the commit BEFORE the blocks' products had names
 # (198d98a): with no room the step lowers to that commit's program.
 # Kimi's is PR 56's, whose KDA layer takes its norm a head, its output gate
-# and its decay over (B, L, heads x dim): the lean program of that layer
+# and its decay over (B, L, heads x dim): the lean program of that layer.
+# PR 60 (the streaming attention forward's log-sum-exp lane-major) re-recorded
+# none of the four: the test models' heads of 16 take the blocked `lax` form,
+# whose log-sum-exp was lane-major already; the CELLS' programs moved and
+# are re-recorded in `tests/test_qwen3_next.py` and `tests/test_nemotron_h.py`
 PARENT_DIGESTS = {
     "granite_hybrid":
         "9780f560a54164b1007f1767af322f0b3560e5ebb1922af1c0650514f78c7ddd",
@@ -547,7 +660,8 @@ def test_one_trip_is_the_plan_it_was(budget):
     assert decoder.lean_step_bytes(shapes, [0, 5], 10, 8, 1) == (
         decoder.lean_step_bytes(shapes, [0, 5], 10, 8)
     ) == (
-        2 * 10 + 160 + int(decoder.BLOCK_SHARE * 3000) + 5
+        # saved inputs, SAVED_NAMES and what the tiling adds to them
+        2 * 10 + 160 + 7 + int(decoder.BLOCK_SHARE * 3000) + 5
         + 3 * decoder.CE_BLOCK * 8 * 4 + decoder.PROGRAM_BYTES
     )
 
@@ -558,12 +672,16 @@ def test_four_trips_hold_four_of_every_per_block_term_and_one_of_the_rest():
         decoder.BlockShapes(BLOCKS[1], 60, 3000, padding=7, cast_weights=9),
     ]
     once = decoder.lean_step_bytes(shapes, [0, 5], 10, 8)
-    per_block = 2 * 10 + 160               # saved inputs and SAVED_NAMES
+    # saved inputs and SAVED_NAMES as the chip tiles them (PR 60: derived
+    # for EVERY application, the first trip's no longer inside a fit)
+    per_block = 2 * 10 + 160 + 11 + 7
+    assert decoder.held_trips(1) == 1 and decoder.held_trips(4) == 5
     assert decoder.lean_step_bytes(shapes, [0, 5], 10, 8, 4) == (
-        once + 3 * per_block
-        # the further trips' tiling of SAVED_NAMES, the loop's hoisted
-        # casts of the weights and its two stacks of states
-        + 3 * (11 + 7) + 9 + 2 * 4 * 10
+        # four trips' worth in the loop's stacks and the trip in flight
+        once + 4 * per_block
+        # the loop's hoisted casts of the weights and its two stacks of
+        # states
+        + 9 + 2 * 4 * 10
     )
     # the working set, the cross-entropy's block and the program: once
     assert once - per_block == (
@@ -599,10 +717,11 @@ def test_remat_blocks_plans_over_the_trips():
     )
     assert estimate == decoder.lean_step_bytes(
         [shapes, shapes], [0, 0], x.size * 4, 8
-    ) + 3 * 2 * x.size * 4 + 2 * 4 * x.size * 4
+    ) + 4 * 2 * x.size * 4 + 2 * 4 * x.size * 4
     out = decoder.remat_block(ToyBlock, (MIXER_OUT,))
     lean = decoder.remat_block(ToyBlock)
-    room = estimate + 4 * 2 * TOKENS * 32 * 4
+    # a kept product is charged four trips' worth and the trip in flight
+    room = estimate + 5 * 2 * TOKENS * 32 * 4
 
     def classes(room, trips):
         return decoder.remat_blocks(
@@ -619,14 +738,19 @@ def test_remat_blocks_plans_over_the_trips():
 
 
 def test_the_tiling_pads_a_log_sum_exp_128_fold():
-    class Value:
-        def __init__(self, shape, dtype):
-            self.aval = jax.ShapeDtypeStruct(shape, dtype)
-
     lse = Value((1, 16, 8192, 1), jnp.float32)
     out = Value((1, 8192, 16, 128), jnp.bfloat16)
     assert decoder.tiled_bytes([lse]) == 128 * 16 * 8192 * 4
     assert decoder.tiled_bytes([out]) == 8192 * 16 * 128 * 2
+    # the same values lane-major, as the streaming forward saves them
+    # since PR 60 (one row of eight in a tile) and as the blocked form
+    # always has (heads on the rows: nothing added)
+    assert decoder.tiled_bytes([Value((1, 16, 1, 8192), jnp.float32)]) == (
+        8 * 16 * 8192 * 4
+    )
+    assert decoder.tiled_bytes([Value((1, 16, 8192), jnp.float32)]) == (
+        16 * 8192 * 4
+    )
     # bfloat16 rows go 16 to a tile, float32 rows 8
     assert decoder.tiled_bytes([Value((3, 130), jnp.bfloat16)]) == (
         16 * 256 * 2

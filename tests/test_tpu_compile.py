@@ -555,8 +555,10 @@ def test_smallthinker_cell_step_compiles_for_the_chip(one_chip, monkeypatch):
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     # `eight_layers`: 4.447e9 + 5.265e9 = 9.712e9 at four layers before PR
-    # 58, 4.447e9 + 5.244e9 = 9.691e9 with the wider carries
-    assert 9.2e9 < held < 10.2e9, held
+    # 58, 4.447e9 + 5.244e9 = 9.691e9 with the wider carries, 4.447e9 +
+    # 4.397e9 = 8.844e9 since PR 60 (four layers' log-sum-exp a lane-major
+    # row of 14.7 MB as the chip tiles it where the column took 235)
+    assert 8.4e9 < held < 9.3e9, held
 
 
 def _whole_arrays_off_the_channels(text, size=2 * 8192 * 4096):
