@@ -10,7 +10,7 @@ arXiv:2412.19437 section 2.2, which this family follows).
 
 The layer equations are written out in `benchmarks/reference/
 glm_moe_lite.py`, the plain float32 reference this model is held to leaf
-by leaf (tests/test_glm_moe_lite.py).  The attention layer is
+by leaf (tests/decoder_cases.py).  The attention layer is
 `model_zoo/common/mla.py: MLA` (a low-rank query with its norm, rotary on
 the 64-column part, scopes `glm/mla/*`), shared with the zoo's other
 latent-attention decoder.

@@ -17,7 +17,7 @@ MLP; four scalar multipliers; the head is the token embedding read again.
 
 The layer equations are written out in `benchmarks/reference/
 granite_hybrid.py`, the plain float32 reference this model is held to
-leaf by leaf (tests/test_granite_hybrid.py), its Mamba-2 the token-by-token
+leaf by leaf (tests/decoder_cases.py), its Mamba-2 the token-by-token
 recurrence.  What it shares with the zoo's other decoders (norms, SwiGLU,
 the blocked cross-entropy, the gated norm) is `model_zoo/common/
 decoder.py`; the Mamba-2 mixer, which `model_zoo/nemotron/nemotron_h.py`

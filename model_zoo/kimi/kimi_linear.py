@@ -17,7 +17,7 @@ expert; the head is its own matrix.
 
 The layer equations are written out in `benchmarks/reference/
 kimi_linear.py`, the plain float32 reference this model is held to leaf
-by leaf (tests/test_kimi_linear.py), its KDA the token-by-token
+by leaf (tests/decoder_cases.py), its KDA the token-by-token
 recurrence.  What it shares with the zoo's other decoders (norms, SwiGLU,
 the routed block, the blocked cross-entropy, MLA) is `model_zoo/common/`;
 the scan is `ops/kda.py: kda`, the convolution `ops/short_conv.py:

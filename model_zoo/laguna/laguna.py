@@ -11,7 +11,7 @@ renormalised weights times 2.5) beside one shared expert.
 
 The layer equations are written out in `benchmarks/reference/laguna.py`,
 the plain float32 reference this model is held to leaf by leaf
-(tests/test_laguna.py).  What it shares with the zoo's other decoder
+(tests/decoder_cases.py).  What it shares with the zoo's other decoder
 (norms, rotary's turn, SwiGLU, the routed block, the blocked
 cross-entropy) is `model_zoo/common/decoder.py`.
 

@@ -14,7 +14,7 @@ is the token embedding read again.
 
 The layer equations are written out in `benchmarks/reference/
 lfm2_moe.py`, the plain float32 reference this model is held to leaf by
-leaf (tests/test_lfm2.py).  What it shares with the zoo's other decoders
+leaf (tests/decoder_cases.py).  What it shares with the zoo's other decoders
 (norms, rotary, SwiGLU, the routed block, the blocked cross-entropy) is
 `model_zoo/common/decoder.py`; the convolution's pass is
 `ops/short_conv.py: gated_short_conv`, attention's core

@@ -20,7 +20,7 @@ knows no positions); the head is untied.
 
 The layer equations are written out in `benchmarks/reference/
 nemotron_h.py`, the plain float32 reference this model is held to leaf by
-leaf (tests/test_nemotron_h.py), its Mamba-2 the token-by-token recurrence
+leaf (tests/decoder_cases.py), its Mamba-2 the token-by-token recurrence
 with B and C by group.  The Mamba-2 mixer is `model_zoo/common/mamba.py`
 (Granite's, at eight groups); norms, attention, the routed block with its
 shared expert, the blocked cross-entropy and the blocks' remat are

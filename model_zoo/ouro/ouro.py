@@ -38,7 +38,7 @@ so `decoder.loss` and the Trainer stay as they are, and the eval
 loss.  With `trips` 1 there is no gate and no entropy: a plain decoder.
 
 The equations are written out in `benchmarks/reference/ouro.py`, the plain
-float32 reference this model is held to leaf by leaf (tests/test_ouro.py).
+float32 reference this model is held to leaf by leaf (tests/decoder_cases.py).
 What it shares with the zoo's other decoders (norms, rotary's turn,
 grouped attention, SwiGLU, the blocked cross-entropy, the blocks' remat
 and its plan over `trips`) is `model_zoo/common/decoder.py`.

@@ -25,7 +25,7 @@ matrix.
 
 The layer equations are written out in `benchmarks/reference/
 qwen3_next.py`, the plain float32 reference this model is held to leaf by
-leaf (tests/test_qwen3_next.py), its delta rule the token-by-token
+leaf (tests/decoder_cases.py), its delta rule the token-by-token
 recurrence.  What it shares with the zoo's other decoders (norms, the
 gated output norm, grouped attention, the routed block, the blocked
 cross-entropy, the blocks' remat) is `model_zoo/common/decoder.py`; the

@@ -18,7 +18,7 @@ shared expert, no dense layer; the head is its own matrix.
 
 The layer equations are written out in `benchmarks/reference/
 smallthinker.py`, the plain float32 reference this model is held to leaf
-by leaf (tests/test_smallthinker.py).  What it shares with the zoo's other
+by leaf (tests/decoder_cases.py).  What it shares with the zoo's other
 decoders (norms, rotary's turn, grouped attention, the routed block, the
 blocked cross-entropy, the blocks' remat) is `model_zoo/common/
 decoder.py`.  The softmax over the chosen six is `layers/moe.py`'s

@@ -38,7 +38,11 @@ def _drop_compiled_programs_after_each_module():
     worth of them reaches the kernel's limit on memory maps
     (`vm.max_map_count`, 65,530: a worker read 56,740 at the end of a
     whole run, and one over the limit dies of a segmentation fault inside
-    the next compile).  No module reuses another's programs."""
+    the next compile).  No module reuses another's programs: the decoder
+    models' shared cases (`tests/decoder_cases.py`) are collected in each
+    model's own module, and what they build once (`seeded`, `computed`)
+    is module-scoped, so one model's programs are dropped here before the
+    next model's are built."""
     yield
     import jax
 

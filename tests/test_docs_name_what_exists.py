@@ -15,7 +15,10 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # flags of tools that live outside the tree, as the documents use them
-FOREIGN_FLAGS = {"--xla_force_host_platform_device_count"}  # XLA's
+FOREIGN_FLAGS = {
+    "--xla_force_host_platform_device_count",          # XLA's
+    "--dist", "--junitxml", "--durations",             # pytest's, xdist's
+}
 
 _FENCE = re.compile(r"```.*?\n(.*?)```", re.S)
 _SPAN = re.compile(r"`([^`]+)`")

@@ -54,11 +54,20 @@ def inputs(batch, length, key_heads, ratio, dim, g_min, seed=0,
     )
 
 
-def value_and_grads(fn, q, k, v, g, beta, weight):
+def out_and_grads(fn, q, k, v, g, beta, weight):
+    """(fn's output, the gradients of a weighted sum of it by the five
+    operands) from ONE compiled program: walked a primitive at a time, an
+    interpreted kernel's forward ran twice a test and its backward once,
+    each an equation at a time."""
+    def weighted(*operands):
+        out = fn(*operands)
+        return (out * weight).sum(), out
+
     with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(
-            lambda *a: (fn(*a) * weight).sum(), argnums=(0, 1, 2, 3, 4)
-        )(q, k, v, g, beta)
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            weighted, argnums=(0, 1, 2, 3, 4), has_aux=True
+        ))(q, k, v, g, beta)
+    return out, grads
 
 
 def assert_close(got, want, limit, what):
@@ -91,12 +100,10 @@ def test_chunked_forms_match_the_recurrence(form, ratio, g_min):
     of a weighted sum of it by q, k, v, g and beta."""
     args = inputs(1, 128, 2, ratio, 128, g_min)
     assert gdn_ops.gdn_shapes_ok(*(a.shape for a in args[:3]))
-    want_out = recurrence(*args[:5])
-    out = form(*args[:5])
+    want_out, want = out_and_grads(recurrence, *args)
+    out, got = out_and_grads(form, *args)
     assert np.isfinite(np.asarray(out)).all()
     assert_close(out, want_out, 5e-5, "o")
-    _, want = value_and_grads(recurrence, *args)
-    _, got = value_and_grads(form, *args)
     for name, a, b in zip(NAMES, got, want):
         assert a.shape == b.shape and np.isfinite(np.asarray(a)).all()
         assert_close(a, b, 2e-4, name)
@@ -108,11 +115,10 @@ def test_the_entry_pads_a_length_that_is_no_whole_chunk(ratio):
     leave the state alone; the outputs and gradients are the first 80's."""
     args = inputs(2, 80, 2, ratio, 16, -2.0, seed=1)
     assert not gdn_ops.gdn_shapes_ok(*(a.shape for a in args[:3]))
-    out = gdn_ops.gdn(*args[:5])
+    want_out, want = out_and_grads(recurrence, *args)
+    out, got = out_and_grads(gdn_ops.gdn, *args)
     assert out.shape == (2, 80, 2 * ratio, 16)
-    assert_close(out, recurrence(*args[:5]), 5e-5, "o")
-    _, want = value_and_grads(recurrence, *args)
-    _, got = value_and_grads(gdn_ops.gdn, *args)
+    assert_close(out, want_out, 5e-5, "o")
     for name, a, b in zip(NAMES, got, want):
         assert_close(a, b, 2e-4, name)
     # a chunk of another size is the same number
@@ -139,9 +145,9 @@ def test_the_l2_norms_inside_the_op(form):
     def inside(q, k, v, g, beta):
         return form(q, k, v, g, beta, norm)
 
-    assert_close(inside(q, k, v, g, beta), plain(q, k, v, g, beta), 5e-5, "o")
-    _, want = value_and_grads(plain, q, k, v, g, beta, weight)
-    _, got = value_and_grads(inside, q, k, v, g, beta, weight)
+    want_out, want = out_and_grads(plain, q, k, v, g, beta, weight)
+    out, got = out_and_grads(inside, q, k, v, g, beta, weight)
+    assert_close(out, want_out, 5e-5, "o")
     for name, a, b in zip(NAMES, got, want):
         assert_close(a, b, 2e-4, name)
 
@@ -150,20 +156,19 @@ def test_the_scalar_op_is_kda_with_g_broadcast_over_the_channels():
     """What ties the new case to the old: one decay a head, handed to
     `ops/kda.py` as 128 equal channels with q and k repeated to the value
     heads, is the same output and the same gradients (dg the sum over the
-    channels)."""
-    q, k, v, g, beta, weight = inputs(1, 128, 2, 2, 128, -1.0, seed=7)
+    channels).  (ONE key head under two value heads: the second key head
+    of the first writing ran the same two kernels over twice the heads,
+    and its compile was the longest of this file's tests.)"""
+    q, k, v, g, beta, weight = inputs(1, 128, 1, 2, 128, -1.0, seed=7)
 
     def through_kda(q, k, v, g, beta):
         q, k = (jnp.repeat(t, 2, axis=2) for t in (q, k))
         wide = jnp.broadcast_to(g[..., None], (*g.shape, q.shape[-1]))
         return kda_ops.kda(q, k, v, wide, beta)
 
-    assert_close(
-        gdn_ops.gdn(q, k, v, g, beta), through_kda(q, k, v, g, beta), 5e-5,
-        "o",
-    )
-    _, want = value_and_grads(through_kda, q, k, v, g, beta, weight)
-    _, got = value_and_grads(gdn_ops.gdn, q, k, v, g, beta, weight)
+    want_out, want = out_and_grads(through_kda, q, k, v, g, beta, weight)
+    out, got = out_and_grads(gdn_ops.gdn, q, k, v, g, beta, weight)
+    assert_close(out, want_out, 5e-5, "o")
     for name, a, b in zip(NAMES, got, want):
         assert_close(a, b, 2e-4, name)
 

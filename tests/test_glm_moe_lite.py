@@ -7,23 +7,19 @@ one compile across loads, the chunked walk of the sorted buffer against
 the whole-buffer form at every kind of load, and a two-task job through
 the CLI."""
 
-import functools
-import os
-import threading
-import types
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import datagen, trees
+from benchmarks import trees
 from benchmarks.reference import glm_moe_lite as reference
 from elasticdl_tpu.layers import moe
 from elasticdl_tpu.layers.moe import ROUTER_STATE, RoutedExperts
 from elasticdl_tpu.layers.step_metrics import AUX_LOSS, STEP_METRICS
 from model_zoo.glm import glm_moe_lite as zoo
-from tests import remat_cases
+from tests import decoder_cases
+from tests.decoder_cases import computed, seeded  # noqa: F401
 
 CONFIG = dict(
     hidden_size=32, num_hidden_layers=3, first_k_dense_replace=1,
@@ -32,144 +28,55 @@ CONFIG = dict(
     intermediate_size=48, moe_intermediate_size=16,
     n_routed_experts_published=8, num_experts_per_tok=2,
     n_shared_experts=1, held_experts=[2, 3], routed_scaling_factor=1.8,
-    vocab_size=50, num_nextn_predict_layers=1, mtp_loss_weight=0.3,
-    rope_theta=1e6, rms_norm_eps=1e-5, use_bf16=True,
+    bias_update_rate=0.0, vocab_size=50, num_nextn_predict_layers=1,
+    mtp_loss_weight=0.3, rope_theta=1e6, rms_norm_eps=1e-5,
+    learning_rate=1e-3, use_bf16=True,
 )
-MUTABLE = [AUX_LOSS, STEP_METRICS, ROUTER_STATE]
 
 
-def model_of(config, **overrides):
-    sizes = dict(
-        hidden=config["hidden_size"], num_layers=config["num_hidden_layers"],
-        dense_layers=config["first_k_dense_replace"],
-        heads=config["num_attention_heads"],
-        q_lora_rank=config["q_lora_rank"],
-        kv_lora_rank=config["kv_lora_rank"],
-        qk_nope_head_dim=config["qk_nope_head_dim"],
-        qk_rope_head_dim=config["qk_rope_head_dim"],
-        v_head_dim=config["v_head_dim"],
-        dense_width=config["intermediate_size"],
-        expert_width=config["moe_intermediate_size"],
-        num_experts=config["n_routed_experts_published"],
-        top_k=config["num_experts_per_tok"],
-        held_experts=config["held_experts"],
-        vocab_size=config["vocab_size"],
-        mtp_layers=config["num_nextn_predict_layers"], remat=True,
+def the_mtp_loss_is_added(metrics, state, loss, seeded):
+    assert AUX_LOSS not in state.model_state
+    sown = state.model_state[STEP_METRICS]
+    assert float(loss) == pytest.approx(
+        float(sown["main_loss"]) + 0.3 * float(sown["mtp_loss"]), rel=1e-5
     )
-    sizes.update(overrides)
-    return zoo.custom_model(**sizes)
+    assert metrics["layer_1/moe/routed/dropped_tokens"] == 0.0
+    assert 0.0 < metrics["layer_1/moe/routed/routed_here_ratio"] < 1.0
+    # a buffer no longer than one chunk: one trip, the whole of it
+    assert metrics["layer_1/moe/routed/live_chunks_ratio"] == 1.0
+    assert metrics["mtp_block/moe/routed/expert_load_imbalance_ratio"] >= 1.0
 
 
-def ids_of(rows, length=16, seed=0):
-    return np.random.RandomState(seed).randint(
-        0, CONFIG["vocab_size"], (rows, length)
-    ).astype(np.int32)
+def job_gauges(registry):
+    assert registry.value("worker_moe_dropped_tokens_total") == 0.0
+    assert 0.0 < registry.value(
+        "worker_moe_routed_here_ratio", layer="layer_1/moe/routed"
+    ) < 1.0
+    assert registry.value(
+        "worker_moe_live_chunks_ratio", layer="layer_1/moe/routed"
+    ) == 1.0
 
 
-def loss_and_grads(model, variables, ids, room=None):
-    """The objective the Trainer builds: the mean of the model's
-    per-position losses plus everything sown into AUX_LOSS."""
-    state = {k: v for k, v in variables.items() if k not in
-             ("params", AUX_LOSS)}
-
-    def loss_of(params):
-        out, sown = model.apply(
-            {"params": params, **state}, {"input_ids": ids}, mutable=MUTABLE,
-            **({} if room is None else {"room": room}),
-        )
-        return zoo.loss(None, out.astype(jnp.float32)) + sum(
-            jax.tree.leaves(sown[AUX_LOSS])
-        )
-
-    with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(loss_of)(variables["params"])
-    return float(loss), {
-        k: np.asarray(v, np.float32) for k, v in trees.flat(grads).items()
-    }
-
-
-@pytest.fixture(scope="module")
-def seeded():
-    ids = ids_of(8)
-    model = model_of(CONFIG)
-    variables = model.init(jax.random.PRNGKey(0), {"input_ids": ids})
-    flat = {
-        k: np.asarray(v) for k, v in trees.flat(variables["params"]).items()
-    }
-    want_loss, want = reference.loss_and_grads(
-        flat, {"input_ids": ids}, None, CONFIG
-    )
-    return types.SimpleNamespace(
-        ids=ids, variables=variables, flat=flat, want_loss=want_loss,
-        want={k: np.asarray(v) for k, v in want.items()},
-    )
-
-
-def test_float32_matches_reference_leaf_by_leaf(seeded):
-    loss, got = loss_and_grads(model_of(CONFIG), seeded.variables, seeded.ids)
-    assert abs(loss - seeded.want_loss) < 1e-4 * abs(seeded.want_loss)
-    assert set(got) == set(seeded.want) and len(got) == 60
-    for name, want in seeded.want.items():
-        error = np.linalg.norm(got[name] - want) / np.linalg.norm(want)
-        assert error < 1e-4, (name, error)
-
-
-@pytest.fixture(scope="module")
-def saved_core(seeded):
-    """bf16 -> (loss, gradients) of the model as the cells run it."""
-    return functools.lru_cache(None)(lambda bf16: loss_and_grads(
-        model_of(CONFIG, bf16=bf16), seeded.variables, seeded.ids
-    ))
-
-
-@pytest.mark.parametrize("bf16", [False, True], ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("other", remat_cases.OTHERS)
-def test_saving_the_attention_core_changes_no_bit(seeded, saved_core,
-                                                  monkeypatch, other, bf16):
-    """`remat=True` against the plain `nn.remat` every commit before ran
-    (bit for bit) and against no remat at all (to 1e-5 of a leaf: XLA on the
-    CPU fuses an MLA block inside a remat's computation otherwise than
-    outside one, the plain remat's gradients differ by the same 1e-6)."""
-    remat_cases.assert_saving_changes_nothing(
-        zoo, monkeypatch, other,
-        lambda remat, room=None: loss_and_grads(
-            model_of(CONFIG, bf16=bf16, remat=remat), seeded.variables,
-            seeded.ids, room,
+DECODER = decoder_cases.Decoder(
+    zoo=zoo, reference=reference, cell="glm-4.7-flash", config=CONFIG,
+    length=16, seed=0, leaves=60,
+    # no remat to 1e-5 of a leaf: XLA on the CPU fuses an MLA block inside
+    # a remat's computation otherwise than outside one, the plain remat's
+    # gradients differ by the same 1e-6
+    no_remat_limit=1e-5,
+    trainer_gauges=the_mtp_loss_is_added,
+    job=decoder_cases.Job(
+        params=(
+            "hidden=32;num_layers=3;heads=2;q_lora_rank=12;kv_lora_rank=8;"
+            "qk_nope_head_dim=6;qk_rope_head_dim=4;v_head_dim=10;"
+            "dense_width=48;expert_width=16;num_experts=8;top_k=2;"
+            "held_experts=(0,4);vocab_size=50;remat=True;lr=0.01"
         ),
-        saved_core(bf16), no_remat_limit=1e-5,
-    )
-
-
-def test_bfloat16_inside_the_twins_rule(seeded):
-    """The model computing in bfloat16 is held as the benchmark holds a
-    cell that states it: to the reference's own bfloat16 twin, leaf by
-    leaf and on the angle (`check_gradient`), where the float8 control
-    in the step's place fails."""
-    from benchmarks.drivers import train
-
-    held = types.SimpleNamespace(
-        **{k: getattr(reference, k) for k in dir(reference)
-           if not k.startswith("__")},
-        STATED_RATIO=reference.TWIN_RATIO,
-    )
-    features = {"input_ids": seeded.ids}
-    labels = np.zeros(len(seeded.ids), np.int32)
-    _, got = loss_and_grads(
-        model_of(CONFIG, bf16=True), seeded.variables, seeded.ids
-    )
-    check = train.check_gradient(
-        held, seeded.flat, features, labels, CONFIG, seeded.want, got
-    )
-    assert check["ok"], sorted(
-        check["shares"].items(), key=lambda kv: -kv[1]
-    )[:4]
-    _, control = reference.loss_and_grads(
-        seeded.flat, features, labels, CONFIG, tower="float8_e4m3fn"
-    )
-    control = {k: np.asarray(v, np.float32) for k, v in control.items()}
-    assert not train.check_gradient(
-        held, seeded.flat, features, labels, CONFIG, seeded.want, control
-    )["ok"]
+        gauges=job_gauges, falls_by=0.1, all_the_room=False, seq_len=16,
+    ),
+)
+model_of = DECODER.model_of
+TestConformance = decoder_cases.conformance(DECODER)
 
 
 # ---- the routed layer -----------------------------------------------------
@@ -475,88 +382,11 @@ def test_the_walk_is_one_program_across_loads(monkeypatch):
 # ---- through the system ---------------------------------------------------
 
 
-def test_trainer_adds_the_mtp_loss_and_carries_step_metrics(seeded):
-    from elasticdl_tpu.worker.sync import ModelOwner
-    from elasticdl_tpu.worker.trainer import Trainer
-
-    trainer = Trainer(
-        model=model_of(CONFIG), optimizer=zoo.optimizer(1e-3),
-        loss_fn=zoo.loss,
-    )
-    batch = {"features": {"input_ids": seeded.ids},
-             "labels": np.zeros(len(seeded.ids), np.int32)}
-    state = trainer.init_state(jax.random.PRNGKey(0), batch["features"])
-    assert AUX_LOSS not in state.model_state
-    state, loss = trainer.train_on_batch(state, batch)
-    sown = state.model_state[STEP_METRICS]
-    assert float(loss) == pytest.approx(
-        float(sown["main_loss"]) + 0.3 * float(sown["mtp_loss"]), rel=1e-5
-    )
-    assert float(loss) == pytest.approx(seeded.want_loss, rel=1e-3)
-    owner = ModelOwner.__new__(ModelOwner)
-    owner.state, owner.lock = state, threading.Lock()
-    value, metrics = owner.fetch_loss(loss)
-    assert value == pytest.approx(float(loss))
-    assert metrics["layer_1/moe/routed/dropped_tokens"] == 0.0
-    assert 0.0 < metrics["layer_1/moe/routed/routed_here_ratio"] < 1.0
-    # a buffer no longer than one chunk: one trip, the whole of it
-    assert metrics["layer_1/moe/routed/live_chunks_ratio"] == 1.0
-    assert metrics["mtp_block/moe/routed/expert_load_imbalance_ratio"] >= 1.0
-
-
-def test_cli_job_of_two_tasks_with_a_falling_loss(tmp_path):
-    from elasticdl_tpu.client.main import main as cli_main
-    from elasticdl_tpu.common import metrics as metrics_lib
-    from elasticdl_tpu.worker.worker import Worker
-
-    path = str(tmp_path / "train.tfrecord")
-    datagen.write_task_file(
-        path, 7, {"format": "tokens", "seq_len": 16, "vocab_size": 50},
-        64, 2,
-    )
-    workers = []
-    init = Worker.__init__
-
-    def recording_init(self, *args, **kwargs):
-        init(self, *args, **kwargs)
-        workers.append(self)
-
-    Worker.__init__ = recording_init
-    try:
-        rc = cli_main([
-            "train", "--model_zoo",
-            os.path.join(os.path.dirname(__file__), "..", "model_zoo"),
-            "--model_def", "glm.glm_moe_lite.custom_model",
-            "--model_params",
-            "hidden=32;num_layers=3;heads=2;q_lora_rank=12;kv_lora_rank=8;"
-            "qk_nope_head_dim=6;qk_rope_head_dim=4;v_head_dim=10;"
-            "dense_width=48;expert_width=16;num_experts=8;top_k=2;"
-            "held_experts=(0,4);vocab_size=50;remat=True;lr=0.01",
-            "--distribution_strategy", "Local", "--training_data", path,
-            "--minibatch_size", "8", "--records_per_task", "64",
-            "--num_epochs", "1",
-        ])
-    finally:
-        Worker.__init__ = init
-    assert rc == 0
-    losses = [float(x) for x in workers[0].losses]
-    assert len(losses) == 16                      # two tasks of 8 steps
-    assert np.mean(losses[-4:]) < np.mean(losses[:4]) - 0.1
-    registry = metrics_lib.default_registry()
-    assert registry.value("worker_moe_dropped_tokens_total") == 0.0
-    assert 0.0 < registry.value(
-        "worker_moe_routed_here_ratio", layer="layer_1/moe/routed"
-    ) < 1.0
-    assert registry.value(
-        "worker_moe_live_chunks_ratio", layer="layer_1/moe/routed"
-    ) == 1.0
-
-
 # ---- MLA lives in model_zoo/common/mla.py ---------------------------------
 
 # sha256 of the sorted "leaf path + shape" lines of a tiny GLM, recorded at
 # the commit before `MLA` moved out of `model_zoo/glm/glm_moe_lite.py`, and
-# of the program its gradient lowers to (`remat_cases.grad_program_digest`),
+# of the program its gradient lowers to (`decoder_cases.grad_program_digest`),
 # recorded at the commit before the blocks' products had names (198d98a;
 # until then the test held the jaxpr's text, which a name no policy lists
 # adds an equation to and the program nothing).
@@ -599,4 +429,4 @@ def test_glm_keeps_its_tree_and_program_through_the_shared_mla(monkeypatch):
     assert hashlib.sha256("\n".join(paths).encode()).hexdigest() == (
         GLM_TREE_DIGEST
     )
-    assert remat_cases.grad_program_digest(model) == GLM_PROGRAM_DIGEST
+    assert decoder_cases.grad_program_digest(model) == GLM_PROGRAM_DIGEST

@@ -45,11 +45,20 @@ def inputs(batch, length, heads, dim, g_min, seed=0, dtype=jnp.float32):
     )
 
 
-def value_and_grads(fn, q, k, v, g, beta, weight):
+def out_and_grads(fn, q, k, v, g, beta, weight):
+    """(fn's output, the gradients of a weighted sum of it by the five
+    operands) from ONE compiled program: walked a primitive at a time, an
+    interpreted kernel's forward ran twice a test and its backward once,
+    each an equation at a time."""
+    def weighted(*operands):
+        out = fn(*operands)
+        return (out * weight).sum(), out
+
     with jax.default_matmul_precision("highest"):
-        return jax.value_and_grad(
-            lambda *a: (fn(*a) * weight).sum(), argnums=(0, 1, 2, 3, 4)
-        )(q, k, v, g, beta)
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            weighted, argnums=(0, 1, 2, 3, 4), has_aux=True
+        ))(q, k, v, g, beta)
+    return out, grads
 
 
 def assert_close(got, want, limit, what):
@@ -80,10 +89,9 @@ def test_chunked_forms_match_the_recurrence(form, g_min):
     weighted sum of it by q, k, v, g and beta."""
     args = inputs(1, 128, 2, 128, g_min)
     assert kda_ops.kda_shapes_ok(*(a.shape for a in args[:3]))
-    want_out = recurrence(*args[:5])
-    assert_close(form(*args[:5]), want_out, 5e-5, "o")
-    _, want = value_and_grads(recurrence, *args)
-    _, got = value_and_grads(form, *args)
+    want_out, want = out_and_grads(recurrence, *args)
+    out, got = out_and_grads(form, *args)
+    assert_close(out, want_out, 5e-5, "o")
     for name, a, b in zip(NAMES, got, want):
         assert a.shape == b.shape and np.isfinite(np.asarray(a)).all()
         assert_close(a, b, 2e-4, name)
@@ -94,11 +102,10 @@ def test_the_entry_pads_a_length_that_is_no_whole_chunk():
     leave the state alone; the outputs and gradients are the first 80's."""
     args = inputs(2, 80, 2, 16, -2.0, seed=1)
     assert not kda_ops.kda_shapes_ok(*(a.shape for a in args[:3]))
-    out = kda_ops.kda(*args[:5])
+    want_out, want = out_and_grads(recurrence, *args)
+    out, got = out_and_grads(kda_ops.kda, *args)
     assert out.shape == (2, 80, 2, 16)
-    assert_close(out, recurrence(*args[:5]), 5e-5, "o")
-    _, want = value_and_grads(recurrence, *args)
-    _, got = value_and_grads(kda_ops.kda, *args)
+    assert_close(out, want_out, 5e-5, "o")
     for name, a, b in zip(NAMES, got, want):
         assert_close(a, b, 2e-4, name)
     # a chunk of another size is the same number
@@ -125,9 +132,9 @@ def test_the_l2_norms_inside_the_op(form):
     def fused(q, k, v, g, beta):
         return form(q, k, v, g, beta, norm)
 
-    assert_close(fused(q, k, v, g, beta), plain(q, k, v, g, beta), 5e-5, "o")
-    _, want = value_and_grads(plain, q, k, v, g, beta, weight)
-    _, got = value_and_grads(fused, q, k, v, g, beta, weight)
+    want_out, want = out_and_grads(plain, q, k, v, g, beta, weight)
+    out, got = out_and_grads(fused, q, k, v, g, beta, weight)
+    assert_close(out, want_out, 5e-5, "o")
     for name, a, b in zip(NAMES, got, want):
         assert_close(a, b, 2e-4, name)
 
@@ -153,10 +160,9 @@ def test_aligned_keys_do_not_cancel(form):
     q, k, v, g, beta, weight = inputs(1, 128, 1, 128, 0.0, seed=3)
     k = jnp.broadcast_to(k[:, :1], k.shape)
     beta = jnp.full_like(beta, 0.95)
-    want = recurrence(q, k, v, g, beta)
-    assert_close(form(q, k, v, g, beta), want, 2e-3, "o")
-    _, want_grads = value_and_grads(recurrence, q, k, v, g, beta, weight)
-    _, got = value_and_grads(form, q, k, v, g, beta, weight)
+    want, want_grads = out_and_grads(recurrence, q, k, v, g, beta, weight)
+    out, got = out_and_grads(form, q, k, v, g, beta, weight)
+    assert_close(out, want, 2e-3, "o")
     for name, a, b in zip(NAMES, got, want_grads):
         assert np.isfinite(np.asarray(a)).all()
         assert_close(a, b, 5e-3, name)
@@ -245,17 +251,20 @@ def test_admission_names_and_types():
     for name in names:
         assert re.match(r"^kda_\w*(fwd|bwd)$", name)
         assert "attention" not in name and "short_conv" not in name
-    out = kda_ops.kda(q, k, v, g, beta)
+    def weighted(*operands):
+        out = kda_ops.kda(*operands)
+        return (out * w).astype(jnp.float32).sum(), out
+
+    # the output and the gradients from one compiled program
+    (_, out), grads = jax.jit(jax.value_and_grad(
+        weighted, argnums=(0, 1, 2, 3, 4), has_aux=True
+    ))(q, k, v, g, beta)
     assert out.dtype == jnp.bfloat16
     assert_close(
         out.astype(jnp.float32),
         recurrence(*(t.astype(jnp.float32) for t in (q, k, v, g, beta))),
         2e-2, "bfloat16 o",
     )
-    grads = jax.grad(
-        lambda *a: (kda_ops.kda(*a) * w).astype(jnp.float32).sum(),
-        argnums=(0, 1, 2, 3, 4),
-    )(q, k, v, g, beta)
     assert [t.dtype for t in grads] == [
         jnp.bfloat16, jnp.bfloat16, jnp.bfloat16, jnp.float32, jnp.float32
     ]

@@ -11,28 +11,25 @@ gauges through the Trainer, the published sizes' parameter count, and a
 two-task job through the CLI."""
 
 import functools
-import json
-import os
-import threading
-import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import datagen, trees
+from benchmarks import trees
 from benchmarks.reference import kimi_linear as reference
 from benchmarks.reference.glm_moe_lite import swiglu
 from elasticdl_tpu.layers.moe import ROUTER_STATE, RoutedExperts
-from elasticdl_tpu.layers.step_metrics import AUX_LOSS, STEP_METRICS
+from elasticdl_tpu.layers.step_metrics import STEP_METRICS
 from elasticdl_tpu.ops import kda as kda_ops
 from elasticdl_tpu.ops import short_conv
+from elasticdl_tpu.ops.flash_attention import stream_shapes_ok
 from model_zoo.common.decoder import MoEFFN
 from model_zoo.kimi import kimi_linear as zoo
-from tests import remat_cases
+from tests import decoder_cases
+from tests.decoder_cases import MUTABLE, computed, seeded  # noqa: F401
 
-ROOT = os.path.join(os.path.dirname(__file__), "..")
 # the published lists' first eight layers (full attention at 4 and 8); the
 # cut's layers 0..4: KDA over the dense FFN, then KDA, KDA, MLA, KDA, all
 # routed; 2 heads, 16 experts of which 4 are held
@@ -48,146 +45,124 @@ CONFIG = dict(
     moe_intermediate_size=16, num_experts_published=16,
     num_experts_per_token=2, num_experts_per_tok=2, num_shared_experts=1,
     held_experts=[4, 4], routed_scaling_factor=2.446, vocab_size=50,
-    rms_norm_eps=1e-5, use_bf16=True,
+    rms_norm_eps=1e-5, learning_rate=1e-3, use_bf16=True,
 )
-MUTABLE = [AUX_LOSS, STEP_METRICS, ROUTER_STATE]
+KDA_LEAVES, MLA_LEAVES = 11, 5
+KINDS = [
+    (zoo.KDA_KIND, False), (zoo.KDA_KIND, True), (zoo.KDA_KIND, True),
+    (zoo.MLA_KIND, True), (zoo.KDA_KIND, True),
+]
 
 
-def model_of(config, **overrides):
+def every_kind_of_block_is_present(model, seeded, got):
+    """KDA over the dense FFN, KDA and MLA over the routed one."""
+    assert list(model.config.layers) == KINDS
+    assert "layer_3/mla/q/kernel" in got and "layer_3/mla/q_a/kernel" not in got
+
+
+def published_also(model, config, shapes, flat, by_top):
+    assert list(model.config.layers) == KINDS
+    assert len(model.config.layers) == config["num_hidden_layers"]
     linear = config["linear_attn_config"]
-    sizes = dict(
-        hidden=config["hidden_size"],
-        num_layers=config["num_hidden_layers_published"],
-        kda_layers=linear["kda_layers"],
-        full_attn_layers=linear["full_attn_layers"],
-        first_k_dense_replace=config["first_k_dense_replace"],
-        layers=config["layers_held"], heads=config["num_attention_heads"],
-        kda_heads=linear["num_heads"], kda_head_dim=linear["head_dim"],
-        conv_kernel=linear["short_conv_kernel_size"],
-        kv_lora_rank=config["kv_lora_rank"],
-        qk_nope_head_dim=config["qk_nope_head_dim"],
-        qk_rope_head_dim=config["qk_rope_head_dim"],
-        v_head_dim=config["v_head_dim"],
-        dense_width=config["intermediate_size"],
-        expert_width=config["moe_intermediate_size"],
-        num_experts=config["num_experts_published"],
-        top_k=config["num_experts_per_token"],
-        shared_experts=config["num_shared_experts"],
-        held_experts=config["held_experts"],
-        routed_scaling=config["routed_scaling_factor"],
-        vocab_size=config["vocab_size"], eps=config["rms_norm_eps"],
-        remat=True,
-    )
-    sizes.update(overrides)
-    return zoo.custom_model(**sizes)
+    assert tuple(linear["kda_layers"]) == zoo.PUBLISHED_KDA_LAYERS
+    assert tuple(linear["full_attn_layers"]) == zoo.PUBLISHED_FULL_ATTN_LAYERS
+    assert config["num_experts_per_tok"] == config["num_experts_per_token"]
+    assert sum(
+        v for k, v in flat.items() if k.startswith("layer_2/kda/")
+    ) == 39_514_272
+    assert sum(
+        v for k, v in flat.items() if k.startswith("layer_3/mla/")
+    ) == 29_114_880
 
 
-def ids_of(rows, length=80, seed=0):
-    return np.random.RandomState(seed).randint(
-        0, CONFIG["vocab_size"], (rows, length)
-    ).astype(np.int32)
+def trainer_gauges(metrics, state, loss, seeded):
+    for layer in (0, 1, 2, 4):
+        assert 0.0 < metrics[f"layer_{layer}/kda/kda_decay_mean_ratio"] < 1.0
+        assert 0.2 < metrics[f"layer_{layer}/kda/kda_beta_mean_ratio"] < 0.8
+    assert "layer_3/kda/kda_decay_mean_ratio" not in metrics       # MLA
+    assert "layer_0/moe/routed/routed_here_ratio" not in metrics
+    assert metrics["layer_1/moe/routed/dropped_tokens"] == 0.0
+    assert 0.0 < metrics["layer_4/moe/routed/routed_here_ratio"] < 1.0
 
 
-def loss_and_grads(model, variables, ids, room=None):
-    """The objective the Trainer builds: the mean of the model's
-    per-position losses (this model sows no auxiliary loss)."""
-    state = {k: v for k, v in variables.items() if k != "params"}
-
-    def loss_of(params):
-        out, _ = model.apply(
-            {"params": params, **state}, {"input_ids": ids}, mutable=MUTABLE,
-            **({} if room is None else {"room": room}),
-        )
-        return zoo.loss(None, out.astype(jnp.float32))
-
-    with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(loss_of)(variables["params"])
-    return float(loss), {
-        k: np.asarray(v, np.float32) for k, v in trees.flat(grads).items()
-    }
+def job_gauges(registry):
+    assert registry.value("worker_moe_dropped_tokens_total") == 0.0
+    assert 0.0 < registry.value(
+        "worker_moe_routed_here_ratio", layer="layer_1/moe/routed"
+    ) < 1.0
+    for layer in (0, 1):
+        assert 0.0 < registry.value(
+            "worker_kda_decay_mean_ratio", layer=f"layer_{layer}/kda"
+        ) < 1.0
+        assert 0.2 < registry.value(
+            "worker_kda_beta_mean_ratio", layer=f"layer_{layer}/kda"
+        ) < 0.8
 
 
-def seeded_of(config, ids):
-    model = model_of(config)
-    variables = model.init(jax.random.PRNGKey(0), {"input_ids": ids})
-    flat = {
-        k: np.asarray(v) for k, v in trees.flat(variables["params"]).items()
-    }
-    want_loss, want = reference.loss_and_grads(
-        flat, {"input_ids": ids}, None, config
-    )
-    return types.SimpleNamespace(
-        ids=ids, variables=variables, flat=flat, want_loss=want_loss,
-        want={k: np.asarray(v) for k, v in want.items()},
-    )
-
-
-@pytest.fixture(scope="module")
-def seeded():
+DECODER = decoder_cases.Decoder(
+    zoo=zoo, reference=reference, cell="kimi-linear-48b-a3b", config=CONFIG,
     # 80 positions: one whole chunk of the scan and a padded one.  `A_log`
     # holds ONE number a head: with two heads its error against the twin's
     # is the ratio of two draws, not an average over a leaf, and under the
     # twins' rule it reads 0.6-0.9 on seeds 3 and 5 and 1.3-6.9 on seeds
     # 1, 2 and 4, where every other leaf reads under 0.6 (the cell is held
     # to shares of a leaf's norm, `reference.LEAF_REL_L2`, not to the twin)
-    return seeded_of(CONFIG, ids_of(8, seed=3))
-
-
-def assert_leaf_by_leaf(got, want, limit=1e-4):
-    assert set(got) == set(want)
-    for name, ref in want.items():
-        error = np.linalg.norm(got[name] - ref) / np.linalg.norm(ref)
-        assert error < limit, (name, error)
-
-
-KDA_LEAVES, MLA_LEAVES = 11, 5
-
-
-def test_float32_matches_reference_leaf_by_leaf(seeded):
-    """Every kind of block is present: KDA over the dense FFN, KDA and
-    MLA over the routed one."""
-    model = model_of(CONFIG)
-    assert list(model.config.layers) == [
-        (zoo.KDA_KIND, False), (zoo.KDA_KIND, True), (zoo.KDA_KIND, True),
-        (zoo.MLA_KIND, True), (zoo.KDA_KIND, True),
-    ]
-    loss, got = loss_and_grads(model, seeded.variables, seeded.ids)
-    assert abs(loss - seeded.want_loss) < 1e-4 * abs(seeded.want_loss)
+    length=80, seed=3,
     # a KDA mixer's 11 leaves (qkv, taps, A_log, dt_bias, two low-rank
     # gates of 2, beta, the output norm, o), MLA's 5 (q, kv_a and its
     # norm, kv_b, o), two norms a block, 2 (dense) or 5 (routed + shared)
     # feed-forward leaves, embedding, head and final norm
-    assert len(got) == (
+    leaves=(
         (KDA_LEAVES + 2 + 2) + 3 * (KDA_LEAVES + 2 + 5)
         + (MLA_LEAVES + 2 + 5) + 3
-    )
-    assert "layer_3/mla/q/kernel" in got and "layer_3/mla/q_a/kernel" not in got
-    assert_leaf_by_leaf(got, seeded.want)
-
-
-def test_kernels_match_reference_leaf_by_leaf():
-    """Two KDA heads of 128 and two MLA heads of 128 + 64 over 128 at 128
-    positions: the scan's kernels (two chunks), the SiLU conv's and the
-    streaming attention at a key width of its own (padded to 256 inside
-    the op), all interpreted here."""
-    from elasticdl_tpu.ops.flash_attention import stream_shapes_ok
-
-    config = dict(
-        CONFIG, hidden_size=64,
-        linear_attn_config=dict(
-            CONFIG["linear_attn_config"], head_dim=128,
+    ),
+    float32_also=every_kind_of_block_is_present,
+    # two KDA heads of 128 and two MLA heads of 128 + 64 over 128 at 128
+    # positions: the scan's kernels (two chunks), the SiLU conv's and the
+    # streaming attention at a key width of its own (padded to 256 inside
+    # the op), all interpreted here; one block of each kind (KDA over the
+    # dense FFN, MLA over the routed one: a second KDA block ran the same
+    # kernels again at the same shapes)
+    kernels=decoder_cases.Kernels(
+        config=dict(
+            hidden_size=64,
+            linear_attn_config=dict(
+                CONFIG["linear_attn_config"], head_dim=128,
+            ),
+            qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+            layers_held=[0, 3], num_hidden_layers=2,
         ),
-        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
-        layers_held=[0, 3, 4], num_hidden_layers=3,
-    )
-    assert kda_ops.kda_shapes_ok(*[(1, 128, 2, 128)] * 3)
-    assert short_conv.silu_conv_shapes_ok((1, 128, 768), (4, 768))
-    assert stream_shapes_ok((1, 128, 2, 192), (1, 128, 2, 192),
-                            (1, 128, 2, 128))
-    seeded = seeded_of(config, ids_of(1, length=128, seed=2))
-    loss, got = loss_and_grads(model_of(config), seeded.variables, seeded.ids)
-    assert abs(loss - seeded.want_loss) < 1e-4 * abs(seeded.want_loss)
-    assert_leaf_by_leaf(got, seeded.want, 2e-4)
+        length=128,
+        admitted=(
+            (kda_ops.kda_shapes_ok, *[(1, 128, 2, 128)] * 3),
+            (short_conv.silu_conv_shapes_ok, (1, 128, 768), (4, 768)),
+            (stream_shapes_ok, (1, 128, 2, 192), (1, 128, 2, 192),
+             (1, 128, 2, 128)),
+        ),
+    ),
+    published=decoder_cases.Published(
+        by_top={
+            "layer_0": 103_219_872, "layer_1": 103_809_696,
+            "layer_2": 103_809_696, "layer_3": 93_410_304,
+            "layer_4": 103_809_696, "token_embedding": 47_185_920,
+            "lm_head_kernel": 47_185_920, "final_norm": 2_304,
+        },
+        total=602_433_408, also=published_also,
+    ),
+    trainer_gauges=trainer_gauges,
+    job=decoder_cases.Job(
+        params=(
+            "hidden=32;num_layers=4;kda_layers=[1,2,3];full_attn_layers=[4];"
+            "layers=[0,2,3];heads=2;kda_heads=2;kda_head_dim=16;"
+            "kv_lora_rank=16;qk_nope_head_dim=16;qk_rope_head_dim=8;"
+            "v_head_dim=16;dense_width=48;expert_width=16;num_experts=16;"
+            "top_k=2;held_experts=(0,8);vocab_size=50;remat=True;lr=0.01"
+        ),
+        gauges=job_gauges, falls_by=0.1, all_the_room=False,
+    ),
+)
+model_of = DECODER.model_of
+TestConformance = decoder_cases.conformance(DECODER)
 
 
 def test_each_part_of_the_mathematics_is_seen(seeded):
@@ -234,62 +209,6 @@ def test_each_part_of_the_mathematics_is_seen(seeded):
         )
     np.testing.assert_allclose(whole[:5], early, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(doubled[1], doubled[0], rtol=1e-5, atol=1e-6)
-
-
-@pytest.fixture(scope="module")
-def saved_core(seeded):
-    """bf16 -> (loss, gradients) of the model as the cells run it."""
-    return functools.lru_cache(None)(lambda bf16: loss_and_grads(
-        model_of(CONFIG, bf16=bf16), seeded.variables, seeded.ids
-    ))
-
-
-@pytest.mark.parametrize("bf16", [False, True], ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("other", remat_cases.OTHERS)
-def test_the_remat_policy_changes_no_bit(seeded, saved_core, monkeypatch,
-                                         other, bf16):
-    """`remat=True` against the plain `nn.remat` and against no remat at
-    all, bit for bit."""
-    remat_cases.assert_saving_changes_nothing(
-        zoo, monkeypatch, other,
-        lambda remat, room=None: loss_and_grads(
-            model_of(CONFIG, bf16=bf16, remat=remat), seeded.variables,
-            seeded.ids, room,
-        ),
-        saved_core(bf16),
-    )
-
-
-def test_bfloat16_inside_the_twins_rule(seeded):
-    """The model computing in bfloat16 is held as the benchmark holds a
-    cell that states it: to the reference's own bfloat16 twin, leaf by
-    leaf and on the angle (`check_gradient`), where the float8 control
-    in the step's place fails."""
-    from benchmarks.drivers import train
-
-    held = types.SimpleNamespace(
-        **{k: getattr(reference, k) for k in dir(reference)
-           if not k.startswith("__")},
-        STATED_RATIO=reference.TWIN_RATIO,
-    )
-    features = {"input_ids": seeded.ids}
-    labels = np.zeros(len(seeded.ids), np.int32)
-    _, got = loss_and_grads(
-        model_of(CONFIG, bf16=True), seeded.variables, seeded.ids
-    )
-    check = train.check_gradient(
-        held, seeded.flat, features, labels, CONFIG, seeded.want, got
-    )
-    assert check["ok"], sorted(
-        check["shares"].items(), key=lambda kv: -kv[1]
-    )[:4]
-    _, control = reference.loss_and_grads(
-        seeded.flat, features, labels, CONFIG, tower="float8_e4m3fn"
-    )
-    control = {k: np.asarray(v, np.float32) for k, v in control.items()}
-    assert not train.check_gradient(
-        held, seeded.flat, features, labels, CONFIG, seeded.want, control
-    )["ok"]
 
 
 class ViewKDA(zoo.nn.Module):
@@ -477,58 +396,6 @@ def test_the_kda_layer_owns_the_leaves_it_always_did():
         )
 
 
-def test_published_sizes_hold_what_the_configuration_states():
-    """The parameters of the cut model at the published widths, counted
-    from the built model's shapes: the numbers in the configuration's
-    `deployment` and its `parameters_held`."""
-    with open(os.path.join(
-        ROOT, "benchmarks", "configs", "kimi-linear-48b-a3b.json"
-    )) as f:
-        config = json.load(f)
-    from elasticdl_tpu.common.model_handler import _call_with_params
-
-    model = _call_with_params(
-        zoo.custom_model, config["model_params"].format(**config)
-    )
-    assert list(model.config.layers) == [
-        (zoo.KDA_KIND, False), (zoo.KDA_KIND, True), (zoo.KDA_KIND, True),
-        (zoo.MLA_KIND, True), (zoo.KDA_KIND, True),
-    ]
-    assert len(model.config.layers) == config["num_hidden_layers"]
-    linear = config["linear_attn_config"]
-    assert tuple(linear["kda_layers"]) == zoo.PUBLISHED_KDA_LAYERS
-    assert tuple(linear["full_attn_layers"]) == zoo.PUBLISHED_FULL_ATTN_LAYERS
-    assert config["num_experts_per_tok"] == config["num_experts_per_token"]
-    assert model.config.dtype == jnp.bfloat16 and model.config.remat
-    shapes = jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), {"input_ids": jnp.zeros((1, 512), jnp.int32)}
-    ))
-    flat = {
-        name: int(np.prod(leaf.shape))
-        for name, leaf in trees.flat(shapes["params"]).items()
-    }
-    by_top = {}
-    for name, size in flat.items():
-        top = name.split("/")[0]
-        by_top[top] = by_top.get(top, 0) + size
-    assert by_top == {
-        "layer_0": 103_219_872, "layer_1": 103_809_696,
-        "layer_2": 103_809_696, "layer_3": 93_410_304,
-        "layer_4": 103_809_696, "token_embedding": 47_185_920,
-        "lm_head_kernel": 47_185_920, "final_norm": 2_304,
-    }
-    assert sum(
-        v for k, v in flat.items() if k.startswith("layer_2/kda/")
-    ) == 39_514_272
-    assert sum(
-        v for k, v in flat.items() if k.startswith("layer_3/mla/")
-    ) == 29_114_880
-    total = sum(by_top.values())
-    assert total == config["parameters_held"] == 602_433_408
-    assert "602,433,408" in config["deployment"]
-    assert 12 * total > 0.25 * 16.9e9          # over the floor, held alone
-
-
 # ---- the routed layer beside its shared expert: the shares ----------------
 
 
@@ -613,79 +480,3 @@ def test_the_init_program_drops_the_forward(seeded):
     ))
 
 
-def test_trainer_carries_the_kda_gauges(seeded):
-    from elasticdl_tpu.worker.sync import ModelOwner
-    from elasticdl_tpu.worker.trainer import Trainer
-
-    trainer = Trainer(
-        model=model_of(CONFIG), optimizer=zoo.optimizer(1e-3),
-        loss_fn=zoo.loss,
-    )
-    batch = {"features": {"input_ids": seeded.ids},
-             "labels": np.zeros(len(seeded.ids), np.int32)}
-    state = trainer.init_state(jax.random.PRNGKey(0), batch["features"])
-    state, loss = trainer.train_on_batch(state, batch)
-    assert float(loss) == pytest.approx(seeded.want_loss, rel=1e-3)
-    owner = ModelOwner.__new__(ModelOwner)
-    owner.state, owner.lock = state, threading.Lock()
-    value, metrics = owner.fetch_loss(loss)
-    assert value == pytest.approx(float(loss))
-    for layer in (0, 1, 2, 4):
-        assert 0.0 < metrics[f"layer_{layer}/kda/kda_decay_mean_ratio"] < 1.0
-        assert 0.2 < metrics[f"layer_{layer}/kda/kda_beta_mean_ratio"] < 0.8
-    assert "layer_3/kda/kda_decay_mean_ratio" not in metrics       # MLA
-    assert "layer_0/moe/routed/routed_here_ratio" not in metrics
-    assert metrics["layer_1/moe/routed/dropped_tokens"] == 0.0
-    assert 0.0 < metrics["layer_4/moe/routed/routed_here_ratio"] < 1.0
-
-
-def test_cli_job_of_two_tasks_with_a_falling_loss(tmp_path):
-    from elasticdl_tpu.client.main import main as cli_main
-    from elasticdl_tpu.common import metrics as metrics_lib
-    from elasticdl_tpu.worker.worker import Worker
-
-    path = str(tmp_path / "train.tfrecord")
-    datagen.write_task_file(
-        path, 7, {"format": "tokens", "seq_len": 32, "vocab_size": 50},
-        64, 2,
-    )
-    workers = []
-    init = Worker.__init__
-
-    def recording_init(self, *args, **kwargs):
-        init(self, *args, **kwargs)
-        workers.append(self)
-
-    Worker.__init__ = recording_init
-    try:
-        rc = cli_main([
-            "train", "--model_zoo", os.path.join(ROOT, "model_zoo"),
-            "--model_def", "kimi.kimi_linear.custom_model",
-            "--model_params",
-            "hidden=32;num_layers=4;kda_layers=[1,2,3];full_attn_layers=[4];"
-            "layers=[0,2,3];heads=2;kda_heads=2;kda_head_dim=16;"
-            "kv_lora_rank=16;qk_nope_head_dim=16;qk_rope_head_dim=8;"
-            "v_head_dim=16;dense_width=48;expert_width=16;num_experts=16;"
-            "top_k=2;held_experts=(0,8);vocab_size=50;remat=True;lr=0.01",
-            "--distribution_strategy", "Local", "--training_data", path,
-            "--minibatch_size", "8", "--records_per_task", "64",
-            "--num_epochs", "1",
-        ])
-    finally:
-        Worker.__init__ = init
-    assert rc == 0
-    losses = [float(x) for x in workers[0].losses]
-    assert len(losses) == 16                      # two tasks of 8 steps
-    assert np.mean(losses[-4:]) < np.mean(losses[:4]) - 0.1
-    registry = metrics_lib.default_registry()
-    assert registry.value("worker_moe_dropped_tokens_total") == 0.0
-    assert 0.0 < registry.value(
-        "worker_moe_routed_here_ratio", layer="layer_1/moe/routed"
-    ) < 1.0
-    for layer in (0, 1):
-        assert 0.0 < registry.value(
-            "worker_kda_decay_mean_ratio", layer=f"layer_{layer}/kda"
-        ) < 1.0
-        assert 0.2 < registry.value(
-            "worker_kda_beta_mean_ratio", layer=f"layer_{layer}/kda"
-        ) < 0.8

@@ -7,26 +7,20 @@ expert-parallel deployment adding up to the uncut layer, the sown gate
 through the Trainer, the published sizes' parameter count, and a two-task
 job through the CLI."""
 
-import functools
-import json
-import os
-import threading
-import types
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import datagen, trees
 from benchmarks.reference import laguna as reference
 from elasticdl_tpu.layers import moe
 from elasticdl_tpu.layers.moe import ROUTER_STATE, RoutedExperts
-from elasticdl_tpu.layers.step_metrics import AUX_LOSS, STEP_METRICS
+from elasticdl_tpu.layers.step_metrics import STEP_METRICS
+from elasticdl_tpu.ops.flash_attention import stream_shapes_ok
 from model_zoo.laguna import laguna as zoo
-from tests import remat_cases
+from tests import decoder_cases
+from tests.decoder_cases import MUTABLE, computed, seeded  # noqa: F401
 
-ROOT = os.path.join(os.path.dirname(__file__), "..")
 ROPES = {
     "full_attention": {
         "rope_theta": 500000, "rope_type": "yarn", "factor": 64,
@@ -53,112 +47,82 @@ CONFIG = dict(
     shared_expert_intermediate_size=16, num_experts_published=16,
     num_experts_per_tok=2, held_experts=[4, 4],
     moe_routed_scaling_factor=2.5, vocab_size=50, rms_norm_eps=1e-6,
-    use_bf16=True,
+    learning_rate=1e-3, use_bf16=True,
 )
-MUTABLE = [AUX_LOSS, STEP_METRICS, ROUTER_STATE]
 
 
-def model_of(config, **overrides):
-    sizes = dict(
-        hidden=config["hidden_size"], num_layers=config["num_hidden_layers"],
-        layer_types=config["layer_types"],
-        mlp_layer_types=config["mlp_layer_types"],
-        heads_per_layer=config["num_attention_heads_per_layer"],
-        kv_heads=config["num_key_value_heads"], head_dim=config["head_dim"],
-        window=config["sliding_window"],
-        rope_parameters=config["rope_parameters"],
-        dense_width=config["intermediate_size"],
-        expert_width=config["moe_intermediate_size"],
-        shared_width=config["shared_expert_intermediate_size"],
-        num_experts=config["num_experts_published"],
-        top_k=config["num_experts_per_tok"],
-        held_experts=config["held_experts"],
-        routed_scaling=config["moe_routed_scaling_factor"],
-        vocab_size=config["vocab_size"], eps=config["rms_norm_eps"],
-        remat=True,
-    )
-    sizes.update(overrides)
-    return zoo.custom_model(**sizes)
+def published_also(model, config, shapes, flat, by_top):
+    assert [layer[:2] for layer in model.config.layers] == [
+        (zoo.FULL, 48), (zoo.WINDOW, 64), (zoo.WINDOW, 64),
+        (zoo.WINDOW, 64), (zoo.FULL, 48),
+    ]
+    assert [layer[2] for layer in model.config.layers] == [False] + [True] * 4
 
 
-def ids_of(rows, length=64, seed=0):
-    return np.random.RandomState(seed).randint(
-        0, CONFIG["vocab_size"], (rows, length)
-    ).astype(np.int32)
+def the_gate_mean_is_carried(metrics, state, loss, seeded):
+    for layer in range(5):
+        # a seeded gate sits near a half; one that closes silences its layer
+        assert 0.3 < metrics[f"layer_{layer}/attn/gate_mean"] < 0.7
+    assert "layer_0/moe/routed/routed_here_ratio" not in metrics
+    assert metrics["layer_1/moe/routed/dropped_tokens"] == 0.0
+    assert 0.0 < metrics["layer_4/moe/routed/routed_here_ratio"] < 1.0
 
 
-def loss_and_grads(model, variables, ids, room=None):
-    """The objective the Trainer builds: the mean of the model's
-    per-position losses (this model sows no auxiliary loss)."""
-    state = {k: v for k, v in variables.items() if k != "params"}
-
-    def loss_of(params):
-        out, _ = model.apply(
-            {"params": params, **state}, {"input_ids": ids}, mutable=MUTABLE,
-            **({} if room is None else {"room": room}),
-        )
-        return zoo.loss(None, out.astype(jnp.float32))
-
-    with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(loss_of)(variables["params"])
-    return float(loss), {
-        k: np.asarray(v, np.float32) for k, v in trees.flat(grads).items()
-    }
+def job_gauges(registry):
+    assert registry.value("worker_moe_dropped_tokens_total") == 0.0
+    assert 0.0 < registry.value(
+        "worker_moe_routed_here_ratio", layer="layer_1/moe/routed"
+    ) < 1.0
+    for layer in range(3):
+        assert 0.2 < registry.value(
+            "worker_attention_gate_mean_ratio", layer=f"layer_{layer}/attn"
+        ) < 0.8
 
 
-def seeded_of(config, ids):
-    model = model_of(config)
-    variables = model.init(jax.random.PRNGKey(0), {"input_ids": ids})
-    flat = {
-        k: np.asarray(v) for k, v in trees.flat(variables["params"]).items()
-    }
-    want_loss, want = reference.loss_and_grads(
-        flat, {"input_ids": ids}, None, config
-    )
-    return types.SimpleNamespace(
-        ids=ids, variables=variables, flat=flat, want_loss=want_loss,
-        want={k: np.asarray(v) for k, v in want.items()},
-    )
-
-
-@pytest.fixture(scope="module")
-def seeded():
-    return seeded_of(CONFIG, ids_of(8))
-
-
-def assert_leaf_by_leaf(got, want, limit=1e-4):
-    assert set(got) == set(want)
-    for name, ref in want.items():
-        error = np.linalg.norm(got[name] - ref) / np.linalg.norm(ref)
-        assert error < limit, (name, error)
-
-
-def test_float32_matches_reference_leaf_by_leaf(seeded):
-    loss, got = loss_and_grads(model_of(CONFIG), seeded.variables, seeded.ids)
-    assert abs(loss - seeded.want_loss) < 1e-4 * abs(seeded.want_loss)
+DECODER = decoder_cases.Decoder(
+    zoo=zoo, reference=reference, cell="laguna-xs.2", config=CONFIG,
+    length=64, seed=0,
     # 5 blocks of 7 attention leaves and 2 (dense) or 5 (routed)
     # feed-forward leaves, embedding, head, final norm
-    assert len(got) == 9 + 4 * 12 + 3
-    assert_leaf_by_leaf(got, seeded.want)
-
-
-def test_streaming_kernels_match_reference_leaf_by_leaf():
-    """Head width 128 and two tiles of 128 positions: the Pallas kernels
-    (interpreted here), a full layer of 2 heads and a window layer of 3
-    over ONE K/V head, the window longer than a tile and shorter than the
-    sequence."""
-    from elasticdl_tpu.ops.flash_attention import stream_shapes_ok
-
-    config = dict(
-        CONFIG, num_hidden_layers=2, num_attention_heads_per_layer=[2, 3],
-        num_key_value_heads=1, head_dim=128, sliding_window=160,
-    )
-    assert stream_shapes_ok((1, 256, 3, 128), (1, 256, 1, 128),
-                            (1, 256, 1, 128))
-    seeded = seeded_of(config, ids_of(1, length=256, seed=2))
-    loss, got = loss_and_grads(model_of(config), seeded.variables, seeded.ids)
-    assert abs(loss - seeded.want_loss) < 1e-4 * abs(seeded.want_loss)
-    assert_leaf_by_leaf(got, seeded.want, 2e-4)
+    leaves=9 + 4 * 12 + 3,
+    # head width 128 and two tiles of 128 positions: the Pallas kernels
+    # (interpreted here), a full layer of 2 heads and a window layer of 3
+    # over ONE K/V head, the window longer than a tile and shorter than the
+    # sequence
+    kernels=decoder_cases.Kernels(
+        config=dict(
+            num_hidden_layers=2, num_attention_heads_per_layer=[2, 3],
+            num_key_value_heads=1, head_dim=128, sliding_window=160,
+        ),
+        length=256,
+        admitted=((stream_shapes_ok, (1, 256, 3, 128), (1, 256, 1, 128),
+                   (1, 256, 1, 128)),),
+    ),
+    published=decoder_cases.Published(
+        by_top={
+            "layer_0": 79_794_176, "layer_1": 142_217_216,
+            "layer_2": 142_217_216, "layer_3": 142_217_216,
+            "layer_4": 133_795_840, "token_embedding": 25_690_112,
+            "lm_head_kernel": 25_690_112, "final_norm": 2_048,
+        },
+        total=691_623_936, also=published_also,
+    ),
+    trainer_gauges=the_gate_mean_is_carried,
+    job=decoder_cases.Job(
+        params=(
+            "hidden=32;num_layers=3;"
+            "layer_types=['full_attention','sliding_attention',"
+            "'sliding_attention'];"
+            "mlp_layer_types=['dense','sparse','sparse'];"
+            "heads_per_layer=[6,8,8];kv_heads=2;head_dim=16;window=12;"
+            "dense_width=48;expert_width=16;shared_width=16;num_experts=16;"
+            "top_k=2;held_experts=(0,8);vocab_size=50;remat=True;lr=0.01"
+        ),
+        gauges=job_gauges, falls_by=0.1, all_the_room=False,
+    ),
+)
+model_of = DECODER.model_of
+TestConformance = decoder_cases.conformance(DECODER)
 
 
 def test_the_window_and_the_groups_are_seen(seeded):
@@ -176,65 +140,10 @@ def test_the_window_and_the_groups_are_seen(seeded):
         loss_with(layer_types=[zoo.FULL] * 6) - seeded.want_loss
     ) > 1e-4
     model = model_of(CONFIG, window=64)
-    out = model.apply(seeded.variables, {"input_ids": seeded.ids},
-                      mutable=MUTABLE)[0]
+    out = jax.jit(lambda variables: model.apply(
+        variables, {"input_ids": seeded.ids}, mutable=MUTABLE
+    )[0])(seeded.variables)
     assert abs(float(out.mean()) - seeded.want_loss) > 1e-4
-
-
-@pytest.fixture(scope="module")
-def saved_core(seeded):
-    """bf16 -> (loss, gradients) of the model as the cells run it."""
-    return functools.lru_cache(None)(lambda bf16: loss_and_grads(
-        model_of(CONFIG, bf16=bf16), seeded.variables, seeded.ids
-    ))
-
-
-@pytest.mark.parametrize("bf16", [False, True], ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("other", remat_cases.OTHERS)
-def test_saving_the_attention_core_changes_no_bit(seeded, saved_core,
-                                                  monkeypatch, other, bf16):
-    """`remat=True` against the plain `nn.remat` every commit before ran
-    and against no remat at all, bit for bit."""
-    remat_cases.assert_saving_changes_nothing(
-        zoo, monkeypatch, other,
-        lambda remat, room=None: loss_and_grads(
-            model_of(CONFIG, bf16=bf16, remat=remat), seeded.variables,
-            seeded.ids, room,
-        ),
-        saved_core(bf16),
-    )
-
-
-def test_bfloat16_inside_the_twins_rule(seeded):
-    """The model computing in bfloat16 is held as the benchmark holds a
-    cell that states it: to the reference's own bfloat16 twin, leaf by
-    leaf and on the angle (`check_gradient`), where the float8 control
-    in the step's place fails."""
-    from benchmarks.drivers import train
-
-    held = types.SimpleNamespace(
-        **{k: getattr(reference, k) for k in dir(reference)
-           if not k.startswith("__")},
-        STATED_RATIO=reference.TWIN_RATIO,
-    )
-    features = {"input_ids": seeded.ids}
-    labels = np.zeros(len(seeded.ids), np.int32)
-    _, got = loss_and_grads(
-        model_of(CONFIG, bf16=True), seeded.variables, seeded.ids
-    )
-    check = train.check_gradient(
-        held, seeded.flat, features, labels, CONFIG, seeded.want, got
-    )
-    assert check["ok"], sorted(
-        check["shares"].items(), key=lambda kv: -kv[1]
-    )[:4]
-    _, control = reference.loss_and_grads(
-        seeded.flat, features, labels, CONFIG, tower="float8_e4m3fn"
-    )
-    control = {k: np.asarray(v, np.float32) for k, v in control.items()}
-    assert not train.check_gradient(
-        held, seeded.flat, features, labels, CONFIG, seeded.want, control
-    )["ok"]
 
 
 def test_rotary_tables_of_model_and_reference_agree():
@@ -242,10 +151,7 @@ def test_rotary_tables_of_model_and_reference_agree():
     reference's, at the published numbers: the fast pairs keep theta's
     frequency, the slow ones turn 64 times slower, and half a head
     turns."""
-    with open(os.path.join(
-        ROOT, "benchmarks", "configs", "laguna-xs.2.json"
-    )) as f:
-        config = json.load(f)
+    config = decoder_cases.cell_config("laguna-xs.2")
     full = config["rope_parameters"]["full_attention"]
     ours, theirs = zoo.rope_of(full, 128), reference.rope_of(full, 128)
     assert ours.columns == theirs.columns == 64
@@ -266,44 +172,6 @@ def test_rotary_tables_of_model_and_reference_agree():
     turned = zoo.partial_rotary(x, ours)
     np.testing.assert_array_equal(turned[..., 64:], x[..., 64:])
     assert not np.allclose(turned[0, 1:, 0, :64], 1.0)
-
-
-def test_published_sizes_hold_what_the_configuration_states():
-    """The parameters of the cut model at the published widths, counted
-    from the built model's shapes: the numbers in the configuration's
-    `deployment`."""
-    with open(os.path.join(
-        ROOT, "benchmarks", "configs", "laguna-xs.2.json"
-    )) as f:
-        config = json.load(f)
-    from elasticdl_tpu.common.model_handler import _call_with_params
-
-    model = _call_with_params(
-        zoo.custom_model, config["model_params"].format(**config)
-    )
-    assert [layer[:2] for layer in model.config.layers] == [
-        (zoo.FULL, 48), (zoo.WINDOW, 64), (zoo.WINDOW, 64),
-        (zoo.WINDOW, 64), (zoo.FULL, 48),
-    ]
-    assert [layer[2] for layer in model.config.layers] == [False] + [True] * 4
-    assert model.config.dtype == jnp.bfloat16 and model.config.remat
-    shapes = jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), {"input_ids": jnp.zeros((1, 512), jnp.int32)}
-    ))
-    by_top = {}
-    for name, leaf in trees.flat(shapes["params"]).items():
-        top = name.split("/")[0]
-        by_top[top] = by_top.get(top, 0) + int(np.prod(leaf.shape))
-    assert by_top == {
-        "layer_0": 79_794_176, "layer_1": 142_217_216,
-        "layer_2": 142_217_216, "layer_3": 142_217_216,
-        "layer_4": 133_795_840, "token_embedding": 25_690_112,
-        "lm_head_kernel": 25_690_112, "final_norm": 2_048,
-    }
-    total = sum(by_top.values())
-    assert total == 691_623_936
-    assert "691,623,936" in config["deployment"]
-    assert 12 * total > 0.25 * 16.9e9          # over the floor, held alone
 
 
 # ---- the routed layer at 256-style routing --------------------------------
@@ -408,77 +276,3 @@ def test_the_walk_at_this_models_routing(monkeypatch):
 # ---- through the system ---------------------------------------------------
 
 
-def test_trainer_carries_the_gate_mean(seeded):
-    from elasticdl_tpu.worker.sync import ModelOwner
-    from elasticdl_tpu.worker.trainer import Trainer
-
-    trainer = Trainer(
-        model=model_of(CONFIG), optimizer=zoo.optimizer(1e-3),
-        loss_fn=zoo.loss,
-    )
-    batch = {"features": {"input_ids": seeded.ids},
-             "labels": np.zeros(len(seeded.ids), np.int32)}
-    state = trainer.init_state(jax.random.PRNGKey(0), batch["features"])
-    state, loss = trainer.train_on_batch(state, batch)
-    assert float(loss) == pytest.approx(seeded.want_loss, rel=1e-3)
-    owner = ModelOwner.__new__(ModelOwner)
-    owner.state, owner.lock = state, threading.Lock()
-    value, metrics = owner.fetch_loss(loss)
-    assert value == pytest.approx(float(loss))
-    for layer in range(5):
-        # a seeded gate sits near a half; one that closes silences its layer
-        assert 0.3 < metrics[f"layer_{layer}/attn/gate_mean"] < 0.7
-    assert "layer_0/moe/routed/routed_here_ratio" not in metrics
-    assert metrics["layer_1/moe/routed/dropped_tokens"] == 0.0
-    assert 0.0 < metrics["layer_4/moe/routed/routed_here_ratio"] < 1.0
-
-
-def test_cli_job_of_two_tasks_with_a_falling_loss(tmp_path):
-    from elasticdl_tpu.client.main import main as cli_main
-    from elasticdl_tpu.common import metrics as metrics_lib
-    from elasticdl_tpu.worker.worker import Worker
-
-    path = str(tmp_path / "train.tfrecord")
-    datagen.write_task_file(
-        path, 7, {"format": "tokens", "seq_len": 32, "vocab_size": 50},
-        64, 2,
-    )
-    workers = []
-    init = Worker.__init__
-
-    def recording_init(self, *args, **kwargs):
-        init(self, *args, **kwargs)
-        workers.append(self)
-
-    Worker.__init__ = recording_init
-    try:
-        rc = cli_main([
-            "train", "--model_zoo", os.path.join(ROOT, "model_zoo"),
-            "--model_def", "laguna.laguna.custom_model",
-            "--model_params",
-            "hidden=32;num_layers=3;"
-            "layer_types=['full_attention','sliding_attention',"
-            "'sliding_attention'];"
-            "mlp_layer_types=['dense','sparse','sparse'];"
-            "heads_per_layer=[6,8,8];kv_heads=2;head_dim=16;window=12;"
-            "dense_width=48;expert_width=16;shared_width=16;num_experts=16;"
-            "top_k=2;held_experts=(0,8);vocab_size=50;remat=True;lr=0.01",
-            "--distribution_strategy", "Local", "--training_data", path,
-            "--minibatch_size", "8", "--records_per_task", "64",
-            "--num_epochs", "1",
-        ])
-    finally:
-        Worker.__init__ = init
-    assert rc == 0
-    losses = [float(x) for x in workers[0].losses]
-    assert len(losses) == 16                      # two tasks of 8 steps
-    assert np.mean(losses[-4:]) < np.mean(losses[:4]) - 0.1
-    registry = metrics_lib.default_registry()
-    assert registry.value("worker_moe_dropped_tokens_total") == 0.0
-    assert 0.0 < registry.value(
-        "worker_moe_routed_here_ratio", layer="layer_1/moe/routed"
-    ) < 1.0
-    for layer in range(3):
-        assert 0.2 < registry.value(
-            "worker_attention_gate_mean_ratio", layer=f"layer_{layer}/attn"
-        ) < 0.8

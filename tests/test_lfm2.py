@@ -8,28 +8,23 @@ deployment adding up to the uncut layer, the short convolution's kernels
 against its shifted form, the sown gauge through the Trainer, the
 published sizes' parameter count, and a two-task job through the CLI."""
 
-import functools
-import json
-import os
 import re
-import threading
-import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import datagen, trees
 from benchmarks.reference import lfm2_moe as reference
 from elasticdl_tpu.layers.moe import ROUTER_STATE, RoutedExperts
-from elasticdl_tpu.layers.step_metrics import AUX_LOSS, STEP_METRICS
+from elasticdl_tpu.layers.step_metrics import STEP_METRICS
 from elasticdl_tpu.ops import short_conv
+from elasticdl_tpu.ops.flash_attention import stream_shapes_ok
 from model_zoo.common.decoder import MoEFFN
 from model_zoo.lfm2 import lfm2_moe as zoo
-from tests import remat_cases
+from tests import decoder_cases
+from tests.decoder_cases import MUTABLE, computed, seeded  # noqa: F401
 
-ROOT = os.path.join(os.path.dirname(__file__), "..")
 # the published pattern's first eight entries; the cut's layers 0, 2..5;
 # 4 query heads of 8 over 2 K/V heads, 16 experts of which 4 are held
 CONFIG = dict(
@@ -41,114 +36,98 @@ CONFIG = dict(
     intermediate_size=48, moe_intermediate_size=16,
     num_experts_published=16, num_experts_per_tok=2, held_experts=[4, 4],
     routed_scaling_factor=1, renorm_eps=1e-6, vocab_size=50, norm_eps=1e-5,
-    use_bf16=True,
+    learning_rate=1e-3, use_bf16=True,
 )
-MUTABLE = [AUX_LOSS, STEP_METRICS, ROUTER_STATE]
 
 
-def model_of(config, **overrides):
-    sizes = dict(
-        hidden=config["hidden_size"], layer_types=config["layer_types"],
-        num_dense_layers=config["num_dense_layers_published"],
-        layers=config["layers_held"], heads=config["num_attention_heads"],
-        kv_heads=config["num_key_value_heads"],
-        conv_kernel=config["conv_L_cache"],
-        rope_theta=config["rope_parameters"]["rope_theta"],
-        dense_width=config["intermediate_size"],
-        expert_width=config["moe_intermediate_size"],
-        num_experts=config["num_experts_published"],
-        top_k=config["num_experts_per_tok"],
-        held_experts=config["held_experts"],
-        routed_scaling=config["routed_scaling_factor"],
-        renorm_eps=config["renorm_eps"], vocab_size=config["vocab_size"],
-        eps=config["norm_eps"], remat=True,
-    )
-    sizes.update(overrides)
-    return zoo.custom_model(**sizes)
+def the_head_is_tied(model, seeded, got):
+    assert "lm_head_kernel" not in got
 
 
-def ids_of(rows, length=64, seed=0):
-    return np.random.RandomState(seed).randint(
-        0, CONFIG["vocab_size"], (rows, length)
-    ).astype(np.int32)
+def published_also(model, config, shapes, flat, by_top):
+    assert list(model.config.layers) == [
+        (zoo.CONV, False), (zoo.FULL, True), (zoo.CONV, True),
+        (zoo.CONV, True), (zoo.CONV, True),
+    ]
+    assert len(model.config.layers) == config["num_hidden_layers"]
+    assert tuple(config["layer_types"]) == zoo.PUBLISHED_LAYER_TYPES
+    assert model.config.hidden // model.config.heads == config["head_dim"]
+    assert model.config.renorm_eps == 1e-6
+    assert sum(
+        v for k, v in flat.items() if k.startswith("layer_2/conv/")
+    ) == 16_783_360
+    assert sum(
+        v for k, v in flat.items() if k.startswith("layer_1/attn/")
+    ) == 10_485_888
 
 
-def loss_and_grads(model, variables, ids, room=None):
-    """The objective the Trainer builds: the mean of the model's
-    per-position losses (this model sows no auxiliary loss)."""
-    state = {k: v for k, v in variables.items() if k != "params"}
-
-    def loss_of(params):
-        out, _ = model.apply(
-            {"params": params, **state}, {"input_ids": ids}, mutable=MUTABLE,
-            **({} if room is None else {"room": room}),
-        )
-        return zoo.loss(None, out.astype(jnp.float32))
-
-    with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(loss_of)(variables["params"])
-    return float(loss), {
-        k: np.asarray(v, np.float32) for k, v in trees.flat(grads).items()
-    }
+def the_conv_gauge_is_carried(metrics, state, loss, seeded):
+    for layer in (0, 2, 3, 4):
+        assert 0.01 < metrics[f"layer_{layer}/conv/out_rms_ratio"] < 2.0
+    assert "layer_1/conv/out_rms_ratio" not in metrics      # attention
+    assert "layer_0/moe/routed/routed_here_ratio" not in metrics
+    assert metrics["layer_1/moe/routed/dropped_tokens"] == 0.0
+    assert 0.0 < metrics["layer_4/moe/routed/routed_here_ratio"] < 1.0
 
 
-def seeded_of(config, ids):
-    model = model_of(config)
-    variables = model.init(jax.random.PRNGKey(0), {"input_ids": ids})
-    flat = {
-        k: np.asarray(v) for k, v in trees.flat(variables["params"]).items()
-    }
-    want_loss, want = reference.loss_and_grads(
-        flat, {"input_ids": ids}, None, config
-    )
-    return types.SimpleNamespace(
-        ids=ids, variables=variables, flat=flat, want_loss=want_loss,
-        want={k: np.asarray(v) for k, v in want.items()},
-    )
+def job_gauges(registry):
+    assert registry.value("worker_moe_dropped_tokens_total") == 0.0
+    assert 0.0 < registry.value(
+        "worker_moe_routed_here_ratio", layer="layer_1/moe/routed"
+    ) < 1.0
+    for layer in (0, 2):
+        assert 0.01 < registry.value(
+            "worker_short_conv_out_rms_ratio", layer=f"layer_{layer}/conv"
+        ) < 2.0
 
 
-@pytest.fixture(scope="module")
-def seeded():
+DECODER = decoder_cases.Decoder(
+    zoo=zoo, reference=reference, cell="lfm2-24b-a2b", config=CONFIG,
     # ids of seed 0 land one router slot on a bfloat16 tie: its leaf reads
     # 3.3 twin's errors where the rule allows 3 (seeds 1-3 read under 0.7)
-    return seeded_of(CONFIG, ids_of(8, seed=1))
-
-
-def assert_leaf_by_leaf(got, want, limit=1e-4):
-    assert set(got) == set(want)
-    for name, ref in want.items():
-        error = np.linalg.norm(got[name] - ref) / np.linalg.norm(ref)
-        assert error < limit, (name, error)
-
-
-def test_float32_matches_reference_leaf_by_leaf(seeded):
-    loss, got = loss_and_grads(model_of(CONFIG), seeded.variables, seeded.ids)
-    assert abs(loss - seeded.want_loss) < 1e-4 * abs(seeded.want_loss)
+    length=64, seed=1,
     # four conv blocks of 3 operator leaves and one attention block of 6,
     # two norms a block, 2 (dense) or 3 (routed) feed-forward leaves, the
     # tied embedding and the final norm: no head leaf
-    assert len(got) == (3 + 2 + 2) + (6 + 2 + 3) + 3 * (3 + 2 + 3) + 2
-    assert "lm_head_kernel" not in got
-    assert_leaf_by_leaf(got, seeded.want)
-
-
-def test_kernels_match_reference_leaf_by_leaf():
-    """Width 128 in 2 heads of 64 over ONE K/V head, 256 positions: the
-    streaming attention kernels at half a lane tile and the short-conv
-    kernels (two tiles of 128 rows), all interpreted here."""
-    from elasticdl_tpu.ops.flash_attention import stream_shapes_ok
-
-    config = dict(
-        CONFIG, hidden_size=128, num_attention_heads=2,
-        num_key_value_heads=1, layers_held=[0, 2, 3], num_hidden_layers=3,
-    )
-    assert stream_shapes_ok((1, 256, 2, 64), (1, 256, 1, 64),
-                            (1, 256, 1, 64))
-    assert short_conv.short_conv_shapes_ok((1, 256, 384), (3, 128))
-    seeded = seeded_of(config, ids_of(1, length=256, seed=2))
-    loss, got = loss_and_grads(model_of(config), seeded.variables, seeded.ids)
-    assert abs(loss - seeded.want_loss) < 1e-4 * abs(seeded.want_loss)
-    assert_leaf_by_leaf(got, seeded.want, 2e-4)
+    leaves=(3 + 2 + 2) + (6 + 2 + 3) + 3 * (3 + 2 + 3) + 2,
+    float32_also=the_head_is_tied,
+    # width 128 in 2 heads of 64 over ONE K/V head, 256 positions: the
+    # streaming attention kernels at half a lane tile and the short-conv
+    # kernels (two tiles of 128 rows), all interpreted here
+    kernels=decoder_cases.Kernels(
+        config=dict(
+            hidden_size=128, num_attention_heads=2, num_key_value_heads=1,
+            layers_held=[0, 2, 3], num_hidden_layers=3,
+        ),
+        length=256,
+        admitted=(
+            (stream_shapes_ok, (1, 256, 2, 64), (1, 256, 1, 64),
+             (1, 256, 1, 64)),
+            (short_conv.short_conv_shapes_ok, (1, 256, 384), (3, 128)),
+        ),
+    ),
+    published=decoder_cases.Published(
+        by_top={
+            "layer_0": 89_139_200, "layer_1": 86_118_528,
+            "layer_2": 92_416_000, "layer_3": 92_416_000,
+            "layer_4": 92_416_000, "token_embedding": 16_777_216,
+            "final_norm": 2_048,
+        },
+        total=469_284_992, also=published_also,
+    ),
+    trainer_gauges=the_conv_gauge_is_carried,
+    job=decoder_cases.Job(
+        params=(
+            "hidden=32;layer_types=['conv','conv','full_attention','conv'];"
+            "num_dense_layers=2;layers=[0,2,3];heads=4;kv_heads=2;"
+            "dense_width=48;expert_width=16;num_experts=16;top_k=2;"
+            "held_experts=(0,8);vocab_size=50;remat=True;lr=0.01"
+        ),
+        gauges=job_gauges, falls_by=0.1, all_the_room=False,
+    ),
+)
+model_of = DECODER.model_of
+TestConformance = decoder_cases.conformance(DECODER)
 
 
 def test_each_part_of_the_mathematics_is_seen(seeded):
@@ -190,113 +169,6 @@ def test_each_part_of_the_mathematics_is_seen(seeded):
     )
     shift = np.abs(np.asarray(with_eps - without)).max()
     assert 0.0 < shift < 1e-5 * np.abs(np.asarray(without)).max()
-
-
-@pytest.fixture(scope="module")
-def saved_core(seeded):
-    """bf16 -> (loss, gradients) of the model as the cells run it."""
-    return functools.lru_cache(None)(lambda bf16: loss_and_grads(
-        model_of(CONFIG, bf16=bf16), seeded.variables, seeded.ids
-    ))
-
-
-@pytest.mark.parametrize("bf16", [False, True], ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("other", remat_cases.OTHERS)
-def test_saving_the_attention_core_changes_no_bit(seeded, saved_core,
-                                                  monkeypatch, other, bf16):
-    """`remat=True` against the plain `nn.remat` every commit before ran
-    and against no remat at all, bit for bit."""
-    remat_cases.assert_saving_changes_nothing(
-        zoo, monkeypatch, other,
-        lambda remat, room=None: loss_and_grads(
-            model_of(CONFIG, bf16=bf16, remat=remat), seeded.variables,
-            seeded.ids, room,
-        ),
-        saved_core(bf16),
-    )
-
-
-def test_bfloat16_inside_the_twins_rule(seeded):
-    """The model computing in bfloat16 is held as the benchmark holds a
-    cell that states it: to the reference's own bfloat16 twin, leaf by
-    leaf and on the angle (`check_gradient`), where the float8 control
-    in the step's place fails."""
-    from benchmarks.drivers import train
-
-    held = types.SimpleNamespace(
-        **{k: getattr(reference, k) for k in dir(reference)
-           if not k.startswith("__")},
-        STATED_RATIO=reference.TWIN_RATIO,
-    )
-    features = {"input_ids": seeded.ids}
-    labels = np.zeros(len(seeded.ids), np.int32)
-    _, got = loss_and_grads(
-        model_of(CONFIG, bf16=True), seeded.variables, seeded.ids
-    )
-    check = train.check_gradient(
-        held, seeded.flat, features, labels, CONFIG, seeded.want, got
-    )
-    assert check["ok"], sorted(
-        check["shares"].items(), key=lambda kv: -kv[1]
-    )[:4]
-    _, control = reference.loss_and_grads(
-        seeded.flat, features, labels, CONFIG, tower="float8_e4m3fn"
-    )
-    control = {k: np.asarray(v, np.float32) for k, v in control.items()}
-    assert not train.check_gradient(
-        held, seeded.flat, features, labels, CONFIG, seeded.want, control
-    )["ok"]
-
-
-def test_published_sizes_hold_what_the_configuration_states():
-    """The parameters of the cut model at the published widths, counted
-    from the built model's shapes: the numbers in the configuration's
-    `deployment` and its `parameters_held`."""
-    with open(os.path.join(
-        ROOT, "benchmarks", "configs", "lfm2-24b-a2b.json"
-    )) as f:
-        config = json.load(f)
-    from elasticdl_tpu.common.model_handler import _call_with_params
-
-    model = _call_with_params(
-        zoo.custom_model, config["model_params"].format(**config)
-    )
-    assert list(model.config.layers) == [
-        (zoo.CONV, False), (zoo.FULL, True), (zoo.CONV, True),
-        (zoo.CONV, True), (zoo.CONV, True),
-    ]
-    assert len(model.config.layers) == config["num_hidden_layers"]
-    assert tuple(config["layer_types"]) == zoo.PUBLISHED_LAYER_TYPES
-    assert model.config.hidden // model.config.heads == config["head_dim"]
-    assert model.config.renorm_eps == 1e-6
-    assert model.config.dtype == jnp.bfloat16 and model.config.remat
-    shapes = jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), {"input_ids": jnp.zeros((1, 512), jnp.int32)}
-    ))
-    flat = {
-        name: int(np.prod(leaf.shape))
-        for name, leaf in trees.flat(shapes["params"]).items()
-    }
-    by_top = {}
-    for name, size in flat.items():
-        top = name.split("/")[0]
-        by_top[top] = by_top.get(top, 0) + size
-    assert by_top == {
-        "layer_0": 89_139_200, "layer_1": 86_118_528,
-        "layer_2": 92_416_000, "layer_3": 92_416_000,
-        "layer_4": 92_416_000, "token_embedding": 16_777_216,
-        "final_norm": 2_048,
-    }
-    assert sum(
-        v for k, v in flat.items() if k.startswith("layer_2/conv/")
-    ) == 16_783_360
-    assert sum(
-        v for k, v in flat.items() if k.startswith("layer_1/attn/")
-    ) == 10_485_888
-    total = sum(by_top.values())
-    assert total == config["parameters_held"] == 469_284_992
-    assert "469,284,992" in config["deployment"]
-    assert 12 * total > 0.25 * 16.9e9          # over the floor, held alone
 
 
 # ---- the routed layer: the epsilon, no shared expert, the shares ----------
@@ -568,74 +440,3 @@ def test_gated_short_conv_jaxpr_is_the_parents():
 # ---- through the system ---------------------------------------------------
 
 
-def test_trainer_carries_the_conv_gauge(seeded):
-    from elasticdl_tpu.worker.sync import ModelOwner
-    from elasticdl_tpu.worker.trainer import Trainer
-
-    trainer = Trainer(
-        model=model_of(CONFIG), optimizer=zoo.optimizer(1e-3),
-        loss_fn=zoo.loss,
-    )
-    batch = {"features": {"input_ids": seeded.ids},
-             "labels": np.zeros(len(seeded.ids), np.int32)}
-    state = trainer.init_state(jax.random.PRNGKey(0), batch["features"])
-    state, loss = trainer.train_on_batch(state, batch)
-    assert float(loss) == pytest.approx(seeded.want_loss, rel=1e-3)
-    owner = ModelOwner.__new__(ModelOwner)
-    owner.state, owner.lock = state, threading.Lock()
-    value, metrics = owner.fetch_loss(loss)
-    assert value == pytest.approx(float(loss))
-    for layer in (0, 2, 3, 4):
-        assert 0.01 < metrics[f"layer_{layer}/conv/out_rms_ratio"] < 2.0
-    assert "layer_1/conv/out_rms_ratio" not in metrics      # attention
-    assert "layer_0/moe/routed/routed_here_ratio" not in metrics
-    assert metrics["layer_1/moe/routed/dropped_tokens"] == 0.0
-    assert 0.0 < metrics["layer_4/moe/routed/routed_here_ratio"] < 1.0
-
-
-def test_cli_job_of_two_tasks_with_a_falling_loss(tmp_path):
-    from elasticdl_tpu.client.main import main as cli_main
-    from elasticdl_tpu.common import metrics as metrics_lib
-    from elasticdl_tpu.worker.worker import Worker
-
-    path = str(tmp_path / "train.tfrecord")
-    datagen.write_task_file(
-        path, 7, {"format": "tokens", "seq_len": 32, "vocab_size": 50},
-        64, 2,
-    )
-    workers = []
-    init = Worker.__init__
-
-    def recording_init(self, *args, **kwargs):
-        init(self, *args, **kwargs)
-        workers.append(self)
-
-    Worker.__init__ = recording_init
-    try:
-        rc = cli_main([
-            "train", "--model_zoo", os.path.join(ROOT, "model_zoo"),
-            "--model_def", "lfm2.lfm2_moe.custom_model",
-            "--model_params",
-            "hidden=32;layer_types=['conv','conv','full_attention','conv'];"
-            "num_dense_layers=2;layers=[0,2,3];heads=4;kv_heads=2;"
-            "dense_width=48;expert_width=16;num_experts=16;top_k=2;"
-            "held_experts=(0,8);vocab_size=50;remat=True;lr=0.01",
-            "--distribution_strategy", "Local", "--training_data", path,
-            "--minibatch_size", "8", "--records_per_task", "64",
-            "--num_epochs", "1",
-        ])
-    finally:
-        Worker.__init__ = init
-    assert rc == 0
-    losses = [float(x) for x in workers[0].losses]
-    assert len(losses) == 16                      # two tasks of 8 steps
-    assert np.mean(losses[-4:]) < np.mean(losses[:4]) - 0.1
-    registry = metrics_lib.default_registry()
-    assert registry.value("worker_moe_dropped_tokens_total") == 0.0
-    assert 0.0 < registry.value(
-        "worker_moe_routed_here_ratio", layer="layer_1/moe/routed"
-    ) < 1.0
-    for layer in (0, 2):
-        assert 0.01 < registry.value(
-            "worker_short_conv_out_rms_ratio", layer=f"layer_{layer}/conv"
-        ) < 2.0

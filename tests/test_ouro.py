@@ -11,11 +11,7 @@ positions, four trips and one) are built ONCE a module and the cases share
 what they compute: the tier-1 run's clock is nearly spent (ISSUE 59)."""
 
 import functools
-import json
 import math
-import os
-import threading
-import types
 
 import flax.linen as nn
 import jax
@@ -23,138 +19,39 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import datagen, trees
 from benchmarks.reference import ouro as reference
 from elasticdl_tpu.layers.step_metrics import AUX_LOSS, STEP_METRICS
+from elasticdl_tpu.ops.flash_attention import stream_shapes_ok
 from model_zoo.common import decoder
 from model_zoo.ouro import ouro as zoo
-from tests import remat_cases
+from tests import decoder_cases
+from tests.decoder_cases import MUTABLE, computed, seeded  # noqa: F401
+from tests.decoder_cases import worst_leaf
 
-ROOT = os.path.join(os.path.dirname(__file__), "..")
 CONFIG = dict(
     hidden_size=64, num_hidden_layers=2, num_hidden_layers_published=4,
     layers_held=[0, 1], num_attention_heads=2, num_key_value_heads=2,
     head_dim=32, intermediate_size=96, total_ut_steps=4, exit_beta=0.05,
-    rope_theta=1e6, vocab_size=128, rms_norm_eps=1e-6, use_bf16=True,
+    rope_theta=1e6, vocab_size=128, rms_norm_eps=1e-6, learning_rate=1e-3,
+    use_bf16=True,
 )
-MUTABLE = [AUX_LOSS, STEP_METRICS]
 # 4 attention kernels, 4 norms, gate | up and down
 BLOCK_LEAVES = 10
 
 
-def model_of(config, **overrides):
-    sizes = dict(
-        hidden=config["hidden_size"],
-        num_layers=config["num_hidden_layers_published"],
-        layers=config["layers_held"], heads=config["num_attention_heads"],
-        kv_heads=config["num_key_value_heads"], head_dim=config["head_dim"],
-        dense_width=config["intermediate_size"],
-        trips=config["total_ut_steps"], exit_beta=config["exit_beta"],
-        rope_theta=config["rope_theta"], vocab_size=config["vocab_size"],
-        eps=config["rms_norm_eps"], remat=True,
-    )
-    sizes.update(overrides)
-    return zoo.custom_model(**sizes)
-
-
-def ids_of(rows, length=128, seed=0):
-    return np.random.RandomState(seed).randint(
-        0, CONFIG["vocab_size"], (rows, length)
-    ).astype(np.int32)
-
-
-def objective(model, params, state, ids, room=None):
-    """The objective the Trainer builds: the mean of the model's
-    per-position losses plus everything sown into AUX_LOSS."""
-    out, sown = model.apply(
-        {"params": params, **state}, {"input_ids": ids}, mutable=MUTABLE,
-        **({} if room is None else {"room": room}),
-    )
-    return zoo.loss(None, out.astype(jnp.float32)) + sum(
-        jax.tree.leaves(sown.get(AUX_LOSS, {}))
-    )
-
-
-def loss_and_grads(model, variables, ids, room=None):
-    state = {k: v for k, v in variables.items() if k != "params"}
-    with jax.default_matmul_precision("highest"):
-        loss, grads = jax.jit(jax.value_and_grad(
-            lambda params: objective(model, params, state, ids, room)
-        ))(variables["params"])
-    return float(loss), {
-        k: np.asarray(v, np.float32) for k, v in trees.flat(grads).items()
-    }
-
-
-def loss_of(model, variables, ids):
-    state = {k: v for k, v in variables.items() if k != "params"}
-    with jax.default_matmul_precision("highest"):
-        return float(jax.jit(
-            lambda params: objective(model, params, state, ids)
-        )(variables["params"]))
-
-
-def seeded_of(config, ids):
-    """Seeded weights with every norm's scale and the gate's bias moved
-    off their seeds, so that each one's place shows in the numbers."""
-    model = model_of(config)
-    variables = {
-        k: v for k, v in model.init(
-            jax.random.PRNGKey(0), {"input_ids": ids}
-        ).items() if k != AUX_LOSS       # as the Trainer drops it
-    }
-    leaves, tree = jax.tree.flatten(variables["params"])
+def moved_off_their_seeds(params):
+    """Every norm's scale and the gate's bias moved off their seeds, so
+    that each one's place shows in the numbers."""
+    leaves, tree = jax.tree.flatten(params)
     keys = jax.random.split(jax.random.PRNGKey(5), len(leaves))
-    variables["params"] = jax.tree.unflatten(tree, [
+    return jax.tree.unflatten(tree, [
         leaf + 0.2 * jax.random.normal(key, leaf.shape)
         if leaf.ndim == 1 else leaf for leaf, key in zip(leaves, keys)
     ])
-    flat = {
-        k: np.asarray(v) for k, v in trees.flat(variables["params"]).items()
-    }
-    want_loss, want = reference.loss_and_grads(
-        flat, {"input_ids": ids}, None, config
-    )
-    return types.SimpleNamespace(
-        ids=ids, variables=variables, flat=flat, want_loss=want_loss,
-        want={k: np.asarray(v) for k, v in want.items()},
-    )
-
-
-@pytest.fixture(scope="module")
-def seeded():
-    return seeded_of(CONFIG, ids_of(8, seed=5))
-
-
-@pytest.fixture(scope="module")
-def computed(seeded):
-    """(loss, gradients) of the float32 model as the cells run it."""
-    return loss_and_grads(model_of(CONFIG), seeded.variables, seeded.ids)
-
-
-def worst_leaf(got, want):
-    assert set(got) == set(want)
-    errors = {
-        name: np.linalg.norm(got[name] - ref) / np.linalg.norm(ref)
-        for name, ref in want.items()
-    }
-    name = max(errors, key=errors.get)
-    return name, errors[name]
-
-
-def test_float32_matches_reference_leaf_by_leaf(seeded, computed):
-    loss, got = computed
-    assert set(seeded.variables) == {"params", STEP_METRICS}   # no buffer
-    assert abs(loss - seeded.want_loss) < 1e-5 * abs(seeded.want_loss)
-    assert got["layer_0/attn/q/kernel"].shape == (64, 2 * 32)
-    assert got["layer_0/mlp/gate_up/kernel"].shape == (64, 2 * 96)
-    assert got["exit_gate/kernel"].shape == (64, 1)
-    name, error = worst_leaf(got, seeded.want)
-    assert error < 1e-4, (name, error)
 
 
 def test_the_weights_are_one_set_of_leaves_with_no_trip_in_a_path(computed):
-    _, got = computed
+    _, got = computed()
     # two blocks, the embedding, the untied head, the final norm, the
     # gate's kernel and bias: what ONE trip would hold, plus the gate
     assert len(got) == 2 * BLOCK_LEAVES + 5
@@ -186,7 +83,7 @@ def test_a_leafs_gradient_is_the_sum_of_the_four_trips_parts(
     parts = reference.trip_grads(
         seeded.flat, {"input_ids": seeded.ids}, None, CONFIG
     )
-    _, got = computed
+    _, got = computed()
     shared = [n for n in got if n.startswith(("layer_", "final_norm"))]
     assert len(shared) == 2 * BLOCK_LEAVES + 1
     for name in shared:
@@ -296,20 +193,188 @@ CONTROLS = {
 }
 
 
-@pytest.mark.parametrize("control", sorted(CONTROLS))
-def test_a_departure_from_the_equations_fails_the_comparison(
-    seeded, monkeypatch, control
-):
+def a_departure_fails_the_comparison(self, seeded, monkeypatch, control):
+    """This model's own rule in the shared case's place: its controls
+    are held on the LOSS (the carried state's norm shows only through the
+    residual sums: a block's first norm rescales what it reads), to 1e-4
+    of it, and the control's control passes to 1e-5."""
     CONTROLS[control](monkeypatch)
     loss = loss_of(model_of(CONFIG, remat=False), seeded.variables,
                    seeded.ids)
     error = abs(loss - seeded.want_loss) / abs(seeded.want_loss)
-    # (the carried state's norm shows only through the residual sums: a
-    # block's first norm rescales what it reads)
     if control == "nothing-moved":
         assert error < 1e-5
     else:
         assert error > 1e-4, error
+
+
+def float32_also(model, seeded, got):
+    assert set(seeded.variables) == {"params", STEP_METRICS}   # no buffer
+    assert got["layer_0/attn/q/kernel"].shape == (64, 2 * 32)
+    assert got["layer_0/mlp/gate_up/kernel"].shape == (64, 2 * 96)
+    assert got["exit_gate/kernel"].shape == (64, 1)
+
+
+def published_also(model, config, shapes, flat, by_top):
+    """Part by part, and the uncut model's 2,667,974,657."""
+    held = config["layers_held"]
+    assert held == [0, 1, 2, 3, 4, 5]
+    assert len(held) == config["num_hidden_layers"]
+    c = model.config
+    assert c.layers == (zoo.FULL_ATTENTION,) * 6
+    assert (c.heads, c.kv_heads, c.head_dim) == (16, 16, 128)
+    assert (c.trips, c.exit_beta, c.dense_width) == (4, 0.05, 5632)
+    assert c.rope.columns == 128
+    assert c.rope.inv_freq[-1] == pytest.approx(1e6 ** (-126 / 128))
+    assert c.eps == 1e-6
+    assert set(config["layer_types"]) == {zoo.FULL_ATTENTION}
+    assert len(config["layer_types"]) == 48
+    assert {
+        k[len("layer_1/"):]: v for k, v in flat.items()
+        if k.startswith("layer_1/")
+    } == {
+        "attn/q/kernel": 4_194_304, "attn/k/kernel": 4_194_304,
+        "attn/v/kernel": 4_194_304, "attn/o/kernel": 4_194_304,
+        "mlp/gate_up/kernel": 23_068_672, "mlp/down/kernel": 11_534_336,
+        "input_layernorm/scale": 2_048, "input_layernorm_2/scale": 2_048,
+        "post_attention_layernorm/scale": 2_048,
+        "post_attention_layernorm_2/scale": 2_048,
+    }
+    total = sum(by_top.values())
+    uncut = 48 * by_top["layer_0"] + total - 6 * by_top["layer_0"]
+    assert uncut == 2_667_974_657
+    assert f"{uncut:,}" in config["deployment"]
+
+
+def the_trips_gauges_publish_four_values_a_step(sown, state, loss, seeded):
+    from elasticdl_tpu.common import metrics as metrics_lib
+    from elasticdl_tpu.layers import step_metrics
+
+    trips = [f"trip_{t}" for t in (1, 2, 3, 4)]
+    assert {path for path in sown if path.startswith("trip_")} == {
+        f"{trip}/{leaf}" for trip in trips
+        for leaf in ("trip_loss", "trip_exit_mass")
+    } | {"trip_exit_entropy_nats"}
+    # nothing is left for the summary: every one is declared
+    assert not [
+        path for path in step_metrics.publish(sown)
+        if path.startswith("trip_")
+    ]
+    registry = metrics_lib.default_registry()
+    mass = [
+        registry.value("worker_trip_exit_mass_ratio", trip=trip)
+        for trip in trips
+    ]
+    assert sum(mass) == pytest.approx(1.0, abs=1e-5)
+    for trip in trips:
+        assert registry.value(
+            "worker_trip_loss_nats", trip=trip
+        ) == pytest.approx(sown[f"{trip}/trip_loss"])
+        assert 3.0 < sown[f"{trip}/trip_loss"] < 7.0
+    assert len(set(sown[f"{trip}/trip_loss"] for trip in trips)) == 4
+    assert registry.value("worker_trip_exit_entropy_nats") == pytest.approx(
+        sown["trip_exit_entropy_nats"]
+    )
+    # the objective the Trainer minimised holds the entropy term
+    assert float(loss) == pytest.approx(
+        sum(m * sown[f"{trip}/trip_loss"] for m, trip in zip(mass, trips))
+        - CONFIG["exit_beta"] * sown["trip_exit_entropy_nats"], abs=0.05
+    )
+
+
+def job_gauges(registry):
+    assert 0.0 < registry.value(
+        "worker_trip_exit_entropy_nats"
+    ) <= math.log(4)
+    assert sum(
+        registry.value("worker_trip_exit_mass_ratio", trip=f"trip_{t}")
+        for t in (1, 2, 3, 4)
+    ) == pytest.approx(1.0, abs=1e-4)
+
+
+def scopes_also(text):
+    """The block's scopes reach the operations' names INSIDE the trips'
+    loop, the exit and the head outside it, and the profiler's table
+    reads through the loop's structure."""
+    from elasticdl_tpu.common import profiler, programs
+
+    inside = ("jit(step)/jvp(Ouro)/ouro/trips/while/body/checkpoint/"
+              "layer_1/ouro/norm/add")
+    assert programs.split_op_name(inside) == (
+        "Ouro/ouro/trips/layer_1/ouro/norm", "forward"
+    )
+    # a block's scope is the innermost entry inside the loop's; what the
+    # loop does beside its blocks is the loop's own
+    assert "ouro/trips/while/body" in text
+    assert profiler.catalogue_scope(
+        "Ouro/ouro/trips/layer_1/attn/ouro/attn/q"
+    ) == "ouro/attn"
+    assert profiler.catalogue_scope(
+        "Ouro/ouro/trips/layer_0/ouro/dense_ffn/mlp/down"
+    ) == "ouro/dense_ffn"
+    assert profiler.catalogue_scope("Ouro/ouro/trips") == "ouro/trips"
+    assert profiler.catalogue_scope("ouro/exit/exit_gate") == "ouro/exit"
+
+
+DECODER = decoder_cases.Decoder(
+    zoo=zoo, reference=reference, cell="ouro-2.6b", config=CONFIG,
+    length=128, seed=5, reseed=moved_off_their_seeds,
+    # two blocks, the embedding, the untied head, the final norm, the
+    # gate's kernel and bias
+    leaves=2 * BLOCK_LEAVES + 5, float32_also=float32_also, loss_limit=1e-5,
+    # a group of ONE at heads of 128 with rotary over the whole head, two
+    # tiles of 128 positions, two trips: the streaming kernels
+    # (interpreted here) inside the trips' loop
+    kernels=decoder_cases.Kernels(
+        config=dict(
+            num_attention_heads=2, num_key_value_heads=2, head_dim=128,
+            layers_held=[0], num_hidden_layers=1, total_ut_steps=2,
+        ),
+        length=256,
+        admitted=((stream_shapes_ok, (1, 256, 2, 128), (1, 256, 2, 128),
+                   (1, 256, 2, 128)),),
+    ),
+    # (four named products of each, `ffn_out` among them)
+    remat_types=(False,),
+    # the gate's bias is ONE number, a sum over 1,016 positions' roundings
+    # here where the cell's sums 8,191 and the twin's own error in it may
+    # come out near nothing: at a test's size it is held as a norm's scale
+    twin_held=dict(
+        LEAF_REL_L2=(("exit_gate/bias$", 4.5e-2),) + reference.LEAF_REL_L2,
+    ),
+    controls=CONTROLS,
+    published=decoder_cases.Published(
+        by_top={
+            **{f"layer_{i}": 51_388_416 for i in range(6)},
+            "token_embedding": 100_663_296, "lm_head_kernel": 100_663_296,
+            "final_norm": 2_048, "exit_gate": 2_049,
+        },
+        total=509_661_185, also=published_also,
+    ),
+    trainer_gauges=the_trips_gauges_publish_four_values_a_step,
+    job=decoder_cases.Job(
+        params=(
+            "hidden=32;num_layers=4;layers=[0,1];heads=2;kv_heads=2;"
+            "head_dim=16;dense_width=48;trips=4;vocab_size=50;remat=True;"
+            "lr=0.03"
+        ),
+        gauges=job_gauges,
+    ),
+    scopes=decoder_cases.Scopes(
+        prefix="ouro",
+        names=("embed", "trips", "norm", "attn", "dense_ffn", "exit",
+               "head_ce"),
+        remat=True, also=scopes_also,
+    ),
+)
+model_of, loss_and_grads = DECODER.model_of, DECODER.loss_and_grads
+loss_of, objective = DECODER.loss_of, DECODER.objective
+TestConformance = decoder_cases.conformance(
+    DECODER,
+    test_a_departure_from_the_mathematics_fails_the_comparison=(
+        a_departure_fails_the_comparison
+    ),
+)
 
 
 def test_the_exit_distribution_is_the_survival_products():
@@ -377,7 +442,7 @@ def test_the_entropy_reaches_the_objective_at_minus_beta(seeded, computed):
     assert 0.0 < entropy <= math.log(4)
     (term,) = sown[AUX_LOSS]["exit_entropy"]
     assert float(term) == pytest.approx(-CONFIG["exit_beta"] * entropy)
-    loss, got = computed
+    loss, got = computed()
     assert loss == pytest.approx(float(out.mean()) + float(term), rel=1e-5)
     # the per-position predictions are the exit-weighted sum of the four
     # trips' losses: between the best and the worst trip's
@@ -397,77 +462,6 @@ def test_the_entropy_reaches_the_objective_at_minus_beta(seeded, computed):
 
 
 # ---- remat, kernels, types --------------------------------------------------
-
-
-@pytest.mark.parametrize("other", remat_cases.OTHERS)
-def test_the_remat_policy_changes_no_bit(seeded, computed, monkeypatch,
-                                         other):
-    """`remat=True` against the plain `nn.remat`, against no remat at all
-    and against every named product kept (four of each, `ffn_out` among
-    them), bit for bit."""
-    remat_cases.assert_saving_changes_nothing(
-        zoo, monkeypatch, other,
-        lambda remat, room=None: loss_and_grads(
-            model_of(CONFIG, remat=remat), seeded.variables, seeded.ids,
-            room,
-        ),
-        computed,
-    )
-
-
-def test_kernels_match_reference_leaf_by_leaf():
-    """A group of ONE at heads of 128 with rotary over the whole head, two
-    tiles of 128 positions, two trips: the streaming kernels (interpreted
-    here) inside the trips' loop."""
-    from elasticdl_tpu.ops.flash_attention import stream_shapes_ok
-
-    config = dict(
-        CONFIG, num_attention_heads=2, num_key_value_heads=2, head_dim=128,
-        layers_held=[0], num_hidden_layers=1, total_ut_steps=2,
-    )
-    assert stream_shapes_ok((1, 256, 2, 128), (1, 256, 2, 128),
-                            (1, 256, 2, 128))
-    seeded = seeded_of(config, ids_of(1, length=256, seed=2))
-    loss, got = loss_and_grads(model_of(config), seeded.variables, seeded.ids)
-    assert abs(loss - seeded.want_loss) < 1e-4 * abs(seeded.want_loss)
-    name, error = worst_leaf(got, seeded.want)
-    assert error < 2e-4, (name, error)
-
-
-def test_bfloat16_inside_the_twins_rule(seeded):
-    """The model computing in bfloat16 is held as the benchmark holds a
-    cell that states it: to the reference's own bfloat16 twin, leaf by
-    leaf and on the angle (`check_gradient`), where the float8 control
-    in the step's place fails."""
-    from benchmarks.drivers import train
-
-    held = types.SimpleNamespace(
-        **{k: getattr(reference, k) for k in dir(reference)
-           if not k.startswith("__")},
-        STATED_RATIO=reference.TWIN_RATIO,
-    )
-    # the gate's bias is ONE number, a sum over 1,016 positions' roundings
-    # here where the cell's sums 8,191 and the twin's own error in it may
-    # come out near nothing: at a test's size it is held as a norm's scale
-    held.LEAF_REL_L2 = (("exit_gate/bias$", 4.5e-2),) + reference.LEAF_REL_L2
-    features = {"input_ids": seeded.ids}
-    labels = np.zeros(len(seeded.ids), np.int32)
-    _, got = loss_and_grads(
-        model_of(CONFIG, bf16=True), seeded.variables, seeded.ids
-    )
-    check = train.check_gradient(
-        held, seeded.flat, features, labels, CONFIG, seeded.want, got
-    )
-    assert check["ok"], sorted(
-        check["shares"].items(), key=lambda kv: -kv[1]
-    )[:4]
-    _, control = reference.loss_and_grads(
-        seeded.flat, features, labels, CONFIG, tower="float8_e4m3fn"
-    )
-    control = {k: np.asarray(v, np.float32) for k, v in control.items()}
-    assert not train.check_gradient(
-        held, seeded.flat, features, labels, CONFIG, seeded.want, control
-    )["ok"]
 
 
 # ---- the program ------------------------------------------------------------
@@ -492,203 +486,6 @@ def test_the_trips_are_one_loop_in_the_lowered_step():
     assert four.count("stablehlo.while") >= one.count("stablehlo.while")
 
 
-def test_published_sizes_hold_what_the_configuration_states():
-    """The parameters of the cut model at the published widths, counted
-    from the built model's shapes: the numbers in the configuration's
-    `deployment` and its `parameters_held`, part by part, and the uncut
-    model's 2,667,974,657."""
-    with open(os.path.join(
-        ROOT, "benchmarks", "configs", "ouro-2.6b.json"
-    )) as f:
-        config = json.load(f)
-    from elasticdl_tpu.common.model_handler import _call_with_params
-
-    model = _call_with_params(
-        zoo.custom_model, config["model_params"].format(**config)
-    )
-    held = config["layers_held"]
-    assert held == [0, 1, 2, 3, 4, 5]
-    assert len(held) == config["num_hidden_layers"]
-    c = model.config
-    assert c.layers == (zoo.FULL_ATTENTION,) * 6
-    assert (c.heads, c.kv_heads, c.head_dim) == (16, 16, 128)
-    assert (c.trips, c.exit_beta, c.dense_width) == (4, 0.05, 5632)
-    assert c.rope.columns == 128
-    assert c.rope.inv_freq[-1] == pytest.approx(1e6 ** (-126 / 128))
-    assert c.dtype == jnp.bfloat16 and c.remat and c.eps == 1e-6
-    assert set(config["layer_types"]) == {zoo.FULL_ATTENTION}
-    assert len(config["layer_types"]) == 48
-    shapes = jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), {"input_ids": jnp.zeros((1, 512), jnp.int32)}
-    ))
-    flat = {
-        name: int(np.prod(leaf.shape))
-        for name, leaf in trees.flat(shapes["params"]).items()
-    }
-    by_top = {}
-    for name, size in flat.items():
-        top = name.split("/")[0]
-        by_top[top] = by_top.get(top, 0) + size
-    assert by_top == {
-        **{f"layer_{i}": 51_388_416 for i in range(6)},
-        "token_embedding": 100_663_296, "lm_head_kernel": 100_663_296,
-        "final_norm": 2_048, "exit_gate": 2_049,
-    }
-    assert {
-        k[len("layer_1/"):]: v for k, v in flat.items()
-        if k.startswith("layer_1/")
-    } == {
-        "attn/q/kernel": 4_194_304, "attn/k/kernel": 4_194_304,
-        "attn/v/kernel": 4_194_304, "attn/o/kernel": 4_194_304,
-        "mlp/gate_up/kernel": 23_068_672, "mlp/down/kernel": 11_534_336,
-        "input_layernorm/scale": 2_048, "input_layernorm_2/scale": 2_048,
-        "post_attention_layernorm/scale": 2_048,
-        "post_attention_layernorm_2/scale": 2_048,
-    }
-    total = sum(by_top.values())
-    assert total == config["parameters_held"] == 509_661_185
-    assert f"{total:,}" in config["deployment"]
-    uncut = 48 * by_top["layer_0"] + total - 6 * by_top["layer_0"]
-    assert uncut == 2_667_974_657
-    assert f"{uncut:,}" in config["deployment"]
-    assert 12 * total > 0.25 * 16.9e9          # over the floor, held alone
-
-
 # ---- through the system ---------------------------------------------------
 
 
-def test_the_trips_gauges_publish_four_values_a_step(seeded):
-    from elasticdl_tpu.common import metrics as metrics_lib
-    from elasticdl_tpu.layers import step_metrics
-    from elasticdl_tpu.worker.sync import ModelOwner
-    from elasticdl_tpu.worker.trainer import Trainer
-
-    trainer = Trainer(
-        model=model_of(CONFIG), optimizer=zoo.optimizer(1e-3),
-        loss_fn=zoo.loss,
-    )
-    batch = {"features": {"input_ids": seeded.ids},
-             "labels": np.zeros(len(seeded.ids), np.int32)}
-    state = trainer.init_state(jax.random.PRNGKey(0), batch["features"])
-    state, loss = trainer.train_on_batch(state, batch)
-    owner = ModelOwner.__new__(ModelOwner)
-    owner.state, owner.lock = state, threading.Lock()
-    value, sown = owner.fetch_loss(loss)
-    assert value == pytest.approx(float(loss))
-    trips = [f"trip_{t}" for t in (1, 2, 3, 4)]
-    assert {path for path in sown if path.startswith("trip_")} == {
-        f"{trip}/{leaf}" for trip in trips
-        for leaf in ("trip_loss", "trip_exit_mass")
-    } | {"trip_exit_entropy_nats"}
-    # nothing is left for the summary: every one is declared
-    assert not [
-        path for path in step_metrics.publish(sown)
-        if path.startswith("trip_")
-    ]
-    registry = metrics_lib.default_registry()
-    mass = [
-        registry.value("worker_trip_exit_mass_ratio", trip=trip)
-        for trip in trips
-    ]
-    assert sum(mass) == pytest.approx(1.0, abs=1e-5)
-    for trip in trips:
-        assert registry.value(
-            "worker_trip_loss_nats", trip=trip
-        ) == pytest.approx(sown[f"{trip}/trip_loss"])
-        assert 3.0 < sown[f"{trip}/trip_loss"] < 7.0
-    assert len(set(sown[f"{trip}/trip_loss"] for trip in trips)) == 4
-    assert registry.value("worker_trip_exit_entropy_nats") == pytest.approx(
-        sown["trip_exit_entropy_nats"]
-    )
-    # the objective the Trainer minimised holds the entropy term
-    assert float(loss) == pytest.approx(
-        sum(m * sown[f"{trip}/trip_loss"] for m, trip in zip(mass, trips))
-        - CONFIG["exit_beta"] * sown["trip_exit_entropy_nats"], abs=0.05
-    )
-
-
-def test_cli_job_of_two_tasks_with_a_falling_loss(tmp_path, monkeypatch):
-    from elasticdl_tpu.client.main import main as cli_main
-    from elasticdl_tpu.common import metrics as metrics_lib
-    from elasticdl_tpu.worker import trainer as trainer_lib
-    from elasticdl_tpu.worker.worker import Worker
-
-    # a device with room for every named product: the gauge reads 1
-    monkeypatch.setattr(
-        trainer_lib, "device_room", lambda mesh: remat_cases.ALL_THE_ROOM
-    )
-    path = str(tmp_path / "train.tfrecord")
-    datagen.write_task_file(
-        path, 7, {"format": "tokens", "seq_len": 32, "vocab_size": 50},
-        64, 2,
-    )
-    workers = []
-    init = Worker.__init__
-
-    def recording_init(self, *args, **kwargs):
-        init(self, *args, **kwargs)
-        workers.append(self)
-
-    Worker.__init__ = recording_init
-    try:
-        rc = cli_main([
-            "train", "--model_zoo", os.path.join(ROOT, "model_zoo"),
-            "--model_def", "ouro.ouro.custom_model",
-            "--model_params",
-            "hidden=32;num_layers=4;layers=[0,1];heads=2;kv_heads=2;"
-            "head_dim=16;dense_width=48;trips=4;vocab_size=50;remat=True;"
-            "lr=0.03",
-            "--distribution_strategy", "Local", "--training_data", path,
-            "--minibatch_size", "8", "--records_per_task", "64",
-            "--num_epochs", "1",
-        ])
-    finally:
-        Worker.__init__ = init
-    assert rc == 0
-    losses = [float(x) for x in workers[0].losses]
-    assert len(losses) == 16                      # two tasks of 8 steps
-    assert np.mean(losses[-4:]) < np.mean(losses[:4]) - 0.05
-    registry = metrics_lib.default_registry()
-    assert registry.value("worker_remat_kept_ratio") == 1.0
-    assert 0.0 < registry.value(
-        "worker_trip_exit_entropy_nats"
-    ) <= math.log(4)
-    assert sum(
-        registry.value("worker_trip_exit_mass_ratio", trip=f"trip_{t}")
-        for t in (1, 2, 3, 4)
-    ) == pytest.approx(1.0, abs=1e-4)
-
-
-def test_the_scopes_reach_the_lowered_operations():
-    """The block's scopes carry the model's prefix into the operations'
-    names INSIDE the trips' loop, the exit and the head outside it, and
-    the profiler's table reads through the loop's structure."""
-    from elasticdl_tpu.common import profiler, programs
-
-    model = model_of(CONFIG)
-    ids = ids_of(1, length=16)
-    variables = model.init(jax.random.PRNGKey(0), {"input_ids": ids})
-    text = jax.jit(
-        lambda v, ids: model.apply(v, {"input_ids": ids}, mutable=MUTABLE)[0]
-    ).lower(variables, ids).as_text(debug_info=True)
-    for scope in ("embed", "trips", "norm", "attn", "dense_ffn", "exit",
-                  "head_ce"):
-        assert f"ouro/{scope}" in profiler.DEVICE_SCOPES
-        assert f"ouro/{scope}/" in text, scope
-    assert "Scope object" not in text
-    inside = ("jit(step)/jvp(Ouro)/ouro/trips/while/body/checkpoint/"
-              "layer_1/ouro/norm/add")
-    assert programs.split_op_name(inside) == (
-        "Ouro/ouro/trips/layer_1/ouro/norm", "forward"
-    )
-    # a block's scope is the innermost entry inside the loop's; what the
-    # loop does beside its blocks is the loop's own
-    assert "ouro/trips/while/body" in text
-    assert profiler.catalogue_scope(
-        "Ouro/ouro/trips/layer_1/attn/ouro/attn/q"
-    ) == "ouro/attn"
-    assert profiler.catalogue_scope(
-        "Ouro/ouro/trips/layer_0/ouro/dense_ffn/mlp/down"
-    ) == "ouro/dense_ffn"
-    assert profiler.catalogue_scope("Ouro/ouro/trips") == "ouro/trips"
-    assert profiler.catalogue_scope("ouro/exit/exit_gate") == "ouro/exit"

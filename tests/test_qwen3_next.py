@@ -11,184 +11,41 @@ layer); controls that each part of the mathematics must fail; bfloat16
 inside the twin's rule; the sown gauges; the published sizes' parameter
 count; and a two-task job through the CLI."""
 
-import functools
-import json
-import os
-import threading
-import types
-
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import datagen, trees
 from benchmarks.reference import qwen3_next as reference
 from elasticdl_tpu.layers import moe
-from elasticdl_tpu.layers.step_metrics import AUX_LOSS, STEP_METRICS
+from elasticdl_tpu.layers.step_metrics import STEP_METRICS
 from elasticdl_tpu.ops import gdn as gdn_ops
 from elasticdl_tpu.ops import short_conv
+from elasticdl_tpu.ops.flash_attention import stream_shapes_ok
 from model_zoo.common import decoder
 from model_zoo.qwen3_next import qwen3_next as zoo
-from tests import remat_cases
+from tests import decoder_cases
+from tests.decoder_cases import MUTABLE, computed, seeded  # noqa: F401
 
-ROOT = os.path.join(os.path.dirname(__file__), "..")
 # one whole period of the published pattern (GDN, GDN, GDN, attention):
 # 2 key heads and 4 value heads of 8, a conv of 4 taps over 48 channels,
 # 4 query heads of 16 (hidden / heads is 8) over 2 K/V heads with the first
 # 4 columns rotated, top-3 of 16 softmax-routed experts 24 wide with 8
 # held, a gated shared expert 24 wide
 CONFIG = dict(
-    hidden_size=32, num_hidden_layers=4, full_attention_interval=4,
+    hidden_size=32, num_hidden_layers=4, num_hidden_layers_published=48,
+    full_attention_interval=4,
     layers_held=[0, 1, 2, 3], num_attention_heads=4, num_key_value_heads=2,
     head_dim=16, partial_rotary_factor=0.25, rope_theta=1e7,
     linear_num_key_heads=2, linear_num_value_heads=4, linear_key_head_dim=8,
     linear_value_head_dim=8, linear_conv_kernel_dim=4,
     moe_intermediate_size=24, shared_expert_intermediate_size=24,
     num_experts=8, num_experts_published=16, num_experts_per_tok=3,
-    held_experts=[4, 8], vocab_size=50, rms_norm_eps=1e-6, use_bf16=True,
+    held_experts=[4, 8], vocab_size=50, rms_norm_eps=1e-6,
+    learning_rate=1e-3, use_bf16=True,
 )
-# (`ROUTER_STATE` is no collection of this model: the sigmoid-scored
-# controls and the sibling models fill it)
-MUTABLE = [AUX_LOSS, STEP_METRICS, moe.ROUTER_STATE]
 GDN_LEAVES, ATTENTION_LEAVES, EXPERT_LEAVES = 7, 6, 6
-
-
-def model_of(config, **overrides):
-    sizes = dict(
-        hidden=config["hidden_size"], num_layers=48,
-        full_attention_interval=config["full_attention_interval"],
-        layers=config["layers_held"], heads=config["num_attention_heads"],
-        kv_heads=config["num_key_value_heads"], head_dim=config["head_dim"],
-        partial_rotary_factor=config["partial_rotary_factor"],
-        rope_theta=config["rope_theta"],
-        gdn_key_heads=config["linear_num_key_heads"],
-        gdn_value_heads=config["linear_num_value_heads"],
-        gdn_head_dim=config["linear_key_head_dim"],
-        conv_kernel=config["linear_conv_kernel_dim"],
-        expert_width=config["moe_intermediate_size"],
-        shared_width=config["shared_expert_intermediate_size"],
-        num_experts=config["num_experts_published"],
-        top_k=config["num_experts_per_tok"],
-        held_experts=config["held_experts"],
-        vocab_size=config["vocab_size"], eps=config["rms_norm_eps"],
-        remat=True,
-    )
-    sizes.update(overrides)
-    return zoo.custom_model(**sizes)
-
-
-def ids_of(rows, length=80, seed=0):
-    return np.random.RandomState(seed).randint(
-        0, CONFIG["vocab_size"], (rows, length)
-    ).astype(np.int32)
-
-
-def loss_and_grads(model, variables, ids, room=None):
-    """The objective the Trainer builds: the mean of the model's
-    per-position losses (this model sows no auxiliary loss)."""
-    state = {k: v for k, v in variables.items() if k != "params"}
-
-    def loss_of(params):
-        out, _ = model.apply(
-            {"params": params, **state}, {"input_ids": ids}, mutable=MUTABLE,
-            **({} if room is None else {"room": room}),
-        )
-        return zoo.loss(None, out.astype(jnp.float32))
-
-    with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(loss_of)(variables["params"])
-    return float(loss), {
-        k: np.asarray(v, np.float32) for k, v in trees.flat(grads).items()
-    }
-
-
-def seeded_of(config, ids):
-    model = model_of(config)
-    variables = model.init(jax.random.PRNGKey(0), {"input_ids": ids})
-    flat = {
-        k: np.asarray(v) for k, v in trees.flat(variables["params"]).items()
-    }
-    want_loss, want = reference.loss_and_grads(
-        flat, {"input_ids": ids}, None, config
-    )
-    return types.SimpleNamespace(
-        ids=ids, variables=variables, flat=flat, want_loss=want_loss,
-        want={k: np.asarray(v) for k, v in want.items()},
-    )
-
-
-@pytest.fixture(scope="module")
-def seeded():
-    # 80 positions: the scan's jnp form pads them to two chunks of 64
-    return seeded_of(CONFIG, ids_of(8, seed=5))
-
-
-def worst_leaf(got, want):
-    assert set(got) == set(want)
-    errors = {
-        name: np.linalg.norm(got[name] - ref) / np.linalg.norm(ref)
-        for name, ref in want.items()
-    }
-    name = max(errors, key=errors.get)
-    return name, errors[name]
-
-
-def test_float32_matches_reference_leaf_by_leaf(seeded):
-    model = model_of(CONFIG)
-    assert list(model.config.layers) == [True, True, True, False]
-    assert set(seeded.variables) == {"params", STEP_METRICS}   # no buffer
-    loss, got = loss_and_grads(model, seeded.variables, seeded.ids)
-    assert abs(loss - seeded.want_loss) < 1e-4 * abs(seeded.want_loss)
-    # two norms a layer beside a GDN mixer's 7 leaves or attention's 6,
-    # and the routed layer's 6 (router, two stacks, the shared expert's
-    # two kernels, its gate); the embedding, the untied head, the final
-    # norm
-    assert len(got) == (
-        3 * GDN_LEAVES + ATTENTION_LEAVES + 4 * (EXPERT_LEAVES + 2) + 3
-    )
-    assert got["layer_0/gdn/qkvz/kernel"].shape == (32, 16 + 16 + 32 + 32)
-    assert got["layer_0/gdn/ba/kernel"].shape == (32, 8)
-    assert got["layer_0/gdn/conv_kernel"].shape == (4, 64)
-    assert got["layer_0/gdn/A_log"].shape == (4,)
-    assert got["layer_0/gdn/o_norm/scale"].shape == (8,)
-    assert got["layer_3/attn/q/kernel"].shape == (32, 4 * 2 * 16)
-    assert got["layer_3/attn/k/kernel"].shape == (32, 32)
-    assert got["layer_3/attn/q_norm/scale"].shape == (16,)
-    assert got["layer_1/moe/routed/router_kernel"].shape == (32, 16)
-    assert got["layer_1/moe/routed/expert_w_gate_up"].shape == (8, 32, 48)
-    assert got["layer_1/moe/shared_gate/kernel"].shape == (32, 1)
-    name, error = worst_leaf(got, seeded.want)
-    assert error < 1e-4, (name, error)
-
-
-def test_kernels_match_reference_leaf_by_leaf():
-    """One key head and two value heads of 128 at 128 positions (two
-    chunks: the state crosses a boundary), the SiLU conv at 512 columns,
-    the streaming attention at two query heads of 128 over one K/V head
-    with 32 columns rotated, and the routed layers, all interpreted
-    here."""
-    from elasticdl_tpu.ops.flash_attention import stream_shapes_ok
-
-    config = dict(
-        CONFIG, hidden_size=128, linear_num_key_heads=1,
-        linear_num_value_heads=2, linear_key_head_dim=128,
-        linear_value_head_dim=128, num_attention_heads=2,
-        num_key_value_heads=1, head_dim=128, layers_held=[2, 3],
-        num_hidden_layers=2,
-    )
-    assert gdn_ops.gdn_shapes_ok(
-        (1, 128, 1, 128), (1, 128, 1, 128), (1, 128, 2, 128)
-    )
-    assert short_conv.silu_conv_shapes_ok((1, 128, 512), (4, 512))
-    assert stream_shapes_ok((1, 128, 2, 128), (1, 128, 1, 128),
-                            (1, 128, 1, 128))
-    seeded = seeded_of(config, ids_of(1, length=128, seed=2))
-    loss, got = loss_and_grads(model_of(config), seeded.variables, seeded.ids)
-    assert abs(loss - seeded.want_loss) < 1e-4 * abs(seeded.want_loss)
-    name, error = worst_leaf(got, seeded.want)
-    assert error < 2e-4, (name, error)
 
 
 # ---- each mechanism alone --------------------------------------------------
@@ -560,26 +417,173 @@ CONTROLS = {
 }
 
 
-@pytest.mark.parametrize("control", sorted(CONTROLS))
-def test_a_departure_from_the_mathematics_fails_the_comparison(
-        seeded, monkeypatch, control):
-    """The comparison that passes the model fails each of these: the gate
-    before the output norm, one statistic over all heads, plain norm
-    scales, value heads on other key heads, no decay, no L2 norms, no
-    conv, the whole head rotated or none of it, one gate a head, sigmoid
-    scores, weights not renormalised."""
-    change = CONTROLS[control]
-    overrides = change if isinstance(change, dict) else {}
-    if not overrides:
-        change(monkeypatch)
-    loss, got = loss_and_grads(
-        model_of(CONFIG, **overrides), seeded.variables, seeded.ids
+def float32_also(model, seeded, got):
+    assert list(model.config.layers) == [True, True, True, False]
+    assert set(seeded.variables) == {"params", STEP_METRICS}   # no buffer
+    assert got["layer_0/gdn/qkvz/kernel"].shape == (32, 16 + 16 + 32 + 32)
+    assert got["layer_0/gdn/ba/kernel"].shape == (32, 8)
+    assert got["layer_0/gdn/conv_kernel"].shape == (4, 64)
+    assert got["layer_0/gdn/A_log"].shape == (4,)
+    assert got["layer_0/gdn/o_norm/scale"].shape == (8,)
+    assert got["layer_3/attn/q/kernel"].shape == (32, 4 * 2 * 16)
+    assert got["layer_3/attn/k/kernel"].shape == (32, 32)
+    assert got["layer_3/attn/q_norm/scale"].shape == (16,)
+    assert got["layer_1/moe/routed/router_kernel"].shape == (32, 16)
+    assert got["layer_1/moe/routed/expert_w_gate_up"].shape == (8, 32, 48)
+    assert got["layer_1/moe/shared_gate/kernel"].shape == (32, 1)
+
+
+def published_also(model, config, shapes, flat, by_top):
+    """Part by part, and every number of the catalog row under its own
+    key."""
+    held = config["layers_held"]
+    assert held == [0, 1, 2, 3] and len(held) == config["num_hidden_layers"]
+    c = model.config
+    assert c.layers == (True, True, True, False)
+    assert (c.num_experts, c.top_k, c.held_experts) == (512, 10, (0, 32))
+    assert (c.heads, c.kv_heads, c.head_dim, c.rope.columns) == (
+        16, 2, 256, 64
     )
-    name, error = worst_leaf(got, seeded.want)
-    assert (
-        abs(loss - seeded.want_loss) > 1e-3 * abs(seeded.want_loss)
-        or error > 1e-2
-    ), (control, loss, seeded.want_loss, name, error)
+    assert (c.gdn_key_heads, c.gdn_value_heads, c.gdn_head_dim) == (
+        16, 32, 128
+    )
+    assert config["linear_value_head_dim"] == c.gdn_head_dim
+    assert c.rope.inv_freq[-1] == pytest.approx(1e7 ** (-62 / 64))
+    assert c.eps == 1e-6
+    assert set(shapes) == {"params", STEP_METRICS}
+
+    def part(prefix):
+        return {
+            k[len(prefix):]: v for k, v in flat.items() if k.startswith(prefix)
+        }
+
+    assert part("layer_0/gdn/") == {
+        "qkvz/kernel": 25_165_824, "ba/kernel": 131_072,
+        "conv_kernel": 32_768, "A_log": 32, "dt_bias": 32,
+        "o_norm/scale": 128, "o/kernel": 8_388_608,
+    }
+    assert part("layer_3/attn/") == {
+        "q/kernel": 16_777_216, "k/kernel": 1_048_576, "v/kernel": 1_048_576,
+        "q_norm/scale": 256, "k_norm/scale": 256, "o/kernel": 8_388_608,
+    }
+    assert part("layer_1/moe/") == {
+        "routed/router_kernel": 1_048_576,
+        "routed/expert_w_gate_up": 32 * 2_097_152,
+        "routed/expert_w_down": 32 * 1_048_576,
+        "shared/gate_up/kernel": 2_097_152, "shared/down/kernel": 1_048_576,
+        "shared_gate/kernel": 2_048,
+    }
+
+
+def trainer_gauges(metrics, state, loss, seeded):
+    for layer in range(3):
+        assert 0.0 < metrics[f"layer_{layer}/gdn/gdn_decay_mean_ratio"] < 1.0
+        assert 0.0 < metrics[f"layer_{layer}/gdn/gdn_beta_mean_ratio"] < 1.0
+    assert 0.0 < metrics["layer_3/attn/query_gate_mean_ratio"] < 1.0
+    assert "layer_3/gdn/gdn_decay_mean_ratio" not in metrics
+    for layer in range(4):
+        path = f"layer_{layer}/moe"
+        assert 0.0 < metrics[f"{path}/shared_gate_mean_ratio"] < 1.0
+        assert metrics[f"{path}/routed/expert_load_imbalance_ratio"] >= 1.0
+        assert 0.0 < metrics[f"{path}/routed/routed_here_ratio"] < 1.0
+        assert metrics[f"{path}/routed/dropped_tokens"] == 0
+
+
+def job_gauges(registry):
+    assert 0.0 < registry.value(
+        "worker_gdn_decay_mean_ratio", layer="layer_0/gdn"
+    ) < 1.0
+    assert 0.0 < registry.value(
+        "worker_gdn_beta_mean_ratio", layer="layer_0/gdn"
+    ) < 1.0
+    assert 0.0 < registry.value(
+        "worker_attention_query_gate_mean_ratio", layer="layer_1/attn"
+    ) < 1.0
+    for layer in range(2):
+        assert 0.0 < registry.value(
+            "worker_moe_shared_gate_mean_ratio", layer=f"layer_{layer}/moe"
+        ) < 1.0
+        assert 0.0 < registry.value(
+            "worker_moe_routed_here_ratio", layer=f"layer_{layer}/moe/routed"
+        ) < 1.0
+
+
+def scopes_also(text):
+    """The scan's gate scope is `decay`: `attn_proj_ms_per_step` takes
+    every `*/gate`."""
+    from elasticdl_tpu.common import profiler
+
+    for part in ("router", "dispatch", "experts", "shared", "combine"):
+        assert f"qwen3_next/moe/{part}" in text.replace("routed/", ""), part
+    assert "qwen3_next/gdn/gate" not in profiler.DEVICE_SCOPES
+
+
+DECODER = decoder_cases.Decoder(
+    zoo=zoo, reference=reference, cell="qwen3-next-80b-a3b", config=CONFIG,
+    # 80 positions: the scan's jnp form pads them to two chunks of 64
+    length=80, seed=5,
+    # two norms a layer beside a GDN mixer's 7 leaves or attention's 6,
+    # and the routed layer's 6 (router, two stacks, the shared expert's
+    # two kernels, its gate); the embedding, the untied head, the final
+    # norm
+    leaves=3 * GDN_LEAVES + ATTENTION_LEAVES + 4 * (EXPERT_LEAVES + 2) + 3,
+    float32_also=float32_also,
+    # one key head and two value heads of 128 at 128 positions (two
+    # chunks: the state crosses a boundary), the SiLU conv at 512 columns,
+    # the streaming attention at two query heads of 128 over one K/V head
+    # with 32 columns rotated, and the routed layers, all interpreted here
+    kernels=decoder_cases.Kernels(
+        config=dict(
+            hidden_size=128, linear_num_key_heads=1,
+            linear_num_value_heads=2, linear_key_head_dim=128,
+            linear_value_head_dim=128, num_attention_heads=2,
+            num_key_value_heads=1, head_dim=128, layers_held=[2, 3],
+            num_hidden_layers=2,
+        ),
+        length=128,
+        admitted=(
+            (gdn_ops.gdn_shapes_ok, (1, 128, 1, 128), (1, 128, 1, 128),
+             (1, 128, 2, 128)),
+            (short_conv.silu_conv_shapes_ok, (1, 128, 512), (4, 512)),
+            (stream_shapes_ok, (1, 128, 2, 128), (1, 128, 1, 128),
+             (1, 128, 1, 128)),
+        ),
+    ),
+    # the gate before the output norm, one statistic over all heads, plain
+    # norm scales, value heads on other key heads, no decay, no L2 norms,
+    # no conv, the whole head rotated or none of it, one gate a head,
+    # sigmoid scores, weights not renormalised
+    controls=CONTROLS,
+    published=decoder_cases.Published(
+        by_top={
+            "layer_0": 138_582_208, "layer_1": 138_582_208,
+            "layer_2": 138_582_208, "layer_3": 132_127_232,
+            "token_embedding": 38_895_616, "lm_head_kernel": 38_895_616,
+            "final_norm": 2_048,
+        },
+        total=625_667_136, bytes_a_parameter=16, also=published_also,
+    ),
+    trainer_gauges=trainer_gauges,
+    # the job's model is one block of each kind (published layers 2 and 3:
+    # the delta rule, attention), each over the routed layer
+    job=decoder_cases.Job(
+        params=(
+            "hidden=32;layers=[2,3];heads=4;kv_heads=2;head_dim=16;"
+            "gdn_key_heads=2;gdn_value_heads=4;gdn_head_dim=8;"
+            "expert_width=24;shared_width=24;num_experts=16;top_k=3;"
+            "held_experts=[4,8];vocab_size=50;remat=True;lr=0.03"
+        ),
+        gauges=job_gauges,
+    ),
+    scopes=decoder_cases.Scopes(
+        prefix="qwen3_next",
+        names=("gdn/proj", "gdn/conv", "gdn/decay", "gdn/core", "gdn/out",
+               "attn", "moe", "norm", "embed", "head_ce"),
+        also=scopes_also,
+    ),
+)
+model_of = DECODER.model_of
+TestConformance = decoder_cases.conformance(DECODER)
 
 
 def test_each_part_of_the_reference_is_seen(seeded):
@@ -633,253 +637,7 @@ def test_the_interval_names_every_layer():
         model_of(CONFIG, kv_heads=3)
 
 
-@pytest.fixture(scope="module")
-def saved_core(seeded):
-    """bf16 -> (loss, gradients) of the model as the cells run it."""
-    return functools.lru_cache(None)(lambda bf16: loss_and_grads(
-        model_of(CONFIG, bf16=bf16), seeded.variables, seeded.ids
-    ))
-
-
-@pytest.mark.parametrize("bf16", [False, True], ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("other", remat_cases.OTHERS)
-def test_the_remat_policy_changes_no_bit(seeded, saved_core, monkeypatch,
-                                         other, bf16):
-    """`remat=True` against the plain `nn.remat` and against no remat at
-    all, bit for bit."""
-    remat_cases.assert_saving_changes_nothing(
-        zoo, monkeypatch, other,
-        lambda remat, room=None: loss_and_grads(
-            model_of(CONFIG, bf16=bf16, remat=remat), seeded.variables,
-            seeded.ids, room,
-        ),
-        saved_core(bf16),
-    )
-
-
-def test_bfloat16_inside_the_twins_rule(seeded):
-    """The model computing in bfloat16 is held as the benchmark holds a
-    cell that states it: to the reference's own bfloat16 twin, leaf by
-    leaf and on the angle (`check_gradient`), where the float8 control
-    in the step's place fails."""
-    from benchmarks.drivers import train
-
-    held = types.SimpleNamespace(
-        **{k: getattr(reference, k) for k in dir(reference)
-           if not k.startswith("__")},
-        STATED_RATIO=reference.TWIN_RATIO,
-    )
-    features = {"input_ids": seeded.ids}
-    labels = np.zeros(len(seeded.ids), np.int32)
-    _, got = loss_and_grads(
-        model_of(CONFIG, bf16=True), seeded.variables, seeded.ids
-    )
-    check = train.check_gradient(
-        held, seeded.flat, features, labels, CONFIG, seeded.want, got
-    )
-    assert check["ok"], sorted(
-        check["shares"].items(), key=lambda kv: -kv[1]
-    )[:4]
-    _, control = reference.loss_and_grads(
-        seeded.flat, features, labels, CONFIG, tower="float8_e4m3fn"
-    )
-    control = {k: np.asarray(v, np.float32) for k, v in control.items()}
-    assert not train.check_gradient(
-        held, seeded.flat, features, labels, CONFIG, seeded.want, control
-    )["ok"]
-
-
-def test_published_sizes_hold_what_the_configuration_states():
-    """The parameters of the cut model at the published widths, counted
-    from the built model's shapes: the numbers in the configuration's
-    `deployment` and its `parameters_held`, part by part, and every
-    number of the catalog row under its own key."""
-    with open(os.path.join(
-        ROOT, "benchmarks", "configs", "qwen3-next-80b-a3b.json"
-    )) as f:
-        config = json.load(f)
-    from elasticdl_tpu.common.model_handler import _call_with_params
-
-    model = _call_with_params(
-        zoo.custom_model, config["model_params"].format(**config)
-    )
-    held = config["layers_held"]
-    assert held == [0, 1, 2, 3] and len(held) == config["num_hidden_layers"]
-    c = model.config
-    assert c.layers == (True, True, True, False)
-    assert (c.num_experts, c.top_k, c.held_experts) == (512, 10, (0, 32))
-    assert (c.heads, c.kv_heads, c.head_dim, c.rope.columns) == (
-        16, 2, 256, 64
-    )
-    assert (c.gdn_key_heads, c.gdn_value_heads, c.gdn_head_dim) == (
-        16, 32, 128
-    )
-    assert config["linear_value_head_dim"] == c.gdn_head_dim
-    assert c.rope.inv_freq[-1] == pytest.approx(1e7 ** (-62 / 64))
-    assert c.dtype == jnp.bfloat16 and c.remat and c.eps == 1e-6
-    shapes = jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), {"input_ids": jnp.zeros((1, 512), jnp.int32)}
-    ))
-    assert set(shapes) == {"params", STEP_METRICS}
-    flat = {
-        name: int(np.prod(leaf.shape))
-        for name, leaf in trees.flat(shapes["params"]).items()
-    }
-    by_top = {}
-    for name, size in flat.items():
-        top = name.split("/")[0]
-        by_top[top] = by_top.get(top, 0) + size
-    assert by_top == {
-        "layer_0": 138_582_208, "layer_1": 138_582_208,
-        "layer_2": 138_582_208, "layer_3": 132_127_232,
-        "token_embedding": 38_895_616, "lm_head_kernel": 38_895_616,
-        "final_norm": 2_048,
-    }
-
-    def part(prefix):
-        return {
-            k[len(prefix):]: v for k, v in flat.items() if k.startswith(prefix)
-        }
-
-    assert part("layer_0/gdn/") == {
-        "qkvz/kernel": 25_165_824, "ba/kernel": 131_072,
-        "conv_kernel": 32_768, "A_log": 32, "dt_bias": 32,
-        "o_norm/scale": 128, "o/kernel": 8_388_608,
-    }
-    assert part("layer_3/attn/") == {
-        "q/kernel": 16_777_216, "k/kernel": 1_048_576, "v/kernel": 1_048_576,
-        "q_norm/scale": 256, "k_norm/scale": 256, "o/kernel": 8_388_608,
-    }
-    assert part("layer_1/moe/") == {
-        "routed/router_kernel": 1_048_576,
-        "routed/expert_w_gate_up": 32 * 2_097_152,
-        "routed/expert_w_down": 32 * 1_048_576,
-        "shared/gate_up/kernel": 2_097_152, "shared/down/kernel": 1_048_576,
-        "shared_gate/kernel": 2_048,
-    }
-    total = sum(by_top.values())
-    assert total == config["parameters_held"] == 625_667_136
-    assert f"{total:,}" in config["deployment"]
-    assert 16 * total > 0.25 * 16.9e9          # over the floor, held alone
-
-
 # ---- through the system ---------------------------------------------------
-
-
-def test_trainer_carries_each_layer_kinds_gauges(seeded):
-    from elasticdl_tpu.worker.sync import ModelOwner
-    from elasticdl_tpu.worker.trainer import Trainer
-
-    trainer = Trainer(
-        model=model_of(CONFIG), optimizer=zoo.optimizer(1e-3),
-        loss_fn=zoo.loss,
-    )
-    batch = {"features": {"input_ids": seeded.ids},
-             "labels": np.zeros(len(seeded.ids), np.int32)}
-    state = trainer.init_state(jax.random.PRNGKey(0), batch["features"])
-    state, loss = trainer.train_on_batch(state, batch)
-    assert float(loss) == pytest.approx(seeded.want_loss, rel=1e-3)
-    owner = ModelOwner.__new__(ModelOwner)
-    owner.state, owner.lock = state, threading.Lock()
-    value, metrics = owner.fetch_loss(loss)
-    assert value == pytest.approx(float(loss))
-    for layer in range(3):
-        assert 0.0 < metrics[f"layer_{layer}/gdn/gdn_decay_mean_ratio"] < 1.0
-        assert 0.0 < metrics[f"layer_{layer}/gdn/gdn_beta_mean_ratio"] < 1.0
-    assert 0.0 < metrics["layer_3/attn/query_gate_mean_ratio"] < 1.0
-    assert "layer_3/gdn/gdn_decay_mean_ratio" not in metrics
-    for layer in range(4):
-        path = f"layer_{layer}/moe"
-        assert 0.0 < metrics[f"{path}/shared_gate_mean_ratio"] < 1.0
-        assert metrics[f"{path}/routed/expert_load_imbalance_ratio"] >= 1.0
-        assert 0.0 < metrics[f"{path}/routed/routed_here_ratio"] < 1.0
-        assert metrics[f"{path}/routed/dropped_tokens"] == 0
-
-
-def test_cli_job_of_two_tasks_with_a_falling_loss(tmp_path, monkeypatch):
-    from elasticdl_tpu.client.main import main as cli_main
-    from elasticdl_tpu.common import metrics as metrics_lib
-    from elasticdl_tpu.worker.worker import Worker
-    from elasticdl_tpu.worker import trainer as trainer_lib
-
-    # a device with room for every named product: the gauge reads 1
-    monkeypatch.setattr(
-        trainer_lib, "device_room", lambda mesh: remat_cases.ALL_THE_ROOM
-    )
-
-    path = str(tmp_path / "train.tfrecord")
-    datagen.write_task_file(
-        path, 7, {"format": "tokens", "seq_len": 32, "vocab_size": 50},
-        64, 2,
-    )
-    workers = []
-    init = Worker.__init__
-
-    def recording_init(self, *args, **kwargs):
-        init(self, *args, **kwargs)
-        workers.append(self)
-
-    Worker.__init__ = recording_init
-    try:
-        rc = cli_main([
-            "train", "--model_zoo", os.path.join(ROOT, "model_zoo"),
-            "--model_def", "qwen3_next.qwen3_next.custom_model",
-            "--model_params",
-            "hidden=32;layers=[0,1,2,3];heads=4;kv_heads=2;head_dim=16;"
-            "gdn_key_heads=2;gdn_value_heads=4;gdn_head_dim=8;"
-            "expert_width=24;shared_width=24;num_experts=16;top_k=3;"
-            "held_experts=[4,8];vocab_size=50;remat=True;lr=0.03",
-            "--distribution_strategy", "Local", "--training_data", path,
-            "--minibatch_size", "8", "--records_per_task", "64",
-            "--num_epochs", "1",
-        ])
-    finally:
-        Worker.__init__ = init
-    assert rc == 0
-    losses = [float(x) for x in workers[0].losses]
-    assert len(losses) == 16                      # two tasks of 8 steps
-    assert np.mean(losses[-4:]) < np.mean(losses[:4]) - 0.05
-    registry = metrics_lib.default_registry()
-    for layer in range(3):
-        assert 0.0 < registry.value(
-            "worker_gdn_decay_mean_ratio", layer=f"layer_{layer}/gdn"
-        ) < 1.0
-        assert 0.0 < registry.value(
-            "worker_gdn_beta_mean_ratio", layer=f"layer_{layer}/gdn"
-        ) < 1.0
-    assert 0.0 < registry.value(
-        "worker_attention_query_gate_mean_ratio", layer="layer_3/attn"
-    ) < 1.0
-    for layer in range(4):
-        assert 0.0 < registry.value(
-            "worker_moe_shared_gate_mean_ratio", layer=f"layer_{layer}/moe"
-        ) < 1.0
-        assert 0.0 < registry.value(
-            "worker_moe_routed_here_ratio", layer=f"layer_{layer}/moe/routed"
-        ) < 1.0
-    assert registry.value("worker_remat_kept_ratio") == 1.0
-
-
-def test_the_layers_scopes_reach_the_lowered_operations():
-    """The scan's five scopes, attention's and the expert layer's carry
-    the model's prefix into the operations' names, and the scan's gate
-    scope is `decay`: `attn_proj_ms_per_step` takes every `*/gate`."""
-    from elasticdl_tpu.common import profiler
-
-    model = model_of(CONFIG, remat=False)
-    ids = ids_of(1, length=16)
-    variables = model.init(jax.random.PRNGKey(0), {"input_ids": ids})
-    text = jax.jit(
-        lambda v, ids: model.apply(v, {"input_ids": ids}, mutable=MUTABLE)[0]
-    ).lower(variables, ids).as_text(debug_info=True)
-    for scope in ("gdn/proj", "gdn/conv", "gdn/decay", "gdn/core", "gdn/out",
-                  "attn", "moe", "norm", "embed", "head_ce"):
-        assert f"qwen3_next/{scope}" in profiler.DEVICE_SCOPES
-        assert f"qwen3_next/{scope}/" in text, scope
-    for part in ("router", "dispatch", "experts", "shared", "combine"):
-        assert f"qwen3_next/moe/{part}" in text.replace("routed/", ""), part
-    assert "qwen3_next/gdn/gate" not in profiler.DEVICE_SCOPES
-    assert "Scope object" not in text
 
 
 # sha256 of str(make_jaxpr(value_and_grad(loss))) of the sibling cells'
@@ -949,10 +707,7 @@ def test_a_sibling_cells_program_is_the_parents(name):
 
     module, shape, digest = PARENTS_JAXPRS[name]
     sibling = importlib.import_module(f"model_zoo.{module}")
-    with open(os.path.join(
-        ROOT, "benchmarks", "configs", f"{name}.json"
-    )) as f:
-        config = json.load(f)
+    config = decoder_cases.cell_config(name)
     model = _call_with_params(
         sibling.custom_model, config["model_params"].format(**config)
     )

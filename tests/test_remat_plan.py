@@ -32,7 +32,7 @@ from model_zoo.common.decoder import (
     Product,
     kept_products,
 )
-from tests import remat_cases
+from tests import decoder_cases
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -249,7 +249,7 @@ def test_remat_blocks_plans_only_with_a_room():
     everything = decoder.remat_block(
         ToyBlock, (MIXER_OUT, MIXER_IN, GATE_UP)
     )
-    assert classes(remat_cases.ALL_THE_ROOM) == [everything, everything]
+    assert classes(decoder_cases.ALL_THE_ROOM) == [everything, everything]
     assert kept_ratio() == 1.0
     # room for the lean step's estimate and the two out-projections
     shapes = decoder.block_shapes(
@@ -460,7 +460,7 @@ def test_a_cells_log_sum_exp_is_padded_eightfold_at_most(
 # ---- the traced step ------------------------------------------------------
 
 ZOOS = ["granite_hybrid", "laguna", "lfm2", "kimi_linear"]
-# `remat_cases.grad_program_digest` of each model file's test model
+# `decoder_cases.grad_program_digest` of each model file's test model
 # (bfloat16, remat) at the commit BEFORE the blocks' products had names
 # (198d98a): with no room the step lowers to that commit's program.
 # Kimi's is PR 56's, whose KDA layer takes its norm a head, its output gate
@@ -495,7 +495,7 @@ def test_with_no_room_the_steps_program_is_the_parents(name, monkeypatch):
     monkeypatch.setattr(moe, "TILE", 8)
     tests = importlib.import_module(f"tests.test_{name}")
     model = tests.model_of(tests.CONFIG, bf16=True, remat=True)
-    assert remat_cases.grad_program_digest(model) == PARENT_DIGESTS[name]
+    assert decoder_cases.grad_program_digest(model) == PARENT_DIGESTS[name]
 
 
 @pytest.mark.parametrize(

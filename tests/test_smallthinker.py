@@ -12,161 +12,36 @@ parameter count; and a two-task job through the CLI.  (The routed siblings'
 programs, which the routing's new argument may not move, are held by
 tests/test_qwen3_next.py: `PARENTS_JAXPRS`.)"""
 
-import functools
-import json
-import os
-import threading
-import types
-
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks import datagen, trees
 from benchmarks.reference import smallthinker as reference
 from elasticdl_tpu.layers import moe
-from elasticdl_tpu.layers.step_metrics import AUX_LOSS, STEP_METRICS
+from elasticdl_tpu.layers.step_metrics import STEP_METRICS
+from elasticdl_tpu.ops.flash_attention import stream_shapes_ok
 from model_zoo.common import decoder
 from model_zoo.smallthinker import smallthinker as zoo
-from tests import remat_cases
+from tests import decoder_cases
+from tests.decoder_cases import MUTABLE, computed, seeded  # noqa: F401
 
-ROOT = os.path.join(os.path.dirname(__file__), "..")
 LAYOUT = [0, 1, 1, 1, 0, 1, 1, 1]
 # one whole period of the published pattern (full, band, band, band): 6
 # query heads of 16 over 2 K/V heads (groups of 3), a band of 24 over 64
 # positions, top-3 of 16 softmax-routed ReGLU experts 24 wide with 8 held
 CONFIG = dict(
-    hidden_size=32, num_hidden_layers=4, layers_held=[0, 1, 2, 3],
+    hidden_size=32, num_hidden_layers=4, num_hidden_layers_published=8,
+    layers_held=[0, 1, 2, 3],
     sliding_window_layout=LAYOUT, rope_layout=LAYOUT,
     num_attention_heads=6, num_key_value_heads=2, head_dim=16,
     sliding_window_size=24, rope_theta=1.5e6, moe_ffn_hidden_size=24,
     moe_num_primary_experts=8, moe_num_primary_experts_published=16,
     moe_num_active_primary_experts=3, held_experts=[4, 8], vocab_size=50,
-    rms_norm_eps=1e-6, use_bf16=True,
+    rms_norm_eps=1e-6, learning_rate=1e-3, use_bf16=True,
 )
-# (`ROUTER_STATE` is no collection of this model: the sigmoid-scored
-# controls and the sibling models fill it)
-MUTABLE = [AUX_LOSS, STEP_METRICS, moe.ROUTER_STATE]
 ATTENTION_LEAVES, EXPERT_LEAVES = 4, 3
-
-
-def model_of(config, **overrides):
-    sizes = dict(
-        hidden=config["hidden_size"],
-        num_layers=len(config["sliding_window_layout"]),
-        sliding_window_layout=config["sliding_window_layout"],
-        rope_layout=config["rope_layout"], layers=config["layers_held"],
-        heads=config["num_attention_heads"],
-        kv_heads=config["num_key_value_heads"], head_dim=config["head_dim"],
-        window=config["sliding_window_size"],
-        rope_theta=config["rope_theta"],
-        expert_width=config["moe_ffn_hidden_size"],
-        num_experts=config["moe_num_primary_experts_published"],
-        top_k=config["moe_num_active_primary_experts"],
-        held_experts=config["held_experts"],
-        vocab_size=config["vocab_size"], eps=config["rms_norm_eps"],
-        remat=True,
-    )
-    sizes.update(overrides)
-    return zoo.custom_model(**sizes)
-
-
-def ids_of(rows, length=64, seed=0):
-    return np.random.RandomState(seed).randint(
-        0, CONFIG["vocab_size"], (rows, length)
-    ).astype(np.int32)
-
-
-def loss_and_grads(model, variables, ids, room=None):
-    """The objective the Trainer builds: the mean of the model's
-    per-position losses (this model sows no auxiliary loss)."""
-    state = {k: v for k, v in variables.items() if k != "params"}
-
-    def loss_of(params):
-        out, _ = model.apply(
-            {"params": params, **state}, {"input_ids": ids}, mutable=MUTABLE,
-            **({} if room is None else {"room": room}),
-        )
-        return zoo.loss(None, out.astype(jnp.float32))
-
-    with jax.default_matmul_precision("highest"):
-        loss, grads = jax.value_and_grad(loss_of)(variables["params"])
-    return float(loss), {
-        k: np.asarray(v, np.float32) for k, v in trees.flat(grads).items()
-    }
-
-
-def seeded_of(config, ids):
-    model = model_of(config)
-    variables = model.init(jax.random.PRNGKey(0), {"input_ids": ids})
-    flat = {
-        k: np.asarray(v) for k, v in trees.flat(variables["params"]).items()
-    }
-    want_loss, want = reference.loss_and_grads(
-        flat, {"input_ids": ids}, None, config
-    )
-    return types.SimpleNamespace(
-        ids=ids, variables=variables, flat=flat, want_loss=want_loss,
-        want={k: np.asarray(v) for k, v in want.items()},
-    )
-
-
-@pytest.fixture(scope="module")
-def seeded():
-    return seeded_of(CONFIG, ids_of(8, seed=5))
-
-
-def worst_leaf(got, want):
-    assert set(got) == set(want)
-    errors = {
-        name: np.linalg.norm(got[name] - ref) / np.linalg.norm(ref)
-        for name, ref in want.items()
-    }
-    name = max(errors, key=errors.get)
-    return name, errors[name]
-
-
-def test_float32_matches_reference_leaf_by_leaf(seeded):
-    model = model_of(CONFIG)
-    assert list(model.config.layers) == [False, True, True, True]
-    assert set(seeded.variables) == {"params", STEP_METRICS}   # no buffer
-    loss, got = loss_and_grads(model, seeded.variables, seeded.ids)
-    assert abs(loss - seeded.want_loss) < 1e-4 * abs(seeded.want_loss)
-    # two norms a layer beside attention's 4 kernels and the routed
-    # layer's 3 (router, two stacks: no shared expert); the embedding, the
-    # untied head, the final norm
-    assert len(got) == 4 * (ATTENTION_LEAVES + EXPERT_LEAVES + 2) + 3
-    assert got["layer_0/attn/q/kernel"].shape == (32, 6 * 16)
-    assert got["layer_0/attn/k/kernel"].shape == (32, 2 * 16)
-    assert got["layer_0/attn/o/kernel"].shape == (6 * 16, 32)
-    assert got["layer_1/moe/routed/router_kernel"].shape == (32, 16)
-    assert got["layer_1/moe/routed/expert_w_gate_up"].shape == (8, 32, 48)
-    assert got["layer_1/moe/routed/expert_w_down"].shape == (8, 24, 32)
-    name, error = worst_leaf(got, seeded.want)
-    assert error < 1e-4, (name, error)
-
-
-def test_kernels_match_reference_leaf_by_leaf():
-    """A group of SEVEN query heads of 128 over one K/V head at two tiles
-    of 128 positions: the streaming kernels (interpreted here), a full
-    layer with no positions and a band layer whose band is longer than a
-    tile and shorter than the sequence."""
-    from elasticdl_tpu.ops.flash_attention import stream_shapes_ok
-
-    config = dict(
-        CONFIG, hidden_size=64, num_attention_heads=7,
-        num_key_value_heads=1, head_dim=128, sliding_window_size=160,
-        layers_held=[0, 1], num_hidden_layers=2,
-    )
-    assert stream_shapes_ok((1, 256, 7, 128), (1, 256, 1, 128),
-                            (1, 256, 1, 128))
-    seeded = seeded_of(config, ids_of(1, length=256, seed=2))
-    loss, got = loss_and_grads(model_of(config), seeded.variables, seeded.ids)
-    assert abs(loss - seeded.want_loss) < 1e-4 * abs(seeded.want_loss)
-    name, error = worst_leaf(got, seeded.want)
-    assert error < 2e-4, (name, error)
 
 
 # ---- each mechanism alone --------------------------------------------------
@@ -427,26 +302,152 @@ CONTROLS = {
 }
 
 
-@pytest.mark.parametrize("control", sorted(CONTROLS))
-def test_a_departure_from_the_mathematics_fails_the_comparison(
-        seeded, monkeypatch, control):
-    """The comparison that passes the model fails each of these: a router
-    that reads the experts' rows or the normed input, SwiGLU or squared
-    ReLU in ReGLU's place, sigmoid scores, weights that do not sum to 1,
-    rotary and a band in every layer or rotary in none, no band, a band a
-    key short, another theta, query heads dealt to other K/V heads."""
-    change = CONTROLS[control]
-    overrides = change if isinstance(change, dict) else {}
-    if not overrides:
-        change(monkeypatch)
-    loss, got = loss_and_grads(
-        model_of(CONFIG, **overrides), seeded.variables, seeded.ids
+def float32_also(model, seeded, got):
+    assert list(model.config.layers) == [False, True, True, True]
+    assert set(seeded.variables) == {"params", STEP_METRICS}   # no buffer
+    assert got["layer_0/attn/q/kernel"].shape == (32, 6 * 16)
+    assert got["layer_0/attn/k/kernel"].shape == (32, 2 * 16)
+    assert got["layer_0/attn/o/kernel"].shape == (6 * 16, 32)
+    assert got["layer_1/moe/routed/router_kernel"].shape == (32, 16)
+    assert got["layer_1/moe/routed/expert_w_gate_up"].shape == (8, 32, 48)
+    assert got["layer_1/moe/routed/expert_w_down"].shape == (8, 24, 32)
+
+
+def published_also(model, config, shapes, flat, by_top):
+    held = config["layers_held"]
+    assert held == [0, 1, 2, 3] and len(held) == config["num_hidden_layers"]
+    c = model.config
+    assert c.layers == (False, True, True, True)
+    assert (c.num_experts, c.top_k, c.held_experts) == (64, 6, (0, 8))
+    assert (c.heads, c.kv_heads, c.head_dim, c.window) == (28, 4, 128, 4096)
+    assert c.rope.columns == 128
+    assert c.rope.inv_freq[-1] == pytest.approx(1.5e6 ** (-126 / 128))
+    assert c.eps == 1e-6
+    assert config["sliding_window_layout"] == config["rope_layout"]
+    assert len(config["rope_layout"]) == 52
+    assert [i for i, v in enumerate(config["rope_layout"]) if not v] == list(
+        range(0, 52, 4)
     )
-    name, error = worst_leaf(got, seeded.want)
-    assert (
-        abs(loss - seeded.want_loss) > 1e-3 * abs(seeded.want_loss)
-        or error > 1e-2
-    ), (control, loss, seeded.want_loss, name, error)
+    assert set(shapes) == {"params", STEP_METRICS}
+
+    def part(prefix):
+        return {
+            k[len(prefix):]: v for k, v in flat.items() if k.startswith(prefix)
+        }
+
+    assert part("layer_1/attn/") == {
+        "q/kernel": 9_175_040, "k/kernel": 1_310_720, "v/kernel": 1_310_720,
+        "o/kernel": 9_175_040,
+    }
+    assert part("layer_1/moe/") == {
+        "routed/router_kernel": 163_840,
+        "routed/expert_w_gate_up": 8 * 3_932_160,
+        "routed/expert_w_down": 8 * 1_966_080,
+    }
+    # the published widths are whole tiles: the walk pads nothing
+    assert moe.padded_work(2560, 768) == 0.0
+
+
+def trainer_gauges(metrics, state, loss, seeded):
+    from elasticdl_tpu.common import metrics as metrics_lib
+
+    for layer in range(4):
+        path = f"layer_{layer}/moe/routed"
+        assert metrics[f"{path}/expert_load_imbalance_ratio"] >= 1.0
+        assert 0.0 < metrics[f"{path}/routed_here_ratio"] < 1.0
+        assert metrics[f"{path}/live_chunks_ratio"] == 1.0
+        assert metrics[f"{path}/dropped_tokens"] == 0
+        # the tiny widths are no whole tiles
+        assert metrics_lib.default_registry().value(
+            "worker_moe_padded_work_ratio", layer=path
+        ) == pytest.approx(moe.padded_work(32, 24))
+
+
+def job_gauges(registry):
+    assert registry.value("worker_moe_dropped_tokens_total") == 0.0
+    for layer in range(2):
+        assert 0.0 < registry.value(
+            "worker_moe_routed_here_ratio", layer=f"layer_{layer}/moe/routed"
+        ) < 1.0
+
+
+def scopes_also(text):
+    """The routing's operations lie under `smallthinker/route` AND under
+    `router` or `dispatch`, which stay their innermost catalogue entries,
+    so that `moe_walk_ms_per_step` reads them where it reads every
+    sibling's."""
+    from elasticdl_tpu.common import profiler
+
+    for part in ("dispatch", "experts", "combine"):
+        assert f"smallthinker/moe/routed/{part}" in text, part
+    for part in ("router", "dispatch"):
+        assert f"smallthinker/moe/routed/smallthinker/route/{part}" in text
+    # nothing routes outside the scope: the only `router` is under it
+    assert "routed/router" not in text
+    assert "smallthinker/route" not in profiler.DEVICE_SCOPES
+    for part in ("router", "dispatch"):
+        assert profiler.catalogue_scope(
+            f"layer_1/moe/smallthinker/moe/routed/smallthinker/route/{part}"
+        ) == part
+
+
+DECODER = decoder_cases.Decoder(
+    zoo=zoo, reference=reference, cell="smallthinker-21b-a3b", config=CONFIG,
+    length=64, seed=5,
+    # two norms a layer beside attention's 4 kernels and the routed
+    # layer's 3 (router, two stacks: no shared expert); the embedding, the
+    # untied head, the final norm
+    leaves=4 * (ATTENTION_LEAVES + EXPERT_LEAVES + 2) + 3,
+    float32_also=float32_also,
+    # a group of SEVEN query heads of 128 over one K/V head at two tiles
+    # of 128 positions: the streaming kernels (interpreted here), a full
+    # layer with no positions and a band layer whose band is longer than a
+    # tile and shorter than the sequence
+    kernels=decoder_cases.Kernels(
+        config=dict(
+            hidden_size=64, num_attention_heads=7, num_key_value_heads=1,
+            head_dim=128, sliding_window_size=160, layers_held=[0, 1],
+            num_hidden_layers=2,
+        ),
+        length=256,
+        admitted=((stream_shapes_ok, (1, 256, 7, 128), (1, 256, 1, 128),
+                   (1, 256, 1, 128)),),
+    ),
+    # a router that reads the experts' rows or the normed input, SwiGLU or
+    # squared ReLU in ReGLU's place, sigmoid scores, weights that do not
+    # sum to 1, rotary and a band in every layer or rotary in none, no
+    # band, a band a key short, another theta, query heads dealt to other
+    # K/V heads
+    controls=CONTROLS,
+    published=decoder_cases.Published(
+        by_top={
+            **{f"layer_{i}": 68_326_400 for i in range(4)},
+            "token_embedding": 48_619_520, "lm_head_kernel": 48_619_520,
+            "final_norm": 2_560,
+        },
+        total=370_547_200, bytes_a_parameter=16, also=published_also,
+    ),
+    trainer_gauges=trainer_gauges,
+    # the job's model is one block of each kind (published layers 0 and 1:
+    # a full layer with no positions, a band layer with rotary)
+    job=decoder_cases.Job(
+        params=(
+            "hidden=32;layers=[0,1];heads=6;kv_heads=2;head_dim=16;"
+            "window=12;expert_width=24;num_experts=16;top_k=3;"
+            "held_experts=[4,8];vocab_size=50;remat=True;lr=0.03"
+        ),
+        gauges=job_gauges,
+    ),
+    # both attention kinds', the expert layer's and the routing's
+    scopes=decoder_cases.Scopes(
+        prefix="smallthinker",
+        names=("attn_full", "attn_window", "moe", "norm", "embed",
+               "head_ce"),
+        also=scopes_also,
+    ),
+)
+model_of = DECODER.model_of
+TestConformance = decoder_cases.conformance(DECODER)
 
 
 def test_each_part_of_the_reference_is_seen(seeded):
@@ -479,243 +480,7 @@ def test_each_part_of_the_reference_is_seen(seeded):
     )
 
 
-@pytest.fixture(scope="module")
-def saved_core(seeded):
-    """bf16 -> (loss, gradients) of the model as the cells run it."""
-    return functools.lru_cache(None)(lambda bf16: loss_and_grads(
-        model_of(CONFIG, bf16=bf16), seeded.variables, seeded.ids
-    ))
-
-
-@pytest.mark.parametrize("bf16", [False, True], ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("other", remat_cases.OTHERS)
-def test_the_remat_policy_changes_no_bit(seeded, saved_core, monkeypatch,
-                                         other, bf16):
-    """`remat=True` against the plain `nn.remat` and against no remat at
-    all, bit for bit."""
-    remat_cases.assert_saving_changes_nothing(
-        zoo, monkeypatch, other,
-        lambda remat, room=None: loss_and_grads(
-            model_of(CONFIG, bf16=bf16, remat=remat), seeded.variables,
-            seeded.ids, room,
-        ),
-        saved_core(bf16),
-    )
-
-
-def test_bfloat16_inside_the_twins_rule(seeded):
-    """The model computing in bfloat16 is held as the benchmark holds a
-    cell that states it: to the reference's own bfloat16 twin, leaf by
-    leaf and on the angle (`check_gradient`), where the float8 control
-    in the step's place fails."""
-    from benchmarks.drivers import train
-
-    held = types.SimpleNamespace(
-        **{k: getattr(reference, k) for k in dir(reference)
-           if not k.startswith("__")},
-        STATED_RATIO=reference.TWIN_RATIO,
-    )
-    features = {"input_ids": seeded.ids}
-    labels = np.zeros(len(seeded.ids), np.int32)
-    _, got = loss_and_grads(
-        model_of(CONFIG, bf16=True), seeded.variables, seeded.ids
-    )
-    check = train.check_gradient(
-        held, seeded.flat, features, labels, CONFIG, seeded.want, got
-    )
-    assert check["ok"], sorted(
-        check["shares"].items(), key=lambda kv: -kv[1]
-    )[:4]
-    _, control = reference.loss_and_grads(
-        seeded.flat, features, labels, CONFIG, tower="float8_e4m3fn"
-    )
-    control = {k: np.asarray(v, np.float32) for k, v in control.items()}
-    assert not train.check_gradient(
-        held, seeded.flat, features, labels, CONFIG, seeded.want, control
-    )["ok"]
-
-
-def test_published_sizes_hold_what_the_configuration_states():
-    """The parameters of the cut model at the published widths, counted
-    from the built model's shapes: the numbers in the configuration's
-    `deployment` and its `parameters_held`, part by part."""
-    with open(os.path.join(
-        ROOT, "benchmarks", "configs", "smallthinker-21b-a3b.json"
-    )) as f:
-        config = json.load(f)
-    from elasticdl_tpu.common.model_handler import _call_with_params
-
-    model = _call_with_params(
-        zoo.custom_model, config["model_params"].format(**config)
-    )
-    held = config["layers_held"]
-    assert held == [0, 1, 2, 3] and len(held) == config["num_hidden_layers"]
-    c = model.config
-    assert c.layers == (False, True, True, True)
-    assert (c.num_experts, c.top_k, c.held_experts) == (64, 6, (0, 8))
-    assert (c.heads, c.kv_heads, c.head_dim, c.window) == (28, 4, 128, 4096)
-    assert c.rope.columns == 128
-    assert c.rope.inv_freq[-1] == pytest.approx(1.5e6 ** (-126 / 128))
-    assert c.dtype == jnp.bfloat16 and c.remat and c.eps == 1e-6
-    assert config["sliding_window_layout"] == config["rope_layout"]
-    assert len(config["rope_layout"]) == 52
-    assert [i for i, v in enumerate(config["rope_layout"]) if not v] == list(
-        range(0, 52, 4)
-    )
-    shapes = jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), {"input_ids": jnp.zeros((1, 512), jnp.int32)}
-    ))
-    assert set(shapes) == {"params", STEP_METRICS}
-    flat = {
-        name: int(np.prod(leaf.shape))
-        for name, leaf in trees.flat(shapes["params"]).items()
-    }
-    by_top = {}
-    for name, size in flat.items():
-        top = name.split("/")[0]
-        by_top[top] = by_top.get(top, 0) + size
-    assert by_top == {
-        **{f"layer_{i}": 68_326_400 for i in range(4)},
-        "token_embedding": 48_619_520, "lm_head_kernel": 48_619_520,
-        "final_norm": 2_560,
-    }
-
-    def part(prefix):
-        return {
-            k[len(prefix):]: v for k, v in flat.items() if k.startswith(prefix)
-        }
-
-    assert part("layer_1/attn/") == {
-        "q/kernel": 9_175_040, "k/kernel": 1_310_720, "v/kernel": 1_310_720,
-        "o/kernel": 9_175_040,
-    }
-    assert part("layer_1/moe/") == {
-        "routed/router_kernel": 163_840,
-        "routed/expert_w_gate_up": 8 * 3_932_160,
-        "routed/expert_w_down": 8 * 1_966_080,
-    }
-    total = sum(by_top.values())
-    assert total == config["parameters_held"] == 370_547_200
-    assert f"{total:,}" in config["deployment"]
-    assert 16 * total > 0.25 * 16.9e9          # over the floor, held alone
-    # the published widths are whole tiles: the walk pads nothing
-    assert moe.padded_work(2560, 768) == 0.0
-
-
 # ---- through the system ---------------------------------------------------
-
-
-def test_trainer_carries_every_layers_gauges(seeded):
-    from elasticdl_tpu.common import metrics as metrics_lib
-    from elasticdl_tpu.worker.sync import ModelOwner
-    from elasticdl_tpu.worker.trainer import Trainer
-
-    trainer = Trainer(
-        model=model_of(CONFIG), optimizer=zoo.optimizer(1e-3),
-        loss_fn=zoo.loss,
-    )
-    batch = {"features": {"input_ids": seeded.ids},
-             "labels": np.zeros(len(seeded.ids), np.int32)}
-    state = trainer.init_state(jax.random.PRNGKey(0), batch["features"])
-    state, loss = trainer.train_on_batch(state, batch)
-    assert float(loss) == pytest.approx(seeded.want_loss, rel=1e-3)
-    owner = ModelOwner.__new__(ModelOwner)
-    owner.state, owner.lock = state, threading.Lock()
-    value, metrics = owner.fetch_loss(loss)
-    assert value == pytest.approx(float(loss))
-    for layer in range(4):
-        path = f"layer_{layer}/moe/routed"
-        assert metrics[f"{path}/expert_load_imbalance_ratio"] >= 1.0
-        assert 0.0 < metrics[f"{path}/routed_here_ratio"] < 1.0
-        assert metrics[f"{path}/live_chunks_ratio"] == 1.0
-        assert metrics[f"{path}/dropped_tokens"] == 0
-        # the tiny widths are no whole tiles
-        assert metrics_lib.default_registry().value(
-            "worker_moe_padded_work_ratio", layer=path
-        ) == pytest.approx(moe.padded_work(32, 24))
-
-
-def test_cli_job_of_two_tasks_with_a_falling_loss(tmp_path, monkeypatch):
-    from elasticdl_tpu.client.main import main as cli_main
-    from elasticdl_tpu.common import metrics as metrics_lib
-    from elasticdl_tpu.worker.worker import Worker
-    from elasticdl_tpu.worker import trainer as trainer_lib
-
-    # a device with room for every named product: the gauge reads 1
-    monkeypatch.setattr(
-        trainer_lib, "device_room", lambda mesh: remat_cases.ALL_THE_ROOM
-    )
-
-    path = str(tmp_path / "train.tfrecord")
-    datagen.write_task_file(
-        path, 7, {"format": "tokens", "seq_len": 32, "vocab_size": 50},
-        64, 2,
-    )
-    workers = []
-    init = Worker.__init__
-
-    def recording_init(self, *args, **kwargs):
-        init(self, *args, **kwargs)
-        workers.append(self)
-
-    Worker.__init__ = recording_init
-    try:
-        rc = cli_main([
-            "train", "--model_zoo", os.path.join(ROOT, "model_zoo"),
-            "--model_def", "smallthinker.smallthinker.custom_model",
-            "--model_params",
-            "hidden=32;layers=[0,1,2,3];heads=6;kv_heads=2;head_dim=16;"
-            "window=12;expert_width=24;num_experts=16;top_k=3;"
-            "held_experts=[4,8];vocab_size=50;remat=True;lr=0.03",
-            "--distribution_strategy", "Local", "--training_data", path,
-            "--minibatch_size", "8", "--records_per_task", "64",
-            "--num_epochs", "1",
-        ])
-    finally:
-        Worker.__init__ = init
-    assert rc == 0
-    losses = [float(x) for x in workers[0].losses]
-    assert len(losses) == 16                      # two tasks of 8 steps
-    assert np.mean(losses[-4:]) < np.mean(losses[:4]) - 0.05
-    registry = metrics_lib.default_registry()
-    assert registry.value("worker_moe_dropped_tokens_total") == 0.0
-    for layer in range(4):
-        assert 0.0 < registry.value(
-            "worker_moe_routed_here_ratio", layer=f"layer_{layer}/moe/routed"
-        ) < 1.0
-    assert registry.value("worker_remat_kept_ratio") == 1.0
-
-
-def test_the_layers_scopes_reach_the_lowered_operations():
-    """Both attention kinds', the expert layer's and the routing's scopes
-    carry the model's prefix into the operations' names; the routing's
-    operations lie under `smallthinker/route` AND under `router` or
-    `dispatch`, which stay their innermost catalogue entries, so that
-    `moe_walk_ms_per_step` reads them where it reads every sibling's."""
-    from elasticdl_tpu.common import profiler
-
-    model = model_of(CONFIG, remat=False)
-    ids = ids_of(1, length=16)
-    variables = model.init(jax.random.PRNGKey(0), {"input_ids": ids})
-    text = jax.jit(
-        lambda v, ids: model.apply(v, {"input_ids": ids}, mutable=MUTABLE)[0]
-    ).lower(variables, ids).as_text(debug_info=True)
-    for scope in ("attn_full", "attn_window", "moe", "norm", "embed",
-                  "head_ce"):
-        assert f"smallthinker/{scope}" in profiler.DEVICE_SCOPES
-        assert f"smallthinker/{scope}/" in text, scope
-    for part in ("dispatch", "experts", "combine"):
-        assert f"smallthinker/moe/routed/{part}" in text, part
-    for part in ("router", "dispatch"):
-        assert f"smallthinker/moe/routed/smallthinker/route/{part}" in text
-    # nothing routes outside the scope: the only `router` is under it
-    assert "routed/router" not in text
-    assert "smallthinker/route" not in profiler.DEVICE_SCOPES
-    for part in ("router", "dispatch"):
-        assert profiler.catalogue_scope(
-            f"layer_1/moe/smallthinker/moe/routed/smallthinker/route/{part}"
-        ) == part
-    assert "Scope object" not in text
 
 
 def test_the_routing_hangs_on_the_blocks_input_alone():

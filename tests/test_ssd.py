@@ -68,13 +68,17 @@ def through(core, given):
             x, jax.nn.softplus(dt_raw + dt_bias), -jnp.exp(A_log), B, C, D
         )
 
-    args = [given[name] for name in NAMES]
-    with jax.default_matmul_precision("highest"):
+    def weighted(*args):
         y = y_of(*args)
-        grads = jax.grad(
-            lambda *a: (y_of(*a) * given["weight"]).sum(),
-            argnums=tuple(range(len(NAMES))),
-        )(*args)
+        return (y * given["weight"]).sum(), y
+
+    # ONE compiled program for y and the gradients: walked a primitive at
+    # a time, an interpreted kernel's forward ran twice and its backward
+    # once, each an equation at a time
+    with jax.default_matmul_precision("highest"):
+        (_, y), grads = jax.jit(jax.value_and_grad(
+            weighted, argnums=tuple(range(len(NAMES))), has_aux=True
+        ))(*[given[name] for name in NAMES])
     return np.asarray(y), dict(zip(NAMES, map(np.asarray, grads)))
 
 

@@ -131,6 +131,49 @@ def test_half_lane_head_kernels_compile_for_the_chip(one_chip, monkeypatch):
     assert "bf16[4,8,8192,64]" in text and "bf16[4,32,8192,64]" in text
 
 
+# (beside the attention kernels it holds and apart from the two other whole
+# steps below: xdist deals the end of a run two tests at a time, and a pair
+# then never holds two whole-step compiles)
+def test_ouro_cell_step_compiles_for_the_chip(one_chip, monkeypatch):
+    """The Ouro cell's whole train step (ONE sequence of 8,192, six layers
+    at the published widths run four times over one set of weights, Adam,
+    the lean remat policy: no room given) compiled for the described v5e,
+    abstract: the streaming kernels at a group of ONE at heads of 128
+    inside the trips' loop, and what the configuration's `eight_layers`
+    states: the step's arguments and temporaries under ISSUE 59's line.
+    (PR 59 measured this compile and left it out: the tier-1 run stood at
+    1,414 s of its 1,470.)"""
+    from model_zoo.ouro import ouro as zoo
+
+    monkeypatch.setattr(fa, "use_interpret", lambda: False)
+    compiled = _cell_train_step(
+        one_chip, zoo, _cell_config("ouro-2.6b"), (1, 8192)
+    )
+    text = compiled.as_text()
+    _assert_two_kernels(text, "causal")
+    # q, k and v at their 16 heads of 128: one K/V head a query head
+    kernel = next(
+        line for line in text.splitlines()
+        if "causal_attention_dkv" in line and "custom-call(" in line
+    )
+    assert kernel.count("bf16[1,8192,2048]") >= 3
+    # the trips are a loop of the program, not four copies of the stack:
+    # six layers' calls of each kernel, not twenty-four
+    assert " while(" in text
+    for kernel in ("fwd", "dkv"):
+        assert len(re.findall(
+            rf"custom-call\(.*causal_attention_{kernel}", text
+        )) == 6, kernel
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    # `eight_layers`: 6.116e9 of arguments (12 bytes a parameter) + 5.432e9
+    # of temporaries = 11.548e9 at six layers (6.070e9 of temporaries
+    # before the final norm inside the loop was rematerialised too: PR 59),
+    # under ISSUE 59's line of 14.5e9
+    assert memory.argument_size_in_bytes == pytest.approx(6.116e9, rel=1e-3)
+    assert 11.2e9 < held < 12.0e9, held
+
+
 # a window of any width: both edges in the diagonal tile, an edge off the
 # tile grid, whole tiles inside the band; at a head of 64 (head-major) and
 # of 128
