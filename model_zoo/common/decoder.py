@@ -12,8 +12,10 @@ and the non-gated squared-ReLU MLP, grouped-query attention
 asked for), the routed block around `layers/moe.py: RoutedExperts` with
 its shared expert (gated where asked for) and its routing's source (the
 block's input where asked for), the cross-entropy
-taken in blocks of tokens, the per-position losses against the ids
-shifted (of one state or of several at once), the blocks'
+taken in blocks of tokens (its gradient made in the pass that makes a
+block's logits), the per-position losses against the ids shifted (of one
+state or of several at once, under the rows' own weights where given),
+the blocks'
 rematerialisation (`remat_block`, and what more of a block it keeps where
 the device has room, however many times a step the stack is applied:
 `remat_blocks`), and the zoo
@@ -770,49 +772,203 @@ def remat_blocks(
     return [remat_block(block_cls, names) for names in kept]
 
 
-def blocked_nll(h, head_kernel, targets, dtype, block: int = CE_BLOCK):
-    """(tokens,) float32 negative log-likelihood of `targets` under
-    softmax(h @ head_kernel), `block` tokens' logits at a time, each block
-    rebuilt in the backward: nothing (tokens, vocab)-shaped is ever held.
-    The kernel is cast inside the block so that its gradient sums over
-    the blocks in its own float32."""
-    tokens, hidden = h.shape
+def _ce_blocks(block: int, *rows):
+    """Each (tokens, ...) array as (tokens // block, block, ...)."""
+    return tuple(
+        a.reshape((a.shape[0] // block, block) + a.shape[1:]) for a in rows
+    )
+
+
+def _block_nll(h_block, kernel, t_block):
+    """A block's negative log-likelihoods from its float32 logits (the
+    operands in the compute type), and the two parts of its softmax,
+    exp(logits - the row's max) and its sum over the row."""
+    logits = jnp.dot(h_block, kernel, preferred_element_type=jnp.float32)
+    picked = jnp.take_along_axis(logits, t_block[:, None], axis=1)[:, 0]
+    highest = jnp.max(logits, axis=-1)
+    raised = jnp.exp(logits - highest[:, None])
+    total = jnp.sum(raised, axis=-1)
+    return jnp.log(total) + highest - picked, raised, total
+
+
+def _ce_block_grads(h_block, t_block, c_block, kernel, dtype):
+    """One block's (nll (block,), d h (block, hidden), d head (hidden,
+    vocab)), float32, for the rows' cotangents `c_block`: its logits made
+    once, then the gradient's two products, with the operand types JAX's
+    transposed product has (the float32 `delta` against the compute-type
+    kernel and states); `delta` reads the exponentials the log-sum-exp
+    made, as the transposed log-sum-exp does."""
+    # the block is sliced ONCE for its two products: fused into each, the
+    # slice cost the head's gradient a third more on the chip (PERF.md)
+    h_block = jax.lax.optimization_barrier(h_block.astype(dtype))
+    nll, raised, total = _block_nll(h_block, kernel, t_block)
+    columns = jnp.arange(kernel.shape[1], dtype=t_block.dtype)
+    delta = (c_block / total)[:, None] * raised - jnp.where(
+        columns == t_block[:, None], c_block[:, None], 0.0
+    )
+    return nll, jax.lax.dot_general(
+        delta, kernel, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ), jax.lax.dot_general(
+        h_block, delta, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _ce_grad_loop(h, head_kernel, targets, cotangents, dtype, block):
+    """ONE loop over the blocks, three vocabulary-wide products a block
+    (`_ce_block_grads`): (nll (tokens,), d h (tokens, hidden) float32,
+    d head (hidden, vocab) float32 summed over the blocks)."""
+    kernel = head_kernel.astype(dtype)
+
+    def one(d_head, args):
+        nll, d_h, more = _ce_block_grads(*args, kernel, dtype)
+        return d_head + more, (nll, d_h)
+
+    d_head, (nll, d_h) = jax.lax.scan(
+        one, jnp.zeros(kernel.shape, jnp.float32),
+        _ce_blocks(block, h, targets, cotangents),
+    )
+    return nll.reshape(-1), d_h.reshape(h.shape), d_head
+
+
+def blocks_again(uniform, blocks: int):
+    """How many of the `blocks` the backward makes again: none where the
+    rows' cotangent was `uniform`, the one the forward reckoned with."""
+    return jnp.where(uniform, 0, blocks)
+
+
+def _ce_grads_again(
+    again, saved, h, head_kernel, targets, cotangents, dtype, block,
+):
+    """`saved` (d h, d head) with the first `again` blocks' share made
+    anew for `cotangents`: all of them or none, so the loop runs whole or
+    not at all, in place either way."""
+    kernel = head_kernel.astype(dtype)
+    rows = _ce_blocks(block, h, targets, cotangents)
+
+    def one(i, grads):
+        d_h, d_head = grads
+        _, d_h_block, more = _ce_block_grads(
+            *(a[i] for a in rows), kernel, dtype
+        )
+        return d_h.at[i].set(d_h_block), jnp.where(i == 0, 0.0, d_head) + more
+
+    d_h, d_head = saved
+    d_h, d_head = jax.lax.fori_loop(
+        0, again, one, (d_h.reshape(rows[0].shape), d_head)
+    )
+    return d_h.reshape(h.shape), d_head
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _weighed_nll(h, head_kernel, targets, weights, dtype, block):
+    kernel = head_kernel.astype(dtype)
+    nll = jax.lax.map(
+        lambda args: _block_nll(args[0].astype(dtype), kernel, args[1])[0],
+        _ce_blocks(block, h, targets),
+    ).reshape(-1)
+    return weights * nll, nll
+
+
+def _weighed_nll_fwd(h, head_kernel, targets, weights, dtype, block):
+    nll, d_h, d_head = _ce_grad_loop(
+        h, head_kernel, targets, weights, dtype, block
+    )
+    return (weights * nll, nll), (
+        h, head_kernel, targets, weights, nll, d_h, d_head
+    )
+
+
+def _weighed_nll_bwd(dtype, block, saved, cotangents):
+    h, head_kernel, targets, weights, nll, d_h, d_head = saved
+    g_weighed, g_nll = cotangents
+    # The forward took the rows' cotangent to be `weights` times ONE
+    # scalar, which is what a mean of the weighed losses sends down; that
+    # is tested here, on the device, and anything else gets the loop again
+    # with the cotangent it did send.
+    counted = weights != 0
+    scalar = jnp.max(jnp.where(counted, g_weighed, -jnp.inf))
+    scalar = jnp.where(counted.any(), scalar, 0.0)
+    uniform = jnp.all(jnp.where(counted, g_weighed == scalar, True)) & (
+        jnp.all(g_nll == 0)
+    )
+    d_h, d_head = _ce_grads_again(
+        blocks_again(uniform, h.shape[0] // block), (d_h, d_head), h,
+        head_kernel, targets, g_weighed * weights + g_nll, dtype, block,
+    )
+    scalar = jnp.where(uniform, scalar, 1.0)
+    return (
+        (scalar * d_h).astype(h.dtype),
+        (scalar * d_head).astype(head_kernel.dtype),
+        None,
+        g_weighed * nll,
+    )
+
+
+_weighed_nll.defvjp(_weighed_nll_fwd, _weighed_nll_bwd)
+
+
+def blocked_nll(
+    h, head_kernel, targets, dtype, block: int = CE_BLOCK, weights=None,
+):
+    """(`weights` * nll, nll), both (tokens,) float32: the negative
+    log-likelihood of `targets` under softmax(h @ head_kernel), `block`
+    tokens' logits at a time, nothing (tokens, vocab)-shaped ever held.
+    `weights` (tokens,) float32, ones where absent, are what the loss
+    multiplies a row by, and they go IN: differentiated, the forward makes
+    a block's logits ONCE and takes the losses AND the gradient's two
+    products from them (`_ce_grad_loop`; the head's gradient sums over the
+    blocks in its own float32), which needs a row's cotangent before the
+    backward runs.  The backward scales what was saved by the one scalar
+    that a mean of the weighed losses sends down, in float32 and before
+    the states' gradient is rounded to their type; any other cotangent, on
+    either output, gets the exact gradient from a second loop.  The
+    gradient to `weights` is the cotangent times the loss, always.
+    Undifferentiated (eval, predict, `init`) it is one product a block."""
+    tokens = h.shape[0]
     if tokens % block:
         block = tokens
+    if weights is None:
+        weights = jnp.ones((tokens,), jnp.float32)
+    return _weighed_nll(
+        h, head_kernel, targets, weights.astype(jnp.float32), dtype, block
+    )
 
-    @jax.checkpoint
-    def one(args):
-        h_block, t_block = args
-        logits = jnp.dot(
-            h_block.astype(dtype), head_kernel.astype(dtype),
-            preferred_element_type=jnp.float32,
+
+def weighed_nll(
+    h, head_kernel, ids, shift: int, dtype, scope: str, weights=None,
+):
+    """(`weights` * nll, nll), both (B, L - shift): the per-position loss
+    of h (B, L, d) against the ids `shift` places on; the positions with
+    no such id are left out (they ride through the blocks at weight 0).
+    `h` may stack several states, (S, B, L, d), each read against the same
+    ids: (S, B, L - shift) then, as `weights` is where given, from ONE
+    blocked pass over all S x B x L rows, so that the head's gradient sums
+    over every state's blocks in one loop."""
+    batch, length = ids.shape
+    stacked = h.shape[:-3]
+    shape = stacked + (batch, length)
+    targets = jnp.roll(ids, -shift, axis=1)
+    if weights is None:
+        weights = jnp.ones(shape[:-1] + (length - shift,), jnp.float32)
+    weights = jnp.pad(
+        weights.astype(jnp.float32), [(0, 0)] * (len(shape) - 1) + [(0, shift)]
+    )
+    with jax.named_scope(scope):
+        weighed, nll = blocked_nll(
+            h.reshape(-1, h.shape[-1]), head_kernel,
+            jnp.broadcast_to(targets, shape).reshape(-1), dtype,
+            weights=weights.reshape(-1),
         )
-        picked = jnp.take_along_axis(logits, t_block[:, None], axis=1)[:, 0]
-        return jax.nn.logsumexp(logits, axis=-1) - picked
-
-    return jax.lax.map(one, (
-        h.reshape(tokens // block, block, hidden),
-        targets.reshape(tokens // block, block),
-    )).reshape(tokens)
+    return tuple(
+        out.reshape(shape)[..., :length - shift] for out in (weighed, nll)
+    )
 
 
 def shifted_nll(h, head_kernel, ids, shift: int, dtype, scope: str):
-    """(B, L - shift) per-position loss of h (B, L, d) against the ids
-    `shift` places on; the positions with no such id are left out.  `h`
-    may stack several states, (S, B, L, d), each read against the same
-    ids: (S, B, L - shift) then, from ONE blocked pass over all S x B x L
-    rows, so that the head's gradient sums over every state's blocks in
-    one loop."""
-    batch, length = ids.shape
-    stacked = h.shape[:-3]
-    targets = jnp.roll(ids, -shift, axis=1)
-    with jax.named_scope(scope):
-        out = blocked_nll(
-            h.reshape(-1, h.shape[-1]), head_kernel,
-            jnp.broadcast_to(targets, stacked + targets.shape).reshape(-1),
-            dtype,
-        ).reshape(stacked + (batch, length))
-    return out[..., :length - shift]
+    """`weighed_nll` at a weight of 1 on every position kept."""
+    return weighed_nll(h, head_kernel, ids, shift, dtype, scope)[0]
 
 
 # ---- the zoo functions of a next-token model ------------------------------

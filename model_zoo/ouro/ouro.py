@@ -27,9 +27,9 @@ broadcast): one set of leaves, `layer_0` .. `layer_{N-1}` with no trip in
 any path, a leaf's gradient the sum over the trips, and a step whose
 trace and compile are those of an N-layer model.  The four head passes
 stand OUTSIDE the loop as one blocked cross-entropy over the four normed
-states' rows (`decoder.shifted_nll`), so the head's float32 gradient sums
-over all of its blocks in one place and no other loop carries a (hidden,
-vocabulary) array.
+states' rows (`decoder.weighed_nll`, the exit distribution the rows'
+weights), so the head's float32 gradient sums over all of its blocks in one
+place and no other loop carries a (hidden, vocabulary) array.
 
 `predictions` are the per-position `sum_t p_t nll_t`; the entropy term
 reaches the objective through `step_metrics.AUX_LOSS` as `-beta * mean H`,
@@ -79,6 +79,7 @@ from model_zoo.common.decoder import (  # noqa: F401
     plain_rope,
     remat_blocks,
     shifted_nll,
+    weighed_nll,
 )
 
 # What the loop leaves a TRIP (`TripGauges`, the label the trip's path,
@@ -244,10 +245,11 @@ class Ouro(nn.Module):
             "lm_head_kernel", nn.initializers.lecun_normal(),
             (c.hidden, c.vocab_size),
         )
-        nll = shifted_nll(states, head, ids, 1, c.dtype, "ouro/head_ce")
         if c.trips == 1:
-            return nll[0]
-        positions = nll.shape[-1]
+            return shifted_nll(
+                states, head, ids, 1, c.dtype, "ouro/head_ce"
+            )[0]
+        positions = ids.shape[1] - 1
         with jax.named_scope("ouro/exit"):
             # the last trip has no gate to read: it takes what survives
             gate = nn.Dense(
@@ -259,7 +261,13 @@ class Ouro(nn.Module):
             log_p = exit_distribution(logits)                # (R, B, L - 1)
             p = jnp.exp(log_p)
             entropy = -jnp.sum(p * log_p, axis=0).mean()
-            weighed = jnp.sum(p * nll, axis=0)
+        # the exit distribution weighs the losses INSIDE the head's pass
+        # (the gate reads the states, not the losses), which so knows every
+        # row's cotangent as it makes the row's logits
+        weighed, nll = weighed_nll(
+            states, head, ids, 1, c.dtype, "ouro/head_ce", p
+        )
+        weighed = jnp.sum(weighed, axis=0)
         for t in range(c.trips):
             TripGauges(name=f"trip_{t + 1}")(nll[t].mean(), p[t].mean())
         sow_step_metric(self, "trip_exit_entropy_nats", entropy)

@@ -657,6 +657,13 @@ def test_the_interval_names_every_layer():
 # 10e12632...), and `tests/test_flash_attention.py::
 # test_the_lane_major_log_sum_exp_keeps_the_parents_bits` holds the kernels'
 # results to the parent's bits.
+# ALL SEVEN RE-RECORDED ON PURPOSE in PR 62: every one of them reads its head
+# through `decoder.blocked_nll`, whose forward loop makes the gradient's two
+# products beside the losses (a `custom_vjp`; no `checkpoint` around a
+# block's logits any more); nothing else of their programs moved (the commit
+# before gave 8da14260..., 7823daed..., 690fd4a6..., 0287421f..., 43cd4ec7...,
+# e8401b42..., b223f00c...), and the attention calls' digests in
+# `tests/test_flash_attention.py` stand.
 PARENTS_JAXPRS = {
     "kimi-linear-48b-a3b": (
         "kimi.kimi_linear", (2, 8192),
@@ -664,15 +671,15 @@ PARENTS_JAXPRS = {
         # kernels' bodies, whose substitution changed: `ops/kda.py`) and
         # in PR 56 (the KDA layer's norm a head, output gate and decay
         # over (B, L, heads x dim): `model_zoo/kimi/kimi_linear.py`)
-        "8da1426065a2c7e0e6f031ac7ff933b0464db98b9c16d995f325ad71e58ed55c",
+        "0c307c8e62781ddb18137b2973b85e6eca6769d93cf72166110597712901294d",
     ),
     "nemotron-3-nano-30b-a3b": (
         "nemotron.nemotron_h", (2, 8192),
-        "7823daedbce08788845e0c4ac65acbc24b74331cb9862a589a5df3e0032e20d9",
+        "6283dd03b08c0f0f534c96457a48366217d2ece823d9375aa644481ce4060b49",
     ),
     "glm-4.7-flash": (
         "glm.glm_moe_lite", (4, 4096),
-        "690fd4a6bff0ccf26f4b2a1bb02a0bd773d88a0a42a9a1da063b3a229393a205",
+        "7156f5ff16aa15dcd05b1af1b5c78cca77cc9e74f59d518d70915d6c6aec61ec",
     ),
     # the four below recorded at the commit before `RoutedExperts` and
     # `MoEFFN` learnt the routing's source, `FORMS` ReGLU and
@@ -680,19 +687,19 @@ PARENTS_JAXPRS = {
     # what they read here
     "laguna-xs.2": (
         "laguna.laguna", (2, 8192),
-        "0287421fb22fe1f15611bddd7d45a75b1c5812f733b5aee16af303e3527fc809",
+        "48dcc8a8e1005f72e6ee3e3a253d1acedd62365b7ee8fafab31ca01021a19dd1",
     ),
     "lfm2-24b-a2b": (
         "lfm2.lfm2_moe", (4, 8192),
-        "43cd4ec71e3658533b4797cb3f6c94d6fb47e724950210771b6c37c873063cbe",
+        "d4beacaf2b788510b18313c68de8a7b27240664882b77897e46524ed0274d034",
     ),
     "qwen3-next-80b-a3b": (
         "qwen3_next.qwen3_next", (2, 8192),
-        "e8401b42fbb8be29e958731082aa0b057e99718eb18578227469d0481a6ec794",
+        "0a5b5ed01f311335f44f80627e78c505196dde1d4193861192a5ab440132fe62",
     ),
     "granite-4.0-h-micro": (
         "granite.granite_hybrid", (1, 8192),
-        "b223f00c88f729f2fcdbd01da8e34fe12b8ef15f5df9d79a0174cf12ae790b8c",
+        "f4937eab9f6cdc257a27a6bb648daeac93ccea7d56a6149ef9bd313010e4b0f5",
     ),
 }
 

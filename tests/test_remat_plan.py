@@ -7,6 +7,7 @@ the estimate under it (`lean_step_bytes`) against the nine decoder
 cells' lean steps on the chip, what the chip's tiling adds to the saved
 names in each, and the program with and without room."""
 
+import functools
 import importlib
 import json
 import os
@@ -23,6 +24,7 @@ import pytest
 from elasticdl_tpu.common import metrics as metrics_lib
 from elasticdl_tpu.common.model_handler import get_model_spec
 from elasticdl_tpu.layers import moe
+from elasticdl_tpu.layers.step_metrics import AUX_LOSS
 from elasticdl_tpu.worker import trainer as trainer_lib
 from model_zoo.common import decoder
 from model_zoo.common.decoder import (
@@ -468,16 +470,22 @@ ZOOS = ["granite_hybrid", "laguna", "lfm2", "kimi_linear"]
 # PR 60 (the streaming attention forward's log-sum-exp lane-major) re-recorded
 # none of the four: the test models' heads of 16 take the blocked `lax` form,
 # whose log-sum-exp was lane-major already; the CELLS' programs moved and
-# are re-recorded in `tests/test_qwen3_next.py` and `tests/test_nemotron_h.py`
+# are re-recorded in `tests/test_qwen3_next.py` and `tests/test_nemotron_h.py`.
+# ALL FOUR RE-RECORDED ON PURPOSE in PR 62: every decoder's head is
+# `decoder.blocked_nll`, whose forward loop makes the gradient's two
+# products beside the losses where a rematerialised block made the logits
+# twice; nothing else of their programs moved (the commit before gave
+# 9780f560..., a3d03a92..., afb2441f..., 7131b7f1...; the attention calls'
+# digests in `tests/test_flash_attention.py` stand)
 PARENT_DIGESTS = {
     "granite_hybrid":
-        "9780f560a54164b1007f1767af322f0b3560e5ebb1922af1c0650514f78c7ddd",
+        "a65ef5f5914452b5d2ae0b7eafa0bc91eb0739fd9f1002b2eb3d653900436a43",
     "laguna":
-        "a3d03a9241c46317b05d7d2e025c3207bb4db429d666574eddd5d007ad275e83",
+        "5553de89c650c610f2b47094bc11a3870455a4676f87d0db9b23c8d4a193b379",
     "lfm2":
-        "afb2441f7e217f4ebda6a7c05f5a144ed909c1e925120ec41172c07facf5f524",
+        "5d69ac1dd9aa92f1ecdce3983a0f88964449f3178c078d45d92bbbe68aea412e",
     "kimi_linear":
-        "7131b7f13bdc1e33cc612e2c47f1a45cd74b145dbb9313df16ff2238920bd822",
+        "3c2439ebf5d23d17783213862513a0c1ec09fa85d6f59733d908bb158aa10a11",
 }
 
 
@@ -804,3 +812,293 @@ def test_several_states_rows_are_the_states_separate_passes():
     np.testing.assert_allclose(got, want, rtol=1e-6)
     for g, w in zip(got_grads, want_grads):
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+
+
+# ---- the cross-entropy that makes the head's gradient with the logits -----
+
+
+def plain_nll(h, head, targets):
+    """(rows,) float32 `logsumexp - picked`, nothing blocked or saved."""
+    logits = h.astype(jnp.float32) @ head.astype(jnp.float32)
+    return jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(
+        logits, targets[:, None], axis=1
+    )[:, 0]
+
+
+def ce_case(rows=48, hidden=8, vocab=32, seed=0):
+    rng = np.random.RandomState(seed)
+    return (
+        jnp.asarray(rng.randn(rows, hidden), jnp.float32),
+        jnp.asarray(0.5 * rng.randn(hidden, vocab), jnp.float32),
+        jnp.asarray(rng.randint(0, vocab, (rows,)), jnp.int32),
+        jnp.asarray(0.1 + rng.rand(rows), jnp.float32),
+    )
+
+
+def assert_close(got, want, rtol=2e-6):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        scale = float(jnp.abs(w).max()) or 1.0
+        np.testing.assert_allclose(g, w, rtol=0, atol=rtol * scale)
+
+
+def saved_gradient_was_used(monkeypatch):
+    """Records the `uniform` each backward of `blocked_nll` chose by."""
+    seen = []
+    choose = decoder.blocks_again
+
+    def recorded(uniform, blocks):
+        jax.debug.callback(lambda u: seen.append(bool(u)), uniform)
+        return choose(uniform, blocks)
+
+    monkeypatch.setattr(decoder, "blocks_again", recorded)
+    return seen
+
+
+def in_blocks_of(monkeypatch, rows: int):
+    """The shifted losses' pass in blocks of `rows` (the module's 2,048
+    would make one block of a test's few rows)."""
+    monkeypatch.setattr(decoder, "blocked_nll", functools.partial(
+        decoder.blocked_nll, block=rows
+    ))
+
+
+@pytest.mark.parametrize("tied", [False, True], ids=["head", "tied-table"])
+@pytest.mark.parametrize("shift", [1, 2])
+def test_a_mean_of_the_shifted_losses_gets_the_plain_gradient(
+    shift, tied, monkeypatch
+):
+    """(a) `shifted_nll` under a mean, the `shift` rows riding through
+    the blocks at weight 0: the losses and the gradients to the states
+    and to the head (or to the tied table the head is the transpose of)
+    are `jax.grad`'s of the plain float32 form, and every backward used
+    what the forward saved."""
+    used = saved_gradient_was_used(monkeypatch)
+    in_blocks_of(monkeypatch, 8)
+    rng = np.random.RandomState(1)
+    h = jnp.asarray(rng.randn(2, 12, 8), jnp.float32)
+    leaf = jnp.asarray(0.5 * rng.randn(*((32, 8) if tied else (8, 32))))
+    ids = jnp.asarray(rng.randint(0, 32, (2, 12)), jnp.int32)
+
+    def got(h, leaf):
+        return decoder.shifted_nll(
+            h, leaf.T if tied else leaf, ids, shift, jnp.float32, "ce"
+        )
+
+    def want(h, leaf):
+        return plain_nll(
+            h.reshape(-1, 8), leaf.T if tied else leaf,
+            jnp.roll(ids, -shift, axis=1).reshape(-1),
+        ).reshape(2, 12)[:, :12 - shift]
+
+    assert got(h, leaf).shape == (2, 12 - shift)
+    assert_close(got(h, leaf), want(h, leaf))
+    assert_close(
+        jax.grad(lambda *a: got(*a).mean(), argnums=(0, 1))(h, leaf),
+        jax.grad(lambda *a: want(*a).mean(), argnums=(0, 1))(h, leaf),
+    )
+    jax.effects_barrier()
+    assert used == [True]
+
+
+def test_stacked_states_under_their_own_weights_get_the_plain_gradient(
+    monkeypatch,
+):
+    """(b) Several states' rows against one set of ids, each row under
+    its own weight (an exit distribution's), summed over the states and
+    averaged: states, head AND weights get the plain form's gradients,
+    from what the forward saved."""
+    used = saved_gradient_was_used(monkeypatch)
+    in_blocks_of(monkeypatch, 16)
+    rng = np.random.RandomState(2)
+    states = jnp.asarray(rng.randn(3, 2, 16, 8), jnp.float32)
+    head = jnp.asarray(0.5 * rng.randn(8, 32), jnp.float32)
+    ids = jnp.asarray(rng.randint(0, 32, (2, 16)), jnp.int32)
+    weights = jax.nn.softmax(jnp.asarray(rng.randn(3, 2, 15)), axis=0)
+
+    def got(states, head, weights):
+        weighed, nll = decoder.weighed_nll(
+            states, head, ids, 1, jnp.float32, "ce", weights
+        )
+        return jnp.sum(weighed, axis=0).mean(), nll
+
+    def want(states, head, weights):
+        nll = plain_nll(
+            states.reshape(-1, 8), head,
+            jnp.broadcast_to(jnp.roll(ids, -1, axis=1), (3, 2, 16)).reshape(-1),
+        ).reshape(3, 2, 16)[..., :15]
+        return jnp.sum(weights * nll, axis=0).mean(), nll
+
+    argnums = (0, 1, 2)
+    (got_loss, got_nll), got_grads = jax.value_and_grad(
+        got, argnums, has_aux=True
+    )(states, head, weights)
+    (want_loss, want_nll), want_grads = jax.value_and_grad(
+        want, argnums, has_aux=True
+    )(states, head, weights)
+    assert_close((got_loss, got_nll), (want_loss, want_nll))
+    assert_close(got_grads, want_grads)
+    jax.effects_barrier()
+    assert used == [True]
+
+
+OTHER_COTANGENTS = {
+    "squared": lambda weighed, nll: jnp.sum(weighed ** 2),
+    "plain-losses-too": lambda weighed, nll: weighed.mean() + 0.1 * nll.sum(),
+    "plain-losses-alone": lambda weighed, nll: jnp.sum(nll * nll),
+    "one-row-off": lambda weighed, nll: weighed.mean() + weighed[5],
+}
+
+
+@pytest.mark.parametrize("name", sorted(OTHER_COTANGENTS))
+def test_any_other_cotangent_still_gets_the_exact_gradient(
+    name, monkeypatch
+):
+    """(c) A loss that sends the rows anything but one scalar times their
+    weights, or sends the plain losses a cotangent: the other branch runs
+    (observed, not asked for) and the gradients are the plain form's."""
+    used = saved_gradient_was_used(monkeypatch)
+    h, head, targets, weights = ce_case()
+    loss = OTHER_COTANGENTS[name]
+
+    def got(h, head, weights):
+        return loss(*decoder.blocked_nll(
+            h, head, targets, jnp.float32, 16, weights
+        ))
+
+    def want(h, head, weights):
+        nll = plain_nll(h, head, targets)
+        return loss(weights * nll, nll)
+
+    assert_close(
+        jax.grad(got, argnums=(0, 1, 2))(h, head, weights),
+        jax.grad(want, argnums=(0, 1, 2))(h, head, weights),
+    )
+    jax.effects_barrier()
+    assert used == [False]
+
+
+@pytest.mark.parametrize("zeros", [(0,), (0, 7, 47), range(16)],
+                         ids=["row-0", "three-rows", "a-whole-block"])
+def test_rows_of_weight_zero_do_not_break_the_uniformity_test(
+    zeros, monkeypatch
+):
+    """(e) Rows whose weight is 0 (row 0 among them) may be sent any
+    cotangent, none at all included: the test reads the rows that count,
+    the saved gradient is used, and it is the plain form's."""
+    used = saved_gradient_was_used(monkeypatch)
+    h, head, targets, weights = ce_case()
+    weights = weights.at[jnp.asarray(list(zeros))].set(0.0)
+    kept = jnp.asarray([i for i in range(48) if i not in set(zeros)])
+
+    def got(h, head):
+        return decoder.blocked_nll(
+            h, head, targets, jnp.float32, 16, weights
+        )[0][kept].sum() / 48
+
+    def want(h, head):
+        return (weights * plain_nll(h, head, targets))[kept].sum() / 48
+
+    assert_close(
+        jax.grad(got, argnums=(0, 1))(h, head),
+        jax.grad(want, argnums=(0, 1))(h, head),
+    )
+    jax.effects_barrier()
+    assert used == [True]
+
+
+def test_all_rows_at_weight_zero_have_a_zero_gradient():
+    h, head, targets, weights = ce_case()
+    grads = jax.grad(lambda h, head: decoder.blocked_nll(
+        h, head, targets, jnp.float32, 16, 0.0 * weights
+    )[0].mean(), argnums=(0, 1))(h, head)
+    assert all(not np.asarray(g).any() for g in grads)
+
+
+def vocabulary_wide(eqns, vocab):
+    return [
+        eqn for eqn in eqns
+        if any(vocab in v.aval.shape for v in eqn.invars + eqn.outvars)
+    ]
+
+
+@pytest.mark.parametrize(
+    "name, heads", [("laguna", 1), ("granite_hybrid", 1), ("ouro", 1),
+                    ("glm_moe_lite", 2)],
+)
+def test_a_decoders_gradient_makes_each_blocks_logits_once(name, heads):
+    """(d) In a tiny decoder's gradient every loop of the cross-entropy
+    holds THREE vocabulary-wide products (the logits and the gradient's
+    two) and carries the head's (hidden, vocabulary) float32 gradient:
+    a head pass has one such loop in the forward and one in the backward
+    (which runs all of its blocks again or none); nothing under
+    `*/head_ce` is rematerialised.  Its eval program holds ONE product a
+    loop and carries nothing."""
+    from tests.test_routed_walk_forms import equations
+
+    tests = importlib.import_module(f"tests.test_{name}")
+    vocab = 50
+    model = tests.model_of(
+        dict(tests.CONFIG, vocab_size=vocab), bf16=True, remat=True
+    )
+    features = {"input_ids": jnp.zeros((2, 128), jnp.int32)}
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0), features)
+    state = {k: v for k, v in shapes.items() if k != "params"}
+
+    def loss(params, state):
+        out, sown = model.apply(
+            {"params": params, **state}, features, mutable=True
+        )
+        return out.astype(jnp.float32).mean() + sum(
+            jnp.sum(leaf) for leaf in jax.tree.leaves(sown.get(AUX_LOSS, {}))
+        )
+
+    def loops(jaxpr):
+        """[(a loop over vocabulary-wide products, how many it holds, the
+        shapes and types it carries)]: the forward's `scan`s and the
+        backward's `while`s."""
+        found = []
+        for name, body in (("scan", "jaxpr"), ("while", "body_jaxpr")):
+            for eqn in equations(jaxpr, name):
+                inner = eqn.params[body]
+                dots = vocabulary_wide(
+                    equations(inner.jaxpr, "dot_general"), vocab
+                )
+                first = eqn.params.get("num_consts", eqn.params.get(
+                    "body_nconsts"
+                ))
+                carried = inner.in_avals[first:][:eqn.params.get(
+                    "num_carry", len(inner.in_avals)
+                )]
+                if dots:
+                    found.append((name, len(dots), [
+                        (c.shape, c.dtype) for c in carried if c.ndim > 1
+                    ]))
+        return found
+
+    d_head = ((model.config.hidden, vocab), jnp.float32)
+    grad = jax.make_jaxpr(jax.grad(loss))(shapes["params"], state)
+    found = loops(grad.jaxpr)
+    assert sorted(name for name, _, _ in found) == (
+        ["scan"] * heads + ["while"] * heads
+    )
+    for name, dots, carried in found:
+        assert dots == 3
+        assert d_head in carried
+        if name == "scan":
+            assert carried == [d_head]
+    for remat in equations(grad.jaxpr, "checkpoint"):
+        assert not vocabulary_wide(
+            equations(remat.params["jaxpr"], "dot_general"), vocab
+        )
+    text = jax.jit(jax.grad(loss)).lower(shapes["params"], state).as_text(
+        debug_info=True
+    )
+    under = [line for line in text.splitlines() if "/head_ce/" in line]
+    assert under
+    assert not [
+        line for line in under
+        if "checkpoint" in line or "rematted_computation" in line
+    ]
+    assert loops(jax.make_jaxpr(loss)(shapes["params"], state).jaxpr) == (
+        [("scan", 1, [])] * heads
+    )
