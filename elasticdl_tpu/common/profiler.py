@@ -115,6 +115,14 @@ DEVICE_SCOPES = (
     # entropy and the weighing of the trips' losses
     "ouro/embed", "ouro/trips", "ouro/norm", "ouro/attn", "ouro/dense_ffn",
     "ouro/exit", "ouro/head_ce",
+    # model_zoo/olmo_hybrid: the delta-rule layer's five are
+    # `model_zoo/common/delta_net.py`'s under this model's prefix;
+    # `attn/qk_norm` is the whole-width QK-norm inside `attn` (its sum of
+    # squares is what head-parallel chips exchange)
+    "olmo_hybrid/embed", "olmo_hybrid/norm", "olmo_hybrid/gdn/proj",
+    "olmo_hybrid/gdn/conv", "olmo_hybrid/gdn/decay", "olmo_hybrid/gdn/core",
+    "olmo_hybrid/gdn/out", "olmo_hybrid/attn", "olmo_hybrid/attn/qk_norm",
+    "olmo_hybrid/dense_ffn", "olmo_hybrid/head_ce",
 )
 
 #: Kernels the TPU's compiler makes from ONE primitive and names after

@@ -12,7 +12,11 @@ the one entry a model calls: two Pallas kernels where the shapes tile
 (`gdn_shapes_ok`), named `gdn_chunk_fwd` and `gdn_chunk_bwd` so that a
 device trace tells them from KDA's and from every other fusion, and
 `chunked_gdn`, the same chunked mathematics in plain `jnp` under autodiff,
-elsewhere: the arrangement of `ops/kda.py: kda`.
+elsewhere: the arrangement of `ops/kda.py: kda`.  Key and value heads may
+differ in width (the state is (dv, dk), not square), and a width that is
+no whole lane tile (96, 192) reaches the kernels padded with zero columns
+to the next one (`_lane_padded`): exact, and a quarter of what they then
+process is padding (`padded_lanes_ratio`).
 
 The chunked form is `ops/kda.py`'s header with D a (C, C) MATRIX a head
 and not a (C, C, dk) object: with G_i the running sum of g over the
@@ -95,21 +99,62 @@ RESULT_NAMES = ("gdn_core_out", "gdn_core_states")
 SAVED_NAMES = ()
 
 
+def _whole_tiles(width: int) -> int:
+    """`width` columns in whole lane tiles."""
+    return -(-width // _LANES) * _LANES
+
+
+def _pads_little(width: int) -> bool:
+    """Whether at most a quarter of a head's whole lane tiles is padding
+    (96 of 128, 192 of 256; not 64 of 128, nor a test model's 8): a
+    narrower head goes the plain form, where the kernels would mostly
+    multiply zeros."""
+    return 4 * width >= 3 * _whole_tiles(width)
+
+
 def gdn_shapes_ok(q_shape, k_shape, v_shape) -> bool:
     """Whether the kernels take q, k (B, L, H_k, dk) and v (B, L, H_v,
     dv): q and k alike, whole value heads a key head and no more of them
-    than a grid step takes (`_HEADS` of `_LANES`), heads of whole lane
-    tiles, whole chunks."""
+    than a grid step takes (`_HEADS` of `_LANES`), whole chunks, and heads
+    of whole lane tiles or little short of them (`_pads_little`), which
+    `gdn` pads to whole tiles (96 -> 128, 192 -> 256); dk and dv need not
+    be equal."""
     return (
         len(q_shape) == 4 and len(v_shape) == 4
         and tuple(q_shape) == tuple(k_shape)
         and tuple(q_shape[:2]) == tuple(v_shape[:2])
         and v_shape[2] % q_shape[2] == 0
-        and v_shape[2] // q_shape[2] * max(q_shape[3], v_shape[3])
-        <= _HEADS * _LANES
-        and q_shape[3] % _LANES == 0 and v_shape[3] % _LANES == 0
+        and v_shape[2] // q_shape[2] * max(
+            _whole_tiles(q_shape[3]), _whole_tiles(v_shape[3])
+        ) <= _HEADS * _LANES
+        and _pads_little(q_shape[3]) and _pads_little(v_shape[3])
         and q_shape[1] % CHUNK == 0
     )
+
+
+def padded_lanes_ratio(q_shape, k_shape, v_shape) -> float:
+    """The share of the q, k and v columns the kernels process that is
+    padding: 0.25 at heads of 96 | 192, 0 at heads of whole lane tiles and
+    where the shapes go the plain form (no kernel processes anything)."""
+    if not gdn_shapes_ok(q_shape, k_shape, v_shape):
+        return 0.0
+    (key_heads, dk), (heads, dv) = q_shape[2:], v_shape[2:]
+    real = 2 * key_heads * dk + heads * dv
+    processed = 2 * key_heads * _whole_tiles(dk) + heads * _whole_tiles(dv)
+    return 1.0 - real / processed
+
+
+def _lane_padded(t):
+    """(B, L, H, D) with zero columns up to whole lane tiles a head.
+    Zero q and k columns add nothing to Q K^T, K K^T or |x|^2 (the L2
+    norm's scale is handed over, not taken from the width), zero v columns
+    give zero state rows and zero output columns: the kernels' result on
+    the real columns is the unpadded one, and autodiff slices the
+    gradients back."""
+    extra = _whole_tiles(t.shape[3]) - t.shape[3]
+    if not extra:
+        return t
+    return jnp.pad(t, ((0, 0), (0, 0), (0, 0), (0, extra)))
 
 
 # ---- the plain chunked form ------------------------------------------------
@@ -570,12 +615,21 @@ def gdn(q, k, v, g, beta, qk_norm=None):
     tile (`gdn_shapes_ok`), the chunked `jnp` form elsewhere, which pads
     a length that is no whole number of chunks.  With `qk_norm` = (eps,
     q's scale), q and k are first L2-normalised a head, x / sqrt(|x|^2 +
-    eps), and q scaled, in float32 INSIDE the op, once a key head."""
+    eps), and q scaled, in float32 INSIDE the op, once a key head.  Heads
+    that are no whole lane tile wide reach the kernels padded to one and
+    the output is sliced back (`_lane_padded`).  Nothing here bounds beta:
+    at beta in (1, 2) the transition exp(g)(I - beta k k^T) has a negative
+    eigenvalue along k, and the chunk's linear system is solved as it
+    is."""
     from elasticdl_tpu.parallel.mesh import in_export_mode
 
     g = g.astype(jnp.float32)
     if qk_norm is not None:
         qk_norm = (float(qk_norm[0]), float(qk_norm[1]))
     if gdn_shapes_ok(q.shape, k.shape, v.shape) and not in_export_mode():
-        return _gdn(q, k, v, g, beta, qk_norm)
+        out = _gdn(
+            _lane_padded(q), _lane_padded(k), _lane_padded(v), g, beta,
+            qk_norm,
+        )
+        return out[..., :v.shape[3]]
     return chunked_gdn(q, k, v, g, beta, qk_norm)

@@ -1,15 +1,21 @@
-"""What the zoo's nine decoder language models have in common
+"""What the zoo's ten decoder language models have in common
 (`model_zoo/glm/glm_moe_lite.py`, `laguna/laguna.py`, `lfm2/lfm2_moe.py`,
 `kimi/kimi_linear.py`, `granite/granite_hybrid.py`,
 `nemotron/nemotron_h.py`, `qwen3_next/qwen3_next.py`,
-`smallthinker/smallthinker.py`, `ouro/ouro.py`): RMSNorm (its scale
-plain or zero-centred) and its gated form (one statistic a group of
-channels, the gate before the norm or after it), rotary's turn whole or
+`smallthinker/smallthinker.py`, `ouro/ouro.py`,
+`olmo_hybrid/olmo_hybrid.py`): RMSNorm (its scale
+plain or zero-centred), its gated form (one statistic a group of
+channels, the gate before the norm or after it) and its whole-width form
+(`WholeWidthNorm`: one statistic over every head's columns together),
+rotary's turn whole or
 over a head's first columns (`Rope`, `partial_rotary`), the seeds of a
 decay (`a_log_init`, `dt_bias_init`), the bias-free dense layer, SwiGLU
 and the non-gated squared-ReLU MLP, grouped-query attention
 (`GroupedAttention`: no positions, no band, no norms and no gate unless
-asked for), the routed block around `layers/moe.py: RoutedExperts` with
+asked for; a head-wise or a whole-width QK-norm; only the HEADS a chip
+holds where told `held_heads`, its part of the output and the norm's sum
+of squares summed over a named axis where one is given: `held_of`,
+`summed_over`), the routed block around `layers/moe.py: RoutedExperts` with
 its shared expert (gated where asked for) and its routing's source (the
 block's input where asked for), the cross-entropy
 taken in blocks of tokens (its gradient made in the pass that makes a
@@ -331,15 +337,63 @@ class ReLU2MLP(nn.Module):
 MLP_OF_FORM = {SWIGLU: SwiGLU, RELU2: ReLU2MLP}
 
 
+def held_of(heads: int, held: Optional[Tuple[int, int]]) -> int:
+    """How many of `heads` a mixer told `held` = (first, count) builds:
+    its share of a layer that head-parallel chips divide; all of them
+    where it is told nothing."""
+    if held is None:
+        return heads
+    first, count = held
+    if first < 0 or count < 1 or first + count > heads:
+        raise ValueError(f"held heads {held} of {heads}")
+    return count
+
+
+def summed_over(axis_name: Optional[str], value):
+    """`value` summed over the head-parallel chips (the named axis of the
+    `shard_map` / `vmap` they run under) where there are any; where no
+    axis is named, one chip runs alone and nothing is emitted."""
+    return value if axis_name is None else jax.lax.psum(value, axis_name)
+
+
+class WholeWidthNorm(nn.Module):
+    """RMSNorm with ONE statistic over every column of a projection (all
+    heads' together, OLMo 2's QK-norm) and a plain scale.  The statistic
+    is not head-wise: under `axis_name` the sum of squares and the width
+    are summed over the chips that share the heads (one number a token is
+    what they exchange); with no axis it is the held columns' own mean."""
+
+    eps: float
+    dtype: jnp.dtype = jnp.float32
+    axis_name: Optional[str] = None
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x = x.astype(jnp.float32)
+        squares = summed_over(
+            self.axis_name, jnp.sum(jnp.square(x), axis=-1, keepdims=True)
+        )
+        width = summed_over(self.axis_name, x.shape[-1])
+        return (
+            x * jax.lax.rsqrt(squares / width + self.eps) * scale
+        ).astype(self.dtype)
+
+
 class GroupedAttention(nn.Module):
     """`heads` query heads over `kv_heads` key/value heads, causal, the
     logits times `scale`.  As it stands: no positions, no band, no norms,
     no gate.  `qk_norm_eps` norms q and k a head (a zero-centred scale
     each, `q_norm` and `k_norm`) before `rope` turns a head's first
-    columns; with `window`, query t sees the keys s with t - window < s <=
-    t; with `query_gate` the q projection is twice as wide, a head's
+    columns, or, with `qk_norm_whole`, over ALL of the projection's
+    columns at once under a plain scale (`WholeWidthNorm`, in the scope
+    `qk_norm`); with `window`, query t sees the keys s with t - window < s
+    <= t; with `query_gate` the q projection is twice as wide, a head's
     columns split q | gate, and the output is times sigmoid(gate), element
-    by element."""
+    by element.  With `held_heads` = (first, count) the layer builds only
+    those query heads' columns of Wq (their K/V heads' of Wk and Wv) and
+    rows of Wo and returns its part of the output, summed over `axis_name`
+    where head-parallel chips run under one (`summed_over`)."""
 
     hidden: int
     heads: int
@@ -352,11 +406,17 @@ class GroupedAttention(nn.Module):
     rope: Optional[Rope] = None
     query_gate: bool = False
     window: Optional[int] = None
+    qk_norm_whole: bool = False
+    held_heads: Optional[Tuple[int, int]] = None
+    axis_name: Optional[str] = None
 
     @nn.compact
     def __call__(self, x):
         batch, length, _ = x.shape
-        heads, kv_heads, dim = self.heads, self.kv_heads, self.head_dim
+        heads, dim = held_of(self.heads, self.held_heads), self.head_dim
+        if heads * self.kv_heads % self.heads:
+            raise ValueError("a share holds whole K/V heads")
+        kv_heads = heads * self.kv_heads // self.heads
         with jax.named_scope(self.trace_scope):
             q, k, v = (
                 dense(count * width, name, self.dtype, MIXER_IN)(x).reshape(
@@ -369,7 +429,16 @@ class GroupedAttention(nn.Module):
             )
             if self.query_gate:
                 q, gate = jnp.split(q, 2, axis=-1)
-            if self.qk_norm_eps is not None:
+            if self.qk_norm_eps is not None and self.qk_norm_whole:
+                with jax.named_scope("qk_norm"):
+                    q, k = (
+                        WholeWidthNorm(
+                            self.qk_norm_eps, self.dtype, self.axis_name,
+                            name=name,
+                        )(t.reshape(batch, length, -1)).reshape(t.shape)
+                        for name, t in (("q_norm", q), ("k_norm", k))
+                    )
+            elif self.qk_norm_eps is not None:
                 q, k = (
                     RMSNorm(self.qk_norm_eps, self.dtype, True, name=name)(t)
                     for name, t in (("q_norm", q), ("k_norm", k))
@@ -385,8 +454,11 @@ class GroupedAttention(nn.Module):
                 gate = jax.nn.sigmoid(gate.astype(jnp.float32))
                 sow_step_metric(self, "query_gate_mean_ratio", gate.mean())
                 out = (out * gate).astype(self.dtype)
-            return dense(self.hidden, "o", self.dtype, MIXER_OUT)(
-                out.reshape(batch, length, heads * dim)
+            return summed_over(
+                self.axis_name,
+                dense(self.hidden, "o", self.dtype, MIXER_OUT)(
+                    out.reshape(batch, length, heads * dim)
+                ),
             )
 
 

@@ -29,7 +29,9 @@ leaf (tests/decoder_cases.py), its delta rule the token-by-token
 recurrence.  What it shares with the zoo's other decoders (norms, the
 gated output norm, grouped attention, the routed block, the blocked
 cross-entropy, the blocks' remat) is `model_zoo/common/decoder.py`; the
-scan is `ops/gdn.py: gdn`, the convolution `ops/short_conv.py:
+delta-rule layer is `model_zoo/common/delta_net.py: GatedDeltaNet` with
+its projections fused (shared with `olmo_hybrid/olmo_hybrid.py`), its
+scan `ops/gdn.py: gdn`, its convolution `ops/short_conv.py:
 silu_short_conv` over the q | k | v columns of the fused projection.
 
 Layer i (a PUBLISHED 0-based index, listed in `layers`) mixes by
@@ -51,25 +53,14 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from elasticdl_tpu.common import metrics as metrics_lib
-from elasticdl_tpu.layers import step_metrics
 from elasticdl_tpu.layers.embedding import DistributedEmbedding
 from elasticdl_tpu.layers.moe import SOFTMAX
-from elasticdl_tpu.layers.step_metrics import sow_step_metric
-from elasticdl_tpu.ops.gdn import gdn
-from elasticdl_tpu.ops.short_conv import silu_short_conv
 from model_zoo.bert.bert_finetune import feed, feed_bulk  # noqa: F401
 from model_zoo.common.decoder import (  # noqa: F401
-    MIXER_IN,
-    MIXER_OUT,
-    GatedRMSNorm,
     GroupedAttention,
     MoEFFN,
     RMSNorm,
     Rope,
-    a_log_init,
-    dense,
-    dt_bias_init,
     eval_metrics_fn,
     loss,
     optimizer,
@@ -78,95 +69,8 @@ from model_zoo.common.decoder import (  # noqa: F401
     remat_blocks,
     routed_walks,
     shifted_nll,
-    tap_init,
 )
-
-L2_EPS = 1e-6
-
-
-# What a delta-rule layer sows into STEP_METRICS, read once a task with
-# the loss: leaf name -> gauge by layer.
-step_metrics.declare(
-    "gdn_decay_mean_ratio",
-    metrics_lib.default_registry().gauge(
-        "worker_gdn_decay_mean_ratio",
-        "mean of a gated-delta-rule layer's per-head decay exp(g) over "
-        "tokens and value heads, last step of the task (0 forgets "
-        "everything, 1 nothing: a decay that collapses is silent in the "
-        "loss for long)",
-        labelnames=("layer",),
-    ),
-)
-step_metrics.declare(
-    "gdn_beta_mean_ratio",
-    metrics_lib.default_registry().gauge(
-        "worker_gdn_beta_mean_ratio",
-        "mean of a gated-delta-rule layer's write strength sigmoid(b) over "
-        "tokens and value heads, last step of the task",
-        labelnames=("layer",),
-    ),
-)
-
-
-class GatedDeltaNet(nn.Module):
-    """The gated delta rule: `value_heads` value heads of `head_dim`
-    columns over `key_heads` key heads of as many, q, k and v through a
-    `taps`-tap causal depthwise conv."""
-
-    hidden: int
-    key_heads: int
-    value_heads: int
-    head_dim: int
-    taps: int
-    eps: float
-    dtype: jnp.dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        batch, length, _ = x.shape
-        dim, heads = self.head_dim, self.value_heads
-        keys, values = self.key_heads * dim, heads * dim
-        with jax.named_scope("qwen3_next/gdn/proj"):
-            qkv, z = jnp.split(
-                dense(2 * keys + 2 * values, "qkvz", self.dtype, MIXER_IN)(x),
-                [2 * keys + values], axis=-1,
-            )
-        with jax.named_scope("qwen3_next/gdn/conv"):
-            weight = self.param(
-                "conv_kernel", tap_init, (self.taps, 2 * keys + values)
-            )
-            q, k, v = (
-                t.reshape(batch, length, -1, dim) for t in jnp.split(
-                    silu_short_conv(qkv, weight), [keys, 2 * keys], axis=-1
-                )
-            )
-        # `decay`, not `gate`: `attn_proj_ms_per_step` takes every
-        # model's `*/gate`
-        with jax.named_scope("qwen3_next/gdn/decay"):
-            a_log = self.param("A_log", a_log_init, (heads,))
-            dt_bias = self.param("dt_bias", dt_bias_init, (heads,))
-            b, a = jnp.split(
-                dense(2 * heads, "ba", self.dtype)(x).astype(jnp.float32), 2,
-                axis=-1,
-            )
-            g = -jnp.exp(a_log) * jax.nn.softplus(a + dt_bias)
-            beta = jax.nn.sigmoid(b)
-            # a decay that collapses (0 forgets everything, 1 nothing) is
-            # silent in the loss for a long while
-            sow_step_metric(self, "gdn_decay_mean_ratio", jnp.exp(g).mean())
-            sow_step_metric(self, "gdn_beta_mean_ratio", beta.mean())
-        with jax.named_scope("qwen3_next/gdn/core"):
-            # q and k are L2-normed a head, q then times d_k^-1/2, in the op
-            out = gdn(q, k, v, g, beta, qk_norm=(L2_EPS, dim ** -0.5))
-        with jax.named_scope("qwen3_next/gdn/out"):
-            # the norm FIRST, then the gate: a statistic a value head, one
-            # scale of `head_dim` that the heads share
-            out = GatedRMSNorm(
-                self.eps, self.dtype, heads, gate_first=False,
-                shared_scale=True, name="o_norm",
-            )(out.reshape(batch, length, values), z)
-            return dense(self.hidden, "o", self.dtype, MIXER_OUT)(out)
-
+from model_zoo.common.delta_net import GatedDeltaNet
 
 @dataclasses.dataclass(frozen=True)
 class Qwen3NextConfig:
@@ -211,7 +115,8 @@ class Block(nn.Module):
         if self.is_gdn:
             y = GatedDeltaNet(
                 c.hidden, c.gdn_key_heads, c.gdn_value_heads, c.gdn_head_dim,
-                c.conv_kernel, c.eps, c.dtype, name="gdn",
+                c.gdn_head_dim, c.conv_kernel, c.eps, c.dtype,
+                "qwen3_next/gdn", name="gdn",
             )(y)
         else:
             y = GroupedAttention(

@@ -7,7 +7,9 @@ all five gradients, at one and at two value heads a key head, at no decay,
 at a mild one and at one so strong that `1 / exp(G)` would overflow; a
 length that is no whole number of chunks; the norms inside the op; the
 tie to `ops/kda.py` (the scalar op is `kda` with g broadcast over the
-channels); the admission rule, the names and the types."""
+channels); heads that are no whole lane tile and differ in width between
+keys and values (96 | 192, padded to 128 | 256 for the kernels) under a
+write strength in (1, 2); the admission rule, the names and the types."""
 
 import re
 
@@ -109,6 +111,82 @@ def test_chunked_forms_match_the_recurrence(form, ratio, g_min):
         assert_close(a, b, 2e-4, name)
 
 
+def wide_inputs(batch, length, heads, dk, dv, g_min, beta_low, seed=0):
+    """`inputs` at key heads `dk` wide under value heads `dv` wide, one a
+    key head, raw q and k (the op norms them) and beta uniform in
+    [beta_low, beta_low + 1]."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    shape = (batch, length, heads)
+    return (
+        3.0 * jax.random.normal(keys[0], (*shape, dk)) + 0.1,
+        0.5 * jax.random.normal(keys[1], (*shape, dk)) - 0.05,
+        jax.random.normal(keys[2], (*shape, dv)),
+        g_min * jax.random.uniform(keys[3], shape),
+        beta_low + jax.random.uniform(keys[4], shape),
+        jax.random.normal(keys[5], (*shape, dv)),
+    )
+
+
+# the Olmo-Hybrid cell's heads: 96 | 192 is (dk, dv), neither a whole lane
+# tile nor equal; r = 1; beta in (1, 2) is the negative-eigenvalue range
+WIDE = [
+    pytest.param(gdn_ops.chunked_gdn, -1.0, 1.0, id="jnp-mild-over-one"),
+    pytest.param(gdn_ops.gdn, -1.0, 1.0, id="kernels-mild-over-one"),
+    pytest.param(gdn_ops.gdn, -20.0, 1.0, id="kernels-strong-over-one"),
+    pytest.param(gdn_ops.gdn, 0.0, 0.0, id="kernels-no-decay-under-one"),
+]
+
+
+@pytest.mark.parametrize("form, g_min, beta_low", WIDE)
+def test_heads_of_96_and_192_match_the_recurrence(form, g_min, beta_low):
+    """Two chunks of two heads 96 wide in keys and 192 in values, the L2
+    norms inside the op at 96^-1/2: the output and all five gradients
+    against the recurrence on operands normed outside.  `gdn` hands the
+    kernels 128 | 256 zero-padded columns a head and slices back."""
+    q, k, v, g, beta, weight = wide_inputs(1, 128, 2, 96, 192, g_min,
+                                           beta_low, seed=3)
+    assert gdn_ops.gdn_shapes_ok(q.shape, k.shape, v.shape)
+    norm = (1e-6, 96 ** -0.5)
+
+    def plain(q, k, v, g, beta):
+        return recurrence(
+            kda_ops.l2_normed(q, *norm), kda_ops.l2_normed(k, norm[0]),
+            v, g, beta,
+        )
+
+    def inside(q, k, v, g, beta):
+        return form(q, k, v, g, beta, norm)
+
+    want_out, want = out_and_grads(plain, q, k, v, g, beta, weight)
+    out, got = out_and_grads(inside, q, k, v, g, beta, weight)
+    assert out.shape == (1, 128, 2, 192)
+    assert np.isfinite(np.asarray(out)).all()
+    assert_close(out, want_out, 5e-5, "o")
+    for name, a, b in zip(NAMES, got, want):
+        assert a.shape == b.shape and np.isfinite(np.asarray(a)).all()
+        assert_close(a, b, 2e-4, name)
+
+
+def test_the_kernels_see_padded_heads_and_the_caller_does_not():
+    """At 96 | 192 the entry runs the two kernels on (B, L, H x 128) keys
+    and (B, L, H x 256) values with a (256, 128) state a head; a quarter
+    of those columns is padding, none at heads of whole tiles."""
+    q, k, v, g, beta, _ = wide_inputs(1, 128, 2, 96, 192, -1.0, 1.0)
+    jaxpr = str(jax.make_jaxpr(jax.grad(
+        lambda *a: gdn_ops.gdn(*a).sum(), argnums=(0, 1, 2)
+    ))(q, k, v, g, beta))
+    assert "gdn_chunk_fwd" in jaxpr and "gdn_chunk_bwd" in jaxpr
+    assert "f32[1,128,256]" in jaxpr and "f32[1,128,512]" in jaxpr
+    assert "f32[1,2,2,256,128]" in jaxpr            # the boundary states
+    assert gdn_ops.padded_lanes_ratio(q.shape, k.shape, v.shape) == 0.25
+    whole = ((1, 128, 2, 128), (1, 128, 2, 128), (1, 128, 4, 128))
+    assert gdn_ops.padded_lanes_ratio(*whole) == 0.0
+    # no kernel runs on a test model's narrow heads: nothing is padded
+    narrow = ((1, 128, 2, 8), (1, 128, 2, 8), (1, 128, 2, 16))
+    assert gdn_ops.padded_lanes_ratio(*narrow) == 0.0
+    assert gdn_ops._groups(10, 1, 256) == (2, 2)     # the cell's ten heads
+
+
 @pytest.mark.parametrize("ratio", RATIOS)
 def test_the_entry_pads_a_length_that_is_no_whole_chunk(ratio):
     """80 positions go the `jnp` form, padded to 128 with tokens that
@@ -199,6 +277,13 @@ def test_bfloat16_operands_keep_float32_state_and_types():
     (((2, 100, 16, 128), (2, 100, 32, 128)), False),    # no whole chunks
     (((2, 128, 16, 64), (2, 128, 32, 128)), False),     # no whole lane tile
     (((2, 128, 2, 128), (2, 128, 32, 128)), False),     # 16 a key head
+    # the Olmo-Hybrid cell's: 96 | 192, one value head a key head, padded
+    (((1, 8192, 10, 96), (1, 8192, 10, 192)), True),
+    (((1, 8192, 30, 96), (1, 8192, 30, 192)), True),
+    (((1, 128, 2, 128), (1, 128, 2, 256)), True),       # dk != dv, whole
+    (((1, 128, 2, 96), (1, 128, 2, 64)), False),        # half a tile pads
+    (((1, 128, 2, 8), (1, 128, 2, 16)), False),         # a test's heads
+    (((1, 128, 1, 96), (1, 128, 8, 192)), False),       # 8 x 256 a key head
 ])
 def test_the_admission_rule(shapes, ok):
     qk, v = shapes

@@ -23,7 +23,7 @@ from elasticdl_tpu.layers.step_metrics import STEP_METRICS
 from elasticdl_tpu.ops import gdn as gdn_ops
 from elasticdl_tpu.ops import short_conv
 from elasticdl_tpu.ops.flash_attention import stream_shapes_ok
-from model_zoo.common import decoder
+from model_zoo.common import decoder, delta_net
 from model_zoo.qwen3_next import qwen3_next as zoo
 from tests import decoder_cases
 from tests.decoder_cases import MUTABLE, computed, seeded  # noqa: F401
@@ -262,9 +262,9 @@ def test_sixteen_holders_and_one_gated_shared_expert_are_the_uncut_layer():
 
 
 def _gate_before_norm(monkeypatch):
-    plain = zoo.GatedRMSNorm
+    plain = delta_net.GatedRMSNorm
     monkeypatch.setattr(
-        zoo, "GatedRMSNorm",
+        delta_net, "GatedRMSNorm",
         lambda eps, dtype, groups, gate_first, shared_scale, name: plain(
             eps, dtype, groups, gate_first=True, shared_scale=shared_scale,
             name=name,
@@ -274,7 +274,7 @@ def _gate_before_norm(monkeypatch):
 
 def _one_norm_over_all_heads(monkeypatch):
     """One statistic over all the value heads' columns, the scale tiled."""
-    class Whole(zoo.GatedRMSNorm):
+    class Whole(delta_net.GatedRMSNorm):
         @nn.compact
         def __call__(self, y, z):
             scale = jnp.tile(self.param(
@@ -284,7 +284,7 @@ def _one_norm_over_all_heads(monkeypatch):
                 decoder.rms_norm(y, scale, self.eps) * jax.nn.silu(z)
             ).astype(self.dtype)
 
-    monkeypatch.setattr(zoo, "GatedRMSNorm", Whole)
+    monkeypatch.setattr(delta_net, "GatedRMSNorm", Whole)
 
 
 def _plain_scales(monkeypatch):
@@ -304,7 +304,7 @@ def _plain_scales(monkeypatch):
 
 def _values_on_other_key_heads(monkeypatch):
     """Value head h reading key head h % 2 (interleaved), not h // 2."""
-    plain = zoo.gdn
+    plain = delta_net.gdn
 
     def regrouped(q, k, v, g, beta, qk_norm):
         order = jnp.asarray([0, 2, 1, 3])
@@ -313,22 +313,22 @@ def _values_on_other_key_heads(monkeypatch):
             qk_norm=qk_norm,
         )[:, :, order]
 
-    monkeypatch.setattr(zoo, "gdn", regrouped)
+    monkeypatch.setattr(delta_net, "gdn", regrouped)
 
 
 def _decay_dropped(monkeypatch):
-    plain = zoo.gdn
+    plain = delta_net.gdn
     monkeypatch.setattr(
-        zoo, "gdn", lambda q, k, v, g, beta, qk_norm: plain(
+        delta_net, "gdn", lambda q, k, v, g, beta, qk_norm: plain(
             q, k, v, jnp.zeros_like(g), beta, qk_norm=qk_norm
         ),
     )
 
 
 def _l2_norms_dropped(monkeypatch):
-    plain = zoo.gdn
+    plain = delta_net.gdn
     monkeypatch.setattr(
-        zoo, "gdn", lambda q, k, v, g, beta, qk_norm: plain(
+        delta_net, "gdn", lambda q, k, v, g, beta, qk_norm: plain(
             q, k, v, g, beta
         ),
     )
@@ -336,7 +336,7 @@ def _l2_norms_dropped(monkeypatch):
 
 def _no_conv(monkeypatch):
     monkeypatch.setattr(
-        zoo, "silu_short_conv", lambda u, w: jax.nn.silu(u * w[-1])
+        delta_net, "silu_short_conv", lambda u, w: jax.nn.silu(u * w[-1])
     )
 
 

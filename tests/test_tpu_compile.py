@@ -388,6 +388,47 @@ def test_granite_cell_kernels_compile_for_the_chip(one_chip, monkeypatch):
     _assert_two_kernels(attention, "causal")
 
 
+# (apart from the three other whole steps, for the reason given above the
+# Ouro cell's)
+def test_olmo_hybrid_cell_step_compiles_for_the_chip(one_chip, monkeypatch):
+    """The Olmo-Hybrid cell's whole train step (ONE sequence of 8,192, four
+    layers at the published widths, heads 0-9 of 30 held, Adam, the lean
+    remat policy: no room given) compiled for the described v5e, abstract:
+    the scalar-decay scan's kernels at ten heads of 96 | 192 PADDED to 128
+    | 256 (the state a head (256, 128)), the SiLU conv over 3,840 columns,
+    attention at 10 / 10 heads of 128; and what the configuration's
+    `fifteen_heads` states: the step's arguments and temporaries under
+    ISSUE 64's line."""
+    from elasticdl_tpu.ops import gdn, short_conv
+    from model_zoo.olmo_hybrid import olmo_hybrid as zoo
+
+    for module in (fa, gdn, short_conv):
+        monkeypatch.setattr(module, "use_interpret", lambda: False)
+    compiled = _cell_train_step(
+        one_chip, zoo, _cell_config("olmo-hybrid-7b"), (1, 8192)
+    )
+    text = compiled.as_text()
+    assert "gdn_chunk_fwd" in text and "gdn_chunk_bwd" in text
+    assert not re.search(r"kda_\w*(fwd|bwd)", text)
+    # q and k at ten heads of 128, v at ten of 256: 96 | 192 padded
+    kernel = next(
+        line for line in text.splitlines()
+        if "gdn_chunk_bwd" in line and "custom-call(" in line
+    )
+    assert "bf16[1,8192,1280]" in kernel and "bf16[1,8192,2560]" in kernel
+    assert "f32[1,10,128,2,64]" in kernel
+    assert "f32[1,10,128,256,128]" in text
+    assert "silu_short_conv_fwd" in text and "silu_short_conv_bwd" in text
+    assert "bf16[1,8192,3840]" in text
+    _assert_two_kernels(text, "causal")
+    memory = compiled.memory_analysis()
+    held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
+    # `fifteen_heads`: 8.546e9 of arguments (12 bytes a parameter) +
+    # 2.369e9 of temporaries = 10.915e9 at ten heads, under the 14.5e9 line
+    assert memory.argument_size_in_bytes == pytest.approx(8.546e9, rel=1e-3)
+    assert 10.5e9 < held < 11.4e9, held
+
+
 def test_nemotron_cell_kernels_compile_for_the_chip(one_chip, monkeypatch):
     """The Nemotron cell's four calls at its shapes: the state-space scan
     at EIGHT groups (2, 8192, 64 heads of 64 over 128 state columns, grid
