@@ -53,6 +53,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.common import metrics as metrics_lib
@@ -116,6 +117,34 @@ padded_work_ratio = metrics_lib.default_registry().gauge(
     "1; 0.0 means nothing is padded",
     labelnames=("layer",),
 )
+
+
+# What a block's backward reads of `RoutedExperts`' routing, NAMED where the
+# layer makes it (`checkpoint_name`): the scores (n, experts) float32 (under
+# a softmax their exponentials, `_softmax`), which the backward of the score
+# function and of the router's product reads; the picks and the picked
+# scores, one a slot; the slots' sorted order; the held groups' sizes.  A
+# remat that keeps them (`model_zoo/common/decoder.py: SAVED_NAMES`, always)
+# rebuilds no router product, no sigmoid or exponential, no `top_k`, no
+# gather of the picked scores, no count of the loads and no sort; what it
+# still rebuilds of the routing is sums and a division: a slot's weight from
+# its picked score and, under a softmax, the exponentials' sum.  The three
+# that are one value a slot are named as the FLAT (tokens x top_k,) view the
+# walk takes of them: the chip tiles the minor axis of an (n, top_k) array to
+# 128 lanes, 8.4 MB held for 0.66 MB of values at 16,384 x 10 (as the
+# attention core's log-sum-exp column was until it went lane-major).  Outside
+# a remat a name is the identity and lowers to nothing.
+SCORES_NAME, PICKS_NAME, PICKED_NAME, ORDER_NAME, SIZES_NAME = SAVED_NAMES = (
+    "router_scores", "router_picks", "router_picked_scores",
+    "routed_slot_order", "routed_group_sizes",
+)
+
+
+def _named_flat(x, name: str):
+    """`x` (n, top_k), its FLAT view carrying `name`: what a remat that
+    saves the name holds is the (n x top_k,) array, and the (n, top_k) one is
+    a view of it."""
+    return checkpoint_name(x.reshape(-1), name).reshape(x.shape)
 
 
 def expert_loads(expert_idx, num_experts: int):
@@ -278,12 +307,51 @@ FORMS = {
     REGLU: ("expert_w_gate_up", 2, _reglu),     # (relu(x Wg) * (x Wu)) Wd
 }
 
+
+def _kept(fn, slope):
+    """Elementwise `fn` with its output carrying `SCORES_NAME` and its
+    derivative READ OFF THE NAMED OUTPUT: `slope(fn(x))` is d fn / dx as
+    jax's own rule for `fn` writes it.  That rule reads the output where the
+    primitive made it, ahead of any name, so a remat that saved the name
+    alone would still rebuild the router's product and `fn` to have that
+    value again.  (The rule names the output ITSELF: called through the
+    `custom_jvp` there, the name would lie inside a `custom_jvp_call`
+    equation, where a remat's policy does not look.)"""
+    def named(x):
+        return checkpoint_name(fn(x), SCORES_NAME)
+
+    kept = jax.custom_jvp(named)
+
+    @kept.defjvp
+    def kept_jvp(primals, tangents):
+        out = named(*primals)
+        return out, tangents[0] * slope(out)
+
+    return kept
+
+
+_kept_exp = _kept(jnp.exp, lambda raised: raised)
+
+
+def _softmax(logits):
+    """`jax.nn.softmax` over the last axis as it is differentiated here
+    (through its exponentials and their sum, the row's maximum held
+    constant), the exponentials kept (`_kept`): they are what the backward
+    reads, the scores themselves it does not."""
+    raised = _kept_exp(
+        logits - lax.stop_gradient(logits.max(axis=-1, keepdims=True))
+    )
+    return raised / raised.sum(axis=-1, keepdims=True)
+
+
 # The router's scores over its float32 logits (n, num_experts), chosen
-# like `FORMS`: each expert's own sigmoid, or one softmax over all.
+# like `FORMS`: each expert's own sigmoid, or one softmax over all.  Each
+# keeps, under `SCORES_NAME`, the one (n, num_experts) array its backward
+# reads: the sigmoid its scores, the softmax its exponentials.
 SIGMOID, SOFTMAX = "sigmoid", "softmax"
 SCORES = {
-    SIGMOID: jax.nn.sigmoid,
-    SOFTMAX: functools.partial(jax.nn.softmax, axis=-1),
+    SIGMOID: _kept(jax.nn.sigmoid, lambda s: s * (1 - s)),
+    SOFTMAX: _softmax,
 }
 
 
@@ -738,7 +806,10 @@ class RoutedExperts(nn.Module):
                 )
                 selection = selection + bias.value
             _, idx = jax.lax.top_k(selection, k)            # (n, k)
-            picked = jnp.take_along_axis(scores, idx, axis=1)
+            idx = _named_flat(idx, PICKS_NAME)
+            picked = _named_flat(
+                jnp.take_along_axis(scores, idx, axis=1), PICKED_NAME
+            )
             total = picked.sum(axis=1, keepdims=True)
             if self.renorm_eps:
                 total = total + self.renorm_eps
@@ -756,8 +827,12 @@ class RoutedExperts(nn.Module):
             held = (local >= 0) & (local < count)
             # slots of absent experts sort past every held group
             key = jnp.where(held, local, count).reshape(-1)
-            order = jnp.argsort(key, stable=True)
-            group_sizes = loads[first:first + count].astype(jnp.int32)
+            order = checkpoint_name(
+                jnp.argsort(key, stable=True), ORDER_NAME
+            )
+            group_sizes = checkpoint_name(
+                loads[first:first + count].astype(jnp.int32), SIZES_NAME
+            )
             rows = group_sizes.sum()
 
         w_first = self.param(

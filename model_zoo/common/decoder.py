@@ -41,7 +41,7 @@ import optax
 from jax.ad_checkpoint import checkpoint_name
 
 from elasticdl_tpu.common import metrics as metrics_lib
-from elasticdl_tpu.layers import step_metrics
+from elasticdl_tpu.layers import moe, step_metrics
 from elasticdl_tpu.layers.embedding import embedding_param_sharding
 from elasticdl_tpu.layers.moe import (
     RELU2,
@@ -59,7 +59,7 @@ from elasticdl_tpu.worker.trainer import remat_kept_ratio
 # for every decoder of the zoo.
 SAVED_NAMES = (
     flash_attention.SAVED_NAMES + kda.SAVED_NAMES + ssd.SAVED_NAMES
-    + gdn.SAVED_NAMES
+    + gdn.SAVED_NAMES + moe.SAVED_NAMES
 )
 
 # What the gates below sow into STEP_METRICS (a gate that closes silences
@@ -540,7 +540,9 @@ class MoEFFN(nn.Module):
 # block that norms the MLP's OUTPUT inside the residual branch: the
 # norm's backward reads it, and `SwiGLU(down_kind=FFN_OUT)` names it
 # there.  Nothing inside `layers/moe.py: routed_walk` is named (its
-# `custom_vjp` keeps what it keeps).
+# `custom_vjp` keeps what it keeps); the routing AROUND it is, where
+# `RoutedExperts` makes it, and is always kept (`moe.SAVED_NAMES`, in
+# SAVED_NAMES above): a hundredth of one projection's bytes.
 MIXER_IN, Q_UP, KV_UP, MIXER_OUT, GATE_UP, FFN_OUT = PRODUCT_NAMES = (
     "mixer_in", "mixer_q_up", "mixer_kv_up", "mixer_out", "gate_up",
     "ffn_out",
@@ -637,12 +639,17 @@ def tiled_bytes(variables) -> int:
     axes in whole tiles of (32 bytes of rows, 128 columns), so that a
     float32 (B, heads, L, 1) column takes 128 times its values (what the
     streaming attention forward saved as its log-sum-exp until PR 60) and
-    the (B, heads, 1, L) row it saves now 8 times them."""
+    the (B, heads, 1, L) row it saves now 8 times them.  An array of ONE
+    axis lies in whole tiles of 1,024 values (`s32[163840]{0:T(1024)}` in
+    the compiled steps: the routing's flat arrays, `layers/moe.py`)."""
     total = 0
     for v in variables:
         if not hasattr(v.aval, "shape"):
             continue
         size = v.aval.dtype.itemsize
+        if len(v.aval.shape) == 1:
+            total += size * -(-v.aval.shape[0] // 1024) * 1024
+            continue
         *lead, rows, columns = (1, 1) + tuple(v.aval.shape)
         tile = 32 // size
         total += size * int(np.prod(lead)) * (
@@ -778,12 +785,15 @@ def remat_block(block_cls, kept: Sequence[str] = ()):
     kernels read what the forward kernel wrote, so that kernel runs once
     a step (`ops/flash_attention.py: SAVED_NAMES`).  What the chunked
     scan of a linear-attention layer names joins the same policy
-    (`ops/kda.py: SAVED_NAMES`, which says what it keeps and why).  A
-    block with neither in it saves nothing.  `kept` adds the names of
-    the block's own products that `remat_blocks` found room for; empty,
-    it is the policy above and nothing else.  One class a (block,
-    names): the blocks of a model that keep the same names trace as
-    one."""
+    (`ops/kda.py: SAVED_NAMES`, which says what it keeps and why), and so
+    does what a routed block's backward reads of its routing (`layers/moe.py:
+    SAVED_NAMES`: the scores, the picks, the picked scores, the sorted order
+    and the group sizes, 19-36 MB a layer in the cells), so that the router,
+    `top_k` and the sort run once a step.  A block with none of them in it
+    saves nothing.  `kept` adds the names of the block's own products that
+    `remat_blocks` found room for; empty, it is the policy above and nothing
+    else.  One class a (block, names): the blocks of a model that keep the
+    same names trace as one."""
     return _remat_class(block_cls, tuple(kept))
 
 

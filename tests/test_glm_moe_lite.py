@@ -396,9 +396,16 @@ GLM_TREE_DIGEST = (
 # re-recorded on purpose in PR 62 (the cross-entropy makes the head's
 # gradient in the pass that makes the logits, `decoder.blocked_nll`, twice
 # here: the main head and the MTP module's; the commit before gave
-# 6fe1e1dc...); the tree's digest stands
+# 6fe1e1dc...); the tree's digest stands.  And in PR 65: a rematerialised
+# routed block keeps what its backward reads of the routing by name
+# (`layers/moe.py: SAVED_NAMES` in `decoder.SAVED_NAMES`: five `name`s a
+# routed layer, three of them on a flat view, and the sigmoid's derivative
+# read off the named scores), so its rebuilt forward holds no router
+# product, `top_k` or sort; the gradients are the parent's bit for bit
+# (`tests/test_remat_plan.py::test_a_rematerialised_routed_block_routes_once`)
+# and nothing else of the program moved (the commit before gave 38c04ffc...)
 GLM_PROGRAM_DIGEST = (
-    "38c04ffc35a2777b587452d0f3347ce2f69ce2f1406db8818c6d4452580555d2"
+    "9dd023e3e42b8d431c0bde9715a34ffa1a574b0a5562a4fcdd5c4d435714c90d"
 )
 
 

@@ -237,12 +237,22 @@ def test_a_routed_layer_sets_what_its_padding_costs(
 # layer at the GLM cell's bfloat16 shape (4 x 4,096 tokens of 2,048, top-4
 # of 64 with experts 8-15 held, 1,536 wide), sigmoid scores with the
 # balancing buffer and softmax scores, recorded at the commit before the
-# layer learnt the routing's source (87e4d23).
+# layer learnt the routing's source (87e4d23).  BOTH RE-RECORDED ON PURPOSE
+# in PR 65: the layer names what a block's backward reads of its routing
+# (`moe.SAVED_NAMES`), so the text gains five `name` equations and the six
+# `reshape`s of the three flat views (435 -> 446 equations under the sigmoid,
+# every other primitive as often as before); the softmax is written out in
+# `moe._softmax` so that its exponentials can carry the name (jax's jitted
+# `softmax` held its row maximum's dead derivative: 454 -> 455 equations, a
+# `max` against -inf, a `div`, two `eq`s' `select_n`s and four broadcasts
+# fewer).  The gradients are the parent's bit for bit in both
+# (`tests/test_remat_plan.py::test_a_rematerialised_routed_block_routes_once`);
+# the commit before gave a7e95908... and 165492cb...
 PARENTS_LAYER = {
     moe.SIGMOID:
-        "a7e9590801cd9b73221b4136a09e86312bd31d8c4a3727bdd76c6858ef2031a3",
+        "6d78ba5d953ebad5a980527e651d2af3e1f4abd275d75042f82b6a50bdf12922",
     moe.SOFTMAX:
-        "165492cba016256cd63f9807f6c036437c9b15b48da4ec76b9f0e839e64454af",
+        "23470e290f1e0e037dd09f505fbdbc19bc485428f146c17f2437aed815efb45e",
 }
 
 

@@ -156,18 +156,13 @@ class _Unsummed:
 
 def _weights_not_renormalised(monkeypatch):
     """w_i = 2.5 s_i: the sum over the chosen six left out."""
-    take = jnp.take_along_axis
+    named = moe._named_flat
 
-    def taken(values, idx, axis):
-        out = take(values, idx, axis=axis)
-        # the router's picked scores alone: (tokens, top_k) of all experts'
-        routers = (values.shape[1], idx.shape[1]) == (
-            CONFIG["n_routed_experts_published"],
-            CONFIG["num_experts_per_tok"],
-        )
-        return _Unsummed(out) if routers else out
+    def picked_unsummed(x, name):
+        out = named(x, name)
+        return _Unsummed(out) if name == moe.PICKED_NAME else out
 
-    monkeypatch.setattr(moe.jnp, "take_along_axis", taken)
+    monkeypatch.setattr(moe, "_named_flat", picked_unsummed)
 
 
 def _rotated(monkeypatch):

@@ -379,7 +379,7 @@ def _moe_change(**changes):
 
 def _weights_not_renormalised(monkeypatch):
     """w_i = p_i: the sum over the chosen left out."""
-    take = jnp.take_along_axis
+    named = moe._named_flat
 
     class Unsummed:
         def __init__(self, picked):
@@ -391,14 +391,11 @@ def _weights_not_renormalised(monkeypatch):
         def __rmul__(self, scale):
             return scale * self.picked
 
-    def taken(values, idx, axis):
-        out = take(values, idx, axis=axis)
-        routers = (values.shape[1], idx.shape[1]) == (
-            CONFIG["num_experts_published"], CONFIG["num_experts_per_tok"],
-        )
-        return Unsummed(out) if routers else out
+    def picked_unsummed(x, name):
+        out = named(x, name)
+        return Unsummed(out) if name == moe.PICKED_NAME else out
 
-    monkeypatch.setattr(moe.jnp, "take_along_axis", taken)
+    monkeypatch.setattr(moe, "_named_flat", picked_unsummed)
 
 
 CONTROLS = {
@@ -664,6 +661,18 @@ def test_the_interval_names_every_layer():
 # before gave 8da14260..., 7823daed..., 690fd4a6..., 0287421f..., 43cd4ec7...,
 # e8401b42..., b223f00c...), and the attention calls' digests in
 # `tests/test_flash_attention.py` stand.
+# THE SIX ROUTED ONES RE-RECORDED ON PURPOSE in PR 65 (Granite's, with no
+# routed layer, STANDS): `layers/moe.py: RoutedExperts` names what a block's
+# backward reads of its routing and `decoder.SAVED_NAMES` keeps it, so each
+# text gains five `name`s and three flat views' `reshape`s a routed layer,
+# the score function's derivative reads the named array (`moe._kept`;
+# Qwen3-Next's softmax is written out in `moe._softmax`), and the `checkpoint`
+# equations' rebuilt forwards lose the router's product, the scores, `top_k`,
+# the picks' gather, `expert_loads` and `argsort`; nothing else moved (the
+# commit before gave 0c307c8e..., 6283dd03..., 7156f5ff..., 48dcc8a8...,
+# d4beacaf..., 0a5b5ed0...), the gradients are the parent's bit for bit
+# (`tests/test_remat_plan.py::test_a_rematerialised_routed_block_routes_once`)
+# and the attention and scan calls' digests stand.
 PARENTS_JAXPRS = {
     "kimi-linear-48b-a3b": (
         "kimi.kimi_linear", (2, 8192),
@@ -671,15 +680,15 @@ PARENTS_JAXPRS = {
         # kernels' bodies, whose substitution changed: `ops/kda.py`) and
         # in PR 56 (the KDA layer's norm a head, output gate and decay
         # over (B, L, heads x dim): `model_zoo/kimi/kimi_linear.py`)
-        "0c307c8e62781ddb18137b2973b85e6eca6769d93cf72166110597712901294d",
+        "da38540e7b7a3f2a3271acacbefdf16ecccef58e7a4983ac8c0949f8d60c866a",
     ),
     "nemotron-3-nano-30b-a3b": (
         "nemotron.nemotron_h", (2, 8192),
-        "6283dd03b08c0f0f534c96457a48366217d2ece823d9375aa644481ce4060b49",
+        "5d356b71ddf91c23d97fac2154dc04d7cec0ef8e5bda1e891d3ba712236fe982",
     ),
     "glm-4.7-flash": (
         "glm.glm_moe_lite", (4, 4096),
-        "7156f5ff16aa15dcd05b1af1b5c78cca77cc9e74f59d518d70915d6c6aec61ec",
+        "da0d7384846a41759fffb13948fcb6fa4f080d7a39568bcf0ea862b29f1e501b",
     ),
     # the four below recorded at the commit before `RoutedExperts` and
     # `MoEFFN` learnt the routing's source, `FORMS` ReGLU and
@@ -687,15 +696,15 @@ PARENTS_JAXPRS = {
     # what they read here
     "laguna-xs.2": (
         "laguna.laguna", (2, 8192),
-        "48dcc8a8e1005f72e6ee3e3a253d1acedd62365b7ee8fafab31ca01021a19dd1",
+        "7842ef69c6012e36def66d97f9c93504e629afe7cfd6593d90a7e955867d0835",
     ),
     "lfm2-24b-a2b": (
         "lfm2.lfm2_moe", (4, 8192),
-        "d4beacaf2b788510b18313c68de8a7b27240664882b77897e46524ed0274d034",
+        "6dcfa8f74b204252ce38b892378dd98b01decee29f1896d3f6d456fc83a069bf",
     ),
     "qwen3-next-80b-a3b": (
         "qwen3_next.qwen3_next", (2, 8192),
-        "0a5b5ed01f311335f44f80627e78c505196dde1d4193861192a5ab440132fe62",
+        "246718d52d37d74fe1290623739b58e4ca9df1d9f02584f31be8974d8f1a5791",
     ),
     "granite-4.0-h-micro": (
         "granite.granite_hybrid", (1, 8192),

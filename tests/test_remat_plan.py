@@ -406,7 +406,10 @@ def test_the_lean_estimate_errs_high_in_every_cell(config_name, traffic_name,
     if config_name.startswith("lfm2"):
         assert 0.35 <= kept <= 0.6
     if config_name.startswith("qwen3"):
-        assert 0.5 <= kept <= 0.7
+        # 0.5965 until PR 65: the routing kept by name (4 x 35.5 MB) is in
+        # the estimate, whose budget had 1.8 MB to spare, and the second
+        # layer's `mixer_in` (403 MB) is passed over for two `gate_up`s
+        assert 0.4 <= kept <= 0.6
     if config_name.startswith("nemotron"):
         assert 0.45 <= kept <= 0.6
     if config_name.startswith("smallthinker"):
@@ -446,11 +449,34 @@ def test_a_cells_log_sum_exp_is_padded_eightfold_at_most(
         ) - values <= 7 * values
     # the blocks whose every saved value is the attention core's (out,
     # bfloat16 and whole tiles, and the row): Ouro's, Laguna's, GLM's,
-    # SmallThinker's and LFM2's attention blocks
+    # SmallThinker's and LFM2's attention blocks; a ROUTED one of them
+    # saves its routing beside them since PR 65 (`moe.SAVED_NAMES`), whose
+    # padding is the scores' where the experts are fewer than the 128
+    # lanes, and a tile of 1,024 for the held groups' sizes: the three
+    # arrays of a value a slot are flat and whole tiles
     heads = sorted({shape[1] for shape in rows})
+    routing = [
+        (shape, dtype) for shape, dtype in plan.saved if len(shape) < 3
+    ][:5]
+    if routing:
+        (tokens, experts), slots, _, _, (held,) = (s for s, _ in routing)
+        assert tokens == batch * length and held <= experts
+        assert [shape for shape, _ in routing[1:4]] == [slots] * 3
+        assert slots[0] % tokens == 0 and slots[0] % 1024 == 0
+        assert [dtype for _, dtype in routing] == [
+            jnp.float32, jnp.int32, jnp.float32, jnp.int32, jnp.int32
+        ]
+    routed = sum(
+        decoder.tiled_bytes([Value(shape, dtype)])
+        - int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+        for shape, dtype in routing
+    )
     cores = [
         block for block in plan.blocks
-        if any(block.padding == 7 * batch * h * length * 4 for h in heads)
+        if any(
+            block.padding - extra == 7 * batch * h * length * 4
+            for h in heads for extra in {0, routed}
+        )
     ]
     if config_name.startswith(("ouro", "laguna", "glm", "smallthinker")):
         assert len(cores) == len(plan.blocks)
@@ -476,16 +502,25 @@ ZOOS = ["granite_hybrid", "laguna", "lfm2", "kimi_linear"]
 # products beside the losses where a rematerialised block made the logits
 # twice; nothing else of their programs moved (the commit before gave
 # 9780f560..., a3d03a92..., afb2441f..., 7131b7f1...; the attention calls'
-# digests in `tests/test_flash_attention.py` stand)
+# digests in `tests/test_flash_attention.py` stand).
+# THE THREE ROUTED ONES (Laguna, LFM2, Kimi) RE-RECORDED ON PURPOSE in PR 65:
+# `moe.SAVED_NAMES` joined `decoder.SAVED_NAMES`, so a rematerialised routed
+# block keeps its scores, picks, picked scores, sorted order and group sizes
+# and its rebuilt forward holds no router product, sigmoid, `top_k`, gather
+# of the picks, `expert_loads` or `argsort`; nothing else of their programs
+# moved and the gradients are the parent's to the bit
+# (`test_a_rematerialised_routed_block_routes_once`; the commit before gave
+# 5553de89..., 5d69ac1d..., 3c2439eb...).  Granite's, with no routed layer,
+# STANDS.
 PARENT_DIGESTS = {
     "granite_hybrid":
         "a65ef5f5914452b5d2ae0b7eafa0bc91eb0739fd9f1002b2eb3d653900436a43",
     "laguna":
-        "5553de89c650c610f2b47094bc11a3870455a4676f87d0db9b23c8d4a193b379",
+        "7e5902643ba5f50b7e47478a6611b8bae032d48a2e593c23c7f49d716115f5f2",
     "lfm2":
-        "5d69ac1dd9aa92f1ecdce3983a0f88964449f3178c078d45d92bbbe68aea412e",
+        "a32fdfb0a62857e34fed4e785ce2ae3c5d37a63f1125a9be5437e2835d538193",
     "kimi_linear":
-        "3c2439ebf5d23d17783213862513a0c1ec09fa85d6f59733d908bb158aa10a11",
+        "55b1bc559b96574fc80fdd991c97141137fc10009fbc612f80fb10f0d1a36ae6",
 }
 
 
@@ -588,6 +623,119 @@ def test_a_kept_product_is_not_rebuilt():
     assert toy_products(True, (GATE_UP,)) == plain + 3
     assert toy_products(True, (MIXER_IN, GATE_UP)) == plain + 1
     assert toy_products(True, everything) == plain
+
+
+class RoutedToyBlock(nn.Module):
+    """One product, then four of eight experts held, top-2: a routed block
+    as the decoders build it, the router reading the block's INPUT where
+    `ahead` (SmallThinker's `route_from`)."""
+
+    scores: str = moe.SIGMOID
+    ahead: bool = False
+
+    @nn.compact
+    def __call__(self, x):
+        y = decoder.dense(x.shape[-1], "mix", x.dtype, MIXER_OUT)(x)
+        return x + moe.RoutedExperts(
+            8, 2, 16, (2, 4), scores=self.scores, name="routed"
+        )(y, x if self.ahead else None).astype(x.dtype)
+
+
+ROUTINGS = [
+    pytest.param(moe.SIGMOID, False, id="sigmoid"),
+    pytest.param(moe.SOFTMAX, False, id="softmax"),
+    pytest.param(moe.SIGMOID, True, id="sigmoid-route_from"),
+    pytest.param(moe.SOFTMAX, True, id="softmax-route_from"),
+]
+
+
+def routed_toy_grads(scores, ahead, remat):
+    """(the gradient's jaxpr as text, the gradients compiled
+    `decoder_cases.UNFUSED`) of one `RoutedToyBlock` over seeded input."""
+    cls = decoder.remat_block(RoutedToyBlock) if remat else RoutedToyBlock
+    block = cls(scores, ahead)
+    x = jax.random.normal(jax.random.PRNGKey(1), TOY_X.shape, TOY_X.dtype)
+    variables = block.init(jax.random.PRNGKey(0), x)
+
+    def grads(params, x):
+        return jax.grad(lambda params, x: jnp.square(block.apply(
+            {**variables, "params": params}, x, mutable=True
+        )[0]).sum(), argnums=(0, 1))(params, x)
+
+    return (
+        str(jax.make_jaxpr(grads)(variables["params"], x)),
+        decoder_cases.compiled(grads, variables["params"], x),
+    )
+
+
+@pytest.mark.parametrize("scores, ahead", ROUTINGS)
+def test_a_rematerialised_routed_block_routes_once(scores, ahead):
+    """What the backward reads of the routing is named where
+    `RoutedExperts` makes it and always kept (`moe.SAVED_NAMES` in
+    `decoder.SAVED_NAMES`): the gradient of a rematerialised routed block
+    holds ONE `top_k` and ONE `sort` (the forward's; two each before PR
+    65), one router product and one pass of the score function's
+    transcendental fewer than a block rebuilt whole would, and its
+    gradients are the un-rematerialised block's bit for bit."""
+    def count(text, primitive):
+        return len(re.findall(rf"\b{primitive}\b", text))
+
+    plain, want = routed_toy_grads(scores, ahead, remat=False)
+    text, got = routed_toy_grads(scores, ahead, remat=True)
+    for primitive in ("top_k", "sort"):
+        assert count(plain, primitive) == count(text, primitive) == 1
+    # the rebuild makes `mix` again and nothing of the router: its
+    # product, its sigmoid or exponentials, the picked scores' gather and
+    # the loads' scatter-add appear as often as with no remat at all
+    assert count(text, "dot_general") == count(plain, "dot_general") + 1
+    for primitive in ("logistic", "exp", "gather", "scatter-add"):
+        assert count(text, primitive) == count(plain, primitive), primitive
+    assert jax.tree.all(jax.tree.map(
+        lambda a, b: bool((a == b).all()), got, want
+    ))
+
+
+@pytest.mark.parametrize("scores, ahead", ROUTINGS[:2])
+def test_block_shapes_counts_the_routing_along_the_lanes(
+    scores, ahead, monkeypatch
+):
+    """The routing's names count in `saved` at the bytes the chip's tiling
+    gives them, as every SAVED_NAMES value does, and the three that are
+    one value a slot are FLAT (tokens x top_k,): an (n, top_k) array, or
+    its column, would hold the lanes' 128 for its top_k."""
+    seen, tiled = [], decoder.tiled_bytes
+
+    def recording_tiling(variables):
+        seen.extend(v.aval for v in variables)
+        return tiled(variables)
+
+    monkeypatch.setattr(decoder, "tiled_bytes", recording_tiling)
+    decoder.block_shapes.cache_clear()
+    shapes = decoder.block_shapes(
+        RoutedToyBlock(scores, ahead, parent=None), TOY_X.shape, TOY_X.dtype
+    )
+    decoder.block_shapes.cache_clear()
+    experts, top_k, held = 8, 2, 4
+    assert sorted((a.shape, str(a.dtype)) for a in seen) == sorted([
+        ((TOKENS, experts), "float32"),         # scores | exponentials
+        ((TOKENS * top_k,), "int32"),           # picks
+        ((TOKENS * top_k,), "float32"),         # picked scores
+        ((TOKENS * top_k,), "int32"),           # sorted order
+        ((held,), "int32"),                     # group sizes
+    ])
+    assert shapes.saved == 4 * (
+        TOKENS * experts + 3 * TOKENS * top_k + held
+    )
+    # eight experts fill 8 of 128 lanes here; an array of one axis lies
+    # in whole tiles of 1,024 values
+    assert shapes.saved + shapes.padding == 4 * (TOKENS * 128 + 4 * 1024)
+    slots = 16384 * 10
+    assert decoder.tiled_bytes([Value((slots,), jnp.int32)]) == 4 * slots
+    assert decoder.tiled_bytes(
+        [Value((16384, 10), jnp.int32)]
+    ) == 4 * 16384 * 128
+    assert set(moe.SAVED_NAMES) <= set(decoder.SAVED_NAMES)
+    assert not set(moe.SAVED_NAMES) & set(decoder.PRODUCT_NAMES)
 
 
 def test_one_class_a_block_and_names():

@@ -563,6 +563,27 @@ def _cell_config(name):
         return json.load(f)
 
 
+def _assert_routed_once_a_layer(text, layers):
+    """The chip's `top_k` is a SORT of every token's scores, whole rows of
+    (tokens, experts), and the dispatch sorts the slots: the compiled step
+    holds one of each a routed layer, the forward's, and none under
+    `rematted_computation` (a remat keeps what the backward reads of the
+    routing by name, `layers/moe.py: SAVED_NAMES`; the step held two of
+    each a layer before PR 65), nor a second router product there."""
+    for kind in ("router/top_k", "dispatch/jit(argsort)/sort"):
+        sorts = [
+            line for line in text.splitlines()
+            if " sort(" in line and f'{kind}"' in line
+        ]
+        assert len(sorts) == layers, (kind, len(sorts))
+        assert not [s for s in sorts if "rematted_computation" in s]
+    assert not [
+        line for line in text.splitlines()
+        if "rematted_computation" in line
+        and re.search(r'router/(dot_general|top_k|scatter-add)"', line)
+    ]
+
+
 def test_qwen3_next_cell_step_compiles_for_the_chip(one_chip, monkeypatch):
     """The Qwen3-Next cell's whole train step (2 sequences of 8,192, four
     layers at the published widths, 32 of 512 experts held, Adam, the lean
@@ -595,6 +616,7 @@ def test_qwen3_next_cell_step_compiles_for_the_chip(one_chip, monkeypatch):
     assert "silu_short_conv_fwd" in text and "silu_short_conv_bwd" in text
     _assert_two_kernels(text, "causal")
     assert "ragged-dot" in text and "s32[163840]" in text
+    _assert_routed_once_a_layer(text, layers=4)
     memory = compiled.memory_analysis()
     held = memory.argument_size_in_bytes + memory.temp_size_in_bytes
     # `fifth_layer`: 7.508e9 + 5.548e9 = 13.056e9 of at most 15.2e9
@@ -627,6 +649,8 @@ def test_smallthinker_cell_step_compiles_for_the_chip(one_chip, monkeypatch):
     )
     assert "bf16[1,16384,3584]" in kernel and "bf16[1,16384,512]" in kernel
     assert "ragged-dot" in text and "s32[98304]" in text
+    # the routing ahead of attention (`smallthinker/route`) is kept as well
+    _assert_routed_once_a_layer(text, layers=4)
     tilings = re.findall(r'ragged_dot_tiling="(\d+),(\d+),(\d+)"', text)
     assert tilings
     assert all(int(t) >= moe.TILE for tiling in tilings for t in tiling)
