@@ -8,7 +8,9 @@ plain or zero-centred), its gated form (one statistic a group of
 channels, the gate before the norm or after it) and its whole-width form
 (`WholeWidthNorm`: one statistic over every head's columns together),
 rotary's turn whole or
-over a head's first columns (`Rope`, `partial_rotary`), the seeds of a
+over a head's first columns (`Rope`, `partial_rotary`; `ops/rotary.py`'s
+one-pass kernel where the shapes tile, the share of a layer's turn it took
+sown beside it: `sow_rope_one_pass`), the seeds of a
 decay (`a_log_init`, `dt_bias_init`), the bias-free dense layer, SwiGLU
 and the non-gated squared-ReLU MLP, grouped-query attention
 (`GroupedAttention`: no positions, no band, no norms and no gate unless
@@ -53,6 +55,7 @@ from elasticdl_tpu.layers.moe import (
 )
 from elasticdl_tpu.layers.step_metrics import STEP_METRICS, sow_step_metric
 from elasticdl_tpu.ops import flash_attention, gdn, kda, ssd
+from elasticdl_tpu.ops.rotary import one_pass_ok, rotary_turn
 from elasticdl_tpu.worker.trainer import remat_kept_ratio
 
 # What a rematerialised block keeps from its forward, by name: ONE policy
@@ -207,29 +210,30 @@ class GatedRMSNorm(nn.Module):
         return (y if self.gate_first else y * gate).astype(self.dtype)
 
 
-def rotary_turn(x, inv_freq, factor: float = 1.0):
-    """Turn (B, L, H, R) by position = index along L at the R / 2
-    frequencies `inv_freq`, the HALVES pairing: column i turns with
-    column i + R/2; cos and sin times `factor`.  float32 inside."""
-    length = x.shape[1]
-    angle = jnp.arange(length, dtype=jnp.float32)[:, None] * inv_freq[None]
-    cos, sin = jnp.cos(angle)[None, :, None], jnp.sin(angle)[None, :, None]
-    if factor != 1.0:
-        cos, sin = cos * factor, sin * factor
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    ).astype(x.dtype)
+# What an attention layer that turns its queries and keys sows into
+# STEP_METRICS: a later shape that falls off the kernel shows here, not as
+# a slower step nobody can name.
+step_metrics.declare(
+    "rope_one_pass_ratio",
+    metrics_lib.default_registry().gauge(
+        "worker_rope_one_pass_ratio",
+        "share of the query and key elements a layer's rotary embedding "
+        "turns that the one-pass kernel turned (`ops/rotary.py`; the rest "
+        "went through float32 halves in plain jnp), last step of the task; "
+        "a layer that turns nothing reports nothing",
+        labelnames=("layer",),
+    ),
+)
 
 
-def rotary(x, theta: float):
-    """Plain rotary embedding over the whole last axis of (B, L, H, R)
-    (the row of the catalog does not say which pairing the checkpoint
-    uses; with seeded weights the two differ by a permutation of
-    columns)."""
-    width = x.shape[-1]
+def rotary(x, theta: float, first: int = 0):
+    """Plain rotary embedding over a head's columns from `first` on (all
+    of them unless said) of (B, L, H, D) (the row of the catalog does not
+    say which pairing the checkpoint uses; with seeded weights the two
+    differ by a permutation of columns)."""
+    width = x.shape[-1] - first
     inv_freq = theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
-    return rotary_turn(x, inv_freq)
+    return rotary_turn(x, inv_freq, first=first)
 
 
 class Rope(NamedTuple):
@@ -254,12 +258,23 @@ def plain_rope(head_dim: int, theta: float, share: float = 1.0) -> Rope:
 
 def partial_rotary(x, rope: Rope):
     """Turn the first `rope.columns` columns of (B, L, H, D)."""
-    inv_freq = jnp.asarray(rope.inv_freq, jnp.float32)
-    if rope.columns == x.shape[-1]:
-        return rotary_turn(x, inv_freq, rope.factor)
-    turned, kept = jnp.split(x, [rope.columns], axis=-1)
-    return jnp.concatenate(
-        [rotary_turn(turned, inv_freq, rope.factor), kept], axis=-1
+    return rotary_turn(
+        x, jnp.asarray(rope.inv_freq, jnp.float32), rope.factor
+    )
+
+
+def sow_rope_one_pass(module, columns: int, q_shape, k_shape,
+                      q_first: int = 0) -> None:
+    """Sow the share of the elements a layer turns that the kernel takes:
+    (B, L, H, D) queries and keys, each turned over `columns` of a head,
+    the queries' from column `q_first` on."""
+    sizes = [
+        (np.prod(shape[:3]) * columns, one_pass_ok(shape, columns, first))
+        for shape, first in ((q_shape, q_first), (k_shape, 0))
+    ]
+    sow_step_metric(
+        module, "rope_one_pass_ratio",
+        sum(size for size, ok in sizes if ok) / sum(size for size, _ in sizes),
     )
 
 
@@ -444,6 +459,7 @@ class GroupedAttention(nn.Module):
                     for name, t in (("q_norm", q), ("k_norm", k))
                 )
             if self.rope is not None:
+                sow_rope_one_pass(self, self.rope.columns, q.shape, k.shape)
                 q, k = partial_rotary(q, self.rope), partial_rotary(
                     k, self.rope
                 )

@@ -30,6 +30,7 @@ from model_zoo.common.decoder import (
     RMSNorm,
     dense,
     rotary,
+    sow_rope_one_pass,
 )
 
 
@@ -78,12 +79,10 @@ class MLA(nn.Module):
             # q's turn before k_pe's, in the order the GLM model always
             # traced them (its jaxpr is held: tests/test_glm_moe_lite.py)
             if self.rotate:
-                q_nope, q_rope = jnp.split(q, [nope], axis=-1)
-                q = jnp.concatenate(
-                    [q_nope, rotary(q_rope, self.rope_theta)], axis=-1
-                )
+                q = rotary(q, self.rope_theta, first=nope)
             k_rope = k_rope[:, :, None, :]
             if self.rotate:
+                sow_rope_one_pass(self, rope, q.shape, k_rope.shape, nope)
                 k_rope = rotary(k_rope, self.rope_theta)
             k = jnp.concatenate(
                 [k_nope, jnp.broadcast_to(

@@ -63,6 +63,7 @@ from model_zoo.common.decoder import (  # noqa: F401
     remat_blocks,
     routed_walks,
     shifted_nll,
+    sow_rope_one_pass,
 )
 
 FULL, WINDOW = "full_attention", "sliding_attention"
@@ -147,6 +148,7 @@ class GatedGroupedAttention(nn.Module):
             v = dense(kv_heads * dim, "v", self.dtype, MIXER_IN)(x).reshape(
                 batch, length, kv_heads, dim
             )
+            sow_rope_one_pass(self, self.rope.columns, q.shape, k.shape)
             out = causal_attention(
                 partial_rotary(q, self.rope), partial_rotary(k, self.rope),
                 v, scale=dim ** -0.5, window=self.window,

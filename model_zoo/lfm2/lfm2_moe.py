@@ -64,6 +64,7 @@ from model_zoo.common.decoder import (  # noqa: F401
     routed_walks,
     rotary,
     shifted_nll,
+    sow_rope_one_pass,
     tap_init,
 )
 
@@ -143,6 +144,7 @@ class NormedGroupedAttention(nn.Module):
             )
             q = RMSNorm(self.eps, self.dtype, name="q_norm")(q)
             k = RMSNorm(self.eps, self.dtype, name="k_norm")(k)
+            sow_rope_one_pass(self, dim, q.shape, k.shape)
             out = causal_attention(
                 rotary(q, self.theta), rotary(k, self.theta), v,
                 scale=dim ** -0.5,

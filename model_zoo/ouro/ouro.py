@@ -45,8 +45,10 @@ and its plan over `trips`) is `model_zoo/common/decoder.py`.
 
 What the loop leaves for the step's metrics comes out of it with a trip
 axis and is published a trip (`TripGauges`: `trip_2/trip_loss`); a gauge
-a BLOCK sowed inside the loop would keep its last trip's value
-(`layers/step_metrics.py: sow_step_metric`), and no block here sows one.
+a BLOCK sows inside the loop does not leave it (the loop carries no such
+collection; `layers/step_metrics.py: sow_step_metric`), so what the blocks'
+attention would sow of its turn (`decoder.sow_rope_one_pass`: static, the
+same shapes every block and trip) the model sows itself.
 
 Record format: seq_len int32 token ids | 1 label byte (ignored), the
 fixed-width record `model_zoo/bert` reads.
@@ -79,6 +81,7 @@ from model_zoo.common.decoder import (  # noqa: F401
     plain_rope,
     remat_blocks,
     shifted_nll,
+    sow_rope_one_pass,
     weighed_nll,
 )
 
@@ -241,6 +244,9 @@ class Ouro(nn.Module):
                 trip, variable_broadcast="params",
                 split_rngs={"params": False}, length=c.trips,
             )(self, x, None)                                 # (R, B, L, d)
+        sow_rope_one_pass(self, c.rope.columns, *(
+            (*ids.shape, heads, c.head_dim) for heads in (c.heads, c.kv_heads)
+        ))
         head = self.param(
             "lm_head_kernel", nn.initializers.lecun_normal(),
             (c.hidden, c.vocab_size),

@@ -403,9 +403,16 @@ GLM_TREE_DIGEST = (
 # read off the named scores), so its rebuilt forward holds no router
 # product, `top_k` or sort; the gradients are the parent's bit for bit
 # (`tests/test_remat_plan.py::test_a_rematerialised_routed_block_routes_once`)
-# and nothing else of the program moved (the commit before gave 38c04ffc...)
+# and nothing else of the program moved (the commit before gave 38c04ffc...).
+# And in PR 66, for the ORDER of two operations alone: the query's turn goes
+# through `decoder.rotary(q, theta, first=nope)`, which makes its
+# frequencies BEFORE `ops/rotary.py: halves_turn` splits nope | rope (this
+# model's heads of 16 are no shape the one-pass kernel takes); the same
+# operations, and the gradients of a seeded step are the parent's bit for
+# bit (compared against a `git archive` of the parent; the commit before
+# gave 9dd023e3...)
 GLM_PROGRAM_DIGEST = (
-    "9dd023e3e42b8d431c0bde9715a34ffa1a574b0a5562a4fcdd5c4d435714c90d"
+    "e1226af4c1f26987e4c115f2ec29f1e83c944449c6e62bdd0a8d7270b1af60cc"
 )
 
 

@@ -120,7 +120,11 @@ def test_one_trip_with_no_entropy_is_a_plain_decoder(seeded):
         model.init, jax.random.PRNGKey(0), {"input_ids": seeded.ids}
     )
     assert jax.tree.structure(shapes["params"]) == jax.tree.structure(params)
-    assert set(shapes[STEP_METRICS]) == {"token_embedding"}
+    # (and the share of the blocks' rotary turn the kernel took, which the
+    # model sows for its blocks: `decoder.sow_rope_one_pass`)
+    assert set(shapes[STEP_METRICS]) == {
+        "token_embedding", "rope_one_pass_ratio"
+    }
     flat = {k: v for k, v in seeded.flat.items() if "exit_gate" not in k}
     want_loss, want = reference.loss_and_grads(
         flat, {"input_ids": seeded.ids}, None, config

@@ -673,6 +673,20 @@ def test_the_interval_names_every_layer():
 # d4beacaf..., 0a5b5ed0...), the gradients are the parent's bit for bit
 # (`tests/test_remat_plan.py::test_a_rematerialised_routed_block_routes_once`)
 # and the attention and scan calls' digests stand.
+# THE FOUR THAT TURN THEIR QUERIES AND KEYS RE-RECORDED ON PURPOSE in PR 66
+# (GLM, Laguna, LFM2, Qwen3-Next; Kimi's MLA carries no positions, Nemotron
+# and Granite no rotary: all three STAND): `decoder.rotary_turn` is
+# `ops/rotary.py`'s one-pass kernel at the cells' shapes, so each text gains
+# a `rotary_turn` `pallas_call` for q and one for k a layer (a
+# `custom_vjp`; its backward the same kernel as `rotary_turn_bwd`) over the
+# (B, L, heads x dim) view and two (L, period) float32 tables, and loses the
+# float32 halves' `split` / `concatenate` (and MLA's split and join of nope |
+# rope: the kernel passes the 192 columns through); each attention layer
+# sows one more constant (`rope_one_pass_ratio`); nothing else moved (the
+# commit before gave da0d7384..., 7842ef69..., 6dcfa8f7..., 246718d5...).
+# On the chip the kernel's results are the halves' bit for bit, forward and
+# VJP, at every one of these shapes (`PERF.md` §6, PR 66), and
+# `tests/test_rotary.py` holds it to them in the interpreter.
 PARENTS_JAXPRS = {
     "kimi-linear-48b-a3b": (
         "kimi.kimi_linear", (2, 8192),
@@ -688,7 +702,7 @@ PARENTS_JAXPRS = {
     ),
     "glm-4.7-flash": (
         "glm.glm_moe_lite", (4, 4096),
-        "da0d7384846a41759fffb13948fcb6fa4f080d7a39568bcf0ea862b29f1e501b",
+        "d5b268bd43f02491e5bb314273d93da8625e6a80726c62488c1d45c83a33d5a5",
     ),
     # the four below recorded at the commit before `RoutedExperts` and
     # `MoEFFN` learnt the routing's source, `FORMS` ReGLU and
@@ -696,15 +710,15 @@ PARENTS_JAXPRS = {
     # what they read here
     "laguna-xs.2": (
         "laguna.laguna", (2, 8192),
-        "7842ef69c6012e36def66d97f9c93504e629afe7cfd6593d90a7e955867d0835",
+        "b68d9770cb3753e5d0b14ad3fc3cb4219371e7a1025dd392485030a18c11e2d0",
     ),
     "lfm2-24b-a2b": (
         "lfm2.lfm2_moe", (4, 8192),
-        "6dcfa8f74b204252ce38b892378dd98b01decee29f1896d3f6d456fc83a069bf",
+        "16b77a167fb2813ed329be2157f53d3e7116a5807f28f1a07017d09c21b5ac96",
     ),
     "qwen3-next-80b-a3b": (
         "qwen3_next.qwen3_next", (2, 8192),
-        "246718d52d37d74fe1290623739b58e4ca9df1d9f02584f31be8974d8f1a5791",
+        "2bb09e25a5ce3a1a239e70fe020df8725931d6e54e49f030863f9ca945960f3d",
     ),
     "granite-4.0-h-micro": (
         "granite.granite_hybrid", (1, 8192),

@@ -388,16 +388,23 @@ def test_the_lean_estimate_errs_high_in_every_cell(config_name, traffic_name,
     # blocks hold a smaller share of what they make than the scan mixers'
     # that bind the fit, PERF.md section 7)
     assert state + lean < chip_peak + 2.0e9
-    if config_name.startswith(("kimi", "laguna")):
-        # Kimi has 0.17e9 under the plan's line and is given nothing;
-        # Laguna has 1.2e9 there since PR 60 and the estimate, 1.6e9 high,
-        # still does not see it
-        # (Kimi's estimate stands within one `ROOM_GRAIN` of the line:
-        # what is left of the room in whole grains holds no product)
+    if config_name.startswith("kimi"):
+        # Kimi has 0.17e9 under the plan's line and is given nothing
+        # (its estimate stands within one `ROOM_GRAIN` of the line: what
+        # is left of the room in whole grains holds no product)
         assert kept == 0
         assert state + lean > 0.9 * CHIP_LIMIT - trainer_lib.ROOM_GRAIN
     if config_name.startswith("laguna"):
-        assert state + lean > 0.9 * CHIP_LIMIT
+        # 0 until PR 66, where the estimate stood 1.6e9 high and over the
+        # line: the blocks' traced arrays held the rotary's float32 q, its
+        # cotangent and the halves (0.743e9 of the estimate), which
+        # `ops/rotary.py`'s kernel never makes.  The estimate reads
+        # 14.876e9 now against a lean peak of 13.819e9 on the chip (my chip
+        # run, PR 66; the parent's 13.836e9 in the same call), 1.06e9
+        # high, and the plan keeps the three window layers' `mixer_out`
+        # and one `gate_up`: the chip's peak WITH them is 13.638e9
+        assert 0.08 <= kept <= 0.1
+        assert state + lean + kept * named <= 0.9 * CHIP_LIMIT
     if config_name.startswith("glm"):
         assert state + lean < 0.9 * CHIP_LIMIT and 0 < kept <= 0.1
     if config_name.startswith("granite"):

@@ -805,6 +805,105 @@ def test_kimi_step_keeps_its_kda_layers_along_the_lanes(
     assert not copies and not off_lanes, (copies, off_lanes)
 
 
+# (q heads, K/V heads, head width, batch, length, turned columns, the first
+# of them): every turn a rotary cell makes
+TURNS = [
+    pytest.param(64, 8, 128, 2, 8192, 128, 0, id="laguna-window"),
+    pytest.param(48, 8, 128, 2, 8192, 64, 0, id="laguna-full-yarn"),
+    pytest.param(28, 4, 128, 1, 16384, 128, 0, id="smallthinker"),
+    pytest.param(16, 16, 128, 1, 8192, 128, 0, id="ouro"),
+    pytest.param(32, 8, 64, 4, 8192, 64, 0, id="lfm2"),
+    pytest.param(16, 2, 256, 2, 8192, 64, 0, id="qwen3-next"),
+    # MLA: 64 columns after 192 that carry no position, and ONE shared
+    # 64-wide key part, read two tokens a row
+    pytest.param(20, 1, 256, 4, 4096, 64, 192, id="glm-mla"),
+]
+
+
+@pytest.mark.parametrize(
+    "heads, kv_heads, dim, batch, length, columns, first", TURNS
+)
+def test_the_rotary_kernel_compiles_for_the_chip(
+    one_chip, monkeypatch, heads, kv_heads, dim, batch, length, columns,
+    first
+):
+    """The one-pass turn of q and of k, forward and VJP, at a cell's
+    shape: Mosaic takes the lane rotations, the select and the blocks."""
+    import numpy as np
+
+    from elasticdl_tpu.ops import rotary
+
+    monkeypatch.setattr(rotary, "use_interpret", lambda: False)
+    inv_freq = jnp.asarray(
+        1e4 ** (-np.arange(0, columns, 2) / columns), jnp.float32
+    )
+    for count in (heads, kv_heads):
+        width, start = (dim, first) if count > 1 else (columns, 0)
+        shape = (batch, length, count, width)
+        assert rotary.one_pass_ok(shape, columns, start)
+
+        def both(x, g, start=start):
+            out, vjp = jax.vjp(
+                lambda x: rotary.rotary_turn(x, inv_freq, 1.0, start), x
+            )
+            return out, vjp(g)[0]
+
+        x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+        text = jax.jit(both).lower(x, x).compile().as_text()
+        assert text.count("tpu_custom_call") == 2
+        assert "rotary_turn_bwd" in text
+
+
+def _float32_or_half_filled(text, q_size, half):
+    """(the float32 arrays of `q_size` elements, the arrays whose two last
+    axes are (heads, `half`)) that the entry computation of a compiled
+    text MATERIALISES outside its kernels (what a fusion holds inside
+    itself never reaches memory)."""
+    whole, halves = set(), set()
+    for line in text[text.index("\nENTRY "):].splitlines():
+        found = re.match(
+            r"\s*(?:ROOT )?%[\w.\-]+ = \(?(\w+)\[([\d,]+)\]", line
+        )
+        if not found or "custom-call(" in line:
+            continue
+        dtype, dims = found.groups()
+        dims = [int(d) for d in dims.split(",")]
+        if dtype == "f32" and math.prod(dims) == q_size:
+            whole.add(f"{dtype}{dims}")
+        if len(dims) == 4 and dims[2:] == [64, half]:
+            halves.add(f"{dtype}{dims}")
+    return sorted(whole), sorted(halves)
+
+
+@pytest.mark.parametrize("form", ["kernel", "halves"])
+def test_a_laguna_window_layer_turns_q_where_its_columns_lie(
+    one_chip, monkeypatch, form
+):
+    """One Laguna window layer (64 heads of 128 over 8 K/V heads, (2,
+    8192, 2048) bfloat16 in) under the zoo's remat, value and gradient,
+    compiled for the described v5e (`scripts/probe_rotary.py`'s program):
+    NO float32 array of q's size and no `[.., 64, 64]` half outside the
+    kernels.  The control is the form the layer had, `halves_turn`: the q
+    product writes float32, the cotangent is converted whole, and four
+    half-filled copies a pass feed the turn."""
+    from elasticdl_tpu.ops import rotary
+    from model_zoo.common import decoder
+    from scripts import probe_rotary
+
+    for module in (fa, rotary):
+        monkeypatch.setattr(module, "use_interpret", lambda: False)
+    monkeypatch.setattr(decoder, "rotary_turn", probe_rotary.turn_of(form))
+    program, operands = probe_rotary.layer_program("window", 8192, one_chip)
+    text = program.lower(*operands).compile().as_text()
+    whole, halves = _float32_or_half_filled(text, 2 * 8192 * 64 * 128, 64)
+    if form == "kernel":
+        assert "rotary_turn" in text and "rotary_turn_bwd" in text
+        assert not whole and not halves, (whole, halves)
+    else:
+        assert "rotary_turn" not in text
+        assert whole and halves
+
+
 def test_the_scope_table_reads_a_text_compiled_for_the_chip(one_chip):
     """The chip's compiled text differs from the CPU's where the parser
     looks: tiled layouts with brackets of their own
